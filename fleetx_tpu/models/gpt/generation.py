@@ -161,8 +161,8 @@ def init_decode_cache(model, batch: int):
     THE cache constructor for every decode driver: ``generate()``,
     ``beam_search()``, and the continuous-batching serving engine
     (fleetx_tpu/serving/) all start from this tree, so its layout
-    ([batch, cache_len, heads, head_dim] per layer + a scalar
-    ``cache_index``; [num_pages, page_size, heads, head_dim] shared pages
+    ([batch, cache_len, heads*head_dim] per layer + a scalar
+    ``cache_index``; [num_pages, page_size, heads*head_dim] shared pages
     when the model carries ``cfg.decode_num_pages``) is defined in exactly
     one place."""
     cache_shapes = jax.eval_shape(

@@ -101,7 +101,7 @@ class GPTConfig:
     decode_cache_len: Optional[int] = None
     # paged decode cache (serving/cache_manager.py): when decode_num_pages
     # is set, decode-mode kv caches are ONE shared pool of
-    # [decode_num_pages, decode_page_size, heads, head_dim] pages instead
+    # [decode_num_pages, decode_page_size, heads*head_dim] pages instead
     # of per-row [b, decode_cache_len, ...] buffers; each row addresses
     # its logical [0, decode_cache_len) window through a block table of
     # page indices (``block_tables`` threading). decode_page_size must be
@@ -241,19 +241,13 @@ class SelfAttention(nn.Module):
                     )
                     out = checkpoint_name(out, "core_attn_out")
                     return self._out_proj(out)
+                # dense fallback: gather each row's pages (and, over an
+                # int8 pool, its scale pages) through the same table
                 k = paged_gather_kv(k, tables)
                 v = paged_gather_kv(v, tables)
                 if kv_scales is not None:
-                    # dense fallback over an int8 pool: gather each row's
-                    # scale pages through the same table, dequantize via
-                    # the shared helper (ops/quant.py)
-                    from fleetx_tpu.ops.quant import dequantize_kv
-
-                    k = dequantize_kv(
-                        k, paged_gather_kv(kv_scales[0], tables), q.dtype)
-                    v = dequantize_kv(
-                        v, paged_gather_kv(kv_scales[1], tables), q.dtype)
-                    kv_scales = None
+                    kv_scales = tuple(
+                        paged_gather_kv(s, tables) for s in kv_scales)
             elif decode_end is not None and self._flash_decode_ok(
                 kv_pad_mask, k.shape[1], deterministic, batch=q.shape[0]
             ):
@@ -274,15 +268,18 @@ class SelfAttention(nn.Module):
                 )
                 out = checkpoint_name(out, "core_attn_out")
                 return self._out_proj(out)
+            # dense fallback (prefill, custom masks, off-TPU): unfold the
+            # lane-dense buffers [b, len, nh*hd] back to per-head form
+            # and, over an int8 cache, dequantize them via the shared
+            # helper — correctness paths cost what dense always cost, the
+            # flash paths above never materialize this
+            k = k.reshape(*k.shape[:2], nh, hd)
+            v = v.reshape(*v.shape[:2], nh, hd)
             if kv_scales is not None:
-                # contiguous dense fallback (prefill, custom masks, off-TPU)
-                # over the int8 slot cache: dequantize the full buffers via
-                # the shared helper — correctness paths cost what dense
-                # always cost, the flash path above never materializes this
                 from fleetx_tpu.ops.quant import dequantize_kv
 
-                k = dequantize_kv(k, kv_scales[0], q.dtype)
-                v = dequantize_kv(v, kv_scales[1], q.dtype)
+                k = dequantize_kv(k, kv_scales[0][..., None], q.dtype)
+                v = dequantize_kv(v, kv_scales[1][..., None], q.dtype)
 
         if cfg.cp_degree > 1 and not decode:
             # Ring attention: sequence stays sharded over the cp axis; KV
@@ -341,7 +338,9 @@ class SelfAttention(nn.Module):
         """Incremental decode: append this step's k/v at cache_index and
         build the absolute-position causal mask (query i at absolute position
         start+i may see cache positions <= start+i). Cache layout
-        [batch, max_len, heads, head_dim].
+        [batch, max_len, heads*head_dim]: lane-dense, the layout the
+        flash-decode kernels block (ops/pallas/decode_attention.py
+        "Layout"); the dense fallback unfolds it at the call site.
 
         ``cache_positions`` ([b] int32, optional) gives each batch row its
         OWN write offset instead of the shared scalar ``cache_index`` — the
@@ -363,10 +362,10 @@ class SelfAttention(nn.Module):
 
         When ``cfg.decode_kv_dtype == "int8"`` the cache leaves store int8
         values plus ``cached_key_scale``/``cached_value_scale`` fp32 leaves
-        of per-vector scales (``[..., max_len, nh, 1]``): this step's k/v
+        of per-vector scales (``[..., max_len, nh]``): this step's k/v
         quantize on write via ``ops/quant.quantize_kv``, and the returned
         buffers are the RAW int8 caches with ``kv_scales`` carrying the
-        scale buffers — the flash kernel dequantizes in VMEM, the dense
+        scale buffers — the flash kernel applies them in VMEM, the dense
         fallback dequantizes in the caller.
 
         Returns ``(k, v, attn_mask, decode_end, paged, kv_scales)``:
@@ -388,23 +387,23 @@ class SelfAttention(nn.Module):
                    if self.cfg.decode_cache_len is not None
                    else self.cfg.max_position_embeddings)
         ck = self.variable(
-            "cache", "cached_key", jnp.zeros, (b, max_len, nh, hd),
+            "cache", "cached_key", jnp.zeros, (b, max_len, nh * hd),
             jnp.int8 if quant else k.dtype
         )
         cv = self.variable(
-            "cache", "cached_value", jnp.zeros, (b, max_len, nh, hd),
+            "cache", "cached_value", jnp.zeros, (b, max_len, nh * hd),
             jnp.int8 if quant else v.dtype
         )
         if quant:
-            # per-vector fp32 scales; the trailing 1 keeps the batch axis
-            # at -4 so scatter_slot and friends treat them like K/V leaves
+            # per-vector fp32 scales: rank 3 with the batch axis at -3,
+            # so scatter_slot and friends treat them like K/V leaves
             cks = self.variable(
                 "cache", "cached_key_scale", jnp.zeros,
-                (b, max_len, nh, 1), jnp.float32
+                (b, max_len, nh), jnp.float32
             )
             cvs = self.variable(
                 "cache", "cached_value_scale", jnp.zeros,
-                (b, max_len, nh, 1), jnp.float32
+                (b, max_len, nh), jnp.float32
             )
         idx = self.variable("cache", "cache_index", lambda: jnp.array(0, jnp.int32))
         decode_end = None
@@ -415,18 +414,21 @@ class SelfAttention(nn.Module):
 
                 k_w, k_s = quantize_kv(k)
                 v_w, v_s = quantize_kv(v)
+                k_s, v_s = k_s[..., 0], v_s[..., 0]  # [b, s, nh]
             else:
                 k_w, v_w = k, v
+            k_w = k_w.reshape(b, s, nh * hd)
+            v_w = v_w.reshape(b, s, nh * hd)
             k_pos = jnp.arange(max_len)
             if cache_positions is None:
                 start = idx.value
-                ck.value = jax.lax.dynamic_update_slice(ck.value, k_w, (0, start, 0, 0))
-                cv.value = jax.lax.dynamic_update_slice(cv.value, v_w, (0, start, 0, 0))
+                ck.value = jax.lax.dynamic_update_slice(ck.value, k_w, (0, start, 0))
+                cv.value = jax.lax.dynamic_update_slice(cv.value, v_w, (0, start, 0))
                 if quant:
                     cks.value = jax.lax.dynamic_update_slice(
-                        cks.value, k_s, (0, start, 0, 0))
+                        cks.value, k_s, (0, start, 0))
                     cvs.value = jax.lax.dynamic_update_slice(
-                        cvs.value, v_s, (0, start, 0, 0))
+                        cvs.value, v_s, (0, start, 0))
                 idx.value = start + s
                 if s == 1:
                     decode_end = idx.value
@@ -436,7 +438,7 @@ class SelfAttention(nn.Module):
                 wpos = cache_positions.astype(jnp.int32)  # [b] write offsets
                 row_update = jax.vmap(
                     lambda buf, new, p: jax.lax.dynamic_update_slice(
-                        buf, new, (p, 0, 0))
+                        buf, new, (p, 0))
                 )
                 ck.value = row_update(ck.value, k_w, wpos)
                 cv.value = row_update(cv.value, v_w, wpos)
@@ -462,8 +464,8 @@ class SelfAttention(nn.Module):
                             block_tables):
         """Page-granular decode cache write (``cfg.decode_num_pages`` set).
 
-        The cache leaves are ONE pool of ``[num_pages, page_size, nh, hd]``
-        shared pages; logical position ``p`` of row ``b`` lives at physical
+        The cache leaves are ONE pool of ``[num_pages, page_size, nh*hd]``
+        shared lane-dense pages; logical position ``p`` of row ``b`` lives at physical
         page ``block_tables[b, p // page_size]``, offset ``p % page_size``.
         This step's k/v rows scatter through the tables (positions clamped
         to the logical capacity: bucket-tail/pinned writes land on the
@@ -474,7 +476,7 @@ class SelfAttention(nn.Module):
         it after :func:`paged_gather_kv` unchanged.
 
         When ``cfg.decode_kv_dtype == "int8"`` the pools store int8 with
-        per-vector fp32 scale pools (``[num_pages, ps, nh, 1]``) scattered
+        per-vector fp32 scale pools (``[num_pages, ps, nh]``) scattered
         through the same block tables — see :meth:`_update_cache`.
 
         Returns ``(k_pages, v_pages, attn_mask, decode_end, tables,
@@ -496,20 +498,22 @@ class SelfAttention(nn.Module):
                 f"decode_page_size {ps}")
         ck = self.variable(
             "cache", "cached_key", jnp.zeros,
-            (cfg.decode_num_pages, ps, nh, hd), jnp.int8 if quant else k.dtype
+            (cfg.decode_num_pages, ps, nh * hd),
+            jnp.int8 if quant else k.dtype
         )
         cv = self.variable(
             "cache", "cached_value", jnp.zeros,
-            (cfg.decode_num_pages, ps, nh, hd), jnp.int8 if quant else v.dtype
+            (cfg.decode_num_pages, ps, nh * hd),
+            jnp.int8 if quant else v.dtype
         )
         if quant:
             cks = self.variable(
                 "cache", "cached_key_scale", jnp.zeros,
-                (cfg.decode_num_pages, ps, nh, 1), jnp.float32
+                (cfg.decode_num_pages, ps, nh), jnp.float32
             )
             cvs = self.variable(
                 "cache", "cached_value_scale", jnp.zeros,
-                (cfg.decode_num_pages, ps, nh, 1), jnp.float32
+                (cfg.decode_num_pages, ps, nh), jnp.float32
             )
         idx = self.variable("cache", "cache_index", lambda: jnp.array(0, jnp.int32))
         decode_end = None
@@ -533,16 +537,16 @@ class SelfAttention(nn.Module):
             pos = jnp.minimum(pos, max_len - 1)            # [b, s] logical
             page = jnp.take_along_axis(tables, pos // ps, axis=1)
             ck.value = ck.value.at[page.reshape(-1), (pos % ps).reshape(-1)
-                                   ].set(k_w.reshape(b * s, nh, hd))
+                                   ].set(k_w.reshape(b * s, nh * hd))
             cv.value = cv.value.at[page.reshape(-1), (pos % ps).reshape(-1)
-                                   ].set(v_w.reshape(b * s, nh, hd))
+                                   ].set(v_w.reshape(b * s, nh * hd))
             if quant:
                 cks.value = cks.value.at[
                     page.reshape(-1), (pos % ps).reshape(-1)
-                ].set(k_s.reshape(b * s, nh, 1))
+                ].set(k_s.reshape(b * s, nh))
                 cvs.value = cvs.value.at[
                     page.reshape(-1), (pos % ps).reshape(-1)
-                ].set(v_s.reshape(b * s, nh, 1))
+                ].set(v_s.reshape(b * s, nh))
                 kv_scales = (cks.value, cvs.value)
             idx.value = jnp.max(wpos) + s
             if s == 1:

@@ -4,8 +4,8 @@
 which on TPU lowers to a threefry2x32 keystream — ~100 VPU ops per pair of
 random words. For the GPT hidden dropouts (2 per layer on [b, s, h]
 activations, reference single_model.py:291,451 dropout1/dropout2) that RNG
-was measured at ~12% of the 345M train step on v5e (round-4 A/B:
-19,907 tok/s with hidden dropout off vs 18,112 on, BENCH_SESSION_r04).
+was about 12% of the 345M train step on v5e in an earlier round's A/B
+(19,907 tok/s with hidden dropout off vs 18,112 on; not reproduced).
 
 ``HashDropout`` keeps the same contract — deterministic given the
 ``'dropout'`` PRNG key, scale-by-1/(1-rate), zero where dropped — but

@@ -127,14 +127,16 @@ def quantize_kv(x: jax.Array):
     """Per-vector int8 for KV caches: absmax over the trailing (head_dim)
     axis, one fp32 scale per cached (row, head) vector.
 
-    Returns ``(int8 values, fp32 scales [..., 1])`` — the keepdims
-    trailing 1 is load-bearing: scale leaves then share the K/V leaves'
-    ``[..., batch, cache_len, heads, X]`` suffix, so every tree walker
-    that addresses K/V by trailing rank (``serving.scatter_slot``, the
-    paged page scatter, block-spec index maps) handles scales unchanged.
-    Per-vector granularity is what the dequant-in-kernel flash-decode
-    variant streams: one scale multiply per K/V row next to the dot
-    product (ops/pallas/decode_attention.py)."""
+    Returns ``(int8 values, fp32 scales [..., 1])`` for an in-flight
+    ``[..., heads, head_dim]`` tensor. The decode cache stores both
+    lane-dense — values ``[..., len, heads*head_dim]``, scales
+    ``[..., len, heads]`` (models/gpt/model.py folds them on write) — so
+    scale leaves share the K/V leaves' trailing rank and every tree
+    walker that addresses K/V by it (``serving.scatter_slot``, the paged
+    page scatter, block-spec index maps) handles scales unchanged.
+    Per-vector granularity is what the flash-decode kernels stream: one
+    scale per (row, head) factored out of the dot products
+    (ops/pallas/decode_attention.py)."""
     x32 = x.astype(jnp.float32)
     absmax = jnp.max(jnp.abs(x32), axis=-1, keepdims=True)
     scale = absmax / 127.0

@@ -18,7 +18,6 @@ from typing import Optional
 
 import jax
 
-from fleetx_tpu.utils.device_guard import honor_platform_env
 from fleetx_tpu.utils.log import logger
 
 __all__ = ["init_dist_env", "set_seed", "root_key", "global_seed", "data_rank_key"]
@@ -42,9 +41,6 @@ def init_dist_env(
     (env.py:85-114) — there are no per-strategy process groups to build;
     the Mesh carries all topology.
     """
-    # Honor an explicit JAX_PLATFORMS request even when a sitecustomize or
-    # other early import already pinned a different platform.
-    honor_platform_env()
     coordinator_address = coordinator_address or os.environ.get("FLEETX_COORDINATOR")
     if num_processes is None and os.environ.get("FLEETX_NUM_PROCESSES"):
         num_processes = int(os.environ["FLEETX_NUM_PROCESSES"])

@@ -155,21 +155,13 @@ def use_mesh(mesh: Mesh):
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-    """``jax.shard_map`` across the API move: newer jax exposes it at the
-    top level with ``check_vma``; 0.4.x ships ``jax.experimental.shard_map``
-    with the same knob spelled ``check_rep``. One call site contract
-    (keyword mesh/in_specs/out_specs) for every framework user."""
-    if hasattr(jax, "shard_map"):
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
+    """``jax.shard_map`` under one call-site contract (keyword
+    mesh/in_specs/out_specs, ``check_vma`` only when given) for every
+    framework user."""
     if check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def ambient_mesh() -> Optional[Mesh]:

@@ -49,6 +49,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from fleetx_tpu.serving.cache_manager import KV_LEAF_RANK
+
 __all__ = ["DraftModelProposer", "NgramProposer", "Proposer",
            "build_proposer"]
 
@@ -157,15 +159,16 @@ class NgramProposer:
 def _gather_slot(cache, slot):
     """Slice one lane's row out of a slot-layout cache tree (the inverse
     of :func:`~fleetx_tpu.serving.cache_manager.scatter_slot`): K/V
-    leaves keep their ``[..., batch, cache_len, heads, head_dim]``
-    suffix with the batch axis cut to 1; rank-<4 leaves (the
-    ``cache_index`` scalars) pass through untouched."""
+    leaves keep their ``[..., batch, cache_len, lanes]`` suffix
+    (``KV_LEAF_RANK``) with the batch axis cut to 1; lower-rank leaves
+    (the ``cache_index`` scalars) pass through untouched."""
 
     def take(big):
-        if big.ndim < 4:
+        if big.ndim < KV_LEAF_RANK:
             return big
-        starts = (0,) * (big.ndim - 4) + (slot, 0, 0, 0)
-        sizes = big.shape[:big.ndim - 4] + (1,) + big.shape[big.ndim - 3:]
+        ax = big.ndim - KV_LEAF_RANK
+        starts = (0,) * ax + (slot,) + (0,) * (KV_LEAF_RANK - 1)
+        sizes = big.shape[:ax] + (1,) + big.shape[ax + 1:]
         return jax.lax.dynamic_slice(big, starts, sizes)
 
     return jax.tree.map(take, cache)

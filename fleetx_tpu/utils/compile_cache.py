@@ -1,0 +1,56 @@
+"""One place for JAX's persistent compilation cache.
+
+Every entry point that compiles calls :func:`enable_compile_cache` before
+its first jit: ``tools/train.py``, ``eval.py``, ``export.py``,
+``inference.py``, the ``serve.py`` replica worker, ``bench.py``,
+``tools/bench_*.py`` and ``chip_smoke.py``. The directory's path is part
+of the cache's key, so a directory that moves between runs never hits;
+there are exactly two places it can be:
+
+- where ``JAX_COMPILATION_CACHE_DIR`` says, when it is set: jax reads
+  that variable itself, and this module then sets NO directory in code
+  (a machine that provides a cache across runs names it this way);
+- otherwise ``.jax_cache/`` at the root of the checkout (gitignored) —
+  a fixed path, never a temporary name, a pid or a timestamp, so a second
+  run of the same program on the same machine hits what the first wrote.
+  This default applies on an accelerator only: on the CPU backend
+  compiles are test-sized, and XLA:CPU's loader logs a page-long
+  machine-feature warning for every entry it reads back.
+
+Child processes inherit the same place: the environment variable passes
+down by itself, and the fixed path is the same for every process started
+from this checkout.
+"""
+
+from __future__ import annotations
+
+import os
+from typing import Optional
+
+__all__ = ["CACHE_DIR", "enable_compile_cache"]
+
+CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache")
+
+
+def enable_compile_cache() -> Optional[str]:
+    """Turn the persistent compilation cache on; returns its directory
+    (None on a CPU run without ``JAX_COMPILATION_CACHE_DIR``). Asks jax for
+    its backend, so call it after everything that must precede backend
+    initialization (utils/xla_flags.py, ``init_dist_env``).
+
+    Also drops jax's size/time floors so every program is kept — the
+    small per-bucket serving jits are many, and re-compiling each of them
+    cold is what a chip run would otherwise pay on every call."""
+    import jax
+
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        if jax.default_backend() == "cpu":
+            return None
+        cache_dir = CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return cache_dir

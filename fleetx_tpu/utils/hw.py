@@ -3,13 +3,14 @@
 One table (public spec sheets, dense bf16) so ``bench.py``'s BENCH_*
 records, the Trainer's live ``mfu`` gauge/log-line, and any future
 report all divide by the SAME peak — MFU numbers stay comparable across
-surfaces. Unknown accelerators assume v5e-class so the ratio is at
-least stable; CPU gets a placeholder that keeps smoke runs finite.
+surfaces. A device the table does not list is an error, never a default:
+a utilization against a guessed peak is not a measurement (the CPU has no
+row, so no CPU run can print an MFU).
 """
 
 from __future__ import annotations
 
-__all__ = ["PEAK_FLOPS", "peak_flops_per_chip"]
+__all__ = ["PEAK_FLOPS", "UnknownDeviceKind", "peak_flops_per_chip"]
 
 # Peak dense bf16 FLOP/s per chip by device kind (public spec sheets).
 PEAK_FLOPS = {
@@ -22,16 +23,22 @@ PEAK_FLOPS = {
     "TPU v3": 123e12,
     "TPU v6 lite": 918e12,   # Trillium
     "TPU v6e": 918e12,
-    "cpu": 1e12,             # placeholder so CPU smoke runs don't div0
 }
+
+
+class UnknownDeviceKind(LookupError):
+    """``device_kind`` has no row in :data:`PEAK_FLOPS`."""
 
 
 def peak_flops_per_chip(device) -> float:
     """Peak dense bf16 FLOP/s for ``device`` (a jax Device or anything
     with ``device_kind``). Longest-prefix match so 'TPU v4 lite'
-    resolves before 'TPU v4'; unknown kinds assume v5e-class."""
-    kind = getattr(device, "device_kind", "cpu")
+    resolves before 'TPU v4'; an unlisted kind raises
+    :class:`UnknownDeviceKind`."""
+    kind = device.device_kind
     for name in sorted(PEAK_FLOPS, key=len, reverse=True):
         if kind.startswith(name):
             return PEAK_FLOPS[name]
-    return 197e12  # unknown accelerator: assume v5e-class
+    raise UnknownDeviceKind(
+        f"no peak FLOP/s on record for device kind {kind!r}; add its row "
+        "to fleetx_tpu/utils/hw.py PEAK_FLOPS with the source")

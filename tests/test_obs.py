@@ -470,9 +470,11 @@ def test_healthz_fails_after_recovery_exhausted():
 # ------------------------------------------------------ trainer MFU line
 
 
-def test_trainer_logs_mfu_and_sets_gauges(tmp_path, caplog):
-    """Acceptance: the TRAIN ips: line reports MFU derived from
-    cost_analysis() flops, and the fleetx_train_* gauges are live."""
+def test_trainer_logs_no_mfu_on_cpu_and_sets_gauges(tmp_path, caplog):
+    """The TRAIN ips: line carries the mfu field, the fleetx_train_*
+    gauges are live, and on the CPU — a device utils/hw.py has no peak
+    for — the field reads "-" and the gauge stays unset: a utilization
+    against a placeholder peak is not a measurement."""
     import os
     import textwrap
 
@@ -536,12 +538,10 @@ def test_trainer_logs_mfu_and_sets_gauges(tmp_path, caplog):
     train_lines = [r.message for r in caplog.records
                    if "ips_total" in r.message]
     assert train_lines, "no TRAIN ips: line logged"
-    assert "mfu: " in train_lines[-1]
-    # XLA's CPU backend exposes flops for this tiny program, so the line
-    # must carry a real number, not the '-' fallback
-    assert "mfu: -" not in train_lines[-1], train_lines[-1]
+    assert "mfu: -" in train_lines[-1], train_lines[-1]
+    assert trainer._step_mfu(1.0) is None
     snap = get_registry().snapshot()
     assert snap["fleetx_train_steps_total"]["series"][0]["value"] >= 2
     assert snap["fleetx_train_tokens_per_second"]["series"][0]["value"] > 0
-    assert snap["fleetx_train_mfu"]["series"][0]["value"] > 0
+    assert not any(s["value"] for s in snap["fleetx_train_mfu"]["series"])
     assert snap["fleetx_train_step_seconds"]["series"][0]["count"] >= 2

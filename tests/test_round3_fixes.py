@@ -1,5 +1,4 @@
-"""Regression tests for the round-3 fix sweep (VERDICT.md round 2, items
-"What's weak" #3/#4/#5): quant weight filter, SR serving conditioning input,
+"""Regression tests for the round-3 fix sweep: quant weight filter, SR serving conditioning input,
 tree-path opt-state sharding, sharding_offload gating, and the
 non-deprecated ambient-mesh lookup."""
 

@@ -529,7 +529,7 @@ def test_raising_health_between_probes_does_not_crash_step(tiny):
     router.step()  # healthy first probe
 
     def raising_health():
-        raise RuntimeError("tunnel wedged")
+        raise RuntimeError("health endpoint wedged")
 
     e0.health = raising_health
     rids = [router.submit(p, max_length=8) for p in PROMPTS]

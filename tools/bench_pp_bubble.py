@@ -262,9 +262,9 @@ def main(argv=None):
                     help="shrink everything for smoke tests")
     args = ap.parse_args(argv)
 
-    from fleetx_tpu.utils.device_guard import honor_platform_env
+    from fleetx_tpu.utils.compile_cache import enable_compile_cache
 
-    honor_platform_env()
+    enable_compile_cache()
     records = []
     if args.virtual_pp:
         # default sweep sits in the thin-stage regime deliberately (module

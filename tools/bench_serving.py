@@ -1055,13 +1055,7 @@ def _decode_bytes_per_token(engine):
     int8 record makes, measured on the COMPILED step, not estimated.
     None when the backend's cost analysis has no byte accounting."""
     try:
-        compiled = engine._decode_jit.lower(
-            engine.params, engine.cache_manager.cache, engine._state,
-            engine._device_tables(), True).compile()
-        cost = compiled.cost_analysis()
-        # jax-version skew: one dict on newer jax, [dict] on older
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else None
+        cost = engine.compiled_decode().cost_analysis()
         if not cost or cost.get("bytes accessed") is None:
             return None
         return round(float(cost["bytes accessed"]) / engine.slots, 1)
@@ -1899,14 +1893,9 @@ def http_qos_record(slots: int = SLOTS, replicas: int = 2):
 
 
 if __name__ == "__main__":
-    from fleetx_tpu.utils.device_guard import acquire_devices_or_die
+    from fleetx_tpu.utils.compile_cache import enable_compile_cache
 
-    # BENCH_PLATFORM=cpu for smoke runs (see bench_decode.py on why the
-    # override must happen inside the guard)
-    acquire_devices_or_die(
-        int(os.environ.get("BENCH_INIT_TIMEOUT", 300)), label="bench_serving",
-        platform_override=os.environ.get("BENCH_PLATFORM") or None,
-    )
+    enable_compile_cache()
     if "--http" in sys.argv[1:]:
         print(json.dumps(http_record()))
         print(json.dumps(http_qos_record()))

@@ -1,5 +1,10 @@
 """Chaos smoke driver: run the resilience scenarios end-to-end on CPU.
 
+A CPU tool (``JAX_PLATFORMS=cpu``): the scenarios run engines in this
+process and ``serving_http`` then spawns replica children from it, which
+an accelerator forbids — a chip belongs to one process at a time, and a
+parent that has touched jax holds it.
+
 Exercises the fault-injection story outside pytest — one PASS/FAIL line
 per scenario, non-zero exit on any failure:
 
@@ -927,7 +932,7 @@ def scenario_serving_http(tmp):
     os.makedirs(tmp, exist_ok=True)
     gen_len = 20
     # clean reference: the same demo engine serve.py replicas build
-    eng = _build_demo_engine(0)
+    eng = _build_demo_engine()
     rid = eng.submit([1, 2, 3], max_length=gen_len)
     clean = [int(t) for t in eng.drain()[rid].tokens]
     assert len(clean) == gen_len

@@ -81,8 +81,6 @@ def convert_state_dict(sd, n_layer: int, n_head: int, pad_vocab_to: int = 0):
 
 
 def main():
-    from tools.hf_convert_common import honor_platform_env
-    honor_platform_env()
     ap = argparse.ArgumentParser()
     ap.add_argument("--hf-dir", required=True,
                     help="local transformers GPT-2 checkpoint directory")

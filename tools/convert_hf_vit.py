@@ -26,7 +26,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 
 import numpy as np
 
-from tools.hf_convert_common import honor_platform_env, linear_t, pack_qkv
+from tools.hf_convert_common import linear_t, pack_qkv
 
 from fleetx_tpu.utils.log import logger
 
@@ -87,7 +87,6 @@ def _f32(x):
 
 
 def main():
-    honor_platform_env()
     ap = argparse.ArgumentParser()
     ap.add_argument("--hf-dir", required=True)
     ap.add_argument("--output", required=True)

@@ -31,6 +31,7 @@ from fleetx_tpu.core.engine import Trainer
 from fleetx_tpu.data import build_dataloader
 from fleetx_tpu.models import build_module
 from fleetx_tpu.parallel.env import init_dist_env
+from fleetx_tpu.utils.compile_cache import enable_compile_cache
 from fleetx_tpu.utils.config import get_config, parse_args
 from fleetx_tpu.utils.log import logger
 
@@ -140,6 +141,7 @@ def offline_eval(cfg):
 def main():
     args = parse_args()
     init_dist_env()
+    enable_compile_cache()
     cfg = get_config(args.config, overrides=args.override, show=False)
     if cfg.get("Offline_Eval"):
         offline_eval(cfg)

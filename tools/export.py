@@ -15,6 +15,7 @@ import numpy as np
 from fleetx_tpu.core.engine import Trainer
 from fleetx_tpu.models import build_module
 from fleetx_tpu.parallel.env import init_dist_env
+from fleetx_tpu.utils.compile_cache import enable_compile_cache
 from fleetx_tpu.utils.config import get_config, parse_args
 from fleetx_tpu.utils.export import export_inference_model
 from fleetx_tpu.utils.log import logger
@@ -23,6 +24,7 @@ from fleetx_tpu.utils.log import logger
 def main():
     args = parse_args()
     init_dist_env()
+    enable_compile_cache()
     cfg = get_config(args.config, overrides=args.override, show=False)
     module = build_module(cfg)
     trainer = Trainer(cfg, module, mode="export")

@@ -13,6 +13,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 import numpy as np
 
 from fleetx_tpu.core.inference_engine import InferenceEngine
+from fleetx_tpu.utils.compile_cache import enable_compile_cache
 from fleetx_tpu.utils.log import logger
 
 
@@ -47,6 +48,7 @@ def main():
     if not export_dir:
         ap.error("--export-dir or -c config with Inference.model_dir required")
 
+    enable_compile_cache()
     engine = InferenceEngine(export_dir)
     if args.prompt is None:
         logger.info("no --prompt; running a smoke forward")

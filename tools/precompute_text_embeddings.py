@@ -101,8 +101,6 @@ def encode_hf(captions, model_name: str, max_len: int, batch_size: int = 32):
 
 
 def main():
-    from tools.hf_convert_common import honor_platform_env
-    honor_platform_env()
     ap = argparse.ArgumentParser()
     ap.add_argument("--input", required=True,
                     help="captions: .jsonl with text/caption keys, or plain "

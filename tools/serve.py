@@ -5,11 +5,14 @@ The deployable shape of the serving stack (docs/SERVING.md
 
     python tools/serve.py --demo --replicas 2 --port 8000
 
-spawns one REPLICA subprocess per ``--replicas`` — each builds its own
-engine (pinned to its own jax device by index), serves it over the
+spawns one REPLICA subprocess per ``--replicas`` — each is started with
+an environment in which it sees exactly ONE chip (:func:`_replica_env`;
+a chip belongs to one process at a time, so ``--replicas N`` needs an
+N-chip host), builds its own engine on it, serves it over the
 :mod:`~fleetx_tpu.serving.api.replica_server` RPC on an ephemeral
 port, and hands that port back through a port file — then runs the
-FRONT DOOR in this process: a
+FRONT DOOR in this process, which is pinned to the host platform so it
+can never take a chip (the router derives request RNG keys with jax): a
 :class:`~fleetx_tpu.serving.router.ServingRouter` over
 :class:`~fleetx_tpu.serving.api.replica_client.ReplicaClient` proxies,
 fronted by the OpenAI-compatible
@@ -47,9 +50,37 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def _build_demo_engine(device_index: int, seed: int = 0):
-    """The deterministic tiny-GPT engine (the suite's serving fixture),
-    placed on one jax device by index so replicas don't share a chip."""
+def _replica_env(index: int) -> dict:
+    """The environment of replica child ``index``: the parent's, plus the
+    libtpu chip-visibility variables that make the child's TPU backend
+    see exactly chip ``index`` as a one-chip topology of its own (set
+    here, before the child imports jax; a CPU run ignores them). The two
+    bounds are needed as well as the chip index: with the index alone the
+    second child dies on libtpu's multi-process lockfile (four-chip v5e
+    host, libtpu 0.0.34)."""
+    return {
+        **os.environ,
+        "TPU_VISIBLE_CHIPS": str(index),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
+
+
+def _popen_replica(index: int, port_file: str, grace_s: float):
+    """Start replica child ``index`` (the worker entry of this file) on
+    its own chip."""
+    return subprocess.Popen(
+        [sys.executable, os.path.abspath(__file__),
+         "--replica-worker", "--replica-index", str(index),
+         "--port-file", port_file, "--grace-s", str(grace_s)],
+        env=_replica_env(index),
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def _build_demo_engine(seed: int = 0):
+    """The deterministic tiny-GPT engine (the suite's serving fixture) on
+    this process's default device — a replica child sees only its own
+    chip (:func:`_replica_env`)."""
     import jax
     import jax.numpy as jnp
 
@@ -57,8 +88,6 @@ def _build_demo_engine(device_index: int, seed: int = 0):
     from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
     from fleetx_tpu.serving import ServingEngine
 
-    devices = jax.devices()
-    dev = devices[device_index % len(devices)]
     cfg = GPTConfig(
         vocab_size=61, hidden_size=32, num_layers=1,
         num_attention_heads=2, ffn_hidden_size=64,
@@ -68,29 +97,34 @@ def _build_demo_engine(device_index: int, seed: int = 0):
     gen_cfg = GenerationConfig(decode_strategy="greedy",
                                eos_token_id=10**6, pad_token_id=60,
                                max_length=8)
-    with jax.default_device(dev):
-        model = GPTForPretraining(cfg)
-        params = model.init(jax.random.PRNGKey(seed),
-                            jnp.zeros((2, 8), jnp.int32))
-        return ServingEngine(model, params, slots=4, cache_len=32,
-                             gen_cfg=gen_cfg, prefill_bucket=4,
-                             paged=True, page_size=8)
+    model = GPTForPretraining(cfg)
+    params = model.init(jax.random.PRNGKey(seed),
+                        jnp.zeros((2, 8), jnp.int32))
+    return ServingEngine(model, params, slots=4, cache_len=32,
+                         gen_cfg=gen_cfg, prefill_bucket=4,
+                         paged=True, page_size=8)
 
 
 def run_replica_worker(args) -> int:
     """Subprocess entry: engine + RPC server + port-file handshake,
     drain-and-exit-0 on SIGTERM."""
+    import jax
+
     from fleetx_tpu.serving.api.replica_server import ReplicaServer
+    from fleetx_tpu.utils.compile_cache import enable_compile_cache
     from fleetx_tpu.utils.log import logger
 
-    engine = _build_demo_engine(args.device_index)
+    enable_compile_cache()
+    engine = _build_demo_engine()
     server = ReplicaServer(engine, port=args.rpc_port).start()
     tmp = args.port_file + ".tmp"
     with open(tmp, "w") as f:
         f.write(str(server.port))
     os.replace(tmp, args.port_file)  # atomic: parent never reads partial
-    logger.info("serve: replica %d ready on %s (device %d)",
-                args.device_index, server.url, args.device_index)
+    dev = jax.devices()[0]
+    logger.info("serve: replica %d ready on %s (%s %s, %d device(s) "
+                "visible)", args.replica_index, server.url, dev.platform,
+                dev.device_kind, len(jax.devices()))
 
     stopping = []
 
@@ -118,11 +152,7 @@ def _spawn_replicas(n: int, grace_s: float, tmpdir: str):
     for i in range(n):
         pf = os.path.join(tmpdir, f"replica_{i}.port")
         port_files.append(pf)
-        procs.append(subprocess.Popen(
-            [sys.executable, os.path.abspath(__file__),
-             "--replica-worker", "--device-index", str(i),
-             "--port-file", pf, "--grace-s", str(grace_s)],
-            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
+        procs.append(_popen_replica(i, pf, grace_s))
     deadline = time.monotonic() + 120
     urls = []
     for i, pf in enumerate(port_files):
@@ -153,11 +183,7 @@ def spawn_replica(tmpdir: str, index: int, grace_s: float = 30.0,
     pf = os.path.join(tmpdir, f"replica_{index}.port")
     if os.path.exists(pf):
         os.remove(pf)  # a reused index must not read a stale port
-    proc = subprocess.Popen(
-        [sys.executable, os.path.abspath(__file__),
-         "--replica-worker", "--device-index", str(index),
-         "--port-file", pf, "--grace-s", str(grace_s)],
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    proc = _popen_replica(index, pf, grace_s)
     deadline = time.monotonic() + 120
     while not os.path.exists(pf):
         if proc.poll() is not None:
@@ -189,6 +215,13 @@ def run_fleet(args) -> int:
             else int(os.environ.get("FLEETX_API_PORT", "8000")))
     host = args.host or os.environ.get("FLEETX_API_HOST", "127.0.0.1")
 
+    # the chips are the replica children's: this process only derives
+    # request RNG keys (ServingRouter), which the host platform does. A
+    # jax config pin, not an environment variable — the children inherit
+    # the environment and must still find their chip.
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
     with tempfile.TemporaryDirectory(prefix="fleetx_serve_") as tmpdir:
         procs, urls = _spawn_replicas(replicas, grace_s, tmpdir)
         api = None
@@ -288,7 +321,7 @@ def main(argv=None) -> int:
     # internal subprocess plumbing
     ap.add_argument("--replica-worker", action="store_true",
                     help=argparse.SUPPRESS)
-    ap.add_argument("--device-index", type=int, default=0,
+    ap.add_argument("--replica-index", type=int, default=0,
                     help=argparse.SUPPRESS)
     ap.add_argument("--rpc-port", type=int, default=0,
                     help=argparse.SUPPRESS)

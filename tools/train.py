@@ -9,24 +9,24 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
-# comms/compute overlap flags must be in XLA_FLAGS before anything touches
-# a jax backend (env-gated, TPU-only by default — utils/xla_flags.py)
-from fleetx_tpu.utils.xla_flags import apply_overlap_flags
-
-apply_overlap_flags()
-
 from fleetx_tpu.core.engine import Trainer
 from fleetx_tpu.data import build_dataloader
 from fleetx_tpu.models import build_module
 from fleetx_tpu.parallel.env import init_dist_env
 from fleetx_tpu.resilience.elastic import run_elastic
+from fleetx_tpu.utils.compile_cache import enable_compile_cache
 from fleetx_tpu.utils.config import get_config, parse_args
 from fleetx_tpu.utils.log import advertise, logger
+from fleetx_tpu.utils.xla_flags import apply_overlap_flags
 
 
 def main():
     args = parse_args()
+    # the comms/compute overlap flags must be in the environment before
+    # anything touches a jax backend (utils/xla_flags.py)
+    apply_overlap_flags()
     init_dist_env()
+    enable_compile_cache()
     cfg = get_config(args.config, overrides=args.override, show=True)
     advertise()
 
