@@ -514,32 +514,34 @@ class Trainer:
                 aux, new_extra = {}, None
             raw_grads = _unbox(grads)
             raw_params = _unbox(params)
-            if zero_sh is not None:
-                # ZeRO update sharding: constraining grads to the update-
-                # shard layout turns the dp/fsdp grad all-reduce into a
-                # reduce-scatter; params slice to the same shard (layout
-                # only, no comms), the whole optax chain + apply_updates
-                # then runs on 1/N elements per device, and the jit's
-                # replicated param out_shardings insert the all-gather —
-                # async under the latency-hiding scheduler (xla_flags.py),
-                # so it floats into the next step's forward.
-                raw_grads = jax.lax.with_sharding_constraint(
-                    raw_grads, zero_sh)
-                raw_params = jax.lax.with_sharding_constraint(
-                    raw_params, zero_sh)
-            updates, new_opt = tx.update(
-                raw_grads, state.opt_state, raw_params
-            )
-            new_params_raw = optax.apply_updates(raw_params, updates)
-            if zero_sh is not None:
-                # keep the post-update tree (and the sentry select below)
-                # on the shard; the gather happens once, at the jit edge
-                new_params_raw = jax.lax.with_sharding_constraint(
-                    new_params_raw, zero_sh)
+            with jax.named_scope("optimizer"):
+                if zero_sh is not None:
+                    # ZeRO update sharding: constraining grads to the update-
+                    # shard layout turns the dp/fsdp grad all-reduce into a
+                    # reduce-scatter; params slice to the same shard (layout
+                    # only, no comms), the whole optax chain + apply_updates
+                    # then runs on 1/N elements per device, and the jit's
+                    # replicated param out_shardings insert the all-gather —
+                    # async under the latency-hiding scheduler (xla_flags.py),
+                    # so it floats into the next step's forward.
+                    raw_grads = jax.lax.with_sharding_constraint(
+                        raw_grads, zero_sh)
+                    raw_params = jax.lax.with_sharding_constraint(
+                        raw_params, zero_sh)
+                updates, new_opt = tx.update(
+                    raw_grads, state.opt_state, raw_params
+                )
+                new_params_raw = optax.apply_updates(raw_params, updates)
+                if zero_sh is not None:
+                    # keep the post-update tree (and the sentry select below)
+                    # on the shard; the gather happens once, at the jit edge
+                    new_params_raw = jax.lax.with_sharding_constraint(
+                        new_params_raw, zero_sh)
             new_params = _rebox_like(new_params_raw, params)
             if new_extra is not None:
                 new_extra = module.post_update_extra(new_params_raw, new_extra)
-            gnorm = optax.global_norm(raw_grads)
+            with jax.named_scope("optimizer"):
+                gnorm = optax.global_norm(raw_grads)
             new_state = TrainState(
                 step=state.step + 1, params=new_params, opt_state=new_opt,
                 extra=new_extra,
@@ -552,14 +554,15 @@ class Trainer:
                 # a NaN batch can never poison a later checkpoint. The
                 # jnp.where select is the identity when ok, so an anomaly-
                 # free run is byte-identical with the sentry on or off.
-                ok = jnp.isfinite(loss) & jnp.isfinite(gnorm)
-                if loss_max > 0:
-                    ok &= loss <= loss_max
-                if gnorm_max > 0:
-                    ok &= gnorm <= gnorm_max
-                new_state = jax.tree.map(
-                    lambda n, o: jnp.where(ok, n, o), new_state, state
-                )
+                with jax.named_scope("sentry"):
+                    ok = jnp.isfinite(loss) & jnp.isfinite(gnorm)
+                    if loss_max > 0:
+                        ok &= loss <= loss_max
+                    if gnorm_max > 0:
+                        ok &= gnorm <= gnorm_max
+                    new_state = jax.tree.map(
+                        lambda n, o: jnp.where(ok, n, o), new_state, state
+                    )
                 metrics["sentry_ok"] = ok
             return new_state, metrics
 
@@ -845,22 +848,31 @@ class Trainer:
                 # supervisor's resumed run re-feeds it exactly once
                 # (resilience/elastic.py has the recovery loop)
                 faults.on_train_step(step)
-                batch = self.module.pretreating_batch(batch)
-                if tokens_per_batch is None:
-                    # ips accounting: LM batches carry "tokens", encoder/
-                    # vision batches "input_ids"/first array respectively
-                    arr = batch.get("tokens")
-                    if arr is None:
-                        arr = batch.get("input_ids")
-                    if arr is None:
-                        arr = next(iter(batch.values()))
-                    tokens_per_batch = int(np.prod(np.asarray(arr).shape))
-                device_batch = self._shard_batch(batch)
-                rng = dist_env.data_rank_key(step)
+                with span("train.shard_batch", step=step):
+                    batch = self.module.pretreating_batch(batch)
+                    if tokens_per_batch is None:
+                        # ips accounting: LM batches carry "tokens", encoder/
+                        # vision batches "input_ids"/first array respectively
+                        arr = batch.get("tokens")
+                        if arr is None:
+                            arr = batch.get("input_ids")
+                        if arr is None:
+                            arr = next(iter(batch.values()))
+                        tokens_per_batch = int(np.prod(np.asarray(arr).shape))
+                    device_batch = self._shard_batch(batch)
+                    rng = dist_env.data_rank_key(step)
+                # a DISPATCH span: it ends when the step is enqueued; the
+                # wait for the device is train.loss_fetch below
                 with span("train.step", step=step):
                     self.state, metrics = train_step(self.state, device_batch,
                                                      rng)
-                if self._sentry_enabled and not bool(metrics["sentry_ok"]):
+                skipped = False
+                if self._sentry_enabled:
+                    # the first read of the step's outputs: the host waits
+                    # here for the device to finish the step
+                    with span("train.loss_fetch", step=step):
+                        skipped = not bool(metrics["sentry_ok"])
+                if skipped:
                     # skipped step: the batch was consumed from the stream
                     # (consumed_samples advances -> resume won't re-feed it)
                     # but no update was applied, so neither the step counter
@@ -906,34 +918,37 @@ class Trainer:
 
                 with span("train.callback", step=step):
                     if step % self.logging_freq == 0:
-                        losses = np.mean([float(l) for l in loss_window])
+                        with span("train.loss_fetch", step=step):
+                            losses = np.mean([float(l) for l in loss_window])
                         loss_window = []
-                        dt = (time.time() - t_last) / self.logging_freq
-                        t_last = time.time()
-                        ips_total = tokens_per_batch / dt
-                        lr = float(self.lr_schedule(step))
-                        mfu = self._step_mfu(dt)
-                        hbm = self._step_hbm_bytes()
-                        self._obs_loss.set(float(losses))
-                        self._obs_lr.set(lr)
-                        self._obs_step_time.observe(dt)
-                        self._obs_tokens_per_s.set(ips_total)
-                        if mfu is not None:
-                            self._obs_mfu.set(mfu)
-                        if hbm is not None:
-                            self._obs_hbm_bytes.set(hbm)
-                        self.module.training_step_end(
-                            {
-                                "epoch": epoch,
-                                "batch": step,
-                                "loss": losses,
-                                "batch_cost": dt,
-                                "ips_total": ips_total,
-                                "ips": ips_total / max(jax.process_count(), 1),
-                                "lr": lr,
-                                "mfu": mfu,
-                            }
-                        )
+                        with span("train.log", step=step):
+                            dt = (time.time() - t_last) / self.logging_freq
+                            t_last = time.time()
+                            ips_total = tokens_per_batch / dt
+                            lr = float(self.lr_schedule(step))
+                            mfu = self._step_mfu(dt)
+                            hbm = self._step_hbm_bytes()
+                            self._obs_loss.set(float(losses))
+                            self._obs_lr.set(lr)
+                            self._obs_step_time.observe(dt)
+                            self._obs_tokens_per_s.set(ips_total)
+                            if mfu is not None:
+                                self._obs_mfu.set(mfu)
+                            if hbm is not None:
+                                self._obs_hbm_bytes.set(hbm)
+                            self.module.training_step_end(
+                                {
+                                    "epoch": epoch,
+                                    "batch": step,
+                                    "loss": losses,
+                                    "batch_cost": dt,
+                                    "ips_total": ips_total,
+                                    "ips": ips_total / max(
+                                        jax.process_count(), 1),
+                                    "lr": lr,
+                                    "mfu": mfu,
+                                }
+                            )
                     if (self.eval_freq and valid_data is not None
                             and step % self.eval_freq == 0):
                         self.evaluate(valid_data, epoch=epoch)

@@ -422,13 +422,14 @@ class SelfAttention(nn.Module):
             k_pos = jnp.arange(max_len)
             if cache_positions is None:
                 start = idx.value
-                ck.value = jax.lax.dynamic_update_slice(ck.value, k_w, (0, start, 0))
-                cv.value = jax.lax.dynamic_update_slice(cv.value, v_w, (0, start, 0))
-                if quant:
-                    cks.value = jax.lax.dynamic_update_slice(
-                        cks.value, k_s, (0, start, 0))
-                    cvs.value = jax.lax.dynamic_update_slice(
-                        cvs.value, v_s, (0, start, 0))
+                with jax.named_scope("cache_write"):
+                    ck.value = jax.lax.dynamic_update_slice(ck.value, k_w, (0, start, 0))
+                    cv.value = jax.lax.dynamic_update_slice(cv.value, v_w, (0, start, 0))
+                    if quant:
+                        cks.value = jax.lax.dynamic_update_slice(
+                            cks.value, k_s, (0, start, 0))
+                        cvs.value = jax.lax.dynamic_update_slice(
+                            cvs.value, v_s, (0, start, 0))
                 idx.value = start + s
                 if s == 1:
                     decode_end = idx.value
@@ -440,11 +441,12 @@ class SelfAttention(nn.Module):
                     lambda buf, new, p: jax.lax.dynamic_update_slice(
                         buf, new, (p, 0))
                 )
-                ck.value = row_update(ck.value, k_w, wpos)
-                cv.value = row_update(cv.value, v_w, wpos)
-                if quant:
-                    cks.value = row_update(cks.value, k_s, wpos)
-                    cvs.value = row_update(cvs.value, v_s, wpos)
+                with jax.named_scope("cache_write"):
+                    ck.value = row_update(ck.value, k_w, wpos)
+                    cv.value = row_update(cv.value, v_w, wpos)
+                    if quant:
+                        cks.value = row_update(cks.value, k_s, wpos)
+                        cvs.value = row_update(cvs.value, v_s, wpos)
                 idx.value = jnp.max(wpos) + s
                 if s == 1:
                     decode_end = wpos + 1  # [b]: per-row live window end
@@ -533,20 +535,22 @@ class SelfAttention(nn.Module):
                 k_w, v_w = k, v
             wpos = cache_positions.astype(jnp.int32)       # [b] write offsets
             tables = block_tables.astype(jnp.int32)        # [b, n_pages_row]
-            pos = wpos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-            pos = jnp.minimum(pos, max_len - 1)            # [b, s] logical
-            page = jnp.take_along_axis(tables, pos // ps, axis=1)
-            ck.value = ck.value.at[page.reshape(-1), (pos % ps).reshape(-1)
-                                   ].set(k_w.reshape(b * s, nh * hd))
-            cv.value = cv.value.at[page.reshape(-1), (pos % ps).reshape(-1)
-                                   ].set(v_w.reshape(b * s, nh * hd))
+            with jax.named_scope("cache_write"):
+                pos = wpos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+                pos = jnp.minimum(pos, max_len - 1)        # [b, s] logical
+                page = jnp.take_along_axis(tables, pos // ps, axis=1)
+                ck.value = ck.value.at[page.reshape(-1), (pos % ps).reshape(-1)
+                                       ].set(k_w.reshape(b * s, nh * hd))
+                cv.value = cv.value.at[page.reshape(-1), (pos % ps).reshape(-1)
+                                       ].set(v_w.reshape(b * s, nh * hd))
+                if quant:
+                    cks.value = cks.value.at[
+                        page.reshape(-1), (pos % ps).reshape(-1)
+                    ].set(k_s.reshape(b * s, nh))
+                    cvs.value = cvs.value.at[
+                        page.reshape(-1), (pos % ps).reshape(-1)
+                    ].set(v_s.reshape(b * s, nh))
             if quant:
-                cks.value = cks.value.at[
-                    page.reshape(-1), (pos % ps).reshape(-1)
-                ].set(k_s.reshape(b * s, nh))
-                cvs.value = cvs.value.at[
-                    page.reshape(-1), (pos % ps).reshape(-1)
-                ].set(v_s.reshape(b * s, nh))
                 kv_scales = (cks.value, cvs.value)
             idx.value = jnp.max(wpos) + s
             if s == 1:
@@ -790,12 +794,13 @@ class GPTModel(nn.Module):
             (cfg.max_position_embeddings, cfg.hidden_size),
             jnp.float32,
         )
-        if position_ids is None:
-            # decode callers must pass explicit position_ids per step
-            position_ids = jnp.arange(input_ids.shape[1])[None, :]
-            position_ids = jnp.broadcast_to(position_ids, input_ids.shape)
-        x = word_emb[input_ids] + pos_emb[position_ids]
-        x = x.astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            if position_ids is None:
+                # decode callers must pass explicit position_ids per step
+                position_ids = jnp.arange(input_ids.shape[1])[None, :]
+                position_ids = jnp.broadcast_to(position_ids, input_ids.shape)
+            x = word_emb[input_ids] + pos_emb[position_ids]
+            x = x.astype(cfg.dtype)
         x = _constrain_act(x, cfg)
         x = _dropout(cfg, "embed_dropout")(x, deterministic=deterministic)
 
@@ -896,15 +901,17 @@ class GPTForPretraining(nn.Module):
             from fleetx_tpu.ops.pallas.ce_loss import fused_linear_ce
 
             b, s, hd = x.shape
-            tok = fused_linear_ce(
-                x.reshape(b * s, hd), emb.astype(self.cfg.dtype),
-                labels.reshape(-1),
-            )
+            with jax.named_scope("loss"):
+                tok = fused_linear_ce(
+                    x.reshape(b * s, hd), emb.astype(self.cfg.dtype),
+                    labels.reshape(-1),
+                )
             return tok.reshape(b, s)
-        logits = jnp.einsum(
-            "bsh,vh->bsv", x, emb.astype(self.cfg.dtype),
-            preferred_element_type=jnp.float32,
-        )
+        with jax.named_scope("logits"):
+            logits = jnp.einsum(
+                "bsh,vh->bsv", x, emb.astype(self.cfg.dtype),
+                preferred_element_type=jnp.float32,
+            )
         return logits
 
 
@@ -980,8 +987,10 @@ def convert_qkv_layout(gpt_params: dict, to_fused: bool) -> dict:
 def masked_loss_mean(token_loss: jax.Array, loss_mask: jax.Array):
     """Loss-mask-weighted mean of per-token losses (the reference
     criterion's reduction, single_model.py:727-736)."""
-    loss_mask = loss_mask.astype(jnp.float32).reshape(token_loss.shape)
-    return (token_loss * loss_mask).sum() / jnp.maximum(loss_mask.sum(), 1.0)
+    with jax.named_scope("loss"):
+        loss_mask = loss_mask.astype(jnp.float32).reshape(token_loss.shape)
+        return ((token_loss * loss_mask).sum()
+                / jnp.maximum(loss_mask.sum(), 1.0))
 
 
 def pretraining_loss(logits: jax.Array, labels: jax.Array, loss_mask: jax.Array):
@@ -989,7 +998,9 @@ def pretraining_loss(logits: jax.Array, labels: jax.Array, loss_mask: jax.Array)
     single_model.py:702-736; the TP ParallelCrossEntropy variant
     hybrid_model.py:857-904 is unnecessary — logits arrive vocab-sharded and
     XLA handles the sharded log-softmax reduction)."""
-    logits = logits.astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    label_logits = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    return masked_loss_mean(logz - label_logits, loss_mask)
+    with jax.named_scope("loss"):
+        logits = logits.astype(jnp.float32)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        label_logits = jnp.take_along_axis(
+            logits, labels[..., None], axis=-1)[..., 0]
+        return masked_loss_mean(logz - label_logits, loss_mask)

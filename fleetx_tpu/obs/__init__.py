@@ -6,9 +6,12 @@ The substrate every subsystem reports through (docs/OBSERVABILITY.md):
 - :mod:`fleetx_tpu.obs.registry` — process-wide Counter/Gauge/Histogram
   families with labels and bounded percentile reservoirs; Prometheus
   text + JSON snapshot expositions.
-- :mod:`fleetx_tpu.obs.tracing` — nested host spans in a ring buffer,
-  Chrome-trace export, and a ``jax.profiler.TraceAnnotation`` bridge so
-  host phases line up with XLA kernels inside profiler traces.
+- :mod:`fleetx_tpu.obs.tracing` — nested host spans in a ring buffer
+  (each with its ``parent``), Chrome-trace export, and a
+  ``jax.profiler.TraceAnnotation`` bridge (always on) that puts the host
+  phases on the device trace's clock: leaf spans name every host phase
+  and every blocking fetch of ``ServingEngine.step()`` and
+  ``Trainer.fit()``, dispatch spans apart from wait spans.
 - :mod:`fleetx_tpu.obs.events` — bounded log of typed operational
   events (sentry skips, quarantines, recoveries, shutdowns), asserted
   on by the chaos suite.

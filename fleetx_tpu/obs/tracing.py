@@ -1,26 +1,31 @@
 """Host-side span tracing with an XLA-profiler bridge.
 
 ``with span("serving.tick"):`` records one nested host span into a
-bounded ring buffer (``FLEETX_OBS_SPANS`` spans, oldest dropped) AND —
-the bridge — enters a ``jax.profiler.TraceAnnotation`` of the same name,
-so when a profiling window is open (``jax.profiler.start_trace`` /
-``Profiler.enable`` in the Trainer) the host phases show up in the
-``.trace.json.gz`` timeline aligned with the XLA kernels they launched:
-admission next to its prefill fusion, the decode tick over its kernel,
-the train data/step/callback phases over the step program. Outside a
-profiling window TraceAnnotation is a near-free TraceMe no-op, so spans
-stay on permanently.
+bounded ring buffer (``FLEETX_OBS_SPANS`` spans, default 65,536, oldest
+dropped) AND — the bridge — enters a ``jax.profiler.TraceAnnotation`` of
+the same name, so when a profiling window is open
+(``jax.profiler.start_trace`` / ``Profiler.enable`` in the Trainer) the
+host phases show up in the trace on the device's clock (the two agree to
+about 0.5 ms on a v5e): the benchmark books every idle gap of the device
+to the innermost span open at that time (``perfbench/trace_reduce.py``),
+so the bridge has no off switch. Outside a profiling window
+TraceAnnotation is a near-free TraceMe no-op, so spans stay on
+permanently (about 5 us each).
 
 The ring buffer is exported as Chrome-trace JSON
 (:meth:`SpanRecorder.chrome_trace`, ``chrome://tracing`` / Perfetto
 loadable) by ``tools/obs_dump.py`` or ``GET /trace`` on the exposition
-server — the always-on, no-profiler view of where host time went.
+server — the always-on, no-profiler view of where host time went; its
+``dropped`` field says how many spans the ring has already pushed out.
 
-Span taxonomy (docs/OBSERVABILITY.md): dotted snake_case names,
-``<subsystem>.<phase>`` — ``serving.tick``, ``serving.admit``,
-``serving.prefill``, ``serving.decode``, ``serving.rollback``,
-``serving.recover``, ``train.data``, ``train.step``, ``train.callback``.
-Nesting is tracked per thread; attrs ride into the Chrome trace as
+Span taxonomy (docs/OBSERVABILITY.md has the table): dotted snake_case
+names, ``<subsystem>.<phase>``. A LEAF span names one activity of the
+host: ``serving.decode`` and ``serving.prefill`` end when the device call
+is DISPATCHED, ``serving.fetch`` and ``serving.first_token`` are the
+blocking waits for its result, ``train.loss_fetch`` the trainer's.
+Nesting is tracked per thread; every span records its ``parent`` (the
+name of the span open on its thread when it began), spans of one request
+share the ``request`` attr, and attrs ride into the Chrome trace as
 ``args``.
 """
 
@@ -33,6 +38,8 @@ import os
 import threading
 import time
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from fleetx_tpu.obs._util import env_int, json_safe as _json_safe
 
@@ -49,6 +56,7 @@ class Span:
     thread_id: int
     depth: int
     attrs: Dict
+    parent: Optional[str] = None  # span open on this thread when it began
 
     @property
     def duration_s(self) -> float:
@@ -63,7 +71,7 @@ class SpanRecorder:
     in :func:`span` still runs — profiler alignment costs nothing)."""
 
     def __init__(self, capacity: Optional[int] = None):
-        cap = (env_int("FLEETX_OBS_SPANS", 4096, minimum=0)
+        cap = (env_int("FLEETX_OBS_SPANS", 65536, minimum=0)
                if capacity is None else capacity)
         self.capacity = max(cap, 0)
         self._lock = threading.Lock()
@@ -104,7 +112,9 @@ class SpanRecorder:
     def chrome_trace(self) -> Dict:
         """Chrome-trace JSON dict (``traceEvents`` of complete ``X``
         events, microsecond timestamps) — load in chrome://tracing or
-        Perfetto; ``tools/obs_dump.py`` writes it to disk."""
+        Perfetto; ``tools/obs_dump.py`` writes it to disk. ``dropped``
+        counts the spans the ring pushed out before this export: above
+        0 the trace is truncated at its old end."""
         pid = os.getpid()
         events = [{
             "ph": "M", "pid": pid, "name": "process_name",
@@ -118,13 +128,14 @@ class SpanRecorder:
                 "name": s.name,
                 "ts": s.start_s * 1e6,
                 "dur": max(s.duration_s, 0.0) * 1e6,
-                "args": {k: _json_safe(v) for k, v in s.attrs.items()},
+                "args": {"parent": s.parent,
+                         **{k: _json_safe(v) for k, v in s.attrs.items()}},
             })
-        return {"traceEvents": events, "displayTimeUnit": "ms"}
+        return {"traceEvents": events, "displayTimeUnit": "ms",
+                "dropped": self.dropped}
 
 
 _RECORDER = SpanRecorder()
-_XPROF = os.environ.get("FLEETX_OBS_XPROF", "1") == "1"
 
 
 def get_recorder() -> SpanRecorder:
@@ -132,39 +143,26 @@ def get_recorder() -> SpanRecorder:
     return _RECORDER
 
 
-def _trace_annotation(name: str):
-    """The profiler bridge: a ``jax.profiler.TraceAnnotation`` context
-    (None when jax is unavailable or ``FLEETX_OBS_XPROF=0``)."""
-    if not _XPROF:
-        return None
-    try:
-        import jax
-
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 — tracing must never break the host
-        return None
-
-
 @contextlib.contextmanager
 def span(name: str, recorder: Optional[SpanRecorder] = None, **attrs):
     """Record one nested host span named ``name`` (module docstring);
-    ``attrs`` become Chrome-trace args. Re-entrant and thread-safe;
-    exceptions propagate (the span still closes and records)."""
+    ``attrs`` become Chrome-trace args, and the ``with`` target is that
+    dict, for an attr known only inside the span (``as at: at["shared"] =
+    n``). Re-entrant and thread-safe; exceptions propagate (the span
+    still closes and records)."""
     rec = recorder or _RECORDER
     stack = rec._stack()
-    ann = _trace_annotation(name)
-    if ann is not None:
-        ann.__enter__()
-    start = time.perf_counter()
-    stack.append(name)
-    try:
-        yield
-    finally:
-        stack.pop()
-        end = time.perf_counter()
-        if ann is not None:
-            ann.__exit__(None, None, None)
-        rec.record(Span(
-            name=name, start_s=start, end_s=end,
-            thread_id=threading.get_ident(), depth=len(stack), attrs=attrs,
-        ))
+    parent = stack[-1] if stack else None
+    with TraceAnnotation(name):
+        start = time.perf_counter()
+        stack.append(name)
+        try:
+            yield attrs
+        finally:
+            stack.pop()
+            end = time.perf_counter()
+            rec.record(Span(
+                name=name, start_s=start, end_s=end,
+                thread_id=threading.get_ident(), depth=len(stack),
+                attrs=attrs, parent=parent,
+            ))

@@ -939,11 +939,14 @@ class ServingEngine:
             # device work is real and their first token was emitted), and a
             # prefill fault rolls back only the admission in flight. No
             # phase ever commits partially.
-            snap = self._snapshot()
+            with span("serving.snapshot"):
+                snap = self._snapshot()
 
             def commit():
+                with span("serving.snapshot"):
+                    fresh = self._snapshot()
                 snap.clear()
-                snap.update(self._snapshot())
+                snap.update(fresh)
 
             try:
                 with span("serving.tick", tick=self._ticks):
@@ -960,21 +963,22 @@ class ServingEngine:
             except Exception as exc:  # noqa: BLE001 — THE crash-safety seam
                 summary = self._handle_tick_fault(snap, exc)
         self._ticks += 1
-        self.metrics.observe_tick(self.scheduler.queue_depth,
-                                  len(self._active), self._now() - t0)
-        if self.paged:
-            self.metrics.observe_pages(self.cache_manager.pages_in_use,
-                                       self.cache_manager.usable_pages)
-        if self._dram_store is not None:
-            self.metrics.observe_host_tier(self._dram_store)
-        if self._disk_store is not None:
-            self.metrics.observe_disk_tier(self._disk_store)
-        self.metrics.observe_queue_tokens(
-            self.scheduler.queued_tokens() + sum(
-                r.prompt_len - r.prefill_pos
-                for r in self._prefilling.values()))
-        if self.log_every and self._ticks % self.log_every == 0:
-            self.metrics.log_snapshot()
+        with span("serving.observe"):
+            self.metrics.observe_tick(self.scheduler.queue_depth,
+                                      len(self._active), self._now() - t0)
+            if self.paged:
+                self.metrics.observe_pages(self.cache_manager.pages_in_use,
+                                           self.cache_manager.usable_pages)
+            if self._dram_store is not None:
+                self.metrics.observe_host_tier(self._dram_store)
+            if self._disk_store is not None:
+                self.metrics.observe_disk_tier(self._disk_store)
+            self.metrics.observe_queue_tokens(
+                self.scheduler.queued_tokens() + sum(
+                    r.prompt_len - r.prefill_pos
+                    for r in self._prefilling.values()))
+            if self.log_every and self._ticks % self.log_every == 0:
+                self.metrics.log_snapshot()
         summary.setdefault("recovered", False)
         summary.setdefault("chunked", 0)
         summary["queue_depth"] = self.scheduler.queue_depth
@@ -992,7 +996,8 @@ class ServingEngine:
         tick's prefill budget is ONE chunk-sized device call — a chunk
         of the in-flight prompt or one short admission — so decode never
         stalls longer (the ``prefill_stall_ms`` histogram measures it)."""
-        timed_out = self._expire_queued(self._now())
+        with span("serving.expire"):
+            timed_out = self._expire_queued(self._now())
         admitted = 0
         chunked = 0
         prefill_t0 = self._now()
@@ -1019,7 +1024,8 @@ class ServingEngine:
             retired = (self._tick_decode_spec() if self._proposer is not None
                        else self._tick_decode())
         # fresh clock: prefill/decode above may have eaten the deadline
-        timed_out += self._expire_active(self._now())
+        with span("serving.expire"):
+            timed_out += self._expire_active(self._now())
         return {"admitted": admitted, "decoded": decoded, "chunked": chunked,
                 "retired": retired + timed_out, "timed_out": timed_out}
 
@@ -1352,7 +1358,7 @@ class ServingEngine:
             req.slot = lane
             self._paged_prefill_call(req, history[shared:], shared, lane,
                                      replay=True)
-            self.cache_manager.register_prefix(lane, req.prompt)
+            self._register_prefix(req)
         else:
             slot = self.cache_manager.alloc(req.id, len(history))
             if slot is None:
@@ -1960,7 +1966,11 @@ class ServingEngine:
         arrival order deterministically; it unblocks as retiring requests
         return pages."""
         if self.paged:
-            return self.cache_manager.can_admit(self._admission_tokens(req))
+            # a dry run of the prefix match, once a tick while the head
+            # of the queue waits: host work between two admissions
+            with span("serving.can_admit", request=req.id):
+                return self.cache_manager.can_admit(
+                    self._admission_tokens(req))
         return self.cache_manager.free_count > 0
 
     def _device_tables(self):
@@ -1970,8 +1980,9 @@ class ServingEngine:
             return None
         version = self.cache_manager.tables_version
         if version != self._tables_version:
-            self._tables_dev = self._replicate(
-                jnp.asarray(self.cache_manager.tables))
+            with span("serving.tables"):
+                self._tables_dev = self._replicate(
+                    jnp.asarray(self.cache_manager.tables))
             self._tables_version = version
         return self._tables_dev
 
@@ -1987,6 +1998,22 @@ class ServingEngine:
             return self._decode_jit.lower(
                 self.params, self.cache_manager.cache, self._state,
                 self._device_tables(), all_greedy).compile()
+
+    def _first_token(self, logits, true_len, eos, min_new, greedy,
+                     temperature, top_k, top_p, key):
+        """Traced tail of every prefill body: sample the first token from
+        the logits of the last true prompt position (EOS suppressed while
+        ``min_new`` > 0)."""
+        with jax.named_scope("sampler"):
+            last = jax.lax.dynamic_slice_in_dim(
+                logits[0], true_len - 1, 1, axis=0).astype(jnp.float32)
+            vocab = last.shape[-1]
+            last = jnp.where(
+                (jnp.arange(vocab)[None, :] == eos) & (min_new > 0),
+                _NEG, last)
+            return self.executor.sample(
+                last, key[None], greedy[None], temperature[None],
+                top_k[None], top_p[None], topk_cap=self.topk_cap)[0]
 
     def _make_prefill(self, bucket_len: int):
         """Jitted prefill-on-insert for prompts bucketed to ``bucket_len``:
@@ -2006,16 +2033,9 @@ class ServingEngine:
             logits, small = self.executor.forward(
                 params, self.executor.init_cache(1), ids, pos)
             cache = self._pin_cache(scatter_slot(cache, small, slot))
-            last = jax.lax.dynamic_slice_in_dim(
-                logits[0], true_len - 1, 1, axis=0).astype(jnp.float32)
-            vocab = last.shape[-1]
-            last = jnp.where(
-                (jnp.arange(vocab)[None, :] == eos) & (min_new > 0),
-                _NEG, last)
-            tok = self.executor.sample(
-                last, key[None], greedy[None], temperature[None],
-                top_k[None], top_p[None], topk_cap=self.topk_cap)[0]
-            return cache, tok
+            return cache, self._first_token(
+                logits, true_len, eos, min_new, greedy, temperature, top_k,
+                top_p, key)
 
         return jax.jit(
             prefill, donate_argnums=(1,) if self._donate_cache else ())
@@ -2042,16 +2062,9 @@ class ServingEngine:
                 params, cache, ids, pos,
                 cache_positions=wpos[None], block_tables=table[None])
             cache = self._pin_cache(cache)
-            last = jax.lax.dynamic_slice_in_dim(
-                logits[0], true_len - 1, 1, axis=0).astype(jnp.float32)
-            vocab = last.shape[-1]
-            last = jnp.where(
-                (jnp.arange(vocab)[None, :] == eos) & (min_new > 0),
-                _NEG, last)
-            tok = self.executor.sample(
-                last, key[None], greedy[None], temperature[None],
-                top_k[None], top_p[None], topk_cap=self.topk_cap)[0]
-            return cache, tok
+            return cache, self._first_token(
+                logits, true_len, eos, min_new, greedy, temperature, top_k,
+                top_p, key)
 
         return jax.jit(
             prefill, donate_argnums=(1,) if self._donate_cache else ())
@@ -2072,6 +2085,24 @@ class ServingEngine:
                 jnp.asarray(req.top_k, jnp.int32),
                 jnp.asarray(req.top_p, jnp.float32),
                 step_key)
+
+    def _prefill_args(self, req: Request, tokens, bucket: int, replay: bool,
+                      *offsets):
+        """Everything a prefill call uploads before it can be dispatched,
+        under one ``serving.prefill_args`` span: the prompt padded to its
+        bucket, its true length, the call's own ``offsets`` (slot, write
+        position, block-table row) and the sampler scalars. Returns
+        ``(operands after params and cache, carry_key)``; replay calls
+        consume no randomness (``carry_key`` None)."""
+        with span("serving.prefill_args", request=req.id, bucket=bucket):
+            padded = np.zeros(bucket, np.int32)
+            padded[:len(tokens)] = tokens
+            step_key = carry_key = None
+            if not replay:
+                step_key, carry_key = jax.random.split(req.rng_key)
+            return (jnp.asarray(padded), jnp.asarray(len(tokens), jnp.int32),
+                    *(jnp.asarray(v, jnp.int32) for v in offsets),
+                    *self._prefill_scalars(req, replay, step_key)), carry_key
 
     def _guarded_prefill(self, req: Request, fn, args, bucket=None,
                          chunk_cache: bool = False):
@@ -2107,15 +2138,9 @@ class ServingEngine:
         if fn is None:
             fn = self._prefill_jits[("slot", bucket)] = \
                 self._make_prefill(bucket)
-        padded = np.zeros(bucket, np.int32)
-        padded[:len(tokens)] = tokens
-        step_key = carry_key = None
-        if not replay:
-            step_key, carry_key = jax.random.split(req.rng_key)
-        args = (self.params, self.cache_manager.cache, jnp.asarray(padded),
-                jnp.asarray(len(tokens), jnp.int32),
-                jnp.asarray(slot, jnp.int32),
-                *self._prefill_scalars(req, replay, step_key))
+        operands, carry_key = self._prefill_args(req, tokens, bucket, replay,
+                                                 slot)
+        args = (self.params, self.cache_manager.cache, *operands)
         tok = self._guarded_prefill(req, fn, args, bucket=bucket)
         return None if replay else (tok, carry_key)
 
@@ -2135,16 +2160,10 @@ class ServingEngine:
         if fn is None:
             fn = self._prefill_jits[("paged", bucket)] = \
                 self._make_paged_prefill(bucket)
-        padded = np.zeros(bucket, np.int32)
-        padded[:len(suffix)] = suffix
-        step_key = carry_key = None
-        if not replay:
-            step_key, carry_key = jax.random.split(req.rng_key)
-        args = (self.params, self.cache_manager.cache, jnp.asarray(padded),
-                jnp.asarray(len(suffix), jnp.int32),
-                jnp.asarray(shared, jnp.int32),
-                jnp.asarray(self.cache_manager.tables[lane]),
-                *self._prefill_scalars(req, replay, step_key))
+        operands, carry_key = self._prefill_args(
+            req, suffix, bucket, replay, shared,
+            self.cache_manager.tables[lane])
+        args = (self.params, self.cache_manager.cache, *operands)
         tok = self._guarded_prefill(req, fn, args, bucket=bucket)
         return None if replay else (tok, carry_key)
 
@@ -2174,16 +2193,9 @@ class ServingEngine:
                 params, cache, ids, pos,
                 cache_positions=wpos[None])
             cache = self._pin_cache(cache)
-            last = jax.lax.dynamic_slice_in_dim(
-                logits[0], true_len - 1, 1, axis=0).astype(jnp.float32)
-            vocab = last.shape[-1]
-            last = jnp.where(
-                (jnp.arange(vocab)[None, :] == eos) & (min_new > 0),
-                _NEG, last)
-            tok = self.executor.sample(
-                last, key[None], greedy[None], temperature[None],
-                top_k[None], top_p[None], topk_cap=self.topk_cap)[0]
-            return cache, tok
+            return cache, self._first_token(
+                logits, true_len, eos, min_new, greedy, temperature, top_k,
+                top_p, key)
 
         return jax.jit(
             prefill, donate_argnums=(1,) if self._donate_cache else ())
@@ -2203,15 +2215,9 @@ class ServingEngine:
         if fn is None:
             fn = self._prefill_jits[("chunk", bucket)] = \
                 self._make_chunk_prefill(bucket)
-        padded = np.zeros(bucket, np.int32)
-        padded[:len(tokens)] = tokens
-        step_key = carry_key = None
-        if not replay:
-            step_key, carry_key = jax.random.split(req.rng_key)
-        args = (self.params, req.chunk_cache, jnp.asarray(padded),
-                jnp.asarray(len(tokens), jnp.int32),
-                jnp.asarray(wpos, jnp.int32),
-                *self._prefill_scalars(req, replay, step_key))
+        operands, carry_key = self._prefill_args(req, tokens, bucket, replay,
+                                                 wpos)
+        args = (self.params, req.chunk_cache, *operands)
         tok = self._guarded_prefill(req, fn, args, bucket=bucket,
                                     chunk_cache=True)
         return None if replay else (tok, carry_key)
@@ -2220,7 +2226,10 @@ class ServingEngine:
         """Claim a decode lane (+ page chain on the paged path) for one
         admission; sets ``req.slot`` and returns the shared-prefix token
         count (trie + host-revived; 0 on the slot path)."""
-        if self.paged:
+        with span("serving.claim", request=req.id, shared=0) as at:
+            if not self.paged:
+                req.slot = self.cache_manager.alloc(req.id, req.prompt_len)
+                return 0
             alloc = self.cache_manager.alloc(req.id, req.prompt)
             if alloc is None:  # _can_admit() passed, so this is an
                 raise RuntimeError(  # invariant breach — fail loudly
@@ -2233,29 +2242,44 @@ class ServingEngine:
             self.metrics.record_prefix(
                 shared, req.prompt_len,
                 int(pool.alloc_counts[lane] - pool.shared_counts[lane]))
+            at["shared"] = int(shared)
             return shared
-        req.slot = self.cache_manager.alloc(req.id, req.prompt_len)
-        return 0
 
     def _install_lane(self, req: Request, *, tok: int, length: int,
                       decoded: int, active: bool, carry_key) -> None:
         """Install one request's decode-lane scalars into the device
         state (shared by fresh admission and replay recovery)."""
-        self._state = self._admit_jit(
-            self._state, jnp.asarray(req.slot, jnp.int32),
-            jnp.asarray(tok, jnp.int32),
-            jnp.asarray(length, jnp.int32),
-            jnp.asarray(decoded, jnp.int32),
-            jnp.asarray(active),
-            jnp.asarray(req.eos_token_id, jnp.int32),
-            jnp.asarray(req.max_new_tokens, jnp.int32),
-            jnp.asarray(req.min_new_tokens, jnp.int32),
-            jnp.asarray(req.greedy),
-            jnp.asarray(req.temperature, jnp.float32),
-            jnp.asarray(req.top_k, jnp.int32),
-            jnp.asarray(req.top_p, jnp.float32),
-            carry_key,
-        )
+        with span("serving.install", request=req.id):
+            self._state = self._admit_jit(
+                self._state, jnp.asarray(req.slot, jnp.int32),
+                jnp.asarray(tok, jnp.int32),
+                jnp.asarray(length, jnp.int32),
+                jnp.asarray(decoded, jnp.int32),
+                jnp.asarray(active),
+                jnp.asarray(req.eos_token_id, jnp.int32),
+                jnp.asarray(req.max_new_tokens, jnp.int32),
+                jnp.asarray(req.min_new_tokens, jnp.int32),
+                jnp.asarray(req.greedy),
+                jnp.asarray(req.temperature, jnp.float32),
+                jnp.asarray(req.top_k, jnp.int32),
+                jnp.asarray(req.top_p, jnp.float32),
+                carry_key,
+            )
+
+    def _register_prefix(self, req: Request) -> None:
+        """Enter the request's prompt pages into the prefix trie (host
+        work that runs while its prefill is still on the device)."""
+        with span("serving.install", request=req.id):
+            self.cache_manager.register_prefix(req.slot, req.prompt)
+
+    def _fetch(self, name: str, *arrays, **attrs):
+        """THE blocking device-to-host read: every result the host waits
+        for (the tick's tokens and done flags, a prefill's first token)
+        comes through here, under a leaf span ``name``, so the wait is
+        never booked to the dispatch span before it. Returns one numpy
+        array per device array."""
+        with span(name, **attrs):
+            return [np.asarray(a) for a in arrays]
 
     def _admit(self, req: Request) -> None:
         """Admit the FIFO head: claim storage, then either the one-call
@@ -2305,16 +2329,16 @@ class ServingEngine:
             if self.paged:
                 tok, carry_key = self._paged_prefill_call(
                     req, req.prompt[shared:], shared, req.slot)
-                self.cache_manager.register_prefix(req.slot, req.prompt)
+                self._register_prefix(req)
             else:
                 tok, carry_key = self._slot_prefill_call(
                     req, req.prompt, req.slot)
-        self._fault_ctx = None
-        self._prefill_strikes.pop(req.id, None)  # survived its prefill
-        now = self._now()
-        req.admit_time = now
-        self.metrics.record_admit(now - req.submit_time)
-        self._finish_first_token(req, int(tok), carry_key)
+            self._fault_ctx = None
+            self._prefill_strikes.pop(req.id, None)  # survived its prefill
+            now = self._now()
+            req.admit_time = now
+            self.metrics.record_admit(now - req.submit_time)
+            self._finish_first_token(req, tok, carry_key)
 
     def _admit_shipped(self, req: Request) -> None:
         """Admit a request whose prompt KV arrived from a PREFILL-role
@@ -2349,7 +2373,7 @@ class ServingEngine:
                        for i in range(start, len(payloads))]
             if entries:
                 self.cache_manager.revive_pages(entries)
-            self.cache_manager.register_prefix(lane, req.prompt)
+            self._register_prefix(req)
         self._fault_ctx = None
         self._prefill_strikes.pop(req.id, None)
         pool = self.cache_manager.pool
@@ -2401,7 +2425,7 @@ class ServingEngine:
             return
         tok, carry_key = out
         if self.paged:
-            self.cache_manager.register_prefix(req.slot, req.prompt)
+            self._register_prefix(req)
         else:
             # fold the finished batch-1 working cache into the slot row
             self.cache_manager.cache = self._scatter_jit(
@@ -2410,7 +2434,7 @@ class ServingEngine:
             req.chunk_cache = None
         del self._prefilling[req.slot]
         self._prefill_strikes.pop(req.id, None)
-        self._finish_first_token(req, int(tok), carry_key)
+        self._finish_first_token(req, tok, carry_key)
 
     def _chunk_tick(self):
         """Advance the mid-prefill request by ONE chunk this tick —
@@ -2433,11 +2457,12 @@ class ServingEngine:
         self._run_chunk(req)
         return 1, []
 
-    def _finish_first_token(self, req: Request, tok: int,
-                            carry_key) -> None:
-        """Shared admission tail: the first token is on the host —
+    def _finish_first_token(self, req: Request, tok, carry_key) -> None:
+        """Shared admission tail: wait for the prefill's first token
+        (``serving.first_token``: the host-visible prefill wait), then
         install the decode lane, record TTFT, fire the callback, route
         to the active set or straight to retirement."""
+        tok = int(self._fetch("serving.first_token", tok, request=req.id)[0])
         now = self._now()
         req.first_token_time = now
         req.tokens.append(tok)
@@ -2481,50 +2506,53 @@ class ServingEngine:
         active = st["active"]
         lengths = st["lengths"]
         max_pos = self.model.cfg.max_position_embeddings
-        wpos = jnp.where(active, lengths, self.cache_len - 1)
-        posid = jnp.where(active, jnp.minimum(lengths, max_pos - 1), 0)
+        with jax.named_scope("lanes"):
+            wpos = jnp.where(active, lengths, self.cache_len - 1)
+            posid = jnp.where(active, jnp.minimum(lengths, max_pos - 1), 0)
         logits, cache = self.executor.forward(
             params, cache, st["last_tok"][:, None],
             posid[:, None], None, cache_positions=wpos,
             block_tables=tables)
-        step = logits[:, -1, :].astype(jnp.float32)
-        vocab = step.shape[-1]
-        suppress = ((st["decoded"] < st["min_new"])[:, None]
-                    & (jnp.arange(vocab)[None, :] == st["eos"][:, None]))
-        step = jnp.where(suppress, _NEG, step)
-        if all_greedy:
-            tok = jnp.argmax(step, axis=-1).astype(jnp.int32)
-            new_rng = st["rng"]  # greedy consumes no randomness
-        else:
-            keys = jax.vmap(functools.partial(jax.random.split, num=2))(
-                st["rng"])
-            tok = self.executor.sample(step, keys[:, 0], st["greedy"],
-                                       st["temperature"], st["top_k"],
-                                       st["top_p"], topk_cap=self.topk_cap)
-            new_rng = jnp.where(active[:, None], keys[:, 1], st["rng"])
-        new_len = lengths + 1
-        decoded = st["decoded"] + 1
-        done = active & (
-            (tok == st["eos"])
-            | (decoded >= st["max_new"])
-            | (new_len >= self.cache_len)
-        )
-        new_st = dict(st)
-        new_st["last_tok"] = jnp.where(active, tok, st["last_tok"])
-        new_st["lengths"] = jnp.where(active, new_len, lengths)
-        new_st["decoded"] = jnp.where(active, decoded, st["decoded"])
-        new_st["active"] = active & ~done
-        new_st["rng"] = new_rng
+        with jax.named_scope("sampler"):
+            step = logits[:, -1, :].astype(jnp.float32)
+            vocab = step.shape[-1]
+            suppress = ((st["decoded"] < st["min_new"])[:, None]
+                        & (jnp.arange(vocab)[None, :] == st["eos"][:, None]))
+            step = jnp.where(suppress, _NEG, step)
+            if all_greedy:
+                tok = jnp.argmax(step, axis=-1).astype(jnp.int32)
+                new_rng = st["rng"]  # greedy consumes no randomness
+            else:
+                keys = jax.vmap(functools.partial(jax.random.split, num=2))(
+                    st["rng"])
+                tok = self.executor.sample(step, keys[:, 0], st["greedy"],
+                                           st["temperature"], st["top_k"],
+                                           st["top_p"], topk_cap=self.topk_cap)
+                new_rng = jnp.where(active[:, None], keys[:, 1], st["rng"])
+            new_len = lengths + 1
+            decoded = st["decoded"] + 1
+            done = active & (
+                (tok == st["eos"])
+                | (decoded >= st["max_new"])
+                | (new_len >= self.cache_len)
+            )
+            new_st = dict(st)
+            new_st["last_tok"] = jnp.where(active, tok, st["last_tok"])
+            new_st["lengths"] = jnp.where(active, new_len, lengths)
+            new_st["decoded"] = jnp.where(active, decoded, st["decoded"])
+            new_st["active"] = active & ~done
+            new_st["rng"] = new_rng
         return self._pin_cache(cache), new_st, tok, done
 
-    def _tick_decode(self):
+    def _grow_pages(self) -> list:
+        """Grow-on-demand BEFORE the write: any active lane whose next
+        position crosses into an unallocated page claims one now; a dry
+        pool retires the request with its partial tokens ("cache_full")
+        — deterministic lowest-lane-first order. Returns the retired
+        ids."""
         retired = []
-        if self.paged:
-            # grow-on-demand BEFORE the write: any active lane whose next
-            # position crosses into an unallocated page claims one now; a
-            # dry pool retires the request with its partial tokens
-            # ("cache_full") — deterministic lowest-lane-first order
-            now = self._now()
+        now = self._now()
+        with span("serving.grow"):
             for slot in sorted(self._active):
                 req = self._active[slot]
                 if not self.cache_manager.ensure_page(slot):
@@ -2532,6 +2560,12 @@ class ServingEngine:
                     obs_emit("cache_full", request=req.id,
                              tokens=len(req.tokens))
                     retired.append(req.id)
+        return retired
+
+    def _tick_decode(self):
+        retired = []
+        if self.paged:
+            retired = self._grow_pages()
             if not self._active:
                 return retired
         all_greedy = all(r.greedy for r in self._active.values())
@@ -2564,31 +2598,33 @@ class ServingEngine:
             cache, st, tok, done = self._run_device(run)
         self.cache_manager.cache = cache
         self._state = st
-        tok_np = np.asarray(tok)  # host sync per tick
-        done_np = np.asarray(done)
+        # the host sync of the tick: the wait for the device is HERE
+        tok_np, done_np = self._fetch("serving.fetch", tok, done,
+                                      batch=len(active_ids))
         now = self._now()
-        for slot, req in list(self._active.items()):
-            t = int(tok_np[slot])
-            req.tokens.append(t)
-            self.cache_manager.lengths[slot] += 1
-            self.metrics.record_tokens(1)
-            finished = bool(done_np[slot])
-            # firewalled callback: a raising on_token retires THIS request
-            # only — every neighbor's host token list was already appended
-            # this tick and keeps decoding undisturbed
-            if not self._emit_token(req, t, finished):
-                self._retire_error(req, now)
-                retired.append(req.id)
-                continue
-            if finished:
-                if req.eos_token_id >= 0 and t == req.eos_token_id:
-                    reason = "eos"
-                elif len(req.tokens) >= req.max_new_tokens:
-                    reason = "max_length"
-                else:
-                    reason = "cache_full"
-                self._finalize(req, reason, now)
-                retired.append(req.id)
+        with span("serving.emit", batch=len(active_ids)):
+            for slot, req in list(self._active.items()):
+                t = int(tok_np[slot])
+                req.tokens.append(t)
+                self.cache_manager.lengths[slot] += 1
+                self.metrics.record_tokens(1)
+                finished = bool(done_np[slot])
+                # firewalled callback: a raising on_token retires THIS
+                # request only — every neighbor's host token list was
+                # already appended this tick and keeps decoding undisturbed
+                if not self._emit_token(req, t, finished):
+                    self._retire_error(req, now)
+                    retired.append(req.id)
+                    continue
+                if finished:
+                    if req.eos_token_id >= 0 and t == req.eos_token_id:
+                        reason = "eos"
+                    elif len(req.tokens) >= req.max_new_tokens:
+                        reason = "max_length"
+                    else:
+                        reason = "cache_full"
+                    self._finalize(req, reason, now)
+                    retired.append(req.id)
         return retired
 
     # ------------------------------------------------ speculative decoding
@@ -2627,112 +2663,114 @@ class ServingEngine:
         # unallocated); the slot path needs start <= cache_len - s so the
         # per-row dynamic_update_slice cannot clamp-shift backwards
         pin = self.cache_len - 1 if self.paged else self.cache_len - s
-        wpos = jnp.where(active, lengths, pin)
-        ids = jnp.concatenate([st["last_tok"][:, None], draft], axis=1)
-        posid = jnp.minimum(wpos[:, None] + jnp.arange(s, dtype=jnp.int32),
-                            max_pos - 1)
-        posid = jnp.where(active[:, None], posid, 0)
+        with jax.named_scope("lanes"):
+            wpos = jnp.where(active, lengths, pin)
+            ids = jnp.concatenate([st["last_tok"][:, None], draft], axis=1)
+            posid = jnp.minimum(
+                wpos[:, None] + jnp.arange(s, dtype=jnp.int32), max_pos - 1)
+            posid = jnp.where(active[:, None], posid, 0)
         logits, cache = self.executor.forward(
             params, cache, ids, posid, None,
             cache_positions=wpos, block_tables=tables)
-        logits = logits.astype(jnp.float32)
-        vocab = logits.shape[-1]
-        # per-position min_new suppression: position j samples generated
-        # token number decoded + j + 1, so EOS is banned while
-        # decoded + j < min_new — the condition each sequential tick
-        # would have applied
-        decoded_at = st["decoded"][:, None] + jnp.arange(s)[None, :]
-        suppress = ((decoded_at < st["min_new"][:, None])[:, :, None]
-                    & (jnp.arange(vocab)[None, None, :]
-                       == st["eos"][:, None, None]))
-        logits = jnp.where(suppress, _NEG, logits)
-        greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [b, s]
-        idx = jnp.arange(s, dtype=jnp.int32)[None, :]
-        if all_greedy:
-            # vectorized acceptance: position j's target IS what tick j
-            # would have emitted, so the emitted run is target[:acc+1]
-            # cut at the first EOS inside it; no rng is consumed
-            match = ((draft == greedy_tok[:, :k])
-                     & (jnp.arange(k)[None, :] < draft_len[:, None]))
-            acc = jnp.cumprod(match.astype(jnp.int32), axis=1).sum(axis=1)
-            m0 = acc + 1
-            is_eos = greedy_tok == st["eos"][:, None]
-            eos_pos = jnp.min(
-                jnp.where(is_eos & (idx < m0[:, None]), idx, s), axis=1)
-            m = jnp.minimum(m0, eos_pos + 1)
-            acc = jnp.minimum(acc, m)
-            out = greedy_tok
-            new_rng = st["rng"]  # greedy consumes no randomness
-        else:
-            # per-position target distributions through THE shared
-            # per-row sampler filter pipeline (rows repeated per
-            # position: row b*s + j filters position j of lane b)
-            b = logits.shape[0]
-            filt = self.executor.filter(
-                logits.reshape(b * s, vocab),
-                jnp.repeat(st["temperature"], s),
-                jnp.repeat(st["top_k"], s),
-                jnp.repeat(st["top_p"], s),
-                topk_cap=self.topk_cap).reshape(b, s, vocab)
-            p = jax.nn.softmax(filt, axis=-1)
-            split2 = jax.vmap(functools.partial(jax.random.split, num=2))
-            alive = active
-            carry = st["rng"]
-            m = jnp.zeros_like(lengths)
-            acc = jnp.zeros_like(lengths)
-            cols = []
-            for j in range(s):
-                pair = split2(carry)
-                step_key, next_carry = pair[:, 0], pair[:, 1]
-                sub = split2(step_key)
-                d = (draft[:, j] if j < k
-                     else jnp.zeros_like(st["last_tok"]))
-                has_draft = j < draft_len
-                pj = p[:, j, :]
-                p_d = jnp.take_along_axis(pj, d[:, None], axis=1)[:, 0]
-                u = jax.vmap(jax.random.uniform)(sub[:, 0])
-                # residual (p - q)+ of a deterministic (one-hot) draft:
-                # p with the draft token zeroed; log turns zeros to -inf
-                resid = jnp.where(jnp.arange(vocab)[None, :] == d[:, None],
-                                  0.0, pj)
-                samp_rej = jax.vmap(jax.random.categorical)(
-                    sub[:, 1], jnp.log(resid))
-                samp_direct = jax.vmap(jax.random.categorical)(
-                    sub[:, 1], filt[:, j, :])
-                accept_s = has_draft & (u < p_d)
-                tok_s = jnp.where(accept_s, d,
-                                  jnp.where(has_draft, samp_rej,
-                                            samp_direct))
-                accept_g = has_draft & (d == greedy_tok[:, j])
-                accept_j = jnp.where(st["greedy"], accept_g, accept_s)
-                tok_j = jnp.where(st["greedy"], greedy_tok[:, j],
-                                  tok_s).astype(jnp.int32)
-                cols.append(jnp.where(alive, tok_j, 0))
-                m = m + alive
-                acc = acc + (alive & accept_j)
-                # one split per emitted token, every active row (the
-                # mixed-tick baseline advances greedy rows' streams too)
-                carry = jnp.where(alive[:, None], next_carry, carry)
-                alive = alive & accept_j & (tok_j != st["eos"])
-            out = jnp.stack(cols, axis=1)
-            new_rng = carry
-        m = jnp.where(active, m, 0)
-        new_len = lengths + m
-        decoded = st["decoded"] + m
-        last = jnp.take_along_axis(
-            out, jnp.maximum(m - 1, 0)[:, None], axis=1)[:, 0]
-        last = jnp.where(active & (m > 0), last, st["last_tok"])
-        done = active & (
-            (last == st["eos"])
-            | (decoded >= st["max_new"])
-            | (new_len >= self.cache_len)
-        )
-        new_st = dict(st)
-        new_st["last_tok"] = last
-        new_st["lengths"] = jnp.where(active, new_len, lengths)
-        new_st["decoded"] = jnp.where(active, decoded, st["decoded"])
-        new_st["active"] = active & ~done
-        new_st["rng"] = new_rng
+        with jax.named_scope("sampler"):
+            logits = logits.astype(jnp.float32)
+            vocab = logits.shape[-1]
+            # per-position min_new suppression: position j samples generated
+            # token number decoded + j + 1, so EOS is banned while
+            # decoded + j < min_new — the condition each sequential tick
+            # would have applied
+            decoded_at = st["decoded"][:, None] + jnp.arange(s)[None, :]
+            suppress = ((decoded_at < st["min_new"][:, None])[:, :, None]
+                        & (jnp.arange(vocab)[None, None, :]
+                           == st["eos"][:, None, None]))
+            logits = jnp.where(suppress, _NEG, logits)
+            greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [b, s]
+            idx = jnp.arange(s, dtype=jnp.int32)[None, :]
+            if all_greedy:
+                # vectorized acceptance: position j's target IS what tick j
+                # would have emitted, so the emitted run is target[:acc+1]
+                # cut at the first EOS inside it; no rng is consumed
+                match = ((draft == greedy_tok[:, :k])
+                         & (jnp.arange(k)[None, :] < draft_len[:, None]))
+                acc = jnp.cumprod(match.astype(jnp.int32), axis=1).sum(axis=1)
+                m0 = acc + 1
+                is_eos = greedy_tok == st["eos"][:, None]
+                eos_pos = jnp.min(
+                    jnp.where(is_eos & (idx < m0[:, None]), idx, s), axis=1)
+                m = jnp.minimum(m0, eos_pos + 1)
+                acc = jnp.minimum(acc, m)
+                out = greedy_tok
+                new_rng = st["rng"]  # greedy consumes no randomness
+            else:
+                # per-position target distributions through THE shared
+                # per-row sampler filter pipeline (rows repeated per
+                # position: row b*s + j filters position j of lane b)
+                b = logits.shape[0]
+                filt = self.executor.filter(
+                    logits.reshape(b * s, vocab),
+                    jnp.repeat(st["temperature"], s),
+                    jnp.repeat(st["top_k"], s),
+                    jnp.repeat(st["top_p"], s),
+                    topk_cap=self.topk_cap).reshape(b, s, vocab)
+                p = jax.nn.softmax(filt, axis=-1)
+                split2 = jax.vmap(functools.partial(jax.random.split, num=2))
+                alive = active
+                carry = st["rng"]
+                m = jnp.zeros_like(lengths)
+                acc = jnp.zeros_like(lengths)
+                cols = []
+                for j in range(s):
+                    pair = split2(carry)
+                    step_key, next_carry = pair[:, 0], pair[:, 1]
+                    sub = split2(step_key)
+                    d = (draft[:, j] if j < k
+                         else jnp.zeros_like(st["last_tok"]))
+                    has_draft = j < draft_len
+                    pj = p[:, j, :]
+                    p_d = jnp.take_along_axis(pj, d[:, None], axis=1)[:, 0]
+                    u = jax.vmap(jax.random.uniform)(sub[:, 0])
+                    # residual (p - q)+ of a deterministic (one-hot) draft:
+                    # p with the draft token zeroed; log turns zeros to -inf
+                    resid = jnp.where(jnp.arange(vocab)[None, :] == d[:, None],
+                                      0.0, pj)
+                    samp_rej = jax.vmap(jax.random.categorical)(
+                        sub[:, 1], jnp.log(resid))
+                    samp_direct = jax.vmap(jax.random.categorical)(
+                        sub[:, 1], filt[:, j, :])
+                    accept_s = has_draft & (u < p_d)
+                    tok_s = jnp.where(accept_s, d,
+                                      jnp.where(has_draft, samp_rej,
+                                                samp_direct))
+                    accept_g = has_draft & (d == greedy_tok[:, j])
+                    accept_j = jnp.where(st["greedy"], accept_g, accept_s)
+                    tok_j = jnp.where(st["greedy"], greedy_tok[:, j],
+                                      tok_s).astype(jnp.int32)
+                    cols.append(jnp.where(alive, tok_j, 0))
+                    m = m + alive
+                    acc = acc + (alive & accept_j)
+                    # one split per emitted token, every active row (the
+                    # mixed-tick baseline advances greedy rows' streams too)
+                    carry = jnp.where(alive[:, None], next_carry, carry)
+                    alive = alive & accept_j & (tok_j != st["eos"])
+                out = jnp.stack(cols, axis=1)
+                new_rng = carry
+            m = jnp.where(active, m, 0)
+            new_len = lengths + m
+            decoded = st["decoded"] + m
+            last = jnp.take_along_axis(
+                out, jnp.maximum(m - 1, 0)[:, None], axis=1)[:, 0]
+            last = jnp.where(active & (m > 0), last, st["last_tok"])
+            done = active & (
+                (last == st["eos"])
+                | (decoded >= st["max_new"])
+                | (new_len >= self.cache_len)
+            )
+            new_st = dict(st)
+            new_st["last_tok"] = last
+            new_st["lengths"] = jnp.where(active, new_len, lengths)
+            new_st["decoded"] = jnp.where(active, decoded, st["decoded"])
+            new_st["active"] = active & ~done
+            new_st["rng"] = new_rng
         return self._pin_cache(cache), new_st, out, m, acc, done
 
     def _tick_decode_spec(self):
@@ -2755,40 +2793,34 @@ class ServingEngine:
         if k <= 0:
             return self._tick_decode()
         retired = []
-        now = self._now()
         if self.paged:
             # phase 1: every lane's PENDING-token page first — the exact
             # allocation the plain tick makes, in the same order, so
             # cache_full retirement decisions are identical to the
             # non-speculative engine even under a near-dry pool (draft
             # windows must never starve a neighbor's pending token)
-            for slot in sorted(self._active):
-                req = self._active[slot]
-                if not self.cache_manager.ensure_page(slot):
-                    self._evict(req, "cache_full", now)
-                    obs_emit("cache_full", request=req.id,
-                             tokens=len(req.tokens))
-                    retired.append(req.id)
+            retired = self._grow_pages()
             if not self._active:
                 return retired
         cov = {}
-        for slot in sorted(self._active):
-            req = self._active[slot]
-            # the PR 11-style budget clamp (ISSUE small fix): a draft may
-            # never overrun the request's remaining token budget or its
-            # page coverage — clamp BEFORE proposing
-            budget = max(req.max_new_tokens - len(req.tokens) - 1, 0)
-            if self.paged:
-                # phase 2: draft windows from whatever slack remains
-                # (uncovered tail writes trash-route; acceptance clamps
-                # to the covered span) — and whatever a draft claims
-                # here is RETURNED by trim_span after the verify, so the
-                # pool a neighbor sees next tick is the plain engine's
-                c = self.cache_manager.ensure_span(
-                    slot, min(k, budget) + 1)
-            else:
-                c = k + 1  # slot lanes are fully allocated
-            cov[slot] = min(k, budget, c - 1)
+        with span("serving.grow"):
+            for slot in sorted(self._active):
+                req = self._active[slot]
+                # the PR 11-style budget clamp (ISSUE small fix): a draft may
+                # never overrun the request's remaining token budget or its
+                # page coverage — clamp BEFORE proposing
+                budget = max(req.max_new_tokens - len(req.tokens) - 1, 0)
+                if self.paged:
+                    # phase 2: draft windows from whatever slack remains
+                    # (uncovered tail writes trash-route; acceptance clamps
+                    # to the covered span) — and whatever a draft claims
+                    # here is RETURNED by trim_span after the verify, so the
+                    # pool a neighbor sees next tick is the plain engine's
+                    c = self.cache_manager.ensure_span(
+                        slot, min(k, budget) + 1)
+                else:
+                    c = k + 1  # slot lanes are fully allocated
+                cov[slot] = min(k, budget, c - 1)
         req_map = {
             slot: (np.concatenate([req.prompt,
                                    np.asarray(req.tokens, np.int32)]),
@@ -2845,54 +2877,53 @@ class ServingEngine:
             cache, st, out_tok, m, acc, done = self._run_device(run)
         self.cache_manager.cache = cache
         self._state = st
-        out_np = np.asarray(out_tok)
-        m_np = np.asarray(m)
-        acc_np = np.asarray(acc)
-        done_np = np.asarray(done)
+        out_np, m_np, acc_np, done_np = self._fetch(
+            "serving.fetch", out_tok, m, acc, done, batch=len(active_ids))
         now = self._now()
         proposed = accepted = 0
         emitted_rows = []
-        for slot, req in list(self._active.items()):
-            n = int(m_np[slot])
-            toks = [int(t) for t in out_np[slot][:n]]
-            row_acc = min(int(acc_np[slot]), n)
-            proposed += int(dlen[slot])
-            accepted += row_acc
-            req.spec_proposed += int(dlen[slot])
-            req.spec_accepted += row_acc
-            emitted_rows.append(n)
-            self.cache_manager.lengths[slot] += n
-            if self.paged:
-                # return rejected-draft pages to the pool THIS tick:
-                # post-trim the chain matches what the plain engine
-                # would hold, so draft windows cost neighbors nothing
-                self.cache_manager.trim_span(slot)
-            self.metrics.record_tokens(n)
-            self._proposer.observe(slot, n)
-            finished = bool(done_np[slot])
-            failed = False
-            for i, t in enumerate(toks):
-                req.tokens.append(t)
-                # firewalled per-token callback, in emission order; a
-                # raise retires THIS request with the tokens streamed so
-                # far — neighbors keep their whole accepted runs
-                if not self._emit_token(req, t, finished and i == n - 1):
-                    self._retire_error(req, now)
+        with span("serving.emit", batch=len(active_ids)):
+            for slot, req in list(self._active.items()):
+                n = int(m_np[slot])
+                toks = [int(t) for t in out_np[slot][:n]]
+                row_acc = min(int(acc_np[slot]), n)
+                proposed += int(dlen[slot])
+                accepted += row_acc
+                req.spec_proposed += int(dlen[slot])
+                req.spec_accepted += row_acc
+                emitted_rows.append(n)
+                self.cache_manager.lengths[slot] += n
+                if self.paged:
+                    # return rejected-draft pages to the pool THIS tick:
+                    # post-trim the chain matches what the plain engine
+                    # would hold, so draft windows cost neighbors nothing
+                    self.cache_manager.trim_span(slot)
+                self.metrics.record_tokens(n)
+                self._proposer.observe(slot, n)
+                finished = bool(done_np[slot])
+                failed = False
+                for i, t in enumerate(toks):
+                    req.tokens.append(t)
+                    # firewalled per-token callback, in emission order; a
+                    # raise retires THIS request with the tokens streamed so
+                    # far — neighbors keep their whole accepted runs
+                    if not self._emit_token(req, t, finished and i == n - 1):
+                        self._retire_error(req, now)
+                        retired.append(req.id)
+                        failed = True
+                        break
+                if failed:
+                    continue
+                if finished:
+                    if (req.eos_token_id >= 0 and toks
+                            and toks[-1] == req.eos_token_id):
+                        reason = "eos"
+                    elif len(req.tokens) >= req.max_new_tokens:
+                        reason = "max_length"
+                    else:
+                        reason = "cache_full"
+                    self._finalize(req, reason, now)
                     retired.append(req.id)
-                    failed = True
-                    break
-            if failed:
-                continue
-            if finished:
-                if (req.eos_token_id >= 0 and toks
-                        and toks[-1] == req.eos_token_id):
-                    reason = "eos"
-                elif len(req.tokens) >= req.max_new_tokens:
-                    reason = "max_length"
-                else:
-                    reason = "cache_full"
-                self._finalize(req, reason, now)
-                retired.append(req.id)
         self.metrics.record_spec(proposed, accepted, emitted_rows)
         return retired
 

@@ -165,11 +165,16 @@ class GPTExecutor(ModelExecutor):
 
     def forward(self, params, cache, ids, positions, mask=None, *,
                 cache_positions=None, block_tables=None):
+        import jax
+
         from fleetx_tpu.models.gpt.generation import decode_step
 
-        return decode_step(self.model, params, cache, ids, positions, mask,
-                           cache_positions=cache_positions,
-                           block_tables=block_tables)
+        # device-trace scope: under it the layer scan's own slices and
+        # updates move the KV cache (docs/OBSERVABILITY.md, parts)
+        with jax.named_scope("cached_forward"):
+            return decode_step(self.model, params, cache, ids, positions,
+                               mask, cache_positions=cache_positions,
+                               block_tables=block_tables)
 
     def sample(self, logits, keys, greedy, temperature, top_k, top_p, *,
                topk_cap: int):

@@ -42,7 +42,8 @@ def enable_compile_cache() -> Optional[str]:
 
     Also drops jax's size/time floors so every program is kept — the
     small per-bucket serving jits are many, and re-compiling each of them
-    cold is what a chip run would otherwise pay on every call."""
+    cold is what a chip run would otherwise pay on every call — and makes
+    op_names and source lines part of the key (below)."""
     import jax
 
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
@@ -53,4 +54,16 @@ def enable_compile_cache() -> Optional[str]:
         jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # jax leaves op_names and source lines out of the cache's key, so a
+    # program that differs from a cached one in its scopes alone comes back
+    # with the OLD names: a device trace then books its time to scopes of a
+    # build that no longer exists (seen on the chip, PR 23: prefill programs
+    # cached by the parent had none of this build's scopes). The names are
+    # what the device-trace parts are read from, so they belong in the key.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    # (The key then holds the Python call stack of every instruction too,
+    # so one program reached through two callers is two entries. Leaving
+    # the stacks out, jax_include_full_tracebacks_in_locations=False, is
+    # no way around it: a Pallas call then loses its name, and the trace
+    # its kernel families; v5e compile, PR 23.)
     return cache_dir

@@ -33,7 +33,8 @@ def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
 
     keys = ("jax_compilation_cache_dir",
             "jax_persistent_cache_min_entry_size_bytes",
-            "jax_persistent_cache_min_compile_time_secs")
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_compilation_cache_include_metadata_in_key")
     saved = {k: getattr(jax.config, k) for k in keys}
     try:
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
@@ -42,6 +43,9 @@ def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         assert compile_cache.enable_compile_cache() == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir == saved[keys[0]]
+        # op_names are read from device traces: a cached program must not
+        # come back under another build's names
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
     finally:
         for k, v in saved.items():
             jax.config.update(k, v)

@@ -1,0 +1,354 @@
+"""Leaf spans inside ``ServingEngine.step()`` and ``Trainer.fit()``, and
+the model-part scopes on the compiled programs (docs/OBSERVABILITY.md,
+"Spans" and "Device-trace scopes"): every host phase and every blocking
+fetch lies under a span that names one activity, nests under the stated
+parent and carries the stated attrs; scopes are metadata only."""
+
+import contextlib
+import re
+import textwrap
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from fleetx_tpu.obs import SpanRecorder, get_recorder, span
+
+
+def test_span_records_its_parent_and_hands_out_its_attrs():
+    rec = SpanRecorder(capacity=8)
+    with span("serving.tick", recorder=rec, tick=1):
+        with span("serving.claim", recorder=rec, request=7, shared=0) as at:
+            at["shared"] = 32  # known only inside the span
+    claim, tick = rec.spans()
+    assert (claim.name, claim.parent, claim.attrs) == (
+        "serving.claim", "serving.tick", {"request": 7, "shared": 32})
+    assert (tick.name, tick.parent) == ("serving.tick", None)
+    event = next(e for e in rec.chrome_trace()["traceEvents"]
+                 if e.get("name") == "serving.claim")
+    assert event["args"] == {"parent": "serving.tick", "request": 7,
+                             "shared": 32}
+
+
+def test_default_ring_and_truncated_export_says_so(monkeypatch):
+    monkeypatch.delenv("FLEETX_OBS_SPANS", raising=False)
+    assert SpanRecorder().capacity == 65536
+    rec = SpanRecorder(capacity=2)
+    for _ in range(5):
+        with span("serving.tick", recorder=rec):
+            pass
+    assert rec.chrome_trace()["dropped"] == 3
+
+
+# ---------------------------------------------------------------- serving
+
+LANES = 16
+
+
+def _engine(**kwargs):
+    from fleetx_tpu.models.gpt.generation import GenerationConfig
+    from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
+    from fleetx_tpu.serving import ServingEngine
+
+    cfg = GPTConfig(
+        vocab_size=61, hidden_size=32, num_layers=2, num_attention_heads=2,
+        ffn_hidden_size=64, max_position_embeddings=32,
+        hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
+        dtype=jnp.float32, use_flash_attention=False)
+    model = GPTForPretraining(cfg)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    return ServingEngine(
+        model, params, slots=LANES, cache_len=16, prefill_bucket=4,
+        paged=True, page_size=8,
+        gen_cfg=GenerationConfig(decode_strategy="greedy",
+                                 eos_token_id=10**6, pad_token_id=60,
+                                 max_length=6), **kwargs)
+
+
+class _SlowFetch:
+    """A device array whose transfer to the host takes ``delay_s``."""
+
+    def __init__(self, array, delay_s):
+        self.array, self.delay_s = array, delay_s
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(self.delay_s)
+        return np.asarray(self.array)
+
+
+@pytest.fixture(scope="module")
+def ticked():
+    """One engine: sixteen admissions, then a warm decode-only tick whose
+    token fetch takes 20 ms. Returns ``(admission tick's spans, decode-only
+    tick's spans)`` from the process recorder."""
+    eng = _engine()
+    rng = np.random.default_rng(0)
+    for _ in range(LANES):
+        eng.submit(rng.integers(1, 60, 5, dtype=np.int32), max_length=6)
+    rec = get_recorder()
+    rec.clear()
+    eng.step()            # admits all sixteen, then decodes once
+    admission = rec.spans()
+    eng.step()            # warm: every program is compiled
+    decode = eng._decode_jit
+
+    def slow(*args):
+        cache, st, tok, done = decode(*args)
+        return cache, st, _SlowFetch(tok, 0.02), done
+
+    eng._decode_jit = slow
+    rec.clear()
+    eng.step()
+    return admission, rec.spans()
+
+
+def _named(spans, name):
+    return [s for s in spans if s.name == name]
+
+
+def test_admission_spans_nest_under_admit_with_their_attrs(ticked):
+    admission, _ = ticked
+    admits = _named(admission, "serving.admit")
+    assert len(admits) == LANES
+    request = admits[0].attrs["request"]
+    mine = [s for s in admission if s.attrs.get("request") == request]
+    # the dry run of the prefix match before the admission is the tick's
+    (check,) = [s for s in mine if s.name == "serving.can_admit"]
+    assert check.parent == "serving.tick"
+    mine.remove(check)
+    by_name = {}
+    for s in mine:
+        by_name.setdefault(s.name, []).append(s)
+    assert set(by_name) == {"serving.admit", "serving.claim",
+                            "serving.prefill_args", "serving.prefill",
+                            "serving.first_token", "serving.install"}
+    admit = by_name["serving.admit"][0]
+    for name, spans in by_name.items():
+        if name != "serving.admit":
+            assert all(s.parent == "serving.admit" for s in spans), name
+            assert all(admit.start_s <= s.start_s and s.end_s <= admit.end_s
+                       for s in spans), name
+    assert by_name["serving.claim"][0].attrs == {"request": request,
+                                                 "shared": 0}
+    assert by_name["serving.prefill_args"][0].attrs == {
+        "request": request, "bucket": 8}
+    # the prefix registration (during the prefill) and the lane install
+    assert len(by_name["serving.install"]) == 2
+    # admit now ends after the first token was fetched and the lane
+    # installed: its documented meaning
+    assert admit.end_s >= by_name["serving.install"][-1].end_s
+    # at most 6 new spans an admission, the re-commit of the snapshot
+    # among them
+    new = [s for s in mine if s.name not in ("serving.admit",
+                                              "serving.prefill")]
+    assert len(new) + 1 <= 6
+    commits = [s for s in _named(admission, "serving.snapshot")
+               if s.parent == "serving.tick"]
+    assert len(commits) == LANES
+
+
+def test_decode_only_tick_has_one_leaf_span_per_phase(ticked):
+    _, spans = ticked
+    names = [s.name for s in spans]
+    assert names.count("serving.tick") == 1
+    assert not _named(spans, "serving.admit")
+    tick = _named(spans, "serving.tick")[0]
+    for name in ("serving.expire", "serving.grow", "serving.decode",
+                 "serving.fetch", "serving.emit"):
+        found = _named(spans, name)
+        assert found, name
+        assert all(s.parent == "serving.tick" for s in found), name
+        assert all(tick.start_s <= s.start_s and s.end_s <= tick.end_s
+                   for s in found), name
+    # the snapshot before the tick and the metrics block after it are the
+    # tick's siblings
+    (snapshot,) = _named(spans, "serving.snapshot")
+    (observe,) = _named(spans, "serving.observe")
+    assert snapshot.parent is None and snapshot.end_s <= tick.start_s
+    assert observe.parent is None and observe.start_s >= tick.end_s
+    # ONE emit span for sixteen lanes, never one per lane
+    (emit,) = _named(spans, "serving.emit")
+    (fetch,) = _named(spans, "serving.fetch")
+    assert emit.attrs == {"batch": LANES} and fetch.attrs == {"batch": LANES}
+    assert len(spans) - names.count("serving.tick") \
+        - names.count("serving.decode") <= 10
+
+
+def test_the_wait_for_the_device_is_in_fetch_not_in_decode(ticked):
+    _, spans = ticked
+    (fetch,) = _named(spans, "serving.fetch")
+    (decode,) = _named(spans, "serving.decode")
+    assert fetch.duration_s >= 0.02
+    assert decode.duration_s < 0.02      # a dispatch span
+    assert decode.end_s <= fetch.start_s
+
+
+def test_tables_upload_is_spanned_only_when_it_uploads():
+    eng = _engine()
+    eng.submit(np.asarray([1, 2, 3], np.int32), max_length=6)
+    rec = get_recorder()
+    rec.clear()
+    eng.step()
+    assert len(_named(rec.spans(), "serving.tables")) == 1
+    rec.clear()
+    eng.step()  # same pages: nothing to upload
+    assert not _named(rec.spans(), "serving.tables")
+
+
+# ---------------------------------------------------------------- trainer
+
+_YAML = textwrap.dedent("""
+    Global:
+      seed: 7
+      local_batch_size: 2
+      micro_batch_size: 2
+    Engine:
+      max_steps: 2
+      logging_freq: 1
+      eval_freq: 0
+      eval_iters: 1
+      save_load:
+        save_steps: 1000
+    Model:
+      module: GPTModule
+      vocab_size: 64
+      hidden_size: 32
+      num_layers: 2
+      num_attention_heads: 2
+      ffn_hidden_size: 64
+      max_position_embeddings: 16
+      hidden_dropout_prob: 0.0
+      attention_probs_dropout_prob: 0.0
+      use_flash_attention: False
+    Optimizer:
+      name: AdamW
+      weight_decay: 0.01
+      lr:
+        name: CosineAnnealingWithWarmupDecay
+        decay_steps: 100
+        max_lr: 1.0e-3
+        min_lr: 1.0e-4
+""")
+
+
+def _trainer_and_data(tmp_path):
+    from fleetx_tpu.core.engine import Trainer
+    from fleetx_tpu.models import build_module
+    from fleetx_tpu.utils.config import get_config
+
+    path = tmp_path / "cfg.yaml"
+    path.write_text(_YAML)
+    cfg = get_config(str(path), nranks=1)
+    cfg.Engine.save_load.output_dir = str(tmp_path / "out")
+    gbs = cfg.Global.global_batch_size
+    tokens = np.random.RandomState(0).randint(0, 64, (gbs, 16)).astype(np.int32)
+    data = [{"tokens": tokens, "labels": ((tokens + 1) % 64).astype(np.int32),
+             "loss_mask": np.ones((gbs, 16), np.float32)}] * 2
+    return Trainer(cfg, build_module(cfg)), data
+
+
+def test_fit_spans_the_batch_the_fetches_and_the_log_line(tmp_path):
+    trainer, data = _trainer_and_data(tmp_path)
+    rec = get_recorder()
+    rec.clear()
+    trainer.fit(data)
+    spans = rec.spans()
+    shard = _named(spans, "train.shard_batch")
+    assert [s.attrs["step"] for s in shard] == [0, 1]
+    assert all(s.parent is None for s in shard)
+    fetches = _named(spans, "train.loss_fetch")
+    # each step: the sentry's read right after the dispatch (top level),
+    # then the loss window's at the logging step (inside the callback)
+    assert [s.parent for s in fetches] == [None, "train.callback"] * 2
+    logs = _named(spans, "train.log")
+    assert [s.parent for s in logs] == ["train.callback"] * 2
+    for step, fetch in zip(_named(spans, "train.step"), fetches[::2]):
+        assert step.end_s <= fetch.start_s  # train.step ends at dispatch
+
+
+# ------------------------------------------------- scopes on the programs
+
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def _instructions(hlo_text):
+    """``[(instruction name, op_name or None)]`` of optimized HLO text."""
+    out = []
+    for line in hlo_text.splitlines():
+        head = _INSTRUCTION.match(line)
+        if head:
+            op = _OP_NAME.search(line)
+            out.append((head.group(1), op and op.group(1)))
+    return out
+
+
+def _unscoped_share(hlo_text):
+    from perfbench import trace_reduce
+    from perfbench.layer_metrics import _parts
+
+    # a parameter's op_name is its argument's name and a reducer's body
+    # has bare primitives: only paths from the traced program count
+    named = [(trace_reduce.instruction("%" + name), op)
+             for name, op in _instructions(hlo_text)
+             if op and op.startswith("jit(")]
+    parts = [_parts.part_of(instruction, op) for instruction, op in named]
+    assert len(parts) > 50
+    return parts.count("unscoped") / len(parts), sorted(
+        {op for (_, op), part in zip(named, parts) if part == "unscoped"})
+
+
+@contextlib.contextmanager
+def _no_scopes(monkeypatch):
+    with monkeypatch.context() as patch:
+        patch.setattr(jax, "named_scope",
+                      lambda name: contextlib.nullcontext())
+        yield
+
+
+def _decode_tick_text():
+    eng = _engine()
+    eng.submit(np.asarray([1, 2, 3], np.int32), max_length=6)
+    eng.step()
+    compiled = eng.compiled_decode()
+    return compiled.as_text(), compiled.cost_analysis()
+
+
+def _train_step_text(tmp_path):
+    trainer, data = _trainer_and_data(tmp_path)
+    trainer.fit(data)
+    return trainer.compiled_text("train"), trainer.cost_analysis("train")
+
+
+@pytest.mark.parametrize("program", ["decode_tick", "train_step"])
+def test_every_named_instruction_falls_in_a_part(program, tmp_path):
+    text, _ = (_decode_tick_text() if program == "decode_tick"
+               else _train_step_text(tmp_path))
+    share, which = _unscoped_share(text)
+    assert share <= 0.05, which
+
+
+@pytest.mark.parametrize("program", ["decode_tick", "train_step"])
+def test_scopes_are_metadata_only(program, tmp_path, monkeypatch):
+    """The same program compiled with every ``jax.named_scope`` (flax's
+    and ours) switched off has the same instructions and the same cost."""
+    build = (_decode_tick_text if program == "decode_tick"
+             else lambda: _train_step_text(tmp_path))
+    text, cost = build()
+    with _no_scopes(monkeypatch):
+        bare_text, bare_cost = build()
+    # instruction for instruction the same, but for the numbers in names
+    assert ([re.sub(r"[.0-9]+$", "", name) for name, _ in _instructions(text)]
+            == [re.sub(r"[.0-9]+$", "", name)
+                for name, _ in _instructions(bare_text)])
+    cost, bare_cost = (c[0] if isinstance(c, (list, tuple)) else c
+                       for c in (cost, bare_cost))
+    for key in ("flops", "bytes accessed"):
+        assert cost.get(key) == bare_cost.get(key), key
+    scoped = sum(1 for _, op in _instructions(text)
+                 if op and re.search(r"sampler|optimizer", op))
+    assert scoped and not any(
+        op and re.search(r"sampler|optimizer", op)
+        for _, op in _instructions(bare_text))
