@@ -17,8 +17,9 @@ the full width of GPT-345M (hidden 1024, 24 layers, 16 heads of 64, vocab
   bucket 32) answering 8 requests with prompts of 32-192 tokens and 16-160
   new tokens. Every request must return exactly its token budget with
   ``finish_reason == "max_length"``, with no recovery, poison retirement or
-  fault event, and the compiled decode tick must hold the paged decode
-  kernel's Mosaic call.
+  fault event, the compiled decode tick must hold the paged decode
+  kernel's Mosaic call, and neither the tick nor a prefill program may hold
+  a copy of the page pool among its temporaries.
 - on a host with four chips, the same two paths again over the mesh: the
   trainer at dp2 x mp2 (parameter shards on all four chips, first-step
   loss against the one-chip run on the same batch with dropout off) and
@@ -286,18 +287,60 @@ def leg_serve(mp: int) -> dict:
     for kind in ("fault_injected", "engine_recovery", "tick_fault",
                  "poison_retired"):
         assert not events.get(kind), (kind, events)
-    calls = _mosaic_calls(engine.compiled_decode().as_text(),
-                          PAGED_KERNEL_NAME)
+    tick = engine.compiled_decode()
+    calls = _mosaic_calls(tick.as_text(), PAGED_KERNEL_NAME)
     assert calls, ("the decode tick holds no Mosaic call of "
                    f"{PAGED_KERNEL_NAME}: flash decode gave way")
+    temporaries = _pool_stays_in_place(engine, tick)
     return {
         "device": device, "mesh": {"mp": mp}, "requests": len(REQUESTS),
         "tokens_generated": int(snap["tokens_generated"]),
         "ticks": int(snap["ticks"]), "drain_wall_s": round(wall_s, 1),
         "mosaic_calls": {PAGED_KERNEL_NAME: calls},
+        "temporaries": temporaries,
         "kv_cache_bytes_per_device": int(snap["kv_cache_bytes"]),
         "tokens": tokens, "compile_cache_dir": cache_dir, **clock.report(),
     }
+
+
+def _pool_stays_in_place(engine, tick) -> dict:
+    """The decode tick and the largest prefill bucket the requests
+    compiled must update the page pool in place: beyond the bf16 copy of
+    the float32 weights that every serving program makes, their
+    temporaries must stay under half a copy of the pool. (With the pool a
+    scanned input and stacked output of the layer loop both held a whole
+    copy of it, and moved it three times: PERF.md, PR 24. At this size the
+    pool is smaller than the weights' copy, so the temporaries alone
+    cannot be held against it.) Bytes are per device."""
+    import jax
+    import jax.numpy as jnp
+
+    def device_bytes(tree, itemsize=None):
+        return sum(
+            x.addressable_shards[0].data.size * (itemsize or x.dtype.itemsize)
+            for x in jax.tree.leaves(tree))
+
+    cache = engine.cache_manager.cache
+    pool = device_bytes(cache)
+    weights_bf16 = device_bytes(engine.params, itemsize=2)
+    bucket = max(b for kind, b in engine._prefill_jits if kind == "paged")
+    i32 = lambda v: jnp.asarray(v, jnp.int32)  # noqa: E731
+    with engine._mesh_context():
+        prefill = engine._prefill_jits[("paged", bucket)].lower(
+            engine.params, cache, jnp.zeros((bucket,), jnp.int32),
+            i32(bucket), i32(0), i32(engine.cache_manager.tables[0]),
+            i32(-1), i32(0), jnp.asarray(True),
+            jnp.asarray(1.0, jnp.float32), i32(0),
+            jnp.asarray(1.0, jnp.float32), jax.random.PRNGKey(0)).compile()
+    out = {"pool_bytes": pool, "weights_bf16_bytes": weights_bf16}
+    for name, program in (("tick", tick), (f"prefill_{bucket}", prefill)):
+        temp = int(program.memory_analysis().temp_size_in_bytes)
+        assert temp < weights_bf16 + pool // 2, (
+            f"{name} holds {temp} bytes of temporaries beside "
+            f"{weights_bf16} of bf16 weights: a copy of the {pool}-byte "
+            "page pool is back")
+        out[f"{name}_temp_bytes"] = temp
+    return out
 
 
 LEGS = {

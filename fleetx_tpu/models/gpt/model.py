@@ -195,7 +195,7 @@ class SelfAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, attn_mask=None, *, deterministic=True, decode=False,
-                 cache_positions=None, block_tables=None):
+                 cache_positions=None, block_tables=None, layer_index=None):
         cfg = self.cfg
         h, nh, hd = cfg.hidden_size, cfg.num_attention_heads, cfg.head_dim
 
@@ -213,7 +213,7 @@ class SelfAttention(nn.Module):
         if decode:
             kv_pad_mask = attn_mask  # pre-causal-merge mask: left-pad layout
             k, v, attn_mask, decode_end, paged, kv_scales = self._update_cache(
-                k, v, attn_mask, cache_positions, block_tables
+                k, v, attn_mask, cache_positions, block_tables, layer_index
             )
             causal = False  # the cache mask encodes absolute-position causality
             if paged is not None:
@@ -334,7 +334,7 @@ class SelfAttention(nn.Module):
         return checkpoint_name(out, "attn_out")
 
     def _update_cache(self, k, v, attn_mask, cache_positions=None,
-                      block_tables=None):
+                      block_tables=None, layer_index=None):
         """Incremental decode: append this step's k/v at cache_index and
         build the absolute-position causal mask (query i at absolute position
         start+i may see cache positions <= start+i). Cache layout
@@ -378,7 +378,7 @@ class SelfAttention(nn.Module):
         buffers); ``kv_scales`` is None at the native kv dtype."""
         if self.cfg.decode_num_pages is not None:
             return self._update_paged_cache(
-                k, v, attn_mask, cache_positions, block_tables
+                k, v, attn_mask, cache_positions, block_tables, layer_index
             )
         quant = self.cfg.decode_kv_dtype == "int8"
         is_init = not self.has_variable("cache", "cached_key")
@@ -463,7 +463,7 @@ class SelfAttention(nn.Module):
         return k, v, attn_mask, decode_end, None, kv_scales
 
     def _update_paged_cache(self, k, v, attn_mask, cache_positions,
-                            block_tables):
+                            block_tables, layer_index=None):
         """Page-granular decode cache write (``cfg.decode_num_pages`` set).
 
         The cache leaves are ONE pool of ``[num_pages, page_size, nh*hd]``
@@ -480,6 +480,14 @@ class SelfAttention(nn.Module):
         When ``cfg.decode_kv_dtype == "int8"`` the pools store int8 with
         per-vector fp32 scale pools (``[num_pages, ps, nh]``) scattered
         through the same block tables — see :meth:`_update_cache`.
+
+        ``layer_index`` (traced int32 scalar) says the layer scan CARRIES
+        the cache (:meth:`GPTModel._decoder_stack`): the leaves are then the
+        whole stack ``[L, num_pages, ps, w]``, read and written as the flat
+        pool ``[L*num_pages, ps, w]`` through ``block_tables + layer_index *
+        num_pages``, so a zeroed table entry of layer ``i`` lands on layer
+        ``i``'s own page 0. The pools and tables handed back are the flat
+        ones; the paged kernel and ``paged_gather_kv`` take them as they are.
 
         Returns ``(k_pages, v_pages, attn_mask, decode_end, tables,
         kv_scales)``: raw pools + tables so the caller picks paged-flash
@@ -535,24 +543,34 @@ class SelfAttention(nn.Module):
                 k_w, v_w = k, v
             wpos = cache_positions.astype(jnp.int32)       # [b] write offsets
             tables = block_tables.astype(jnp.int32)        # [b, n_pages_row]
+            rows = [(ck, k_w.reshape(b * s, nh * hd)),
+                    (cv, v_w.reshape(b * s, nh * hd))]
+            if quant:
+                rows += [(cks, k_s.reshape(b * s, nh)),
+                         (cvs, v_s.reshape(b * s, nh))]
+            pools = []
             with jax.named_scope("cache_write"):
+                if layer_index is not None:
+                    # carried stack [L, P, ps, w]: layer i's pages are
+                    # [i*P, (i+1)*P) of the flat pool, its trash page i*P
+                    tables = tables + layer_index * ck.value.shape[1]
                 pos = wpos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
                 pos = jnp.minimum(pos, max_len - 1)        # [b, s] logical
                 page = jnp.take_along_axis(tables, pos // ps, axis=1)
-                ck.value = ck.value.at[page.reshape(-1), (pos % ps).reshape(-1)
-                                       ].set(k_w.reshape(b * s, nh * hd))
-                cv.value = cv.value.at[page.reshape(-1), (pos % ps).reshape(-1)
-                                       ].set(v_w.reshape(b * s, nh * hd))
-                if quant:
-                    cks.value = cks.value.at[
-                        page.reshape(-1), (pos % ps).reshape(-1)
-                    ].set(k_s.reshape(b * s, nh))
-                    cvs.value = cvs.value.at[
-                        page.reshape(-1), (pos % ps).reshape(-1)
-                    ].set(v_s.reshape(b * s, nh))
+                page, off = page.reshape(-1), (pos % ps).reshape(-1)
+                for var, new in rows:
+                    # merging the leading axes is a bitcast (and a no-op on
+                    # one layer's own [P, ps, w] pool)
+                    pool = var.value.reshape((-1,) + var.value.shape[-2:])
+                    pool = pool.at[page, off].set(new)
+                    var.value = pool.reshape(var.value.shape)
+                    pools.append(pool)
             if quant:
-                kv_scales = (cks.value, cvs.value)
-            idx.value = jnp.max(wpos) + s
+                kv_scales = tuple(pools[2:])
+            if layer_index is None:
+                idx.value = jnp.max(wpos) + s
+            else:
+                idx.value = idx.value.at[layer_index].set(jnp.max(wpos) + s)
             if s == 1:
                 decode_end = wpos + 1  # [b]: per-row live logical length
             k_pos = jnp.arange(max_len)
@@ -561,7 +579,7 @@ class SelfAttention(nn.Module):
             attn_mask = (causal if attn_mask is None
                          else attn_mask.astype(bool) & causal)
             paged = tables
-            k, v = ck.value, cv.value
+            k, v = pools[:2]
         return k, v, attn_mask, decode_end, paged, kv_scales
 
     def _flash_decode_ok(self, kv_pad_mask, cache_len: int,
@@ -688,7 +706,7 @@ class DecoderLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x, attn_mask=None, deterministic=True, decode=False,
-                 cache_positions=None, block_tables=None):
+                 cache_positions=None, block_tables=None, layer_index=None):
         cfg = self.cfg
         x = _constrain_act(x, cfg)
         residual = x
@@ -696,6 +714,7 @@ class DecoderLayer(nn.Module):
         y = SelfAttention(cfg, name="attn")(
             y, attn_mask, deterministic=deterministic, decode=decode,
             cache_positions=cache_positions, block_tables=block_tables,
+            layer_index=layer_index,
         )
         y = _dropout(cfg, "attn_dropout")(y, deterministic=deterministic)
         x = residual + y
@@ -728,10 +747,10 @@ class _ScanLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x, attn_mask, deterministic, decode,
-                 cache_positions=None, block_tables=None):
+                 cache_positions=None, block_tables=None, layer_index=None):
         x = DecoderLayer(self.cfg, name="layer")(
             x, attn_mask, deterministic, decode, cache_positions,
-            block_tables
+            block_tables, layer_index
         )
         return x, None
 
@@ -841,18 +860,30 @@ class GPTModel(nn.Module):
                     prevent_cse=False,
                     static_argnums=(3, 4),
                 )
+            # Paged serving: the page pools ride the layer loop as a CARRY
+            # and every layer scatters into its own pages of the whole
+            # stack, which XLA updates in place. A scanned input and
+            # stacked output (the form training and the contiguous layout
+            # keep) has each layer's pool sliced out and restacked and the
+            # donated stack copied: the whole pool moves three times a
+            # program to write a few rows (PERF.md, PR 24).
+            carried = (decode and cfg.decode_num_pages is not None
+                       and block_tables is not None)
+            args = (x, attn_mask, deterministic, decode, cache_positions,
+                    block_tables)
+            if carried:  # each layer's index into the carried stack
+                args += (jnp.arange(cfg.num_layers, dtype=jnp.int32),)
             stack = nn.scan(
                 layer_cls,
-                variable_axes={"params": 0, "cache": 0, "intermediates": 0},
+                variable_axes={"params": 0, "intermediates": 0,
+                               **({} if carried else {"cache": 0})},
+                variable_carry="cache" if carried else False,
                 split_rngs={"params": True, "dropout": True},
-                in_axes=(nn.broadcast, nn.broadcast, nn.broadcast,
-                         nn.broadcast, nn.broadcast),
+                in_axes=(nn.broadcast,) * 5 + ((0,) if carried else ()),
                 length=cfg.num_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )
-            x, _ = stack(cfg, name="layers")(x, attn_mask, deterministic,
-                                             decode, cache_positions,
-                                             block_tables)
+            x, _ = stack(cfg, name="layers")(*args)
             return x
         # Unrolled path: needed for per-layer recompute opt-out
         # (no_recompute_layers, reference single_model.py:473-475).
