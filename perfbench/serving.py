@@ -55,7 +55,8 @@ def build_model(cell, seed: int):
 
 def build_engine(cell, model, variables):
     """The engine as ``tools/bench_serving.py`` and ``chip_smoke.py`` build
-    it: paged cache, greedy, EOS off, sizes from the cell's file."""
+    it: paged cache, greedy, EOS off, sizes from the cell's file (a cell
+    without ``prefill_bucket`` keeps the engine's default)."""
     from fleetx_tpu.models.gpt.generation import GenerationConfig
     from fleetx_tpu.serving import ServingEngine
 
@@ -67,7 +68,8 @@ def build_engine(cell, model, variables):
         gen_cfg=GenerationConfig(decode_strategy="greedy", eos_token_id=-1,
                                  pad_token_id=0, max_length=max_new),
         paged=True, page_size=deploy["page_size"],
-        num_pages=deploy["pool_tokens"] // deploy["page_size"] + 1)
+        num_pages=deploy["pool_tokens"] // deploy["page_size"] + 1,
+        prefill_bucket=deploy.get("prefill_bucket"))
 
 
 def warm_up(engine, cell, seed: int) -> list:

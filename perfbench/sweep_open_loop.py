@@ -6,9 +6,12 @@ the engine sustains without a growing queue.
 One process and one engine, so the rates share one compilation; between
 rates the engine drains. For each rate it prints the requests measured,
 tokens/s, TTFT and gap percentiles, the share of requests whose first token
-came within ``--ttft-limit-ms``, and the backlog (requests submitted and
+came within ``--ttft-limit-ms``, the backlog (requests submitted and
 not finished) at the middle and at the end of the window: a backlog that
-grows through the window is past the knee. Not part of a check: the rate it
+grows through the window is past the knee; and, by the cell's own readers,
+the lanes' occupancy and the share of gaps that held another request's
+admission, which says where the gaps' 95th percentile lies (PERF.md
+section 2). Not part of a check: the rate it
 finds is written into the traffic file as a number.
 """
 
@@ -26,6 +29,7 @@ os.environ.setdefault("FLEETX_OBS_SPANS", "1048576")
 
 from perfbench import harness, serving, traffic as traffic_gen  # noqa: E402
 from perfbench.drivers.serve_open_loop import replay  # noqa: E402
+from perfbench.layer_metrics import admit_gap_share, lane_occupancy  # noqa: E402
 
 
 def main() -> int:
@@ -65,7 +69,13 @@ def main() -> int:
 
         ttft = [(r["stamps"][0] - r["due_s"]) * 1e3 if r["stamps"] else 1e9
                 for r in measured]
-        gaps = [ms for _, ms in clients.gaps(start, end)]
+        stamped = clients.gaps(start, end)
+        gaps = [ms for _, ms in stamped]
+        run = harness.Run(  # what the cell's own readers take
+            cell=cell, device={}, setup_s=0.0, window=(start, end),
+            attempted=len(measured), failed=0, correct=False, checks={},
+            samples={"gaps": stamped, "lanes": cell.deploy["lanes"]},
+            spans=harness.program_spans(start), counters={})
         tokens = sum(1 for t in clients.token_s if start <= t <= end)
         print("sweep " + json.dumps({
             "rate_per_s": rate, "measured": len(measured),
@@ -74,14 +84,17 @@ def main() -> int:
             "ttft_ms_p90": harness.percentile(ttft, 90),
             "ttft_within_limit": sum(t <= args.ttft_limit_ms for t in ttft)
             / max(len(ttft), 1),
-            "gap_ms_p50": harness.percentile(gaps, 50),
-            "gap_ms_p99": harness.percentile(gaps, 99),
+            **{f"gap_ms_p{q}": harness.percentile(gaps, q)
+               for q in (50, 90, 95, 97, 99)},
+            "admit_gap_share": admit_gap_share.read(run),
+            "lane_occupancy": lane_occupancy.read(run),
             "backlog_mid": backlog((start + end) / 2),
             "backlog_end": backlog(end),
             "lanes": cell.deploy["lanes"]}), flush=True)
         t0 = time.perf_counter()
         engine.drain()
         harness.log(f"drained in {time.perf_counter() - t0:.1f} s")
+    harness.log(f"memory_peak_bytes {harness.memory_peak_bytes(cell.chips)}")
     return 0
 
 

@@ -74,3 +74,21 @@ def test_int8_weights_in_the_engine_turn_the_check_false(tiny, monkeypatch):
     assert out["reference_rms_err"] > 2 * bf16["reference_rms_err"]
     assert out["reference_rms_err"] > (serving.REFERENCE_RMS_TOL
                                        * out["reference_logit_std"])
+
+
+@pytest.mark.parametrize("cell_name,key", [
+    ("gpt1.3b-serve-chat-steady-v2", 16),
+    ("gpt1.3b-serve-docs-batch", None),
+], ids=["the cell's key", "no key: the engine's default"])
+def test_build_engine_hands_the_cells_prefill_bucket_to_the_engine(
+        tiny, cell_name, key):
+    _, model, variables = tiny
+    cell = harness.load_cell(cell_name, tiny=True)
+    assert cell.deploy.get("prefill_bucket") == key
+    engine = serving.build_engine(cell, model, variables)
+    # 32 is the engine's default (FLEETX_SERVING_PREFILL_BUCKET unset)
+    assert engine.prefill_bucket == (key or 32)
+    if key:  # and the warm-up follows it: one program a bucket
+        longest = cell.traffic["tenants"][0]["prompt"]["max"]
+        assert serving.warm_up(engine, cell, 3) == list(
+            range(key, longest + 1, key))

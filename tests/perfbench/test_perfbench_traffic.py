@@ -44,6 +44,20 @@ def test_open_loop_trace_is_a_function_of_the_seed(name):
             assert r.prompt.dtype == np.int32 and r.prompt.min() >= 1
 
 
+@pytest.mark.parametrize("name", OPEN_LOOP)
+def test_open_loop_rate_is_a_stated_share_of_a_swept_knee(name):
+    """An open-loop cell judged on its tails runs at about four fifths of
+    the highest rate the system sustained in a sweep on the chip: the file
+    says what that knee was, which share of it the rate is, and which PR
+    swept it, so that a later PR that moves the knee can be told from one
+    that did not."""
+    job = _load(name)
+    rate, knee = job["arrivals"]["rate_per_s"], job["knee_per_s"]
+    assert 0.7 <= rate / knee <= 0.85
+    assert rate == pytest.approx(job["knee_share"] * knee)
+    assert job["knee_swept_by"].startswith("PR ")
+
+
 @pytest.mark.parametrize("name", CLOSED_LOOP)
 def test_client_stream_depends_on_seed_and_client_only(name):
     job = _load(name)
