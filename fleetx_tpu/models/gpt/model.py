@@ -24,6 +24,7 @@ Reference feature map:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import jax
@@ -85,6 +86,30 @@ class GPTConfig:
     # 'scatter' = index scatter/gather, O(n) dispatch memory (large E);
     # 'auto' picks scatter once the dense masks would dominate memory
     moe_dispatch: str = "auto"
+    # gate "softmax_topk" (parallel/moe.py DroplessMoEMLP): softmax over
+    # all experts in float32, the ``top_k`` largest kept with their
+    # weights as they are (``norm_topk_prob`` renormalises them to sum to
+    # one), no capacity and no dropped token; gated-SiLU experts of width
+    # ``ffn_hidden_size`` without biases
+    norm_topk_prob: bool = False
+    # ---- the decoder block's kind. The defaults are the GPT-2 block
+    # (learned positions, LayerNorm, GELU MLP, biases, tied head); a YAML's
+    # ``Model`` section sets the others (configs/nlp/olmoe/).
+    # "rope": no position table; q and k are rotated (whole head, two
+    # halves, base ``rope_theta``) at ``position_ids`` BEFORE the cache
+    # write, so a cached key is rotated once
+    position_embedding: str = "learned"   # learned | rope
+    rope_theta: float = 10000.0
+    norm: str = "layernorm"               # layernorm | rmsnorm
+    norm_eps: float = 1e-5
+    mlp_act: str = "gelu"                 # gelu | swiglu (gated SiLU)
+    use_bias: bool = True
+    # RMSNorm with a learned weight over the WHOLE q and k projection
+    # (all heads), before the split into heads and the rotation
+    qk_norm: bool = False
+    tie_word_embeddings: bool = True      # False: an ``lm_head`` of its own
+    # the serving family name (/healthz ``model``, /v1/models)
+    family: str = "gpt"
     # virtual/interleaved pipeline: each physical stage owns this many
     # non-contiguous layer chunks (reference num_virtual_pipeline_stages,
     # hybrid_model.py:1095)
@@ -150,6 +175,27 @@ class GPTConfig:
             kw["expert_mode"] = True
         return cls(**kw)
 
+    def __post_init__(self) -> None:
+        """Refuse block kinds nobody wrote and combinations no test runs."""
+        for field, allowed in (("position_embedding", ("learned", "rope")),
+                               ("norm", ("layernorm", "rmsnorm")),
+                               ("mlp_act", ("gelu", "swiglu"))):
+            if getattr(self, field) not in allowed:
+                raise ValueError(
+                    f"{field}={getattr(self, field)!r}; choose "
+                    + " | ".join(allowed))
+        if self.position_embedding == "rope":
+            if self.head_dim % 2:
+                raise ValueError("rope needs an even head size")
+            if self.pp_degree > 1 or self.cp_degree > 1:
+                raise NotImplementedError(
+                    "rotary positions under pipeline or context parallelism "
+                    "(the stage and ring paths do not carry positions)")
+        if self.gate == "softmax_topk" and self.expert_mode and not (
+                1 <= self.top_k <= self.num_experts):
+            raise ValueError(
+                f"top_k {self.top_k} of {self.num_experts} experts")
+
 
 def _dense(features, logical_axes, name, use_bias=True, dtype=jnp.bfloat16):
     """Dense with logical-axis-partitioned kernel; bias follows the kernel's
@@ -167,13 +213,13 @@ def _dense(features, logical_axes, name, use_bias=True, dtype=jnp.bfloat16):
     )
 
 
-def attn_out_dense(hidden_size, dtype, name="out_proj"):
+def attn_out_dense(hidden_size, dtype, name="out_proj", use_bias=True):
     """Row-parallel attention output projection [.., heads, kv] -> [.., embed]
     — shared by GPT/ERNIE/ViT attention blocks."""
     return nn.DenseGeneral(
         features=hidden_size,
         axis=(-2, -1),
-        use_bias=True,
+        use_bias=use_bias,
         dtype=dtype,
         param_dtype=jnp.float32,
         kernel_init=nn.with_logical_partitioning(
@@ -195,19 +241,29 @@ class SelfAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, attn_mask=None, *, deterministic=True, decode=False,
-                 cache_positions=None, block_tables=None, layer_index=None):
+                 cache_positions=None, block_tables=None, layer_index=None,
+                 rope=None):
         cfg = self.cfg
         h, nh, hd = cfg.hidden_size, cfg.num_attention_heads, cfg.head_dim
+        proj = functools.partial(_dense, logical_axes=("embed", "heads", "kv"),
+                                 use_bias=cfg.use_bias, dtype=cfg.dtype)
 
         if cfg.fuse_attn_qkv:
-            qkv = _dense((nh, 3 * hd), ("embed", "heads", "kv"), "qkv_proj", dtype=cfg.dtype)(x)
+            qkv = proj((nh, 3 * hd), name="qkv_proj")(x)
             qkv = checkpoint_name(qkv, "qkv_out")
             q, k, v = jnp.split(qkv, 3, axis=-1)
         else:
-            q = _dense((nh, hd), ("embed", "heads", "kv"), "q_proj", dtype=cfg.dtype)(x)
-            k = _dense((nh, hd), ("embed", "heads", "kv"), "k_proj", dtype=cfg.dtype)(x)
-            v = _dense((nh, hd), ("embed", "heads", "kv"), "v_proj", dtype=cfg.dtype)(x)
+            q = proj((nh, hd), name="q_proj")(x)
+            k = proj((nh, hd), name="k_proj")(x)
+            v = proj((nh, hd), name="v_proj")(x)
             q, k, v = (checkpoint_name(t, "qkv_out") for t in (q, k, v))
+        if cfg.qk_norm:
+            q = _qk_norm(cfg, "q_norm")(q)
+            k = _qk_norm(cfg, "k_norm")(k)
+        if rope is not None:
+            # before the cache write: a cached key is rotated once, at the
+            # position it was written for, and the kernels see plain keys
+            q, k = apply_rope(q, rope), apply_rope(k, rope)
 
         causal = True
         if decode:
@@ -330,7 +386,8 @@ class SelfAttention(nn.Module):
 
     def _out_proj(self, out):
         cfg = self.cfg
-        out = attn_out_dense(cfg.hidden_size, cfg.dtype)(out)
+        out = attn_out_dense(cfg.hidden_size, cfg.dtype,
+                             use_bias=cfg.use_bias)(out)
         return checkpoint_name(out, "attn_out")
 
     def _update_cache(self, k, v, attn_mask, cache_positions=None,
@@ -666,16 +723,23 @@ class SelfAttention(nn.Module):
 
 class MLP(nn.Module):
     """FFN: column-parallel up (embed→mlp), gelu, row-parallel down
-    (mlp→embed) — reference linear1/linear2 (hybrid_model.py:546-563)."""
+    (mlp→embed) — reference linear1/linear2 (hybrid_model.py:546-563).
+    ``mlp_act: swiglu`` is the gated form: ``down(silu(gate(x)) * up(x))``."""
 
     cfg: GPTConfig
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        x = _dense(cfg.ffn_size, ("embed", "mlp"), "up_proj", dtype=cfg.dtype)(x)
-        x = checkpoint_name(nn.gelu(x, approximate=True), "ffn_gelu")
-        x = _dense(cfg.hidden_size, ("mlp", "embed"), "down_proj", dtype=cfg.dtype)(x)
+        dense = functools.partial(_dense, use_bias=cfg.use_bias,
+                                  dtype=cfg.dtype)
+        up = dense(cfg.ffn_size, ("embed", "mlp"), "up_proj")
+        if cfg.mlp_act == "swiglu":
+            gate = dense(cfg.ffn_size, ("embed", "mlp"), "gate_proj")(x)
+            x = checkpoint_name(nn.silu(gate) * up(x), "ffn_gelu")
+        else:
+            x = checkpoint_name(nn.gelu(up(x), approximate=True), "ffn_gelu")
+        x = dense(cfg.hidden_size, ("mlp", "embed"), "down_proj")(x)
         return checkpoint_name(x, "mlp_out")
 
 
@@ -687,9 +751,47 @@ def _dropout(cfg, name):
     return dropout_layer(cfg.hidden_dropout_prob, name, cfg.fast_dropout)
 
 
+def _qk_norm(cfg, name):
+    """RMSNorm over a whole projection ``[.., heads, head_dim]`` (both
+    axes reduced, one learned weight per element)."""
+    return nn.RMSNorm(
+        epsilon=cfg.norm_eps, dtype=cfg.dtype, param_dtype=jnp.float32,
+        reduction_axes=(-2, -1), feature_axes=(-2, -1),
+        scale_init=nn.with_logical_partitioning(
+            nn.initializers.ones_init(), ("heads", "kv")),
+        name=name)
+
+
+def rope_tables(position_ids, head_dim: int, theta: float):
+    """``(cos, sin)`` ``[b, s, head_dim/2]`` float32 of the rotary angles
+    ``position * theta**(-2i/head_dim)``."""
+    inv_freq = 1.0 / (theta ** (
+        jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    angles = position_ids.astype(jnp.float32)[..., None] * inv_freq
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def apply_rope(x, rope):
+    """Rotate ``x`` ``[b, s, heads, head_dim]``: the head's two halves
+    ``(x1, x2)`` become ``(x1 cos - x2 sin, x2 cos + x1 sin)``, in float32."""
+    cos, sin = (t[:, :, None, :] for t in rope)
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
 def _layer_norm(cfg, name):
+    # ERNIE and ViT call this with configurations of their own, which
+    # have no ``norm`` kind: LayerNorm at 1e-5, as ever
+    eps = getattr(cfg, "norm_eps", 1e-5)
+    if getattr(cfg, "norm", "layernorm") == "rmsnorm":
+        return nn.RMSNorm(
+            epsilon=eps, dtype=cfg.dtype, param_dtype=jnp.float32,
+            scale_init=nn.with_logical_partitioning(
+                nn.initializers.ones_init(), ("norm",)),
+            name=name)
     return nn.LayerNorm(
-        epsilon=1e-5,
+        epsilon=eps,
         dtype=cfg.dtype,
         param_dtype=jnp.float32,
         scale_init=nn.with_logical_partitioning(nn.initializers.ones_init(), ("norm",)),
@@ -706,7 +808,8 @@ class DecoderLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x, attn_mask=None, deterministic=True, decode=False,
-                 cache_positions=None, block_tables=None, layer_index=None):
+                 cache_positions=None, block_tables=None, rope=None,
+                 expert_stack=None, layer_index=None):
         cfg = self.cfg
         x = _constrain_act(x, cfg)
         residual = x
@@ -714,13 +817,19 @@ class DecoderLayer(nn.Module):
         y = SelfAttention(cfg, name="attn")(
             y, attn_mask, deterministic=deterministic, decode=decode,
             cache_positions=cache_positions, block_tables=block_tables,
-            layer_index=layer_index,
+            layer_index=layer_index, rope=rope,
         )
         y = _dropout(cfg, "attn_dropout")(y, deterministic=deterministic)
         x = residual + y
         residual = x
         y = _layer_norm(cfg, "norm2")(x)
-        if cfg.expert_mode:
+        if cfg.expert_mode and cfg.gate == "softmax_topk":
+            from fleetx_tpu.parallel.moe import DroplessMoEMLP
+
+            y = DroplessMoEMLP(cfg, name="moe_mlp")(
+                y, decode=decode, layer_index=layer_index,
+                expert_stack=expert_stack)
+        elif cfg.expert_mode:
             from fleetx_tpu.parallel.moe import MoEMLP
 
             y = MoEMLP(cfg, name="moe_mlp")(y)
@@ -747,10 +856,11 @@ class _ScanLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x, attn_mask, deterministic, decode,
-                 cache_positions=None, block_tables=None, layer_index=None):
+                 cache_positions=None, block_tables=None, rope=None,
+                 expert_stack=None, layer_index=None):
         x = DecoderLayer(self.cfg, name="layer")(
             x, attn_mask, deterministic, decode, cache_positions,
-            block_tables, layer_index
+            block_tables, rope, expert_stack, layer_index
         )
         return x, None
 
@@ -805,32 +915,57 @@ class GPTModel(nn.Module):
             (cfg.vocab_size, cfg.hidden_size),
             jnp.float32,
         )
-        pos_emb = self.param(
-            "position_embeddings",
-            nn.with_logical_partitioning(
-                nn.initializers.normal(cfg.initializer_range), (None, "embed")
-            ),
-            (cfg.max_position_embeddings, cfg.hidden_size),
-            jnp.float32,
-        )
+        rotary = cfg.position_embedding == "rope"
+        if not rotary:
+            pos_emb = self.param(
+                "position_embeddings",
+                nn.with_logical_partitioning(
+                    nn.initializers.normal(cfg.initializer_range), (None, "embed")
+                ),
+                (cfg.max_position_embeddings, cfg.hidden_size),
+                jnp.float32,
+            )
+        rope = None
         with jax.named_scope("embed"):
             if position_ids is None:
                 # decode callers must pass explicit position_ids per step
                 position_ids = jnp.arange(input_ids.shape[1])[None, :]
                 position_ids = jnp.broadcast_to(position_ids, input_ids.shape)
-            x = word_emb[input_ids] + pos_emb[position_ids]
+            if rotary:
+                # the angles once, for every layer (they ride the layer
+                # loop as a broadcast input)
+                rope = rope_tables(position_ids, cfg.head_dim, cfg.rope_theta)
+                x = word_emb[input_ids]
+            else:
+                x = word_emb[input_ids] + pos_emb[position_ids]
             x = x.astype(cfg.dtype)
         x = _constrain_act(x, cfg)
         x = _dropout(cfg, "embed_dropout")(x, deterministic=deterministic)
 
         x = self._decoder_stack(x, attn_mask, deterministic=deterministic,
                                 decode=decode, cache_positions=cache_positions,
-                                block_tables=block_tables)
+                                block_tables=block_tables, rope=rope)
         x = _layer_norm(cfg, "final_norm")(x)
         return _constrain_act(x, cfg)
 
+    def _expert_stack(self):
+        """The three expert weights of ALL layers as the layer loop holds
+        them, ``[layers, experts, in, out]``, for a softmax top-k expert
+        layer in a cached forward (None otherwise): handed to every layer
+        next to its own slice, which it then leaves alone. The layer's
+        Mosaic kernels pick the layer and the experts that have rows in
+        their index maps, where the loop's slice of a 268 MB matrix would
+        first be copied (ops/pallas/moe_gmm.py)."""
+        cfg = self.cfg
+        if (not cfg.expert_mode or cfg.gate != "softmax_topk"
+                or self.is_initializing()):
+            return None
+        held = nn.meta.unbox(
+            self.variables["params"]["layers"]["layer"]["moe_mlp"])
+        return tuple(held[k] for k in ("w_gate", "w_up", "w_down"))
+
     def _decoder_stack(self, x, attn_mask, *, deterministic, decode,
-                       cache_positions=None, block_tables=None):
+                       cache_positions=None, block_tables=None, rope=None):
         cfg = self.cfg
         policy = _remat_policy(cfg)
         selective = cfg.no_recompute_layers
@@ -869,17 +1004,20 @@ class GPTModel(nn.Module):
             # program to write a few rows (PERF.md, PR 24).
             carried = (decode and cfg.decode_num_pages is not None
                        and block_tables is not None)
+            expert_stack = self._expert_stack() if carried else None
+            # ``rope`` and ``expert_stack`` are None (empty trees: no
+            # input) for the GPT-2 block
             args = (x, attn_mask, deterministic, decode, cache_positions,
-                    block_tables)
+                    block_tables, rope, expert_stack)
             if carried:  # each layer's index into the carried stack
                 args += (jnp.arange(cfg.num_layers, dtype=jnp.int32),)
             stack = nn.scan(
                 layer_cls,
-                variable_axes={"params": 0, "intermediates": 0,
+                variable_axes={"params": 0, "intermediates": 0, "routing": 0,
                                **({} if carried else {"cache": 0})},
                 variable_carry="cache" if carried else False,
                 split_rngs={"params": True, "dropout": True},
-                in_axes=(nn.broadcast,) * 5 + ((0,) if carried else ()),
+                in_axes=(nn.broadcast,) * 7 + ((0,) if carried else ()),
                 length=cfg.num_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )
@@ -896,7 +1034,7 @@ class GPTModel(nn.Module):
                 )
             x = layer_cls(cfg, name=f"layer_{i}")(
                 x, attn_mask, deterministic, decode, cache_positions,
-                block_tables
+                block_tables, rope
             )
         return x
 
@@ -923,8 +1061,17 @@ class GPTForPretraining(nn.Module):
             cache_positions=cache_positions,
             block_tables=block_tables,
         )
-        word_emb = backbone.variables["params"]["word_embeddings"]
-        emb = word_emb.value if isinstance(word_emb, nn.Partitioned) else word_emb
+        if self.cfg.tie_word_embeddings:
+            word_emb = backbone.variables["params"]["word_embeddings"]
+            emb = (word_emb.value if isinstance(word_emb, nn.Partitioned)
+                   else word_emb)
+        else:  # an output head of its own, laid out as the embedding is
+            emb = self.param(
+                "lm_head",
+                nn.with_logical_partitioning(
+                    nn.initializers.normal(self.cfg.initializer_range),
+                    ("vocab", "embed")),
+                (self.cfg.vocab_size, self.cfg.hidden_size), jnp.float32)
         if labels is not None and self.cfg.fused_ce:
             # blockwise fused LM-head + CE: returns PER-TOKEN loss [b, s]
             # (callers apply loss_mask); the [b, s, vocab] logits never

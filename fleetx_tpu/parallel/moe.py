@@ -17,6 +17,14 @@ Gates:
             (random routing, gshard_gate.py:29-73)
 - switch  — top-1, capacity, jitter noise, switch balance loss
             (switch_gate.py:29)
+
+``gate: softmax_topk`` is another layer, :class:`DroplessMoEMLP` (the
+OLMoE / Mixtral kind): softmax over all experts in float32, the ``top_k``
+largest kept, NO capacity and no dropped token, gated-SiLU experts
+without biases. Its tokens are sorted by expert and the three expert
+matmuls are grouped matmuls over the sorted rows: at 64 experts and 8 a
+token a dense ``[n, E, C]`` dispatch is seven eighths zeros in prefill and
+reads every expert for one token in decode.
 """
 
 from __future__ import annotations
@@ -29,7 +37,8 @@ from flax import linen as nn
 
 from fleetx_tpu.models.gpt import model as gpt_model
 
-__all__ = ["MoEMLP", "compute_routing", "compute_routing_indices"]
+__all__ = ["DroplessMoEMLP", "MoEMLP", "compute_routing",
+           "compute_routing_indices", "expert_row_layout"]
 
 
 def _balance_loss(gate_probs: jax.Array, expert_mask: jax.Array) -> jax.Array:
@@ -239,3 +248,165 @@ class MoEMLP(nn.Module):
             "ech,nec->nh", expert_out, combine.astype(dt)
         )
         return out.reshape(b, s, h)
+
+
+# per layer, in the decode cache's ``moe_stats`` leaf: for one-token calls
+# (a decode tick) and for longer ones (a prefill) the calls, the
+# token-expert pairs routed, the experts that had a row, and the rows of
+# the largest expert, each summed over the calls as a count of two uint32
+# words, low then high (serving/model_protocol.py reads them)
+MOE_STATS = ("calls", "pairs", "experts_read", "largest_load")
+
+
+def expert_row_layout(topk_idx: jax.Array, num_experts: int, tm: int):
+    """Where each (token, slot) pair goes when rows are sorted by expert
+    and every expert's group is padded to whole ``tm``-row tiles (``tm`` 1:
+    the plain sorted order ``jax.lax.ragged_dot`` takes).
+
+    ``topk_idx`` ``[n, k]``. Returns ``(dest, src, sizes, tile_expert,
+    num_tiles)``: ``dest`` ``[n*k]`` the row of each pair, ``src``
+    ``[rows]`` the token each row holds (padding rows hold token 0),
+    ``sizes`` ``[E]`` the pairs of each expert, ``tile_expert`` ``[rows //
+    tm]`` and ``num_tiles`` as ``ops/pallas/moe_gmm.py`` takes them.
+    ``rows`` is static: ``n*k + E*(tm-1)`` rounded up to a tile, plus one
+    spare tile when ``tm > 1``. No sort: a pair's rank inside its expert
+    is a running count."""
+    n, k = topk_idx.shape
+    m = n * k
+    flat = topk_idx.reshape(m).astype(jnp.int32)
+    onehot = (flat[:, None] == jnp.arange(num_experts, dtype=jnp.int32)
+              ).astype(jnp.int32)                           # [m, E]
+    sizes = onehot.sum(axis=0)
+    rank = jnp.take_along_axis(jnp.cumsum(onehot, axis=0), flat[:, None],
+                               axis=1)[:, 0] - 1
+    padded = (sizes + tm - 1) // tm * tm
+    ends = jnp.cumsum(padded)
+    dest = (ends - padded)[flat] + rank
+    rows = m if tm == 1 else (-(-(m + num_experts * (tm - 1)) // tm) + 1) * tm
+    src = jnp.zeros((rows,), jnp.int32).at[dest].set(
+        jnp.arange(m, dtype=jnp.int32) // k, unique_indices=True)
+    tiles = rows // tm
+    num_tiles = ends[-1] // tm
+    first_row = jnp.arange(tiles, dtype=jnp.int32) * tm
+    tile_expert = jnp.minimum(
+        (ends[None, :] <= first_row[:, None]).sum(axis=1), num_experts - 1)
+    return dest, src, sizes, tile_expert.astype(jnp.int32), num_tiles
+
+
+class DroplessMoEMLP(nn.Module):
+    """Softmax top-k experts without capacity (module docstring):
+    ``y = sum over the top_k chosen e of p_e * down_e(silu(gate_e(x)) *
+    up_e(x))``, ``p = softmax(router(x))`` over all experts in float32, the
+    weights left as they are unless ``cfg.norm_topk_prob``.
+
+    Handed ``expert_stack`` in a cached forward on a TPU, the grouped
+    matmuls are the Pallas kernels of ``ops/pallas/moe_gmm.py`` (no
+    gradient); everywhere else ``jax.lax.ragged_dot``. Scopes ``moe_route``
+    and ``moe_experts`` mark the two halves on the device trace
+    (docs/OBSERVABILITY.md).
+
+    ``expert_stack`` is the three expert weights of ALL layers as the
+    layer loop holds them, ``[layers, experts, in, out]``, with
+    ``layer_index`` this layer's place in them
+    (``GPTModel._expert_stack``): the kernels pick the layer themselves,
+    where the loop's own slice would be copied first, so they take the
+    stack and nothing else."""
+
+    cfg: "gpt_model.GPTConfig"
+
+    @nn.compact
+    def __call__(self, x: jax.Array, *, decode: bool = False,
+                 layer_index=None, expert_stack=None) -> jax.Array:
+        cfg = self.cfg
+        b, s, h = x.shape
+        E, k, f, n, dt = cfg.num_experts, cfg.top_k, cfg.ffn_size, b * s, cfg.dtype
+        router = nn.DenseGeneral(
+            features=E, use_bias=False, dtype=jnp.float32,
+            param_dtype=jnp.float32, precision=jax.lax.Precision.HIGHEST,
+            kernel_init=nn.with_logical_partitioning(
+                gpt_model.default_kernel_init, ("embed", None)),
+            name="router")
+
+        def experts(name, shape, axes):
+            return self.param(
+                name, nn.with_logical_partitioning(
+                    gpt_model.default_kernel_init, axes), shape, jnp.float32)
+
+        w_gate = experts("w_gate", (E, h, f), ("expert", "embed", "mlp"))
+        w_up = experts("w_up", (E, h, f), ("expert", "embed", "mlp"))
+        w_down = experts("w_down", (E, f, h), ("expert", "mlp", "embed"))
+
+        from fleetx_tpu.ops.pallas import moe_gmm
+        from fleetx_tpu.ops.pallas.flash_attention import kernels_enabled
+
+        kernel = (decode and expert_stack is not None
+                  and cfg.use_flash_attention and kernels_enabled())
+        tm = moe_gmm.row_tile(n * k, E) if kernel else 1
+        tokens = x.reshape(n, h)
+        with jax.named_scope("moe_route"):
+            probs = jax.nn.softmax(router(tokens.astype(jnp.float32)), axis=-1)
+            weights, topk_idx = jax.lax.top_k(probs, k)
+            if cfg.norm_topk_prob:
+                weights = weights / weights.sum(axis=-1, keepdims=True)
+            dest, src, sizes, tile_expert, num_tiles = expert_row_layout(
+                topk_idx, E, tm)
+            rows = tokens.astype(dt)[src]
+        if self.is_mutable_collection("intermediates"):
+            self.sow("intermediates", "balance_loss", _balance_loss(
+                probs, jax.nn.one_hot(topk_idx, E).sum(axis=1) / k))
+        # what the layer saw, chose and gave, for whoever asks (collection
+        # ``routing``: the benchmark's reference check holds the layer to
+        # its reference on the input it really had)
+        probed = (self.is_mutable_collection("routing")
+                  and not self.is_initializing())
+        if probed:
+            self.sow("routing", "input", x)
+            self.sow("routing", "experts", topk_idx.reshape(b, s, k))
+            self.sow("routing", "weights", weights.reshape(b, s, k))
+        self._count(sizes, n * k, s, decode, layer_index)
+        with jax.named_scope("moe_experts"):
+            if kernel:
+                w_gate, w_up, w_down = (w.astype(dt) for w in expert_stack)
+                act = moe_gmm.grouped_gate_up(
+                    rows, w_gate, w_up, tile_expert, num_tiles, tm=tm,
+                    layer=layer_index)
+                out = moe_gmm.grouped_down(act, w_down, tile_expert,
+                                           num_tiles, tm=tm, layer=layer_index)
+            else:
+                w_gate, w_up, w_down = (w.astype(dt)
+                                        for w in (w_gate, w_up, w_down))
+                act = (jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes))
+                       * jax.lax.ragged_dot(rows, w_up, sizes))
+                out = jax.lax.ragged_dot(act, w_down, sizes)
+        with jax.named_scope("moe_route"):
+            picked = out[dest].reshape(n, k, h).astype(jnp.float32)
+            y = (picked * weights[..., None]).sum(axis=1)
+        y = y.astype(dt).reshape(b, s, h)
+        if probed:
+            self.sow("routing", "output", y)
+        return y
+
+    def _count(self, sizes, pairs: int, seq: int, decode: bool, layer_index):
+        """Add this call to the ``moe_stats`` leaf of the decode cache (the
+        engine carries that tree from program to program and fetches none
+        of it: ``ServingMetrics.snapshot()`` does, through the executor)."""
+        if not decode:
+            return
+        fresh = not self.has_variable("cache", "moe_stats")
+        stats = self.variable("cache", "moe_stats", jnp.zeros,
+                              (2 * len(MOE_STATS) * 2,), jnp.uint32)
+        if fresh:
+            return
+        with jax.named_scope("moe_route"):
+            add = jnp.stack([jnp.uint32(1), jnp.uint32(pairs),
+                             (sizes > 0).sum().astype(jnp.uint32),
+                             sizes.max().astype(jnp.uint32)])
+            low = 2 * ((0 if seq == 1 else len(MOE_STATS))
+                       + jnp.arange(len(MOE_STATS)))
+            # the layer scan carries the whole stack [L, 16]
+            at = (low,) if layer_index is None else (layer_index, low)
+            up = (low + 1,) if layer_index is None else (layer_index, low + 1)
+            was = stats.value[at]
+            now = was + add                      # wraps at 2**32 ...
+            stats.value = stats.value.at[at].set(now).at[up].add(
+                (now < was).astype(jnp.uint32))  # ... into the high word

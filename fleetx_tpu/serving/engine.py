@@ -239,7 +239,7 @@ from fleetx_tpu.models.gpt.generation import (
     GenerationConfig,
     _top_p_cutoff_bisect,
 )
-from fleetx_tpu.serving.model_protocol import GPTExecutor
+from fleetx_tpu.serving.model_protocol import GPTExecutor, device_counters_of
 from fleetx_tpu.serving.cache_manager import (
     KV_LEAF_RANK,
     DiskPageStore,
@@ -416,12 +416,7 @@ class ServingEngine:
         self.capabilities = (executor.capabilities if executor is not None
                              else GPTExecutor(model).capabilities)
         self.model_family = self.capabilities.family
-        if not self.capabilities.has_kv_cache:
-            raise ValueError(
-                f"model family {self.model_family!r} has no KV cache "
-                "(capabilities.has_kv_cache=False); serve it behind a "
-                "KV-free engine (serving/batch_engine.py), not "
-                "ServingEngine")
+        self.capabilities.require(has_kv_cache=True)
         if gen_cfg.repetition_penalty != 1.0:
             raise ValueError("continuous batching does not support "
                              "repetition_penalty (use one-shot generate())")
@@ -504,6 +499,10 @@ class ServingEngine:
             kv_dtype, "FLEETX_SERVING_KV_DTYPE")
         self.weight_dtype = resolve_serving_dtype(
             weight_dtype, "FLEETX_SERVING_WEIGHT_DTYPE")
+        self.capabilities.require(
+            supports_int8_kv=self.kv_dtype == "int8",
+            supports_int8_weights=self.weight_dtype == "int8",
+            supports_mesh=mesh is not None)
         decode_kv = "int8" if self.kv_dtype == "int8" else None
         if self.paged:
             # default pool = the slot cache's capacity in pages + the
@@ -641,6 +640,9 @@ class ServingEngine:
         self.metrics = metrics or ServingMetrics(self.slots)
         self.metrics.set_role(self.role)
         self._publish_quant_metrics()
+        # what the model's programs counted on the device (an expert
+        # model's routing): fetched by snapshot() alone, never by a tick
+        self.metrics.device_counters = device_counters_of(self)
         self._base_key = jax.random.PRNGKey(base_seed)
         self._next_id = 0
         self._ticks = 0
@@ -695,10 +697,7 @@ class ServingEngine:
         self.spec_k = (spec_k if spec_k is not None
                        else _env_int("FLEETX_SERVING_SPEC_K", 4))
         self._proposer = None
-        if self.spec and not self.capabilities.supports_spec:
-            raise ValueError(
-                f"model family {self.model_family!r} does not support "
-                "speculative decoding (capabilities.supports_spec=False)")
+        self.capabilities.require(supports_spec=self.spec)
         if self.spec:
             if self.spec_k < 1:
                 raise ValueError(

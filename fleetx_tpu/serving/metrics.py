@@ -58,6 +58,8 @@ class ServingMetrics:
                  registry: Optional[MetricsRegistry] = None):
         reg = registry or get_registry()
         self.registry = reg
+        # set by the engine: ``() -> dict`` of its executor's counters
+        self.device_counters = None
         self.engine_label = str(next(self._labels))
         lab = {"engine": self.engine_label}
         # (family, labels) of every series this instance creates; a
@@ -688,9 +690,11 @@ class ServingMetrics:
         return self._h_pages_per_req.reservoir
 
     # ------------------------------------------------------------ snapshot
-    def snapshot(self) -> Dict:
+    def snapshot(self, device: bool = True) -> Dict:
         """Aggregate view: counters, queue/occupancy stats, TTFT
-        percentiles, decode tokens/s."""
+        percentiles, decode tokens/s, and (``device``) what the model's
+        programs counted on the device: a blocking fetch, so the log line
+        a tick writes (:meth:`log_snapshot`) leaves it out."""
         span = None
         if self._first_token_t is not None and self._last_token_t is not None:
             span = self._last_token_t - self._first_token_t
@@ -789,13 +793,17 @@ class ServingMetrics:
             "drain_rejects": self.drain_rejects,
             "tick_ms_p50": _ms(tick_p50),
             "tick_ms_p99": _ms(tick_p99),
+            # what the model's programs counted on the device (an expert
+            # model's routing: ``moe_*``), fetched here and nowhere else
+            **(self.device_counters() if device and self.device_counters
+               else {}),
         }
 
     def log_snapshot(self) -> None:
         """One structured log line through the framework logger."""
         from fleetx_tpu.utils.log import logger
 
-        s = self.snapshot()
+        s = self.snapshot(device=False)
         logger.info(
             "serving: queue=%d active=%d/%d retired=%d/%d rejected=%d "
             "timeouts=%d cancels=%d tokens=%d "

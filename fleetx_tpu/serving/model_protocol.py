@@ -39,13 +39,18 @@ scrape-don't-import discipline as the ``role`` field
 from __future__ import annotations
 
 import dataclasses
+import weakref
 from typing import Optional
+
+import jax
+import numpy as np
 
 __all__ = [
     "ENGINE_SURFACE",
     "GPTExecutor",
     "ModelCapabilities",
     "ModelExecutor",
+    "device_counters_of",
     "engine_conforms",
 ]
 
@@ -79,10 +84,37 @@ class ModelCapabilities:
     #: ``/v1/embeddings`` eligibility on this, not on KV-freeness —
     #: ERNIE is KV-free but token-out
     emits: str = "tokens"
+    #: int8 weights (weight-only PTQ), an int8 KV cache and a serving mesh
+    #: are each legal only where a test covers them for this model; the
+    #: engine refuses the others at construction
+    supports_int8_weights: bool = True
+    supports_int8_kv: bool = True
+    supports_mesh: bool = True
 
     def as_dict(self) -> dict:
         """JSON-ready form for the ``/healthz`` report."""
         return dataclasses.asdict(self)
+
+    def require(self, **asked) -> None:
+        """The construction-time gate: ``asked`` maps a flag of this
+        class to whether the engine was asked for that feature; the first
+        one asked for and not supported raises ``ValueError`` with the
+        family and the flag in it."""
+        for flag, wanted in asked.items():
+            if wanted and not getattr(self, flag):
+                raise ValueError(
+                    f"model family {self.family!r} does not support "
+                    f"{_FEATURES[flag]} (capabilities.{flag}=False)")
+
+
+_FEATURES = {
+    "has_kv_cache": "a KV cache: serve it behind a KV-free engine "
+                    "(serving/batch_engine.py), not ServingEngine",
+    "supports_spec": "speculative decoding",
+    "supports_int8_weights": "int8 weights: no test covers them",
+    "supports_int8_kv": "an int8 KV cache: no test covers it",
+    "supports_mesh": "a serving mesh: no test covers it",
+}
 
 
 class ModelExecutor:
@@ -136,27 +168,81 @@ class ModelExecutor:
         needs the filtered distribution, not a draw)."""
         raise NotImplementedError
 
+    def counters(self, cache) -> dict:
+        """Numbers the model's programs accumulated ON THE DEVICE inside
+        the cache tree they carry (an expert model's routing counts), as
+        a flat dict for ``ServingMetrics.snapshot()``. This is the one
+        place they are fetched: no tick reads them. Empty by default."""
+        return {}
+
 
 class GPTExecutor(ModelExecutor):
-    """The GPT decode path behind the protocol — pure delegation.
+    """The decoder stack of ``models/gpt/model.py`` behind the protocol —
+    pure delegation: every block ``GPTConfig`` describes (the GPT-2 block,
+    the rotary / RMSNorm / gated block, softmax top-k experts) is served
+    by the same cached forward, under the family name its configuration
+    gives (``cfg.family``: "gpt", "olmoe").
 
     Every method forwards to the exact function the engine called
     before the extraction, with the model closed over; tracing under
     ``jit`` produces identical programs, which is what keeps the
-    byte-parity suites green unchanged."""
+    byte-parity suites green unchanged.
 
-    def __init__(self, model, family: str = "gpt"):
+    The capability flags say what was really tried: over an expert layer
+    no test covers speculation (a verify call routes several tokens of a
+    lane at once), int8 weights (``ops/quant.py`` knows no expert axis),
+    an int8 cache or a mesh (the grouped-matmul kernels are not sharded),
+    so an engine asked for one of those over experts refuses at
+    construction."""
+
+    def __init__(self, model, family: Optional[str] = None):
         self.model = model
+        dense = not getattr(model.cfg, "expert_mode", False)
         self.capabilities = ModelCapabilities(
-            family=family,
+            family=family or getattr(model.cfg, "family", "gpt"),
             has_kv_cache=True,
-            supports_spec=True,
+            supports_spec=dense,
             cache_layout="slot+paged",
             max_input=int(model.cfg.max_position_embeddings),
+            supports_int8_weights=dense,
+            supports_int8_kv=dense,
+            supports_mesh=dense,
         )
 
     def bind(self, model):
         return GPTExecutor(model, family=self.capabilities.family)
+
+    def counters(self, cache) -> dict:
+        """The routing counts of ``parallel/moe.py`` ``DroplessMoEMLP``
+        (its ``moe_stats`` cache leaves, per layer :data:`MOE_STATS` as
+        two-word counts): per layer and per program the experts that had
+        a row and the largest-over-mean expert load, for one-token
+        programs (ticks) and longer ones (prefills), and the token-expert
+        pairs routed in all."""
+        from fleetx_tpu.parallel.moe import MOE_STATS
+
+        leaves = [leaf for path, leaf in
+                  jax.tree_util.tree_flatten_with_path(cache)[0]
+                  if "moe_stats" in jax.tree_util.keystr(path)]
+        if not leaves:
+            return {}
+        words = np.concatenate([np.asarray(leaf).astype(np.uint64).reshape(
+            -1, 2, len(MOE_STATS), 2) for leaf in leaves])
+        per_layer = words[..., 0] + (words[..., 1] << np.uint64(32))
+        stats = per_layer.sum(axis=0)            # [ticks | prefills, stat]
+        experts = int(self.model.cfg.num_experts)
+        out = {"moe_layers": len(per_layer),
+               "moe_pairs_routed": int(stats[:, 1].sum())}
+        for kind, (calls, pairs, read, largest) in zip(("tick", "prefill"),
+                                                       stats.tolist()):
+            out[f"moe_{kind}_layer_calls"] = calls
+            out[f"moe_{kind}_pairs"] = pairs
+            out[f"moe_{kind}_experts_read"] = read / calls if calls else 0.0
+            # the largest expert's rows over the mean expert's, over all
+            # the calls (each weighted by the pairs it routed)
+            out[f"moe_{kind}_load_max_over_mean"] = (
+                largest * experts / pairs if pairs else 0.0)
+        return out
 
     def init_cache(self, batch: int):
         from fleetx_tpu.models.gpt.generation import init_decode_cache
@@ -188,6 +274,34 @@ class GPTExecutor(ModelExecutor):
 
         return filter_logits(logits, temperature, top_k, top_p,
                              topk_cap=topk_cap)
+
+
+def device_counters_of(engine):
+    """``() -> dict`` for ``ServingMetrics.device_counters``: what the
+    engine's executor reads from the cache tree its programs carry
+    (:meth:`ModelExecutor.counters`). Called by ``snapshot()`` alone, so a
+    tick fetches nothing; holds the engine weakly."""
+    from fleetx_tpu.obs.events import emit as obs_emit
+
+    ref = weakref.ref(engine)
+
+    def read() -> dict:
+        eng = ref()
+        if eng is None:
+            return {}
+        try:
+            return eng.executor.counters(eng.cache_manager.cache)
+        except RuntimeError as err:
+            # a scrape from another thread met a cache buffer the running
+            # tick had just donated: nothing to report now, and the event
+            # log says it happened
+            if "deleted" not in str(err):
+                raise
+            obs_emit("serving_device_counters_missed",
+                     engine=eng.metrics.engine_label)
+            return {}
+
+    return read
 
 
 #: The engine-side contract: every serving engine kind — autoregressive
