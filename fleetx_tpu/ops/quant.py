@@ -67,10 +67,13 @@ def resolve_serving_dtype(value, env_var, label=None) -> str:
 def serving_weight_params(params, weight_dtype: str):
     """Apply the serving weight-only PTQ: at ``"int8"`` the tree becomes
     int8 + per-channel scales (idempotent — pre-quantized artifacts pass
-    through). At ``"bf16"`` a float tree passes through untouched, but a
-    tree that already carries ``{"_q8", "_scale"}`` leaves RAISES — the
-    bf16 path has no dequant seam, so serving it would crash deep inside
-    the first traced ``model.apply`` instead of here with a cause."""
+    through). At ``"bf16"`` a float tree passes through THIS function as
+    it is (``ServingEngine`` then hands it to its executor's
+    ``resident_params``, which converts what the model computes in
+    ``cfg.dtype``), but a tree that already carries ``{"_q8", "_scale"}``
+    leaves RAISES — the bf16 path has no dequant seam, so serving it would
+    crash deep inside the first traced ``model.apply`` instead of here
+    with a cause."""
     if weight_dtype == "int8":
         return quantize_tree_int8(params)
     if any(_is_qdict(leaf)

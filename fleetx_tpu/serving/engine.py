@@ -106,6 +106,10 @@ Replay recovery re-prefills through the same jitted seams, so crash
 safety is precision-agnostic. Both knobs default off ("bf16" = the model
 compute dtype), and the default path stays byte-identical; quantized
 configs trade byte parity for a documented token/logit tolerance.
+At ``"bf16"`` the layers' weights are RESIDENT in the compute dtype: what
+the model would convert whole to ``cfg.dtype`` in every program is
+converted once at construction (``executor.resident_params``) and no
+float32 copy of it is kept. Same bits (tests/test_resident_weights.py).
 
 Speculative decoding (``FLEETX_SERVING_SPEC=1``, default off;
 docs/SERVING.md "Speculative decoding"): each tick a proposer
@@ -538,14 +542,16 @@ class ServingEngine:
         self.params = (variables["params"]
                        if isinstance(variables, dict) and "params" in variables
                        else variables)
-        # weight-only PTQ once, up front (no-op at bf16): servable params
-        # live in HBM as int8 + per-channel scales; every jitted prefill/
-        # decode call dequantizes INSIDE the jit (_dequant_params), so
-        # XLA fuses the scale multiply into the matmul consumers.
-        # Idempotent for pre-quantized trees (InferenceEngine).
+        # the servable tree, once, up front. int8: weight-only PTQ, params
+        # live in HBM as int8 + per-channel scales and every jitted prefill/
+        # decode call dequantizes INSIDE the jit (_dequant_params), so XLA
+        # fuses the scale multiply into the matmul consumers; idempotent for
+        # pre-quantized trees (InferenceEngine). bf16: the executor converts
+        # what the model would convert in every program (module docstring).
         from fleetx_tpu.ops.quant import serving_weight_params
 
-        self.params = serving_weight_params(self.params, self.weight_dtype)
+        self.params = self.executor.resident_params(
+            serving_weight_params(self.params, self.weight_dtype))
         if self.mesh is not None:
             # TP(mp)/FSDP-shard the (possibly quantized) servable tree:
             # committed NamedSharding inputs drive GSPMD inside every jit
@@ -1802,10 +1808,10 @@ class ServingEngine:
 
     def _dequant_params(self, params):
         """Weight-only-int8 dequant seam, called INSIDE every jitted
-        prefill/decode body: a no-op at bf16; at int8 it re-expands the
-        {"_q8", "_scale"} leaves so XLA fuses the scale multiply into
-        each matmul consumer — HBM holds the int8 tree, the float view
-        is a fusion-local temporary."""
+        prefill/decode body: a no-op at bf16 (the resident tree is read as
+        it is); at int8 it re-expands the {"_q8", "_scale"} leaves so XLA
+        fuses the scale multiply into each matmul consumer — HBM holds the
+        int8 tree, the float view is a fusion-local temporary."""
         if self.weight_dtype != "int8":
             return params
         from fleetx_tpu.ops.quant import dequantize_tree_int8

@@ -143,6 +143,13 @@ class ModelExecutor:
         serving cache config, not the training one."""
         raise NotImplementedError
 
+    def resident_params(self, params):
+        """The tree the engine keeps on the device for its programs, from
+        the servable tree it was handed: what the model would convert in
+        every program, converted once. Called once, at construction,
+        before the tree is sharded. The tree as handed over by default."""
+        return params
+
     def init_cache(self, batch: int):
         """A fresh decode cache for ``batch`` lanes (None when
         ``capabilities.has_kv_cache`` is False)."""
@@ -243,6 +250,11 @@ class GPTExecutor(ModelExecutor):
             out[f"moe_{kind}_load_max_over_mean"] = (
                 largest * experts / pairs if pairs else 0.0)
         return out
+
+    def resident_params(self, params):
+        from fleetx_tpu.models.gpt.resident import resident_params
+
+        return resident_params(self.model.cfg, params)
 
     def init_cache(self, batch: int):
         from fleetx_tpu.models.gpt.generation import init_decode_cache
