@@ -259,9 +259,8 @@ def leg_serve(mp: int) -> dict:
     engine = ServingEngine(
         model, variables, slots=SLOTS,
         cache_len=model.cfg.max_position_embeddings, gen_cfg=gen_cfg,
-        paged=True, page_size=PAGE_SIZE, prefill_bucket=PREFILL_BUCKET,
-        mesh=mesh)
-    assert engine.paged and engine.page_size == PAGE_SIZE
+        page_size=PAGE_SIZE, prefill_bucket=PREFILL_BUCKET, mesh=mesh)
+    assert engine.page_size == PAGE_SIZE
 
     rng = np.random.RandomState(0)
     prompts = [rng.randint(0, VOCAB, p).astype(np.int32)
@@ -323,10 +322,10 @@ def _pool_stays_in_place(engine, tick) -> dict:
     cache = engine.cache_manager.cache
     pool = device_bytes(cache)
     weights_bf16 = device_bytes(engine.params, itemsize=2)
-    bucket = max(b for kind, b in engine._prefill_jits if kind == "paged")
+    bucket = max(engine._prefill_jits)
     i32 = lambda v: jnp.asarray(v, jnp.int32)  # noqa: E731
     with engine._mesh_context():
-        prefill = engine._prefill_jits[("paged", bucket)].lower(
+        prefill = engine._prefill_jits[bucket].lower(
             engine.params, cache, jnp.zeros((bucket,), jnp.int32),
             i32(bucket), i32(0), i32(engine.cache_manager.tables[0]),
             i32(-1), i32(0), jnp.asarray(True),

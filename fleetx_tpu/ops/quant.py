@@ -135,8 +135,8 @@ def quantize_kv(x: jax.Array):
     lane-dense — values ``[..., len, heads*head_dim]``, scales
     ``[..., len, heads]`` (models/gpt/model.py folds them on write) — so
     scale leaves share the K/V leaves' trailing rank and every tree
-    walker that addresses K/V by it (``serving.scatter_slot``, the paged
-    page scatter, block-spec index maps) handles scales unchanged.
+    walker that addresses K/V by it (``serving.spec.scatter_slot``, the
+    paged page scatter, block-spec index maps) handles scales unchanged.
     Per-vector granularity is what the flash-decode kernels stream: one
     scale per (row, head) factored out of the dot products
     (ops/pallas/decode_attention.py)."""

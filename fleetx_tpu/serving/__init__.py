@@ -7,18 +7,17 @@ tick they arrive (prefill-on-insert), every tick runs ONE jitted decode
 step over all lanes at their own depths, and finished requests free
 their lane immediately for the next queued request.
 
-K/V storage is PAGED by default: a shared ``[num_pages, page_size, ...]``
-pool with per-request block tables and a refcounted prefix trie, so
-cache capacity tracks live tokens (page-granular admission) and requests
-sharing a system prompt reuse one prefill (``FLEETX_SERVING_PAGED=0``
-restores the fixed per-slot cache).
+K/V storage is PAGED: a shared ``[num_pages, page_size, ...]`` pool with
+per-request block tables and a refcounted prefix trie, so cache capacity
+tracks live tokens (page-granular admission) and requests sharing a
+system prompt reuse one prefill.
 
     engine = ServingEngine(model, variables, slots=8)
     rid = engine.submit(prompt_ids, max_length=64)
     results = engine.drain()          # {rid: ServingResult}
 
-Layout: ``cache_manager`` (page pool + prefix trie + slot-compat cache,
-and the no-zeroing live-window safety argument), ``scheduler`` (FIFO
+Layout: ``cache_manager`` (page pool + prefix trie, and the no-zeroing
+live-window safety argument), ``scheduler`` (FIFO
 admission policy seam), ``engine`` (submit/step/drain loop + jitted
 prefill/decode), ``model_protocol`` (the model-agnostic serving
 contract: executor seam + capability flags + the router-facing engine
@@ -40,9 +39,7 @@ from fleetx_tpu.serving.cache_manager import (
     HostPageStore,
     PagedKVCacheManager,
     PagePool,
-    SlotKVCacheManager,
     TieredPageStore,
-    scatter_slot,
 )
 from fleetx_tpu.serving.embedding_engine import (
     EmbeddingEngine,
@@ -114,7 +111,6 @@ __all__ = [
     "HostPageStore",
     "PagePool",
     "PagedKVCacheManager",
-    "SlotKVCacheManager",
     "TieredPageStore",
     "FIFOScheduler",
     "Request",
@@ -136,7 +132,6 @@ __all__ = [
     "generate_trace",
     "run_trace",
     "sample_tokens",
-    "scatter_slot",
     "score_goodput",
     "trace_hash",
 ]

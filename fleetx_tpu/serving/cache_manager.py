@@ -1,22 +1,17 @@
-"""KV-cache managers for continuous-batching decode: paged + slot-based.
+"""The paged KV-cache manager for continuous-batching decode.
 
-Two storage strategies behind one engine:
+:class:`PagedKVCacheManager` is the serving engine's one storage layout:
+K/V live in ONE shared pool of lane-dense
+``[num_pages, page_size, heads*head_dim]`` pages (the layout the
+flash-decode kernels block, ops/pallas/decode_attention.py "Layout");
+each request holds a block table mapping its logical positions to
+physical pages (vLLM-style). Cache capacity and prefill compute track
+tokens actually live, not per-lane worst case: a short request pins
+pages for ITS tokens only, and requests sharing a token prefix share the
+prefix's pages through a refcounted trie (:class:`PagePool`) — one
+prefill serves them all.
 
-- :class:`PagedKVCacheManager` (the default): K/V live in ONE shared pool
-  of lane-dense ``[num_pages, page_size, heads*head_dim]`` pages (the
-  layout the flash-decode kernels block, ops/pallas/decode_attention.py
-  "Layout"); each request
-  holds a block table mapping its logical positions to physical pages
-  (vLLM-style). Cache capacity and prefill compute track tokens actually
-  live, not per-slot worst case: a short request pins pages for ITS
-  tokens only, and requests sharing a token prefix share the prefix's
-  pages through a refcounted trie (:class:`PagePool`) — one prefill
-  serves them all.
-- :class:`SlotKVCacheManager` (compat, ``paged=False`` /
-  ``FLEETX_SERVING_PAGED=0``): the original fixed ``[slots, cache_len]``
-  cache, one full-length lane per request.
-
-Both rely on the flash-decode live-window contract
+It relies on the flash-decode live-window contract
 (ops/pallas/decode_attention.py) to skip ALL buffer zeroing:
 
 - each row's attention window is ``[0, lengths[row] + 1)`` — the per-row
@@ -49,7 +44,7 @@ built by ``init_decode_cache`` then carries int8 K/V leaves plus fp32
 Nothing in this module special-cases them — the scale leaves share the
 K/V leaves' trailing-rank layout (``[..., lanes|pages, len, heads]``
 beside ``[..., lanes|pages, len, heads*head_dim]``: :data:`KV_LEAF_RANK`),
-so :func:`scatter_slot` slots them by the same rank rule and the page
+so every walker that addresses K/V by rank treats them alike and the page
 lifecycle (trash-page routing, no-zeroing, refcounts) is dtype-blind:
 a page's scales travel with its values because both are indexed by the
 same block table. :meth:`_LaneBook.cache_nbytes` measures the actual
@@ -85,8 +80,7 @@ import jax
 import numpy as np
 
 __all__ = ["DiskPageStore", "HostPageStore", "KV_LEAF_RANK", "PagePool",
-           "PagedKVCacheManager", "SlotKVCacheManager", "TieredPageStore",
-           "leaf_device_nbytes", "scatter_slot"]
+           "PagedKVCacheManager", "TieredPageStore", "leaf_device_nbytes"]
 
 # Trailing rank of every K/V (and int8 scale) cache leaf:
 # [batch | pages, positions, lanes] as SelfAttention._update_cache stores
@@ -569,29 +563,8 @@ class TieredPageStore:
         self.disk.check_invariants()
 
 
-def scatter_slot(cache, prefill_cache, slot):
-    """Write a 1-row prefill cache tree into row ``slot`` of the slot cache.
-
-    Pure function (used inside the engine's jitted prefill, ``slot`` may be
-    traced). K/V leaves carry a ``[..., batch, cache_len, lanes]`` suffix
-    (:data:`KV_LEAF_RANK`) — the batch axis sits at -3 for both the
-    scan-stacked ``[layers, batch, ...]`` and the unrolled nested layouts
-    — and are updated at that axis; lower-rank leaves (the ``cache_index``
-    scalars) are left untouched, since per-slot progress is tracked by
-    the manager."""
-
-    def put(big, small):
-        if big.ndim < KV_LEAF_RANK:
-            return big
-        starts = ((0,) * (big.ndim - KV_LEAF_RANK) + (slot,)
-                  + (0,) * (KV_LEAF_RANK - 1))
-        return jax.lax.dynamic_update_slice(big, small, starts)
-
-    return jax.tree.map(put, cache, prefill_cache)
-
-
 class _LaneBook:
-    """Decode-lane bookkeeping shared by both cache managers: a min-heap
+    """Decode-lane bookkeeping of the cache manager: a min-heap
     free list (lowest lane first, deterministic, O(log n) alloc/free —
     the original list re-sorted on every release), per-lane request ids,
     and the HOST mirror of per-lane live lengths (the device copy rides
@@ -642,41 +615,6 @@ class _LaneBook:
         (``fleetx_serving_kv_cache_bytes``)."""
         return sum(leaf_device_nbytes(leaf)
                    for leaf in jax.tree.leaves(self.cache))
-
-
-class SlotKVCacheManager(_LaneBook):
-    """Fixed-slot decode cache + slot bookkeeping (free list, tenants).
-
-    ``cache`` is the live device tree; the engine routes it through its
-    jitted prefill/decode functions and stores the result back here."""
-
-    def __init__(self, model, slots: int, cache_len: int):
-        from fleetx_tpu.models.gpt.generation import init_decode_cache
-
-        if (model.cfg.decode_cache_len or 0) != cache_len:
-            raise ValueError(
-                f"model.cfg.decode_cache_len ({model.cfg.decode_cache_len}) "
-                f"must equal the manager's cache_len ({cache_len})"
-            )
-        self._init_lanes(slots)
-        self.cache_len = cache_len
-        self.cache = init_decode_cache(model, slots)
-
-    def alloc(self, request_id: int, prompt_len: int) -> Optional[int]:
-        """Claim the lowest free slot for ``request_id`` (None when full)."""
-        if not self._free:
-            return None
-        if prompt_len > self.cache_len:
-            raise ValueError(
-                f"prompt_len {prompt_len} exceeds cache_len {self.cache_len}"
-            )
-        return self._claim_lane(request_id, prompt_len)
-
-    def free(self, slot: int) -> None:
-        """Release ``slot`` for the next queued request. No buffer zeroing:
-        the live-window contract (module docstring) keeps stale rows
-        invisible to the next tenant."""
-        self._release_lane(slot)
 
 
 class _TrieNode:
@@ -1138,12 +1076,11 @@ class PagePool:
 
 
 class PagedKVCacheManager(_LaneBook):
-    """Page-granular decode cache + lane bookkeeping (the paged sibling of
-    :class:`SlotKVCacheManager`; module docstring has the design).
+    """Page-granular decode cache + lane bookkeeping (module docstring
+    has the design).
 
-    Decode *lanes* (batch rows of the jitted step) are still allocated
-    lowest-free-first like slots — ``free_count``/``active_count`` keep
-    their slot-era meaning — but storage admission is by PAGES: a lane is
+    Decode *lanes* (batch rows of the jitted step) are allocated
+    lowest-free-first, but storage admission is by PAGES: a lane is
     only claimable when :class:`PagePool` can cover the prompt, and the
     chain grows page-by-page as the request decodes. ``cache`` is the live
     device tree of ``[num_pages, page_size, heads*head_dim]`` leaves;

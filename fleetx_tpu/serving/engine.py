@@ -1,4 +1,4 @@
-"""ServingEngine: continuous-batching decode over the slot kv-cache.
+"""ServingEngine: continuous-batching decode over the paged kv-cache.
 
 The runtime layer between "a stream of requests" and the single-step
 decode functions exposed by ``models/gpt/generation.py``:
@@ -13,17 +13,16 @@ decode functions exposed by ``models/gpt/generation.py``:
 - **drain()** ticks until queue and slots are empty and returns the
   finished :class:`ServingResult` records.
 
-Cache storage is PAGED by default (``FLEETX_SERVING_PAGED=0`` or
-``paged=False`` restores the fixed per-slot cache): K/V live in a shared
+Cache storage is PAGED, the engine's one layout: K/V live in a shared
 ``[num_pages, page_size, heads*head_dim]`` pool, each request holds a
 block table of page indices, and a refcounted prefix trie lets requests
 sharing a token prefix (system prompts) reuse one prefill — admission is
-then page-granular (the queue head admits when its PAGES fit, not when a
+page-granular (the queue head admits when its PAGES fit, not when a
 worst-case slot does), prefill runs only over the non-shared prompt
 suffix, and a request's chain grows page-by-page as it decodes
 (``finish_reason="cache_full"`` when the pool runs dry mid-flight). See
 ``cache_manager.py`` for the allocator/trie and the no-zeroing safety
-argument; both storage modes emit byte-identical greedy tokens.
+argument.
 
 Chunked prefill (``FLEETX_SERVING_PREFILL_CHUNK``, default off;
 docs/SERVING.md): whole-prompt prefill-on-insert makes decode TPOT
@@ -37,15 +36,14 @@ with the batched decode, so no decode tick ever stalls more than ~one
 chunk of prefill compute. Chunks reuse the bucketed prefill jits at
 chunk granularity — long prompts stop minting per-length buckets up to
 ``cache_len`` — writing through the same per-row ``cache_positions`` /
-page-scatter seams decode uses: paged chunks write straight into the
-lane's pages at absolute positions, slot chunks accumulate into a
-batch-1 working cache scattered into the slot on the final chunk. The
-final chunk samples the first token exactly where the one-call path
-would (same rng split discipline), so greedy tokens are BYTE-IDENTICAL
-to the unchunked engine, and chunk progress rides the transactional-tick
-snapshot: a mid-prefill fault rolls back, recovery requeues the request
-at the queue head (zero tokens emitted — byte-identity is structural)
-and the host-tier prefix cache below makes the re-prefill cheap.
+page-scatter seams decode uses: chunks write straight into the lane's
+pages at absolute positions. The final chunk samples the first token
+exactly where the one-call path would (same rng split discipline), so
+greedy tokens are BYTE-IDENTICAL to the unchunked engine, and chunk
+progress rides the transactional-tick snapshot: a mid-prefill fault
+rolls back, recovery requeues the request at the queue head (zero
+tokens emitted — byte-identity is structural) and the host-tier prefix
+cache below makes the re-prefill cheap.
 Deadlines are honored BETWEEN chunks: an expired request stops burning
 prefill compute and retires ``finish_reason="timeout"`` with its lane
 and pages freed (no partial-chunk leak — prefix registration only
@@ -94,7 +92,7 @@ row and their outputs discarded; a freed slot's stale K/V is never
 attended (see ``cache_manager.py``).
 
 Quantized serving (docs/QUANTIZATION.md): ``FLEETX_SERVING_KV_DTYPE=int8``
-stores decode K/V (slot cache or paged pool) as int8 with per-vector fp32
+stores decode K/V (the page pool) as int8 with per-vector fp32
 scales — quantize-on-write in ``SelfAttention._update_cache``, dequant in
 VMEM inside the flash-decode kernels — roughly halving the HBM bytes the
 bandwidth-bound decode tick moves (and the pages a cached token pins).
@@ -144,8 +142,8 @@ blueprint), so a model that does not fit — or does not hit latency
 targets — on one chip serves from a mesh. What shards: params (and
 quantized weight trees) get TP(mp)/FSDP shardings from the model's own
 logical-axis metadata via ``parallel/sharding.serving_param_shardings``,
-and BOTH cache layouts (slot and paged pools, int8 scale leaves
-included) split their heads axis over ``mp`` — per-device cache bytes
+and the page pool (int8 scale leaves included) splits its heads axis
+over ``mp`` — per-device cache bytes
 and ``cache_nbytes()`` divide by the mp extent, which is the capacity
 math a router prices replicas with. What replicates: the decode-lane
 state dict, block tables, and every scalar. Every jitted device call
@@ -249,9 +247,7 @@ from fleetx_tpu.serving.cache_manager import (
     DiskPageStore,
     HostPageStore,
     PagedKVCacheManager,
-    SlotKVCacheManager,
     TieredPageStore,
-    scatter_slot,
 )
 from fleetx_tpu.resilience.faults import faults
 from fleetx_tpu.serving.metrics import ServingMetrics
@@ -377,7 +373,8 @@ class ServingResult:
 
 
 class ServingEngine:
-    """Slot-based continuous-batching serving loop (module docstring)."""
+    """Continuous-batching serving loop over decode lanes and a page
+    pool (module docstring)."""
 
     def __init__(self, model, variables, *, slots: Optional[int] = None,
                  cache_len: Optional[int] = None,
@@ -462,8 +459,19 @@ class ServingEngine:
                     "router", shape["dp"], shape["dp"])
             self._rules = make_rules(fsdp_params=shape.get("fsdp", 1) > 1)
         self.slots = slots or _env_int("FLEETX_SERVING_SLOTS", 8)
-        self.paged = (paged if paged is not None
-                      else _env_int("FLEETX_SERVING_PAGED", 1) == 1)
+        # `paged` stays a keyword because perfbench/serving.py passes
+        # paged=True (ROADMAP D2: a `benchmark` PR drops it there, then
+        # the keyword goes); the slot layout it once chose was deleted at
+        # PR 29, and input from outside the program is refused, not ignored
+        if paged is not None and not paged:
+            raise ValueError(
+                "paged=False: the fixed per-slot cache layout was removed "
+                "at PR 29 (docs/MIGRATION.md); the page pool is the "
+                "engine's one layout — drop the argument")
+        # an attribute, not a switch: the router and replica server tell
+        # an engine with a page pool from a KV-free BatchingEngine
+        # (batch_engine.py, False) by it
+        self.paged = True
         self.page_size = page_size or _env_int("FLEETX_SERVING_PAGE_SIZE", 16)
         # phase-disaggregated serving (docs/SERVING.md "Disaggregated
         # prefill/decode"): a PREFILL-role replica runs admission and
@@ -477,22 +485,12 @@ class ServingEngine:
             raise ValueError(
                 f"role must be 'prefill', 'decode' or 'both', got "
                 f"{self.role!r}")
-        if self.role == "prefill" and not self.paged:
-            raise ValueError(
-                "role='prefill' requires the paged cache (paged=True): "
-                "export_kv() ships whole pages through the block table")
         cache_len = (cache_len
                      or _env_int("FLEETX_SERVING_CACHE_LEN", 0)
                      or model.cfg.max_position_embeddings)
-        if self.paged:
-            # per-request logical capacity rounds to whole pages (the page
-            # is also the flash-decode DMA tile, so this covers the 8-row
-            # rounding below)
-            cache_len += -cache_len % self.page_size
-        elif model.cfg.use_flash_attention:
-            # round up to the flash-decode kernel's 8-row KV tile so the
-            # fast path engages; the extra rows are never attended
-            cache_len += -cache_len % 8
+        # per-request logical capacity rounds to whole pages (the page
+        # is also the flash-decode DMA tile)
+        cache_len += -cache_len % self.page_size
         self.cache_len = cache_len
         # quantized serving (module docstring): kv int8 halves decode HBM
         # traffic + pages per cached token; weight int8 halves/quarters
@@ -508,29 +506,20 @@ class ServingEngine:
             supports_int8_weights=self.weight_dtype == "int8",
             supports_mesh=mesh is not None)
         decode_kv = "int8" if self.kv_dtype == "int8" else None
-        if self.paged:
-            # default pool = the slot cache's capacity in pages + the
-            # reserved trash page; short requests then leave pages free
-            # for extra concurrent tenants instead of padding dead slots
-            self.num_pages = (num_pages
-                              or _env_int("FLEETX_SERVING_PAGES", 0)
-                              or self.slots * (cache_len // self.page_size)
-                              + 1)
-            self.prefix_cache = (
-                prefix_cache if prefix_cache is not None
-                else _env_int("FLEETX_SERVING_PREFIX_CACHE", 1) == 1)
-            self.model = model.clone(cfg=dataclasses.replace(
-                model.cfg, decode_cache_len=cache_len,
-                decode_num_pages=self.num_pages,
-                decode_page_size=self.page_size,
-                decode_kv_dtype=decode_kv))
-        else:
-            self.num_pages = 0
-            self.prefix_cache = False
-            self.model = model.clone(cfg=dataclasses.replace(
-                model.cfg, decode_cache_len=cache_len,
-                decode_num_pages=None, decode_page_size=None,
-                decode_kv_dtype=decode_kv))
+        # default pool = every lane's full capacity in pages + the
+        # reserved trash page; short requests then leave pages free for
+        # extra concurrent tenants instead of padding dead lanes
+        self.num_pages = (num_pages
+                          or _env_int("FLEETX_SERVING_PAGES", 0)
+                          or self.slots * (cache_len // self.page_size) + 1)
+        self.prefix_cache = (
+            prefix_cache if prefix_cache is not None
+            else _env_int("FLEETX_SERVING_PREFIX_CACHE", 1) == 1)
+        self.model = model.clone(cfg=dataclasses.replace(
+            model.cfg, decode_cache_len=cache_len,
+            decode_num_pages=self.num_pages,
+            decode_page_size=self.page_size,
+            decode_kv_dtype=decode_kv))
         if self.executor is None:
             # wrap the decode-configured clone: init_cache/forward read
             # decode_cache_len/pages off cfg, so the executor must see
@@ -583,7 +572,7 @@ class ServingEngine:
                     else os.environ.get("FLEETX_SERVING_DISK_CACHE_DIR", ""))
         disk_bytes = (disk_cache_bytes if disk_cache_bytes is not None
                       else _env_int("FLEETX_SERVING_DISK_CACHE_BYTES", 0))
-        tiered = self.paged and self.prefix_cache
+        tiered = self.prefix_cache
         dram = HostPageStore(host_bytes) if host_bytes > 0 and tiered else None
         self._disk_store = (DiskPageStore(disk_dir, disk_bytes)
                             if disk_dir and disk_bytes > 0 and tiered
@@ -629,14 +618,10 @@ class ServingEngine:
         self._shutdown_event_pending = False
         self._prev_sigterm = None
         self._now = time.perf_counter  # swappable clock (chaos tests)
-        if self.paged:
-            self.cache_manager = PagedKVCacheManager(
-                self.model, self.slots, cache_len, self.num_pages,
-                self.page_size, prefix_cache=self.prefix_cache,
-                host_store=self._host_store)
-        else:
-            self.cache_manager = SlotKVCacheManager(self.model, self.slots,
-                                                    cache_len)
+        self.cache_manager = PagedKVCacheManager(
+            self.model, self.slots, cache_len, self.num_pages,
+            self.page_size, prefix_cache=self.prefix_cache,
+            host_store=self._host_store)
         # mesh: the freshly-built cache tree splits its heads over mp
         # (scale leaves ride the same rule); state/tables replicate
         self.cache_manager.cache = self._shard_cache(self.cache_manager.cache)
@@ -676,16 +661,7 @@ class ServingEngine:
         self._probe_jit = jax.jit(self._decode_fn, static_argnums=(4,))
         self._admit_jit = jax.jit(self._admit_fn, donate_argnums=())
         self._deactivate_jit = jax.jit(_deactivate)
-        # chunked slot prefill: fold the finished batch-1 working cache
-        # into the big slot cache (both operands are dead afterwards);
-        # the pin keeps the folded cache on its mesh layout
-
-        def _scatter_pinned(cache, small, slot):
-            return self._pin_cache(scatter_slot(cache, small, slot))
-
-        self._scatter_jit = jax.jit(
-            _scatter_pinned, donate_argnums=(0, 1) if donate else ())
-        self._prefill_jits = {}  # (kind, bucket_len) -> jitted prefill
+        self._prefill_jits = {}  # bucket_len -> jitted prefill
         self._donate_cache = donate
         # speculative decoding (module docstring): default OFF — a spec-
         # disabled engine never touches the proposer/verify machinery and
@@ -859,10 +835,6 @@ class ServingEngine:
                     "terminal; do not migrate it")
         decoded_pages = None
         if kv_payloads is not None:
-            if not self.paged:
-                raise ValueError(
-                    "kv_payloads requires the paged cache (paged=True): "
-                    "shipped KV revives into pages")
             if not hist:
                 raise ValueError(
                     "kv_payloads without history: the prefill replica "
@@ -971,9 +943,8 @@ class ServingEngine:
         with span("serving.observe"):
             self.metrics.observe_tick(self.scheduler.queue_depth,
                                       len(self._active), self._now() - t0)
-            if self.paged:
-                self.metrics.observe_pages(self.cache_manager.pages_in_use,
-                                           self.cache_manager.usable_pages)
+            self.metrics.observe_pages(self.cache_manager.pages_in_use,
+                                       self.cache_manager.usable_pages)
             if self._dram_store is not None:
                 self.metrics.observe_host_tier(self._dram_store)
             if self._disk_store is not None:
@@ -1065,12 +1036,12 @@ class ServingEngine:
         frees the lane, parking the pages zero-ref-warm in the trie — so
         the replica's first real request prefix-hits instead of
         re-prefilling. Returns the number of prefix tokens now warm
-        (0: not paged / no prefix cache / nothing persisted / pool busy).
+        (0: no prefix cache / nothing persisted / pool busy).
 
         Deliberately NEVER registers fresh pages: only pages revived
         with actual K/V may enter the trie, or later matches would serve
         garbage."""
-        if not (self.paged and self.prefix_cache):
+        if not self.prefix_cache:
             return 0
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size == 0 or prompt.size >= self.cache_len:
@@ -1151,9 +1122,9 @@ class ServingEngine:
             # itself is kept, appends are what a failed tick added).
             # prefill_pos/phase cover chunked-prefill progress, so a
             # mid-chunk fault rolls the request back to its exact
-            # pre-tick chunk position (req.chunk_cache is device state —
-            # NOT captured; recovery requeues mid-prefill requests and
-            # rebuilds it from scratch); spec_proposed/accepted cover the
+            # pre-tick chunk position (the pages a chunk wrote are device
+            # state — NOT captured; recovery requeues mid-prefill requests
+            # and restarts them); spec_proposed/accepted cover the
             # speculative draft counters a mid-verify fault would have
             # advanced
             "reqs": [(r, r.slot, r.admit_time, r.first_token_time,
@@ -1264,25 +1235,20 @@ class ServingEngine:
             for _, req in sorted(self._prefilling.items(), reverse=True):
                 req.slot = None
                 req.prefill_pos = 0
-                req.chunk_cache = None
                 req.phase = "queued"
                 self.scheduler.requeue(req)
             self._prefilling = {}
             self._tables_dev = None
             self._tables_version = -1
             self._state = self._replicate(self._init_state())
-            if self.paged:
-                # the HOST spill tier survives the rebuild: its entries
-                # are keyed by token-chunk path, not trie-node identity,
-                # so replayed/requeued prompts revive them from the new
-                # pool (only the DEVICE warm cache is a recovery loss)
-                self.cache_manager = PagedKVCacheManager(
-                    self.model, self.slots, self.cache_len, self.num_pages,
-                    self.page_size, prefix_cache=self.prefix_cache,
-                    host_store=self._host_store)
-            else:
-                self.cache_manager = SlotKVCacheManager(
-                    self.model, self.slots, self.cache_len)
+            # the HOST spill tier survives the rebuild: its entries are
+            # keyed by token-chunk path, not trie-node identity, so
+            # replayed/requeued prompts revive them from the new pool
+            # (only the DEVICE warm cache is a recovery loss)
+            self.cache_manager = PagedKVCacheManager(
+                self.model, self.slots, self.cache_len, self.num_pages,
+                self.page_size, prefix_cache=self.prefix_cache,
+                host_store=self._host_store)
             # the rebuilt device cache re-commits onto the SAME mesh
             # layout — host truth is mesh-agnostic, the layout is not
             self.cache_manager.cache = self._shard_cache(
@@ -1352,25 +1318,17 @@ class ServingEngine:
         n = len(req.tokens)
         history = np.concatenate(
             [req.prompt, np.asarray(req.tokens[:-1], np.int32)])
-        if self.paged:
-            alloc = self.cache_manager.alloc(req.id, history)
-            if alloc is None:
-                raise RuntimeError(
-                    f"replay alloc failed for request {req.id} "
-                    f"({len(history)} history tokens; "
-                    f"{self.cache_manager.pool.free_pages} pages free)")
-            lane, shared = alloc
-            req.slot = lane
-            self._paged_prefill_call(req, history[shared:], shared, lane,
-                                     replay=True)
-            self._register_prefix(req)
-        else:
-            slot = self.cache_manager.alloc(req.id, len(history))
-            if slot is None:
-                raise RuntimeError(
-                    f"replay alloc failed for request {req.id}: no free slot")
-            req.slot = slot
-            self._slot_prefill_call(req, history, slot, replay=True)
+        alloc = self.cache_manager.alloc(req.id, history)
+        if alloc is None:
+            raise RuntimeError(
+                f"replay alloc failed for request {req.id} "
+                f"({len(history)} history tokens; "
+                f"{self.cache_manager.pool.free_pages} pages free)")
+        lane, shared = alloc
+        req.slot = lane
+        self._paged_prefill_call(req, history[shared:], shared, lane,
+                                 replay=True)
+        self._register_prefix(req)
         # reconstruct the request's RNG stream position: one split at
         # admit, one per decode tick it was active in (greedy requests
         # never consume their stream, so the value is irrelevant there)
@@ -1620,8 +1578,8 @@ class ServingEngine:
         limit = min(self.cache_len, self.model.cfg.max_position_embeddings)
         if prompt_len + g.max_length > limit:
             # one-shot generate()'s contract: a decode that cannot fit the
-            # position table (or this engine's slot cache) is an error here,
-            # not the streaming submit()'s clamp-and-warn
+            # position table (or this engine's lane capacity) is an error
+            # here, not the streaming submit()'s clamp-and-warn
             raise ValueError(
                 f"prompt_len({prompt_len}) + max_length({g.max_length}) "
                 f"exceeds the engine's decode limit ({limit}: "
@@ -1765,10 +1723,9 @@ class ServingEngine:
                    for r in self._prefilling.values()),
                "active": (len(self._active) + len(self._prefilling)
                           + len(self._prefilled)),
-               "slots": self.slots}
-        if self.paged:
-            out["pages_in_use"] = self.cache_manager.pages_in_use
-            out["usable_pages"] = self.cache_manager.usable_pages
+               "slots": self.slots,
+               "pages_in_use": self.cache_manager.pages_in_use,
+               "usable_pages": self.cache_manager.usable_pages}
         return out
 
     def declare_dead(self) -> None:
@@ -1964,25 +1921,20 @@ class ServingEngine:
         return req.prompt
 
     def _can_admit(self, req: Request) -> bool:
-        """FIFO-head admission judgment: a free decode lane, and — paged —
-        enough free pages for the head's prompt plus any migrated history
+        """FIFO-head admission judgment: a free decode lane and enough
+        free pages for the head's prompt plus any migrated history
         (page-granular admission: total live tokens gate entry, not
-        worst-case slot capacity). A too-big head BLOCKS, preserving
+        worst-case lane capacity). A too-big head BLOCKS, preserving
         arrival order deterministically; it unblocks as retiring requests
         return pages."""
-        if self.paged:
-            # a dry run of the prefix match, once a tick while the head
-            # of the queue waits: host work between two admissions
-            with span("serving.can_admit", request=req.id):
-                return self.cache_manager.can_admit(
-                    self._admission_tokens(req))
-        return self.cache_manager.free_count > 0
+        # a dry run of the prefix match, once a tick while the head of the
+        # queue waits: host work between two admissions
+        with span("serving.can_admit", request=req.id):
+            return self.cache_manager.can_admit(self._admission_tokens(req))
 
     def _device_tables(self):
         """Device copy of the block tables, re-uploaded only when the
-        manager's version counter moved (None on the slot path)."""
-        if not self.paged:
-            return None
+        manager's version counter moved."""
         version = self.cache_manager.tables_version
         if version != self._tables_version:
             with span("serving.tables"):
@@ -2019,31 +1971,6 @@ class ServingEngine:
             return self.executor.sample(
                 last, key[None], greedy[None], temperature[None],
                 top_k[None], top_p[None], topk_cap=self.topk_cap)[0]
-
-    def _make_prefill(self, bucket_len: int):
-        """Jitted prefill-on-insert for prompts bucketed to ``bucket_len``:
-        batch-1 cached forward into a fresh cache, scatter into the slot,
-        sample the first token — one device round-trip per admission."""
-        max_pos = self.model.cfg.max_position_embeddings
-
-        def prefill(params, cache, prompt, true_len, slot, eos, min_new,
-                    greedy, temperature, top_k, top_p, key):
-            params = self._dequant_params(params)
-            ids = prompt[None, :]
-            # right-pad bucket tail: causal masking keeps the tail out of
-            # every position <= true_len-1, and its K/V rows sit beyond the
-            # live window until decode overwrites them one by one
-            pos = jnp.minimum(jnp.arange(bucket_len, dtype=jnp.int32),
-                              max_pos - 1)[None, :]
-            logits, small = self.executor.forward(
-                params, self.executor.init_cache(1), ids, pos)
-            cache = self._pin_cache(scatter_slot(cache, small, slot))
-            return cache, self._first_token(
-                logits, true_len, eos, min_new, greedy, temperature, top_k,
-                top_p, key)
-
-        return jax.jit(
-            prefill, donate_argnums=(1,) if self._donate_cache else ())
 
     def _make_paged_prefill(self, bucket_len: int):
         """Jitted paged prefill-on-insert for prompt SUFFIXES bucketed to
@@ -2109,12 +2036,10 @@ class ServingEngine:
                     *(jnp.asarray(v, jnp.int32) for v in offsets),
                     *self._prefill_scalars(req, replay, step_key)), carry_key
 
-    def _guarded_prefill(self, req: Request, fn, args, bucket=None,
-                         chunk_cache: bool = False):
+    def _guarded_prefill(self, req: Request, fn, args, bucket=None):
         """One prefill device call through the fault-injection hook;
-        stores the returned cache (into ``req.chunk_cache`` for chunked
-        slot calls, the cache manager otherwise). Deliberately NOT under
-        the hung-tick watchdog: prefill calls legitimately include
+        stores the returned cache in the cache manager. Deliberately NOT
+        under the hung-tick watchdog: prefill calls legitimately include
         fresh-bucket XLA compiles (seconds), and replay recovery
         re-prefills through here — a watchdog here would misread every
         cold compile as a hang and quarantine healthy requests. The
@@ -2126,28 +2051,8 @@ class ServingEngine:
             faults.on_serving_prefill(attempt, req.id)
             with self._mesh_context():
                 cache, tok = fn(*args)
-        if chunk_cache:
-            req.chunk_cache = cache
-        else:
-            self.cache_manager.cache = cache
+        self.cache_manager.cache = cache
         return tok
-
-    def _slot_prefill_call(self, req: Request, tokens, slot,
-                           replay: bool = False):
-        """Batch-1 prefill of ``tokens`` scattered into ``slot``'s cache
-        row. Admission returns ``(first_token, carry_key)``; replay
-        (``tokens`` = the request's history) returns None."""
-        bucket = -(-len(tokens) // self.prefill_bucket) * self.prefill_bucket
-        bucket = min(max(bucket, len(tokens)), self.cache_len)
-        fn = self._prefill_jits.get(("slot", bucket))
-        if fn is None:
-            fn = self._prefill_jits[("slot", bucket)] = \
-                self._make_prefill(bucket)
-        operands, carry_key = self._prefill_args(req, tokens, bucket, replay,
-                                                 slot)
-        args = (self.params, self.cache_manager.cache, *operands)
-        tok = self._guarded_prefill(req, fn, args, bucket=bucket)
-        return None if replay else (tok, carry_key)
 
     def _paged_prefill_call(self, req: Request, suffix, shared, lane,
                             replay: bool = False):
@@ -2161,9 +2066,9 @@ class ServingEngine:
         lands on the last prompt token."""
         bucket = -(-len(suffix) // self.prefill_bucket) * self.prefill_bucket
         bucket = min(max(bucket, len(suffix)), self.cache_len - shared)
-        fn = self._prefill_jits.get(("paged", bucket))
+        fn = self._prefill_jits.get(bucket)
         if fn is None:
-            fn = self._prefill_jits[("paged", bucket)] = \
+            fn = self._prefill_jits[bucket] = \
                 self._make_paged_prefill(bucket)
         operands, carry_key = self._prefill_args(
             req, suffix, bucket, replay, shared,
@@ -2172,69 +2077,11 @@ class ServingEngine:
         tok = self._guarded_prefill(req, fn, args, bucket=bucket)
         return None if replay else (tok, carry_key)
 
-    def _make_chunk_prefill(self, bucket_len: int):
-        """Jitted slot-path CHUNK prefill: write ``bucket_len`` prompt
-        tokens into the request's batch-1 working cache at absolute
-        positions ``wpos..`` through the per-row ``cache_positions`` seam
-        (the paged path needs no sibling — ``_make_paged_prefill`` already
-        takes a write offset), and sample from the chunk's last true
-        token — the returned token only matters on the FINAL chunk, where
-        ``true_len - 1`` is the last prompt position, exactly where the
-        one-call path samples."""
-        max_pos = self.model.cfg.max_position_embeddings
-
-        def prefill(params, cache, chunk, true_len, wpos, eos, min_new,
-                    greedy, temperature, top_k, top_p, key):
-            params = self._dequant_params(params)
-            ids = chunk[None, :]
-            # absolute positions wpos..; the right-pad bucket tail is
-            # causally invisible to every real query and its writes are
-            # overwritten by the next chunk (or decode) before the live
-            # window ever reaches them — same contract as the one-call
-            # bucket tail
-            pos = jnp.minimum(wpos + jnp.arange(bucket_len, dtype=jnp.int32),
-                              max_pos - 1)[None, :]
-            logits, cache = self.executor.forward(
-                params, cache, ids, pos,
-                cache_positions=wpos[None])
-            cache = self._pin_cache(cache)
-            return cache, self._first_token(
-                logits, true_len, eos, min_new, greedy, temperature, top_k,
-                top_p, key)
-
-        return jax.jit(
-            prefill, donate_argnums=(1,) if self._donate_cache else ())
-
-    def _chunk_prefill_call(self, req: Request, tokens, wpos,
-                            replay: bool = False):
-        """One slot-path chunk: ``tokens`` into ``req.chunk_cache`` at
-        absolute positions ``wpos..``. Intermediate chunks pass
-        ``replay=True`` (KV only, rng untouched, returns None); the
-        final chunk returns ``(first_token, carry_key)``."""
-        bucket = -(-len(tokens) // self.prefill_bucket) * self.prefill_bucket
-        # cap at the REMAINING cache span (mirroring the paged call's
-        # cache_len - shared): a bucket crossing cache_len would clamp
-        # its dynamic_update_slice start and overwrite live prompt KV
-        bucket = min(max(bucket, len(tokens)), self.cache_len - wpos)
-        fn = self._prefill_jits.get(("chunk", bucket))
-        if fn is None:
-            fn = self._prefill_jits[("chunk", bucket)] = \
-                self._make_chunk_prefill(bucket)
-        operands, carry_key = self._prefill_args(req, tokens, bucket, replay,
-                                                 wpos)
-        args = (self.params, req.chunk_cache, *operands)
-        tok = self._guarded_prefill(req, fn, args, bucket=bucket,
-                                    chunk_cache=True)
-        return None if replay else (tok, carry_key)
-
     def _claim_storage(self, req: Request) -> int:
-        """Claim a decode lane (+ page chain on the paged path) for one
-        admission; sets ``req.slot`` and returns the shared-prefix token
-        count (trie + host-revived; 0 on the slot path)."""
+        """Claim a decode lane and its page chain for one admission; sets
+        ``req.slot`` and returns the shared-prefix token count (trie +
+        host-revived)."""
         with span("serving.claim", request=req.id, shared=0) as at:
-            if not self.paged:
-                req.slot = self.cache_manager.alloc(req.id, req.prompt_len)
-                return 0
             alloc = self.cache_manager.alloc(req.id, req.prompt)
             if alloc is None:  # _can_admit() passed, so this is an
                 raise RuntimeError(  # invariant breach — fail loudly
@@ -2322,22 +2169,15 @@ class ServingEngine:
                     and req.prompt_len - shared > self.prefill_chunk):
                 req.prefill_pos = shared
                 req.phase = "prefilling"
-                if not self.paged:
-                    req.chunk_cache = self._shard_cache(
-                        self.executor.init_cache(1))
                 self._prefilling[req.slot] = req
                 req.admit_time = self._now()
                 self.metrics.record_admit(req.admit_time - req.submit_time)
                 self._fault_ctx = None
                 self._run_chunk(req)  # this tick's one chunk of budget
                 return
-            if self.paged:
-                tok, carry_key = self._paged_prefill_call(
-                    req, req.prompt[shared:], shared, req.slot)
-                self._register_prefix(req)
-            else:
-                tok, carry_key = self._slot_prefill_call(
-                    req, req.prompt, req.slot)
+            tok, carry_key = self._paged_prefill_call(
+                req, req.prompt[shared:], shared, req.slot)
+            self._register_prefix(req)
             self._fault_ctx = None
             self._prefill_strikes.pop(req.id, None)  # survived its prefill
             now = self._now()
@@ -2417,26 +2257,15 @@ class ServingEngine:
         self._fault_ctx = ("prefill", req.id)
         with span("serving.prefill_chunk", request=req.id, start=start,
                   final=final):
-            if self.paged:
-                out = self._paged_prefill_call(req, tokens, start, req.slot,
-                                               replay=not final)
-            else:
-                out = self._chunk_prefill_call(req, tokens, start,
-                                               replay=not final)
+            out = self._paged_prefill_call(req, tokens, start, req.slot,
+                                           replay=not final)
         self._fault_ctx = None
         req.prefill_pos = end
         self.metrics.record_prefill_chunk(len(tokens))
         if not final:
             return
         tok, carry_key = out
-        if self.paged:
-            self._register_prefix(req)
-        else:
-            # fold the finished batch-1 working cache into the slot row
-            self.cache_manager.cache = self._scatter_jit(
-                self.cache_manager.cache, req.chunk_cache,
-                jnp.asarray(req.slot, jnp.int32))
-            req.chunk_cache = None
+        self._register_prefix(req)
         del self._prefilling[req.slot]
         self._prefill_strikes.pop(req.id, None)
         self._finish_first_token(req, tok, carry_key)
@@ -2503,10 +2332,9 @@ class ServingEngine:
         """Jitted: ONE decode token for every slot (inactive slots ride
         along with writes pinned to the last cache row — which a freed
         lane's zeroed block table re-routes to the trash page — outputs
-        ignored). ``tables`` is the device block tables on the paged path
-        (None on the slot path). ``all_greedy`` is static — greedy-only
-        ticks take a bare argmax and skip the sampler's top-k sort /
-        top-p bisection / rng split."""
+        ignored). ``tables`` is the device block tables. ``all_greedy``
+        is static — greedy-only ticks take a bare argmax and skip the
+        sampler's top-k sort / top-p bisection / rng split."""
         params = self._dequant_params(params)
         active = st["active"]
         lengths = st["lengths"]
@@ -2568,11 +2396,9 @@ class ServingEngine:
         return retired
 
     def _tick_decode(self):
-        retired = []
-        if self.paged:
-            retired = self._grow_pages()
-            if not self._active:
-                return retired
+        retired = self._grow_pages()
+        if not self._active:
+            return retired
         all_greedy = all(r.greedy for r in self._active.values())
         active_ids = [r.id for r in self._active.values()]
         attempt = self._fault_ticks
@@ -2652,10 +2478,8 @@ class ServingEngine:
         deterministic, q = 1 — else sample the residual ``(p - q)+``),
         consuming exactly one rng split per EMITTED token so replay's
         stream reconstruction is unchanged. Inactive lanes ride along
-        with writes pinned beyond every live window (paged: position
-        clamps re-route through zeroed tables to the trash page; slot:
-        the tail rows of a dead/mid-prefill lane, which the next
-        tenant's full-row scatter overwrites). Returns
+        with writes pinned beyond every live window (position clamps
+        re-route through zeroed tables to the trash page). Returns
         ``(cache, new_state, out_tokens [b,k+1], n_emit [b],
         n_accepted [b], done [b])``."""
         params = self._dequant_params(params)
@@ -2663,13 +2487,10 @@ class ServingEngine:
         active = st["active"]
         lengths = st["lengths"]
         max_pos = self.model.cfg.max_position_embeddings
-        # pinned write base for inactive rows: the paged path clamps all
-        # s positions onto the last logical slot (trash-routed when
-        # unallocated); the slot path needs start <= cache_len - s so the
-        # per-row dynamic_update_slice cannot clamp-shift backwards
-        pin = self.cache_len - 1 if self.paged else self.cache_len - s
+        # pinned write base for inactive rows: all s positions clamp onto
+        # the last logical slot (trash-routed when unallocated)
         with jax.named_scope("lanes"):
-            wpos = jnp.where(active, lengths, pin)
+            wpos = jnp.where(active, lengths, self.cache_len - 1)
             ids = jnp.concatenate([st["last_tok"][:, None], draft], axis=1)
             posid = jnp.minimum(
                 wpos[:, None] + jnp.arange(s, dtype=jnp.int32), max_pos - 1)
@@ -2797,16 +2618,14 @@ class ServingEngine:
                 min(self.cache_len - 1 - n for n in lens.values()))
         if k <= 0:
             return self._tick_decode()
-        retired = []
-        if self.paged:
-            # phase 1: every lane's PENDING-token page first — the exact
-            # allocation the plain tick makes, in the same order, so
-            # cache_full retirement decisions are identical to the
-            # non-speculative engine even under a near-dry pool (draft
-            # windows must never starve a neighbor's pending token)
-            retired = self._grow_pages()
-            if not self._active:
-                return retired
+        # phase 1: every lane's PENDING-token page first — the exact
+        # allocation the plain tick makes, in the same order, so
+        # cache_full retirement decisions are identical to the
+        # non-speculative engine even under a near-dry pool (draft
+        # windows must never starve a neighbor's pending token)
+        retired = self._grow_pages()
+        if not self._active:
+            return retired
         cov = {}
         with span("serving.grow"):
             for slot in sorted(self._active):
@@ -2815,16 +2634,12 @@ class ServingEngine:
                 # never overrun the request's remaining token budget or its
                 # page coverage — clamp BEFORE proposing
                 budget = max(req.max_new_tokens - len(req.tokens) - 1, 0)
-                if self.paged:
-                    # phase 2: draft windows from whatever slack remains
-                    # (uncovered tail writes trash-route; acceptance clamps
-                    # to the covered span) — and whatever a draft claims
-                    # here is RETURNED by trim_span after the verify, so the
-                    # pool a neighbor sees next tick is the plain engine's
-                    c = self.cache_manager.ensure_span(
-                        slot, min(k, budget) + 1)
-                else:
-                    c = k + 1  # slot lanes are fully allocated
+                # phase 2: draft windows from whatever slack remains
+                # (uncovered tail writes trash-route; acceptance clamps
+                # to the covered span) — and whatever a draft claims
+                # here is RETURNED by trim_span after the verify, so the
+                # pool a neighbor sees next tick is the plain engine's
+                c = self.cache_manager.ensure_span(slot, min(k, budget) + 1)
                 cov[slot] = min(k, budget, c - 1)
         req_map = {
             slot: (np.concatenate([req.prompt,
@@ -2853,9 +2668,8 @@ class ServingEngine:
             # proposer holds per-tick state that needs an observe() here).
             # Phase-2 draft pages go back first, so the plain tick and
             # every neighbor see the plain engine's pool state.
-            if self.paged:
-                for slot in sorted(self._active):
-                    self.cache_manager.trim_span(slot)
+            for slot in sorted(self._active):
+                self.cache_manager.trim_span(slot)
             return retired + self._tick_decode()
         all_greedy = all(r.greedy for r in self._active.values())
         active_ids = [r.id for r in self._active.values()]
@@ -2898,11 +2712,10 @@ class ServingEngine:
                 req.spec_accepted += row_acc
                 emitted_rows.append(n)
                 self.cache_manager.lengths[slot] += n
-                if self.paged:
-                    # return rejected-draft pages to the pool THIS tick:
-                    # post-trim the chain matches what the plain engine
-                    # would hold, so draft windows cost neighbors nothing
-                    self.cache_manager.trim_span(slot)
+                # return rejected-draft pages to the pool THIS tick:
+                # post-trim the chain matches what the plain engine
+                # would hold, so draft windows cost neighbors nothing
+                self.cache_manager.trim_span(slot)
                 self.metrics.record_tokens(n)
                 self._proposer.observe(slot, n)
                 finished = bool(done_np[slot])
@@ -2959,8 +2772,8 @@ class ServingEngine:
             del self._prefilling[req.slot]
         if req.slot in self._prefilled and self._prefilled[req.slot] is req:
             del self._prefilled[req.slot]
-        req.chunk_cache = None  # a mid-prefill retiree drops its working
-        req.phase = "finished"  # cache; pages/lane free below (no leak)
+        req.phase = "finished"  # a mid-prefill retiree's pages and lane
+        # free below (no leak)
         if req.slot is not None:  # queued-expiry/cancel never held a slot
             if self._proposer is not None:
                 self._proposer.on_retire(req.slot)

@@ -113,8 +113,7 @@ class ServingMetrics:
         self._c_poison = counter(
             "fleetx_serving_poison_retired_total",
             "Requests quarantined as poison (bisection or replay failure)")
-        # paged-cache counters (zero on the slot path so the snapshot
-        # schema is stable across modes)
+        # paged-cache counters
         self._c_prefix_queries = counter(
             "fleetx_serving_prefix_queries_total",
             "Paged admissions that consulted the prefix trie")
@@ -729,7 +728,7 @@ class ServingMetrics:
                                     if span and span > 0 else None),
             "finish_reasons": self.finish_reasons,
             # paged-cache story: how much prefill the prefix trie saved
-            # and how full the page pool ran (zeros on the slot path)
+            # and how full the page pool ran
             "prefix_queries": self.prefix_queries,
             "prefix_hits": self.prefix_hits,
             "prefix_hit_rate": (self.prefix_hits / self.prefix_queries

@@ -73,7 +73,7 @@ class ModelCapabilities:
     #: draft-and-verify speculative decoding is legal (requires a decode
     #: loop whose verify call replays multi-token windows — GPT only)
     supports_spec: bool
-    #: "slot+paged" (the GPT engine's two cache layouts), "none" (KV-free)
+    #: "paged" (the GPT engine's page pool), "none" (KV-free)
     cache_layout: str
     #: hard per-request input bound (tokens for text, flat elements for
     #: vision) — what the router's per-group submit validation prices
@@ -160,8 +160,7 @@ class ModelExecutor:
         """One cached forward: ``(logits, new_cache)``. Serves bucketed
         prefill (multi-token ``ids``) and the decode tick (one token per
         lane) through the same seam; ``cache_positions`` are per-lane
-        write offsets, ``block_tables`` the paged indirection (None on
-        the slot path)."""
+        write offsets, ``block_tables`` the paged indirection."""
         raise NotImplementedError
 
     def sample(self, logits, keys, greedy, temperature, top_k, top_p, *,
@@ -209,7 +208,7 @@ class GPTExecutor(ModelExecutor):
             family=family or getattr(model.cfg, "family", "gpt"),
             has_kv_cache=True,
             supports_spec=dense,
-            cache_layout="slot+paged",
+            cache_layout="paged",
             max_input=int(model.cfg.max_position_embeddings),
             supports_int8_weights=dense,
             supports_int8_kv=dense,

@@ -35,9 +35,7 @@ class Request:
     long prompt's KV ingestion is spread over scheduler ticks — one
     chunk per tick, interleaved with the batched decode — with
     ``prefill_pos`` tracking how many prompt tokens (shared prefix
-    included) have been written so far and, on the slot path,
-    ``chunk_cache`` holding the batch-1 working cache the chunks
-    accumulate into before the final scatter."""
+    included) have been written into the request's pages so far."""
 
     id: int
     prompt: np.ndarray  # [prompt_len] int32, no padding
@@ -66,7 +64,6 @@ class Request:
     # restores chunk progress exactly
     phase: str = "queued"
     prefill_pos: int = 0
-    chunk_cache: Any = dataclasses.field(default=None, repr=False)
     # speculative-decoding draft accounting (docs/SERVING.md): lifetime
     # proposed/accepted draft tokens for THIS request — also snapshot-
     # covered, so a tick that faults mid-verify rolls its counts back

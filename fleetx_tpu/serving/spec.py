@@ -156,12 +156,35 @@ class NgramProposer:
         """Stateless — nothing to drop."""
 
 
+def scatter_slot(cache, prefill_cache, slot):
+    """Write a 1-row cache tree into row ``slot`` of the draft model's
+    slot-layout cache (its only user: the serving engine itself holds
+    pages).
+
+    Pure function (``slot`` may be traced). K/V leaves carry a
+    ``[..., batch, cache_len, lanes]`` suffix (``KV_LEAF_RANK``) — the
+    batch axis sits at -3 for both the scan-stacked
+    ``[layers, batch, ...]`` and the unrolled nested layouts — and are
+    updated at that axis; lower-rank leaves (the ``cache_index``
+    scalars) are left untouched, since per-lane progress is tracked by
+    the proposer."""
+
+    def put(big, small):
+        if big.ndim < KV_LEAF_RANK:
+            return big
+        starts = ((0,) * (big.ndim - KV_LEAF_RANK) + (slot,)
+                  + (0,) * (KV_LEAF_RANK - 1))
+        return jax.lax.dynamic_update_slice(big, small, starts)
+
+    return jax.tree.map(put, cache, prefill_cache)
+
+
 def _gather_slot(cache, slot):
     """Slice one lane's row out of a slot-layout cache tree (the inverse
-    of :func:`~fleetx_tpu.serving.cache_manager.scatter_slot`): K/V
-    leaves keep their ``[..., batch, cache_len, lanes]`` suffix
-    (``KV_LEAF_RANK``) with the batch axis cut to 1; lower-rank leaves
-    (the ``cache_index`` scalars) pass through untouched."""
+    of :func:`scatter_slot`): K/V leaves keep their
+    ``[..., batch, cache_len, lanes]`` suffix (``KV_LEAF_RANK``) with the
+    batch axis cut to 1; lower-rank leaves (the ``cache_index`` scalars)
+    pass through untouched."""
 
     def take(big):
         if big.ndim < KV_LEAF_RANK:
@@ -260,7 +283,6 @@ class DraftModelProposer:
         one multi-token cached forward, scatter back). Logits are
         discarded — catch-up is KV ingestion only."""
         from fleetx_tpu.models.gpt.generation import decode_step
-        from fleetx_tpu.serving.cache_manager import scatter_slot
 
         max_pos = self.model.cfg.max_position_embeddings
 

@@ -148,8 +148,8 @@ def test_donated_cache_aliases_the_output(kv_dtype, tokens):
     model = GPTForPretraining(CFG)
     params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
     eng = ServingEngine(
-        model, params, slots=LANES, cache_len=CACHE_LEN, paged=True,
-        page_size=PAGE, prefill_bucket=8, kv_dtype=kv_dtype or "bf16",
+        model, params, slots=LANES, cache_len=CACHE_LEN, page_size=PAGE,
+        prefill_bucket=8, kv_dtype=kv_dtype or "bf16",
         gen_cfg=GenerationConfig(decode_strategy="greedy",
                                  eos_token_id=10**6, pad_token_id=60))
     lowered = _donating_programs(eng, tokens)

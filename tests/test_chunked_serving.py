@@ -3,7 +3,7 @@
 Three layers:
 
 - **Parity gates**: chunked prefill must emit BYTE-identical greedy
-  tokens to the unchunked engine across slot/paged storage, bf16/int8
+  tokens to the unchunked engine across bf16/int8
   KV, and the flash-interpret kernel path — and a request decoded from
   spill-REVIVED host pages must match its cold-prefilled run byte for
   byte (revived bytes are the spilled bytes).
@@ -75,9 +75,7 @@ def _engine(model, params, **kw):
     kw.setdefault("cache_len", 32)
     kw.setdefault("gen_cfg", GREEDY)
     kw.setdefault("prefill_bucket", 4)
-    if kw.get("paged", True):
-        kw.setdefault("paged", True)
-        kw.setdefault("page_size", 8)
+    kw.setdefault("page_size", 8)
     return ServingEngine(model, params, **kw)
 
 
@@ -96,33 +94,28 @@ def _run(eng, prompts, max_length=4):
 
 # ---------------------------------------------------------- parity gates
 
-# tier-1 keeps ONE compact gate (paged bf16 — the default lane); the
-# slot compat lane (separate chunk-cache path) and the int8 variants
-# re-prove the same contract in the full sweep (8-15s each on the
-# slow-host baseline; PR 11 tier-1 budget audit — the suite must fit
-# the 870s harness cap with headroom for loaded hosts)
-@pytest.mark.parametrize(
-    "paged", [pytest.param(False, marks=pytest.mark.slow, id="slot"),
-              pytest.param(True, id="paged")])
+# tier-1 keeps ONE compact gate (bf16); the int8 variant re-proves the
+# same contract in the full sweep (8-15s on the slow-host baseline;
+# PR 11 tier-1 budget audit)
 @pytest.mark.parametrize(
     "kv", ["bf16", pytest.param("int8", marks=pytest.mark.slow)])
-def test_chunked_vs_unchunked_byte_parity(tiny, paged, kv):
+def test_chunked_vs_unchunked_byte_parity(tiny, kv):
     """The acceptance gate: chunking only reschedules WHEN prompt tokens
     ingest, never what anything computes — byte-identical greedy streams
-    on both storage lanes at both KV precisions (int8 compares against
+    at both KV precisions (int8 compares against
     its own unchunked run: same quantization, same bytes), and the
     one-shot reference pins the bf16 runs to ``generate()``."""
     model, params = tiny
     prompts = _mixed_prompts()
-    kw = dict(paged=paged, kv_dtype=None if kv == "bf16" else "int8")
+    kw = dict(kv_dtype=None if kv == "bf16" else "int8")
     want = _run(_engine(model, params, **kw), prompts)
     eng = _engine(model, params, prefill_chunk=6, **kw)
     got = _run(eng, prompts)
     for i, (a, b) in enumerate(zip(got, want)):
-        assert_token_parity(a, b, err_msg=f"req {i} (paged={paged}, {kv})")
+        assert_token_parity(a, b, err_msg=f"req {i} ({kv})")
     if kv == "bf16":
         # one-shot pin on the longest prompt only: unchunked-vs-one-shot
-        # is already the paged/slot suites' gate; each extra reference
+        # is already the paged suites' gate; each extra reference
         # is a fresh generate() compile the tier-1 budget pays for
         ref = one_shot_tokens(model, params, prompts[2], 4, gen_cfg=GREEDY)
         assert_token_parity(got[2], ref, err_msg="req 2 vs one-shot")
@@ -145,22 +138,18 @@ def test_chunked_flash_interpret_parity(tiny_flash):
 
 
 def test_chunked_parity_at_cache_capacity_edge(tiny):
-    """Regression (PR 11 review): a slot-path chunk whose PADDED bucket
-    would cross ``cache_len`` must cap at the remaining span — an
-    overhanging bucket clamps its ``dynamic_update_slice`` start and
-    silently overwrites live prompt KV (prompt_len 31 in a 32-cache,
-    final chunk at wpos 30 with a 4-row bucket clobbered positions
-    28-29 and flipped the sampled token)."""
+    """Regression (PR 11 review): a chunk whose PADDED bucket would
+    cross ``cache_len`` must cap at the remaining span (prompt_len 31 in
+    a 32-cache, final chunk at wpos 30 with a 4-row bucket): positions
+    past the lane's last page must never clobber live prompt KV or flip
+    the sampled token."""
     model, params = tiny
     prompt = np.random.RandomState(13).randint(
         1, 61, (31,)).astype(np.int32)  # cache_len - 1: the worst case
-    for paged in (False, True):
-        kw = dict(slots=1, paged=paged, page_size=8 if paged else None)
-        want = _run(_engine(model, params, **kw), [prompt], max_length=1)
-        got = _run(_engine(model, params, prefill_chunk=6, **kw),
-                   [prompt], max_length=1)
-        assert_token_parity(got[0], want[0],
-                            err_msg=f"cache-edge chunk (paged={paged})")
+    want = _run(_engine(model, params, slots=1), [prompt], max_length=1)
+    got = _run(_engine(model, params, slots=1, prefill_chunk=6), [prompt],
+               max_length=1)
+    assert_token_parity(got[0], want[0], err_msg="cache-edge chunk")
 
 
 def test_chunk_at_or_above_prompt_is_one_call(tiny):

@@ -1,7 +1,7 @@
 """Mesh-sharded serving gates (ISSUE 14).
 
 The contract (docs/SERVING.md "Mesh-sharded serving"): a ``ServingEngine``
-handed a TP/FSDP mesh shards params + both KV cache layouts (heads over
+handed a TP/FSDP mesh shards params + the KV page pool (heads over
 ``mp``, int8 scale leaves included) and runs every jitted device call —
 prefill, decode tick, spec verify, probe, replay — under the mesh, with
 the flash-decode kernels invoked per-shard inside ``shard_map``. Host
@@ -11,7 +11,7 @@ divide by the mp extent, and ``recover()`` must rebuild sharded device
 state from the same host truth.
 
 Compact mp2 gates (paged parity + cache-bytes ÷2, flash-sharded-kernel
-dispatch, replay recovery, slot parity, sharding-spec units) are tier-1;
+dispatch, replay recovery, sharding-spec units) are tier-1;
 the wider matrix (int8, speculative, chunked, sampling, mp2 x fsdp2)
 rides the slow tier per the ISSUE 14 budget audit.
 """
@@ -112,11 +112,11 @@ def test_mesh_paged_parity_cache_bytes_and_gauge(model_and_params, mp2):
 # variants behind the slow mark (chaos serving_mesh drives it e2e)
 def test_mesh_flash_decode_takes_sharded_kernels(model_and_params, mp2,
                                                  monkeypatch):
-    """Both Pallas decode kernels (interpret mode) must actually run
+    """The paged Pallas decode kernel (interpret mode) must actually run
     under the mesh: for a tileable mp2 decode the dense fallback is NOT
-    taken — the kernel entry points are invoked with ``mesh=`` set (the
-    shard_map path) — and tokens still match the single-device flash
-    engine byte-for-byte."""
+    taken — the kernel entry point is invoked with ``mesh=`` set (the
+    shard_map path), the contiguous kernel never — and tokens still
+    match the single-device flash engine byte-for-byte."""
     monkeypatch.setenv("FLEETX_FORCE_FLASH", "1")
     _, params = model_and_params
     flash_model = GPTForPretraining(
@@ -150,14 +150,7 @@ def test_mesh_flash_decode_takes_sharded_kernels(model_and_params, mp2,
         "paged flash kernel ran bare under the mesh (GSPMD would "
         "replicate the head-sharded pool around it)")
     _assert_streams_equal(got_paged, want_paged, "flash paged mp2")
-
-    want_slot = _run(_engine(flash_model, params, paged=False))
-    calls["contig"].clear()
-    got_slot = _run(_engine(flash_model, params, paged=False, mesh=mp2))
-    assert calls["contig"], "mp2 decode never reached the contiguous kernel"
-    assert any(m is mp2 for m in calls["contig"]), (
-        "contiguous flash kernel ran bare under the mesh")
-    _assert_streams_equal(got_slot, want_slot, "flash slot mp2")
+    assert not calls["contig"], "the engine holds pages, not slot rows"
 
 
 def test_mesh_recover_rebuilds_sharded_state(model_and_params, mp2):
@@ -181,20 +174,6 @@ def test_mesh_recover_rebuilds_sharded_state(model_and_params, mp2):
     # the REBUILT cache is still the per-device shard, not a gathered copy
     single_bytes = _engine(model, params).cache_manager.cache_nbytes()
     assert eng.cache_manager.cache_nbytes() < 0.55 * single_bytes
-
-
-@pytest.mark.slow  # 5.5s (PR 15 tier-1 budget audit): the mesh parity
-# contract stays tier-1 via the paged (default-layout) gate above; the
-# slot x mesh combination re-runs in the slow matrix
-def test_mesh_slot_path_parity(model_and_params, mp2):
-    """The slot cache layout shards heads-over-mp too: byte parity vs the
-    single-device slot engine, with per-request overrides riding along
-    (min_length EOS suppression through the meshed prefill)."""
-    model, params = model_and_params
-    kw = dict(paged=False)
-    want = _run(_engine(model, params, **kw))
-    got = _run(_engine(model, params, mesh=mp2, **kw))
-    _assert_streams_equal(got, want, "slot mp2")
 
 
 def test_mesh_validation_and_spec_units(model_and_params, eight_devices):
@@ -308,11 +287,8 @@ def test_mesh_matrix_int8_spec_chunked(model_and_params, mp2):
     model, params = model_and_params
     for kw in (
         dict(kv_dtype="int8", weight_dtype="int8"),
-        dict(kv_dtype="int8", weight_dtype="int8", paged=False),
         dict(spec=True, spec_k=4),
-        dict(spec=True, spec_k=4, paged=False),
         dict(prefill_chunk=3),
-        dict(prefill_chunk=3, paged=False),
     ):
         want = _run(_engine(model, params, **kw))
         got = _run(_engine(model, params, mesh=mp2, **kw))

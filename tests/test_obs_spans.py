@@ -61,7 +61,7 @@ def _engine(**kwargs):
     params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     return ServingEngine(
         model, params, slots=LANES, cache_len=16, prefill_bucket=4,
-        paged=True, page_size=8,
+        page_size=8,
         gen_cfg=GenerationConfig(decode_strategy="greedy",
                                  eos_token_id=10**6, pad_token_id=60,
                                  max_length=6), **kwargs)

@@ -248,8 +248,8 @@ def test_the_grouped_matmul_kernels_match_ragged_dot(layer):
 
 def engine_of(model, v, **kw):
     return ServingEngine(
-        model, v, slots=4, cache_len=CACHE_LEN, paged=True, page_size=PAGE,
-        num_pages=40, prefill_bucket=8, gen_cfg=GenerationConfig(
+        model, v, slots=4, cache_len=CACHE_LEN, page_size=PAGE, num_pages=40,
+        prefill_bucket=8, gen_cfg=GenerationConfig(
             decode_strategy="greedy", eos_token_id=-1, pad_token_id=0,
             max_length=8), **kw)
 

@@ -8,7 +8,7 @@ Three layers:
   plus exact small scenarios for trie match / revive / LRU eviction. No
   model, no device arrays.
 - **Engine parity**: paged serving must emit byte-identical greedy tokens
-  to the slot-based compat path AND to one-shot ``generate()`` under
+  to one-shot ``generate()`` under
   staggered mixed-length load with lane reuse — on the dense path and
   through the paged flash-decode kernel (interpret mode).
 - **The paged wins**: prefix reuse measurably cuts prefill tokens and
@@ -70,7 +70,6 @@ def _engine(model, params, **kw):
     kw.setdefault("gen_cfg", GREEDY)
     kw.setdefault("prefill_bucket", 4)
     kw.setdefault("page_size", 8)
-    kw.setdefault("paged", True)
     return ServingEngine(model, params, **kw)
 
 
@@ -357,7 +356,7 @@ def test_host_store_payload_bytes_roundtrip():
     params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     eng = ServingEngine(
         model, params, slots=1, cache_len=16, prefill_bucket=4,
-        paged=True, page_size=8,
+        page_size=8,
         gen_cfg=GenerationConfig(decode_strategy="greedy",
                                  eos_token_id=10**6, pad_token_id=60,
                                  max_length=2))
@@ -489,11 +488,11 @@ def test_paged_manager_lane_lifecycle(model_and_params):
 
 # --------------------------------------------------------- parity contracts
 
-@pytest.mark.slow  # 25.1s baseline (PR 12 tier-1 budget audit): paged-vs-
-def test_paged_vs_slot_staggered_parity(model_and_params):
-    # slot byte parity stays tier-1 via test_chunked_serving's paged gate
-    # + test_serving_recovery's paged replay parity
-    """The acceptance gate, compact: paged serving == slot serving ==
+@pytest.mark.slow  # 25.1s baseline (PR 12 tier-1 budget audit): one-shot
+def test_paged_staggered_one_shot_parity(model_and_params):
+    # byte parity stays tier-1 via test_serving.py's staggered gate,
+    # test_chunked_serving's gate + test_serving_recovery's replay parity
+    """The acceptance gate, compact: paged serving ==
     one-shot generate(), byte-identical greedy tokens, under mixed prompt
     lengths, staggered admission, and lane reuse (slots=2, 5 requests —
     the 8-request / mixed-decode-length sweep is in the slow sibling).
@@ -504,24 +503,17 @@ def test_paged_vs_slot_staggered_parity(model_and_params):
     plens = (3, 5, 4, 5, 3)
     prompts = [rng.randint(1, 97, (n,)).astype(np.int32) for n in plens]
 
-    def run(**kw):
-        eng = _engine(model, params, slots=2, **kw)
-        rids = [eng.submit(p, max_length=4) for p in prompts[:3]]
-        eng.step()  # requests 3.. arrive mid-flight
-        rids += [eng.submit(p, max_length=4) for p in prompts[3:]]
-        res = eng.drain()
-        return eng, [res[r].tokens for r in rids]
-
-    paged_eng, paged_toks = run(paged=True)
-    _, slot_toks = run(paged=False)
+    eng = _engine(model, params, slots=2)
+    rids = [eng.submit(p, max_length=4) for p in prompts[:3]]
+    eng.step()  # requests 3.. arrive mid-flight
+    rids += [eng.submit(p, max_length=4) for p in prompts[3:]]
+    res = eng.drain()
     for i, p in enumerate(prompts):
-        want = _one_shot_tokens(model, params, p, 4)
-        assert_token_parity(paged_toks[i], want,
+        assert_token_parity(res[rids[i]].tokens,
+                            _one_shot_tokens(model, params, p, 4),
                             err_msg=f"paged vs one-shot, req {i}")
-        assert_token_parity(slot_toks[i], want,
-                            err_msg=f"slot vs one-shot, req {i}")
-    assert paged_eng.cache_manager.pages_in_use == 0  # all chains returned
-    assert paged_eng.cache_manager.free_count == 2
+    assert eng.cache_manager.pages_in_use == 0  # all chains returned
+    assert eng.cache_manager.free_count == 2
 
 
 # ------------------------------------------------------------ the paged wins

@@ -4,7 +4,7 @@ slow-host baseline, dominated by one-shot ``generate()`` reference
 compiles).
 
 Full-width versions of the tier-1 parity gates: 8-request staggered
-mixed-length parity against BOTH storage modes, the paged flash-decode
+mixed-length parity against one-shot ``generate()``, the paged flash-decode
 kernel in interpret mode (including shared-prefix gather through the
 trie's pages), the hot-vs-cold prefix-cache engine comparison, and the
 per-request sampling/callback behaviors under paged storage. The compact
@@ -30,38 +30,31 @@ from fleetx_tpu.models.gpt.model import GPTForPretraining
 pytestmark = pytest.mark.slow
 
 
-def test_paged_vs_slot_staggered_parity_full(model_and_params):  # noqa: F811
+def test_paged_staggered_one_shot_parity_full(model_and_params):  # noqa: F811
     """8 requests, mixed prompt AND decode lengths, staggered admission,
-    slots=3 (queueing + lane reuse): paged == slot == one-shot, per
-    request, byte-identical."""
+    slots=3 (queueing + lane reuse): paged == one-shot, per request,
+    byte-identical."""
     model, params = model_and_params
     rng = np.random.RandomState(7)
     plens = (3, 5, 4, 7, 6, 3, 8, 4)
     glens = (6, 4, 7, 3, 6, 5, 4, 6)
     prompts = [rng.randint(1, 97, (n,)).astype(np.int32) for n in plens]
 
-    def run(**kw):
-        eng = _engine(model, params, **kw)
-        rids = []
-        for p, g in zip(prompts[:4], glens[:4]):
-            rids.append(eng.submit(p, max_length=g))
-        for _ in range(3):
-            eng.step()
-        for p, g in zip(prompts[4:], glens[4:]):
-            rids.append(eng.submit(p, max_length=g))
-        res = eng.drain()
-        return eng, [res[r].tokens for r in rids]
-
-    paged_eng, paged_toks = run(paged=True)
-    _, slot_toks = run(paged=False)
+    eng = _engine(model, params)
+    rids = []
+    for p, g in zip(prompts[:4], glens[:4]):
+        rids.append(eng.submit(p, max_length=g))
+    for _ in range(3):
+        eng.step()
+    for p, g in zip(prompts[4:], glens[4:]):
+        rids.append(eng.submit(p, max_length=g))
+    res = eng.drain()
     for i, (p, g) in enumerate(zip(prompts, glens)):
-        want = _one_shot_tokens(model, params, p, g)
-        np.testing.assert_array_equal(paged_toks[i], want,
-                                      err_msg=f"paged vs one-shot, req {i}")
-        np.testing.assert_array_equal(slot_toks[i], want,
-                                      err_msg=f"slot vs one-shot, req {i}")
-    assert paged_eng.cache_manager.pages_in_use == 0
-    assert paged_eng.cache_manager.free_count == 3
+        np.testing.assert_array_equal(
+            res[rids[i]].tokens, _one_shot_tokens(model, params, p, g),
+            err_msg=f"paged vs one-shot, req {i}")
+    assert eng.cache_manager.pages_in_use == 0
+    assert eng.cache_manager.free_count == 3
 
 
 def test_paged_flash_interpret_parity(model_and_params, monkeypatch):  # noqa: F811

@@ -135,8 +135,10 @@ def test_health_report_shape(zoo, kind):
     assert isinstance(caps["has_kv_cache"], bool)
     if kind == "gpt":
         assert caps["has_kv_cache"] and h["model"] == "gpt"
+        assert caps["cache_layout"] == "paged" and eng.paged is True
     else:
         assert not caps["has_kv_cache"] and caps["cache_layout"] == "none"
+        assert eng.paged is False
     eng.request_shutdown()
     assert eng.health()["state"] == "draining"
     eng.drain()

@@ -3,7 +3,7 @@
 The acceptance gates for ``FLEETX_SERVING_KV_DTYPE=int8`` /
 ``FLEETX_SERVING_WEIGHT_DTYPE=int8`` (docs/QUANTIZATION.md):
 
-- **Tolerance parity** — slot and paged serving under int8 KV (dense
+- **Tolerance parity** — serving under int8 KV (dense
   fallback AND the dequant-in-kernel flash-decode variants in interpret
   mode) reproduce the bf16 one-shot ``generate()`` streams within the
   documented ``QUANT_ATOL`` prefix budget from ``serving_parity.py``;
@@ -85,8 +85,7 @@ def _engine(model, params, **kw):
     kw.setdefault("cache_len", 32)
     kw.setdefault("gen_cfg", GREEDY)
     kw.setdefault("prefill_bucket", 8)
-    if kw.get("paged"):
-        kw.setdefault("page_size", 8)
+    kw.setdefault("page_size", 8)
     return ServingEngine(model, params, **kw)
 
 
@@ -176,45 +175,34 @@ def test_kv_dtype_validation(model_and_params):
 @pytest.mark.slow  # 5.0s+5.2s (PR 15 tier-1 budget audit): the dense/XLA
 # FALLBACK's int8 parity — the production flash-interpret variants stay
 # tier-1 below, and the dense path re-runs in the slow int8 matrix
-@pytest.mark.parametrize("paged", [False, True], ids=["slot", "paged"])
-def test_int8_kv_parity_dense(model_and_params, prompts, reference, paged):
-    """int8 KV on the dense/XLA fallback (slot + paged): streams within
+def test_int8_kv_parity_dense(model_and_params, prompts, reference):
+    """int8 KV on the dense/XLA fallback: streams within
     the QUANT_ATOL prefix budget of the bf16 one-shot reference, and the
     engine publishes its precision config."""
     model, params = model_and_params
-    eng, toks = _serve(model, params, prompts, paged=paged, kv_dtype="int8")
+    eng, toks = _serve(model, params, prompts, kv_dtype="int8")
     for i, t in enumerate(toks):
         assert_token_parity(t, reference[i], atol=QUANT_ATOL,
-                            err_msg=f"int8-kv {'paged' if paged else 'slot'} "
-                                    f"req {i}")
+                            err_msg=f"int8-kv req {i}")
     snap = eng.metrics.snapshot()
     assert snap["kv_dtype"] == "int8" and snap["weight_dtype"] == "bf16"
     assert snap["kv_bytes_per_token"] > 0 and snap["kv_cache_bytes"] > 0
 
 
-@pytest.mark.parametrize("paged", [
-    # slot 5.6s -> slow (PR 15 tier-1 budget audit): the paged default
-    # layout keeps the tier-1 dequant-in-kernel parity gate; slot x int8
-    # re-runs in the slow matrix
-    pytest.param(False, id="slot", marks=pytest.mark.slow),
-    pytest.param(True, id="paged"),
-])
 def test_int8_kv_parity_flash_interpret(model_and_params, prompts, reference,
-                                        paged, monkeypatch):
-    """The dequant-in-kernel flash-decode variants (contiguous + paged,
-    interpret mode): int8 tiles rescaled in VMEM inside the online
+                                        monkeypatch):
+    """The dequant-in-kernel paged flash-decode variant (interpret
+    mode): int8 tiles rescaled in VMEM inside the online
     softmax must land inside the same tolerance budget as the dense
     dequant — one quantization contract across every attention path."""
     monkeypatch.setenv("FLEETX_FORCE_FLASH", "1")
     model, params = model_and_params
     flash_model = GPTForPretraining(
         dataclasses.replace(CFG, use_flash_attention=True))
-    _, toks = _serve(flash_model, params, prompts, paged=paged,
-                     kv_dtype="int8")
+    _, toks = _serve(flash_model, params, prompts, kv_dtype="int8")
     for i, t in enumerate(toks):
         assert_token_parity(t, reference[i], atol=QUANT_ATOL,
-                            err_msg=f"int8-kv flash "
-                                    f"{'paged' if paged else 'slot'} req {i}")
+                            err_msg=f"int8-kv flash req {i}")
 
 
 @pytest.mark.slow  # 6.4s (PR 15 tier-1 budget audit): weight-int8
@@ -225,7 +213,7 @@ def test_int8_weight_only_parity(model_and_params, prompts, reference):
     (measurably smaller than float), dequant happens inside the jitted
     prefill/decode, and streams stay inside the tolerance budget."""
     model, params = model_and_params
-    eng, toks = _serve(model, params, prompts, paged=True,
+    eng, toks = _serve(model, params, prompts,
                        weight_dtype="int8")
     for i, t in enumerate(toks):
         assert_token_parity(t, reference[i], atol=QUANT_ATOL,
@@ -240,18 +228,16 @@ def test_int8_weight_only_parity(model_and_params, prompts, reference):
 
 def test_int8_kv_halves_cache_bytes(model_and_params):
     """The HBM claim, measured: the int8 cache tree (int8 values + one
-    fp32 scale per head vector) is under half the fp32 tree's bytes on
-    both storage layouts."""
+    fp32 scale per head vector) is under half the fp32 tree's bytes."""
     model, params = model_and_params
-    for paged in (False, True):
-        full = _engine(model, params, paged=paged)
-        quant = _engine(model, params, paged=paged, kv_dtype="int8")
-        fb = full.cache_manager.cache_nbytes()
-        qb = quant.cache_manager.cache_nbytes()
-        assert qb < 0.5 * fb, (paged, qb, fb)
-        assert quant.metrics.snapshot()["kv_cache_bytes"] == qb
-        assert quant.metrics.snapshot()["kv_bytes_per_token"] < (
-            full.metrics.snapshot()["kv_bytes_per_token"])
+    full = _engine(model, params)
+    quant = _engine(model, params, kv_dtype="int8")
+    fb = full.cache_manager.cache_nbytes()
+    qb = quant.cache_manager.cache_nbytes()
+    assert qb < 0.5 * fb, (qb, fb)
+    assert quant.metrics.snapshot()["kv_cache_bytes"] == qb
+    assert quant.metrics.snapshot()["kv_bytes_per_token"] < (
+        full.metrics.snapshot()["kv_bytes_per_token"])
 
 
 # ---------------------------------------------- crash-safety determinism
@@ -263,7 +249,7 @@ def test_int8_replay_recovery_byte_identical(model_and_params, prompts):
     re-prefills through the same quantize-on-write seam (atol=0, not the
     tolerance budget)."""
     model, params = model_and_params
-    kw = dict(paged=True, kv_dtype="int8", weight_dtype="int8")
+    kw = dict(kv_dtype="int8", weight_dtype="int8")
     _, clean = _serve(model, params, prompts, **kw)
     faults.configure(tick_raise="1")
     try:
@@ -284,7 +270,7 @@ def test_int8_manual_recover_byte_identical(model_and_params, prompts):
     rebuilt pool re-quantizes the replayed history and resumes exactly
     where the unfaulted quantized run goes."""
     model, params = model_and_params
-    kw = dict(paged=True, kv_dtype="int8")
+    kw = dict(kv_dtype="int8")
     _, clean = _serve(model, params, prompts, **kw)
     eng = _engine(model, params, **kw)
     rids = [eng.submit(p, max_length=MAX_NEW) for p in prompts]

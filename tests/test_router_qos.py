@@ -65,8 +65,8 @@ def _engine(tiny, **kw):
     gen_cfg = kw.pop("gen_cfg", GEN)
     return ServingEngine(model, params, slots=kw.pop("slots", 2),
                          cache_len=kw.pop("cache_len", 32),
-                         gen_cfg=gen_cfg, prefill_bucket=4,
-                         paged=True, page_size=8, **kw)
+                         gen_cfg=gen_cfg, prefill_bucket=4, page_size=8,
+                         **kw)
 
 
 _CLEAN = {}

@@ -18,7 +18,7 @@ from serving_parity import assert_token_parity, one_shot_tokens
 
 from fleetx_tpu.models.gpt.generation import GenerationConfig, generate
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
-from fleetx_tpu.serving import ServingEngine, ServingMetrics, SlotKVCacheManager
+from fleetx_tpu.serving import ServingEngine, ServingMetrics
 
 CFG = GPTConfig(
     vocab_size=97,
@@ -236,23 +236,20 @@ def test_request_overrides_validated(model_and_params):
 
 # ----------------------------------------------------- unit: manager/metrics
 
-def test_cache_manager_slot_lifecycle(model_and_params):
-    model, _ = model_and_params
-    sized = model.clone(cfg=dataclasses.replace(model.cfg,
-                                                decode_cache_len=16))
-    mgr = SlotKVCacheManager(sized, slots=2, cache_len=16)
-    assert mgr.free_count == 2 and mgr.active_count == 0
-    s0 = mgr.alloc(request_id=7, prompt_len=5)
-    s1 = mgr.alloc(request_id=8, prompt_len=3)
-    assert (s0, s1) == (0, 1)  # deterministic lowest-first
-    assert mgr.alloc(request_id=9, prompt_len=1) is None  # full
-    assert mgr.occupancy() == 1.0
-    mgr.free(s0)
-    assert mgr.request_ids == [None, 8]
-    assert mgr.alloc(request_id=9, prompt_len=2) == 0  # reused
-    mgr.free(0)
-    with pytest.raises(ValueError, match="already free"):
-        mgr.free(0)
+def test_slot_layout_is_refused(model_and_params):
+    """The page pool is the engine's one layout: ``paged=False`` (the
+    fixed per-slot cache, removed at PR 29) raises and names the PR;
+    ``paged=True`` (what perfbench/serving.py passes) and no argument
+    build the same engine, and ``engine.paged`` reads True, which is how
+    the router tells it from a KV-free BatchingEngine."""
+    model, params = model_and_params
+    with pytest.raises(ValueError, match="PR 29"):
+        _engine(model, params, paged=False)
+    a, b = _engine(model, params), _engine(model, params, paged=True)
+    assert a.paged is True and b.paged is True
+    assert (a.num_pages, a.page_size, a.cache_len, a.prefix_cache) == (
+        b.num_pages, b.page_size, b.cache_len, b.prefix_cache)
+    assert type(a.cache_manager) is type(b.cache_manager)
 
 
 def test_metrics_snapshot_shape():

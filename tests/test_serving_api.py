@@ -78,7 +78,6 @@ def _engine(tiny, **kw):
     return ServingEngine(model, params, slots=kw.pop("slots", 3),
                          cache_len=kw.pop("cache_len", 32),
                          gen_cfg=kw.pop("gen_cfg", GEN), prefill_bucket=4,
-                         paged=kw.pop("paged", True),
                          page_size=kw.pop("page_size", 8), **kw)
 
 

@@ -404,14 +404,11 @@ def test_export_admit_parity(model_and_params, kv_dtype):
 
 def test_role_and_payload_validation(model_and_params):
     """The contract edges fail loudly at the right layer: bad role
-    strings, prefill without the paged cache, payload count mismatch,
-    payloads without history, payloads on the slot cache, and
+    strings, payload count mismatch, payloads without history, and
     export_kv of a request that is not parked."""
     model, params = model_and_params
     with pytest.raises(ValueError, match="role"):
         _engine(model, params, role="decoder")
-    with pytest.raises(ValueError, match="paged"):
-        _engine(model, params, role="prefill", paged=False)
 
     eng = _engine(model, params)
     blob = HostPageStore.payload_to_bytes(
@@ -422,10 +419,6 @@ def test_role_and_payload_validation(model_and_params):
         # 3-token prompt needs 1 page; two payloads is a protocol bug
         eng.submit(PROMPTS[0], max_length=8, history=[1],
                    kv_payloads=[blob, blob])
-    slot_eng = _engine(model, params, paged=False, page_size=None)
-    with pytest.raises(ValueError, match="paged"):
-        slot_eng.submit(PROMPTS[0], max_length=8, history=[1],
-                        kv_payloads=[blob])
     with pytest.raises(KeyError, match="not parked"):
         eng.export_kv(12345)
 

@@ -6,8 +6,9 @@ INSIDE tier-1 by design, like tests/test_resilience.py: a serving engine
 that loses tokens under faults is as broken as one that emits wrong ones.
 The load-bearing assertions are byte-parity ones: after any injected
 fault (tick raise, poison request, hung tick, device reset), every
-SURVIVING request's token stream must equal the unfaulted run's, on both
-the slot and the paged cache paths, with PagePool invariants intact.
+SURVIVING request's token stream must equal the unfaulted run's, for
+prompts that share nothing and for prompts that share refcounted prefix
+pages, with PagePool invariants intact.
 """
 
 import signal
@@ -35,6 +36,13 @@ PROMPTS = [np.asarray([1, 2, 3], np.int32),
            np.asarray([4, 5, 6, 7, 8], np.int32),
            np.asarray([9, 10], np.int32),
            np.asarray([11, 12, 13], np.int32)]
+# the same tails behind a two-page common prefix (page 8): admission and
+# replay go through alloc() with shared > 0 and _register_prefix, so
+# refcounted pages ride the fault
+SHARED = [np.concatenate([np.arange(20, 36, dtype=np.int32), p])
+          for p in PROMPTS]
+WORKLOADS = pytest.mark.parametrize(
+    "prompts", [PROMPTS, SHARED], ids=["paged", "paged-shared-prefix"])
 
 
 @pytest.fixture(scope="module")
@@ -56,30 +64,30 @@ def _clean_faults():
     faults.reset()
 
 
-def _engine(tiny, paged, **kw):
+def _engine(tiny, **kw):
     model, params = tiny
     gen_cfg = kw.pop("gen_cfg", None) or GenerationConfig(
         decode_strategy="greedy", eos_token_id=10**6, pad_token_id=60,
         max_length=8)
     return ServingEngine(model, params, slots=3, cache_len=32,
-                         gen_cfg=gen_cfg, prefill_bucket=4, paged=paged,
-                         page_size=8 if paged else None, **kw)
+                         gen_cfg=gen_cfg, prefill_bucket=4, page_size=8,
+                         **kw)
 
 
 def _check_pool(eng):
-    if eng.paged:
-        eng.cache_manager.pool.check_invariants()
+    eng.cache_manager.pool.check_invariants()
 
 
-def _run(tiny, paged, *, fault_kw=None, seeds=None, max_length=8, **ekw):
-    """Submit PROMPTS, drain, return ({rid: tokens}, engine)."""
+def _run(tiny, prompts=PROMPTS, *, fault_kw=None, seeds=None, max_length=8,
+         **ekw):
+    """Submit ``prompts``, drain, return ({rid: tokens}, engine)."""
     if fault_kw:
         faults.configure(**fault_kw)
     try:
-        eng = _engine(tiny, paged, **ekw)
+        eng = _engine(tiny, **ekw)
         rids = [eng.submit(p, max_length=max_length,
                            seed=None if seeds is None else seeds[i])
-                for i, p in enumerate(PROMPTS)]
+                for i, p in enumerate(prompts)]
         res = eng.drain()
     finally:
         faults.reset()
@@ -90,40 +98,49 @@ def _run(tiny, paged, *, fault_kw=None, seeds=None, max_length=8, **ekw):
 _CLEAN = {}
 
 
-def _clean(tiny, paged):
-    """Unfaulted-run token streams, computed once per storage path (every
+def _clean(tiny, prompts=PROMPTS):
+    """Unfaulted-run token streams, computed once per workload (every
     parity test compares against the same greedy baseline; recomputing it
     per test would just re-pay engine compile time)."""
-    if paged not in _CLEAN:
-        _CLEAN[paged] = _run(tiny, paged)[0]
-    return _CLEAN[paged]
+    key = id(prompts)
+    if key not in _CLEAN:
+        _CLEAN[key] = _run(tiny, prompts)[0]
+    return _CLEAN[key]
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["slot", "paged"])
-def test_tick_raise_rollback_and_replay_parity(tiny, paged):
+@WORKLOADS
+def test_tick_raise_rollback_and_replay_parity(tiny, prompts):
     """An injected decode-tick failure rolls the host bookkeeping back and
     replay recovery resumes byte-identically — surviving token streams
-    equal the unfaulted run's on both storage paths."""
-    clean = _clean(tiny, paged)
-    faulty, eng = _run(tiny, paged, fault_kw=dict(tick_raise="1"))
+    equal the unfaulted run's, and replay re-populates the prefix trie
+    (requests behind one system prompt share its pages again)."""
+    clean = _clean(tiny, prompts)
+    faulty, eng = _run(tiny, prompts, fault_kw=dict(tick_raise="1"))
     assert eng.metrics.engine_recoveries == 1
     assert eng.metrics.snapshot()["engine_recoveries"] == 1
+    if prompts is SHARED:
+        assert eng.metrics.snapshot()["prefix_hits"] >= 2
     for i in clean:
         assert_token_parity(clean[i], faulty[i])
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["slot", "paged"])
-def test_manual_recover_is_byte_identical(tiny, paged):
+@WORKLOADS
+def test_manual_recover_is_byte_identical(tiny, prompts):
     """recover() mid-flight (the external-device-reset path) rebuilds the
     caches from prompt + emitted tokens and the finished streams are
     byte-identical to a run that never recovered."""
-    clean = _clean(tiny, paged)
-    eng = _engine(tiny, paged)
-    rids = [eng.submit(p, max_length=8) for p in PROMPTS]
+    clean = _clean(tiny, prompts)
+    eng = _engine(tiny)
+    rids = [eng.submit(p, max_length=8) for p in prompts]
     eng.step()
     eng.step()
     eng.recover()
     _check_pool(eng)
+    if prompts is SHARED:
+        # replay went through alloc(history) with shared > 0: every lane
+        # replayed after the first holds the prefix's two refcounted pages
+        shared = eng.cache_manager.pool.shared_counts
+        assert sorted(int(shared[s]) for s in eng._active) == [0, 2, 2]
     res = eng.drain()
     _check_pool(eng)
     for i, r in enumerate(rids):
@@ -141,8 +158,8 @@ def test_sampling_replay_reconstructs_rng_stream(tiny):
     gen = GenerationConfig(decode_strategy="sampling", temperature=0.9,
                            top_k=8, top_p=0.9, eos_token_id=10**6,
                            pad_token_id=60, max_length=8)
-    clean, _ = _run(tiny, True, gen_cfg=gen, seeds=[100, 101, 102, 103])
-    faulty, eng = _run(tiny, True, gen_cfg=gen, seeds=[100, 101, 102, 103],
+    clean, _ = _run(tiny, gen_cfg=gen, seeds=[100, 101, 102, 103])
+    faulty, eng = _run(tiny, gen_cfg=gen, seeds=[100, 101, 102, 103],
                        fault_kw=dict(tick_raise="2"))
     assert eng.metrics.engine_recoveries == 1
     for i in clean:
@@ -156,7 +173,7 @@ def test_failed_tick_leaves_pre_tick_state(tiny):
     were before that tick."""
     faults.configure(tick_raise="1")
     try:
-        eng = _engine(tiny, True)
+        eng = _engine(tiny)
         rids = [eng.submit(p, max_length=8) for p in PROMPTS]
         eng.step()  # tick 0: admits + first decode (fault tick counter 0)
         tokens_before = {r.id: list(r.tokens)
@@ -173,21 +190,21 @@ def test_failed_tick_leaves_pre_tick_state(tiny):
         res = eng.drain()
     finally:
         faults.reset()
-    clean = _clean(tiny, True)
+    clean = _clean(tiny)
     for i, r in enumerate(rids):
         assert_token_parity(clean[i], np.asarray(res[r].tokens))
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["slot", "paged"])
-def test_poison_request_bisection_neighbor_parity(tiny, paged):
+@WORKLOADS
+def test_poison_request_bisection_neighbor_parity(tiny, prompts):
     """A request whose presence kills the decode step is isolated by
     bisection, retired finish_reason='error' WITH its partial tokens, and
     every neighbor finishes byte-identically to the unfaulted run."""
-    clean = _clean(tiny, paged)
+    clean = _clean(tiny, prompts)
     faults.configure(poison_request="1")
     try:
-        eng = _engine(tiny, paged)
-        rids = [eng.submit(p, max_length=8) for p in PROMPTS]
+        eng = _engine(tiny)
+        rids = [eng.submit(p, max_length=8) for p in prompts]
         res = eng.drain()
     finally:
         faults.reset()
@@ -208,7 +225,7 @@ def test_poison_prefill_quarantined_without_bisection(tiny):
     bisection; the queue keeps serving afterwards."""
     faults.configure(prefill_raise="0+")
     try:
-        eng = _engine(tiny, True)
+        eng = _engine(tiny)
         rid = eng.submit(PROMPTS[0], max_length=8)
         res = eng.drain(max_ticks=10)
     finally:
@@ -217,26 +234,19 @@ def test_poison_prefill_quarantined_without_bisection(tiny):
     assert len(res[rid].tokens) == 0
     _check_pool(eng)
     # engine healthy after the quarantine: a clean request still matches
-    clean = _clean(tiny, True)
+    clean = _clean(tiny)
     rid2 = eng.submit(PROMPTS[0], max_length=8)
     res2 = eng.drain()
     assert_token_parity(clean[0], np.asarray(res2[rid2].tokens))
 
 
-@pytest.mark.parametrize(
-    "paged",
-    [pytest.param(False, marks=pytest.mark.slow), True],
-    # slot variant slow-marked (PR 13 tier-1 budget audit): the watchdog
-    # wraps _run_device identically for both layouts, so the default
-    # (paged) variant keeps the contract tier-1
-    ids=["slot", "paged"])
-def test_hung_tick_watchdog_recovers(tiny, paged):
+def test_hung_tick_watchdog_recovers(tiny):
     """A tick stuck past FLEETX_SERVING_TICK_TIMEOUT_S is abandoned by the
     watchdog (diagnostics banked) and recovery resumes byte-identically.
     The engine is warmed first — the timeout budget is for steady-state
     ticks, not cold XLA compiles."""
-    clean = _clean(tiny, paged)
-    eng = _engine(tiny, paged)
+    clean = _clean(tiny)
+    eng = _engine(tiny)
     eng.submit(np.asarray([50, 51], np.int32), max_length=3)
     eng.drain()  # warm the decode jit
     faults.configure(tick_hang=str(eng._fault_ticks + 1), tick_hang_s=2.0)
@@ -259,7 +269,7 @@ def test_recovery_exhausted_raises(tiny):
     clean) burns the recovery budget and surfaces RecoveryExhausted."""
     faults.configure(tick_raise="0+")
     try:
-        eng = _engine(tiny, True, max_recoveries=3)
+        eng = _engine(tiny, max_recoveries=3)
         eng.submit(PROMPTS[0], max_length=8)
         with pytest.raises(RecoveryExhausted):
             eng.drain(max_ticks=20)
@@ -267,13 +277,13 @@ def test_recovery_exhausted_raises(tiny):
         faults.reset()
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["slot", "paged"])
-def test_shutdown_returns_partials_for_everything(tiny, paged):
+@WORKLOADS
+def test_shutdown_returns_partials_for_everything(tiny, prompts):
     """shutdown() under load: every in-flight request returns with its
     partial tokens and finish_reason='shutdown', queued ones return empty,
     new submits reject with ShuttingDown, drain_rejects counts them."""
-    eng = _engine(tiny, paged)
-    rids = [eng.submit(p, max_length=50) for p in PROMPTS]
+    eng = _engine(tiny)
+    rids = [eng.submit(p, max_length=50) for p in prompts]
     extra = eng.submit(np.asarray([20, 21], np.int32), max_length=50)
     eng.step()
     eng.step()
@@ -294,8 +304,8 @@ def test_shutdown_returns_partials_for_everything(tiny, paged):
 def test_shutdown_with_grace_finishes_short_requests(tiny):
     """Inside a generous grace window the drain FINISHES the work instead
     of truncating it: short requests end eos/max_length, not shutdown."""
-    clean = _clean(tiny, True)
-    eng = _engine(tiny, True)
+    clean = _clean(tiny)
+    eng = _engine(tiny)
     rids = [eng.submit(p, max_length=8) for p in PROMPTS]
     eng.step()
     res = eng.shutdown(grace_s=60.0)
@@ -308,7 +318,7 @@ def test_sigterm_requests_drain(tiny):
     """SIGTERM → request_shutdown via the installed handler: admission
     stops, the running drain loop finishes in-flight work, partials come
     back. The handler chains and uninstall restores the previous one."""
-    eng = _engine(tiny, True)
+    eng = _engine(tiny)
     prev = signal.getsignal(signal.SIGTERM)
     eng.install_sigterm_handler(grace_s=0.0)
     try:
@@ -329,45 +339,13 @@ def test_sigterm_requests_drain(tiny):
     assert signal.getsignal(signal.SIGTERM) is prev
 
 
-@pytest.mark.slow  # 6.8s baseline (PR 14 tier-1 budget audit): paged
-def test_shared_prefix_replay_keeps_trie_sharing(tiny):
-    # replay parity (incl. trie rebuild) stays tier-1 via
-    # test_tick_raise_rollback_and_replay_parity[paged]; trie sharing
-    # itself via test_paged_serving's prefix gates
-    """Replay recovery re-populates the prefix trie: requests sharing a
-    system prompt stay byte-identical through a mid-flight fault and the
-    pool's conservation/refcount invariants hold."""
-    prefix = (np.arange(16, dtype=np.int32) + 20)
-    prompts = [np.concatenate([prefix, np.asarray([i + 1], np.int32)])
-               for i in range(3)]
-
-    def run(fault):
-        if fault:
-            faults.configure(tick_raise="2")
-        try:
-            eng = _engine(tiny, True)
-            rids = [eng.submit(p, max_length=6) for p in prompts]
-            res = eng.drain()
-        finally:
-            faults.reset()
-        _check_pool(eng)
-        return [np.asarray(res[r].tokens) for r in rids], eng
-
-    clean, _ = run(False)
-    faulty, eng = run(True)
-    assert eng.metrics.engine_recoveries == 1
-    assert eng.metrics.snapshot()["prefix_hits"] >= 2
-    for a, b in zip(clean, faulty):
-        assert_token_parity(a, b)
-
-
 @pytest.mark.slow  # 10.2s baseline (PR 14 tier-1 budget audit): the
 def test_tick_wallclock_metrics_present(tiny):
     # tick_ms_p50/p99 schema stays tier-1 via the bench faulted record's
     # schema test (asserts both > 0 on a recovered engine)
     """Per-tick wall-clock percentiles ride the snapshot so recovery cost
     is observable next to steady-state ticks."""
-    _, eng = _run(tiny, True)
+    _, eng = _run(tiny)
     snap = eng.metrics.snapshot()
     assert snap["tick_ms_p50"] is not None
     assert snap["tick_ms_p99"] >= snap["tick_ms_p50"]

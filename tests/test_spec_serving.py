@@ -6,9 +6,9 @@ Acceptance gates for ``FLEETX_SERVING_SPEC=1`` (docs/SERVING.md
 - **Greedy byte parity** — a speculative engine's greedy streams are
   byte-identical to the non-speculative engine (and therefore to the
   one-shot ``generate()`` reference the serving suites already gate on)
-  across slot + paged storage, bf16(f32) + int8 KV, dense + flash-
-  interpret attention, and both proposers. Compact slot/paged gates run
-  tier-1; the full matrix is slow-marked.
+  across bf16(f32) + int8 KV, dense + flash-interpret attention, and
+  both proposers. Compact gates run tier-1; the full matrix is
+  slow-marked.
 - **Edge cases** — a draft can never overrun a request's token budget
   (k ≥ remaining), its lane/page capacity (cache-capacity edge — the
   PR 11 chunk-edge precedent), or run past an EOS emitted inside the
@@ -79,8 +79,7 @@ def _engine(model, params, **kw):
     kw.setdefault("cache_len", 32)
     kw.setdefault("gen_cfg", GREEDY)
     kw.setdefault("prefill_bucket", 4)
-    if kw.get("paged"):
-        kw.setdefault("page_size", 8)
+    kw.setdefault("page_size", 8)
     return ServingEngine(model, params, **kw)
 
 
@@ -95,30 +94,19 @@ def _serve(model, params, prompts, max_length=MAX_NEW, submit_kw=None,
 
 # ------------------------------------------------- tier-1 byte-parity gates
 
-@pytest.mark.parametrize("paged", [
-    # slot 6.4s -> slow (PR 15 tier-1 budget audit): the paged default
-    # layout keeps the tier-1 spec byte-parity gate; slot x spec re-runs
-    # in the slow matrix
-    pytest.param(False, id="slot", marks=pytest.mark.slow),
-    pytest.param(True, id="paged"),
-])
-def test_spec_greedy_byte_parity(model_and_params, prompts, paged):
+def test_spec_greedy_byte_parity(model_and_params, prompts):
     """THE gate: speculative greedy streams are byte-identical to the
-    non-speculative engine on both storage layouts, and the engine
-    actually speculated (drafts proposed, some accepted, spec metrics
-    live)."""
+    non-speculative engine, and the engine actually speculated (drafts
+    proposed, some accepted, spec metrics live)."""
     model, params = model_and_params
-    _, base = _serve(model, params, prompts, paged=paged)
-    eng, spec = _serve(model, params, prompts, paged=paged, spec=True,
-                       spec_k=4)
+    _, base = _serve(model, params, prompts)
+    eng, spec = _serve(model, params, prompts, spec=True, spec_k=4)
     for i, (a, b) in enumerate(zip(base, spec)):
-        assert_token_parity(b, a, err_msg=f"spec {'paged' if paged else 'slot'}"
-                                          f" req {i}")
+        assert_token_parity(b, a, err_msg=f"spec req {i}")
     snap = eng.metrics.snapshot()
     assert snap["spec_proposed_tokens"] > 0
     assert snap["spec_tokens_per_tick_mean"] is not None
-    if paged:
-        eng.cache_manager.pool.check_invariants()
+    eng.cache_manager.pool.check_invariants()
 
 
 @pytest.mark.slow  # ~13s; redundant composition — spec==non-spec is the
@@ -129,7 +117,7 @@ def test_spec_matches_one_shot_generate(model_and_params, prompts):
     per-request one-shot ``generate()`` streams byte-exactly (the same
     reference every serving suite gates on)."""
     model, params = model_and_params
-    _, spec = _serve(model, params, prompts, paged=True, spec=True,
+    _, spec = _serve(model, params, prompts, spec=True,
                      spec_k=4)
     for i, (p, got) in enumerate(zip(prompts, spec)):
         want = one_shot_tokens(model, params, p, MAX_NEW, gen_cfg=GREEDY)
@@ -141,7 +129,7 @@ def test_spec_off_is_default_and_inert(model_and_params, prompts):
     proposer/verify machinery constructed at all — the existing serving
     suites run exactly the pre-spec engine."""
     model, params = model_and_params
-    eng = _engine(model, params, paged=True)
+    eng = _engine(model, params)
     assert eng.spec is False and eng._proposer is None
     assert not hasattr(eng, "_verify_jit")
 
@@ -156,8 +144,8 @@ def test_spec_draft_clamped_to_budget(model_and_params, prompts):
     """k ≥ remaining budget: 2-token requests under k=6 emit exactly 2
     tokens, byte-unchanged."""
     model, params = model_and_params
-    _, base = _serve(model, params, prompts[:3], max_length=2, paged=True)
-    _, spec = _serve(model, params, prompts[:3], max_length=2, paged=True,
+    _, base = _serve(model, params, prompts[:3], max_length=2)
+    _, spec = _serve(model, params, prompts[:3], max_length=2,
                      spec=True, spec_k=6)
     for a, b in zip(base, spec):
         assert len(b) == 2
@@ -175,7 +163,7 @@ def test_spec_eos_inside_draft_window(model_and_params, prompts):
     eos = int(probe[2])
 
     def run(spec):
-        eng = _engine(model, params, paged=True, spec=spec, spec_k=6)
+        eng = _engine(model, params, spec=spec, spec_k=6)
         rid = eng.submit(prompts[0], max_length=MAX_NEW, eos_token_id=eos)
         return eng.drain()[rid]
 
@@ -197,21 +185,18 @@ def test_spec_cache_capacity_edge(model_and_params):
     model, params = model_and_params
     prompt = np.arange(1, 17, dtype=np.int32)  # 16 of cache_len 24
 
-    def run(spec, paged):
-        eng = _engine(model, params, slots=1, cache_len=24, paged=paged,
-                      spec=spec, spec_k=8)
+    def run(spec):
+        eng = _engine(model, params, slots=1, cache_len=24, spec=spec,
+                      spec_k=8)
         rid = eng.submit(prompt, max_length=50)  # clamps to 8
         res = eng.drain()[rid]
-        if paged:
-            eng.cache_manager.pool.check_invariants()
+        eng.cache_manager.pool.check_invariants()
         return res
 
-    for paged in (False, True):
-        a, b = run(False, paged), run(True, paged)
-        assert len(a.tokens) == len(b.tokens) == 8
-        assert_token_parity(b.tokens, a.tokens,
-                            err_msg=f"capacity edge paged={paged}")
-        assert a.finish_reason == b.finish_reason
+    a, b = run(False), run(True)
+    assert len(a.tokens) == len(b.tokens) == 8
+    assert_token_parity(b.tokens, a.tokens, err_msg="capacity edge")
+    assert a.finish_reason == b.finish_reason
 
 
 def test_spec_near_dry_pool_matches_plain(model_and_params):
@@ -228,7 +213,7 @@ def test_spec_near_dry_pool_matches_plain(model_and_params):
     def run(spec):
         # 8 usable pages of 8 tokens = exactly 2 lanes x (7 prompt + 20
         # decode = 27 tokens -> 4 pages); zero slack for draft windows
-        eng = _engine(model, params, slots=2, cache_len=32, paged=True,
+        eng = _engine(model, params, slots=2, cache_len=32,
                       num_pages=9, prefix_cache=False, spec=spec,
                       spec_k=4)
         rids = [eng.submit(p, max_length=20) for p in prompts]
@@ -266,7 +251,7 @@ def test_spec_acceptance_on_repetitive_prompt(model_and_params):
     model, params = model_and_params
     motif = np.asarray([11, 23, 5, 42], np.int32)
     prompt = np.tile(motif, 3)
-    eng = _engine(model, params, slots=1, paged=True, spec=True, spec_k=4)
+    eng = _engine(model, params, slots=1, spec=True, spec_k=4)
     rid = eng.submit(prompt, max_length=16)
     res = eng.drain()[rid]
     assert len(res.tokens) == 16
@@ -289,11 +274,11 @@ def test_spec_verify_fault_rolls_back_and_recovers(model_and_params,
     drops the un-verified draft (per-request spec counters included),
     replay recovery resumes byte-identically, speculation stays on."""
     model, params = model_and_params
-    _, clean = _serve(model, params, prompts, paged=True, spec=True,
+    _, clean = _serve(model, params, prompts, spec=True,
                       spec_k=4)
     faults.configure(tick_raise="1")
     try:
-        eng, faulty = _serve(model, params, prompts, paged=True, spec=True,
+        eng, faulty = _serve(model, params, prompts, spec=True,
                              spec_k=4)
     finally:
         faults.reset()
@@ -332,10 +317,52 @@ def test_ngram_proposer_matching():
         NgramProposer(max_n=2, min_n=3)
 
 
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
+def test_scatter_gather_slot_roundtrip(model_and_params, kv_dtype):
+    """The draft model's private slot-layout cache (the one contiguous
+    cache left in serving/): ``scatter_slot`` writes a one-row tree into
+    lane ``s`` and ``_gather_slot`` reads it back, K/V leaves and (int8)
+    their scale leaves alike; every other lane's rows and the
+    ``cache_index`` scalars stay as they were. The draft-model suites
+    that reach it end to end are slow."""
+    from fleetx_tpu.models.gpt.generation import init_decode_cache
+    from fleetx_tpu.serving.cache_manager import KV_LEAF_RANK
+    from fleetx_tpu.serving.spec import _gather_slot, scatter_slot
+
+    model, _ = model_and_params
+    sized = model.clone(cfg=dataclasses.replace(
+        model.cfg, decode_cache_len=16, decode_kv_dtype=kv_dtype))
+
+    def filled(lanes, seed):
+        leaves, tree = jax.tree.flatten(init_decode_cache(sized, lanes))
+        rng = np.random.RandomState(seed)
+        return jax.tree.unflatten(tree, [
+            jnp.asarray(rng.randint(-100, 100, x.shape), x.dtype)
+            for x in leaves])
+
+    big, small = filled(3, 0), filled(1, 1)
+    kinds = {str(x.dtype) for x in jax.tree.leaves(big)}
+    assert ("int8" in kinds) == (kv_dtype == "int8"), kinds
+    lane = jnp.asarray(1, jnp.int32)  # traced in the proposer's jit
+    out = jax.jit(scatter_slot)(big, small, lane)
+    back = jax.jit(_gather_slot)(out, lane)
+    for o, b, s, r in zip(*map(jax.tree.leaves, (out, big, small, back))):
+        if b.ndim < KV_LEAF_RANK:  # cache_index scalars: untouched
+            np.testing.assert_array_equal(o, b)
+            np.testing.assert_array_equal(r, b)
+            continue
+        np.testing.assert_array_equal(r, s)
+        ax = b.ndim - KV_LEAF_RANK
+        for other in (0, 2):
+            np.testing.assert_array_equal(
+                np.take(np.asarray(o), other, axis=ax),
+                np.take(np.asarray(b), other, axis=ax))
+
+
 @pytest.mark.slow  # 7.2s baseline (PR 14 tier-1 budget audit): the
 def test_draft_model_proposer_lane_lifecycle(model_and_params):
     # self-draft proposer's end-to-end contract stays covered by the
-    # slow matrix (slot+paged x ngram+self-draft parity); the n-gram
+    # slow matrix (ngram+self-draft parity); the n-gram
     # proposer units above remain tier-1
     """The draft proposer's lane protocol without an engine: catch-up
     prefill on first propose, drafts equal the model's own greedy
@@ -395,7 +422,7 @@ def test_spec_self_draft_acceptance_one(model_and_params, prompts,
     by construction: acceptance rate 1.0 and one-shot byte parity."""
     model, params = model_and_params
     monkeypatch.setenv("FLEETX_SERVING_SPEC_DRAFT", "self")
-    eng, toks = _serve(model, params, prompts[:2], paged=True, spec=True,
+    eng, toks = _serve(model, params, prompts[:2], spec=True,
                        spec_k=3)
     assert eng._proposer.name == "draft"
     snap = eng.metrics.snapshot()
@@ -417,8 +444,7 @@ def test_spec_sampling_topk1_byte_parity(model_and_params, prompts):
     matching draft, residual never sampled) — gated through the shared
     parity harness like every other serving mode."""
     model, params = model_and_params
-    kw = dict(paged=True,
-              submit_kw=dict(decode_strategy="sampling", top_k=1))
+    kw = dict(submit_kw=dict(decode_strategy="sampling", top_k=1))
     _, base = _serve(model, params, prompts[:3], **kw)
     _, spec = _serve(model, params, prompts[:3], spec=True, spec_k=4, **kw)
     for i, (a, b) in enumerate(zip(base, spec)):
@@ -441,7 +467,7 @@ def test_spec_sampling_distribution_preserved(model_and_params):
 
     def second_tokens(spec):
         eng = _engine(model, params, slots=8, cache_len=16, gen_cfg=cfg,
-                      paged=True, spec=spec, spec_k=3)
+                      spec=spec, spec_k=3)
         rids = [eng.submit(p, max_length=3, seed=1000 + i)
                 for i in range(96)]
         res = eng.drain()
@@ -458,8 +484,8 @@ def test_spec_sampling_distribution_preserved(model_and_params):
 
 @pytest.mark.slow  # full storage × precision × attention × proposer
 def test_spec_parity_matrix(model_and_params, prompts, monkeypatch):
-    # matrix; the compact slot/paged bf16 gates above stay tier-1
-    """Greedy parity across slot+paged × f32+int8-KV × dense+flash-
+    # matrix; the compact bf16 gates above stay tier-1
+    """Greedy parity across f32+int8-KV × dense+flash-
     interpret × ngram+self-draft: int8 configs must match THEIR OWN
     non-speculative int8 engine byte-exactly (speculation is a
     scheduling change — the quantization noise is deterministic and
@@ -470,23 +496,19 @@ def test_spec_parity_matrix(model_and_params, prompts, monkeypatch):
         dataclasses.replace(CFG, use_flash_attention=True))
     for use_flash in (False, True):
         m = flash_model if use_flash else model
-        for paged in (False, True):
-            for kv in (None, "int8"):
-                kw = dict(paged=paged)
-                if kv:
-                    kw["kv_dtype"] = kv
-                _, base = _serve(m, params, prompts, **kw)
-                for proposer in ("ngram", "self"):
-                    if proposer == "self":
-                        monkeypatch.setenv("FLEETX_SERVING_SPEC_DRAFT",
-                                           "self")
-                    else:
-                        monkeypatch.delenv("FLEETX_SERVING_SPEC_DRAFT",
-                                           raising=False)
-                    _, spec = _serve(m, params, prompts, spec=True,
-                                     spec_k=4, **kw)
-                    for i, (a, b) in enumerate(zip(base, spec)):
-                        assert_token_parity(
-                            b, a,
-                            err_msg=f"flash={use_flash} paged={paged} "
-                                    f"kv={kv} proposer={proposer} req {i}")
+        for kv in (None, "int8"):
+            kw = dict(kv_dtype=kv) if kv else {}
+            _, base = _serve(m, params, prompts, **kw)
+            for proposer in ("ngram", "self"):
+                if proposer == "self":
+                    monkeypatch.setenv("FLEETX_SERVING_SPEC_DRAFT", "self")
+                else:
+                    monkeypatch.delenv("FLEETX_SERVING_SPEC_DRAFT",
+                                       raising=False)
+                _, spec = _serve(m, params, prompts, spec=True, spec_k=4,
+                                 **kw)
+                for i, (a, b) in enumerate(zip(base, spec)):
+                    assert_token_parity(
+                        b, a,
+                        err_msg=f"flash={use_flash} kv={kv} "
+                                f"proposer={proposer} req {i}")

@@ -21,8 +21,8 @@ per scenario, non-zero exit on any failure:
   raising ``on_token`` callback retires only its own request while a
   clean request keeps one-shot parity;
 - ``serving_recovery``: an injected decode-tick failure rolls the tick
-  back and replay recovery resumes byte-identically (slot AND paged
-  paths, PagePool invariants checked);
+  back and replay recovery resumes byte-identically (PagePool
+  invariants checked);
 - ``serving_poison``: a poison request is isolated by bisection and
   quarantined with partial tokens while neighbors keep byte parity;
 - ``serving_hang``: a hung tick trips the FLEETX_SERVING_TICK_TIMEOUT_S
@@ -408,10 +408,10 @@ def _serving_fixture():
                np.asarray([9, 10], np.int32),
                np.asarray([11, 12, 13], np.int32)]
 
-    def make(paged, **kw):
+    def make(**kw):
         return ServingEngine(model, params, slots=3, cache_len=32,
-                             gen_cfg=gen_cfg, prefill_bucket=4, paged=paged,
-                             page_size=8 if paged else None, **kw)
+                             gen_cfg=gen_cfg, prefill_bucket=4, page_size=8,
+                             **kw)
 
     return make, prompts
 
@@ -425,35 +425,32 @@ def _run_workload(eng, prompts, max_length=8):
 
 
 def scenario_serving_recovery(tmp):
-    """Tick-raise -> rollback + replay recovery, byte parity both paths."""
+    """Tick-raise -> rollback + replay recovery, byte parity."""
     import numpy as np
 
     from fleetx_tpu.resilience.faults import faults
 
     make, prompts = _serving_fixture()
-    recov = []
-    for paged in (False, True):
-        clean, _, _ = _run_workload(make(paged), prompts)
-        faults.configure(tick_raise="1")
-        try:
-            eng = make(paged)
-            faulty, _, _ = _run_workload(eng, prompts)
-        finally:
-            faults.reset()
-        assert eng.metrics.engine_recoveries == 1, eng.metrics.snapshot()
-        assert all(np.array_equal(a, b) for a, b in zip(clean, faulty)), \
-            f"paged={paged} tokens diverged after recovery"
-        if paged:
-            eng.cache_manager.pool.check_invariants()
-        recov.append(eng.metrics.engine_recoveries)
+    clean, _, _ = _run_workload(make(), prompts)
+    faults.configure(tick_raise="1")
+    try:
+        eng = make()
+        faulty, _, _ = _run_workload(eng, prompts)
+    finally:
+        faults.reset()
+    assert eng.metrics.engine_recoveries == 1, eng.metrics.snapshot()
+    assert all(np.array_equal(a, b) for a, b in zip(clean, faulty)), \
+        "tokens diverged after recovery"
+    eng.cache_manager.pool.check_invariants()
     from fleetx_tpu.obs import get_event_log
 
     ev = get_event_log()
-    assert len(ev.find("engine_recovery")) == 2, \
-        "each recovery must bank an engine_recovery event"
-    assert len(ev.find("tick_fault")) == 2, "tick faults unbanked"
-    return ("tick-raise recovered byte-identically on slot AND paged paths "
-            f"(engine_recoveries={recov}, events banked)")
+    assert len(ev.find("engine_recovery")) == 1, \
+        "the recovery must bank an engine_recovery event"
+    assert len(ev.find("tick_fault")) == 1, "tick fault unbanked"
+    return ("tick-raise recovered byte-identically "
+            f"(engine_recoveries={eng.metrics.engine_recoveries}, "
+            "events banked)")
 
 
 def scenario_serving_poison(tmp):
@@ -463,10 +460,10 @@ def scenario_serving_poison(tmp):
     from fleetx_tpu.resilience.faults import faults
 
     make, prompts = _serving_fixture()
-    clean, _, _ = _run_workload(make(True), prompts)
+    clean, _, _ = _run_workload(make(), prompts)
     faults.configure(poison_request="1")
     try:
-        eng = make(True)
+        eng = make()
         _, res, rids = _run_workload(eng, prompts)
     finally:
         faults.reset()
@@ -496,8 +493,8 @@ def scenario_serving_hang(tmp):
     from fleetx_tpu.resilience.faults import faults
 
     make, prompts = _serving_fixture()
-    clean, _, _ = _run_workload(make(True), prompts)
-    eng = make(True)
+    clean, _, _ = _run_workload(make(), prompts)
+    eng = make()
     eng.submit(np.asarray([50, 51], np.int32), max_length=3)
     eng.drain()  # warm the decode jit: the budget is for steady-state ticks
     faults.configure(tick_hang=str(eng._fault_ticks + 1), tick_hang_s=2.0)
@@ -524,7 +521,7 @@ def scenario_serving_drain(tmp):
     from fleetx_tpu.serving import ShuttingDown
 
     make, prompts = _serving_fixture()
-    eng = make(True)
+    eng = make()
     rids = [eng.submit(p, max_length=50) for p in prompts]
     eng.step()
     eng.step()
@@ -558,15 +555,15 @@ def scenario_serving_spec(tmp):
     from fleetx_tpu.resilience.faults import faults
 
     make, prompts = _serving_fixture()
-    plain, _, _ = _run_workload(make(True), prompts)
-    clean_eng = make(True, spec=True, spec_k=4)
+    plain, _, _ = _run_workload(make(), prompts)
+    clean_eng = make(spec=True, spec_k=4)
     clean, _, _ = _run_workload(clean_eng, prompts)
     # speculation must not move a byte even before any fault
     assert all(np.array_equal(a, b) for a, b in zip(plain, clean)), \
         "speculative engine diverged from the plain engine"
     faults.configure(tick_raise="1")  # the first verify attempt dies
     try:
-        eng = make(True, spec=True, spec_k=4)
+        eng = make(spec=True, spec_k=4)
         faulty, _, _ = _run_workload(eng, prompts)
     finally:
         faults.reset()
@@ -609,14 +606,14 @@ def scenario_serving_mesh(tmp):
 
     make, prompts = _serving_fixture()
     mesh = build_mesh(MeshConfig(mp=2), jax.devices()[:2])
-    single = make(True)
+    single = make()
     clean, _, _ = _run_workload(single, prompts)
-    meshed, _, _ = _run_workload(make(True, mesh=mesh), prompts)
+    meshed, _, _ = _run_workload(make(mesh=mesh), prompts)
     assert all(np.array_equal(a, b) for a, b in zip(clean, meshed)), \
         "mesh-sharded engine diverged from the single-device engine"
     faults.configure(tick_raise="1")
     try:
-        eng = make(True, mesh=mesh)
+        eng = make(mesh=mesh)
         faulty, _, _ = _run_workload(eng, prompts)
     finally:
         faults.reset()
@@ -667,7 +664,7 @@ def scenario_serving_spill(tmp):
     params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     # smallest legal pool (4 usable pages) + chunked prefill + host tier
     eng = ServingEngine(model, params, slots=2, cache_len=32, gen_cfg=gen_cfg,
-                        prefill_bucket=4, paged=True, page_size=8,
+                        prefill_bucket=4, page_size=8,
                         num_pages=5, prefill_chunk=6,
                         host_cache_bytes=1 << 20)
     rng = np.random.RandomState(5)
@@ -688,7 +685,7 @@ def scenario_serving_spill(tmp):
         [sys_a, rng.randint(1, 61, (10,))]).astype(np.int32)
     want = _run_workload(  # byte-parity reference from a clean engine
         ServingEngine(model, params, slots=2, cache_len=32, gen_cfg=gen_cfg,
-                      prefill_bucket=4, paged=True, page_size=8,
+                      prefill_bucket=4, page_size=8,
                       num_pages=5, prefill_chunk=6,
                       host_cache_bytes=1 << 20), [victim], 4)[0][0]
     revived_before = store.revived_pages
@@ -745,7 +742,7 @@ def scenario_router_kill(tmp):
     make, prompts = _serving_fixture()
     # clean single-replica reference streams (batch composition never
     # changes greedy tokens, so one engine is THE reference)
-    clean, _, _ = _run_workload(make(True), prompts)
+    clean, _, _ = _run_workload(make(), prompts)
     streams = {}
 
     def cb(rid, tok, fin):
@@ -753,7 +750,7 @@ def scenario_router_kill(tmp):
 
     faults.configure(replica_kill="1:3")
     try:
-        router = ServingRouter([make(True) for _ in range(3)],
+        router = ServingRouter([make() for _ in range(3)],
                                probe_every=1)
         rids = [router.submit(p, max_length=8, on_token=cb)
                 for p in prompts]
@@ -787,7 +784,7 @@ def scenario_router_kill(tmp):
     trace = generate_trace(spec)
     faults.configure(replica_kill="0:4")
     try:
-        router2 = ServingRouter([make(True) for _ in range(3)],
+        router2 = ServingRouter([make() for _ in range(3)],
                                 probe_every=1)
         score = score_goodput(run_trace(router2, trace))
     finally:
@@ -809,7 +806,7 @@ def scenario_router_saturation(tmp):
     from fleetx_tpu.serving import QueueFull, ServingRouter
 
     make, prompts = _serving_fixture()
-    router = ServingRouter([make(True)], max_queue=6)
+    router = ServingRouter([make()], max_queue=6)
     accepted, rejected = [], 0
     # a burst far past one 3-slot replica: the bounded queue must reject
     # the overflow, and the tight-deadline stragglers must shed as
@@ -857,7 +854,7 @@ def scenario_serving_disagg(tmp):
     from fleetx_tpu.serving import ServingRouter
 
     make, prompts = _serving_fixture()
-    clean, _, _ = _run_workload(make(True), prompts)
+    clean, _, _ = _run_workload(make(), prompts)
 
     def run_router(router):
         rids = [router.submit(p, max_length=8) for p in prompts]
@@ -867,8 +864,8 @@ def scenario_serving_disagg(tmp):
         return [np.asarray(res[r].tokens) for r in rids]
 
     # 1) clean disaggregated pass: 1 prefill + 1 decode == colocated
-    router = ServingRouter([make(True, role="prefill"),
-                            make(True, role="decode")], probe_every=1)
+    router = ServingRouter([make(role="prefill"),
+                            make(role="decode")], probe_every=1)
     got = run_router(router)
     assert all(np.array_equal(a, b) for a, b in zip(clean, got)), \
         "disaggregated tokens diverged from colocated"
@@ -885,7 +882,7 @@ def scenario_serving_disagg(tmp):
     faults.configure(kv_ship_corrupt="1")
     try:
         got = run_router(ServingRouter(
-            [make(True, role="prefill"), make(True, role="decode")],
+            [make(role="prefill"), make(role="decode")],
             probe_every=1))
     finally:
         faults.reset()
@@ -901,7 +898,7 @@ def scenario_serving_disagg(tmp):
     faults.configure(replica_kill="0:3")
     try:
         got = run_router(ServingRouter(
-            [make(True, role="prefill"), make(True, role="decode")],
+            [make(role="prefill"), make(role="decode")],
             probe_every=1, probe_max_failures=1))
     finally:
         faults.reset()
@@ -1012,7 +1009,7 @@ def scenario_serving_hetero(tmp):
     )
 
     make, prompts = _serving_fixture()
-    clean, _, _ = _run_workload(make(True), prompts)
+    clean, _, _ = _run_workload(make(), prompts)
 
     vcfg = ViTConfig(image_size=8, patch_size=4, in_channels=3,
                      num_classes=0, hidden_size=32, num_layers=2,
@@ -1058,7 +1055,7 @@ def scenario_serving_hetero(tmp):
     faults.configure(replica_kill="2:1,1:3")
     try:
         router = ServingRouter(
-            [tap(make(True), "gpt"), tap(make(True), "gpt"),
+            [tap(make(), "gpt"), tap(make(), "gpt"),
              tap(make_emb(), "vit"), tap(make_emb(), "vit")],
             probe_every=1)
         rids = []  # (family, index, rid)
@@ -1129,9 +1126,9 @@ def scenario_serving_qos(tmp):
     # clean references from a lone uncontended engine: greedy decode is
     # batch-composition-invariant, so these are THE bytes every tenant
     # must reproduce through preemption, migration, and the kill
-    clean_paid, _, _ = _run_workload(make(True), prompts)
+    clean_paid, _, _ = _run_workload(make(), prompts)
     flood_ref = {}
-    ref = make(True)
+    ref = make()
     for j, p in enumerate(flood_prompts):
         flood_ref[j] = ref.submit(p, max_length=16)
     ref_res = ref.drain()
@@ -1141,7 +1138,7 @@ def scenario_serving_qos(tmp):
     faults.configure(replica_kill="1:6")
     try:
         router = ServingRouter(
-            [make(True, max_queue=1) for _ in range(2)],
+            [make(max_queue=1) for _ in range(2)],
             tenants={"paid": TenantPolicy(weight=4.0, priority=1),
                      "flood": TenantPolicy(weight=1.0, max_queue=4)},
             probe_every=1, preempt_risk_frac=0.0)

@@ -101,8 +101,7 @@ def _build_demo_engine(seed: int = 0):
     params = model.init(jax.random.PRNGKey(seed),
                         jnp.zeros((2, 8), jnp.int32))
     return ServingEngine(model, params, slots=4, cache_len=32,
-                         gen_cfg=gen_cfg, prefill_bucket=4,
-                         paged=True, page_size=8)
+                         gen_cfg=gen_cfg, prefill_bucket=4, page_size=8)
 
 
 def run_replica_worker(args) -> int:
