@@ -74,15 +74,34 @@ projection GSPMD already manages.
 Paged variant (:func:`flash_decode_paged_attention`): the serving engine's
 page-granular cache stores K/V as ``[num_pages, page_size, h*d]`` shared
 pages and each batch row addresses its logical window through a block
-table of page indices (serving/cache_manager.py). The kernel body is THE
-SAME online-softmax walk with ``major == page_size`` — only the K/V index
-maps change: the per-row block table rides scalar prefetch next to
-``starts``/``ends``, and grid step ``jm`` (the row's logical page index)
-gathers physical page ``table[b, jm]`` instead of streaming block ``jm``
-of a contiguous buffer. Dead steps still clamp into the live
-``[first, last]`` logical range, so they repeat a resident physical page
-and trigger no DMA; pages shared between rows (prefix reuse) are simply
-gathered by several rows' tables.
+table of page indices (serving/cache_manager.py), which rides scalar
+prefetch next to ``starts``/``ends``. The body is THE SAME online-softmax
+walk (:func:`_decode_kernel`); what differs is how a step's rows reach
+VMEM, and how many they are. A grid step covers **P consecutive logical
+pages** of a row, grid ``(b, ceil(pages of a row / P))``, where P follows
+from the shapes the call sees (:func:`_pages_per_step`): ``block_k`` rows
+(``FLEETX_DECODE_BLOCK_K``, 256: the contiguous kernel's tile) over the
+page size, capped at the table's width: 16 pages of 16 rows a step. The
+MXU pays a matmul by the weight tiles it loads, not by the rows of the
+tile, so a step of one 16-row page cost what a step of 256 rows costs
+(PERF.md, PR 30).
+- P > 1 (:func:`_paged_block_call`): the pools stay in HBM and a live
+  step's LIVE pages are copied, one async copy a page through the row's
+  table, side by side into a double-buffered ``[2, P * page_size, h*d]``
+  VMEM tile; each live step starts the next live step's copies before it
+  waits for its own. Pages of the block outside ``[first, last]`` are not
+  copied and their rows are masked by position, so a call reads the rows'
+  live pages, rounded up to pages and never to blocks; a step wholly
+  outside the window does nothing.
+- P = 1 (a page is ``block_k`` rows already, or a page is below the pool
+  dtype's packed tile, as a 16-row int8 page is half a (32, 128) tile and
+  P of them would need a relayout to lie side by side): a step is a page,
+  streamed by the BlockSpec pipeline like the contiguous kernel's blocks
+  with ``major == page_size``; the index map gathers physical page
+  ``table[b, jm]``, and dead steps clamp into the live ``[first, last]``
+  logical range, so they repeat a resident physical page (no DMA).
+Either way pages shared between rows (prefix reuse) are simply gathered
+by several rows' tables.
 """
 
 from __future__ import annotations
@@ -276,7 +295,8 @@ def _sharded_decode(mesh, starts_b, ends_b, operands, tables=None,
 
 def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr, *, block_k: int, major: int,
-                   scale: float, heads: int, ks_ref=None, vs_ref=None):
+                   scale: float, heads: int, ks_ref=None, vs_ref=None,
+                   gather=None):
     """Grid step (batch bi, K/V major block jm): online-softmax update of
     ALL heads' single query row against the live tiles of the resident
     major block.
@@ -301,7 +321,12 @@ def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
     scale is constant over that head's d lanes, so it factors out of both
     dots: the int8 tile enters the MXU as exact small integers and the
     [rows, block_k] score / probability tile is multiplied by the
-    transposed scale tile (module docstring "Int8 KV")."""
+    transposed scale tile (module docstring "Int8 KV").
+
+    ``gather`` (the paged kernel at several pages a step,
+    :func:`_paged_block_call`) is called at the top of a live step and
+    returns the cache refs in place of the four above: the step's pages
+    copied side by side into VMEM."""
     bi = pl.program_id(0)
     jm = pl.program_id(1)
     start = starts_ref[bi]
@@ -324,6 +349,8 @@ def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when((jm >= first_jm) & (jm <= last_jm))
     def _step():
+        k_rows, v_rows, ks_rows, vs_rows = (
+            (k_ref, v_ref, ks_ref, vs_ref) if gather is None else gather())
         mm_dt = _mm_dtype(q_ref.dtype)
         # select in f32: the iota mask has the 32-bit tile layout, which
         # Mosaic will not relayout onto a packed bf16 operand
@@ -338,11 +365,11 @@ def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
         def body(t, carry):
             m, l, acc = carry
             row0 = pl.multiple_of(t * block_k, block_k)
-            k_blk = k_ref[pl.ds(row0, block_k), :].astype(mm_dt)
-            v_blk = v_ref[pl.ds(row0, block_k), :].astype(mm_dt)
+            k_blk = k_rows[pl.ds(row0, block_k), :].astype(mm_dt)
+            v_blk = v_rows[pl.ds(row0, block_k), :].astype(mm_dt)
             s = _dot(q_bd, k_blk, _NT) * scale  # [rows, block_k]
-            if ks_ref is not None:
-                s = s * _scale_rows(ks_ref[pl.ds(row0, block_k), :], rows)
+            if ks_rows is not None:
+                s = s * _scale_rows(ks_rows[pl.ds(row0, block_k), :], rows)
             k_row = (jm * major + t * block_k
                      + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1))
             s = jnp.where((k_row >= start) & (k_row < end), s, NEG_INF)
@@ -353,8 +380,8 @@ def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
             p = jnp.where(s > NEG_INF / 2, p, 0.0)
             alpha = jnp.exp(m - m_new)
             l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-            if vs_ref is not None:
-                p = p * _scale_rows(vs_ref[pl.ds(row0, block_k), :], rows)
+            if vs_rows is not None:
+                p = p * _scale_rows(vs_rows[pl.ds(row0, block_k), :], rows)
             acc_new = alpha * acc + _dot(p.astype(mm_dt), v_blk, _NN)
             return m_new, l_new, acc_new
 
@@ -414,6 +441,17 @@ def _sublane_rows(heads: int, dtype) -> int:
     return -(-heads // tile) * tile
 
 
+def _softmax_scratch(heads: int, width: int, q_dtype):
+    """The online-softmax state one batch row carries along the block
+    axis, as ``scratch_shapes`` entries: m, l and the accumulator."""
+    rows = _sublane_rows(heads, _mm_dtype(q_dtype))
+    return [
+        pltpu.VMEM((rows, 1), jnp.float32),      # running max m
+        pltpu.VMEM((rows, 1), jnp.float32),      # normalizer l
+        pltpu.VMEM((rows, width), jnp.float32),  # accumulator
+    ]
+
+
 def _decode_call(name, q, cache_operands, index_map, rows_per_block: int,
                  prefetch, n_blocks: int, block_k: int):
     """The one ``pallas_call`` behind both entry points: grid (b, blocks),
@@ -427,7 +465,6 @@ def _decode_call(name, q, cache_operands, index_map, rows_per_block: int,
     consumed by the index map only). ``name`` is the kernel's stable name
     in compiled HLO and in traces."""
     b, _, h, d = q.shape
-    acc_rows = _sublane_rows(h, _mm_dtype(q.dtype))
     quant = len(cache_operands) == 4
 
     def kernel(*refs):
@@ -448,11 +485,7 @@ def _decode_call(name, q, cache_operands, index_map, rows_per_block: int,
             pl.BlockSpec((None, rows_per_block, x.shape[-1]), index_map)
             for x in cache_operands],
         out_specs=pl.BlockSpec((None, 1, h * d), _q_index_map),
-        scratch_shapes=[
-            pltpu.VMEM((acc_rows, 1), jnp.float32),      # running max m
-            pltpu.VMEM((acc_rows, 1), jnp.float32),      # normalizer l
-            pltpu.VMEM((acc_rows, h * d), jnp.float32),  # accumulator
-        ],
+        scratch_shapes=_softmax_scratch(h, h * d, q.dtype),
     )
     out = pl.pallas_call(
         kernel,
@@ -566,6 +599,142 @@ def _paged_kv_index_map(page_size: int):
     return index_map
 
 
+def _packed_rows(dtype) -> int:
+    """Sublane rows of one packed VMEM tile of ``dtype``: 8 of 32 bits,
+    16 of bfloat16, 32 of int8."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def _pages_per_step(page_size: int, n_pages: int, operands,
+                    block_k: Optional[int]) -> int:
+    """P, the pages one grid step of the paged kernel covers: ``block_k``
+    rows (default ``DEFAULT_DECODE_BLOCK_K``) over the page size, capped at
+    the table's width. 1 (a step is a page) where a page is ``block_k``
+    rows already, or where a page of some cache operand is below that
+    dtype's packed tile (an int8 page of 16 rows is half a (32, 128)
+    tile), so P of them cannot lie side by side without a relayout."""
+    want = DEFAULT_DECODE_BLOCK_K if block_k is None else block_k
+    if any(page_size % _packed_rows(x.dtype) for x in operands):
+        return 1
+    return max(1, min(want // page_size, n_pages))
+
+
+def _paged_block_call(q, pools, starts_b, ends_b, tables_b, pages: int):
+    """The paged kernel at ``pages`` > 1 pages a grid step: grid
+    ``(b, ceil(n_pages / pages))``, and a step's tile is ``pages *
+    page_size`` logical rows of one lane, contiguous in VMEM, so the two
+    products run on a ``block_k``-row tile as in the contiguous kernel.
+
+    The pools stay in HBM (``pl.ANY``). A live step's pages are gathered
+    through the lane's table by one async copy a LIVE page into its place
+    in a ``[2, rows, width]`` buffer per cache operand: pages of the block
+    outside the lane's ``[first, last]`` are not copied (their rows hold
+    whatever an earlier step left, finite, and ``_decode_kernel`` masks
+    them by position), so the bytes a call reads are the lanes' live
+    pages, never rounded up to blocks. Each live step starts the NEXT live
+    step's copies (this lane's next block, else the next lane's first)
+    into the other half before it waits for its own, so the gather runs
+    under the step before it; a dead step does nothing. Both grid axes are
+    sequential: the buffer half and whether it is already on its way pass
+    from step to step in SMEM (``state``)."""
+    b, _, h, d = q.shape
+    ps = pools[0].shape[1]
+    n_pages = tables_b.shape[1]
+    rows = pages * ps
+    n_ops = len(pools)
+
+    def kernel(starts_ref, ends_ref, tables_ref, q_ref, *rest):
+        pool_refs, rest = rest[:n_ops], rest[n_ops:]
+        o_ref, m_scr, l_scr, acc_scr = rest[:4]
+        bufs, (sem, state) = rest[4:4 + n_ops], rest[4 + n_ops:]
+        bi = pl.program_id(0)
+        jm = pl.program_id(1)
+
+        @pl.when((bi == 0) & (jm == 0))
+        def _reset():
+            state[0] = 0  # buffer half of the next live step
+            state[1] = 0  # 1: that step's copies are already on their way
+            for buf in bufs:
+                # a row no copy ever wrote must be finite: p is exactly 0
+                # on it, and 0 * NaN would still poison p @ v
+                buf[...] = jnp.zeros(buf.shape, buf.dtype)
+
+        def copies(lane, blk, half, wait):
+            """Start (or wait for) the copies of block ``blk`` of ``lane``:
+            its pages inside the lane's window, each to its own rows."""
+            first = starts_ref[lane] // ps
+            # never past the table, whatever ``end`` says
+            last = jnp.minimum((ends_ref[lane] - 1) // ps, n_pages - 1)
+            lo = jnp.clip(first - blk * pages, 0, pages)
+            hi = jnp.clip(last + 1 - blk * pages, 0, pages)
+
+            def one(i, carry):
+                page = tables_ref[lane, blk * pages + i]
+                row0 = pl.multiple_of(i * ps, ps)
+                for pool, buf in zip(pool_refs, bufs):
+                    dma = pltpu.make_async_copy(
+                        pool.at[page], buf.at[half, pl.ds(row0, ps), :],
+                        sem.at[half])
+                    if wait:
+                        dma.wait()
+                    else:
+                        dma.start()
+                return carry
+
+            jax.lax.fori_loop(lo, hi, one, 0)
+
+        def gather():
+            half = state[0]
+
+            @pl.when(state[1] == 0)
+            def _own():
+                copies(bi, jm, half, wait=False)
+
+            same = jm < (ends_ref[bi] - 1) // rows
+            lane = jnp.minimum(jnp.where(same, bi, bi + 1), b - 1)
+            blk = jnp.where(same, jm + 1, starts_ref[lane] // rows)
+            ahead = same | ((bi + 1 < b)
+                            & (ends_ref[lane] > starts_ref[lane]))
+
+            @pl.when(ahead)
+            def _next():
+                copies(lane, blk, 1 - half, wait=False)
+
+            state[0] = 1 - half
+            state[1] = ahead.astype(jnp.int32)
+            copies(bi, jm, half, wait=True)
+            views = [buf.at[half] for buf in bufs]
+            return views if n_ops == 4 else views + [None, None]
+
+        _decode_kernel(starts_ref, ends_ref, q_ref, None, None, o_ref,
+                       m_scr, l_scr, acc_scr, block_k=rows, major=rows,
+                       scale=1.0 / (d**0.5), heads=h, gather=gather)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, -(-n_pages // pages)),
+        in_specs=[pl.BlockSpec((None, 1, h * d), _q_index_map)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * n_ops,
+        out_specs=pl.BlockSpec((None, 1, h * d), _q_index_map),
+        scratch_shapes=_softmax_scratch(h, h * d, q.dtype)
+        + [pltpu.VMEM((2, rows, x.shape[-1]), x.dtype) for x in pools]
+        + [pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((2,), jnp.int32)],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, 1, h * d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # the lane axis too: a lane's last step starts the next
+            # lane's first copies
+            dimension_semantics=("arbitrary", "arbitrary")
+        ),
+        interpret=_interpret(),
+        name=PAGED_KERNEL_NAME,
+    )(starts_b, ends_b, tables_b, q.reshape(b, 1, h * d), *pools)
+    return out.reshape(b, 1, h, d)
+
+
 def paged_gather_kv(pages: jax.Array, tables: jax.Array) -> jax.Array:
     """Dense-fallback gather: materialize each row's logical buffer
     ``[b, logical_len, width]`` from the shared page pool
@@ -611,8 +780,11 @@ def flash_decode_paged_attention(
     block table (module docstring "Int8 KV").
 
     ``page_size`` must be a multiple of 8 (callers pre-screen with
-    :func:`decode_flash_supported` on the page size); ``block_k`` tiles
-    within a page (largest divisor wins, as in the contiguous kernel).
+    :func:`decode_flash_supported` on the page size). ``block_k`` is the
+    rows of a compute tile, as in the contiguous kernel: a grid step
+    gathers ``block_k // page_size`` pages of the row into one tile
+    (module docstring "Paged variant"), and where a step stays one page
+    ``block_k`` tiles within it (largest divisor wins).
     ``mesh`` runs the kernel per-shard over the local head slice of the
     page pools (tables replicated) — see :func:`flash_decode_attention`.
     """
@@ -624,7 +796,11 @@ def flash_decode_paged_attention(
         return _sharded_decode(mesh, starts_b, ends_b, [q] + operands,
                                tables=tables_b, block_k=block_k)
     page_size = k_pages.shape[1]
-    # major is pinned to one page (the gather unit); block_k tiles inside
+    pages = _pages_per_step(page_size, tables.shape[1], operands, block_k)
+    if pages > 1:
+        return _paged_block_call(q, operands, starts_b, ends_b, tables_b,
+                                 pages)
+    # a step is one page (the gather unit); block_k tiles inside it
     block_k, major = fit_decode_blocks(page_size, block_k, page_size)
     if block_k is None or major != page_size:
         raise ValueError(

@@ -17,6 +17,8 @@ import pytest
 from fleetx_tpu.models.gpt.generation import GenerationConfig, generate
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
 from fleetx_tpu.ops.pallas.decode_attention import (
+    PAGED_KERNEL_NAME,
+    _pages_per_step,
     decode_flash_supported,
     fit_decode_blocks,
     flash_decode_attention,
@@ -111,27 +113,60 @@ def test_kernel_traced_end_under_jit():
             rtol=_TOL, atol=_TOL, err_msg=f"end={end}")
 
 
+def _rows(n_row, *rows):
+    """Block tables of ``n_row`` pages a lane from each lane's leading
+    physical pages (page 0, the engine's trash page, fills the rest)."""
+    tables = np.zeros((len(rows), n_row), np.int32)
+    for i, pages in enumerate(rows):
+        tables[i, :len(pages)] = pages
+    return tables
+
+
+# name -> (block tables, ends, starts). At page 16 and the default block_k
+# a grid step of the bf16 pool covers P = min(16, pages a row) pages (the
+# int8 pool of 16-row pages keeps P = 1: module docstring "Paged variant").
+_PAGED_CASES = {
+    # 6 pages a row (P = 6, one block a lane): shared prefix pages, a
+    # left-padded row, a lane on the trash page
+    "row6": (_rows(6, [1, 2, 3, 4, 5, 6], [1, 2, 7, 8],
+                   [9, 10, 11, 12, 13, 14], [15, 16, 17, 18]),
+             [96, 50, 81, 17], [0, 0, 5, 0]),
+    # 80 pages a row (P = 16, five blocks): windows that end inside a
+    # block's first page (257 + 3), on a block's edge (512) and in a last
+    # partial page; ``starts`` inside the second block; shared prefix
+    "row80": (_rows(80, range(1, 18), range(18, 50),
+                    list(range(1, 9)) + list(range(50, 122)),
+                    range(130, 160)),
+              [260, 512, 1275, 470], [0, 0, 0, 300]),
+    # today's free lane (engine.py: zero table, ``end`` = the row's
+    # length) beside busy ones, at the chat cell's 64 pages a row
+    "free64": (_rows(64, range(1, 28), [], range(28, 40)),
+               [430, 1024, 177], [0, 0, 0]),
+    # a width P does not divide (70 = 4 x 16 + 6): the last block is short
+    "row70": (_rows(70, range(1, 71), range(71, 76)),
+              [1120, 66], [0, 17]),
+}
+
+
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
 @pytest.mark.parametrize("h,d", [(16, 64), (8, 128)])
-def test_paged_kernel_at_engine_shapes(h, d, kv_dtype):
+@pytest.mark.parametrize("case", list(_PAGED_CASES))
+def test_paged_kernel_at_engine_shapes(case, h, d, kv_dtype):
     """The serving engine's own shapes (page 16, bf16 queries, 16 heads of
     64 / 8 of 128, shared prefix pages, ragged windows) against the dense
     window reference — the case chip runs certify numerically
     (FLEETX_TEST_PLATFORM=real), since a 16-row page sits below the
-    packed bf16/int8 tile and Mosaic pads it."""
+    packed int8 tile and Mosaic pads it."""
     rng = np.random.RandomState(2)
-    b, ps, n_row, n_pages = 4, 16, 6, 19
+    tables, ends, starts = _PAGED_CASES[case]
+    (b, n_row), ps = tables.shape, 16
+    n_pages = int(tables.max()) + 1
     q = jnp.asarray(rng.randn(b, 1, h, d), jnp.bfloat16)
     k = jnp.asarray(rng.randn(n_pages, ps, h, d), jnp.bfloat16)
     v = jnp.asarray(rng.randn(n_pages, ps, h, d), jnp.bfloat16)
-    tables = np.zeros((b, n_row), np.int32)
-    tables[0] = [1, 2, 3, 4, 5, 6]
-    tables[1] = [1, 2, 7, 8, 0, 0]       # shares row 0's two prefix pages
-    tables[2] = [9, 10, 11, 12, 13, 14]
-    tables[3] = [15, 16, 17, 18, 0, 0]
     tables = jnp.asarray(tables)
-    ends = jnp.asarray([96, 50, 81, 17], jnp.int32)
-    starts = jnp.asarray([0, 0, 5, 0], jnp.int32)
+    ends = jnp.asarray(ends, jnp.int32)
+    starts = jnp.asarray(starts, jnp.int32)
     scales = {}
     if kv_dtype == "int8":
         k8, ks = quantize_kv(k)
@@ -154,6 +189,52 @@ def test_paged_kernel_at_engine_shapes(h, d, kv_dtype):
                                rtol=3e-2, atol=3e-2)
 
 
+@pytest.mark.parametrize("page,block_k,pages", [
+    (16, None, 16),   # the serve cells: block_k 256 over page 16
+    (16, 128, 8),
+    (32, None, 8),
+    (256, None, 1),   # a page is block_k rows already
+    (512, None, 1),
+])
+def test_pages_per_step(page, block_k, pages):
+    """P follows from the shapes a call sees: block_k over the page size,
+    capped at the table's width, and 1 where a page of some cache operand
+    is below its dtype's packed tile."""
+    pool = lambda dt: jnp.zeros((3, page, 256), dt)
+    bf16 = [pool(jnp.bfloat16)] * 2
+    assert _pages_per_step(page, 64, bf16, block_k) == pages
+    assert _pages_per_step(page, 6, bf16, block_k) == min(pages, 6)
+    int8 = [pool(jnp.int8)] * 2 + [jnp.zeros((3, page, 2), jnp.float32)] * 2
+    assert _pages_per_step(page, 64, int8, block_k) == (
+        1 if page < 32 else pages)
+
+
+def test_paged_kernel_with_free_and_empty_lanes_interleaved():
+    """A lane whose window is empty (``end`` <= ``start``: no live step)
+    between busy lanes must not break the chain in which a live step
+    starts the next live step's copies: the lanes after it still read
+    their own pages."""
+    rng = np.random.RandomState(5)
+    b, ps, h, d, n_row = 4, 16, 4, 32, 40
+    tables = jnp.asarray(_rows(n_row, range(1, 21), range(21, 41),
+                               range(41, 61), range(61, 81)))
+    q = jnp.asarray(rng.randn(b, 1, h, d), jnp.bfloat16)
+    k = jnp.asarray(rng.randn(81, ps, h, d), jnp.bfloat16)
+    v = jnp.asarray(rng.randn(81, ps, h, d), jnp.bfloat16)
+    ends = jnp.asarray([300, 0, 100, 290], jnp.int32)
+    starts = jnp.asarray([0, 0, 100, 270], jnp.int32)
+    out = flash_decode_paged_attention(
+        q, _fold(k), _fold(v), tables=tables, end=ends, starts=starts)
+    gather = lambda x: x[tables].reshape(b, n_row * ps, h, d)
+    ref = _dense_window_attention(
+        q.astype(jnp.float32), gather(k).astype(jnp.float32),
+        gather(v).astype(jnp.float32), ends, starts)
+    for lane in (0, 3):  # lanes 1 and 2 have no key to attend to
+        np.testing.assert_allclose(
+            np.asarray(out[lane], np.float32), np.asarray(ref[lane]),
+            rtol=3e-2, atol=3e-2)
+
+
 def _lower_for_tpu(monkeypatch, fn, *args):
     """Lower ``fn`` for the tpu platform with the interpreter off: the
     Pallas TPU lowering's block-shape rules run in Python, so a layout the
@@ -161,9 +242,45 @@ def _lower_for_tpu(monkeypatch, fn, *args):
     import fleetx_tpu.ops.pallas.decode_attention as da
 
     monkeypatch.setattr(da, "_interpret", lambda: False)
-    text = jax.jit(fn).trace(*args).lower(
-        lowering_platforms=("tpu",)).as_text()
+    traced = jax.jit(fn).trace(*args)
+    text = traced.lower(lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" in text
+    return traced, text
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("lanes,n_row,block_k,pages", [
+    (24, 64, None, 16),   # gpt1.3b-serve-chat-steady-v2
+    (16, 64, None, 16),   # gpt1.3b-serve-docs-batch
+    (32, 80, None, 16),   # olmoe-l8-serve-gen-batch
+    (32, 80, 128, 8),     # FLEETX_DECODE_BLOCK_K=128: half the rows a step
+])
+def test_paged_kernel_grid_at_the_serve_cells(monkeypatch, lanes, n_row,
+                                              block_k, pages):
+    """The three serve cells' decode call (page 16, 16 heads of 128, a
+    bf16 pool) lowers for the TPU as ``fleetx_decode_paged`` with
+    ceil(pages of a row / P) grid steps a lane: the mechanism of PR 30 has
+    no hit share, it engages on every call of a shape or on none, and the
+    grid says which."""
+    h, d, ps = 16, 128, 16
+    q = jnp.zeros((lanes, 1, h, d), jnp.bfloat16)
+    kv = jnp.zeros((lanes * n_row + 1, ps, h * d), jnp.bfloat16)
+    tables = jnp.zeros((lanes, n_row), jnp.int32)
+    ends = jnp.full((lanes,), n_row * ps, jnp.int32)
+    fn = lambda q, kv, t, e: flash_decode_paged_attention(
+        q, kv, kv, tables=t, end=e, block_k=block_k)
+    traced, text = _lower_for_tpu(monkeypatch, fn, q, kv, tables, ends)
+    (call,) = _pallas_calls(traced.jaxpr.jaxpr)
+    assert call.params["grid_mapping"].grid == (lanes, -(-n_row // pages))
+    assert f'kernel_name = "{PAGED_KERNEL_NAME}"' in text
 
 
 @pytest.mark.parametrize("mp", [1, 2])
@@ -175,6 +292,9 @@ def test_decode_kernels_lower_for_tpu(monkeypatch, paged, h, d, kv_dtype,
     """Every decode entry point at the serving engine's shapes (GPT-345M:
     8 lanes, page 16, cache 352; bf16 and int8 caches; head_dim 64 and
     128; bare and under the mp2 shard_map) must pass the TPU lowering.
+    The bf16 pool at 22 pages a row takes P = 16 pages a step; the int8
+    pool keeps P = 1, the honest answer while a 16-row int8 page is half
+    a packed (32, 128) tile (``test_pages_per_step``).
     The seed's ``[.., len, h, d]`` blocks squeezed the sublane axis and
     were refused in every one of these combinations while twenty PRs of
     interpret-mode tests stayed green."""
