@@ -164,7 +164,14 @@ def init_decode_cache(model, batch: int):
     ([batch, cache_len, heads*head_dim] per layer + a scalar
     ``cache_index``; [num_pages, page_size, heads*head_dim] shared pages
     when the model carries ``cfg.decode_num_pages``) is defined in exactly
-    one place."""
+    one place. A model whose layers have kinds (grouped heads, window
+    layers: ``models/gpt/hybrid.py``) keeps its pages in one flat pool of
+    two classes, built there."""
+    if (getattr(model.cfg, "layer_kinds", False)
+            and model.cfg.decode_num_pages is not None):
+        from fleetx_tpu.models.gpt.hybrid import init_cache
+
+        return init_cache(model, batch)
     cache_shapes = jax.eval_shape(
         lambda: model.init(
             jax.random.PRNGKey(0),

@@ -1,0 +1,332 @@
+"""The decoder layer for blocks whose layers are not all alike
+(``models/gpt/block_fields.py``): grouped-query heads with a head size of
+their own, window and full attention layers mixed, rotary and position-free
+layers mixed, and a router that reads the block's input.
+
+``GPTModel._decoder_stack`` scans ONE body over the layers whatever the
+depth: a layer's kind is data. Each layer gets its own index and looks its
+kind up in the configuration's layout lists (constants of the program);
+``rope`` on or off is a ``where``, window or full attention a ``lax.cond``
+whose two branches are the same calls with other bounds, each under a
+device scope of its own (``attn_full`` / ``attn_window``) so that a trace
+tells them apart. A scan over whole periods of the pattern would compile
+the body once too, but as many layers as a period has, hold the parameters
+as ``[periods, ...]`` by place in the period (another tree for the engine,
+``resident.py``, the expert kernels' layer index and the references), and
+fix the pattern's period in the tree; per-layer flags cost two small
+conditionals a layer.
+
+Serving keeps TWO CLASSES OF PAGE in one flat pool ``[pages, page_size,
+kv_heads * head_dim]`` (the layer loop's carry): a full-attention layer
+owns ``decode_num_pages`` pages and a lane's table for it grows with the
+context; a window layer owns ``decode_window_pages`` and a lane's table for
+it holds only the pages a live query can still see (``serving/
+cache_manager.py`` ``WindowPagePool`` releases the others). The model is
+handed both tables ``[2, lanes, pages of a row]`` in LOGICAL page order and
+picks the one of the layer's kind; entry 0 is the layer's own trash page,
+as in the one-class pool. A window layer passes the decode kernels ``starts
+= max(0, end - window)``, which their ``starts`` / ``ends`` contract has
+always had, and a prefill chunk gathers the window plus the chunk and not
+the whole row.
+
+Forward only where it differs from ``model.py``: a forward outside the
+cache takes the dense path (``grouped_attention``); training this block,
+with grouped heads and a window in the flash kernels, is ROADMAP R4.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
+
+from fleetx_tpu.models.gpt.model import (
+    MLP,
+    GPTConfig,
+    SelfAttention,
+    _constrain_act,
+    _dense,
+    _dropout,
+    _layer_norm,
+    apply_rope,
+)
+
+__all__ = ["HybridDecoderLayer", "HybridSelfAttention", "grouped_attention",
+           "init_cache", "layer_bases", "total_pages"]
+
+_NEG = -1e30  # a masked score: finite, so a row of padding stays finite
+
+
+def _pages_of(cfg: GPTConfig):
+    """Pages of every layer in the flat pool, by the layer's kind."""
+    full, window = cfg.decode_num_pages, cfg.decode_window_pages
+    if any(cfg.window_layers) and window is None:
+        raise ValueError(
+            "a paged decode cache over window layers needs "
+            "decode_window_pages beside decode_num_pages (the serving "
+            "engine sets both)")
+    return [window if w else full for w in cfg.window_layers]
+
+
+def layer_bases(cfg: GPTConfig) -> np.ndarray:
+    """First page of every layer in the flat pool (its trash page)."""
+    pages = _pages_of(cfg)
+    return np.concatenate([[0], np.cumsum(pages)[:-1]]).astype(np.int32)
+
+
+def total_pages(cfg: GPTConfig) -> int:
+    """Pages of the flat pool: every layer's, both classes."""
+    return int(sum(_pages_of(cfg)))
+
+
+def init_cache(model, batch: int):
+    """The zero decode cache of a model with layer kinds over a page pool:
+    the tree ``model.init`` declares, with the key and value leaves made
+    the ONE flat pool of both classes (module docstring)."""
+    cfg = model.cfg
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((batch, 1), jnp.int32),
+        jnp.zeros((batch, 1), jnp.int32), decode=True))["cache"]
+    pool = (total_pages(cfg), cfg.decode_page_size,
+            cfg.kv_heads * cfg.head_dim)
+
+    def one(path, leaf):
+        kv = path[-1].key in ("cached_key", "cached_value")
+        return jnp.zeros(pool if kv else leaf.shape, leaf.dtype)
+
+    return jax.tree_util.tree_map_with_path(one, shapes)
+
+
+def grouped_attention(q, k, v, allowed):
+    """Dense attention of grouped heads: ``q`` ``[b, s, heads, d]``, ``k``
+    and ``v`` ``[b, t, kv_heads * d]`` (lane-dense, as the cache holds
+    them), ``allowed`` bool broadcastable to ``[b, 1, s, t]``. Query head
+    ``h`` reads key head ``h // group``; scores over ``sqrt(d)``, softmax
+    in float32. No key is repeated: the group is an axis of the product."""
+    b, s, heads, d = q.shape
+    t = k.shape[1]
+    kv_heads = k.shape[-1] // d
+    k = k.reshape(b, t, kv_heads, d)
+    v = v.reshape(b, t, kv_heads, d)
+    q = q.reshape(b, s, kv_heads, heads // kv_heads, d)
+    scores = jnp.einsum("bskgd,btkd->bkgst", q, k,
+                        preferred_element_type=jnp.float32) / (d ** 0.5)
+    scores = jnp.where(allowed[:, :, None], scores, _NEG)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
+    return out.reshape(b, s, heads, d)
+
+
+class HybridSelfAttention(SelfAttention):
+    """``SelfAttention`` with ``kv_heads`` key/value heads of ``head_dim``
+    and a kind a layer (module docstring). The out-projection, the
+    flash-decode dispatch check and the kernels are the base's."""
+
+    @nn.compact
+    def __call__(self, x, attn_mask=None, *, deterministic=True, decode=False,
+                 cache_positions=None, block_tables=None, layer_index=None,
+                 rope=None):
+        cfg = self.cfg
+        nh, kvh, hd = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
+        if layer_index is None:
+            raise NotImplementedError(
+                "layers with a kind run under the layer scan, which hands "
+                "each its index (scan_layers)")
+        if not deterministic and cfg.attention_probs_dropout_prob > 0.0:
+            raise NotImplementedError(
+                "attention dropout over grouped heads or a window "
+                "(training this block: ROADMAP R4)")
+        proj = functools.partial(_dense, logical_axes=("embed", "heads", "kv"),
+                                 use_bias=cfg.use_bias, dtype=cfg.dtype)
+        if cfg.fuse_attn_qkv:
+            # one product for all three, split along the HEADS axis (the
+            # fused GPT-2 kernel splits the last: the head counts differ)
+            qkv = checkpoint_name(
+                proj((nh + 2 * kvh, hd), name="qkv_proj")(x), "qkv_out")
+            q, k, v = jnp.split(qkv, (nh, nh + kvh), axis=-2)
+        else:
+            q = proj((nh, hd), name="q_proj")(x)
+            k = proj((kvh, hd), name="k_proj")(x)
+            v = proj((kvh, hd), name="v_proj")(x)
+            q, k, v = (checkpoint_name(t, "qkv_out") for t in (q, k, v))
+        windowed = jnp.asarray(cfg.window_layers, bool)[layer_index]
+        if rope is not None and any(cfg.rope_layers):
+            rotates = jnp.asarray(cfg.rope_layers, bool)[layer_index]
+            q = jnp.where(rotates, apply_rope(q, rope), q)
+            k = jnp.where(rotates, apply_rope(k, rope), k)
+        b, s = q.shape[:2]
+        k, v = k.reshape(b, s, kvh * hd), v.reshape(b, s, kvh * hd)
+
+        out = None
+        if decode:
+            if cfg.decode_num_pages is None:
+                raise NotImplementedError(
+                    "a contiguous decode cache over grouped heads or window "
+                    "layers (one-shot generate()): serve the model through "
+                    "ServingEngine, whose page pool holds both")
+            if attn_mask is not None:
+                raise NotImplementedError(
+                    "a key mask over the paged cache of grouped heads")
+            out = self._paged_attention(q, k, v, cache_positions,
+                                        block_tables, layer_index, windowed,
+                                        deterministic)
+        if out is None:  # no cache (or its init): every position at once
+            pos = jnp.arange(s)
+            allowed = self._visible(pos[:, None], pos[None, :], windowed)
+            if attn_mask is not None:
+                allowed = allowed & attn_mask.astype(bool)
+            out = grouped_attention(q, k, v, allowed[None, None])
+        out = checkpoint_name(out, "core_attn_out")
+        return self._out_proj(out)
+
+    def _visible(self, q_pos, k_pos, windowed):
+        """Whether the query at ``q_pos`` sees the key at ``k_pos``."""
+        window = self.cfg.sliding_window
+        seen = k_pos <= q_pos
+        if window and any(self.cfg.window_layers):
+            seen = seen & (~windowed | (q_pos - k_pos < window))
+        return seen
+
+    def _by_kind(self, windowed, full, window):
+        """``window()`` in a window layer, ``full()`` in a full one, each
+        under its device scope; a conditional only where the configuration
+        has both kinds."""
+        kinds = set(self.cfg.window_layers)
+
+        def scoped(name, fn):
+            def run():
+                with jax.named_scope(name):
+                    return fn()
+            return run
+
+        full, window = scoped("attn_full", full), scoped("attn_window", window)
+        if kinds == {0}:
+            return full()
+        if kinds == {1}:
+            return window()
+        return jax.lax.cond(windowed, window, full)
+
+    def _paged_attention(self, q, k, v, cache_positions, block_tables,
+                         layer_index, windowed, deterministic):
+        """Write this call's keys and values into the layer's pages and
+        attend through them; None at the cache's init."""
+        from fleetx_tpu.ops.pallas.decode_attention import (
+            flash_decode_paged_attention,
+            paged_gather_kv,
+        )
+
+        cfg = self.cfg
+        ps, window = cfg.decode_page_size, cfg.sliding_window
+        b, s, width = k.shape
+        is_init = not self.has_variable("cache", "cached_key")
+        # one page at the init: ``init_cache`` makes the leaves the flat pool
+        ck = self.variable("cache", "cached_key", jnp.zeros, (1, ps, width),
+                           k.dtype)
+        cv = self.variable("cache", "cached_value", jnp.zeros,
+                           (1, ps, width), v.dtype)
+        self.variable("cache", "cache_index", lambda: jnp.array(0, jnp.int32))
+        if is_init:
+            return None
+        if cache_positions is None or block_tables is None:
+            raise ValueError(
+                "a paged decode cache needs cache_positions AND "
+                "block_tables (the serving engine threads both)")
+        max_len = cfg.decode_cache_len or cfg.max_position_embeddings
+        wpos = cache_positions.astype(jnp.int32)               # [b]
+        tables = block_tables.astype(jnp.int32)
+        if tables.ndim == 3:     # [class, lanes, pages]: 0 full, 1 window
+            tables = jnp.where(windowed, tables[1], tables[0])
+        tables = tables + jnp.asarray(layer_bases(cfg))[layer_index]
+        with jax.named_scope("cache_write"):
+            pos = wpos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+            pos = jnp.minimum(pos, max_len - 1)
+            page = jnp.take_along_axis(tables, pos // ps, axis=1).reshape(-1)
+            off = (pos % ps).reshape(-1)
+            ck.value = ck.value.at[page, off].set(k.reshape(b * s, width))
+            cv.value = cv.value.at[page, off].set(v.reshape(b * s, width))
+        k_pool, v_pool = ck.value, cv.value
+        n = tables.shape[1]
+
+        if s == 1 and self._flash_decode_ok(None, n * ps, deterministic,
+                                            tile_len=ps):
+            end = wpos + 1
+
+            def kernel(starts):
+                return flash_decode_paged_attention(
+                    q, k_pool, v_pool, tables=tables, end=end, starts=starts)
+
+            return self._by_kind(
+                windowed, lambda: kernel(None),
+                lambda: kernel(jnp.maximum(end - window, 0)))
+
+        # dense (a prefill chunk; every call off the TPU): gather the rows
+        # the chunk's queries can see, the whole row for a full layer, the
+        # window plus the chunk for a window layer
+        q_pos = wpos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+
+        def dense(first, pages):
+            held = tables if pages == n else jax.vmap(
+                lambda row, at: jax.lax.dynamic_slice(row, (at,), (pages,))
+            )(tables, first)
+            k_pos = (first[:, None] * ps
+                     + jnp.arange(pages * ps, dtype=jnp.int32)[None, :])
+            allowed = self._visible(q_pos[:, :, None], k_pos[:, None, :],
+                                    windowed)
+            return grouped_attention(q, paged_gather_kv(k_pool, held),
+                                     paged_gather_kv(v_pool, held),
+                                     allowed[:, None])
+
+        def in_window():
+            pages = min(n, -(-(window + s - 1) // ps) + 1)
+            first = jnp.clip((wpos - window + 1) // ps, 0, n - pages)
+            return dense(first, pages)
+
+        return self._by_kind(
+            windowed, lambda: dense(jnp.zeros((b,), jnp.int32), n), in_window)
+
+
+class HybridDecoderLayer(nn.Module):
+    """``model.DecoderLayer`` over :class:`HybridSelfAttention`, the router
+    of a softmax top-k expert layer reading the block's input where
+    ``router_input`` says so. Same call, same parameter names (the router
+    stays inside ``moe_mlp``, which is handed its input apart from its
+    experts')."""
+
+    cfg: GPTConfig
+
+    @nn.compact
+    def __call__(self, x, attn_mask=None, deterministic=True, decode=False,
+                 cache_positions=None, block_tables=None, rope=None,
+                 expert_stack=None, layer_index=None):
+        cfg = self.cfg
+        x = _constrain_act(x, cfg)
+        y = _layer_norm(cfg, "norm1")(x)
+        y = HybridSelfAttention(cfg, name="attn")(
+            y, attn_mask, deterministic=deterministic, decode=decode,
+            cache_positions=cache_positions, block_tables=block_tables,
+            layer_index=layer_index, rope=rope)
+        y = _dropout(cfg, "attn_dropout")(y, deterministic=deterministic)
+        h = x + y
+        y = _layer_norm(cfg, "norm2")(h)
+        if cfg.expert_mode and cfg.gate == "softmax_topk":
+            from fleetx_tpu.parallel.moe import DroplessMoEMLP
+
+            # the layer's index reaches the expert layer only where the
+            # loop carries the cache and so hands the experts' whole stack
+            # over (its counters and kernels index the stack with it)
+            y = DroplessMoEMLP(cfg, name="moe_mlp")(
+                y, decode=decode, expert_stack=expert_stack,
+                layer_index=None if expert_stack is None else layer_index,
+                router_input=x if cfg.router_input == "block_input" else None)
+        elif cfg.expert_mode:
+            from fleetx_tpu.parallel.moe import MoEMLP
+
+            y = MoEMLP(cfg, name="moe_mlp")(y)
+        else:
+            y = MLP(cfg, name="mlp")(y)
+        y = _dropout(cfg, "mlp_dropout")(y, deterministic=deterministic)
+        return _constrain_act(h + y, cfg)

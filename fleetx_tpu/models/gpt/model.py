@@ -32,17 +32,17 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax.ad_checkpoint import checkpoint_name
 
+from fleetx_tpu.models.gpt import block_fields
 from fleetx_tpu.ops.attention import causal_attention
-
 Dtype = Any
 
 default_kernel_init = nn.initializers.normal(stddev=0.02)
 
 
 @dataclasses.dataclass(frozen=True)
-class GPTConfig:
+class GPTConfig(block_fields.BlockLayoutFields):
     """GPT model hyperparameters incl. parallel/remat/flash switches
-    (reference GPTModel construction args)."""
+    (reference GPTModel construction args; block_fields.py has more)."""
     vocab_size: int = 50304
     hidden_size: int = 1024
     num_layers: int = 24
@@ -102,7 +102,7 @@ class GPTConfig:
     rope_theta: float = 10000.0
     norm: str = "layernorm"               # layernorm | rmsnorm
     norm_eps: float = 1e-5
-    mlp_act: str = "gelu"                 # gelu | swiglu (gated SiLU)
+    mlp_act: str = "gelu"    # gelu | swiglu (gated SiLU) | reglu (experts)
     use_bias: bool = True
     # RMSNorm with a learned weight over the WHOLE q and k projection
     # (all heads), before the split into heads and the rotation
@@ -150,7 +150,7 @@ class GPTConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
+        return self.head_size or self.hidden_size // self.num_attention_heads
 
     @property
     def ffn_size(self) -> int:
@@ -163,9 +163,9 @@ class GPTConfig:
         kw = {k: v for k, v in dict(model_cfg).items() if k in known and v is not None}
         if isinstance(kw.get("dtype"), str):
             kw["dtype"] = jnp.dtype(kw["dtype"]).type
-        nrl = kw.get("no_recompute_layers")
-        if nrl is not None:
-            kw["no_recompute_layers"] = tuple(nrl)
+        for name in ("no_recompute_layers", *block_fields.LAYOUT_FIELDS):
+            if kw.get(name) is not None:
+                kw[name] = tuple(kw[name])
         res = kw.get("recompute_extra_saves")
         if res is not None:
             if isinstance(res, str):  # "qkv_out,ffn_gelu" CLI/-o form
@@ -179,7 +179,7 @@ class GPTConfig:
         """Refuse block kinds nobody wrote and combinations no test runs."""
         for field, allowed in (("position_embedding", ("learned", "rope")),
                                ("norm", ("layernorm", "rmsnorm")),
-                               ("mlp_act", ("gelu", "swiglu"))):
+                               ("mlp_act", ("gelu", "swiglu", "reglu"))):
             if getattr(self, field) not in allowed:
                 raise ValueError(
                     f"{field}={getattr(self, field)!r}; choose "
@@ -193,8 +193,8 @@ class GPTConfig:
                     "(the stage and ring paths do not carry positions)")
         if self.gate == "softmax_topk" and self.expert_mode and not (
                 1 <= self.top_k <= self.num_experts):
-            raise ValueError(
-                f"top_k {self.top_k} of {self.num_experts} experts")
+            raise ValueError(f"top_k {self.top_k} of {self.num_experts} experts")
+        block_fields.check(self)
 
 
 def _dense(features, logical_axes, name, use_bias=True, dtype=jnp.bfloat16):
@@ -858,7 +858,7 @@ class _ScanLayer(nn.Module):
     def __call__(self, x, attn_mask, deterministic, decode,
                  cache_positions=None, block_tables=None, rope=None,
                  expert_stack=None, layer_index=None):
-        x = DecoderLayer(self.cfg, name="layer")(
+        x = block_fields.layer_class(self.cfg, DecoderLayer)(self.cfg, name="layer")(
             x, attn_mask, deterministic, decode, cache_positions,
             block_tables, rope, expert_stack, layer_index
         )
@@ -1009,7 +1009,7 @@ class GPTModel(nn.Module):
             # input) for the GPT-2 block
             args = (x, attn_mask, deterministic, decode, cache_positions,
                     block_tables, rope, expert_stack)
-            if carried:  # each layer's index into the carried stack
+            if carried or cfg.layer_kinds:  # each layer's own index
                 args += (jnp.arange(cfg.num_layers, dtype=jnp.int32),)
             stack = nn.scan(
                 layer_cls,
@@ -1017,7 +1017,7 @@ class GPTModel(nn.Module):
                                **({} if carried else {"cache": 0})},
                 variable_carry="cache" if carried else False,
                 split_rngs={"params": True, "dropout": True},
-                in_axes=(nn.broadcast,) * 7 + ((0,) if carried else ()),
+                in_axes=(nn.broadcast,) * 7 + ((0,) * (len(args) - 8)),
                 length=cfg.num_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )

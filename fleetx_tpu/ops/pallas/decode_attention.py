@@ -46,6 +46,19 @@ What's different from the training kernel:
 - forward-only: decode never differentiates, so there is no VJP, no lse
   output, and no dropout plumbing.
 
+Grouped-query heads: the cache may hold FEWER heads than the query has
+(``kv_heads * d`` lanes a row; query head r reads key head ``r // group``).
+The kernels are the same walk with the block-diagonal query built for the
+cache's lanes: row r holds query head r's d values on the lanes of key head
+``r // group``, so one product against the K tile still gives every query
+head's scores, and the product with the V tile ``[rows, kv_heads * d]``
+whose block ``(r, r // group)`` is head r's output. With as many key heads
+as query heads (group 1) the query arrives as one lane-dense row and the
+kernel spreads it, as it always did; with fewer, the wrapper lays the
+``[rows, kv_heads * d]`` matrix out (28 x 512 at most: nothing beside the
+cache rows) and picks each head's block of the result, so the kernel
+neither tiles nor slices lanes. Int8 scales and a mesh are refused there.
+
 Int8 KV (``k_scale``/``v_scale`` given): K/V stream from HBM as int8 with
 one fp32 scale per cached (row, head) vector (``ops/quant.quantize_kv``
 values, scales stored ``[..., cache_len, h]``). A (row, head) scale is
@@ -296,7 +309,7 @@ def _sharded_decode(mesh, starts_b, ends_b, operands, tables=None,
 def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr, *, block_k: int, major: int,
                    scale: float, heads: int, ks_ref=None, vs_ref=None,
-                   gather=None):
+                   gather=None, group: int = 1):
     """Grid step (batch bi, K/V major block jm): online-softmax update of
     ALL heads' single query row against the live tiles of the resident
     major block.
@@ -326,7 +339,12 @@ def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
     ``gather`` (the paged kernel at several pages a step,
     :func:`_paged_block_call`) is called at the top of a live step and
     returns the cache refs in place of the four above: the step's pages
-    copied side by side into VMEM."""
+    copied side by side into VMEM.
+
+    ``group`` > 1 (module docstring "Grouped-query heads"): the cache holds
+    ``heads // group`` heads, ``q_ref`` is the block-diagonal ``[rows,
+    kv_heads * d]`` query already and ``o_ref`` takes the accumulator's
+    diagonal blocks in place, ``[rows, kv_heads * d]``."""
     bi = pl.program_id(0)
     jm = pl.program_id(1)
     start = starts_ref[bi]
@@ -335,10 +353,11 @@ def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
     last_jm = (end - 1) // major
     tiles = major // block_k
     rows, hd = acc_scr.shape
-    d = hd // heads
-    # diag[r, c] <=> lane c belongs to head r (rows past ``heads`` are
-    # sublane padding: never selected, their state stays inert)
-    diag = (jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 0)
+    d = hd // (heads // group)
+    # diag[r, c] <=> lane c belongs to (the key head of) head r (rows past
+    # ``heads`` are sublane padding: never selected, their state stays inert)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 0)
+    diag = ((row if group == 1 else row // group)
             == jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 1) // d)
 
     @pl.when(jm == first_jm)
@@ -354,8 +373,9 @@ def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
         mm_dt = _mm_dtype(q_ref.dtype)
         # select in f32: the iota mask has the 32-bit tile layout, which
         # Mosaic will not relayout onto a packed bf16 operand
-        q_bd = jnp.where(diag, q_ref[:].astype(jnp.float32), 0.0
-                         ).astype(mm_dt)
+        q_bd = (jnp.where(diag, q_ref[:].astype(jnp.float32), 0.0
+                          ).astype(mm_dt) if group == 1
+                else q_ref[:].astype(mm_dt))
         # local tile range intersecting the valid window [start, end)
         t_lo = jnp.clip((start - jm * major) // block_k, 0, tiles)
         t_hi = jnp.clip(
@@ -398,7 +418,9 @@ def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
         # guard keeps a (contract-violating) empty window finite, not NaN
         l_safe = jnp.where(l > 0.0, l, 1.0)
         out = jnp.where(diag, acc_scr[:] / l_safe, 0.0)
-        o_ref[:] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+        if group == 1:
+            out = jnp.sum(out, axis=0, keepdims=True)
+        o_ref[:] = out.astype(o_ref.dtype)
 
 
 def _scale_rows(scale_tile, rows: int):
@@ -441,6 +463,35 @@ def _sublane_rows(heads: int, dtype) -> int:
     return -(-heads // tile) * tile
 
 
+def _query_operand(q, width: int):
+    """``(operand, block shape, group)`` of the query ``[b, 1, h, d]``
+    against a cache of ``width`` lanes a row: one lane-dense row where the
+    cache holds every head (group 1), else the block-diagonal ``[b, rows,
+    width]`` matrix (module docstring "Grouped-query heads")."""
+    b, _, h, d = q.shape
+    group = h * d // width
+    if group == 1:
+        return q.reshape(b, 1, h * d), (None, 1, h * d), 1
+    kv_heads = h // group
+    rows = _sublane_rows(h, _mm_dtype(q.dtype))
+    mine = (jnp.arange(h)[:, None] // group
+            == jnp.arange(kv_heads)[None, :]).astype(q.dtype)   # [h, kv]
+    spread = q[:, 0, :, None, :] * mine[None, :, :, None]       # [b,h,kv,d]
+    spread = jnp.pad(spread.reshape(b, h, width),
+                     ((0, 0), (0, rows - h), (0, 0)))
+    return spread, (None, rows, width), group
+
+
+def _heads_of(out, q_shape, group: int):
+    """The kernels' result back as ``[b, 1, h, d]``: with grouped heads,
+    row r's block ``r // group`` of ``[b, rows, kv_heads * d]`` (the others
+    are exact zeros, so a sum picks it)."""
+    b, _, h, d = q_shape
+    if group == 1:
+        return out.reshape(b, 1, h, d)
+    return out[:, :h].reshape(b, h, h // group, d).sum(axis=2)[:, None]
+
+
 def _softmax_scratch(heads: int, width: int, q_dtype):
     """The online-softmax state one batch row carries along the block
     axis, as ``scratch_shapes`` entries: m, l and the accumulator."""
@@ -466,6 +517,8 @@ def _decode_call(name, q, cache_operands, index_map, rows_per_block: int,
     in compiled HLO and in traces."""
     b, _, h, d = q.shape
     quant = len(cache_operands) == 4
+    width = cache_operands[0].shape[-1]
+    q_in, q_block, group = _query_operand(q, width)
 
     def kernel(*refs):
         # pallas_call's ref order: scalar prefetch, inputs, output, scratch
@@ -476,29 +529,29 @@ def _decode_call(name, q, cache_operands, index_map, rows_per_block: int,
         _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
                        m_scr, l_scr, acc_scr, block_k=block_k,
                        major=rows_per_block, scale=1.0 / (d**0.5), heads=h,
-                       ks_ref=ks_ref, vs_ref=vs_ref)
+                       ks_ref=ks_ref, vs_ref=vs_ref, group=group)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(b, n_blocks),
-        in_specs=[pl.BlockSpec((None, 1, h * d), _q_index_map)] + [
+        in_specs=[pl.BlockSpec(q_block, _q_index_map)] + [
             pl.BlockSpec((None, rows_per_block, x.shape[-1]), index_map)
             for x in cache_operands],
-        out_specs=pl.BlockSpec((None, 1, h * d), _q_index_map),
-        scratch_shapes=_softmax_scratch(h, h * d, q.dtype),
+        out_specs=pl.BlockSpec(q_block, _q_index_map),
+        scratch_shapes=_softmax_scratch(h, width, q.dtype),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1, h * d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_in.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             # the block axis carries the online-softmax scratch state
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=_interpret(),
         name=name,
-    )(*prefetch, q.reshape(b, 1, h * d), *cache_operands)
-    return out.reshape(b, 1, h, d)
+    )(*prefetch, q_in, *cache_operands)
+    return _heads_of(out, q.shape, group)
 
 
 def _cache_operands(k, v, k_scale, v_scale):
@@ -513,16 +566,21 @@ def _window(b: int, end, starts):
     return starts_b, ends_b
 
 
-def _check_operands(q, k, k_scale, v_scale):
+def _check_operands(q, k, k_scale, v_scale, meshed: bool = False):
     b, sq, h, d = q.shape
     if sq != 1:
         raise ValueError(f"flash decode is single-query (q_len={sq})")
     if (k_scale is None) != (v_scale is None):
         raise ValueError("int8 KV needs BOTH k_scale and v_scale")
-    if k.ndim != 3 or k.shape[-1] != h * d:
+    if k.ndim != 3 or (h * d) % k.shape[-1] or k.shape[-1] % d:
         raise ValueError(
             f"flash decode reads the lane-dense cache [..., len, h*d="
-            f"{h * d}]; got K {k.shape}")
+            f"{h * d}] (or kv_heads*d lanes, kv_heads dividing h={h}); "
+            f"got K {k.shape}")
+    if k.shape[-1] != h * d and (k_scale is not None or meshed):
+        raise NotImplementedError(
+            "flash decode over grouped heads takes no int8 scales and no "
+            "mesh (module docstring \"Grouped-query heads\")")
 
 
 def flash_decode_attention(
@@ -560,10 +618,11 @@ def flash_decode_attention(
     scalars/tables replicated — callers pre-screen with
     :func:`decode_mesh_shardable`.
     """
-    _check_operands(q, k, k_scale, v_scale)
+    meshed = mesh is not None and mesh.size > 1
+    _check_operands(q, k, k_scale, v_scale, meshed)
     starts_b, ends_b = _window(q.shape[0], end, starts)
     operands = _cache_operands(k, v, k_scale, v_scale)
-    if mesh is not None and mesh.size > 1:
+    if meshed:
         return _sharded_decode(mesh, starts_b, ends_b, [q] + operands,
                                block_k=block_k, block_major=block_major)
     cache_len = k.shape[1]
@@ -642,6 +701,8 @@ def _paged_block_call(q, pools, starts_b, ends_b, tables_b, pages: int):
     n_pages = tables_b.shape[1]
     rows = pages * ps
     n_ops = len(pools)
+    width = pools[0].shape[-1]
+    q_in, q_block, group = _query_operand(q, width)
 
     def kernel(starts_ref, ends_ref, tables_ref, q_ref, *rest):
         pool_refs, rest = rest[:n_ops], rest[n_ops:]
@@ -708,22 +769,23 @@ def _paged_block_call(q, pools, starts_b, ends_b, tables_b, pages: int):
 
         _decode_kernel(starts_ref, ends_ref, q_ref, None, None, o_ref,
                        m_scr, l_scr, acc_scr, block_k=rows, major=rows,
-                       scale=1.0 / (d**0.5), heads=h, gather=gather)
+                       scale=1.0 / (d**0.5), heads=h, gather=gather,
+                       group=group)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, -(-n_pages // pages)),
-        in_specs=[pl.BlockSpec((None, 1, h * d), _q_index_map)]
+        in_specs=[pl.BlockSpec(q_block, _q_index_map)]
         + [pl.BlockSpec(memory_space=pl.ANY)] * n_ops,
-        out_specs=pl.BlockSpec((None, 1, h * d), _q_index_map),
-        scratch_shapes=_softmax_scratch(h, h * d, q.dtype)
+        out_specs=pl.BlockSpec(q_block, _q_index_map),
+        scratch_shapes=_softmax_scratch(h, width, q.dtype)
         + [pltpu.VMEM((2, rows, x.shape[-1]), x.dtype) for x in pools]
         + [pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((2,), jnp.int32)],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1, h * d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_in.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             # the lane axis too: a lane's last step starts the next
             # lane's first copies
@@ -731,8 +793,8 @@ def _paged_block_call(q, pools, starts_b, ends_b, tables_b, pages: int):
         ),
         interpret=_interpret(),
         name=PAGED_KERNEL_NAME,
-    )(starts_b, ends_b, tables_b, q.reshape(b, 1, h * d), *pools)
-    return out.reshape(b, 1, h, d)
+    )(starts_b, ends_b, tables_b, q_in, *pools)
+    return _heads_of(out, q.shape, group)
 
 
 def paged_gather_kv(pages: jax.Array, tables: jax.Array) -> jax.Array:
@@ -788,11 +850,12 @@ def flash_decode_paged_attention(
     ``mesh`` runs the kernel per-shard over the local head slice of the
     page pools (tables replicated) — see :func:`flash_decode_attention`.
     """
-    _check_operands(q, k_pages, k_scale, v_scale)
+    meshed = mesh is not None and mesh.size > 1
+    _check_operands(q, k_pages, k_scale, v_scale, meshed)
     starts_b, ends_b = _window(q.shape[0], end, starts)
     tables_b = tables.astype(jnp.int32)
     operands = _cache_operands(k_pages, v_pages, k_scale, v_scale)
-    if mesh is not None and mesh.size > 1:
+    if meshed:
         return _sharded_decode(mesh, starts_b, ends_b, [q] + operands,
                                tables=tables_b, block_k=block_k)
     page_size = k_pages.shape[1]

@@ -13,8 +13,9 @@ the weights and nothing else.
 
 Two kernels, named for the device trace (docs/OBSERVABILITY.md):
 
-- ``fleetx_moe_gate_up``: ``silu(x @ w_gate[e]) * (x @ w_up[e])``, both
-  products and the activation in float32, one rounding on the way out;
+- ``fleetx_moe_gate_up``: ``act(x @ w_gate[e]) * (x @ w_up[e])``, ``act``
+  ``silu`` or ``relu`` (ReGLU), both products and the activation in
+  float32, one rounding on the way out;
 - ``fleetx_moe_down``: ``a @ w_down[e]``.
 
 The row layout is bounded statically (``rows + experts * (tm - 1)``, plus
@@ -57,6 +58,8 @@ DOWN_KERNEL_NAME = "fleetx_moe_down"
 # this (double-buffered: gate and up at 2048 x 1024 bf16 hold 16 MiB)
 _BLOCK_BYTES = 4 << 20
 _VMEM_LIMIT = 48 << 20
+# the gate's activation: gated SiLU (OLMoE) or ReGLU
+_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def row_tile(rows: int, experts: int) -> int:
@@ -74,7 +77,7 @@ def _col_tile(k: int, n: int, itemsize: int) -> int:
     return tn
 
 
-def _kernel(te_ref, nt_ref, layer_ref, x_ref, *refs, gated: bool):
+def _kernel(te_ref, nt_ref, layer_ref, x_ref, *refs, act):
     del te_ref, layer_ref  # read by the index maps
     o_ref = refs[-1]
 
@@ -82,14 +85,17 @@ def _kernel(te_ref, nt_ref, layer_ref, x_ref, *refs, gated: bool):
     def _():
         x = x_ref[...]
         out = jnp.dot(x, refs[0][...], preferred_element_type=jnp.float32)
-        if gated:
+        if act is not None:
             up = jnp.dot(x, refs[1][...], preferred_element_type=jnp.float32)
-            out = jax.nn.silu(out) * up
+            out = _ACTS[act](out) * up
         o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _grouped(name, x, weights, tile_expert, num_tiles, tm, layer):
+def _grouped(name, x, weights, tile_expert, num_tiles, tm, layer, act=None):
     rows, k = x.shape
+    if (act is None) != (len(weights) == 1) or (act and act not in _ACTS):
+        raise ValueError(f"{name}: activation {act!r} (of {sorted(_ACTS)}) "
+                         f"over {len(weights)} weights")
     if weights[0].ndim != 4:
         raise ValueError(f"{name}: weights are the layer stack [layers, "
                          f"experts, k, n], not {weights[0].shape}")
@@ -118,7 +124,7 @@ def _grouped(name, x, weights, tile_expert, num_tiles, tm, layer):
             lambda j, i, te, nt, li: (jnp.where(i < nt[0], i, spare), j)),
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, gated=len(weights) == 2),
+        functools.partial(_kernel, act=act),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -134,14 +140,14 @@ def _grouped(name, x, weights, tile_expert, num_tiles, tm, layer):
 
 
 def grouped_gate_up(x, w_gate, w_up, tile_expert, num_tiles, *, tm: int,
-                    layer):
-    """``silu(x @ w_gate[l, e]) * (x @ w_up[l, e])`` for rows ``x`` ``[rows,
+                    layer, act: str = "silu"):
+    """``act(x @ w_gate[l, e]) * (x @ w_up[l, e])`` for rows ``x`` ``[rows,
     k]`` laid out in ``tm``-row tiles (the last one spare), tile ``i`` of
     expert ``tile_expert[i]``; weights the stack ``[layers, experts, k, n]``
     and ``layer`` (a traced scalar) the layer ``l``. Tiles from
     ``num_tiles`` on are skipped: their rows of the result are not defined."""
     return _grouped(GATE_UP_KERNEL_NAME, x, (w_gate, w_up), tile_expert,
-                    num_tiles, tm, layer)
+                    num_tiles, tm, layer, act)
 
 
 def grouped_down(x, w_down, tile_expert, num_tiles, *, tm: int, layer):

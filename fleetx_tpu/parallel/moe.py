@@ -258,6 +258,31 @@ class MoEMLP(nn.Module):
 MOE_STATS = ("calls", "pairs", "experts_read", "largest_load")
 
 
+# rows of one block of ``_running_count``
+_COUNT_BLOCK = 128
+
+
+def _running_count(onehot: jax.Array) -> jax.Array:
+    """Inclusive cumulative sum of ``onehot`` ``[m, E]`` (0/1, int32) along
+    its rows, taken inside blocks of 128 rows by a product with a triangle
+    of ones (exact: whole numbers under 2**24 in float32) plus the blocks
+    before. XLA's cumulative sum is a ``reduce-window`` over the whole
+    column: over the thousands of pairs a prefill chunk routes it took 1.6%
+    of the device's time in the SmallThinker cell, outside every scope (the
+    compiler drops its name)."""
+    m, experts = onehot.shape
+    blocks = -(-m // _COUNT_BLOCK)
+    x = jnp.pad(onehot, ((0, blocks * _COUNT_BLOCK - m), (0, 0))).reshape(
+        blocks, _COUNT_BLOCK, experts).astype(jnp.float32)
+    triangle = jnp.tril(jnp.ones((_COUNT_BLOCK, _COUNT_BLOCK), jnp.float32))
+    inside = jnp.einsum("ij,bje->bie", triangle, x,
+                        precision=jax.lax.Precision.HIGHEST)
+    sums = x.sum(axis=1)
+    before = jnp.cumsum(sums, axis=0) - sums
+    return (inside + before[:, None, :]).reshape(-1, experts)[:m].astype(
+        jnp.int32)
+
+
 def expert_row_layout(topk_idx: jax.Array, num_experts: int, tm: int):
     """Where each (token, slot) pair goes when rows are sorted by expert
     and every expert's group is padded to whole ``tm``-row tiles (``tm`` 1:
@@ -277,7 +302,7 @@ def expert_row_layout(topk_idx: jax.Array, num_experts: int, tm: int):
     onehot = (flat[:, None] == jnp.arange(num_experts, dtype=jnp.int32)
               ).astype(jnp.int32)                           # [m, E]
     sizes = onehot.sum(axis=0)
-    rank = jnp.take_along_axis(jnp.cumsum(onehot, axis=0), flat[:, None],
+    rank = jnp.take_along_axis(_running_count(onehot), flat[:, None],
                                axis=1)[:, 0] - 1
     padded = (sizes + tm - 1) // tm * tm
     ends = jnp.cumsum(padded)
@@ -297,7 +322,10 @@ class DroplessMoEMLP(nn.Module):
     """Softmax top-k experts without capacity (module docstring):
     ``y = sum over the top_k chosen e of p_e * down_e(silu(gate_e(x)) *
     up_e(x))``, ``p = softmax(router(x))`` over all experts in float32, the
-    weights left as they are unless ``cfg.norm_topk_prob``.
+    weights left as they are unless ``cfg.norm_topk_prob``. ``mlp_act:
+    reglu`` makes the gate ``relu``; ``router_input`` is what the router
+    reads where that is not ``x`` (a router placed before attention reads
+    the block's input, its experts the normed post-attention stream).
 
     Handed ``expert_stack`` in a cached forward on a TPU, the grouped
     matmuls are the Pallas kernels of ``ops/pallas/moe_gmm.py`` (no
@@ -316,9 +344,12 @@ class DroplessMoEMLP(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array, *, decode: bool = False,
-                 layer_index=None, expert_stack=None) -> jax.Array:
+                 layer_index=None, expert_stack=None,
+                 router_input=None) -> jax.Array:
         cfg = self.cfg
         b, s, h = x.shape
+        # the gate's activation: ``reglu`` is ``relu(gate) * up``
+        act = "relu" if cfg.mlp_act == "reglu" else "silu"
         E, k, f, n, dt = cfg.num_experts, cfg.top_k, cfg.ffn_size, b * s, cfg.dtype
         router = nn.DenseGeneral(
             features=E, use_bias=False, dtype=jnp.float32,
@@ -343,8 +374,11 @@ class DroplessMoEMLP(nn.Module):
                   and cfg.use_flash_attention and kernels_enabled())
         tm = moe_gmm.row_tile(n * k, E) if kernel else 1
         tokens = x.reshape(n, h)
+        # the router reads the experts' input unless it is handed one of
+        # its own (``router_input: block_input``, models/gpt/hybrid.py)
+        routed = tokens if router_input is None else router_input.reshape(n, h)
         with jax.named_scope("moe_route"):
-            probs = jax.nn.softmax(router(tokens.astype(jnp.float32)), axis=-1)
+            probs = jax.nn.softmax(router(routed.astype(jnp.float32)), axis=-1)
             weights, topk_idx = jax.lax.top_k(probs, k)
             if cfg.norm_topk_prob:
                 weights = weights / weights.sum(axis=-1, keepdims=True)
@@ -361,23 +395,26 @@ class DroplessMoEMLP(nn.Module):
                   and not self.is_initializing())
         if probed:
             self.sow("routing", "input", x)
+            if router_input is not None:
+                self.sow("routing", "router_input", router_input)
             self.sow("routing", "experts", topk_idx.reshape(b, s, k))
             self.sow("routing", "weights", weights.reshape(b, s, k))
         self._count(sizes, n * k, s, decode, layer_index)
         with jax.named_scope("moe_experts"):
             if kernel:
                 w_gate, w_up, w_down = (w.astype(dt) for w in expert_stack)
-                act = moe_gmm.grouped_gate_up(
+                hidden = moe_gmm.grouped_gate_up(
                     rows, w_gate, w_up, tile_expert, num_tiles, tm=tm,
-                    layer=layer_index)
-                out = moe_gmm.grouped_down(act, w_down, tile_expert,
+                    layer=layer_index, act=act)
+                out = moe_gmm.grouped_down(hidden, w_down, tile_expert,
                                            num_tiles, tm=tm, layer=layer_index)
             else:
                 w_gate, w_up, w_down = (w.astype(dt)
                                         for w in (w_gate, w_up, w_down))
-                act = (jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes))
-                       * jax.lax.ragged_dot(rows, w_up, sizes))
-                out = jax.lax.ragged_dot(act, w_down, sizes)
+                hidden = (getattr(jax.nn, act)(
+                    jax.lax.ragged_dot(rows, w_gate, sizes))
+                    * jax.lax.ragged_dot(rows, w_up, sizes))
+                out = jax.lax.ragged_dot(hidden, w_down, sizes)
         with jax.named_scope("moe_route"):
             picked = out[dest].reshape(n, k, h).astype(jnp.float32)
             y = (picked * weights[..., None]).sum(axis=1)

@@ -50,6 +50,19 @@ a page's scales travel with its values because both are indexed by the
 same block table. :meth:`_LaneBook.cache_nbytes` measures the actual
 device bytes either way, which is how the ~2× HBM win is asserted.
 
+Two classes of page (a model with window-attention layers,
+``models/gpt/hybrid.py``): a full-attention layer keeps every token of a
+lane, a window layer only the rows a live query can still see. The manager
+then holds a second allocator, :class:`WindowPagePool`, beside
+:class:`PagePool`: one table a lane and class, both in logical page order,
+handed to the model together (``tables`` is ``[2, lanes, pages of a row]``:
+0 full, 1 window). The full class is :class:`PagePool` as it always was
+(without the prefix trie: a prefix's window pages are gone once the window
+has passed them, so prefix reuse is refused at construction); the window
+class allocates a lane's pages as its prefill chunks and decode ticks reach
+them and releases each page once every row of it lies behind the window of
+the lane's next query. Admission counts both classes.
+
 Two-level page cache (``FLEETX_SERVING_HOST_CACHE_BYTES``;
 docs/SERVING.md): with a :class:`HostPageStore` attached, LRU eviction
 of a zero-ref warm trie subtree SPILLS each page's content (K/V and, at
@@ -80,7 +93,8 @@ import jax
 import numpy as np
 
 __all__ = ["DiskPageStore", "HostPageStore", "KV_LEAF_RANK", "PagePool",
-           "PagedKVCacheManager", "TieredPageStore", "leaf_device_nbytes"]
+           "PagedKVCacheManager", "TieredPageStore", "WindowPagePool",
+           "leaf_device_nbytes", "window_lane_pages"]
 
 # Trailing rank of every K/V (and int8 scale) cache leaf:
 # [batch | pages, positions, lanes] as SelfAttention._update_cache stores
@@ -1075,6 +1089,135 @@ class PagePool:
         self.version += 1
 
 
+def window_lane_pages(window: int, span: int, page_size: int) -> int:
+    """The most pages of the window class one lane can hold: ``window +
+    span`` tokens (``span``: the most one program writes, a prefill chunk),
+    a page more for where they begin and stop inside a page."""
+    return -(-(window + span) // page_size) + 1
+
+
+class WindowPagePool:
+    """Host-side allocator of the WINDOW class of pages (module docstring
+    "Two classes of page"): pure host state, like :class:`PagePool`, with
+    the part of its surface the manager needs.
+
+    A lane's table is in logical page order, as the full class's, but only
+    the pages ``[first, end)`` are held: ``prepare(lane, pos, n)``, called
+    before a program writes positions ``[pos, pos + n)`` (a prefill chunk,
+    or one decode token), first RELEASES every page whose rows all lie
+    before ``pos - window + 1`` (the earliest key the query at ``pos`` sees;
+    later queries of the lane see later keys only), then allocates through
+    ``pos + n - 1``. A released entry reads 0, the trash page, which the
+    model's ``starts`` keeps outside every read. So a lane never holds more
+    than ``window + n`` tokens rounded out to pages (:attr:`lane_pages`
+    for ``n`` up to ``span``), and a pool of ``lanes * lane_pages + 1``
+    pages cannot run dry. Page 0 is the trash page."""
+
+    def __init__(self, num_pages: int, page_size: int, lanes: int,
+                 table_pages: int, window: int, span: int):
+        if window < 1 or span < 1:
+            raise ValueError(f"window {window} and span {span} must be "
+                             "positive")
+        self.num_pages = num_pages
+        self.page_size = page_size
+        self.lanes = lanes
+        self.window = window
+        self.span = span
+        self.lane_pages = min(table_pages,
+                              window_lane_pages(window, span, page_size))
+        if num_pages < self.lane_pages + 1:
+            raise ValueError(
+                f"window pool of {num_pages} pages cannot hold one lane's "
+                f"window ({self.lane_pages} pages) plus the trash page")
+        self.tables = np.zeros((lanes, table_pages), np.int32)
+        self.first = np.zeros(lanes, np.int64)   # held pages: [first, end)
+        self.end = np.zeros(lanes, np.int64)
+        self._free: List[int] = list(range(num_pages - 1, 0, -1))
+        self.version = 0
+        self.recycled = 0     # pages released behind a window, ever
+
+    @property
+    def usable_pages(self) -> int:
+        return self.num_pages - 1
+
+    @property
+    def free_pages(self) -> int:
+        return len(self._free)
+
+    @property
+    def pages_in_use(self) -> int:
+        return self.usable_pages - len(self._free)
+
+    def pages_needed(self, n_tokens: int) -> int:
+        """Pages a request of ``n_tokens`` prompt tokens comes to hold in
+        this class (its first decode write included)."""
+        return min(n_tokens // self.page_size + 1, self.lane_pages)
+
+    def can_admit(self, n_tokens: int) -> bool:
+        return self.pages_needed(n_tokens) <= len(self._free)
+
+    def prepare(self, lane: int, pos: int, n: int = 1) -> bool:
+        """Make positions ``[pos, pos + n)`` writable for ``lane`` and let
+        go of what no query from ``pos`` on can see. False: the pool is dry
+        (what was released stays released; the caller retires the request)
+        or the span runs past the table."""
+        ps = self.page_size
+        row = self.tables[lane]
+        moved = False
+        # the first page a query from ``pos`` on can see
+        lo = min(max(pos - self.window + 1, 0) // ps, row.shape[0])
+        for i in range(int(self.first[lane]), min(lo, int(self.end[lane]))):
+            self._free.append(int(row[i]))
+            row[i] = 0
+            self.recycled += 1
+            moved = True
+        if lo > self.first[lane]:
+            self.first[lane] = lo
+            self.end[lane] = max(int(self.end[lane]), lo)
+        last = (pos + n - 1) // ps
+        ok = last < row.shape[0]
+        for i in range(int(self.end[lane]), min(last, row.shape[0] - 1) + 1):
+            if not self._free:
+                ok = False
+                break
+            row[i] = self._free.pop()
+            self.end[lane] = i + 1
+            moved = True
+        if moved:
+            self.version += 1
+        return ok
+
+    def free(self, lane: int) -> None:
+        """Release every page ``lane`` holds."""
+        row = self.tables[lane]
+        for i in range(int(self.first[lane]), int(self.end[lane])):
+            self._free.append(int(row[i]))
+        row[:] = 0
+        self.first[lane] = self.end[lane] = 0
+        self.version += 1
+
+    def check_invariants(self) -> None:
+        """Conservation: every usable page is free or held by exactly one
+        lane inside its ``[first, end)``, and nothing else is in a table."""
+        held = []
+        for lane in range(self.lanes):
+            row = self.tables[lane]
+            lo, hi = int(self.first[lane]), int(self.end[lane])
+            assert 0 <= lo <= hi <= len(row), (lane, lo, hi)
+            assert hi - lo <= self.lane_pages, (
+                f"lane {lane} holds {hi - lo} window pages, more than "
+                f"{self.lane_pages}")
+            assert not row[:lo].any() and not row[hi:].any(), (
+                f"lane {lane} has window pages outside [{lo}, {hi})")
+            assert row[lo:hi].all(), f"lane {lane} holds the trash page"
+            held += row[lo:hi].tolist()
+        assert len(held) == len(set(held)), "a window page is held twice"
+        assert not set(held) & set(self._free), "a held window page is free"
+        assert len(held) + len(self._free) == self.usable_pages, (
+            f"window pages lost: {len(held)} held + {len(self._free)} free "
+            f"!= {self.usable_pages}")
+
+
 class PagedKVCacheManager(_LaneBook):
     """Page-granular decode cache + lane bookkeeping (module docstring
     has the design).
@@ -1089,7 +1232,8 @@ class PagedKVCacheManager(_LaneBook):
 
     def __init__(self, model, slots: int, cache_len: int, num_pages: int,
                  page_size: int, prefix_cache: bool = True,
-                 host_store: Optional[HostPageStore] = None):
+                 host_store: Optional[HostPageStore] = None,
+                 window_span: int = 0):
         from fleetx_tpu.models.gpt.generation import init_decode_cache
 
         if page_size % 8:
@@ -1120,6 +1264,25 @@ class PagedKVCacheManager(_LaneBook):
                              host_store=host_store,
                              spill_fn=self._spill_pages,
                              revive_fn=self._revive_pages)
+        # the second class of page (module docstring): a model with window
+        # layers says how many pages one of them owns
+        self.window_pool = None
+        self.admits_refused = {"full": 0, "window": 0}
+        window_pages = getattr(cfg, "decode_window_pages", None)
+        if window_pages:
+            if prefix_cache:
+                raise ValueError(
+                    "prefix reuse over window-attention layers: a prefix's "
+                    "window pages are released once the window has passed "
+                    "them, so there is nothing to share (prefix_cache=False)")
+            if window_span < 1:
+                raise ValueError(
+                    "window-attention layers need chunked prefill: "
+                    "window_span (the engine's prefill_chunk) bounds what a "
+                    "lane's window pages hold beside the window")
+            self.window_pool = WindowPagePool(
+                window_pages, page_size, slots, cache_len // page_size,
+                cfg.sliding_window, window_span)
         self.cache = init_decode_cache(model, slots)
 
     # ------------------------------------------------------ host spill tier
@@ -1226,13 +1389,40 @@ class PagedKVCacheManager(_LaneBook):
 
     @property
     def tables(self) -> np.ndarray:
-        """Host block tables [slots, cache_len // page_size] int32."""
-        return self.pool.tables
+        """Host block tables [slots, cache_len // page_size] int32; with a
+        window class ``[2, slots, ...]``, full then window."""
+        if self.window_pool is None:
+            return self.pool.tables
+        return np.stack([self.pool.tables, self.window_pool.tables])
+
+    def lane_tables(self, slot: int) -> np.ndarray:
+        """``slot``'s row of :attr:`tables` (of every class: ``[2, ...]``
+        with a window class)."""
+        if self.window_pool is None:
+            return self.pool.tables[slot]
+        return np.stack([self.pool.tables[slot],
+                         self.window_pool.tables[slot]])
 
     @property
     def tables_version(self) -> int:
         """Monotone counter: re-upload the device tables when it moves."""
-        return self.pool.version
+        if self.window_pool is None:
+            return self.pool.version
+        return self.pool.version + self.window_pool.version
+
+    def class_counters(self) -> dict:
+        """The pool by class of page, for ``ServingMetrics.snapshot()``
+        (through ``model_protocol.device_counters_of``); empty with one
+        class."""
+        if self.window_pool is None:
+            return {}
+        return {"pages_in_use_full": self.pool.pages_in_use,
+                "pages_in_use_window": self.window_pool.pages_in_use,
+                "usable_pages_full": self.pool.usable_pages,
+                "usable_pages_window": self.window_pool.usable_pages,
+                "window_pages_recycled": self.window_pool.recycled,
+                "admits_refused_full": self.admits_refused["full"],
+                "admits_refused_window": self.admits_refused["window"]}
 
     @property
     def pages_in_use(self) -> int:
@@ -1251,8 +1441,18 @@ class PagedKVCacheManager(_LaneBook):
     # ---------------------------------------------------------- lifecycle
 
     def can_admit(self, tokens) -> bool:
-        """A free lane AND enough free pages for this prompt right now."""
-        return bool(self._free) and self.pool.can_admit(tokens)
+        """A free lane AND enough free pages for this prompt right now,
+        of every class."""
+        if not self._free:
+            return False
+        if not self.pool.can_admit(tokens):
+            self.admits_refused["full"] += 1
+            return False
+        if (self.window_pool is not None
+                and not self.window_pool.can_admit(len(tokens))):
+            self.admits_refused["window"] += 1
+            return False
+        return True
 
     def alloc(self, request_id: int, tokens) -> Optional[Tuple[int, int]]:
         """Claim the lowest free lane + a page chain for prompt ``tokens``.
@@ -1283,9 +1483,19 @@ class PagedKVCacheManager(_LaneBook):
 
     def ensure_page(self, slot: int) -> bool:
         """Grow ``slot``'s chain to cover its next write position
-        (``lengths[slot]``); False = pool dry, caller retires the
-        request."""
-        return self.pool.ensure_page(slot, int(self.lengths[slot]))
+        (``lengths[slot]``), in every class; False = a pool is dry, caller
+        retires the request."""
+        pos = int(self.lengths[slot])
+        return self.pool.ensure_page(slot, pos) and (
+            self.window_pool is None or self.window_pool.prepare(slot, pos))
+
+    def prepare_span(self, slot: int, pos: int, n: int) -> bool:
+        """Before a prefill call writes positions ``[pos, pos + n)`` of
+        ``slot``: the window class lets go of what lies behind and
+        allocates the span (the full class holds its pages since
+        ``alloc``). True with one class."""
+        return (self.window_pool is None
+                or self.window_pool.prepare(slot, pos, n))
 
     def ensure_span(self, slot: int, n: int) -> int:
         """Grow ``slot``'s chain toward covering its next ``n`` write
@@ -1306,4 +1516,6 @@ class PagedKVCacheManager(_LaneBook):
         if self.request_ids[slot] is None:
             raise ValueError(f"slot {slot} is already free")
         self.pool.free(slot)
+        if self.window_pool is not None:
+            self.window_pool.free(slot)
         self._release_lane(slot)

@@ -248,6 +248,7 @@ from fleetx_tpu.serving.cache_manager import (
     HostPageStore,
     PagedKVCacheManager,
     TieredPageStore,
+    window_lane_pages,
 )
 from fleetx_tpu.resilience.faults import faults
 from fleetx_tpu.serving.metrics import ServingMetrics
@@ -512,6 +513,36 @@ class ServingEngine:
         self.num_pages = (num_pages
                           or _env_int("FLEETX_SERVING_PAGES", 0)
                           or self.slots * (cache_len // self.page_size) + 1)
+        # chunked prefill (module docstring): 0/off = today's whole-prompt
+        # prefill-on-insert, byte-identical; >0 bounds per-tick prefill
+        # work to one chunk-sized call so decode TPOT never stalls longer
+        self.prefill_chunk = (prefill_chunk if prefill_chunk is not None
+                              else _env_int("FLEETX_SERVING_PREFILL_CHUNK", 0))
+        if self.prefill_chunk < 0:
+            raise ValueError(
+                f"prefill_chunk must be >= 0, got {self.prefill_chunk}")
+        # a second class of page for a model's window-attention layers
+        # (cache_manager.py "Two classes of page"): what the family cannot
+        # ride is refused here, with its cause
+        self.window_pages = None
+        extra = {}
+        if "window" in self.capabilities.page_classes:
+            self.capabilities.require(
+                supports_prefix_cache=bool(prefix_cache),
+                supports_roles=self.role != "both")
+            prefix_cache = False
+            if not self.prefill_chunk:
+                raise ValueError(
+                    f"model family {self.model_family!r} has window-attention "
+                    "layers: prefill_chunk must be set (a lane's window pages "
+                    "hold the window plus one chunk, and a whole-prompt "
+                    "prefill through the dense path does not fit long "
+                    "prompts)")
+            # sized so that no lane can meet a dry window class
+            self.window_pages = self.slots * window_lane_pages(
+                model.cfg.sliding_window, self.prefill_chunk,
+                self.page_size) + 1
+            extra["decode_window_pages"] = self.window_pages
         self.prefix_cache = (
             prefix_cache if prefix_cache is not None
             else _env_int("FLEETX_SERVING_PREFIX_CACHE", 1) == 1)
@@ -519,7 +550,7 @@ class ServingEngine:
             model.cfg, decode_cache_len=cache_len,
             decode_num_pages=self.num_pages,
             decode_page_size=self.page_size,
-            decode_kv_dtype=decode_kv))
+            decode_kv_dtype=decode_kv, **extra))
         if self.executor is None:
             # wrap the decode-configured clone: init_cache/forward read
             # decode_cache_len/pages off cfg, so the executor must see
@@ -549,14 +580,6 @@ class ServingEngine:
         self.topk_cap = topk_cap or _env_int("FLEETX_SERVING_TOPK_CAP", 64)
         self.prefill_bucket = (prefill_bucket
                                or _env_int("FLEETX_SERVING_PREFILL_BUCKET", 32))
-        # chunked prefill (module docstring): 0/off = today's whole-prompt
-        # prefill-on-insert, byte-identical; >0 bounds per-tick prefill
-        # work to one chunk-sized call so decode TPOT never stalls longer
-        self.prefill_chunk = (prefill_chunk if prefill_chunk is not None
-                              else _env_int("FLEETX_SERVING_PREFILL_CHUNK", 0))
-        if self.prefill_chunk < 0:
-            raise ValueError(
-                f"prefill_chunk must be >= 0, got {self.prefill_chunk}")
         # host-DRAM KV spill tier (module docstring): 0/off = LRU eviction
         # destroys warm trie pages (today's behavior); >0 bounds the
         # pinned-host store warm pages spill into instead
@@ -621,7 +644,7 @@ class ServingEngine:
         self.cache_manager = PagedKVCacheManager(
             self.model, self.slots, cache_len, self.num_pages,
             self.page_size, prefix_cache=self.prefix_cache,
-            host_store=self._host_store)
+            host_store=self._host_store, window_span=self.prefill_chunk)
         # mesh: the freshly-built cache tree splits its heads over mp
         # (scale leaves ride the same rule); state/tables replicate
         self.cache_manager.cache = self._shard_cache(self.cache_manager.cache)
@@ -1248,7 +1271,8 @@ class ServingEngine:
             self.cache_manager = PagedKVCacheManager(
                 self.model, self.slots, self.cache_len, self.num_pages,
                 self.page_size, prefix_cache=self.prefix_cache,
-                host_store=self._host_store)
+                host_store=self._host_store,
+                window_span=self.prefill_chunk)
             # the rebuilt device cache re-commits onto the SAME mesh
             # layout — host truth is mesh-agnostic, the layout is not
             self.cache_manager.cache = self._shard_cache(
@@ -1326,8 +1350,13 @@ class ServingEngine:
                 f"{self.cache_manager.pool.free_pages} pages free)")
         lane, shared = alloc
         req.slot = lane
-        self._paged_prefill_call(req, history[shared:], shared, lane,
-                                 replay=True)
+        # a window class holds the window plus one chunk: its history is
+        # written chunk by chunk, as its prefill was
+        suffix = history[shared:]
+        step = self.prefill_chunk if self.window_pages else len(suffix)
+        for at in range(0, max(len(suffix), 1), max(step, 1)):
+            self._paged_prefill_call(req, suffix[at:at + step],
+                                     shared + at, lane, replay=True)
         self._register_prefix(req)
         # reconstruct the request's RNG stream position: one split at
         # admit, one per decode tick it was active in (greedy requests
@@ -1675,7 +1704,7 @@ class ServingEngine:
         self._fault_ships += 1
         faults.on_kv_ship(attempt, request_id)
         n_pages = -(-req.prompt_len // self.page_size)
-        table = self.cache_manager.tables[req.slot]
+        table = self.cache_manager.pool.tables[req.slot]
         pages = [int(table[i]) for i in range(n_pages)]
         with span("serving.export_kv", request=request_id, pages=n_pages):
             payloads = self.cache_manager.read_pages(pages)
@@ -1726,6 +1755,14 @@ class ServingEngine:
                "slots": self.slots,
                "pages_in_use": self.cache_manager.pages_in_use,
                "usable_pages": self.cache_manager.usable_pages}
+        # a pool of two classes of page reports both ("pages_in_use" and
+        # "usable_pages" above stay the full class's)
+        classes = self.cache_manager.class_counters()
+        if classes:
+            out["page_classes"] = {
+                kind: {"pages_in_use": classes[f"pages_in_use_{kind}"],
+                       "usable_pages": classes[f"usable_pages_{kind}"]}
+                for kind in self.capabilities.page_classes}
         return out
 
     def declare_dead(self) -> None:
@@ -1874,7 +1911,7 @@ class ServingEngine:
         # K + V bytes one cached token costs across every layer ON ONE
         # DEVICE, scales included (one fp32 scale per head vector at
         # int8); heads divide over mp under a mesh
-        kv_bytes = cfg.num_layers * (cfg.num_attention_heads // mp) * 2 * (
+        kv_bytes = cfg.num_layers * (cfg.kv_heads // mp) * 2 * (
             cfg.head_dim * kv_item + (4 if self.kv_dtype == "int8" else 0))
         weight_bytes = sum(
             leaf_device_nbytes(leaf)
@@ -1992,7 +2029,9 @@ class ServingEngine:
                               max_pos - 1)[None, :]
             logits, cache = self.executor.forward(
                 params, cache, ids, pos,
-                cache_positions=wpos[None], block_tables=table[None])
+                cache_positions=wpos[None],
+                # [pages] -> [1, pages]; two classes [2, pages] -> [2, 1, .]
+                block_tables=jnp.expand_dims(table, -2))
             cache = self._pin_cache(cache)
             return cache, self._first_token(
                 logits, true_len, eos, min_new, greedy, temperature, top_k,
@@ -2070,9 +2109,13 @@ class ServingEngine:
         if fn is None:
             fn = self._prefill_jits[bucket] = \
                 self._make_paged_prefill(bucket)
+        if not self.cache_manager.prepare_span(lane, shared, len(suffix)):
+            raise RuntimeError(
+                f"window pages ran dry preparing {len(suffix)} tokens at "
+                f"{shared} for request {req.id}")
         operands, carry_key = self._prefill_args(
             req, suffix, bucket, replay, shared,
-            self.cache_manager.tables[lane])
+            self.cache_manager.lane_tables(lane))
         args = (self.params, self.cache_manager.cache, *operands)
         tok = self._guarded_prefill(req, fn, args, bucket=bucket)
         return None if replay else (tok, carry_key)
@@ -2213,7 +2256,7 @@ class ServingEngine:
             # trie/host-revived prefix pages are already populated —
             # revive only the shipped pages beyond them
             start = shared // self.page_size
-            table = self.cache_manager.tables[lane]
+            table = self.cache_manager.pool.tables[lane]
             entries = [(int(table[i]), payloads[i])
                        for i in range(start, len(payloads))]
             if entries:
@@ -2395,6 +2438,17 @@ class ServingEngine:
                     retired.append(req.id)
         return retired
 
+    def _decode_rows(self) -> dict:
+        """Span fields of a tick over two classes of page: the live cache
+        rows its kernel calls read for the active lanes, in ONE full layer
+        (every row up to the token being written) and in ONE window layer
+        (the window's rows of those). Empty with one class."""
+        if not self.window_pages:
+            return {}
+        rows = self.cache_manager.lengths[list(self._active)] + 1
+        return {"full_rows": int(rows.sum()), "window_rows": int(
+            np.minimum(rows, self.model.cfg.sliding_window).sum())}
+
     def _tick_decode(self):
         retired = self._grow_pages()
         if not self._active:
@@ -2425,7 +2479,8 @@ class ServingEngine:
                 jax.block_until_ready(out)
             return out
 
-        with span("serving.decode", batch=len(active_ids)):
+        with span("serving.decode", batch=len(active_ids),
+                  **self._decode_rows()):
             cache, st, tok, done = self._run_device(run)
         self.cache_manager.cache = cache
         self._state = st

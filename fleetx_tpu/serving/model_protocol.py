@@ -90,10 +90,21 @@ class ModelCapabilities:
     supports_int8_weights: bool = True
     supports_int8_kv: bool = True
     supports_mesh: bool = True
+    #: the classes of page the engine's pool keeps for the model: "full"
+    #: (a layer keeps every token of a lane) and, for a model with
+    #: window-attention layers, "window" (a layer keeps what a live query
+    #: can still see: serving/cache_manager.py "Two classes of page").
+    #: Window pages are released behind the window, so a prompt's prefix
+    #: cannot be reused from them, nor shipped between replicas
+    page_classes: tuple = ("full",)
+    supports_prefix_cache: bool = True
+    supports_roles: bool = True
 
     def as_dict(self) -> dict:
-        """JSON-ready form for the ``/healthz`` report."""
-        return dataclasses.asdict(self)
+        """JSON-ready form for the ``/healthz`` report (a tuple reads as
+        the list its JSON round trip gives back)."""
+        return {k: list(v) if isinstance(v, tuple) else v
+                for k, v in dataclasses.asdict(self).items()}
 
     def require(self, **asked) -> None:
         """The construction-time gate: ``asked`` maps a flag of this
@@ -114,6 +125,11 @@ _FEATURES = {
     "supports_int8_weights": "int8 weights: no test covers them",
     "supports_int8_kv": "an int8 KV cache: no test covers it",
     "supports_mesh": "a serving mesh: no test covers it",
+    "supports_prefix_cache": "prefix reuse: its window-attention layers "
+                             "release a prefix's pages once the window has "
+                             "passed them",
+    "supports_roles": "a prefill or decode role: the pages of its window "
+                      "class are not shipped between replicas",
 }
 
 
@@ -204,6 +220,12 @@ class GPTExecutor(ModelExecutor):
     def __init__(self, model, family: Optional[str] = None):
         self.model = model
         dense = not getattr(model.cfg, "expert_mode", False)
+        # grouped heads, a head size of its own, layers of two kinds
+        # (models/gpt/hybrid.py): the decode kernels take grouped heads
+        # without int8 scales and without a mesh, and no test speculates
+        kinds = bool(getattr(model.cfg, "layer_kinds", False))
+        windowed = kinds and any(model.cfg.window_layers)
+        dense = dense and not kinds
         self.capabilities = ModelCapabilities(
             family=family or getattr(model.cfg, "family", "gpt"),
             has_kv_cache=True,
@@ -213,6 +235,9 @@ class GPTExecutor(ModelExecutor):
             supports_int8_weights=dense,
             supports_int8_kv=dense,
             supports_mesh=dense,
+            page_classes=("full", "window") if windowed else ("full",),
+            supports_prefix_cache=not windowed,
+            supports_roles=not windowed,
         )
 
     def bind(self, model):
@@ -301,7 +326,9 @@ def device_counters_of(engine):
         if eng is None:
             return {}
         try:
-            return eng.executor.counters(eng.cache_manager.cache)
+            # and the pool by class of page, which is host state
+            return {**eng.executor.counters(eng.cache_manager.cache),
+                    **eng.cache_manager.class_counters()}
         except RuntimeError as err:
             # a scrape from another thread met a cache buffer the running
             # tick had just donated: nothing to report now, and the event

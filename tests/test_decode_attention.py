@@ -321,6 +321,93 @@ def test_decode_kernels_lower_for_tpu(monkeypatch, paged, h, d, kv_dtype,
     _lower_for_tpu(monkeypatch, fn, q, kv, ends)
 
 
+def _dense_grouped(q, k, v, end, starts):
+    """The dense path over grouped heads: query head r against key head
+    ``r // group`` (keys repeated for the reference, which the kernels never
+    do), the window ``[starts, end)`` of each lane."""
+    group = q.shape[2] // k.shape[2]
+    return _dense_window_attention(
+        q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2), end,
+        starts)
+
+
+@pytest.mark.parametrize("path", ["contiguous", "paged_page", "paged_block"])
+@pytest.mark.parametrize("heads,kv_heads", [(28, 4), (8, 2), (4, 4)])
+def test_grouped_heads_match_the_dense_path(path, heads, kv_heads):
+    """Grouped-query heads through the three kernel paths (the contiguous
+    kernel, the paged kernel at one page a step, and at several), at group
+    7 (28 rows against the lanes of 4 key heads: SmallThinker's), 4 and 1,
+    with ``starts`` from a window (``end - 24``) beside rows read from 0."""
+    rng = np.random.RandomState(3)
+    b, d, ps, n_row = 3, 16, 8, 12
+    q = jnp.asarray(rng.randn(b, 1, heads, d), jnp.float32)
+    k = jnp.asarray(rng.randn(b, n_row * ps, kv_heads, d), jnp.float32)
+    v = jnp.asarray(rng.randn(b, n_row * ps, kv_heads, d), jnp.float32)
+    ends = jnp.asarray([5, 61, 96], jnp.int32)
+    starts = jnp.asarray([0, 61 - 24, 0], jnp.int32)
+    want = _dense_grouped(q, k, v, ends, starts)
+    if path == "contiguous":
+        got = flash_decode_attention(q, _fold(k), _fold(v), end=ends,
+                                     starts=starts, block_k=16, block_major=32)
+    else:
+        # every lane's rows scattered over a shared pool through its table
+        order = 1 + rng.permutation(b * n_row).reshape(b, n_row)
+        pool_k = np.zeros((b * n_row + 1, ps, kv_heads * d), np.float32)
+        pool_v = np.zeros_like(pool_k)
+        for lane in range(b):
+            pool_k[order[lane]] = np.asarray(_fold(k))[lane].reshape(
+                n_row, ps, -1)
+            pool_v[order[lane]] = np.asarray(_fold(v))[lane].reshape(
+                n_row, ps, -1)
+        got = flash_decode_paged_attention(
+            q, jnp.asarray(pool_k), jnp.asarray(pool_v),
+            tables=jnp.asarray(order, jnp.int32), end=ends, starts=starts,
+            block_k=ps if path == "paged_page" else 4 * ps)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=_TOL, atol=_TOL)
+
+
+def test_grouped_heads_take_no_scales_and_no_mesh():
+    q = jnp.zeros((2, 1, 8, 16), jnp.float32)
+    kv = jnp.zeros((2, 32, 2 * 16), jnp.int8)
+    scale = jnp.ones((2, 32, 2), jnp.float32)
+    with pytest.raises(NotImplementedError, match="grouped"):
+        flash_decode_attention(q, kv, kv, end=jnp.asarray(4, jnp.int32),
+                               k_scale=scale, v_scale=scale)
+    with pytest.raises(ValueError, match="kv_heads"):
+        flash_decode_attention(q, jnp.zeros((2, 32, 3 * 16)),
+                               jnp.zeros((2, 32, 3 * 16)),
+                               end=jnp.asarray(4, jnp.int32))
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_grouped_kernels_lower_for_tpu_at_the_smallthinker_cell(monkeypatch,
+                                                                paged):
+    """``smallthinker-l8-serve-longdoc-gen``'s decode call (24 lanes, 28
+    query heads over 4 key heads of 128, page 16, 800 pages a row, a bf16
+    pool of both classes) passes the TPU lowering as the block kernel, 16
+    pages a step; the contiguous kernel at the same heads too."""
+    lanes, h, kv, d, ps, n_row = 24, 28, 4, 128, 16, 800
+    q = jnp.zeros((lanes, 1, h, d), jnp.bfloat16)
+    ends = jnp.full((lanes,), 6000, jnp.int32)
+    starts = ends - 4096
+    if paged:
+        pool = jnp.zeros((4096, ps, kv * d), jnp.bfloat16)
+        tables = jnp.zeros((lanes, n_row), jnp.int32)
+        fn = lambda q, kv_, t, e, s: flash_decode_paged_attention(
+            q, kv_, kv_, tables=t, end=e, starts=s)
+        traced, text = _lower_for_tpu(monkeypatch, fn, q, pool, tables, ends,
+                                      starts)
+        (call,) = _pallas_calls(traced.jaxpr.jaxpr)
+        assert call.params["grid_mapping"].grid == (lanes, n_row // 16)
+        assert f'kernel_name = "{PAGED_KERNEL_NAME}"' in text
+    else:
+        cache = jnp.zeros((lanes, 1024, kv * d), jnp.bfloat16)
+        fn = lambda q, c, e, s: flash_decode_attention(q, c, c, end=e,
+                                                       starts=s)
+        _lower_for_tpu(monkeypatch, fn, q, cache, ends // 8, starts // 8)
+
+
 def test_fit_decode_blocks():
     assert fit_decode_blocks(1024) == (256, 1024)
     assert fit_decode_blocks(16) == (16, 16)
