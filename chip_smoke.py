@@ -312,7 +312,6 @@ def _pool_stays_in_place(engine, tick) -> dict:
     pool is smaller than the weights' copy, so the temporaries alone
     cannot be held against it.) Bytes are per device."""
     import jax
-    import jax.numpy as jnp
 
     def device_bytes(tree, itemsize=None):
         return sum(
@@ -323,14 +322,12 @@ def _pool_stays_in_place(engine, tick) -> dict:
     pool = device_bytes(cache)
     weights_bf16 = device_bytes(engine.params, itemsize=2)
     bucket = max(engine._prefill_jits)
-    i32 = lambda v: jnp.asarray(v, jnp.int32)  # noqa: E731
     with engine._mesh_context():
         prefill = engine._prefill_jits[bucket].lower(
-            engine.params, cache, jnp.zeros((bucket,), jnp.int32),
-            i32(bucket), i32(0), i32(engine.cache_manager.tables[0]),
-            i32(-1), i32(0), jnp.asarray(True),
-            jnp.asarray(1.0, jnp.float32), i32(0),
-            jnp.asarray(1.0, jnp.float32), jax.random.PRNGKey(0)).compile()
+            engine.params, cache,
+            engine._prefill_ints(
+                (), bucket, 0, engine.cache_manager.lane_tables(0)),
+            engine._inert_floats, jax.random.PRNGKey(0)).compile()
     out = {"pool_bytes": pool, "weights_bf16_bytes": weights_bf16}
     for name, program in (("tick", tick), (f"prefill_{bucket}", prefill)):
         temp = int(program.memory_analysis().temp_size_in_bytes)

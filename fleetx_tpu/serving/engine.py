@@ -309,6 +309,27 @@ def _env_float(name: str, default: float) -> float:
         return default
 
 
+# An admission's operands cross to the device PACKED, one host-built
+# numpy array a dtype (PERF.md, PR 32): a prefill call takes the int32
+# vector of _prefill_ints, a lane install the one of _install_lane, and
+# temperature and top_p ride a float32 pair that one upload serves both
+# with. Flags are 0/1 and become bool by comparison inside the program.
+
+
+def _upload(at, host: np.ndarray):
+    """THE host-to-device transfer of an admission: a plain copy of one
+    numpy array (no device program, where ``jnp.asarray(scalar, dtype)``
+    runs a convert), counted as ``transfers`` on the span whose attrs
+    are ``at``."""
+    at["transfers"] += 1
+    return jax.device_put(host)
+
+
+def _sampler_floats(req: Request) -> np.ndarray:
+    """The float32 operand of a request's prefill and lane install."""
+    return np.asarray([req.temperature, req.top_p], np.float32)
+
+
 def _deactivate(st, slot):
     # clear one slot's active lane; its row still rides the batched decode
     # step (outputs discarded) exactly like any other free slot
@@ -683,6 +704,8 @@ class ServingEngine:
         # leave the committed cache/state buffers untouched
         self._probe_jit = jax.jit(self._decode_fn, static_argnums=(4,))
         self._admit_jit = jax.jit(self._admit_fn, donate_argnums=())
+        # a replay call's temperature and top_p: inert, and resident
+        self._inert_floats = jax.device_put(np.ones(2, np.float32))
         self._deactivate_jit = jax.jit(_deactivate)
         self._prefill_jits = {}  # bucket_len -> jitted prefill
         self._donate_cache = donate
@@ -1926,26 +1949,24 @@ class ServingEngine:
             self.kv_dtype, self.weight_dtype, kv_bytes, weight_bytes,
             kv_cache_bytes=self.cache_manager.cache_nbytes())
 
-    def _admit_fn(self, st, slot, tok, length, decoded, active, eos, max_new,
-                  min_new, greedy, temperature, top_k, top_p, key):
-        """Jitted: install one request's scalars into slot ``slot`` of the
-        device state — ``decoded=1`` for a fresh admission (first token
-        just sampled), ``decoded=n`` when replay recovery reinstalls a
-        request that already emitted ``n`` tokens."""
-        return {
-            "last_tok": st["last_tok"].at[slot].set(tok),
-            "lengths": st["lengths"].at[slot].set(length),
-            "decoded": st["decoded"].at[slot].set(decoded),
-            "active": st["active"].at[slot].set(active),
-            "eos": st["eos"].at[slot].set(eos),
-            "max_new": st["max_new"].at[slot].set(max_new),
-            "min_new": st["min_new"].at[slot].set(min_new),
-            "greedy": st["greedy"].at[slot].set(greedy),
-            "temperature": st["temperature"].at[slot].set(temperature),
-            "top_k": st["top_k"].at[slot].set(top_k),
-            "top_p": st["top_p"].at[slot].set(top_p),
-            "rng": st["rng"].at[slot].set(key),
+    def _admit_fn(self, st, ints, floats, key):
+        """Jitted: install one request's scalars (``ints`` as
+        ``_install_lane`` packed them, ``floats`` temperature and top_p)
+        into slot ``slot`` of the device state — ``decoded=1`` for a
+        fresh admission (first token just sampled), ``decoded=n`` when
+        replay recovery reinstalls a request that already emitted ``n``
+        tokens."""
+        (slot, tok, length, decoded, active, eos, max_new, min_new, greedy,
+         top_k) = ints
+        lane = {
+            "last_tok": tok, "lengths": length, "decoded": decoded,
+            "active": active != 0, "eos": eos, "max_new": max_new,
+            "min_new": min_new, "greedy": greedy != 0,
+            "temperature": floats[0], "top_k": top_k, "top_p": floats[1],
+            "rng": key,
         }
+        return {name: st[name].at[slot].set(value)
+                for name, value in lane.items()}
 
     def _admission_tokens(self, req: Request) -> np.ndarray:
         """The tokens admission must find storage for: the prompt alone
@@ -2017,9 +2038,19 @@ class ServingEngine:
         already in place, then samples the first token — the prefix-cache
         compute saving is exactly the skipped ``wpos`` leading tokens."""
         max_pos = self.model.cfg.max_position_embeddings
+        # one class of page [pages], two classes [2, pages]
+        table_shape = self.cache_manager.lane_tables(0).shape
+        n_table = int(np.prod(table_shape))
 
-        def prefill(params, cache, suffix, true_len, wpos, table, eos,
-                    min_new, greedy, temperature, top_k, top_p, key):
+        def prefill(params, cache, ints, floats, key):
+            # as _prefill_ints packed them
+            true_len, wpos, eos, min_new, greedy, top_k = ints[:6]
+            table = ints[6:6 + n_table].reshape(table_shape)
+            suffix = ints[6 + n_table:]
+            # the admission's one split of the request's stream: the
+            # sampler's key, and the carry the lane install takes (the
+            # bits of the eager split; a replay call drops both)
+            step_key, carry_key = jax.random.split(key)
             params = self._dequant_params(params)
             ids = suffix[None, :]
             # absolute positions wpos.. for the suffix; the right-pad
@@ -2034,50 +2065,55 @@ class ServingEngine:
                 block_tables=jnp.expand_dims(table, -2))
             cache = self._pin_cache(cache)
             return cache, self._first_token(
-                logits, true_len, eos, min_new, greedy, temperature, top_k,
-                top_p, key)
+                logits, true_len, eos, min_new, greedy != 0, floats[0],
+                top_k, floats[1], step_key), carry_key
 
         return jax.jit(
             prefill, donate_argnums=(1,) if self._donate_cache else ())
 
-    def _prefill_scalars(self, req: Request, replay: bool, step_key):
-        """Per-request sampler scalars for a prefill call. Replay rebuilds
-        K/V only: greedy argmax with inert filters (result discarded, no
-        stream consumed)."""
-        if replay:
-            return (jnp.asarray(-1, jnp.int32), jnp.asarray(0, jnp.int32),
-                    jnp.asarray(True), jnp.asarray(1.0, jnp.float32),
-                    jnp.asarray(0, jnp.int32), jnp.asarray(1.0, jnp.float32),
-                    req.rng_key)
-        return (jnp.asarray(req.eos_token_id, jnp.int32),
-                jnp.asarray(req.min_new_tokens, jnp.int32),
-                jnp.asarray(req.greedy),
-                jnp.asarray(req.temperature, jnp.float32),
-                jnp.asarray(req.top_k, jnp.int32),
-                jnp.asarray(req.top_p, jnp.float32),
-                step_key)
+    @staticmethod
+    def _prefill_ints(tokens, bucket: int, wpos: int, table, *,
+                      eos: int = -1, min_new: int = 0, greedy: bool = True,
+                      top_k: int = 0) -> np.ndarray:
+        """The int32 operand of a prefill call, built on the host: six
+        scalars, the lane's table row (of every class), then ``tokens``
+        right-padded to ``bucket``. The defaults are a replay's inert
+        sampler: greedy argmax, no filter, nothing suppressed (the result
+        is discarded)."""
+        table = np.asarray(table, np.int32).ravel()
+        ints = np.zeros(6 + table.size + bucket, np.int32)
+        ints[:6] = len(tokens), wpos, eos, min_new, greedy, top_k
+        ints[6:6 + table.size] = table
+        ints[6 + table.size:][:len(tokens)] = tokens
+        return ints
 
     def _prefill_args(self, req: Request, tokens, bucket: int, replay: bool,
-                      *offsets):
+                      wpos: int, table):
         """Everything a prefill call uploads before it can be dispatched,
-        under one ``serving.prefill_args`` span: the prompt padded to its
-        bucket, its true length, the call's own ``offsets`` (slot, write
-        position, block-table row) and the sampler scalars. Returns
-        ``(operands after params and cache, carry_key)``; replay calls
-        consume no randomness (``carry_key`` None)."""
-        with span("serving.prefill_args", request=req.id, bucket=bucket):
-            padded = np.zeros(bucket, np.int32)
-            padded[:len(tokens)] = tokens
-            step_key = carry_key = None
-            if not replay:
-                step_key, carry_key = jax.random.split(req.rng_key)
-            return (jnp.asarray(padded), jnp.asarray(len(tokens), jnp.int32),
-                    *(jnp.asarray(v, jnp.int32) for v in offsets),
-                    *self._prefill_scalars(req, replay, step_key)), carry_key
+        under one ``serving.prefill_args`` span, each a plain copy of a
+        host-built array (``transfers`` counts them: the int32 vector
+        with the prompt padded to its bucket, and an admission's float32
+        pair): no device program runs here. Returns the operands after
+        params and cache: ``(ints, floats, key)``. A replay call (K/V
+        only, also an intermediate chunk) takes the inert sampler, whose
+        float32 pair is the engine's resident constant."""
+        with span("serving.prefill_args", request=req.id, bucket=bucket,
+                  transfers=0) as at:
+            if replay:
+                ints = self._prefill_ints(tokens, bucket, wpos, table)
+                floats = self._inert_floats
+            else:
+                ints = self._prefill_ints(
+                    tokens, bucket, wpos, table, eos=req.eos_token_id,
+                    min_new=req.min_new_tokens, greedy=req.greedy,
+                    top_k=req.top_k)
+                floats = _upload(at, _sampler_floats(req))
+            return _upload(at, ints), floats, req.rng_key
 
     def _guarded_prefill(self, req: Request, fn, args, bucket=None):
         """One prefill device call through the fault-injection hook;
-        stores the returned cache in the cache manager. Deliberately NOT
+        stores the returned cache in the cache manager and returns the
+        first token with the stream's carry key. Deliberately NOT
         under the hung-tick watchdog: prefill calls legitimately include
         fresh-bucket XLA compiles (seconds), and replay recovery
         re-prefills through here — a watchdog here would misread every
@@ -2089,15 +2125,16 @@ class ServingEngine:
         with span("serving.prefill", request=req.id, bucket=bucket):
             faults.on_serving_prefill(attempt, req.id)
             with self._mesh_context():
-                cache, tok = fn(*args)
+                cache, tok, carry_key = fn(*args)
         self.cache_manager.cache = cache
-        return tok
+        return tok, carry_key
 
     def _paged_prefill_call(self, req: Request, suffix, shared, lane,
                             replay: bool = False):
         """Batch-1 prefill of the non-shared ``suffix`` straight into
         ``lane``'s pages at absolute positions ``shared..``. Admission
-        returns ``(first_token, carry_key)``; replay returns None.
+        returns ``(first_token, carry_key, sampler_floats)``, all on the
+        device; replay returns None.
         Chunked prefill reuses this call verbatim — an intermediate
         chunk is exactly a ``replay`` call (KV writes only, inert
         sampler, no rng consumed) at its chunk's write offset, and the
@@ -2113,12 +2150,12 @@ class ServingEngine:
             raise RuntimeError(
                 f"window pages ran dry preparing {len(suffix)} tokens at "
                 f"{shared} for request {req.id}")
-        operands, carry_key = self._prefill_args(
+        ints, floats, key = self._prefill_args(
             req, suffix, bucket, replay, shared,
             self.cache_manager.lane_tables(lane))
-        args = (self.params, self.cache_manager.cache, *operands)
-        tok = self._guarded_prefill(req, fn, args, bucket=bucket)
-        return None if replay else (tok, carry_key)
+        args = (self.params, self.cache_manager.cache, ints, floats, key)
+        tok, carry_key = self._guarded_prefill(req, fn, args, bucket=bucket)
+        return None if replay else (tok, carry_key, floats)
 
     def _claim_storage(self, req: Request) -> int:
         """Claim a decode lane and its page chain for one admission; sets
@@ -2141,25 +2178,22 @@ class ServingEngine:
             return shared
 
     def _install_lane(self, req: Request, *, tok: int, length: int,
-                      decoded: int, active: bool, carry_key) -> None:
+                      decoded: int, active: bool, carry_key,
+                      floats=None) -> None:
         """Install one request's decode-lane scalars into the device
-        state (shared by fresh admission and replay recovery)."""
-        with span("serving.install", request=req.id):
+        state (shared by fresh admission, replay recovery and a shipped
+        admission): ten int32 in one upload; ``floats`` is the float32
+        pair the admission's prefill already sent, uploaded here when no
+        such call was made."""
+        with span("serving.install", request=req.id, transfers=0) as at:
+            ints = np.asarray(
+                [req.slot, tok, length, decoded, active, req.eos_token_id,
+                 req.max_new_tokens, req.min_new_tokens, req.greedy,
+                 req.top_k], np.int32)
+            if floats is None:
+                floats = _upload(at, _sampler_floats(req))
             self._state = self._admit_jit(
-                self._state, jnp.asarray(req.slot, jnp.int32),
-                jnp.asarray(tok, jnp.int32),
-                jnp.asarray(length, jnp.int32),
-                jnp.asarray(decoded, jnp.int32),
-                jnp.asarray(active),
-                jnp.asarray(req.eos_token_id, jnp.int32),
-                jnp.asarray(req.max_new_tokens, jnp.int32),
-                jnp.asarray(req.min_new_tokens, jnp.int32),
-                jnp.asarray(req.greedy),
-                jnp.asarray(req.temperature, jnp.float32),
-                jnp.asarray(req.top_k, jnp.int32),
-                jnp.asarray(req.top_p, jnp.float32),
-                carry_key,
-            )
+                self._state, _upload(at, ints), floats, carry_key)
 
     def _register_prefix(self, req: Request) -> None:
         """Enter the request's prompt pages into the prefix trie (host
@@ -2218,7 +2252,7 @@ class ServingEngine:
                 self._fault_ctx = None
                 self._run_chunk(req)  # this tick's one chunk of budget
                 return
-            tok, carry_key = self._paged_prefill_call(
+            first = self._paged_prefill_call(
                 req, req.prompt[shared:], shared, req.slot)
             self._register_prefix(req)
             self._fault_ctx = None
@@ -2226,7 +2260,7 @@ class ServingEngine:
             now = self._now()
             req.admit_time = now
             self.metrics.record_admit(now - req.submit_time)
-            self._finish_first_token(req, tok, carry_key)
+            self._finish_first_token(req, *first)
 
     def _admit_shipped(self, req: Request) -> None:
         """Admit a request whose prompt KV arrived from a PREFILL-role
@@ -2307,11 +2341,10 @@ class ServingEngine:
         self.metrics.record_prefill_chunk(len(tokens))
         if not final:
             return
-        tok, carry_key = out
         self._register_prefix(req)
         del self._prefilling[req.slot]
         self._prefill_strikes.pop(req.id, None)
-        self._finish_first_token(req, tok, carry_key)
+        self._finish_first_token(req, *out)
 
     def _chunk_tick(self):
         """Advance the mid-prefill request by ONE chunk this tick —
@@ -2334,7 +2367,8 @@ class ServingEngine:
         self._run_chunk(req)
         return 1, []
 
-    def _finish_first_token(self, req: Request, tok, carry_key) -> None:
+    def _finish_first_token(self, req: Request, tok, carry_key,
+                            floats) -> None:
         """Shared admission tail: wait for the prefill's first token
         (``serving.first_token``: the host-visible prefill wait), then
         install the decode lane, record TTFT, fire the callback, route
@@ -2353,7 +2387,7 @@ class ServingEngine:
         parked = self.role == "prefill" and not done
         self._install_lane(req, tok=tok, length=req.prompt_len, decoded=1,
                            active=not done and not parked,
-                           carry_key=carry_key)
+                           carry_key=carry_key, floats=floats)
         # callback AFTER the device state is consistent: a raising callback
         # then retires exactly this request and can't leave the slot half-
         # installed (previously it unwound _admit between cache scatter and

@@ -134,12 +134,10 @@ def _donating_programs(eng, tokens):
                         eng._device_tables(), True)
     eng._donate_cache = True
     fn = eng._make_paged_prefill(tokens)
-    i32 = lambda v: jnp.asarray(v, jnp.int32)
     return fn.lower(
-        eng.params, cache, jnp.zeros((tokens,), jnp.int32), i32(tokens),
-        i32(0), i32(eng.cache_manager.tables[0]), i32(-1), i32(0),
-        jnp.asarray(True), jnp.asarray(1.0, jnp.float32), i32(0),
-        jnp.asarray(1.0, jnp.float32), jax.random.PRNGKey(0))
+        eng.params, cache,
+        eng._prefill_ints((), tokens, 0, eng.cache_manager.lane_tables(0)),
+        eng._inert_floats, jax.random.PRNGKey(0))
 
 
 @KV

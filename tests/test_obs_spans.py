@@ -132,10 +132,17 @@ def test_admission_spans_nest_under_admit_with_their_attrs(ticked):
                        for s in spans), name
     assert by_name["serving.claim"][0].attrs == {"request": request,
                                                  "shared": 0}
+    # the uploads before the dispatch: the int32 vector that ends in the
+    # padded prompt, and the float32 pair, each one plain copy of a
+    # host-built array
     assert by_name["serving.prefill_args"][0].attrs == {
-        "request": request, "bucket": 8}
-    # the prefix registration (during the prefill) and the lane install
-    assert len(by_name["serving.install"]) == 2
+        "request": request, "bucket": 8, "transfers": 2}
+    # the prefix registration (during the prefill) and the lane install,
+    # which sends its int32 vector and takes the prefill's float32 pair
+    register, install = by_name["serving.install"]
+    assert register.attrs == {"request": request}
+    assert install.attrs == {"request": request, "transfers": 1}
+    assert by_name["serving.first_token"][0].end_s <= install.start_s
     # admit now ends after the first token was fetched and the lane
     # installed: its documented meaning
     assert admit.end_s >= by_name["serving.install"][-1].end_s

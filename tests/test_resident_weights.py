@@ -159,14 +159,12 @@ def program_jaxpr(engine, program):
         return jax.make_jaxpr(engine._decode_fn, static_argnums=(4,))(
             engine.params, cache, engine._state, engine._device_tables(),
             True).jaxpr
-    i32 = lambda v: jnp.asarray(v, jnp.int32)  # noqa: E731
     with engine._mesh_context():
         return jax.make_jaxpr(engine._make_paged_prefill(BUCKET))(
-            engine.params, cache, jnp.zeros((BUCKET,), jnp.int32),
-            i32(BUCKET), i32(0), i32(engine.cache_manager.tables[0]),
-            i32(-1), i32(0), jnp.asarray(True),
-            jnp.asarray(1.0, jnp.float32), i32(0),
-            jnp.asarray(1.0, jnp.float32), jax.random.PRNGKey(0)).jaxpr
+            engine.params, cache,
+            engine._prefill_ints(
+                (), BUCKET, 0, engine.cache_manager.lane_tables(0)),
+            engine._inert_floats, jax.random.PRNGKey(0)).jaxpr
 
 
 @pytest.mark.parametrize("program", ["tick", "prefill"])
