@@ -1,9 +1,13 @@
 """``GPTConfig``'s fields for a block whose layers are not all alike:
 grouped-query heads, a head size of its own, window and full attention
 layers mixed, rotary and position-free layers mixed, a router that reads
-the block's input. ``GPTConfig`` inherits them, ``check`` is the part of its
-``__post_init__`` that refuses what nobody wrote, and ``layer_class`` picks
-the layer that runs them (``models/gpt/hybrid.py``).
+the block's input; and for a stack whose layers do not even hold the same
+parameters (``layer_types``: gated short-convolution layers beside attention
+layers, dense feed-forward layers before expert layers, a sigmoid router
+with a selection bias). ``GPTConfig`` inherits them, ``check`` is the part of
+its ``__post_init__`` that refuses what nobody wrote, ``layer_class`` picks
+the layer that runs the first group (``models/gpt/hybrid.py``) and
+``stack_of`` the stack that runs the second (``models/gpt/mixed_stack.py``).
 
 A module of its own, and not a part of model.py, for the reason
 ``models/gpt/resident.py`` gives: a line added there makes every training
@@ -15,11 +19,14 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
-__all__ = ["BlockLayoutFields", "LAYOUT_FIELDS", "check", "layer_class"]
+__all__ = ["BlockLayoutFields", "LAYOUT_FIELDS", "LAYER_TYPES", "check",
+           "layer_class", "stack_of"]
 
 # per-layer lists (a YAML or JSON list becomes a tuple: the configuration is
 # a module attribute and has to hash)
-LAYOUT_FIELDS = ("rope_layout", "sliding_window_layout")
+LAYOUT_FIELDS = ("rope_layout", "sliding_window_layout", "layer_types")
+# the operators a layer of ``layer_types`` can name, under the source's names
+LAYER_TYPES = ("conv", "full_attention")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,6 +53,31 @@ class BlockLayoutFields:
     # ("mlp_norm": norm2 of the post-attention stream) or the block's input
     # before norm1 ("block_input")
     router_input: str = "mlp_norm"
+    # ---- a stack whose layers hold different parameters (mixed_stack.py).
+    # The operator of every layer, ``num_layers`` entries of LAYER_TYPES:
+    # "conv" is the gated short convolution ``out((C * conv(B * u)))`` with
+    # ``B, C, u = split(in(x), 3)`` and a causal depthwise filter of
+    # ``conv_L_cache`` taps, whose state is the last ``conv_L_cache - 1``
+    # rows of ``B * u``; "full_attention" the grouped attention of hybrid.py
+    layer_types: Optional[Tuple[str, ...]] = None
+    conv_L_cache: int = 3
+    # the first ``num_dense_layers`` layers take the dense MLP, of width
+    # ``dense_ffn_hidden_size``; the others experts of ``ffn_hidden_size``
+    num_dense_layers: int = 0
+    dense_ffn_hidden_size: Optional[int] = None
+    # what ``qk_norm`` normalises: the whole projection (all heads, one
+    # weight per element: OLMoE) or each "head" (one weight [head_dim] for
+    # q and one for k), either before the rotation
+    qk_norm_scope: str = "projection"
+    # gate "sigmoid_topk" (parallel/moe.py): scores ``sigmoid(router(x))``
+    # in float32; the ``top_k`` largest of ``score + expert_bias`` chosen
+    # (``use_expert_bias``: a float32 leaf [experts], read for the CHOICE
+    # only); weights the chosen scores, over their sum + 1e-6 under
+    # ``norm_topk_prob``, times ``routed_scaling_factor``. A bias made from
+    # a seed is drawn normal at ``expert_bias_init_std`` (0: zeros)
+    use_expert_bias: bool = False
+    expert_bias_init_std: float = 0.0
+    routed_scaling_factor: float = 1.0
     # serving, set by the engine beside ``decode_num_pages`` (which then
     # counts one full-attention layer's pages): the pages of one WINDOW
     # layer, whose lanes keep only the rows a live query can still see
@@ -61,7 +93,17 @@ class BlockLayoutFields:
         ``models/gpt/model.py`` writes: ``models/gpt/hybrid.py`` runs them."""
         return bool(self.kv_heads != self.num_attention_heads
                     or self.head_size or self.sliding_window
-                    or self.rope_layout or self.router_input != "mlp_norm")
+                    or self.rope_layout or self.router_input != "mlp_norm"
+                    or self.layer_types or self.qk_norm_scope != "projection")
+
+    @property
+    def state_kinds(self) -> Tuple[str, ...]:
+        """What a lane keeps in the page pool: keys and values ("kv") in
+        every attention layer and, in a gated short-convolution layer, the
+        operator's last inputs ("conv")."""
+        kinds = set(self.layer_types or ("full_attention",))
+        return tuple(name for name, kind in (("kv", "full_attention"),
+                                             ("conv", "conv")) if kind in kinds)
 
     @property
     def window_layers(self) -> Tuple[int, ...]:
@@ -85,12 +127,15 @@ def check(cfg) -> None:
         value = getattr(cfg, name)
         if value is None:
             continue
-        value = tuple(int(v) for v in value)
+        allowed = LAYER_TYPES if name == "layer_types" else (0, 1)
+        value = tuple(v if isinstance(v, str) else int(v) for v in value)
         object.__setattr__(cfg, name, value)
-        if len(value) != cfg.num_layers or set(value) - {0, 1}:
+        if len(value) != cfg.num_layers or set(value) - set(allowed):
             raise ValueError(
                 f"{name} has {len(value)} entries {value}; it needs "
-                f"num_layers = {cfg.num_layers} entries of 0 or 1")
+                f"num_layers = {cfg.num_layers} entries of "
+                + " | ".join(map(str, allowed)))
+    _check_mixed(cfg)
     if cfg.router_input not in ("mlp_norm", "block_input"):
         raise ValueError(f"router_input={cfg.router_input!r}; choose "
                          "mlp_norm | block_input")
@@ -121,7 +166,6 @@ def check(cfg) -> None:
     if not cfg.layer_kinds:
         return
     for field, why in (
-            ("qk_norm", "QK-norm over grouped heads: no test covers it"),
             ("sequence_parallel", "no test covers it"),
             ("no_recompute_layers", "the layers run as ONE scanned body, "
                                     "whatever the depth")):
@@ -138,6 +182,74 @@ def check(cfg) -> None:
         raise NotImplementedError(
             "decode_kv_dtype with grouped heads: the decode kernels take "
             "int8 scales a head, for as many key heads as query heads")
+
+
+def _check_mixed(cfg) -> None:
+    """The fields of a stack with layer types, a sigmoid gate or a per-head
+    QK-norm, each refusal with the field's name."""
+    if cfg.qk_norm_scope not in ("projection", "head"):
+        raise ValueError(f"qk_norm_scope={cfg.qk_norm_scope!r}; choose "
+                         "projection | head")
+    if cfg.qk_norm_scope == "head" and not cfg.qk_norm:
+        raise ValueError("qk_norm_scope='head' without qk_norm")
+    if (cfg.qk_norm and cfg.qk_norm_scope == "projection"
+            and cfg.layer_kinds):
+        raise NotImplementedError(
+            "qk_norm over the whole projection with grouped heads, a head "
+            "size of its own or mixed layers: no test covers it "
+            "(qk_norm_scope: head is the per-head norm)")
+    sigmoid = cfg.expert_mode and cfg.gate == "sigmoid_topk"
+    if sigmoid and not 1 <= cfg.top_k <= cfg.num_experts:
+        raise ValueError(f"top_k {cfg.top_k} of {cfg.num_experts} experts")
+    if (cfg.use_expert_bias or cfg.expert_bias_init_std) and not sigmoid:
+        raise ValueError("use_expert_bias (expert_bias_init_std) without "
+                         "gate: sigmoid_topk, the gate that reads the bias")
+    if sigmoid and not cfg.layer_types:
+        raise NotImplementedError(
+            "gate: sigmoid_topk without layer_types: the stack of "
+            "models/gpt/mixed_stack.py is the one that runs it")
+    if not 0 <= cfg.num_dense_layers <= cfg.num_layers:
+        raise ValueError(f"num_dense_layers {cfg.num_dense_layers} of "
+                         f"num_layers {cfg.num_layers}")
+    if cfg.conv_L_cache < 2:
+        raise ValueError(f"conv_L_cache {cfg.conv_L_cache}: a short "
+                         "convolution has at least 2 taps")
+    if not cfg.layer_types:
+        if cfg.num_dense_layers or cfg.dense_ffn_hidden_size:
+            raise ValueError("num_dense_layers / dense_ffn_hidden_size "
+                             "without layer_types")
+        return
+    if bool(cfg.num_dense_layers) != bool(cfg.dense_ffn_hidden_size):
+        raise ValueError("num_dense_layers and dense_ffn_hidden_size (the "
+                         "dense layers' width) come together")
+    if cfg.num_dense_layers < cfg.num_layers and not (
+            cfg.expert_mode and cfg.gate in ("softmax_topk", "sigmoid_topk")):
+        raise ValueError(
+            "layer_types: the layers after num_dense_layers are dropless "
+            "experts (gate: softmax_topk | sigmoid_topk, num_experts > 1)")
+    if cfg.mlp_act != "swiglu" or cfg.use_bias or cfg.norm != "rmsnorm":
+        raise NotImplementedError(
+            "layer_types with mlp_act other than swiglu, with biases or "
+            "without rmsnorm: no test covers it")
+    for field, why in (("sliding_window", "window layers"),
+                       ("rope_layout", "position-free layers"),
+                       ("use_recompute", "training this stack (ROADMAP R5)")):
+        if getattr(cfg, field):
+            raise NotImplementedError(f"{field} with layer_types: {why} in "
+                                      "a stack of mixed operators")
+    if cfg.router_input != "mlp_norm":
+        raise NotImplementedError("router_input with layer_types")
+
+
+def stack_of(model):
+    """What ``GPTModel`` runs its layers with: its own ``_decoder_stack``
+    (one scanned body over layers that hold the same parameters) or, for a
+    configuration with ``layer_types``, ``mixed_stack.MixedStack``."""
+    if not model.cfg.layer_types:
+        return model._decoder_stack
+    from fleetx_tpu.models.gpt.mixed_stack import MixedStack
+
+    return MixedStack(model.cfg, name="layers")
 
 
 def layer_class(cfg, default):

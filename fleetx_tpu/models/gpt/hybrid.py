@@ -56,7 +56,7 @@ from fleetx_tpu.models.gpt.model import (
 )
 
 __all__ = ["HybridDecoderLayer", "HybridSelfAttention", "grouped_attention",
-           "init_cache", "layer_bases", "total_pages"]
+           "init_cache", "layer_bases", "total_pages", "write_rows"]
 
 _NEG = -1e30  # a masked score: finite, so a row of padding stays finite
 
@@ -69,6 +69,9 @@ def _pages_of(cfg: GPTConfig):
             "a paged decode cache over window layers needs "
             "decode_window_pages beside decode_num_pages (the serving "
             "engine sets both)")
+    if cfg.layer_types:  # only the attention layers hold keys and values,
+        # and are counted among themselves (models/gpt/mixed_stack.py)
+        return [full] * cfg.layer_types.count("full_attention")
     return [window if w else full for w in cfg.window_layers]
 
 
@@ -101,6 +104,26 @@ def init_cache(model, batch: int):
     return jax.tree_util.tree_map_with_path(one, shapes)
 
 
+def write_rows(cfg: GPTConfig, k_pool, v_pool, tables, wpos, k, v, keep=None):
+    """The pools with this call's keys and values ``[b, s, width]`` written
+    at positions ``wpos + [0, s)`` through ``tables`` (a layer's own:
+    its base added). ``keep`` (a traced bool) False: nothing is written (the
+    rows' page lies past the pool)."""
+    ps = cfg.decode_page_size
+    max_len = cfg.decode_cache_len or cfg.max_position_embeddings
+    b, s, width = k.shape
+    with jax.named_scope("cache_write"):
+        pos = wpos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+        pos = jnp.minimum(pos, max_len - 1)
+        page = jnp.take_along_axis(tables, pos // ps, axis=1).reshape(-1)
+        if keep is not None:
+            page = jnp.where(keep, page, k_pool.shape[0])
+        off = (pos % ps).reshape(-1)
+        mode = None if keep is None else "drop"
+        return (k_pool.at[page, off].set(k.reshape(b * s, width), mode=mode),
+                v_pool.at[page, off].set(v.reshape(b * s, width), mode=mode))
+
+
 def grouped_attention(q, k, v, allowed):
     """Dense attention of grouped heads: ``q`` ``[b, s, heads, d]``, ``k``
     and ``v`` ``[b, t, kv_heads * d]`` (lane-dense, as the cache holds
@@ -129,9 +152,19 @@ class HybridSelfAttention(SelfAttention):
     @nn.compact
     def __call__(self, x, attn_mask=None, *, deterministic=True, decode=False,
                  cache_positions=None, block_tables=None, layer_index=None,
-                 rope=None):
+                 rope=None, phase=None):
+        """``phase`` splits a cached forward in two for a caller that writes
+        the cache itself between them (``models/gpt/mixed_stack.py``):
+        "project" returns ``(q, k, v)`` as the cache takes them, "attend"
+        takes that ``q`` as ``x`` and attends through the cache as it
+        stands."""
         cfg = self.cfg
         nh, kvh, hd = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
+        if phase == "attend":
+            windowed = jnp.asarray(cfg.window_layers, bool)[layer_index]
+            return self._out_proj(checkpoint_name(self._paged_attention(
+                x, None, None, cache_positions, block_tables, layer_index,
+                windowed, deterministic), "core_attn_out"))
         if layer_index is None:
             raise NotImplementedError(
                 "layers with a kind run under the layer scan, which hands "
@@ -153,6 +186,11 @@ class HybridSelfAttention(SelfAttention):
             k = proj((kvh, hd), name="k_proj")(x)
             v = proj((kvh, hd), name="v_proj")(x)
             q, k, v = (checkpoint_name(t, "qkv_out") for t in (q, k, v))
+        if cfg.qk_norm:  # each head's own values, one weight [head_dim]
+            # for q and one for k (``check`` lets no other scope in here)
+            q, k = (nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype,
+                               param_dtype=jnp.float32, name=name)(t)
+                    for name, t in (("q_norm", q), ("k_norm", k)))
         windowed = jnp.asarray(cfg.window_layers, bool)[layer_index]
         if rope is not None and any(cfg.rope_layers):
             rotates = jnp.asarray(cfg.rope_layers, bool)[layer_index]
@@ -160,6 +198,8 @@ class HybridSelfAttention(SelfAttention):
             k = jnp.where(rotates, apply_rope(k, rope), k)
         b, s = q.shape[:2]
         k, v = k.reshape(b, s, kvh * hd), v.reshape(b, s, kvh * hd)
+        if phase == "project":
+            return q, k, v
 
         out = None
         if decode:
@@ -221,13 +261,13 @@ class HybridSelfAttention(SelfAttention):
 
         cfg = self.cfg
         ps, window = cfg.decode_page_size, cfg.sliding_window
-        b, s, width = k.shape
+        b, s, width = q.shape[0], q.shape[1], cfg.kv_heads * cfg.head_dim
         is_init = not self.has_variable("cache", "cached_key")
         # one page at the init: ``init_cache`` makes the leaves the flat pool
         ck = self.variable("cache", "cached_key", jnp.zeros, (1, ps, width),
-                           k.dtype)
+                           q.dtype)
         cv = self.variable("cache", "cached_value", jnp.zeros,
-                           (1, ps, width), v.dtype)
+                           (1, ps, width), q.dtype)
         self.variable("cache", "cache_index", lambda: jnp.array(0, jnp.int32))
         if is_init:
             return None
@@ -235,19 +275,14 @@ class HybridSelfAttention(SelfAttention):
             raise ValueError(
                 "a paged decode cache needs cache_positions AND "
                 "block_tables (the serving engine threads both)")
-        max_len = cfg.decode_cache_len or cfg.max_position_embeddings
         wpos = cache_positions.astype(jnp.int32)               # [b]
         tables = block_tables.astype(jnp.int32)
         if tables.ndim == 3:     # [class, lanes, pages]: 0 full, 1 window
             tables = jnp.where(windowed, tables[1], tables[0])
         tables = tables + jnp.asarray(layer_bases(cfg))[layer_index]
-        with jax.named_scope("cache_write"):
-            pos = wpos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-            pos = jnp.minimum(pos, max_len - 1)
-            page = jnp.take_along_axis(tables, pos // ps, axis=1).reshape(-1)
-            off = (pos % ps).reshape(-1)
-            ck.value = ck.value.at[page, off].set(k.reshape(b * s, width))
-            cv.value = cv.value.at[page, off].set(v.reshape(b * s, width))
+        if k is not None:  # (phase "attend": the caller has written them)
+            ck.value, cv.value = write_rows(cfg, ck.value, cv.value, tables,
+                                            wpos, k, v)
         k_pool, v_pool = ck.value, cv.value
         n = tables.shape[1]
 

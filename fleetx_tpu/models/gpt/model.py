@@ -942,7 +942,7 @@ class GPTModel(nn.Module):
         x = _constrain_act(x, cfg)
         x = _dropout(cfg, "embed_dropout")(x, deterministic=deterministic)
 
-        x = self._decoder_stack(x, attn_mask, deterministic=deterministic,
+        x = block_fields.stack_of(self)(x, attn_mask, deterministic=deterministic,
                                 decode=decode, cache_positions=cache_positions,
                                 block_tables=block_tables, rope=rope)
         x = _layer_norm(cfg, "final_norm")(x)

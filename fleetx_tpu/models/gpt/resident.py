@@ -29,7 +29,7 @@ __all__ = ["resident_params"]
 # other logits there (PERF.md, PR 28).
 _WHOLE_CAST = frozenset({
     "qkv_proj", "q_proj", "k_proj", "v_proj", "out_proj",   # modules
-    "up_proj", "gate_proj", "down_proj",
+    "up_proj", "gate_proj", "down_proj", "in_proj",
     "w_gate", "w_up", "w_down", "b_up", "b_down"})          # expert leaves
 
 

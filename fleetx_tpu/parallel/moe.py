@@ -319,10 +319,12 @@ def expert_row_layout(topk_idx: jax.Array, num_experts: int, tm: int):
 
 
 class DroplessMoEMLP(nn.Module):
-    """Softmax top-k experts without capacity (module docstring):
+    """Top-k experts without capacity (module docstring):
     ``y = sum over the top_k chosen e of p_e * down_e(silu(gate_e(x)) *
     up_e(x))``, ``p = softmax(router(x))`` over all experts in float32, the
-    weights left as they are unless ``cfg.norm_topk_prob``. ``mlp_act:
+    weights left as they are unless ``cfg.norm_topk_prob`` (gate
+    ``softmax_topk``), or sigmoid scores chosen under a bias
+    (``sigmoid_topk``: :meth:`_sigmoid_topk`). ``mlp_act:
     reglu`` makes the gate ``relu``; ``router_input`` is what the router
     reads where that is not ``x`` (a router placed before attention reads
     the block's input, its experts the normed post-attention stream).
@@ -378,10 +380,15 @@ class DroplessMoEMLP(nn.Module):
         # its own (``router_input: block_input``, models/gpt/hybrid.py)
         routed = tokens if router_input is None else router_input.reshape(n, h)
         with jax.named_scope("moe_route"):
-            probs = jax.nn.softmax(router(routed.astype(jnp.float32)), axis=-1)
-            weights, topk_idx = jax.lax.top_k(probs, k)
-            if cfg.norm_topk_prob:
-                weights = weights / weights.sum(axis=-1, keepdims=True)
+            if cfg.gate == "sigmoid_topk":
+                probs, weights, topk_idx = self._sigmoid_topk(
+                    router(routed.astype(jnp.float32)))
+            else:
+                probs = jax.nn.softmax(router(routed.astype(jnp.float32)),
+                                       axis=-1)
+                weights, topk_idx = jax.lax.top_k(probs, k)
+                if cfg.norm_topk_prob:
+                    weights = weights / weights.sum(axis=-1, keepdims=True)
             dest, src, sizes, tile_expert, num_tiles = expert_row_layout(
                 topk_idx, E, tm)
             rows = tokens.astype(dt)[src]
@@ -422,6 +429,29 @@ class DroplessMoEMLP(nn.Module):
         if probed:
             self.sow("routing", "output", y)
         return y
+
+    def _sigmoid_topk(self, logits):
+        """Gate ``sigmoid_topk``: ``(scores, weights, chosen)``. The scores
+        are ``sigmoid(logits)`` in float32; the ``top_k`` largest of ``score
+        + expert_bias`` are chosen (the bias, a float32 leaf of its own,
+        steers the CHOICE alone); a chosen expert's weight is its score,
+        over the chosen scores' sum + 1e-6 under ``norm_topk_prob``, times
+        ``routed_scaling_factor``."""
+        cfg = self.cfg
+        scores = jax.nn.sigmoid(logits)
+        ranked = scores
+        if cfg.use_expert_bias:
+            std = cfg.expert_bias_init_std
+            ranked = scores + self.param(
+                "expert_bias", nn.with_logical_partitioning(
+                    nn.initializers.normal(std) if std
+                    else nn.initializers.zeros_init(), (None,)),
+                (cfg.num_experts,), jnp.float32)
+        _, chosen = jax.lax.top_k(ranked, cfg.top_k)
+        weights = jnp.take_along_axis(scores, chosen, axis=-1)
+        if cfg.norm_topk_prob:
+            weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
+        return scores, weights * cfg.routed_scaling_factor, chosen
 
     def _count(self, sizes, pairs: int, seq: int, decode: bool, layer_index):
         """Add this call to the ``moe_stats`` leaf of the decode cache (the

@@ -63,6 +63,23 @@ class allocates a lane's pages as its prefill chunks and decode ticks reach
 them and releases each page once every row of it lies behind the window of
 the lane's next query. Admission counts both classes.
 
+Two kinds of state (a model with gated short-convolution layers,
+``models/gpt/mixed_stack.py``): beside the keys and values of its attention
+layers a lane keeps, in every convolution layer, the operator's last
+inputs. They live in TAIL PAGES of the same pool under the same block
+table: physical page ``p`` has a few rows in every convolution layer, and
+the state of position ``t`` is kept in the page that holds ``t``. Nothing
+in :class:`PagePool` knows it: a full prompt page that enters the trie
+carries, in its tail, the state as it stands at the page's end, and that
+snapshot is shared, parked, revived and evicted WITH the page, because it
+is the page. A prompt that matches a prefix therefore resumes the state at
+whatever page boundary the match ends; a freed lane's zeroed table routes
+its state writes to the trash page's tail, which nobody reads. The host
+tiers and the page ship between replicas do not carry tails and are
+refused for such a model at the engine's construction;
+:meth:`PagedKVCacheManager.class_counters` counts the snapshots and the
+bytes of either kind.
+
 Two-level page cache (``FLEETX_SERVING_HOST_CACHE_BYTES``;
 docs/SERVING.md): with a :class:`HostPageStore` attached, LRU eviction
 of a zero-ref warm trie subtree SPILLS each page's content (K/V and, at
@@ -707,6 +724,11 @@ class PagePool:
                            and revive_fn else None)
         self._spill_fn = spill_fn
         self._revive_fn = revive_fn
+        # the trie's traffic, counted in pages: entered, matched by an
+        # alloc, and evicted (with recurrent state in the pages' tails these
+        # are the snapshots written, resumed from and lost)
+        self.registered = self.matched = self.evicted = 0
+        self.matched_allocs = 0
 
     # ------------------------------------------------------------- stats
 
@@ -796,6 +818,7 @@ class PagePool:
             for (payload, nbytes), key in zip(
                     self._spill_fn([n.page for n in victims]), keys):
                 self.host_store.put(key, payload, nbytes)
+        self.evicted += len(victims)
         for n in victims:
             self._cached.pop(n.page, None)
             del self._node_of_page[n.page]
@@ -922,6 +945,8 @@ class PagePool:
             self._revive_fn(revive)
         self.alloc_counts[lane] = need_total
         self.shared_counts[lane] = len(path) + len(host_keys)
+        self.matched += len(path)
+        self.matched_allocs += bool(path)
         self.version += 1
         return (len(path) + len(host_keys)) * self.page_size
 
@@ -941,6 +966,7 @@ class PagePool:
                 nxt = _TrieNode(c, int(row[i]), node)
                 node.children[c] = nxt
                 self._node_of_page[nxt.page] = nxt
+                self.registered += 1
             node = nxt
 
     def ensure_page(self, lane: int, pos: int) -> bool:
@@ -1284,6 +1310,17 @@ class PagedKVCacheManager(_LaneBook):
                 window_pages, page_size, slots, cache_len // page_size,
                 cfg.sliding_window, window_span)
         self.cache = init_decode_cache(model, slots)
+        # bytes one page holds of each kind of state (module docstring,
+        # "Two kinds of state"): keys and values in the attention layers,
+        # and the tail rows of a model's convolution layers
+        self.state_kinds = tuple(getattr(cfg, "state_kinds", ("kv",)))
+        self.page_bytes = {"kv": 0, "conv": 0}
+        for path, leaf in jax.tree_util.tree_flatten_with_path(self.cache)[0]:
+            name = getattr(path[-1], "key", "")
+            kind = {"cached_key": "kv", "cached_value": "kv",
+                    "conv_state": "conv"}.get(name)
+            if kind and "conv" in self.state_kinds:
+                self.page_bytes[kind] += leaf_device_nbytes(leaf) // num_pages
 
     # ------------------------------------------------------ host spill tier
 
@@ -1414,6 +1451,19 @@ class PagedKVCacheManager(_LaneBook):
         """The pool by class of page, for ``ServingMetrics.snapshot()``
         (through ``model_protocol.device_counters_of``); empty with one
         class."""
+        if "conv" in self.state_kinds:
+            pool, tail = self.pool, self.page_bytes["conv"]
+            return {
+                "state_snapshots_written": pool.registered,
+                "state_snapshots_resumed": pool.matched_allocs,
+                "state_snapshot_pages_matched": pool.matched,
+                "state_snapshots_evicted": pool.evicted,
+                # a lane's running state is the tail of its last page; a
+                # snapshot the tail of a page the trie holds
+                "state_bytes_lanes": self.active_count * tail,
+                "state_bytes_snapshots": len(pool._node_of_page) * tail,
+                "kv_page_bytes_in_use": (pool.pages_in_use
+                                         * self.page_bytes["kv"])}
         if self.window_pool is None:
             return {}
         return {"pages_in_use_full": self.pool.pages_in_use,

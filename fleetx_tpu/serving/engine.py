@@ -526,7 +526,11 @@ class ServingEngine:
         self.capabilities.require(
             supports_int8_kv=self.kv_dtype == "int8",
             supports_int8_weights=self.weight_dtype == "int8",
-            supports_mesh=mesh is not None)
+            supports_mesh=mesh is not None,
+            supports_roles=self.role != "both")
+        # a model with recurrent state beside keys and values tells its
+        # cached forward which rows of a call are tokens (``_row_mask``)
+        self._state_rows = self.capabilities.state_kinds != ("kv",)
         decode_kv = "int8" if self.kv_dtype == "int8" else None
         # default pool = every lane's full capacity in pages + the
         # reserved trash page; short requests then leave pages free for
@@ -616,6 +620,8 @@ class ServingEngine:
                     else os.environ.get("FLEETX_SERVING_DISK_CACHE_DIR", ""))
         disk_bytes = (disk_cache_bytes if disk_cache_bytes is not None
                       else _env_int("FLEETX_SERVING_DISK_CACHE_BYTES", 0))
+        self.capabilities.require(supports_host_spill=bool(
+            host_bytes > 0 or (disk_dir and disk_bytes > 0)))
         tiered = self.prefix_cache
         dram = HostPageStore(host_bytes) if host_bytes > 0 and tiered else None
         self._disk_store = (DiskPageStore(disk_dir, disk_bytes)
@@ -1781,11 +1787,18 @@ class ServingEngine:
         # a pool of two classes of page reports both ("pages_in_use" and
         # "usable_pages" above stay the full class's)
         classes = self.cache_manager.class_counters()
-        if classes:
+        if len(self.capabilities.page_classes) > 1:
             out["page_classes"] = {
                 kind: {"pages_in_use": classes[f"pages_in_use_{kind}"],
                        "usable_pages": classes[f"usable_pages_{kind}"]}
                 for kind in self.capabilities.page_classes}
+        # a pool of two kinds of state reports the bytes held of each
+        # (``capabilities.state_kinds`` names them)
+        if self._state_rows:
+            out["state_bytes"] = {
+                "kv": classes["kv_page_bytes_in_use"],
+                "conv": (classes["state_bytes_lanes"]
+                         + classes["state_bytes_snapshots"])}
         return out
 
     def declare_dead(self) -> None:
@@ -2014,6 +2027,14 @@ class ServingEngine:
                 self.params, self.cache_manager.cache, self._state,
                 self._device_tables(), all_greedy).compile()
 
+    def _row_mask(self, tokens):
+        """``tokens`` ``[batch, rows]``, which rows of a cached forward are
+        tokens, for a model whose recurrent state must not take a padded
+        row or an idle lane in (models/gpt/mixed_stack.py); None for a
+        model that keeps keys and values alone, whose writes of such rows
+        are never read."""
+        return tokens if self._state_rows else None
+
     def _first_token(self, logits, true_len, eos, min_new, greedy,
                      temperature, top_k, top_p, key):
         """Traced tail of every prefill body: sample the first token from
@@ -2060,6 +2081,7 @@ class ServingEngine:
                               max_pos - 1)[None, :]
             logits, cache = self.executor.forward(
                 params, cache, ids, pos,
+                self._row_mask((jnp.arange(bucket_len) < true_len)[None]),
                 cache_positions=wpos[None],
                 # [pages] -> [1, pages]; two classes [2, pages] -> [2, 1, .]
                 block_tables=jnp.expand_dims(table, -2))
@@ -2240,8 +2262,12 @@ class ServingEngine:
             return
         self._fault_ctx = ("prefill", req.id)
         with span("serving.admit", request=req.id,
-                  prompt_len=req.prompt_len):
+                  prompt_len=req.prompt_len) as at:
             shared = self._claim_storage(req)
+            # the tokens the trie matched, and whether the recurrent state
+            # was resumed from the matched pages' tails
+            at["matched"] = int(shared)
+            at["state_resumed"] = bool(shared and self._state_rows)
             if (self.prefill_chunk
                     and req.prompt_len - shared > self.prefill_chunk):
                 req.prefill_pos = shared
@@ -2421,7 +2447,8 @@ class ServingEngine:
             posid = jnp.where(active, jnp.minimum(lengths, max_pos - 1), 0)
         logits, cache = self.executor.forward(
             params, cache, st["last_tok"][:, None],
-            posid[:, None], None, cache_positions=wpos,
+            posid[:, None], self._row_mask(active[:, None]),
+            cache_positions=wpos,
             block_tables=tables)
         with jax.named_scope("sampler"):
             step = logits[:, -1, :].astype(jnp.float32)
@@ -2476,10 +2503,13 @@ class ServingEngine:
         """Span fields of a tick over two classes of page: the live cache
         rows its kernel calls read for the active lanes, in ONE full layer
         (every row up to the token being written) and in ONE window layer
-        (the window's rows of those). Empty with one class."""
+        (the window's rows of those); over two kinds of state, the rows of
+        one attention layer. Empty with one class and one kind."""
+        rows = self.cache_manager.lengths[list(self._active)] + 1
+        if self._state_rows:  # what ONE of its attention layers reads
+            return {"attn_rows": int(rows.sum())}
         if not self.window_pages:
             return {}
-        rows = self.cache_manager.lengths[list(self._active)] + 1
         return {"full_rows": int(rows.sum()), "window_rows": int(
             np.minimum(rows, self.model.cfg.sliding_window).sum())}
 

@@ -99,6 +99,14 @@ class ModelCapabilities:
     page_classes: tuple = ("full",)
     supports_prefix_cache: bool = True
     supports_roles: bool = True
+    #: the kinds of state a lane keeps in the pool: "kv" (keys and values
+    #: of attention layers) and, for a model with gated short-convolution
+    #: layers, "conv" (the operator's last inputs, in tail pages under the
+    #: same block table: models/gpt/mixed_stack.py). A page's tail rows
+    #: live and die with the page, so a prefix hit resumes them; they are
+    #: neither spilled to the host tiers nor shipped between replicas
+    state_kinds: tuple = ("kv",)
+    supports_host_spill: bool = True
 
     def as_dict(self) -> dict:
         """JSON-ready form for the ``/healthz`` report (a tuple reads as
@@ -129,7 +137,10 @@ _FEATURES = {
                              "release a prefix's pages once the window has "
                              "passed them",
     "supports_roles": "a prefill or decode role: the pages of its window "
-                      "class are not shipped between replicas",
+                      "class, or the tail pages of its convolution state, "
+                      "are not shipped between replicas",
+    "supports_host_spill": "a host or disk page tier: the tail pages of its "
+                           "convolution state are not spilled",
 }
 
 
@@ -226,6 +237,8 @@ class GPTExecutor(ModelExecutor):
         kinds = bool(getattr(model.cfg, "layer_kinds", False))
         windowed = kinds and any(model.cfg.window_layers)
         dense = dense and not kinds
+        state = tuple(getattr(model.cfg, "state_kinds", ("kv",)))
+        recurrent = state != ("kv",)
         self.capabilities = ModelCapabilities(
             family=family or getattr(model.cfg, "family", "gpt"),
             has_kv_cache=True,
@@ -237,7 +250,9 @@ class GPTExecutor(ModelExecutor):
             supports_mesh=dense,
             page_classes=("full", "window") if windowed else ("full",),
             supports_prefix_cache=not windowed,
-            supports_roles=not windowed,
+            supports_roles=not windowed and not recurrent,
+            state_kinds=state,
+            supports_host_spill=not recurrent,
         )
 
     def bind(self, model):

@@ -60,6 +60,12 @@ _CANNOT_APPLY = {
         "perfbench/flops.py gpt_param_count counts the GPT-2 block only "
         "(a benchmark PR's to extend); tests/test_smallthinker_serving.py "
         "holds this configuration's count to the program's own model",
+    "tests/perfbench/test_perfbench_flops.py::"
+    "test_param_count_matches_the_programs_model"
+    "[perfbench/configs/lfm2-8b-a1b-l14.json]":
+        "perfbench/flops.py gpt_param_count counts the GPT-2 block only "
+        "(a benchmark PR's to extend); tests/test_lfm2_serving.py "
+        "holds this configuration's count to the program's own model",
 }
 
 

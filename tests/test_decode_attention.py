@@ -537,3 +537,58 @@ def test_untileable_cache_falls_back_dense(monkeypatch, model_and_params):
     out = generate(model, params, prompt, cfg)
     assert calls["n"] == 0  # 13 is not a multiple of 8: dense fallback
     assert out.shape == (1, 8)
+
+
+@pytest.mark.parametrize("path", ["contiguous", "paged_page", "paged_block"])
+def test_group_4_at_head_size_64_matches_the_dense_path(path):
+    """LFM2's heads (32 query heads over 8 key heads of 64: 512 lanes a row,
+    group 4) through the three kernel paths against the dense path."""
+    rng = np.random.RandomState(5)
+    b, heads, kv_heads, d, ps, n_row = 3, 32, 8, 64, 16, 6
+    q = jnp.asarray(rng.randn(b, 1, heads, d), jnp.float32)
+    k = jnp.asarray(rng.randn(b, n_row * ps, kv_heads, d), jnp.float32)
+    v = jnp.asarray(rng.randn(b, n_row * ps, kv_heads, d), jnp.float32)
+    ends = jnp.asarray([7, 61, 96], jnp.int32)
+    want = _dense_grouped(q, k, v, ends, jnp.zeros_like(ends))
+    if path == "contiguous":
+        got = flash_decode_attention(q, _fold(k), _fold(v), end=ends,
+                                     block_k=16, block_major=32)
+    else:
+        order = 1 + rng.permutation(b * n_row).reshape(b, n_row)
+        pool_k = np.zeros((b * n_row + 1, ps, kv_heads * d), np.float32)
+        pool_v = np.zeros_like(pool_k)
+        for lane in range(b):
+            pool_k[order[lane]] = np.asarray(_fold(k))[lane].reshape(
+                n_row, ps, -1)
+            pool_v[order[lane]] = np.asarray(_fold(v))[lane].reshape(
+                n_row, ps, -1)
+        got = flash_decode_paged_attention(
+            q, jnp.asarray(pool_k), jnp.asarray(pool_v),
+            tables=jnp.asarray(order, jnp.int32), end=ends,
+            block_k=ps if path == "paged_page" else 4 * ps)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=_TOL, atol=_TOL)
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_grouped_kernels_lower_for_tpu_at_the_lfm2_cell(monkeypatch, paged):
+    """``lfm2-l14-serve-agent-prefix``'s decode call (48 lanes, 32 query
+    heads over 8 key heads of 64, page 16, 304 pages a row, the bf16 pool of
+    its three attention layers) passes the TPU lowering as the block kernel,
+    16 pages a step; the contiguous kernel at the same heads too."""
+    lanes, h, kv, d, ps, n_row = 48, 32, 8, 64, 16, 304
+    q = jnp.zeros((lanes, 1, h, d), jnp.bfloat16)
+    ends = jnp.full((lanes,), 4000, jnp.int32)
+    if paged:
+        pool = jnp.zeros((3 * (lanes * n_row + 1), ps, kv * d), jnp.bfloat16)
+        tables = jnp.zeros((lanes, n_row), jnp.int32)
+        fn = lambda q, kv_, t, e: flash_decode_paged_attention(
+            q, kv_, kv_, tables=t, end=e)
+        traced, text = _lower_for_tpu(monkeypatch, fn, q, pool, tables, ends)
+        (call,) = _pallas_calls(traced.jaxpr.jaxpr)
+        assert call.params["grid_mapping"].grid == (lanes, n_row // 16)
+        assert f'kernel_name = "{PAGED_KERNEL_NAME}"' in text
+    else:
+        cache = jnp.zeros((lanes, 1024, kv * d), jnp.bfloat16)
+        fn = lambda q, c, e: flash_decode_attention(q, c, c, end=e)
+        _lower_for_tpu(monkeypatch, fn, q, cache, ends // 8)
