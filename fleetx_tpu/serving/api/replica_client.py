@@ -140,13 +140,18 @@ class ReplicaClient:
         except ConnectionError as e:
             raise ReplicaKilled(
                 f"replica {self.url} unreachable at step ({e})") from e
-        for erid, tok, finished in out.get("events", ()):
+        self._replay(out.get("events", ()))
+        return out.get("summary", {})
+
+    def _replay(self, events) -> None:
+        """Hand buffered ``on_token`` events to their callbacks, in
+        emission order."""
+        for erid, tok, finished in events:
             cb = self._cbs.get(erid)
             if cb is not None:
                 cb(erid, tok, bool(finished))
                 if finished:
                     self._cbs.pop(erid, None)
-        return out.get("summary", {})
 
     def health(self) -> Dict:
         """The replica's ``/healthz`` body (its engine's ``health()``
@@ -197,14 +202,18 @@ class ReplicaClient:
 
     def cancel(self, request_id: int) -> bool:
         """Cancel remotely; an unreachable replica returns ``False``
-        (nothing left to cancel). Drops the local callback first so no
-        late events replay for a request the router abandoned."""
-        self._cbs.pop(int(request_id), None)
+        (nothing left to cancel). The replica reads its tick in flight
+        before the cancel acts, so the response carries the tokens that
+        emitted (the cancelled request's last one among them); after
+        those the local callback is dropped, so no late event replays
+        for a request the router abandoned."""
         try:
-            return bool(self._rpc("cancel",
-                                  {"id": int(request_id)})["cancelled"])
+            out = self._rpc("cancel", {"id": int(request_id)})
         except ConnectionError:
-            return False
+            out = {"cancelled": False}
+        self._replay(out.get("events", ()))
+        self._cbs.pop(int(request_id), None)
+        return bool(out["cancelled"])
 
     def request_shutdown(self, grace_s: Optional[float] = None) -> None:
         """Flip the remote engine to draining (SIGTERM semantics). An

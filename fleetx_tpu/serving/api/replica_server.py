@@ -108,8 +108,10 @@ class ReplicaServer(HttpDaemon):
                          thread_name="fleetx-replica-rpc")
         self.engine = engine
         self._lock = threading.Lock()
-        # on_token events buffered between /rpc/step responses, in
-        # emission order: [(engine_rid, token, finished), ...]
+        # on_token events buffered until the next /rpc/step or
+        # /rpc/cancel response (an engine reads its tick in flight before
+        # a cancel acts, so tokens are emitted there too), in emission
+        # order: [(engine_rid, token, finished), ...]
         self._events: List[Tuple[int, int, bool]] = []
         self.rpc_methods = {
             "/rpc/submit": self._rpc_submit,
@@ -168,7 +170,6 @@ class ReplicaServer(HttpDaemon):
         """One tick; the response carries the tick's summary and every
         ``on_token`` event it emitted, in order."""
         with self._lock:
-            self._events = []
             summary = self.engine.step()
             events, self._events = self._events, []
         return {"summary": _json_summary(summary), "events": events}
@@ -180,7 +181,9 @@ class ReplicaServer(HttpDaemon):
 
     def _rpc_cancel(self, p: Dict) -> Dict:
         with self._lock:
-            return {"cancelled": bool(self.engine.cancel(int(p["id"])))}
+            cancelled = bool(self.engine.cancel(int(p["id"])))
+            events, self._events = self._events, []
+        return {"cancelled": cancelled, "events": events}
 
     def _rpc_emitted_tokens(self, p: Dict) -> Dict:
         with self._lock:

@@ -8,10 +8,65 @@ decode functions exposed by ``models/gpt/generation.py``:
 - **step()** is one scheduler tick: admit queued requests into free slots
   (prefill-on-insert — each prompt is prefilled batch-1 into its slot's
   storage, its first token sampled in the same jitted call), then ONE
-  jitted decode step over ALL slots, then per-slot EOS / max-length
-  retirement that frees slots for the next tick's admissions.
+  jitted decode step over ALL slots is DISPATCHED, then the decode step
+  before it is read: its tokens emitted, per-slot EOS / max-length
+  retirement freeing slots for the next tick's admissions ("Tick order"
+  below).
 - **drain()** ticks until queue and slots are empty and returns the
   finished :class:`ServingResult` records.
+
+Tick order (one decode tick in flight; ``serving/inflight.py`` holds the
+record): everything a tick needs lives on the device (``active``,
+``lengths``, ``last_tok``, ``decoded``, ``rng``; a lane that samples EOS
+or reaches its budget clears its own ``active`` bit inside the program),
+so tick n+1 needs nothing that tick n's tokens decide. ``_tick_decode``
+therefore dispatches tick n and only THEN reads tick n-1
+(``serving.fetch``, ``serving.emit``): the result's way back, the emit
+loop, ``serving.observe`` and the caller's work between two ``step()``
+calls all run while tick n is on the device. What follows from it:
+
+- the host's position (``cache_manager.lengths``) is the position AS
+  DISPATCHED: it advances by one for every lane a tick is dispatched for,
+  and page growth, window-page recycling, the ``serving.decode`` span's
+  row counts and the block tables are reckoned from it. A request's token
+  list is what was DELIVERED, at most one token behind.
+- a token goes to the request that still holds the lane it was dispatched
+  for: not to one cancelled, expired or retired by its own callback in
+  between, never to the lane's next tenant.
+- a lane frees one tick late, and that is all that is late: a request
+  that finished in tick n-1 is known finished after tick n's dispatch
+  (tick n carries the lane inactive), and an admission takes the lane in
+  the next ``step()``. The host counts ``max_new_tokens`` and cache-end
+  finishes itself (only EOS needs the token), so it grows no page for,
+  and dispatches no tick made only of, lanes whose last token is already
+  in flight.
+- ONE path that adapts, by what the engine already is: a speculative
+  engine (the proposer reads the tokens on the host) and an engine with
+  the watchdog armed (``tick_timeout_s`` > 0 blocks on the program by
+  design) read every tick right after its dispatch, through the same
+  function called earlier. Whatever must act on exact state reads the
+  tick in flight first: ``cancel``, a deadline eviction (the partial
+  result keeps the token), a dry pool before ``cache_full`` is decided,
+  ``export_kv``, ``emitted_tokens``, a ``metrics.snapshot()`` that reads
+  the device's counters, the end of the grace window, ``recover`` called
+  from outside, and a ``step()`` that finds no lane left to dispatch for.
+  With no tick in flight the host is where it always was between two
+  steps: ``lengths[slot] == prompt_len + len(tokens) - 1`` for every
+  active lane.
+  ``metrics.snapshot()`` counts both ways: ``decode_ticks_overlapped``
+  (dispatched while the tick before was unread) and
+  ``decode_ticks_flushed`` (read with nothing behind it, by cause:
+  ``_spec``, ``_watchdog``, ``_evict``, ``_pool_dry``, ``_idle``,
+  ``_other``); ``serving.decode`` carries ``inflight=0|1``.
+- a device error of tick n surfaces at tick n+1's dispatch or at the
+  read, one ``step()`` later, inside the same transaction: the rollback
+  drops the unread tick, whose tokens never reached host truth, so replay
+  recovery computes them again and nothing is emitted twice. The
+  snapshot re-commits after every delivery, so tokens that were emitted
+  stay emitted whatever fails later in the step.
+- an admission still waits for its prefill's first token before the lane
+  install (``_finish_first_token``); that wait also covers the tick in
+  flight before it.
 
 Cache storage is PAGED, the engine's one layout: K/V live in a shared
 ``[num_pages, page_size, heads*head_dim]`` pool, each request holds a
@@ -180,8 +235,10 @@ Crash safety (docs/RESILIENCE.md serving-recovery):
 
 - **Transactional ticks** — ``step()`` snapshots the pure-host
   bookkeeping (scheduler queue, request table, active map, results)
-  before any device work and rolls it back on ANY exception, so a failed
-  tick never loses or duplicates a token, a request, or a queue position.
+  before any device work (again after each admission and each delivery
+  of a tick's tokens) and rolls it back on ANY exception, dropping the
+  tick in flight, so a failed tick never loses or duplicates a token, a
+  request, or a queue position.
 - **Replay recovery** — device caches are pure functions of each
   request's ``prompt + emitted tokens``, so :meth:`ServingEngine.recover`
   rebuilds a fresh cache/pool/lane-table and re-prefills every active
@@ -251,6 +308,7 @@ from fleetx_tpu.serving.cache_manager import (
     window_lane_pages,
 )
 from fleetx_tpu.resilience.faults import faults
+from fleetx_tpu.serving.inflight import InflightTick, pending_of
 from fleetx_tpu.serving.metrics import ServingMetrics
 from fleetx_tpu.serving.scheduler import FIFOScheduler, Request
 from fleetx_tpu.serving.spec import build_proposer
@@ -696,6 +754,10 @@ class ServingEngine:
         # decode lane inert) until the router calls export_kv()
         self._prefilled: Dict[int, Request] = {}
         self._results: Dict[int, ServingResult] = {}
+        # the decode tick dispatched and not yet read (module docstring
+        # "Tick order"), and the lanes whose tokens this step() delivered
+        self._inflight: Optional[InflightTick] = None
+        self._delivered = 0
         self._state = self._replicate(self._init_state())
         # buffer donation halves cache HBM residency on TPU; skipped on
         # CPU/interpret runs where XLA would only warn about it
@@ -947,9 +1009,12 @@ class ServingEngine:
         snapshotted before any device work; any exception rolls it back to
         the exact pre-tick state and runs the recovery path (module
         docstring), so the caller's ticking loop just keeps ticking.
-        Returns a summary dict (``timed_out`` lists this tick's deadline
-        victims; ``recovered`` marks a rolled-back-and-recovered tick).
-        Raises only :class:`RecoveryExhausted` (the engine is dead)."""
+        Returns a summary dict (``decoded`` counts the lanes whose token
+        this step DELIVERED: with a tick in flight those of the tick
+        dispatched one step earlier; ``timed_out`` lists this tick's
+        deadline victims; ``recovered`` marks a rolled-back-and-recovered
+        tick). Raises only :class:`RecoveryExhausted` (the engine is
+        dead)."""
         t0 = self._now()
         self._flush_shutdown_event()
         if (self._shutting_down and self._shutdown_deadline is not None
@@ -957,7 +1022,8 @@ class ServingEngine:
                 and (len(self.scheduler) or self._active
                      or self._prefilling or self._prefilled)):
             # grace window over: everything still in flight returns NOW
-            # with its partial tokens
+            # with its partial tokens (the unread tick's among them)
+            self._settle("other")
             retired = self._retire_all("shutdown")
             summary = {"admitted": 0, "decoded": 0, "retired": retired,
                        "timed_out": []}
@@ -1018,9 +1084,11 @@ class ServingEngine:
     def _step_inner(self, commit=lambda: None) -> Dict:
         """The actual tick body: queued-expiry sweep, prefill work
         (admissions — or, mid-chunked-prefill, exactly one chunk), one
-        batched decode step, retirements, active-deadline sweep.
-        ``commit`` re-bases the transactional snapshot after each
-        completed phase (see :meth:`step`). With chunking enabled the
+        batched decode step dispatched and the one before it read
+        (module docstring "Tick order"), retirements, active-deadline
+        sweep. ``commit`` re-bases the transactional snapshot after each
+        completed phase (see :meth:`step`): an admission, a chunk, a
+        tick's tokens delivered. With chunking enabled the
         tick's prefill budget is ONE chunk-sized device call — a chunk
         of the in-flight prompt or one short admission — so decode never
         stalls longer (the ``prefill_stall_ms`` histogram measures it)."""
@@ -1046,25 +1114,35 @@ class ServingEngine:
                     break  # one prefill-shaped device call per tick
         if admitted or chunked:
             self.metrics.observe_prefill_stall(self._now() - prefill_t0)
-        decoded = len(self._active)
+        self._delivered = 0
         retired = []
-        if decoded:
-            retired = (self._tick_decode_spec() if self._proposer is not None
-                       else self._tick_decode())
+        if self._proposer is not None:
+            if self._active:
+                retired = self._tick_decode_spec(commit)
+        elif self._active or self._inflight is not None:
+            retired = self._tick_decode(commit)
         # fresh clock: prefill/decode above may have eaten the deadline
         with span("serving.expire"):
-            timed_out += self._expire_active(self._now())
-        return {"admitted": admitted, "decoded": decoded, "chunked": chunked,
-                "retired": retired + timed_out, "timed_out": timed_out}
+            now = self._now()
+            if self._inflight is not None and self._overdue(now):
+                # a deadline's partial result keeps the token in flight
+                retired += self._collect("evict", commit)
+            timed_out += self._expire_active(now)
+        return {"admitted": admitted, "decoded": self._delivered,
+                "chunked": chunked, "retired": retired + timed_out,
+                "timed_out": timed_out}
 
     def cancel(self, request_id: int) -> bool:
         """Cancel a queued or in-flight request: its slot (if any) is freed
         for the next admission THIS instant and its partial output is
         recorded with ``finish_reason="cancelled"``. Returns False when the
         id is unknown or already finished."""
-        now = self._now()
         req = self.scheduler.remove(request_id)
         if req is None:
+            if any(r.id == request_id for r in self._active.values()):
+                # its token of the tick in flight is part of what it
+                # leaves with (and may be its last: then it has finished)
+                self._settle("other")
             for r in (list(self._active.values())
                       + list(self._prefilling.values())
                       + list(self._prefilled.values())):
@@ -1073,7 +1151,7 @@ class ServingEngine:
                     break
         if req is None:
             return False
-        self._evict(req, "cancelled", now)
+        self._evict(req, "cancelled", self._now())
         obs_emit("request_cancelled", request=request_id)
         return True
 
@@ -1132,15 +1210,19 @@ class ServingEngine:
             out.append(req.id)
         return out
 
+    def _overdue(self, now) -> list:
+        """The in-flight requests past their total deadline."""
+        return [req for req in self._active.values()
+                if req.deadline_s and now - req.submit_time > req.deadline_s]
+
     def _expire_active(self, now):
         """Retire in-flight requests past their total deadline, freeing
         their slots; partial tokens are kept in the result."""
         out = []
-        for req in list(self._active.values()):
-            if req.deadline_s and now - req.submit_time > req.deadline_s:
-                self._evict(req, "timeout", now)
-                obs_emit("request_timeout", request=req.id, where="active")
-                out.append(req.id)
+        for req in self._overdue(now):
+            self._evict(req, "timeout", now)
+            obs_emit("request_timeout", request=req.id, where="active")
+            out.append(req.id)
         return out
 
     def _evict(self, req: Request, reason: str, now: float) -> None:
@@ -1206,6 +1288,9 @@ class ServingEngine:
         streams are untouched (nothing the failed tick produced was
         committed); the queue and every request are exactly pre-tick."""
         ctx, self._fault_ctx = self._fault_ctx, None
+        # the unread tick goes with the device state it came from: its
+        # tokens never reached host truth, so replay computes them again
+        self._inflight = None
         with span("serving.rollback", tick=self._ticks):
             self._restore(snap)
         victim = ctx[1] if ctx else None
@@ -1258,6 +1343,16 @@ class ServingEngine:
         Mid-prefill (chunked) requests requeue at the head and restart.
         Returns the ids of requests retired because their own replay
         failed (their fault followed them into recovery — poison)."""
+        if self._inflight is not None:
+            # called from outside a failed tick: the unread tick's tokens
+            # join host truth if the device still gives them, else replay
+            # computes them again (nothing of it was emitted either way)
+            try:
+                self._collect("other")
+            except Exception:  # noqa: BLE001 — the device may be gone
+                logger.exception(
+                    "serving: the tick in flight could not be read before "
+                    "recovery; replay recomputes its tokens")
         self._recoveries_consecutive += 1
         self.metrics.record_recovery()
         if self._recoveries_consecutive > self.max_recoveries:
@@ -1549,7 +1644,7 @@ class ServingEngine:
         # deferred shutdown event here too (step() flushes it otherwise)
         self._flush_shutdown_event()
         while (len(self.scheduler) or self._active or self._prefilling
-               or self._prefilled):
+               or self._prefilled or self._inflight is not None):
             self.step()  # the deadline check inside step() retires leftovers
         out, self._results = self._results, {}
         return out
@@ -1613,7 +1708,8 @@ class ServingEngine:
         """Tick until queue and slots are empty (or ``max_ticks``), then
         return-and-clear every finished result since the last drain."""
         n = 0
-        while len(self.scheduler) or self._active or self._prefilling:
+        while (len(self.scheduler) or self._active or self._prefilling
+               or self._inflight is not None):
             self.step()
             n += 1
             if max_ticks is not None and n >= max_ticks:
@@ -1692,7 +1788,9 @@ class ServingEngine:
         unknown/finished ids). The router's stream-reconciliation seam:
         after a recovered tick it re-bases its durable per-request history
         on the engine's rolled-back-and-replayed token list — the in-
-        process analogue of a streaming client re-syncing its offset."""
+        process analogue of a streaming client re-syncing its offset. Host
+        truth is exact here: the tick in flight is read first."""
+        self._settle("other")
         for r in (list(self._active.values())
                   + list(self._prefilling.values())
                   + list(self._prefilled.values())
@@ -1729,6 +1827,7 @@ class ServingEngine:
             raise KeyError(
                 f"request {request_id} is not parked for export "
                 f"(parked: {self.prefilled_ready()})")
+        self._settle("other")
         attempt = self._fault_ships
         self._fault_ships += 1
         faults.on_kv_ship(attempt, request_id)
@@ -2481,31 +2580,52 @@ class ServingEngine:
             new_st["rng"] = new_rng
         return self._pin_cache(cache), new_st, tok, done
 
-    def _grow_pages(self) -> list:
-        """Grow-on-demand BEFORE the write: any active lane whose next
+    def _live_lanes(self) -> Dict[int, Request]:
+        """The lanes the next tick decodes for, by the host's own count:
+        every active lane but those whose LAST token is already in flight
+        (the unread tick takes them to ``max_new_tokens`` or to the end of
+        the cache, and the program cleared their ``active`` bit itself).
+        A lane that sampled EOS in the unread tick is still among them:
+        only the token says so, and the device carries it inactive."""
+        tick, lengths = self._inflight, self.cache_manager.lengths
+        return {slot: req for slot, req in self._active.items()
+                if (len(req.tokens) + pending_of(tick, slot, req)
+                    < req.max_new_tokens)
+                and lengths[slot] < self.cache_len}
+
+    def _grow_pages(self, commit=lambda: None) -> list:
+        """Grow-on-demand BEFORE the write: any live lane whose next
         position crosses into an unallocated page claims one now; a dry
-        pool retires the request with its partial tokens ("cache_full")
-        — deterministic lowest-lane-first order. Returns the retired
-        ids."""
+        pool first reads the tick in flight (a lane it finished gives its
+        pages back) and only then retires the request with its partial
+        tokens ("cache_full") — deterministic lowest-lane-first order.
+        Returns the retired ids."""
         retired = []
-        now = self._now()
         with span("serving.grow"):
-            for slot in sorted(self._active):
-                req = self._active[slot]
-                if not self.cache_manager.ensure_page(slot):
-                    self._evict(req, "cache_full", now)
-                    obs_emit("cache_full", request=req.id,
-                             tokens=len(req.tokens))
-                    retired.append(req.id)
+            for slot, req in sorted(self._live_lanes().items()):
+                if self._active.get(slot) is not req:
+                    continue  # the read a dry pool forced (below) finished it
+                if self.cache_manager.ensure_page(slot):
+                    continue
+                if self._inflight is not None:
+                    retired += self._collect("pool_dry", commit)
+                    if (self._active.get(slot) is not req
+                            or self.cache_manager.ensure_page(slot)):
+                        continue
+                self._evict(req, "cache_full", self._now())
+                obs_emit("cache_full", request=req.id,
+                         tokens=len(req.tokens))
+                retired.append(req.id)
         return retired
 
-    def _decode_rows(self) -> dict:
+    def _decode_rows(self, lanes) -> dict:
         """Span fields of a tick over two classes of page: the live cache
-        rows its kernel calls read for the active lanes, in ONE full layer
-        (every row up to the token being written) and in ONE window layer
-        (the window's rows of those); over two kinds of state, the rows of
-        one attention layer. Empty with one class and one kind."""
-        rows = self.cache_manager.lengths[list(self._active)] + 1
+        rows its kernel calls read for the lanes it is dispatched for, in
+        ONE full layer (every row up to the token being written) and in
+        ONE window layer (the window's rows of those); over two kinds of
+        state, the rows of one attention layer. Empty with one class and
+        one kind."""
+        rows = self.cache_manager.lengths[list(lanes)] + 1
         if self._state_rows:  # what ONE of its attention layers reads
             return {"attn_rows": int(rows.sum())}
         if not self.window_pages:
@@ -2513,12 +2633,26 @@ class ServingEngine:
         return {"full_rows": int(rows.sum()), "window_rows": int(
             np.minimum(rows, self.model.cfg.sliding_window).sum())}
 
-    def _tick_decode(self):
-        retired = self._grow_pages()
-        if not self._active:
-            return retired
-        all_greedy = all(r.greedy for r in self._active.values())
-        active_ids = [r.id for r in self._active.values()]
+    def _sync_cause(self) -> Optional[str]:
+        """Why this engine reads every tick before it dispatches the next,
+        by what it already is (None: it keeps one in flight): a
+        speculative proposer reads the tokens on the host; an armed
+        watchdog blocks on the program by design."""
+        if self._proposer is not None:
+            return "spec"
+        return "watchdog" if self.tick_timeout_s > 0 else None
+
+    def _tick_decode(self, commit=lambda: None):
+        """Dispatch tick n, THEN read tick n-1 (module docstring "Tick
+        order"). Returns the ids retired by what was read."""
+        retired = self._grow_pages(commit)
+        lanes = self._live_lanes()
+        if not lanes:
+            # nothing to dispatch for: the lanes left have their last
+            # token in flight (drain()'s last tick), or all have gone
+            return retired + self._collect("idle", commit)
+        all_greedy = all(r.greedy for r in lanes.values())
+        active_ids = [r.id for r in lanes.values()]
         attempt = self._fault_ticks
         self._fault_ticks += 1
         # bind the device operands NOW, on the main thread: if the watchdog
@@ -2543,20 +2677,71 @@ class ServingEngine:
                 jax.block_until_ready(out)
             return out
 
+        before = self._inflight
         with span("serving.decode", batch=len(active_ids),
-                  **self._decode_rows()):
+                  inflight=int(before is not None),
+                  **self._decode_rows(lanes)):
             cache, st, tok, done = self._run_device(run)
         self.cache_manager.cache = cache
         self._state = st
-        # the host sync of the tick: the wait for the device is HERE
-        tok_np, done_np = self._fetch("serving.fetch", tok, done,
-                                      batch=len(active_ids))
+        # the host's position is the position AS DISPATCHED: the next
+        # tick's page growth, window recycling, row counts and tables are
+        # reckoned from it while this tick's tokens are still on their way
+        self.cache_manager.lengths[list(lanes)] += 1
+        self._inflight = InflightTick(tok, done, lanes)
+        if before is not None:
+            # the wait for tick n-1 is HERE, with tick n on the device
+            self.metrics.record_tick_overlapped()
+            retired += self._deliver(before, commit)
+        cause = self._sync_cause()
+        if cause:
+            retired += self._collect(cause, commit)
+        return retired
+
+    def _collect(self, cause: str, commit=lambda: None) -> list:
+        """Read the tick in flight NOW, with no tick behind it on the
+        device (``cause``: one of ``inflight.FLUSH_CAUSES``); nothing to
+        do without one. Returns the ids its tokens retired."""
+        tick, self._inflight = self._inflight, None
+        if tick is None:
+            return []
+        retired = self._deliver(tick, commit)
+        self.metrics.record_tick_flushed(cause)
+        return retired
+
+    def _settle(self, cause: str) -> None:
+        """:meth:`_collect` from OUTSIDE a step() (cancel, export_kv, the
+        end of the grace window): no transaction is open, so a read that
+        fails is handled as the failed tick it is: nothing of it was
+        emitted, the device is rebuilt from host truth."""
+        try:
+            self._collect(cause)
+        except RecoveryExhausted:
+            raise
+        except Exception as exc:  # noqa: BLE001 — the crash-safety seam
+            self._handle_tick_fault(self._snapshot(), exc)
+
+    def _deliver(self, tick: InflightTick, commit) -> list:
+        """The host sync of a tick: wait for its tokens, hand each to the
+        request that STILL holds the lane it was dispatched for, retire
+        what finished, then ``commit`` (what was emitted stays emitted,
+        whatever fails later in this step)."""
+        batch = len(tick.lanes)
+        tok_np, done_np = self._fetch("serving.fetch", tick.tok, tick.done,
+                                      batch=batch)
         now = self._now()
-        with span("serving.emit", batch=len(active_ids)):
-            for slot, req in list(self._active.items()):
+        retired = []
+        with span("serving.emit", batch=batch):
+            for slot, req in tick.lanes.items():
+                if self._active.get(slot) is not req:
+                    # it left after the dispatch (cancelled, expired,
+                    # retired by its callback, EOS one tick earlier): the
+                    # token is nobody's, least of all the lane's next
+                    # tenant's
+                    continue
                 t = int(tok_np[slot])
                 req.tokens.append(t)
-                self.cache_manager.lengths[slot] += 1
+                self._delivered += 1
                 self.metrics.record_tokens(1)
                 finished = bool(done_np[slot])
                 # firewalled callback: a raising on_token retires THIS
@@ -2575,6 +2760,7 @@ class ServingEngine:
                         reason = "cache_full"
                     self._finalize(req, reason, now)
                     retired.append(req.id)
+        commit()
         return retired
 
     # ------------------------------------------------ speculative decoding
@@ -2718,7 +2904,7 @@ class ServingEngine:
             new_st["rng"] = new_rng
         return self._pin_cache(cache), new_st, out, m, acc, done
 
-    def _tick_decode_spec(self):
+    def _tick_decode_spec(self, commit=lambda: None):
         """Speculative sibling of :meth:`_tick_decode`: clamp k, grow
         pages for the verify window, draft, verify once, commit the
         accepted run per lane. Falls back to the plain tick when no lane
@@ -2736,13 +2922,13 @@ class ServingEngine:
         k = min(self.spec_k,
                 min(self.cache_len - 1 - n for n in lens.values()))
         if k <= 0:
-            return self._tick_decode()
+            return self._tick_decode(commit)
         # phase 1: every lane's PENDING-token page first — the exact
         # allocation the plain tick makes, in the same order, so
         # cache_full retirement decisions are identical to the
         # non-speculative engine even under a near-dry pool (draft
         # windows must never starve a neighbor's pending token)
-        retired = self._grow_pages()
+        retired = self._grow_pages(commit)
         if not self._active:
             return retired
         cov = {}
@@ -2789,7 +2975,7 @@ class ServingEngine:
             # every neighbor see the plain engine's pool state.
             for slot in sorted(self._active):
                 self.cache_manager.trim_span(slot)
-            return retired + self._tick_decode()
+            return retired + self._tick_decode(commit)
         all_greedy = all(r.greedy for r in self._active.values())
         active_ids = [r.id for r in self._active.values()]
         attempt = self._fault_ticks
@@ -2830,6 +3016,7 @@ class ServingEngine:
                 req.spec_proposed += int(dlen[slot])
                 req.spec_accepted += row_acc
                 emitted_rows.append(n)
+                self._delivered += 1
                 self.cache_manager.lengths[slot] += n
                 # return rejected-draft pages to the pool THIS tick:
                 # post-trim the chain matches what the plain engine

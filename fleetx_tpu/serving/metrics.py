@@ -32,6 +32,7 @@ import weakref
 from typing import Dict, Optional
 
 from fleetx_tpu.obs.registry import MetricsRegistry, get_registry
+from fleetx_tpu.serving.inflight import FLUSH_CAUSES
 
 __all__ = ["ServingMetrics"]
 
@@ -291,6 +292,21 @@ class ServingMetrics:
             "fleetx_serving_batch_occupancy",
             "Fraction of the coalescing window filled per batched forward")
         self._reasons: Dict[str, object] = {}  # reason -> counter child
+        # the decode tick kept in flight (engine.py "Tick order"): a tick
+        # dispatched while the one before was unread, against a tick read
+        # with nothing behind it on the device, by its one cause
+        self._c_ticks_overlapped = counter(
+            "fleetx_serving_decode_ticks_overlapped_total",
+            "Decode ticks dispatched while the tick before was unread")
+        self._flushed_family = reg.counter(
+            "fleetx_serving_decode_ticks_flushed_total",
+            "Decode ticks read with no tick behind them on the device, by "
+            "cause", ("engine", "cause"))
+        self._flushed: Dict[str, object] = {}
+        for cause in FLUSH_CAUSES:
+            labels = {"engine": self.engine_label, "cause": cause}
+            owned.append((self._flushed_family, labels))
+            self._flushed[cause] = self._flushed_family.labels(**labels)
         self._first_token_t: Optional[float] = None
         self._last_token_t: Optional[float] = None
         weakref.finalize(self, _drop_series, owned)
@@ -464,6 +480,15 @@ class ServingMetrics:
             int(self._c_spec_accepted.value) / total if total else 0.0)
         for n in emitted_rows:
             self._h_spec_tokens.observe(int(n))
+
+    def record_tick_overlapped(self) -> None:
+        """A decode tick was dispatched while the one before was unread."""
+        self._c_ticks_overlapped.inc()
+
+    def record_tick_flushed(self, cause: str) -> None:
+        """A decode tick was read with no tick behind it on the device
+        (``cause``: one of ``inflight.FLUSH_CAUSES``)."""
+        self._flushed[cause].inc()
 
     def observe_pages(self, pages_in_use: int, pages_total: int) -> None:
         """Per-tick page-pool gauge sample (paged mode only)."""
@@ -694,6 +719,10 @@ class ServingMetrics:
         percentiles, decode tokens/s, and (``device``) what the model's
         programs counted on the device: a blocking fetch, so the log line
         a tick writes (:meth:`log_snapshot`) leaves it out."""
+        # first: the engine reads its tick in flight before it reads the
+        # device, so the host's counts below describe the same ticks
+        on_device = (self.device_counters() if device and self.device_counters
+                     else {})
         span = None
         if self._first_token_t is not None and self._last_token_t is not None:
             span = self._last_token_t - self._first_token_t
@@ -785,6 +814,13 @@ class ServingMetrics:
             "spec_accepted_tokens": self.spec_accepted_tokens,
             "spec_acceptance_rate": float(self._g_spec_rate.value),
             "spec_tokens_per_tick_mean": self._h_spec_tokens.mean,
+            # the tick in flight (engine.py "Tick order"): overlapped +
+            # flushed = the decode ticks that were read
+            "decode_ticks_overlapped": int(self._c_ticks_overlapped.value),
+            "decode_ticks_flushed": sum(
+                int(c.value) for c in self._flushed.values()),
+            **{f"decode_ticks_flushed_{cause}": int(c.value)
+               for cause, c in self._flushed.items()},
             # crash-safety story: how often the engine recovered, what it
             # quarantined, what shutdown turned away, and what a tick costs
             "engine_recoveries": self.engine_recoveries,
@@ -794,8 +830,7 @@ class ServingMetrics:
             "tick_ms_p99": _ms(tick_p99),
             # what the model's programs counted on the device (an expert
             # model's routing: ``moe_*``), fetched here and nowhere else
-            **(self.device_counters() if device and self.device_counters
-               else {}),
+            **on_device,
         }
 
     def log_snapshot(self) -> None:

@@ -331,7 +331,11 @@ def device_counters_of(engine):
     """``() -> dict`` for ``ServingMetrics.device_counters``: what the
     engine's executor reads from the cache tree its programs carry
     (:meth:`ModelExecutor.counters`). Called by ``snapshot()`` alone, so a
-    tick fetches nothing; holds the engine weakly."""
+    tick fetches nothing; holds the engine weakly. The engine's tick in
+    flight is read first, so that what the host counted (tokens,
+    retirements, positions) and what the device counted describe the same
+    ticks: a ``snapshot()`` that reads the device belongs to the thread
+    that drives the engine."""
     from fleetx_tpu.obs.events import emit as obs_emit
 
     ref = weakref.ref(engine)
@@ -340,6 +344,7 @@ def device_counters_of(engine):
         eng = ref()
         if eng is None:
             return {}
+        eng._settle("other")
         try:
             # and the pool by class of page, which is host state
             return {**eng.executor.counters(eng.cache_manager.cache),

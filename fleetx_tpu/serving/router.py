@@ -1414,7 +1414,7 @@ class ServingRouter:
                 kw["deadline_s"] = remaining
             try:
                 erid = rep.engine.submit(
-                    req.prompt, on_token=self._make_cb(req),
+                    req.prompt, on_token=self._make_cb(req, rep),
                     rng_key=req.rng_key,
                     history=req.tokens if req.tokens else None,
                     kv_payloads=req.kv_payloads, **kw)
@@ -1474,11 +1474,17 @@ class ServingRouter:
             self.metrics.record_dispatch(via_affinity, req.tenant)
             return True
 
-    def _make_cb(self, req: _RouterRequest):
+    def _make_cb(self, req: _RouterRequest, rep: _Replica):
         """Per-dispatch ``on_token`` wrapper: append to the router's
         durable history (the failover replay source), record TTFT, and
-        forward to the user's callback under the ROUTER request id."""
-        def cb(_engine_rid, tok, finished):
+        forward to the user's callback under the ROUTER request id.
+        Exactly-one-stream: a token from a copy the request was moved
+        away from (an engine reads its tick in flight before a cancel
+        acts, so a stale copy's last token arrives with its cancel) is
+        dropped here."""
+        def cb(engine_rid, tok, finished):
+            if req.replica != rep.index or req.engine_rid != engine_rid:
+                return
             req.tokens.append(int(tok))
             if req.first_token_time is None:
                 req.first_token_time = self._now()

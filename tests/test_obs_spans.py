@@ -80,9 +80,11 @@ class _SlowFetch:
 
 @pytest.fixture(scope="module")
 def ticked():
-    """One engine: sixteen admissions, then a warm decode-only tick whose
-    token fetch takes 20 ms. Returns ``(admission tick's spans, decode-only
-    tick's spans)`` from the process recorder."""
+    """One engine: sixteen admissions, then a warm decode-only tick that
+    reads a tick whose token fetch takes 20 ms (the engine keeps one tick
+    in flight: a step() dispatches its own tick, then reads the one
+    dispatched a step earlier). Returns ``(admission tick's spans,
+    decode-only tick's spans)`` from the process recorder."""
     eng = _engine()
     rng = np.random.default_rng(0)
     for _ in range(LANES):
@@ -99,8 +101,9 @@ def ticked():
         return cache, st, _SlowFetch(tok, 0.02), done
 
     eng._decode_jit = slow
+    eng.step()            # dispatches the tick whose tokens come slowly
     rec.clear()
-    eng.step()
+    eng.step()            # dispatches the next one, then reads that one
     return admission, rec.spans()
 
 
@@ -170,13 +173,14 @@ def test_decode_only_tick_has_one_leaf_span_per_phase(ticked):
         assert all(tick.start_s <= s.start_s and s.end_s <= tick.end_s
                    for s in found), name
     # the snapshot before the tick and the metrics block after it are the
-    # tick's siblings
-    (snapshot,) = _named(spans, "serving.snapshot")
+    # tick's siblings; the re-commit after the delivered tokens is the tick's
+    snapshot, commit = _named(spans, "serving.snapshot")
     (observe,) = _named(spans, "serving.observe")
     assert snapshot.parent is None and snapshot.end_s <= tick.start_s
     assert observe.parent is None and observe.start_s >= tick.end_s
     # ONE emit span for sixteen lanes, never one per lane
     (emit,) = _named(spans, "serving.emit")
+    assert commit.parent == "serving.tick" and commit.start_s >= emit.end_s
     (fetch,) = _named(spans, "serving.fetch")
     assert emit.attrs == {"batch": LANES} and fetch.attrs == {"batch": LANES}
     assert len(spans) - names.count("serving.tick") \
@@ -189,6 +193,8 @@ def test_the_wait_for_the_device_is_in_fetch_not_in_decode(ticked):
     (decode,) = _named(spans, "serving.decode")
     assert fetch.duration_s >= 0.02
     assert decode.duration_s < 0.02      # a dispatch span
+    # this step's tick is dispatched BEFORE the wait for the one before it
+    assert decode.attrs["inflight"] == 1
     assert decode.end_s <= fetch.start_s
 
 
