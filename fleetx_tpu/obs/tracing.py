@@ -5,12 +5,17 @@ bounded ring buffer (``FLEETX_OBS_SPANS`` spans, default 65,536, oldest
 dropped) AND — the bridge — enters a ``jax.profiler.TraceAnnotation`` of
 the same name, so when a profiling window is open
 (``jax.profiler.start_trace`` / ``Profiler.enable`` in the Trainer) the
-host phases show up in the trace on the device's clock (the two agree to
-about 0.5 ms on a v5e): the benchmark books every idle gap of the device
+host phases show up in the profiler's trace (its host events agree with
+the ring to microseconds; the device's lines run 0 to 0.9 ms ahead of
+them on a v5e, a constant of the profiling session:
+docs/OBSERVABILITY.md): the benchmark books every idle gap of the device
 to the innermost span open at that time (``perfbench/trace_reduce.py``),
 so the bridge has no off switch. Outside a profiling window
 TraceAnnotation is a near-free TraceMe no-op, so spans stay on
-permanently (about 5 us each).
+permanently (about 5 us each). Inside one, the annotation also takes the
+span's identity attrs (``program``, ``reads``, ``request``, ``tick``) as
+arguments, which the trace keeps as stats of the event under its bare
+name: they say which ring span a host event is.
 
 The ring buffer is exported as Chrome-trace JSON
 (:meth:`SpanRecorder.chrome_trace`, ``chrome://tracing`` / Perfetto
@@ -44,6 +49,13 @@ from jax.profiler import TraceAnnotation
 from fleetx_tpu.obs._util import env_int, json_safe as _json_safe
 
 __all__ = ["Span", "SpanRecorder", "get_recorder", "span"]
+
+# The attrs that say WHICH program a span dispatched (``program``) or read
+# (``reads``) and whose work it was: they alone ride into the profiler's
+# trace, as arguments of the span's TraceAnnotation, so a host event there
+# can be matched to its ring span exactly (docs/OBSERVABILITY.md).
+_IDENTITY = frozenset(("program", "reads", "request", "tick"))
+_profiling = TraceAnnotation.is_enabled
 
 
 @dataclasses.dataclass
@@ -153,7 +165,14 @@ def span(name: str, recorder: Optional[SpanRecorder] = None, **attrs):
     rec = recorder or _RECORDER
     stack = rec._stack()
     parent = stack[-1] if stack else None
-    with TraceAnnotation(name):
+    # the annotation's NAME stays the bare span name (the benchmark books
+    # idle gaps by it); the identity attrs known when the span opens become
+    # its arguments, and only inside a profiling window: a closed profiler
+    # costs this one check
+    with TraceAnnotation(name) as annotation:
+        if _profiling():
+            annotation.set_metadata(**{k: v for k, v in attrs.items()
+                                       if k in _IDENTITY})
         start = time.perf_counter()
         stack.append(name)
         try:

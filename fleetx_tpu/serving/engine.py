@@ -57,7 +57,9 @@ calls all run while tick n is on the device. What follows from it:
   (dispatched while the tick before was unread) and
   ``decode_ticks_flushed`` (read with nothing behind it, by cause:
   ``_spec``, ``_watchdog``, ``_evict``, ``_pool_dry``, ``_idle``,
-  ``_other``); ``serving.decode`` carries ``inflight=0|1``.
+  ``_other``); ``serving.decode`` carries ``inflight=0|1`` and its
+  ``program`` number, which the ``serving.fetch`` that reads the tick
+  repeats as ``reads`` (a flushed one beside ``flushed=<cause>``).
 - a device error of tick n surfaces at tick n+1's dispatch or at the
   read, one ``step()`` later, inside the same transaction: the rollback
   drops the unread tick, whose tokens never reached host truth, so replay
@@ -758,6 +760,8 @@ class ServingEngine:
         # "Tick order"), and the lanes whose tokens this step() delivered
         self._inflight: Optional[InflightTick] = None
         self._delivered = 0
+        # programs dispatched so far (_next_program)
+        self._programs = 0
         self._state = self._replicate(self._init_state())
         # buffer donation halves cache HBM residency on TPU; skipped on
         # CPU/interpret runs where XLA would only warn about it
@@ -2234,7 +2238,8 @@ class ServingEngine:
     def _guarded_prefill(self, req: Request, fn, args, bucket=None):
         """One prefill device call through the fault-injection hook;
         stores the returned cache in the cache manager and returns the
-        first token with the stream's carry key. Deliberately NOT
+        first token with the stream's carry key and the call's program
+        number (:meth:`_next_program`). Deliberately NOT
         under the hung-tick watchdog: prefill calls legitimately include
         fresh-bucket XLA compiles (seconds), and replay recovery
         re-prefills through here — a watchdog here would misread every
@@ -2243,19 +2248,21 @@ class ServingEngine:
         the loop that actually wedges."""
         attempt = self._fault_prefills
         self._fault_prefills += 1
-        with span("serving.prefill", request=req.id, bucket=bucket):
+        program = self._next_program()
+        with span("serving.prefill", request=req.id, bucket=bucket,
+                  program=program):
             faults.on_serving_prefill(attempt, req.id)
             with self._mesh_context():
                 cache, tok, carry_key = fn(*args)
         self.cache_manager.cache = cache
-        return tok, carry_key
+        return tok, carry_key, program
 
     def _paged_prefill_call(self, req: Request, suffix, shared, lane,
                             replay: bool = False):
         """Batch-1 prefill of the non-shared ``suffix`` straight into
         ``lane``'s pages at absolute positions ``shared..``. Admission
         returns ``(first_token, carry_key, sampler_floats)``, all on the
-        device; replay returns None.
+        device, and the call's program number; replay returns None.
         Chunked prefill reuses this call verbatim — an intermediate
         chunk is exactly a ``replay`` call (KV writes only, inert
         sampler, no rng consumed) at its chunk's write offset, and the
@@ -2275,8 +2282,9 @@ class ServingEngine:
             req, suffix, bucket, replay, shared,
             self.cache_manager.lane_tables(lane))
         args = (self.params, self.cache_manager.cache, ints, floats, key)
-        tok, carry_key = self._guarded_prefill(req, fn, args, bucket=bucket)
-        return None if replay else (tok, carry_key, floats)
+        tok, carry_key, program = self._guarded_prefill(
+            req, fn, args, bucket=bucket)
+        return None if replay else (tok, carry_key, floats, program)
 
     def _claim_storage(self, req: Request) -> int:
         """Claim a decode lane and its page chain for one admission; sets
@@ -2306,7 +2314,8 @@ class ServingEngine:
         admission): ten int32 in one upload; ``floats`` is the float32
         pair the admission's prefill already sent, uploaded here when no
         such call was made."""
-        with span("serving.install", request=req.id, transfers=0) as at:
+        with span("serving.install", request=req.id, transfers=0,
+                  program=self._next_program()) as at:
             ints = np.asarray(
                 [req.slot, tok, length, decoded, active, req.eos_token_id,
                  req.max_new_tokens, req.min_new_tokens, req.greedy,
@@ -2321,6 +2330,18 @@ class ServingEngine:
         work that runs while its prefill is still on the device)."""
         with span("serving.install", request=req.id):
             self.cache_manager.register_prefix(req.slot, req.prompt)
+
+    def _next_program(self) -> int:
+        """The number of the program about to be dispatched: one count
+        over every prefill call, lane install, decode tick and verify
+        call of this engine's life, never reset and never handed out
+        twice (not by a rollback, a replay or a recovery). The DISPATCH
+        span records it as ``program``; the WAIT span that reads the
+        program's result records it as ``reads``. One device runs its
+        programs in the order of their dispatch, so the numbers are the
+        order of the device's program line (docs/OBSERVABILITY.md)."""
+        self._programs += 1
+        return self._programs
 
     def _fetch(self, name: str, *arrays, **attrs):
         """THE blocking device-to-host read: every result the host waits
@@ -2492,13 +2513,15 @@ class ServingEngine:
         self._run_chunk(req)
         return 1, []
 
-    def _finish_first_token(self, req: Request, tok, carry_key,
-                            floats) -> None:
+    def _finish_first_token(self, req: Request, tok, carry_key, floats,
+                            program: int) -> None:
         """Shared admission tail: wait for the prefill's first token
-        (``serving.first_token``: the host-visible prefill wait), then
+        (``serving.first_token``: the host-visible prefill wait, which
+        ``reads`` the request's last prefill ``program``), then
         install the decode lane, record TTFT, fire the callback, route
         to the active set or straight to retirement."""
-        tok = int(self._fetch("serving.first_token", tok, request=req.id)[0])
+        tok = int(self._fetch("serving.first_token", tok, request=req.id,
+                              reads=program)[0])
         now = self._now()
         req.first_token_time = now
         req.tokens.append(tok)
@@ -2678,8 +2701,9 @@ class ServingEngine:
             return out
 
         before = self._inflight
+        program = self._next_program()
         with span("serving.decode", batch=len(active_ids),
-                  inflight=int(before is not None),
+                  inflight=int(before is not None), program=program,
                   **self._decode_rows(lanes)):
             cache, st, tok, done = self._run_device(run)
         self.cache_manager.cache = cache
@@ -2688,7 +2712,7 @@ class ServingEngine:
         # tick's page growth, window recycling, row counts and tables are
         # reckoned from it while this tick's tokens are still on their way
         self.cache_manager.lengths[list(lanes)] += 1
-        self._inflight = InflightTick(tok, done, lanes)
+        self._inflight = InflightTick(tok, done, lanes, program)
         if before is not None:
             # the wait for tick n-1 is HERE, with tick n on the device
             self.metrics.record_tick_overlapped()
@@ -2705,7 +2729,7 @@ class ServingEngine:
         tick, self._inflight = self._inflight, None
         if tick is None:
             return []
-        retired = self._deliver(tick, commit)
+        retired = self._deliver(tick, commit, flushed=cause)
         self.metrics.record_tick_flushed(cause)
         return retired
 
@@ -2721,14 +2745,18 @@ class ServingEngine:
         except Exception as exc:  # noqa: BLE001 — the crash-safety seam
             self._handle_tick_fault(self._snapshot(), exc)
 
-    def _deliver(self, tick: InflightTick, commit) -> list:
-        """The host sync of a tick: wait for its tokens, hand each to the
+    def _deliver(self, tick: InflightTick, commit,
+                 flushed: Optional[str] = None) -> list:
+        """The host sync of a tick: wait for its tokens (``serving.fetch``
+        ``reads`` the tick's ``program``, and names as ``flushed`` the
+        cause of a read with no tick behind it), hand each to the
         request that STILL holds the lane it was dispatched for, retire
         what finished, then ``commit`` (what was emitted stays emitted,
         whatever fails later in this step)."""
         batch = len(tick.lanes)
+        why = {"flushed": flushed} if flushed else {}
         tok_np, done_np = self._fetch("serving.fetch", tick.tok, tick.done,
-                                      batch=batch)
+                                      batch=batch, reads=tick.program, **why)
         now = self._now()
         retired = []
         with span("serving.emit", batch=batch):
@@ -2997,12 +3025,15 @@ class ServingEngine:
                 jax.block_until_ready(out)
             return out
 
-        with span("serving.verify", batch=len(active_ids), k=k):
+        program = self._next_program()
+        with span("serving.verify", batch=len(active_ids), k=k,
+                  program=program):
             cache, st, out_tok, m, acc, done = self._run_device(run)
         self.cache_manager.cache = cache
         self._state = st
         out_np, m_np, acc_np, done_np = self._fetch(
-            "serving.fetch", out_tok, m, acc, done, batch=len(active_ids))
+            "serving.fetch", out_tok, m, acc, done, batch=len(active_ids),
+            reads=program, flushed="spec")
         now = self._now()
         proposed = accepted = 0
         emitted_rows = []

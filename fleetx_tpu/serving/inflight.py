@@ -33,6 +33,7 @@ class InflightTick:
     tok: object                 # device [slots] int32: the tick's tokens
     done: object                # device [slots] bool: lanes it finished
     lanes: Dict[int, Request]   # slot -> request, as dispatched
+    program: int                # its dispatch's number (engine._next_program)
 
 
 def pending_of(tick: Optional[InflightTick], slot: int, req: Request) -> int:

@@ -144,7 +144,9 @@ def test_admission_spans_nest_under_admit_with_their_attrs(ticked):
     # which sends its int32 vector and takes the prefill's float32 pair
     register, install = by_name["serving.install"]
     assert register.attrs == {"request": request}
-    assert install.attrs == {"request": request, "transfers": 1}
+    prefill = by_name["serving.prefill"][0].attrs["program"]
+    assert install.attrs == {"request": request, "transfers": 1,
+                             "program": prefill + 1}
     assert by_name["serving.first_token"][0].end_s <= install.start_s
     # admit now ends after the first token was fetched and the lane
     # installed: its documented meaning
@@ -182,7 +184,12 @@ def test_decode_only_tick_has_one_leaf_span_per_phase(ticked):
     (emit,) = _named(spans, "serving.emit")
     assert commit.parent == "serving.tick" and commit.start_s >= emit.end_s
     (fetch,) = _named(spans, "serving.fetch")
-    assert emit.attrs == {"batch": LANES} and fetch.attrs == {"batch": LANES}
+    # an overlapped read: of the tick dispatched a step() earlier, which
+    # nothing flushed
+    (decode,) = _named(spans, "serving.decode")
+    assert emit.attrs == {"batch": LANES}
+    assert fetch.attrs == {"batch": LANES,
+                           "reads": decode.attrs["program"] - 1}
     assert len(spans) - names.count("serving.tick") \
         - names.count("serving.decode") <= 10
 
@@ -196,6 +203,163 @@ def test_the_wait_for_the_device_is_in_fetch_not_in_decode(ticked):
     # this step's tick is dispatched BEFORE the wait for the one before it
     assert decode.attrs["inflight"] == 1
     assert decode.end_s <= fetch.start_s
+
+
+# ------------------------------ which program a span dispatched, which it read
+
+DISPATCH = ("serving.decode", "serving.verify", "serving.prefill",
+            "serving.install")
+
+
+def _dispatched(spans):
+    """The dispatch spans that number a program, in the order they began."""
+    return sorted((s for s in spans
+                   if s.name in DISPATCH and "program" in s.attrs),
+                  key=lambda s: s.start_s)
+
+
+def _steps(spans):
+    """``[[the spans inside one serving.tick], ...]`` in tick order."""
+    ticks = sorted(_named(spans, "serving.tick"), key=lambda s: s.start_s)
+    return [[s for s in spans
+             if t.start_s <= s.start_s and s.end_s <= t.end_s and s is not t]
+            for t in ticks]
+
+
+def test_programs_are_numbered_without_gap_or_reuse_through_a_recovery():
+    """An admission, decode-only ticks, a chunked admission, a tick that
+    fails and is rolled back, the recovery's replays and the ticks after
+    it: every dispatch takes the next number, and none comes twice."""
+    from fleetx_tpu.resilience.faults import faults
+
+    eng = _engine(prefill_chunk=4)
+    rec = get_recorder()
+    rec.clear()
+    short = eng.submit(np.asarray([1, 2, 3], np.int32), max_length=6)
+    eng.step()                     # one-call admission, first decode tick
+    eng.step()                     # decode-only
+    chunked = eng.submit(np.arange(1, 11, dtype=np.int32), max_length=6)
+    for _ in range(3):             # chunks of 4, 4 and 2 beside the ticks
+        eng.step()
+    faults.configure(tick_raise=str(eng._fault_ticks))
+    try:
+        summary = eng.step()       # the dispatch raises: rollback, recovery
+    finally:
+        faults.reset()
+    assert summary["recovered"] and eng.metrics.engine_recoveries == 1
+    results = eng.drain()
+    assert set(results) == {short, chunked}
+    spans = rec.spans()
+    programs = [s.attrs["program"] for s in _dispatched(spans)]
+    assert programs == list(range(1, len(programs) + 1))
+    assert eng._programs == len(programs)
+    names = [s.name for s in _dispatched(spans)]
+    assert names[:3] == ["serving.prefill", "serving.install",
+                         "serving.decode"]
+    # the failed dispatch kept its number, and the replays come after it
+    (rollback,) = _named(spans, "serving.rollback")
+    failed = [s for s in _dispatched(spans) if s.end_s <= rollback.start_s][-1]
+    assert failed.name == "serving.decode"
+    assert not [s for s in _named(spans, "serving.fetch")
+                if s.attrs["reads"] == failed.attrs["program"]]
+    replays = [s for s in _dispatched(spans) if s.parent == "serving.recover"]
+    assert {s.name for s in replays} == {"serving.prefill", "serving.install"}
+    # a chunked admission waits for its FINAL chunk's program
+    chunks = [s for s in _named(spans, "serving.prefill")
+              if s.attrs["request"] == chunked
+              and s.parent == "serving.prefill_chunk"]
+    assert len(chunks) == 3
+    (wait,) = [s for s in _named(spans, "serving.first_token")
+               if s.attrs["request"] == chunked]
+    assert wait.attrs["reads"] == chunks[-1].attrs["program"]
+    assert wait.start_s >= chunks[-1].end_s
+
+
+def test_first_token_reads_its_requests_prefill(ticked):
+    admission, _ = ticked
+    prefills = {s.attrs["request"]: s.attrs["program"]
+                for s in _named(admission, "serving.prefill")}
+    waits = _named(admission, "serving.first_token")
+    assert len(waits) == len(prefills) == LANES
+    assert all(s.attrs["reads"] == prefills[s.attrs["request"]]
+               for s in waits)
+
+
+@pytest.mark.parametrize("kw, cause", [
+    (dict(spec=True, spec_k=2), "spec"), (dict(tick_timeout_s=60.0), "watchdog"),
+    ({}, "idle")])
+def test_a_fetch_names_the_program_it_reads_and_why_it_was_flushed(kw, cause):
+    """An overlapped fetch reads the tick dispatched in the step BEFORE
+    its own; a flushed one names its cause and reads the tick of its own
+    step (``idle``: there is none, so the last one dispatched)."""
+    eng = _engine(**kw)
+    rec = get_recorder()
+    rec.clear()
+    eng.submit(np.asarray([3, 1, 4, 1, 5], np.int32), max_length=6)
+    eng.step()
+    eng.submit(np.asarray([2, 7, 1, 8], np.int32), max_length=6)
+    eng.drain()
+    steps = _steps(rec.spans())
+    ticks = [[s for s in step if s.name in ("serving.decode",
+                                            "serving.verify")]
+             for step in steps]
+    fetches = [(n, s) for n, step in enumerate(steps)
+               for s in _named(step, "serving.fetch")]
+    assert fetches
+    flushed = [(n, s) for n, s in fetches if "flushed" in s.attrs]
+    assert {s.attrs["flushed"] for _, s in flushed} == {cause}
+    for n, fetch in fetches:
+        if "flushed" not in fetch.attrs:
+            (before,) = ticks[n - 1]
+            assert before.name == "serving.decode"
+            assert before.attrs["inflight"] in (0, 1)
+            assert fetch.attrs["reads"] == before.attrs["program"]
+            assert ticks[n] and ticks[n][0].end_s <= fetch.start_s
+        elif cause == "idle":
+            assert not ticks[n]
+            assert fetch.attrs["reads"] == ticks[n - 1][0].attrs["program"]
+        else:
+            (own,) = ticks[n]
+            assert fetch.attrs["reads"] == own.attrs["program"]
+    if cause == "idle":
+        assert len(flushed) == 1 and len(fetches) > 1
+    else:
+        assert len(flushed) == len(fetches)
+
+
+@pytest.mark.parametrize("profiling", [True, False])
+def test_the_annotation_keeps_the_bare_name_and_takes_the_identity(
+        monkeypatch, profiling):
+    """Inside a profiling window the identity attrs become arguments of
+    the annotation, whose name stays the bare span name; outside one
+    nothing is handed over."""
+    from fleetx_tpu.obs import tracing
+
+    seen = []
+
+    class Annotation:
+        def __init__(self, name, **kwargs):
+            seen.append([name, kwargs, None])
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        def set_metadata(self, **kwargs):
+            seen[-1][2] = kwargs
+
+    monkeypatch.setattr(tracing, "TraceAnnotation", Annotation)
+    monkeypatch.setattr(tracing, "_profiling", lambda: profiling)
+    rec = SpanRecorder(capacity=4)
+    with span("serving.fetch", recorder=rec, batch=3, reads=7, request=2,
+              tick=5, flushed="idle") as at:
+        at["program"] = 9   # known too late to ride
+    identity = {"reads": 7, "request": 2, "tick": 5}
+    assert seen == [["serving.fetch", {}, identity if profiling else None]]
+    assert rec.spans()[0].attrs == {"batch": 3, "flushed": "idle",
+                                    "program": 9, **identity}
 
 
 def test_tables_upload_is_spanned_only_when_it_uploads():
