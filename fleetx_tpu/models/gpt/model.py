@@ -57,10 +57,10 @@ class GPTConfig(block_fields.BlockLayoutFields):
     use_recompute: bool = False
     recompute_granularity: Optional[str] = None  # full | full_attn | core_attn
     # extra checkpoint_name'd tensors to SAVE on top of the granularity's
-    # base save-set: trades HBM for less backward recompute. Named sites:
-    # 'qkv_out' (skip re-running the qkv projection), 'ffn_gelu' (skip
-    # up_proj + gelu — the widest activation), 'mlp_out', 'attn_out'.
-    # v5e guidance in docs/PERFORMANCE.md.
+    # base save-set: 'qkv_out', 'ffn_gelu' (the widest), 'mlp_out',
+    # 'attn_out'. core_attn's base is what the flash backward kernels read:
+    # 'core_attn_out' and the row statistic 'core_attn_lse' ([b*h, s]
+    # float32: 1 MB a layer at 345M, batch 16). docs/PERFORMANCE.md.
     recompute_extra_saves: Optional[Tuple[str, ...]] = None
     no_recompute_layers: Optional[Tuple[int, ...]] = None
     use_flash_attention: bool = True
@@ -381,7 +381,7 @@ class SelfAttention(nn.Module):
             # would fight the stage sharding (parallel/pipeline.py)
             mesh_shard=cfg.pp_degree == 1,
         )
-        out = checkpoint_name(out, "core_attn_out")
+        # (causal_attention names its result "core_attn_out", on either path)
         return self._out_proj(out)
 
     def _out_proj(self, out):
@@ -453,7 +453,7 @@ class SelfAttention(nn.Module):
         )
         if quant:
             # per-vector fp32 scales: rank 3 with the batch axis at -3,
-            # so scatter_slot and friends treat them like K/V leaves
+            # so tree walkers address them like K/V leaves (ops/quant.py)
             cks = self.variable(
                 "cache", "cached_key_scale", jnp.zeros,
                 (b, max_len, nh), jnp.float32
@@ -867,9 +867,9 @@ class _ScanLayer(nn.Module):
 
 # every checkpoint_name site in this model; a typo'd save name would
 # otherwise silently match nothing and masquerade as the base save-set
-_CHECKPOINT_NAMES = frozenset(
-    {"qkv_out", "core_attn_out", "attn_out", "ffn_gelu", "mlp_out"}
-)
+_CHECKPOINT_NAMES = frozenset({"qkv_out", "core_attn_out", "core_attn_lse",
+                               "attn_out", "ffn_gelu", "mlp_out"})
+# ("core_attn_lse" is named in ops/pallas/flash_attention.py's forward rule)
 
 
 def _remat_policy(cfg: GPTConfig):
@@ -890,9 +890,9 @@ def _remat_policy(cfg: GPTConfig):
     if g == "full_attn":
         return jax.checkpoint_policies.save_only_these_names(
             "attn_out", *extra)
-    if g == "core_attn":
+    if g == "core_attn":  # what the flash backward kernels read
         return jax.checkpoint_policies.save_only_these_names(
-            "core_attn_out", *extra)
+            "core_attn_out", "core_attn_lse", *extra)
     raise ValueError(f"unknown recompute_granularity {g!r}")
 
 

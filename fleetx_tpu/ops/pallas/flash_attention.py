@@ -62,9 +62,9 @@ import functools
 from typing import Optional
 
 import numpy as np
-
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -688,13 +688,13 @@ def _flash(q, k, v, seed, kvlens, meta, block_q, block_k, dropout_rate,
 def _flash_fwd(q, k, v, seed, kvlens, meta, block_q, block_k, dropout_rate,
                causal):
     b, s, h, d = q.shape
-    scale = 1.0 / (d**0.5)
     q3, k3, v3 = _to_bh(q), _to_bh(k), _to_bh(v)
-    o3, lse = _fwd_call(
-        seed, kvlens, meta, q3, k3, v3, block_q, block_k, scale, dropout_rate,
-        causal
-    )
-    return _from_bh(o3, b, h), (q3, k3, v3, o3, lse, seed, kvlens, meta, b, h)
+    o3, lse = _fwd_call(seed, kvlens, meta, q3, k3, v3, block_q, block_k,
+                        1.0 / (d**0.5), dropout_rate, causal)
+    # named, so core_attn recompute saves what the backward reads; lse [bh, s]
+    out = checkpoint_name(_from_bh(o3, b, h), "core_attn_out")
+    lse = checkpoint_name(lse[..., 0], "core_attn_lse")
+    return out, (q3, k3, v3, out, lse, seed, kvlens, meta)
 
 
 def _dq_call(seed, kvlens, meta, q3, k3, v3, do3, lse, delta, block_q,
@@ -776,10 +776,10 @@ def _dkv_call(seed, kvlens, meta, q3, k3, v3, do3, lse, delta, block_q,
 
 
 def _flash_bwd(block_q, block_k, dropout_rate, causal, res, g):
-    q3, k3, v3, o3, lse, seed, kvlens, meta, b, h = res
-    bh, s, d = q3.shape
-    scale = 1.0 / (d**0.5)
-    do3 = _to_bh(g)
+    q3, k3, v3, out, lse, seed, kvlens, meta = res
+    b, _, h, d = out.shape
+    scale, lse = 1.0 / (d**0.5), lse[..., None]  # the kernels' [bh, s, 1]
+    do3, o3 = _to_bh(g), _to_bh(out)
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32), axis=-1,
                     keepdims=True)  # [bh, s, 1]
     dq3 = _dq_call(seed, kvlens, meta, q3, k3, v3, do3, lse, delta,
