@@ -385,6 +385,11 @@ def engine_check(engine, served: Served, in_flight, unit: float) -> dict:
     out = {"engine_lanes_live": len(live), "engine_lanes_checked": len(held)}
     for rid in list(in_flight):
         engine.cancel(rid)
+    if not held:
+        # no lane was decoding when the window closed (a rehearsal of three
+        # lanes can end so): nothing was checked, so the run is not correct
+        out["engine_ok"] = False
+        return out
     rows_err, deficits, margins = [], [], []
     for tokens, prompt_len, theirs in held:
         n = len(tokens) - 1

@@ -5,13 +5,34 @@ ramp (``ramp_s``, set-up) fills the lanes before the window opens; the
 requests DUE inside the window are the ones measured, each timed from when
 it was due; tokens count only if delivered inside the window. After the
 window closes the loop runs on, bounded by ``grace_s``, until every
-measured request has its first token."""
+measured request has its first token.
+
+A request that falls due while a step runs is submitted when the step
+returns, as the program's own server does it (``serving/api/server.py``
+holds one lock around ``step()`` and ``submit()``): that wait is the
+deployment's, and is inside a TTFT taken from the due time there as here.
+What the GENERATOR adds is the rest of a request's lateness, from the later
+of its due time and the last step's return to its submit (the loop's own
+work and the submits queued before it): ``own_late_ms`` of a request.
+
+In a ``--trace 1`` run the schedule's clock stands still while the profiler
+starts and while it writes its trace out (2-3 s and more, with this thread
+held): at 9.6 requests/s those seconds would queue some thirty requests,
+the traced stretch would see full lanes where the cell runs them 0.6 busy,
+and requests due near the window's end would outwait the grace."""
 
 from __future__ import annotations
 
 import time
 
 from perfbench import harness, serving, traffic as traffic_gen
+
+
+def lateness_ms(rec: dict) -> tuple:
+    """``(late, own)`` of a submitted request's record: submit less due
+    time, and the generator's own part of it (module docstring)."""
+    return ((rec["submit_s"] - rec["due_s"]) * 1e3,
+            (rec["submit_s"] - max(rec["due_s"], rec["free_s"])) * 1e3)
 
 
 def replay(engine, pending, ramp: float, seconds: float, grace_s: float,
@@ -26,11 +47,12 @@ def replay(engine, pending, ramp: float, seconds: float, grace_s: float,
     start, end = origin + ramp, origin + ramp + seconds
     profiler.arm(start, seconds)
     measured, live = [], []
+    free = origin  # when the last step returned: the thread could submit
     while True:
         now = time.perf_counter()
         while pending and origin + pending[-1].due_s <= now:
             request = pending.pop()
-            rec = clients.submit(request, origin + request.due_s)
+            rec = clients.submit(request, origin + request.due_s, free_s=free)
             if rec["due_s"] >= start:
                 measured.append(rec)
         if now >= end:
@@ -40,10 +62,17 @@ def replay(engine, pending, ramp: float, seconds: float, grace_s: float,
             if not waiting or now >= end + grace_s:
                 break
         elif now >= start:
-            profiler.poll(now)
+            if profiler.poll(now):
+                # starting the profiler, and writing its trace out, holds
+                # this thread for seconds: the schedule's clock stood still
+                # meanwhile, so that what fell due then does not pile up
+                # and the traced stretch sees the load the cell offers
+                stalled = time.perf_counter() - now
+                origin, end = origin + stalled, end + stalled
         if clients.open:
             engine.step()
-            live.append((time.perf_counter(), clients.live_tokens))
+            free = time.perf_counter()
+            live.append((free, clients.live_tokens))
         elif pending:
             time.sleep(max(0.0, min(
                 origin + pending[-1].due_s - time.perf_counter(), 0.001)))
@@ -73,7 +102,8 @@ def run(cell, seed: int, seconds: float, trace: bool, t_process: float):
     # a failed or refused request misses every limit: it enters the tail
     # as a very long wait
     requests = [{"id": r["id"], "due_s": r["due_s"],
-                 "late_ms": (r["submit_s"] - r["due_s"]) * 1e3,
+                 "late_ms": lateness_ms(r)[0],
+                 "own_late_ms": lateness_ms(r)[1],
                  "ttft_ms": ((r["stamps"][0] - r["due_s"]) * 1e3
                              if r["stamps"] else 1e9)} for r in measured]
     samples = {"token_s": clients.token_s, "requests": requests,
@@ -82,6 +112,7 @@ def run(cell, seed: int, seconds: float, trace: bool, t_process: float):
     pct = harness.percentile
     ttft = [r["ttft_ms"] for r in requests]
     gap_ms = [ms for _, ms in samples["gaps"]]
+    own = [r["own_late_ms"] for r in requests]
     harness.log(
         f"requests measured {len(measured)}; ttft ms "
         + " ".join(f"p{q} {pct(ttft, q):.1f}" for q in (25, 50, 75, 90))
@@ -90,8 +121,9 @@ def run(cell, seed: int, seconds: float, trace: bool, t_process: float):
         + f" over {len(gap_ms)} gaps; tokens/s in the window "
         f"{sum(1 for t in clients.token_s if start <= t <= end) / seconds:.1f}"
         f"; generator late ms p50 "
-        f"{pct([r['late_ms'] for r in requests], 50):.1f}; open at the end "
-        f"{len(clients.open)}")
+        f"{pct([r['late_ms'] for r in requests], 50):.2f} (of it the "
+        f"generator's own p50 {pct(own, 50):.3f} p99 {pct(own, 99):.3f}); "
+        f"open at the end {len(clients.open)}")
     return harness.Run(
         cell=cell, device=device, setup_s=start - t_process,
         window=(start, end), attempted=len(measured),

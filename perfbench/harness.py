@@ -82,7 +82,9 @@ def by_name(kind: str, name: str):
     end-to-end metric it moves and is reported only where that one is, so
     a reader that serves cells with different end-to-end metrics appears
     under one tagged name for each, and is found by the part after the
-    dot."""
+    dot. Cells that report the same end-to-end metric share the entry:
+    its ``workloads`` lists them (``batch.tick_ms_p50`` names the four
+    closed-loop cells)."""
     return importlib.import_module(f"perfbench.{kind}.{name.split('.')[-1]}")
 
 
@@ -207,7 +209,8 @@ class ProfilerWindow:
     chip run, PR 22), which an open loop feels as a queue: so it sits late,
     and the client-side readers take what came before it
     (:meth:`Run.before_trace`). ``poll(now)`` is called by the driver
-    between steps or ticks and opens or closes the trace when due."""
+    between steps or ticks and opens or closes the trace when due; it
+    returns True from a call that did either (and so held the thread)."""
 
     def __init__(self, enabled: bool, length_s: float):
         self.enabled, self.length_s = enabled, length_s
@@ -221,9 +224,9 @@ class ProfilerWindow:
             self.start_at = window_start + max(
                 0.0, 0.8 * seconds - self.length_s)
 
-    def poll(self, now: float) -> None:
+    def poll(self, now: float) -> bool:
         if self.start_at is None or self.closed is not None:
-            return
+            return False
         import jax
 
         if self.opened is None and now >= self.start_at:
@@ -233,10 +236,13 @@ class ProfilerWindow:
             self.opening = time.perf_counter()
             jax.profiler.start_trace(self.dir, profiler_options=options)
             self.opened = time.perf_counter()
-        elif self.opened is not None and now >= self.opened + self.length_s:
+            return True
+        if self.opened is not None and now >= self.opened + self.length_s:
             self.closed = time.perf_counter()
             jax.profiler.stop_trace()
             self.stopped = time.perf_counter()
+            return True
+        return False
 
     def close(self) -> None:
         """End a trace the window's end overtook."""

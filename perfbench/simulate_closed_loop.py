@@ -14,8 +14,8 @@ One JSON line a seed (``serve_tokens_per_s`` as the cell counts it: tokens
 delivered inside the window over its length; the window opens when the first
 round has returned), then the spread over the seeds as the driver takes it.
 The times are what a traced run of the cell reads: the tick with its chunk
-(``st.tick_ms_p50``) less the chunk program (``st.prefill_ms_p50``), and the
-chunk program with the host's work around it (PERF.md section 7).
+(``batch.tick_ms_p50``) less the chunk program (``batch.prefill_ms_p50``),
+and the chunk program with the host's work around it (PERF.md section 7).
 """
 
 from __future__ import annotations

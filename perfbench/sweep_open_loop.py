@@ -28,7 +28,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 os.environ.setdefault("FLEETX_OBS_SPANS", "1048576")
 
 from perfbench import harness, serving, traffic as traffic_gen  # noqa: E402
-from perfbench.drivers.serve_open_loop import replay  # noqa: E402
+from perfbench.drivers.serve_open_loop import lateness_ms, replay  # noqa: E402
 from perfbench.layer_metrics import admit_gap_share, lane_occupancy  # noqa: E402
 
 
@@ -86,6 +86,10 @@ def main() -> int:
             / max(len(ttft), 1),
             **{f"gap_ms_p{q}": harness.percentile(gaps, q)
                for q in (50, 90, 95, 97, 99)},
+            "gen_late_ms_p50": harness.percentile(
+                [lateness_ms(r)[0] for r in measured], 50),
+            "gen_own_late_ms_p50": harness.percentile(
+                [lateness_ms(r)[1] for r in measured], 50),
             "admit_gap_share": admit_gap_share.read(run),
             "lane_occupancy": lane_occupancy.read(run),
             "backlog_mid": backlog((start + end) / 2),

@@ -29,7 +29,12 @@ and bursts compress the gaps that fall into them. Lengths come in blocks
 that hold the distribution's quantiles once each, in an order drawn from
 the seed (``stratified_lengths``): the same multiset for every seed, and
 only order and timing differ. A closed loop draws each client's lengths
-from such blocks of ``block`` requests.
+from such blocks of ``block`` requests. Where a few dozen long requests
+fill a window, the ORDER alone moves what the window completes (the
+long-document cell: 6% from seed to seed with no device in the loop,
+``simulate_closed_loop.py``): such a file gives ``order_seed``, which
+fixes tenants and lengths for every ``--seed``; the seed then draws the
+prompts' tokens (and the weights) alone.
 """
 
 from __future__ import annotations
@@ -196,20 +201,24 @@ def open_loop_trace(traffic: dict, seed: int, duration_s: float,
 def client_stream(traffic: dict, seed: int, client: int,
                   vocab: int) -> Iterator[Request]:
     """Closed loop: the endless request sequence of one client, a function
-    of the seed and the client's index alone (never of timing)."""
+    of the seed and the client's index alone (never of timing). With
+    ``order_seed`` in the traffic file, tenants and lengths are a function
+    of that and the client's index, and the seed draws the tokens."""
     tenants = traffic["tenants"]
     prefixes = _prefixes(np.random.default_rng([seed, 0]), tenants, vocab)
     rng = np.random.default_rng([seed, 1, client])
+    order = rng if traffic.get("order_seed") is None else \
+        np.random.default_rng([int(traffic["order_seed"]), 1, client])
     weights = _weights(tenants)
     block = int(traffic.get("block", 16))
     index, queues = 0, {}
     while True:
-        ti = int(rng.choice(len(tenants), p=weights))
+        ti = int(order.choice(len(tenants), p=weights))
         tenant = tenants[ti]
         if not queues.get(ti):  # this tenant's next block of lengths
             queues[ti] = list(zip(
-                stratified_lengths(rng, tenant["prompt"], block),
-                stratified_lengths(rng, tenant["output"], block)))
+                stratified_lengths(order, tenant["prompt"], block),
+                stratified_lengths(order, tenant["output"], block)))
         plen, new = queues[ti].pop()
         yield _one_request(rng, index, 0.0, tenant, prefixes, vocab, plen, new)
         index += 1

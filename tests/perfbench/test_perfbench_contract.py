@@ -14,6 +14,10 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 METRICS = [(kind, m) for kind in ("end_to_end", "per_layer")
            for m in BENCH[kind]]
+CELLS = [w["name"] for w in BENCH["workloads"]]
+# one case for every cell an entry lists (every cell, where it lists none)
+METRIC_CELLS = [pytest.param(kind, m, cell, id=f"{m['name']}-{cell}")
+                for kind, m in METRICS for cell in m.get("workloads", CELLS)]
 
 
 def test_top_level_keys_and_limits():
@@ -68,28 +72,44 @@ def test_cell_entry_files_and_metrics(cell):
     assert len(set(pairs)) == len(pairs)
 
 
-@pytest.mark.parametrize("kind,metric", METRICS,
-                         ids=lambda x: x if isinstance(x, str) else x["name"])
-def test_metric_entry_and_reader(kind, metric):
+@pytest.mark.parametrize("kind,metric,cell", METRIC_CELLS)
+def test_metric_entry_and_reader(kind, metric, cell):
     allowed = {"name", "unit", "better", "source", "workloads"}
     allowed |= {"bound"} if kind == "end_to_end" else {"layer", "moves"}
     assert set(metric) <= allowed and allowed - {"workloads"} <= set(metric)
     assert NAME.match(metric["name"]) and UNIT.match(metric["unit"])
     assert metric["better"] in ("lower", "higher")
     assert metric["source"] in SOURCES
-    cells = {w["name"] for w in BENCH["workloads"]}
-    assert set(metric.get("workloads", cells)) <= cells
+    # the cell exists and reports this entry, and the metric it moves
+    loaded = harness.load_cell(cell)
+    assert metric in getattr(loaded, kind)
     if kind == "end_to_end":
         assert metric["source"] in ("host_clock", "device_trace")
         assert 0.01 <= metric["bound"] <= 0.1
         reader = harness.by_name("end_to_end", metric["name"])
     else:
-        assert metric["moves"] in {m["name"] for m in BENCH["end_to_end"]}
+        assert metric["moves"] in {m["name"] for m in loaded.end_to_end}
+        assert metric["workloads"], "a per-layer entry lists its cells"
         assert "\n" not in metric["layer"] and len(metric["layer"]) <= 200
         if metric["name"].endswith("_roofline"):
             assert metric["unit"] == "%"
         reader = harness.by_name("layer_metrics", metric["name"])
     assert callable(reader.read)
+
+
+def test_no_reader_is_listed_twice_for_one_end_to_end_metric():
+    """``per_layer`` holds at most 128 entries, and a reader repeated under
+    a tag for each cell filled them (PR 37 folded 73 into 20): cells that
+    report the same end-to-end metric share ONE entry of a reader, whose
+    ``workloads`` lists them."""
+    seen = {}
+    for m in BENCH["per_layer"]:
+        key = (m["name"].split(".")[-1], m["moves"])
+        assert key not in seen, (m["name"], seen[key])
+        seen[key] = m["name"]
+    assert len(BENCH["per_layer"]) <= 128
+    assert not [m["name"] for m in BENCH["per_layer"]
+                if m["name"].startswith(("moe.", "st.", "lfm."))]
 
 
 def test_names_are_unique_and_files_use_allowed_characters():

@@ -77,7 +77,7 @@ def test_int8_weights_in_the_engine_turn_the_check_false(tiny, monkeypatch):
 
 
 @pytest.mark.parametrize("cell_name,key", [
-    ("gpt1.3b-serve-chat-steady-v2", 16),
+    ("gpt1.3b-serve-chat-steady-v3", 16),
     ("gpt1.3b-serve-docs-batch", None),
 ], ids=["the cell's key", "no key: the engine's default"])
 def test_build_engine_hands_the_cells_prefill_bucket_to_the_engine(

@@ -42,6 +42,70 @@ def test_tiny_rehearsal_runs_the_cells_control_flow(cell):
     assert '"compiles_in_window": 0' in out
 
 
+def _listed(cell):
+    return [m["name"] for m in BENCH["per_layer"]
+            if cell in m.get("workloads", [cell])]
+
+
+# What a ``--tiny --trace 1`` run of each cell reports on the CPU, where no
+# reader of the device's trace, of its memory or of a peak finds anything:
+# the names the parent's run (PR 36's tree) reported under a tag of each
+# cell (``moe.`` / ``st.`` / ``lfm.``), written as the folded entries
+# name them, and ``batch.tick_overlap_share``, which PR 37 added.
+_CLOSED_LOOP = {"batch.lane_occupancy", "batch.tick_host_ms_p50",
+                "batch.tick_ms_p50", "batch.tick_overlap_share"}
+TINY_REPORTS = {
+    "gpt345m-pretrain-s1024": {"train_data_wait_share"},
+    "gpt1.3b-pretrain-dp2mp2": {"train_data_wait_share"},
+    "gpt1.3b-serve-chat-steady-v3": {
+        "chat.admit_gap_share", "chat.admit_host_ms_p50",
+        "chat.lane_occupancy", "chat.tick_host_ms_p50", "chat.tick_ms_p50",
+        "chat.tick_overlap_share", "gap_p99_ms", "gen_late_p99_ms",
+        "queue_wait_p50_ms", "ttft_p90_ms"},
+    "gpt1.3b-serve-docs-batch": _CLOSED_LOOP | {"batch.admit_host_ms_p50"},
+    "olmoe-l8-serve-gen-batch": _CLOSED_LOOP | {
+        "batch.admit_host_ms_p50", "moe_load_max_over_mean"},
+    "smallthinker-l8-serve-longdoc-gen": _CLOSED_LOOP | {
+        "moe_load_max_over_mean", "swa_pool_bytes_share",
+        "swa_window_rows_share"},
+    "lfm2-l14-serve-agent-prefix": _CLOSED_LOOP | {
+        "batch.admit_host_ms_p50", "moe_load_max_over_mean",
+        "prefix_tokens_saved_share", "state_bytes_share",
+        "state_resume_share"},
+}
+
+
+@pytest.fixture(scope="module")
+def traced_rehearsal():
+    """The result of one ``--tiny --trace 1`` run a cell, made when a case
+    first asks for it (the file runs on one worker); a run that failed is
+    not made again for the cell's other cases."""
+    results = {}
+
+    def of(cell):
+        if cell not in results:
+            results[cell] = None
+            results[cell], _ = _run(
+                harness.ROOT, "--workload", cell, "--seed", "3",
+                "--seconds", "2", "--trace", "1", "--tiny")
+        return results[cell]
+    return of
+
+
+@pytest.mark.parametrize("cell,name", [
+    (w["name"], name) for w in BENCH["workloads"]
+    for name in _listed(w["name"])])
+def test_traced_rehearsal_reports_a_shared_entry_in_each_cell_it_lists(
+        traced_rehearsal, cell, name):
+    """The fold's promise: a cell finds every entry that lists it, under
+    the entry's one name, and reports what its readers can read."""
+    result = traced_rehearsal(cell)
+    assert result and result["attempted"] > 0 and result["failed"] == 0
+    reported = set(result["rehearsal"])
+    assert reported <= set(_listed(cell))
+    assert (name in reported) == (name in TINY_REPORTS[cell]), sorted(reported)
+
+
 def test_without_a_tpu_there_is_no_result():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(

@@ -231,13 +231,20 @@ def test_the_loop_replayed_on_the_host_counts_tokens_as_the_cell_does():
     times, and as many chunk-ticks as the prompts have chunks."""
     from perfbench import simulate_closed_loop
 
-    cell = harness.load_cell(CELL)
+    pinned = harness.load_cell(CELL)
+    cell = dataclasses.replace(pinned, traffic={
+        k: v for k, v in pinned.traffic.items() if k != "order_seed"})
     out = simulate_closed_loop.simulate(cell, 3000000311, 40.0, 0.0205, 0.0275)
     assert out["serve_tokens_per_s"] == pytest.approx(448.5)
     assert out["requests_returned_in_window"] == 67
     # twice the window at the same times holds about twice the requests
     twice = simulate_closed_loop.simulate(cell, 3000000311, 80.0, 0.0205, 0.0275)
     assert 1.8 < twice["requests_returned_in_window"] / 67 < 2.2
+    # the order the traffic file pins since PR 37 gives every seed one
+    # number (the times of PR 34's traced run: PERF.md section 6)
+    rates = [simulate_closed_loop.simulate(pinned, seed, 40.0, 0.015, 0.02693)[
+        "serve_tokens_per_s"] for seed in (3000000311, 5)]
+    assert rates == [pytest.approx(477.925)] * 2
 
 
 def test_the_reference_sums_the_experts_it_is_given_at_the_positions_compared(tiny):

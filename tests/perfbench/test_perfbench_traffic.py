@@ -71,6 +71,31 @@ def test_client_stream_depends_on_seed_and_client_only(name):
     assert head(3, 0) != head(4, 0)
 
 
+@pytest.mark.parametrize("name", CLOSED_LOOP)
+def test_order_seed_pins_tenants_and_lengths_and_leaves_the_seed_the_tokens(
+        name):
+    """Where a window holds a few dozen long requests their ORDER alone
+    moves what it completes, so such a file pins it; every other file's
+    streams are what they were without the key."""
+    job = _load(name)
+
+    def head(job, seed, client, n=9):
+        stream = traffic.client_stream(job, seed, client, 50304)
+        return [next(stream) for _ in range(n)]
+
+    def sizes(requests):
+        return [(r.tenant, len(r.prompt), r.max_new_tokens) for r in requests]
+
+    pinned = dict(job, order_seed=job.get("order_seed", 5))
+    a, b = head(pinned, 3, 1), head(pinned, 4, 1)
+    assert sizes(a) == sizes(b) != sizes(head(pinned, 3, 2))
+    assert sizes(a) != sizes(head(dict(pinned, order_seed=6), 3, 1))
+    assert not any(np.array_equal(x.prompt, y.prompt) for x, y in zip(a, b))
+    free = {k: v for k, v in job.items() if k != "order_seed"}
+    assert sizes(head(free, 3, 1)) != sizes(head(free, 4, 1))
+    assert ("order_seed" in job) == (name == "longdoc-gen")
+
+
 def test_lengths_follow_their_spec():
     rng = np.random.default_rng(0)
     spec = {"dist": "lognormal", "median": 256, "sigma": 0.6, "min": 64, "max": 768}
