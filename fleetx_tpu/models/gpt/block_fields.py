@@ -3,7 +3,8 @@ grouped-query heads, a head size of its own, window and full attention
 layers mixed, rotary and position-free layers mixed, a router that reads
 the block's input; and for a stack whose layers do not even hold the same
 parameters (``layer_types``: gated short-convolution layers beside attention
-layers, dense feed-forward layers before expert layers, a sigmoid router
+layers, Mamba-1 selective-scan layers beside attention layers, dense
+feed-forward layers before expert layers or throughout, a sigmoid router
 with a selection bias). ``GPTConfig`` inherits them, ``check`` is the part of
 its ``__post_init__`` that refuses what nobody wrote, ``layer_class`` picks
 the layer that runs the first group (``models/gpt/hybrid.py``) and
@@ -26,7 +27,7 @@ __all__ = ["BlockLayoutFields", "LAYOUT_FIELDS", "LAYER_TYPES", "check",
 # a module attribute and has to hash)
 LAYOUT_FIELDS = ("rope_layout", "sliding_window_layout", "layer_types")
 # the operators a layer of ``layer_types`` can name, under the source's names
-LAYER_TYPES = ("conv", "full_attention")
+LAYER_TYPES = ("conv", "mamba", "full_attention")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,11 +59,20 @@ class BlockLayoutFields:
     # "conv" is the gated short convolution ``out((C * conv(B * u)))`` with
     # ``B, C, u = split(in(x), 3)`` and a causal depthwise filter of
     # ``conv_L_cache`` taps, whose state is the last ``conv_L_cache - 1``
-    # rows of ``B * u``; "full_attention" the grouped attention of hybrid.py
+    # rows of ``B * u``; "full_attention" the grouped attention of hybrid.py;
+    # "mamba" the Mamba-1 selective scan (mixed_stack.MambaMixer): inner
+    # width ``mamba_expand * hidden_size``, a state of ``mamba_d_state`` a
+    # channel held in float32, a causal depthwise filter of
+    # ``mamba_d_conv`` taps, ``dt`` of rank ``mamba_dt_rank``
     layer_types: Optional[Tuple[str, ...]] = None
     conv_L_cache: int = 3
+    mamba_expand: int = 2
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_dt_rank: Optional[int] = None
     # the first ``num_dense_layers`` layers take the dense MLP, of width
     # ``dense_ffn_hidden_size``; the others experts of ``ffn_hidden_size``
+    # (``num_dense_layers == num_layers``: no expert layer at all)
     num_dense_layers: int = 0
     dense_ffn_hidden_size: Optional[int] = None
     # what ``qk_norm`` normalises: the whole projection (all heads, one
@@ -98,12 +108,19 @@ class BlockLayoutFields:
 
     @property
     def state_kinds(self) -> Tuple[str, ...]:
-        """What a lane keeps in the page pool: keys and values ("kv") in
+        """What a lane keeps: in the page pool, keys and values ("kv") in
         every attention layer and, in a gated short-convolution layer, the
-        operator's last inputs ("conv")."""
+        operator's last inputs ("conv"); once a lane and outside the pool,
+        a selective-scan layer's state ("ssm")."""
         kinds = set(self.layer_types or ("full_attention",))
-        return tuple(name for name, kind in (("kv", "full_attention"),
-                                             ("conv", "conv")) if kind in kinds)
+        return tuple(name for name, kind in (
+            ("kv", "full_attention"), ("conv", "conv"), ("ssm", "mamba"))
+            if kind in kinds)
+
+    @property
+    def mamba_inner(self) -> int:
+        """A selective-scan layer's inner width."""
+        return self.mamba_expand * self.hidden_size
 
     @property
     def window_layers(self) -> Tuple[int, ...]:
@@ -214,6 +231,11 @@ def _check_mixed(cfg) -> None:
     if cfg.conv_L_cache < 2:
         raise ValueError(f"conv_L_cache {cfg.conv_L_cache}: a short "
                          "convolution has at least 2 taps")
+    if min(cfg.mamba_expand, cfg.mamba_d_state) < 1 or cfg.mamba_d_conv < 2:
+        raise ValueError(
+            f"mamba_expand {cfg.mamba_expand}, mamba_d_state "
+            f"{cfg.mamba_d_state}, mamba_d_conv {cfg.mamba_d_conv}: widths "
+            "of at least 1 and a filter of at least 2 taps")
     if not cfg.layer_types:
         if cfg.num_dense_layers or cfg.dense_ffn_hidden_size:
             raise ValueError("num_dense_layers / dense_ffn_hidden_size "
@@ -231,12 +253,26 @@ def _check_mixed(cfg) -> None:
         raise NotImplementedError(
             "layer_types with mlp_act other than swiglu, with biases or "
             "without rmsnorm: no test covers it")
+    if "mamba" in cfg.layer_types:
+        if "conv" in cfg.layer_types:
+            raise NotImplementedError(
+                "layer_types with conv AND mamba layers: no test covers a "
+                "stack with both recurrent operators")
+        if not cfg.mamba_dt_rank:
+            raise ValueError("layer_types with mamba layers needs "
+                             "mamba_dt_rank (the source states it)")
     for field, why in (("sliding_window", "window layers"),
-                       ("rope_layout", "position-free layers"),
                        ("use_recompute", "training this stack (ROADMAP R5)")):
         if getattr(cfg, field):
             raise NotImplementedError(f"{field} with layer_types: {why} in "
                                       "a stack of mixed operators")
+    if cfg.rope_layout and any(cfg.rope_layout):
+        # all zeros is the one layout taken: NO layer rotates (a model
+        # without positions of any kind, under position_embedding: rope,
+        # which also keeps the learned position table out of the tree)
+        raise NotImplementedError(
+            "rope_layout with layer_types: rotating some layers and not "
+            "others in a stack of mixed operators (all 0: none rotates)")
     if cfg.router_input != "mlp_norm":
         raise NotImplementedError("router_input with layer_types")
 

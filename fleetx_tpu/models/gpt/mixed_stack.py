@@ -1,33 +1,39 @@
 """The decoder stack for layers that do not hold the same parameters
 (``layer_types``, ``models/gpt/block_fields.py``): gated short-convolution
-layers beside grouped-query attention layers, dense feed-forward layers
-before expert layers.
+layers or Mamba-1 selective-scan layers beside grouped-query attention
+layers, dense feed-forward layers before expert layers or throughout.
 
 **The form.** ``model.GPTModel._decoder_stack`` scans one flax body over
 layers that share one parameter tree; here a convolution layer holds ``[h,
-3h] + [h, L] + [h, h]``, an attention layer four projections and two ``[head]``
-norms, a dense layer a wide MLP and an expert layer a router and its
-experts. The parameters are therefore STACKED BY KIND (``conv``,
-``attention``, ``dense``, ``experts``: each the kind's own module's tree
-with the kind's layers as the leading axis), and ONE ``lax.scan`` body runs
-every layer: it looks the layer's two kinds and its place in each kind's
-stack up in constants of the program, and a ``lax.cond`` picks the operator
-and another the feed-forward part. So the program holds one body with each
-kind in it once, whatever the depth and whatever the order of the kinds
-(the published list is not periodic at its end); no layer carries the other
-kind's parameters (a tree with both operators in every layer would hold a
-dead third of the operators' weights); and the expert kernels index the
-experts' own stack, which skips the dense layers. Stacks of the two leading
-layers and of the rest would have compiled two loops and fixed where the
-dense layers stand. The kinds' modules are the ones the other stacks use
+3h] + [h, L] + [h, h]``, a selective-scan layer two wide projections, a
+filter, the low-rank ``dt`` path, ``A`` and ``D``, an attention layer four
+projections (and two ``[head]`` norms), a dense layer a wide MLP and an
+expert layer a router and its experts. The parameters are therefore STACKED
+BY KIND (the operators ``conv``, ``mamba``, ``attention``; the feed-forward
+parts ``dense``, ``experts``: each the kind's own module's tree with the
+kind's layers as the leading axis), and ONE ``lax.scan`` body runs every
+layer: it looks the layer's two kinds and its place in each kind's stack up
+in constants of the program, and a ``lax.cond`` picks the operator and
+another the feed-forward part (a stack holds ONE recurrent kind beside
+attention: ``block_fields.check``; a kind that the configuration lacks is
+in no conditional). So the program holds one body with each kind in it
+once, whatever the depth and whatever the order of the kinds (a published
+list need not be periodic); no layer carries another kind's parameters (a
+tree with both operators in every layer would hold a dead third of the
+operators' weights); and the expert kernels index the experts' own stack,
+which skips the dense layers. Stacks of the leading layers and of the rest
+would have compiled two loops and fixed where the dense layers stand. The
+kinds' modules are the ones the other stacks use
 (``hybrid.HybridSelfAttention``, ``model.MLP``, ``parallel/moe.py``
 ``DroplessMoEMLP``), applied to their slice of the stack.
 
-**State.** A lane keeps two kinds of state in ONE page pool under one block
-table (``serving/cache_manager.py``): keys and values in the attention
-layers (``cached_key`` / ``cached_value``: the flat pool of ``hybrid.py``,
-counted over the attention layers alone), and in every convolution layer
-the operator's last ``L - 1`` inputs ``z = B * u`` (``conv_state``). The
+**State.** A lane's state has TWO HOMES, by what the state costs.
+
+*In the page pool*, under the lane's block table (``serving/
+cache_manager.py``): keys and values in the attention layers
+(``cached_key`` / ``cached_value``: the flat pool of ``hybrid.py``, counted
+over the attention layers alone), and in every convolution layer the
+operator's last ``L - 1`` inputs ``z = B * u`` (``conv_state``). The
 convolution layers own TAIL PAGES: page ``p`` of the pool has, in every
 convolution layer, ``L - 1`` rows, and ``z`` of position ``t`` is kept in row
 ``t % (L - 1)`` of the page that holds position ``t``. A call reads the
@@ -38,19 +44,37 @@ the page's end, and keeps it as long as the page lives: a prompt that
 matches a prefix up to any page boundary starts every convolution layer
 from what a prefill from the start would hold there, with nothing copied
 and no other bookkeeping than the page's own (its refcount, its parking,
-its eviction). A separate state of the lane plus snapshots copied into the
-pages at registration would have needed a copy program, a lane install
-and a second lifecycle to keep in step with the first. The rows are written
-before they are read, as keys and values are, so a recycled page needs no
-zeroing; a call's rows that are no tokens (a padded bucket's tail, a lane
-that is not decoding) write nothing: the call is handed which rows are
-tokens, as its ``attn_mask`` ``[batch, rows]``.
+its eviction). That is affordable because the state is small: 8 KB a page
+and layer beside 32 KB of keys and values.
+
+*Once a lane*, outside the pool: a selective-scan layer's state, ``h``
+``[d_state, inner]`` float32 and the filter's last ``d_conv - 1`` inputs.
+At the widths served that is 358,400 bytes a layer: kept a page it would
+be 9.3 MB for every 16 tokens over 26 layers, so it is kept where the lane
+is: the leaves ``ssm_state`` ``[mamba layers, lanes, d_state, inner]`` and
+``ssm_conv`` ``[mamba layers, lanes, (d_conv - 1) x inner]`` are indexed by the
+lane, which a call is told as column 0 of its block table (the engine's
+tables for such a model: the address of a lane's state beside the addresses
+of its pages). A call that starts at position 0 starts from zero, whatever
+the lane held (no program resets a lane); a later chunk of a prefill and a
+decode tick start from what the lane holds and write it back in place (the
+tick through ``ops/pallas/ssm_scan.py``'s step kernel, which aliases the
+whole leaf). Nothing outlives the lane's request: a prefix hit would need
+the state as it stood at the match's end, a snapshot this stack does not
+take (ROADMAP R5), so the engine refuses prefix reuse for it.
+
+In both homes the rows are written before they are read (or begun from
+zero), so a recycled page or lane needs no zeroing, and a call's rows that
+are no tokens (a padded bucket's tail, a lane that is not decoding) change
+nothing: the call is handed which rows are tokens, as its ``attn_mask``
+``[batch, rows]``.
 
 Forward only: training this stack is ROADMAP R5.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 
 import jax
@@ -70,15 +94,19 @@ from fleetx_tpu.models.gpt.model import (
     _dense,
 )
 
-__all__ = ["MixedStack", "ShortConv", "layer_plan", "state_rows"]
+__all__ = ["MambaMixer", "MixedStack", "ShortConv", "layer_plan",
+           "state_rows"]
 
 
 def layer_plan(cfg: GPTConfig) -> dict:
     """Each layer's two kinds and its place among its kind, as arrays of
-    ``num_layers`` entries: ``attention`` (1: attention, 0: convolution),
-    ``operator_index``, ``experts`` (1: expert layer, 0: dense),
-    ``ffn_index``; and the four counts."""
+    ``num_layers`` entries: ``attention`` (1: attention, 0: the stack's
+    recurrent kind), ``operator_index``, ``experts`` (1: expert layer, 0:
+    dense), ``ffn_index``; the counts of every kind; and ``recurrent``, the
+    name of the recurrent kind ("conv" | "mamba", None without one)."""
     attention = np.asarray([t == "full_attention" for t in cfg.layer_types])
+    recurrent = next((t for t in ("mamba", "conv") if t in cfg.layer_types),
+                     None)
     experts = np.arange(cfg.num_layers) >= cfg.num_dense_layers
 
     def place(mask):  # the layer's index among the layers of its own kind
@@ -88,7 +116,9 @@ def layer_plan(cfg: GPTConfig) -> dict:
             "operator_index": place(attention).astype(np.int32),
             "experts": experts.astype(np.int32),
             "ffn_index": place(experts).astype(np.int32),
-            "counts": {"conv": int((~attention).sum()),
+            "recurrent": recurrent,
+            "counts": {"conv": cfg.layer_types.count("conv"),
+                       "mamba": cfg.layer_types.count("mamba"),
                        "attention": int(attention.sum()),
                        "dense": int((~experts).sum()),
                        "experts": int(experts.sum())}}
@@ -139,6 +169,146 @@ class ShortConv(nn.Module):
                       dtype=cfg.dtype)(y), z
 
 
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """``dt``'s bias as the family draws it: the inverse softplus of a step
+    drawn log-uniform in [1e-3, 1e-1], so that a fresh layer forgets over
+    tens to thousands of positions."""
+    step = jnp.exp(jax.random.uniform(key, shape, dtype)
+                   * (np.log(1e-1) - np.log(1e-3)) + np.log(1e-3))
+    return step + jnp.log(-jnp.expm1(-step))
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """``A = -(1 .. d_state)`` in every channel (S4D-real), as its log;
+    ``[d_state, inner]``: the layout of ``ops/pallas/ssm_scan.py``."""
+    del key
+    return jnp.broadcast_to(jnp.log(jnp.arange(
+        1, shape[0] + 1, dtype=dtype))[:, None], shape)
+
+
+def _inner_norm(cfg: GPTConfig, name: str):
+    """Jamba's norm of ``dt``'s low-rank input, of ``B`` and of ``C``."""
+    return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
+                      param_dtype=jnp.float32, name=name)
+
+
+def _gated(y, u, z, skip):
+    """``(y + D * u) * silu(z)``, float32."""
+    return (y + skip * u) * nn.silu(z.astype(jnp.float32))
+
+
+def _begins(wpos):
+    """The lanes ``[b]`` whose call begins a sequence: their lane-resident
+    state is read as zero, whatever the lane holds."""
+    return wpos == 0
+
+
+@contextlib.contextmanager
+def _moving_lane_state():
+    """The device scope of what moves lane-resident state outside the
+    kernels (``cache_write/ssm_state``)."""
+    with jax.named_scope("cache_write"), jax.named_scope("ssm_state"):
+        yield
+
+
+def _state_rows(rows):
+    """The rows ``[b, s]`` of a call that advance the lane-resident state:
+    those that are tokens."""
+    return rows
+
+
+class MambaMixer(nn.Module):
+    """The Mamba-1 mixer with Jamba's inner norms: ``[u, z] = split(in_proj
+    (a))``; ``u = silu(conv(u))`` (depthwise, causal, ``mamba_d_conv`` taps,
+    with bias); ``[r, B, C] = split(x_proj(u))``, each through an RMSNorm
+    with a learned weight; ``dt = softplus(dt_proj(r))``; ``A = -exp
+    (A_log)``; the scan (``ops/pallas/ssm_scan.py``); ``out_proj((y + D * u)
+    * silu(z))``. ``u`` from the filter on, ``dt``, ``B``, ``C``, the scan
+    and ``y`` are float32; the two small projections take float32 inputs at
+    the backend's default matmul precision.
+
+    In two phases for a caller that keeps the state itself (the layer loop:
+    the scan and the state's writes stand outside its conditionals):
+    ``phase="project"`` takes ``a`` and ``conv_rows`` ``[b, (d_conv - 1) x
+    inner]`` (the filter's inputs at the positions before the call's first,
+    side by side as the lanes' leaf holds them) and returns what the scan
+    takes and the filter's inputs the caller keeps: of a call of several
+    rows ``xs`` ``[b, d_conv - 1 + s, inner]``, those of the state and the
+    call together; of a call of ONE row ``tail``, the rows held after it,
+    side by side again (a one-row call never leaves that form: slices and
+    joins along the last axis are free, a ``[lanes, 3, inner]`` view is a
+    re-layout of 8 MB a layer and tick). ``phase="finish"`` takes that
+    dictionary with the scan's ``y`` in it. Without a phase: every position
+    at once from a zero state."""
+
+    cfg: GPTConfig
+
+    @nn.compact
+    def __call__(self, a, conv_rows=None, *, phase=None):
+        cfg = self.cfg
+        d, n, rank, taps = (cfg.mamba_inner, cfg.mamba_d_state,
+                            cfg.mamba_dt_rank, cfg.mamba_d_conv)
+        if phase == "finish":
+            return self._finish(a)
+        b, s = a.shape[:2]
+        uz = _dense(2 * d, ("embed", "mlp"), "in_proj", use_bias=False,
+                    dtype=cfg.dtype)(a)
+        u, z = jnp.split(uz, 2, axis=-1)
+        w = self.param("conv_kernel", nn.with_logical_partitioning(
+            _torch_conv_init, ("mlp", None)), (d, taps), jnp.float32)
+        bias = self.param("conv_bias", nn.with_logical_partitioning(
+            lambda key, shape, dtype: _torch_conv_init(
+                key, shape + (taps,), dtype)[:, 0], ("mlp",)),
+            (d,), jnp.float32)
+        if conv_rows is None:
+            conv_rows = jnp.zeros((b, (taps - 1) * d), uz.dtype)
+        conv_rows = conv_rows.astype(uz.dtype)
+        if s == 1:
+            kept = {"tail": jnp.concatenate([conv_rows[:, d:], u[:, 0]], -1)}
+            taken = [conv_rows[:, None, i * d:(i + 1) * d]
+                     for i in range(taps - 1)] + [u]
+        else:
+            xs = jnp.concatenate([conv_rows.reshape(b, taps - 1, d), u], 1)
+            kept = {"xs": xs}
+            taken = [xs[:, i:i + s] for i in range(taps)]
+        u = nn.silu(bias.astype(jnp.float32) + sum(
+            w[:, i].astype(jnp.float32) * t.astype(jnp.float32)
+            for i, t in enumerate(taken)))
+        r, b_in, c_in = jnp.split(
+            _dense(rank + 2 * n, ("mlp", None), "x_proj", use_bias=False,
+                   dtype=jnp.float32)(u), (rank, rank + n), axis=-1)
+        r, b_in, c_in = (_inner_norm(cfg, name)(t) for name, t in (
+            ("dt_norm", r), ("b_norm", b_in), ("c_norm", c_in)))
+        dt = jax.nn.softplus(nn.DenseGeneral(
+            d, dtype=jnp.float32, param_dtype=jnp.float32,
+            kernel_init=nn.with_logical_partitioning(
+                nn.initializers.normal(rank ** -0.5), (None, "mlp")),
+            bias_init=nn.with_logical_partitioning(_dt_bias_init, ("mlp",)),
+            name="dt_proj")(r))
+        a_log = self.param("A_log", nn.with_logical_partitioning(
+            _a_log_init, (None, "mlp")), (n, d), jnp.float32)
+        skip = self.param("D", nn.with_logical_partitioning(
+            nn.initializers.ones_init(), ("mlp",)), (d,), jnp.float32)
+        # (a served tree may hold every leaf in the compute dtype)
+        mixed = {"u": u, "dt": dt, "B": b_in, "C": c_in, "z": z, **kept,
+                 "A": -jnp.exp(a_log.astype(jnp.float32)),
+                 "D": skip.astype(jnp.float32)}
+        if phase == "project":
+            return mixed
+        from fleetx_tpu.ops.pallas.ssm_scan import selective_scan_plain
+
+        mixed["y"] = selective_scan_plain(
+            u, dt, mixed["A"], b_in, c_in, jnp.zeros((b, n, d)))[0]
+        return self._finish(mixed)
+
+    def _finish(self, mixed):
+        cfg = self.cfg
+        y = _gated(mixed["y"], mixed["u"], mixed["z"],
+                   mixed["D"]).astype(cfg.dtype)
+        return _dense(cfg.hidden_size, ("mlp", "embed"), "out_proj",
+                      use_bias=False, dtype=cfg.dtype)(y)
+
+
 def _stacked(module, count: int, rng, *example, **kwargs):
     """``module``'s parameter tree drawn ``count`` times, stacked along a
     new leading axis (plain arrays: the kinds' own partitioning boxes name
@@ -181,6 +351,7 @@ class MixedStack(nn.Module):
             "conv": (ShortConv(cfg, parent=None),
                      (x, jnp.zeros((1, state_rows(cfg), cfg.hidden_size),
                                    cfg.dtype)), {}),
+            "mamba": (MambaMixer(cfg, parent=None), (x,), {}),
             "attention": (HybridSelfAttention(cfg, parent=None), (x,),
                           {"layer_index": jnp.int32(0), "rope": rope}),
             "dense": (MLP(dense_cfg, parent=None), (x,), {}),
@@ -201,7 +372,7 @@ class MixedStack(nn.Module):
                 name, lambda rng, m=module, n=count, a=args, k=kwargs: {
                     "norm": _stacked(_norm(cfg), n, rng, a[0]),
                     "op": _stacked(m, n, rng, *a, **k)})
-        cache = self._cache(decode, plan)
+        cache = self._cache(decode, plan, lanes=x.shape[0])
         if decode and cache is not None and (cache_positions is None
                                              or block_tables is None):
             raise ValueError("a paged decode cache needs cache_positions AND "
@@ -212,11 +383,12 @@ class MixedStack(nn.Module):
             deterministic=deterministic, cache_positions=cache_positions,
             block_tables=block_tables, rope=rope)
 
-    def _cache(self, decode: bool, plan: dict):
+    def _cache(self, decode: bool, plan: dict, lanes: int):
         """The cache collection's variables (None outside a cached forward
         and at its init, which only declares them): the attention layers'
         flat pool (``hybrid.init_cache`` sizes it), the convolution layers'
-        tail pages, the expert layers' counters."""
+        tail pages or the selective-scan layers' state of every lane (the
+        init's batch is the lanes), the expert layers' counters."""
         cfg = self.cfg
         if not decode:
             return None
@@ -238,15 +410,30 @@ class MixedStack(nn.Module):
                 "cache", "cached_key", jnp.zeros, (1, ps, width), cfg.dtype),
             "cached_value": self.variable(
                 "cache", "cached_value", jnp.zeros, (1, ps, width), cfg.dtype),
-            "conv_state": self.variable(
+        }
+        if counts["mamba"]:
+            # [d_state, inner] and [lanes, rows x inner]: no axis of 16 or of
+            # 3 in the last two places, which the device's tiles would pad;
+            # a lane's filter rows lie side by side in ONE row of the leaf,
+            # which a tick takes whole and a one-lane call slices out
+            n, d = counts["mamba"], cfg.mamba_inner
+            held["ssm_state"] = self.variable(
+                "cache", "ssm_state", jnp.zeros,
+                (n, lanes, cfg.mamba_d_state, d), jnp.float32)
+            held["ssm_conv"] = self.variable(
+                "cache", "ssm_conv", jnp.zeros,
+                (n, lanes, (cfg.mamba_d_conv - 1) * d), cfg.dtype)
+        else:
+            held["conv_state"] = self.variable(
                 "cache", "conv_state", jnp.zeros,
                 (max(counts["conv"], 1) * cfg.decode_num_pages, rows,
-                 cfg.hidden_size), cfg.dtype),
+                 cfg.hidden_size), cfg.dtype)
+        held.update({
             "moe_stats": self.variable(
                 "cache", "moe_stats", jnp.zeros,
                 (max(counts["experts"], 1), 2 * len(MOE_STATS) * 2),
                 jnp.uint32),
-        }
+        })
         return None if fresh else held
 
     def _decoder_stack(self, x, params, cache, plan, kinds, *, rows, key_mask,
@@ -260,14 +447,33 @@ class MixedStack(nn.Module):
         pools = {k: v.value for k, v in cache.items()} if cached else {}
         b, s, h = x.shape
         state_shape = (b, state_rows(cfg), h)
+        recurrent = plan["recurrent"]
         if cached:
             tables = block_tables.astype(jnp.int32)
             wpos = cache_positions.astype(jnp.int32)
             rows = (jnp.ones((b, s), bool) if rows is None
                     else rows.astype(bool))
+            if recurrent == "mamba":
+                # column 0: the lane, where its state is held (module
+                # docstring); a tick is handed every lane in order
+                lanes, tables = tables[:, 0], tables[:, 1:]
+                tick = s == 1 and b == pools["ssm_state"].shape[1]
+                if not tick and b != 1:
+                    raise NotImplementedError(
+                        "state held once a lane takes a tick over every lane "
+                        f"in order or a call of ONE lane, not {b} of "
+                        f"{pools['ssm_state'].shape[1]} lanes")
+                begins, advancing = _begins(wpos), _state_rows(rows)
         norm = _norm(cfg)
         conv_op, attn_op = kinds["conv"][0], kinds["attention"][0]
-        both = (counts["attention"], counts["conv"])
+        mamba_op = kinds["mamba"][0]
+
+        def zeros_like_of(fn):
+            """What ``fn()`` returns, as zeros: the other branch's share of
+            a conditional's result."""
+            return jax.tree.map(lambda t: jnp.zeros(t.shape, t.dtype),
+                                jax.eval_shape(fn))
+        both = (counts["attention"], counts[recurrent] if recurrent else 0)
 
         def normed(kind, index, value):
             return norm.apply({"params": _at(params[kind]["norm"], index)},
@@ -295,6 +501,90 @@ class MixedStack(nn.Module):
                     {"params": _at(params["conv"]["op"], index)}, a, state)
             return y, z[:, state_shape[1]:]
 
+        def mamba(value, index, held=None):
+            """The mixer's projections (``MambaMixer`` phase "project") from
+            the filter rows ``held`` ``[b, (taps - 1) x d]`` (None: zeros);
+            ``dt`` zero in the rows that are no tokens, which then leave
+            ``h`` alone."""
+            a = normed("mamba", index, value)
+            with jax.named_scope("ssm_mix"):
+                mixed = mamba_op.apply(
+                    {"params": _at(params["mamba"]["op"], index)}, a, held,
+                    phase="project")
+            if held is not None:
+                mixed["dt"] = jnp.where(advancing[..., None], mixed["dt"],
+                                        0.0)
+            return mixed
+
+        def mamba_finish(mixed, index):
+            with jax.named_scope("ssm_mix"):
+                return mamba_op.apply(
+                    {"params": _at(params["mamba"]["op"], index)}, mixed,
+                    phase="finish")
+
+        def scan(mixed, h0, skip=None):
+            from fleetx_tpu.ops.pallas.ssm_scan import selective_scan
+
+            with jax.named_scope("ssm_mix"), jax.named_scope("ssm_scan"):
+                return selective_scan(
+                    mixed["u"], mixed["dt"], mixed["A"], mixed["B"],
+                    mixed["C"], h0, skip=skip,
+                    kernel=cfg.use_flash_attention)
+
+        # the lane-resident leaves are read and written by SLICES (a tick:
+        # one layer of every lane; a call of one lane: that lane of one
+        # layer), never gathered: an index vector over the lanes made XLA
+        # re-lay the whole ``ssm_conv`` leaf out at each end of a prefill
+        def filter_rows(conv_pool, index):
+            """The filter rows the call's lanes hold, side by side ``[b,
+            (taps - 1) x d]``."""
+            with _moving_lane_state():
+                return conv_pool[index] if tick else (
+                    jax.lax.dynamic_slice_in_dim(conv_pool[index], lanes[0],
+                                                 1, axis=0))
+
+        def ssm_update(pools, mixed, held, mixes, index):
+            """``y`` of the call's rows; ``pools`` (the caller's own dict)
+            takes the leaves with the lanes' state advanced over them, in
+            place. A layer of another kind (``mixes``) changes nothing: its
+            ``dt`` is zero, so ``h`` stays, and the filter rows written are
+            the ones held."""
+            from fleetx_tpu.ops.pallas.ssm_scan import selective_step
+
+            state, fresh = pools["ssm_state"], begins & ~mixes
+            if tick:
+                with jax.named_scope("ssm_mix"), jax.named_scope("ssm_step"):
+                    y, state = selective_step(
+                        state, index, mixed["u"][:, 0], mixed["dt"][:, 0],
+                        mixed["A"], mixed["B"][:, 0], mixed["C"][:, 0],
+                        fresh, kernel=cfg.use_flash_attention)
+                    y = y[:, None]
+            else:
+                at = (index, lanes[0], 0, 0)
+                with _moving_lane_state():
+                    h0 = jnp.where(fresh[:, None, None], 0.0,
+                                   jax.lax.dynamic_slice(
+                                       state, at, (1, 1) + state.shape[2:])[0])
+                y, h = scan(mixed, h0, skip=mixes)
+                with _moving_lane_state():
+                    state = jax.lax.dynamic_update_slice(state, h[None], at)
+            pools["ssm_state"] = state
+            with _moving_lane_state():
+                # the filter's inputs at the last positions that are tokens:
+                # a lane with no token keeps the rows it held (``xs`` begins
+                # with them)
+                if s == 1:
+                    last = jnp.where(advancing, mixed["tail"], held)
+                else:
+                    last = jax.lax.dynamic_slice_in_dim(
+                        mixed["xs"], advancing.sum().astype(jnp.int32),
+                        cfg.mamba_d_conv - 1, axis=1)
+                last = jnp.where(mixes, held, last.reshape(b, -1))[None]
+                pools["ssm_conv"] = jax.lax.dynamic_update_slice(
+                    pools["ssm_conv"], last.astype(pools["ssm_conv"].dtype),
+                    (index, 0 if tick else lanes[0], 0))
+            return y
+
         def attention(value, index, *args, **kwargs):
             return attn_op.apply(
                 {"params": _at(params["attention"]["op"], index),
@@ -302,56 +592,85 @@ class MixedStack(nn.Module):
                 deterministic=deterministic, layer_index=index, **kwargs)
 
         def operator(value, mixes, index, pools):
-            """The operator over the pool, in three steps: a conditional
-            that computes (the convolution whole, reading its state; the
-            attention's queries, keys and values), the writes of BOTH kinds
-            of state outside every conditional (the other kind's rows write
-            nothing), and a conditional that attends. A pool that a
-            conditional hands back is copied whole by XLA: 1.3 GB in every
-            attention layer at the served sizes."""
-            heads = (b, s, cfg.num_attention_heads, cfg.head_dim)
-            kv = (b, s, cfg.kv_heads * cfg.head_dim)
+            """The operator over the cache, in three steps: a conditional
+            that computes (the recurrent operator up to its state, reading
+            what it starts from; the attention's queries, keys and values),
+            the writes of BOTH kinds of state outside every conditional (the
+            other kind's rows write nothing), and a conditional that
+            finishes (attends; the mixer's gate and output projection). A
+            pool that a conditional hands back is copied whole by XLA: 1.3
+            GB in every attention layer at the served sizes."""
+            held = (filter_rows(pools["ssm_conv"], index)
+                    if recurrent == "mamba" else None)
 
-            def conv_step():
-                y, z = conv(value, index, pools["conv_state"])
-                return (y, z, jnp.zeros(heads, cfg.dtype),
-                        jnp.zeros(kv, cfg.dtype), jnp.zeros(kv, cfg.dtype))
+            def recur():
+                if recurrent == "conv":
+                    return dict(zip(("y", "z"),
+                                    conv(value, index, pools["conv_state"])))
+                # (a lane that begins a sequence begins from zeros)
+                return mamba(value, index,
+                             jnp.where(begins[:, None], 0, held))
+
+            def project():
+                return dict(zip("qkv", attention(
+                    normed("attention", index, value), index, rope=rope,
+                    phase="project")))
+
+            def recur_step():
+                return recur(), zeros_like_of(project)
 
             def project_step():
-                return (jnp.zeros_like(value), jnp.zeros_like(value),
-                        *attention(normed("attention", index, value), index,
-                                   rope=rope, phase="project"))
+                return zeros_like_of(recur), project()
 
-            y, z, q, k, v = pick(mixes, both, project_step, conv_step)
+            if not counts["attention"]:
+                mixed, qkv = recur(), None
+            elif not recurrent:
+                mixed, qkv = None, project()
+            else:
+                mixed, qkv = jax.lax.cond(mixes, project_step, recur_step)
             pools = dict(pools)
-            if counts["conv"]:
+            if recurrent == "conv":
                 with jax.named_scope("cache_write"), \
                         jax.named_scope("conv_state"):
                     pools["conv_state"] = _write_state(
                         cfg, pools["conv_state"], tables, wpos,
-                        rows & ~mixes, index, z)
+                        rows & ~mixes, index, mixed["z"])
+            elif recurrent == "mamba":
+                mixed["y"] = ssm_update(pools, mixed, held, mixes, index)
             if counts["attention"]:
                 pools["cached_key"], pools["cached_value"] = write_rows(
                     cfg, pools["cached_key"], pools["cached_value"],
-                    tables + jnp.asarray(layer_bases(cfg))[index], wpos, k, v,
-                    keep=mixes)
+                    tables + jnp.asarray(layer_bases(cfg))[index], wpos,
+                    qkv["k"], qkv["v"], keep=mixes)
 
             def attend_step():
                 return attention(
-                    q, index, decode=True, cache_positions=wpos,
+                    qkv["q"], index, decode=True, cache_positions=wpos,
                     block_tables=tables, phase="attend", mutable=["cache"],
                     variables={"cache": {n: pools[n] for n in (
                         "cached_key", "cached_value")}})[0]
 
-            return pick(mixes, both, attend_step, lambda: y), pools
+            def finish_step():
+                if recurrent == "conv":
+                    return mixed["y"]
+                return mamba_finish(mixed, index)
+
+            return pick(mixes, both, attend_step, finish_step), pools
 
         def plain(value, mixes, index, pools):
             """The operator outside a cache: every position at once."""
+            def recur():
+                if recurrent == "conv":
+                    return conv(value, index)[0]
+                mixed = mamba(value, index)
+                mixed["y"] = scan(mixed, jnp.zeros(
+                    (b, cfg.mamba_d_state, cfg.mamba_inner)))[0]
+                return mamba_finish(mixed, index)
+
             return pick(
                 mixes, both,
                 lambda: attention(normed("attention", index, value), index,
-                                  key_mask, rope=rope),
-                lambda: conv(value, index)[0]), pools
+                                  key_mask, rope=rope), recur), pools
 
         def dense(value, index, stats):
             y = kinds["dense"][0].apply(
@@ -359,9 +678,9 @@ class MixedStack(nn.Module):
                 normed("dense", index, value))
             # where an expert layer gives its routing a dense layer gives
             # zeros of the same shapes: the two are branches of one conditional
-            return y, stats, jax.tree.map(
-                lambda t: jnp.zeros(t.shape, t.dtype),
-                jax.eval_shape(lambda: experts(value, index, stats)[2]))
+            return y, stats, (zeros_like_of(
+                lambda: experts(value, index, stats)[2])
+                if counts["experts"] else {})
 
         def experts(value, index, stats):
             held = params["experts"]["op"]

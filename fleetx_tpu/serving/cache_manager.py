@@ -63,8 +63,9 @@ class allocates a lane's pages as its prefill chunks and decode ticks reach
 them and releases each page once every row of it lies behind the window of
 the lane's next query. Admission counts both classes.
 
-Two kinds of state (a model with gated short-convolution layers,
-``models/gpt/mixed_stack.py``): beside the keys and values of its attention
+Kinds of state, and their two homes (a model with recurrent layers,
+``models/gpt/mixed_stack.py``). IN THE POOL (gated short-convolution
+layers): beside the keys and values of its attention
 layers a lane keeps, in every convolution layer, the operator's last
 inputs. They live in TAIL PAGES of the same pool under the same block
 table: physical page ``p`` has a few rows in every convolution layer, and
@@ -78,7 +79,20 @@ its state writes to the trash page's tail, which nobody reads. The host
 tiers and the page ship between replicas do not carry tails and are
 refused for such a model at the engine's construction;
 :meth:`PagedKVCacheManager.class_counters` counts the snapshots and the
-bytes of either kind.
+bytes of either kind. ONCE A LANE (selective-scan layers, state kind
+"ssm"): a state of hundreds of kilobytes a layer cannot be kept a page, so
+the cache tree holds it in leaves indexed by the LANE (``ssm_state``,
+``ssm_conv``: ``[layers, lanes, ...]``), beside the pool and outside it.
+The manager's part is the address: :attr:`PagedKVCacheManager.tables` then
+has the lane's own index as column 0 of every row, before the pages, and
+the model reads and writes the lane's state there. Its lifecycle is the
+lane's and needs no program: a call at position 0 begins from zero (every
+:meth:`PagedKVCacheManager.alloc` is counted as ``ssm_state_resets``), a
+later chunk and a tick carry on from what the lane holds, a freed lane's
+state is dead weight until the next request begins over it. Nothing of it
+is shared: prefix reuse is refused for such a model at construction (a
+match would need the state as it stood at the match's end), as are the
+host tiers and the page ship.
 
 Two-level page cache (``FLEETX_SERVING_HOST_CACHE_BYTES``;
 docs/SERVING.md): with a :class:`HostPageStore` attached, LRU eviction
@@ -1279,6 +1293,16 @@ class PagedKVCacheManager(_LaneBook):
                 f"{cfg.decode_num_pages}, {cfg.decode_page_size}) must "
                 f"match the manager's ({cache_len}, {num_pages}, "
                 f"{page_size})")
+        # the kinds of state a lane keeps (module docstring, "Kinds of
+        # state"): keys and values, a convolution's tail rows in the pool,
+        # a selective scan's state once a lane
+        self.state_kinds = tuple(getattr(cfg, "state_kinds", ("kv",)))
+        self.lane_state = "ssm" in self.state_kinds
+        if self.lane_state and prefix_cache:
+            raise ValueError(
+                "prefix reuse over selective-scan layers: a lane's state is "
+                "kept once a lane, not at page boundaries, so a matched "
+                "prefix has no state to resume from (prefix_cache=False)")
         self._init_lanes(slots)
         self.cache_len = cache_len
         self.page_size = page_size
@@ -1310,17 +1334,20 @@ class PagedKVCacheManager(_LaneBook):
                 window_pages, page_size, slots, cache_len // page_size,
                 cfg.sliding_window, window_span)
         self.cache = init_decode_cache(model, slots)
-        # bytes one page holds of each kind of state (module docstring,
-        # "Two kinds of state"): keys and values in the attention layers,
-        # and the tail rows of a model's convolution layers
-        self.state_kinds = tuple(getattr(cfg, "state_kinds", ("kv",)))
+        # bytes one page holds of each kind of state in the pool (keys and
+        # values in the attention layers, the tail rows of a model's
+        # convolution layers), and bytes one LANE holds outside it
         self.page_bytes = {"kv": 0, "conv": 0}
+        self.lane_bytes = 0
+        self.state_resets = 0
         for path, leaf in jax.tree_util.tree_flatten_with_path(self.cache)[0]:
             name = getattr(path[-1], "key", "")
             kind = {"cached_key": "kv", "cached_value": "kv",
                     "conv_state": "conv"}.get(name)
-            if kind and "conv" in self.state_kinds:
+            if kind and self.state_kinds != ("kv",):
                 self.page_bytes[kind] += leaf_device_nbytes(leaf) // num_pages
+            elif name in ("ssm_state", "ssm_conv"):
+                self.lane_bytes += leaf_device_nbytes(leaf) // slots
 
     # ------------------------------------------------------ host spill tier
 
@@ -1427,7 +1454,13 @@ class PagedKVCacheManager(_LaneBook):
     @property
     def tables(self) -> np.ndarray:
         """Host block tables [slots, cache_len // page_size] int32; with a
-        window class ``[2, slots, ...]``, full then window."""
+        window class ``[2, slots, ...]``, full then window; for a model
+        whose state is held once a lane ``[slots, 1 + pages]``, the lane's
+        own index before its pages (module docstring, "Kinds of state")."""
+        if self.lane_state:  # column 0: where the lane's own state is held
+            return np.concatenate(
+                [np.arange(self.slots, dtype=np.int32)[:, None],
+                 self.pool.tables], axis=1)
         if self.window_pool is None:
             return self.pool.tables
         return np.stack([self.pool.tables, self.window_pool.tables])
@@ -1435,6 +1468,8 @@ class PagedKVCacheManager(_LaneBook):
     def lane_tables(self, slot: int) -> np.ndarray:
         """``slot``'s row of :attr:`tables` (of every class: ``[2, ...]``
         with a window class)."""
+        if self.lane_state:
+            return np.concatenate([[np.int32(slot)], self.pool.tables[slot]])
         if self.window_pool is None:
             return self.pool.tables[slot]
         return np.stack([self.pool.tables[slot],
@@ -1451,6 +1486,13 @@ class PagedKVCacheManager(_LaneBook):
         """The pool by class of page, for ``ServingMetrics.snapshot()``
         (through ``model_protocol.device_counters_of``); empty with one
         class."""
+        if self.lane_state:
+            return {
+                # every lane's state is resident, whoever holds the lane
+                "state_bytes_lanes": self.slots * self.lane_bytes,
+                "ssm_state_resets": self.state_resets,
+                "kv_page_bytes_in_use": (self.pool.pages_in_use
+                                         * self.page_bytes["kv"])}
         if "conv" in self.state_kinds:
             pool, tail = self.pool, self.page_bytes["conv"]
             return {
@@ -1524,6 +1566,7 @@ class PagedKVCacheManager(_LaneBook):
             return None
         claimed = self._claim_lane(request_id, len(tokens))
         assert claimed == lane  # heap head == the lane the pool filled
+        self.state_resets += self.lane_state  # its first call begins from 0
         return lane, shared
 
     def register_prefix(self, slot: int, tokens) -> None:

@@ -589,8 +589,15 @@ class ServingEngine:
             supports_mesh=mesh is not None,
             supports_roles=self.role != "both")
         # a model with recurrent state beside keys and values tells its
-        # cached forward which rows of a call are tokens (``_row_mask``)
+        # cached forward which rows of a call are tokens (``_row_mask``);
+        # state held once a lane (cache_manager.py "Kinds of state") is
+        # nothing a matched prefix could resume
         self._state_rows = self.capabilities.state_kinds != ("kv",)
+        self._lane_state = "ssm" in self.capabilities.state_kinds
+        if self._lane_state:
+            self.capabilities.require(
+                supports_prefix_cache=bool(prefix_cache))
+            prefix_cache = False
         decode_kv = "int8" if self.kv_dtype == "int8" else None
         # default pool = every lane's full capacity in pages + the
         # reserved trash page; short requests then leave pages free for
@@ -1900,8 +1907,9 @@ class ServingEngine:
         if self._state_rows:
             out["state_bytes"] = {
                 "kv": classes["kv_page_bytes_in_use"],
-                "conv": (classes["state_bytes_lanes"]
-                         + classes["state_bytes_snapshots"])}
+                "ssm" if self._lane_state else "conv": (
+                    classes["state_bytes_lanes"]
+                    + classes.get("state_bytes_snapshots", 0))}
         return out
 
     def declare_dead(self) -> None:
@@ -2162,7 +2170,8 @@ class ServingEngine:
         already in place, then samples the first token — the prefix-cache
         compute saving is exactly the skipped ``wpos`` leading tokens."""
         max_pos = self.model.cfg.max_position_embeddings
-        # one class of page [pages], two classes [2, pages]
+        # one class of page [pages], two classes [2, pages]; lane-resident
+        # state [1 + pages] (the lane before its pages)
         table_shape = self.cache_manager.lane_tables(0).shape
         n_table = int(np.prod(table_shape))
 
@@ -2257,6 +2266,18 @@ class ServingEngine:
         self.cache_manager.cache = cache
         return tok, carry_key, program
 
+    def _bucket_rows(self, n: int, shared: int) -> int:
+        """Rows of the prefill program that takes ``n`` tokens behind
+        ``shared``: ``n`` rounded up to the bucket, within the cache."""
+        bucket = -(-n // self.prefill_bucket) * self.prefill_bucket
+        return min(max(bucket, n), self.cache_len - shared)
+
+    def _scan_rows(self, n: int, shared: int) -> dict:
+        """Span field of a prefill call over lane-resident state: the rows
+        its selective scans run over, padding included."""
+        return ({"scan_rows": self._bucket_rows(n, shared)}
+                if self._lane_state else {})
+
     def _paged_prefill_call(self, req: Request, suffix, shared, lane,
                             replay: bool = False):
         """Batch-1 prefill of the non-shared ``suffix`` straight into
@@ -2268,8 +2289,7 @@ class ServingEngine:
         sampler, no rng consumed) at its chunk's write offset, and the
         final chunk is exactly an admission call whose ``true_len``
         lands on the last prompt token."""
-        bucket = -(-len(suffix) // self.prefill_bucket) * self.prefill_bucket
-        bucket = min(max(bucket, len(suffix)), self.cache_len - shared)
+        bucket = self._bucket_rows(len(suffix), shared)
         fn = self._prefill_jits.get(bucket)
         if fn is None:
             fn = self._prefill_jits[bucket] = \
@@ -2398,6 +2418,7 @@ class ServingEngine:
                 self._fault_ctx = None
                 self._run_chunk(req)  # this tick's one chunk of budget
                 return
+            at.update(self._scan_rows(req.prompt_len - shared, shared))
             first = self._paged_prefill_call(
                 req, req.prompt[shared:], shared, req.slot)
             self._register_prefix(req)
@@ -2479,7 +2500,7 @@ class ServingEngine:
         tokens = req.prompt[start:end]
         self._fault_ctx = ("prefill", req.id)
         with span("serving.prefill_chunk", request=req.id, start=start,
-                  final=final):
+                  final=final, **self._scan_rows(end - start, start)):
             out = self._paged_prefill_call(req, tokens, start, req.slot,
                                            replay=not final)
         self._fault_ctx = None
@@ -2649,8 +2670,11 @@ class ServingEngine:
         state, the rows of one attention layer. Empty with one class and
         one kind."""
         rows = self.cache_manager.lengths[list(lanes)] + 1
-        if self._state_rows:  # what ONE of its attention layers reads
-            return {"attn_rows": int(rows.sum())}
+        if self._state_rows:  # what ONE of its attention layers reads, and
+            # the lanes whose lane-resident state the tick advances
+            return {"attn_rows": int(rows.sum()),
+                    **({"state_lanes": len(lanes)} if self._lane_state
+                       else {})}
         if not self.window_pages:
             return {}
         return {"full_rows": int(rows.sum()), "window_rows": int(

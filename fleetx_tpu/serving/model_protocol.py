@@ -99,12 +99,16 @@ class ModelCapabilities:
     page_classes: tuple = ("full",)
     supports_prefix_cache: bool = True
     supports_roles: bool = True
-    #: the kinds of state a lane keeps in the pool: "kv" (keys and values
-    #: of attention layers) and, for a model with gated short-convolution
+    #: the kinds of state a lane keeps: "kv" (keys and values of attention
+    #: layers, in the page pool); for a model with gated short-convolution
     #: layers, "conv" (the operator's last inputs, in tail pages under the
-    #: same block table: models/gpt/mixed_stack.py). A page's tail rows
-    #: live and die with the page, so a prefix hit resumes them; they are
-    #: neither spilled to the host tiers nor shipped between replicas
+    #: same block table: models/gpt/mixed_stack.py): a page's tail rows
+    #: live and die with the page, so a prefix hit resumes them; for a
+    #: model with selective-scan layers, "ssm" (the scan's state and its
+    #: filter's last inputs, held ONCE A LANE outside the pool and updated
+    #: in place): no page holds it, so a prefix hit has nothing to resume
+    #: and prefix reuse is refused. Neither recurrent kind is spilled to
+    #: the host tiers or shipped between replicas
     state_kinds: tuple = ("kv",)
     supports_host_spill: bool = True
 
@@ -135,12 +139,16 @@ _FEATURES = {
     "supports_mesh": "a serving mesh: no test covers it",
     "supports_prefix_cache": "prefix reuse: its window-attention layers "
                              "release a prefix's pages once the window has "
-                             "passed them",
+                             "passed them, or its selective-scan layers keep "
+                             "their state once a lane, with no snapshot at "
+                             "the match's end to resume from",
     "supports_roles": "a prefill or decode role: the pages of its window "
-                      "class, or the tail pages of its convolution state, "
-                      "are not shipped between replicas",
+                      "class, the tail pages of its convolution state, or "
+                      "the lane-resident state of its selective-scan "
+                      "layers, are not shipped between replicas",
     "supports_host_spill": "a host or disk page tier: the tail pages of its "
-                           "convolution state are not spilled",
+                           "convolution state, or the lane-resident state "
+                           "of its selective-scan layers, are not spilled",
 }
 
 
@@ -249,7 +257,7 @@ class GPTExecutor(ModelExecutor):
             supports_int8_kv=dense,
             supports_mesh=dense,
             page_classes=("full", "window") if windowed else ("full",),
-            supports_prefix_cache=not windowed,
+            supports_prefix_cache=not windowed and "ssm" not in state,
             supports_roles=not windowed and not recurrent,
             state_kinds=state,
             supports_host_spill=not recurrent,

@@ -258,7 +258,7 @@ def _pallas_calls(jaxpr):
 
 
 @pytest.mark.parametrize("lanes,n_row,block_k,pages", [
-    (24, 64, None, 16),   # gpt1.3b-serve-chat-steady-v2
+    (24, 64, None, 16),   # gpt1.3b-serve-chat-steady-v3
     (16, 64, None, 16),   # gpt1.3b-serve-docs-batch
     (32, 80, None, 16),   # olmoe-l8-serve-gen-batch
     (32, 80, 128, 8),     # FLEETX_DECODE_BLOCK_K=128: half the rows a step
@@ -332,11 +332,13 @@ def _dense_grouped(q, k, v, end, starts):
 
 
 @pytest.mark.parametrize("path", ["contiguous", "paged_page", "paged_block"])
-@pytest.mark.parametrize("heads,kv_heads", [(28, 4), (8, 2), (4, 4)])
+@pytest.mark.parametrize("heads,kv_heads", [(28, 4), (8, 2), (4, 4),
+                                            (20, 1)])
 def test_grouped_heads_match_the_dense_path(path, heads, kv_heads):
     """Grouped-query heads through the three kernel paths (the contiguous
     kernel, the paged kernel at one page a step, and at several), at group
     7 (28 rows against the lanes of 4 key heads: SmallThinker's), 4 and 1,
+    and 20 over ONE key head (Jamba2's: 20 rows against one head's lanes),
     with ``starts`` from a window (``end - 24``) beside rows read from 0."""
     rng = np.random.RandomState(3)
     b, d, ps, n_row = 3, 16, 8, 12
@@ -406,6 +408,24 @@ def test_grouped_kernels_lower_for_tpu_at_the_smallthinker_cell(monkeypatch,
         fn = lambda q, c, e, s: flash_decode_attention(q, c, c, end=e,
                                                        starts=s)
         _lower_for_tpu(monkeypatch, fn, q, cache, ends // 8, starts // 8)
+
+
+def test_grouped_kernel_lowers_for_tpu_at_the_jamba2_cell(monkeypatch):
+    """``jamba2-3b-serve-chat-peak``'s decode call (256 lanes, 20 query
+    heads over ONE key head of 128, page 16, 64 pages a row, a bf16 pool of
+    its two attention layers) passes the TPU lowering as the block kernel,
+    16 pages a step."""
+    lanes, h, d, ps, n_row = 256, 20, 128, 16, 64
+    q = jnp.zeros((lanes, 1, h, d), jnp.bfloat16)
+    pool = jnp.zeros((2 * 16385, ps, d), jnp.bfloat16)
+    tables = jnp.zeros((lanes, n_row), jnp.int32)
+    ends = jnp.full((lanes,), 700, jnp.int32)
+    fn = lambda q, kv_, t, e: flash_decode_paged_attention(
+        q, kv_, kv_, tables=t, end=e)
+    traced, text = _lower_for_tpu(monkeypatch, fn, q, pool, tables, ends)
+    (call,) = _pallas_calls(traced.jaxpr.jaxpr)
+    assert call.params["grid_mapping"].grid == (lanes, n_row // 16)
+    assert f'kernel_name = "{PAGED_KERNEL_NAME}"' in text
 
 
 def test_fit_decode_blocks():

@@ -375,8 +375,8 @@ def test_the_stack_builds_from_the_published_list_with_no_unused_parameter():
     assert len(types) == 24 and types[:14] == config["layer_types"]
     model = build(num_layers=24, layer_types=tuple(types))
     plan = mixed_stack.layer_plan(model.cfg)
-    assert plan["counts"] == {"conv": 18, "attention": 6, "dense": 2,
-                              "experts": 22}
+    assert plan["counts"] == {"conv": 18, "mamba": 0, "attention": 6,
+                              "dense": 2, "experts": 22}
     held = flax.core.meta.unbox(jax.jit(model.init)(
         jax.random.PRNGKey(0), np.zeros((1, 8), np.int32)))
     tokens = TOKENS[:1, :16]
@@ -399,7 +399,9 @@ def test_the_block_fields_are_checked_by_name():
     ok = dict(SIZES)
     for changes, exc, word in (
             ({"layer_types": TYPES[:5]}, ValueError, "layer_types"),
-            ({"layer_types": TYPES[:6] + ("mamba",)}, ValueError, "layer_types"),
+            ({"layer_types": TYPES[:6] + ("gru",)}, ValueError, "layer_types"),
+            ({"layer_types": TYPES[:6] + ("mamba",)}, NotImplementedError,
+             "conv AND mamba"),
             ({"num_dense_layers": 9}, ValueError, "num_dense_layers"),
             ({"conv_L_cache": 1}, ValueError, "conv_L_cache"),
             ({"gate": "softmax_topk"}, ValueError, "use_expert_bias"),
