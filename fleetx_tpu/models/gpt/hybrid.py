@@ -44,6 +44,7 @@ import numpy as np
 from flax import linen as nn
 from jax.ad_checkpoint import checkpoint_name
 
+from fleetx_tpu.models.gpt import paged_write
 from fleetx_tpu.models.gpt.model import (
     MLP,
     GPTConfig,
@@ -107,21 +108,14 @@ def init_cache(model, batch: int):
 def write_rows(cfg: GPTConfig, k_pool, v_pool, tables, wpos, k, v, keep=None):
     """The pools with this call's keys and values ``[b, s, width]`` written
     at positions ``wpos + [0, s)`` through ``tables`` (a layer's own:
-    its base added). ``keep`` (a traced bool) False: nothing is written (the
-    rows' page lies past the pool)."""
-    ps = cfg.decode_page_size
+    its base added). ``keep`` (a traced bool) False: nothing is written.
+    The write itself is ``paged_write.write_rows``: a page at a time where
+    the call is one sequence over whole pages, else a row at a time."""
     max_len = cfg.decode_cache_len or cfg.max_position_embeddings
     b, s, width = k.shape
-    with jax.named_scope("cache_write"):
-        pos = wpos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-        pos = jnp.minimum(pos, max_len - 1)
-        page = jnp.take_along_axis(tables, pos // ps, axis=1).reshape(-1)
-        if keep is not None:
-            page = jnp.where(keep, page, k_pool.shape[0])
-        off = (pos % ps).reshape(-1)
-        mode = None if keep is None else "drop"
-        return (k_pool.at[page, off].set(k.reshape(b * s, width), mode=mode),
-                v_pool.at[page, off].set(v.reshape(b * s, width), mode=mode))
+    return paged_write.write_rows(
+        [k_pool, v_pool], [k.reshape(b * s, width), v.reshape(b * s, width)],
+        tables, wpos, max_len, keep)
 
 
 def grouped_attention(q, k, v, allowed):

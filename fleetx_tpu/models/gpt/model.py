@@ -600,28 +600,28 @@ class SelfAttention(nn.Module):
                 k_w, v_w = k, v
             wpos = cache_positions.astype(jnp.int32)       # [b] write offsets
             tables = block_tables.astype(jnp.int32)        # [b, n_pages_row]
-            rows = [(ck, k_w.reshape(b * s, nh * hd)),
-                    (cv, v_w.reshape(b * s, nh * hd))]
-            if quant:
-                rows += [(cks, k_s.reshape(b * s, nh)),
-                         (cvs, v_s.reshape(b * s, nh))]
-            pools = []
-            with jax.named_scope("cache_write"):
-                if layer_index is not None:
+            cached = [ck, cv] + ([cks, cvs] if quant else [])
+            rows = [k_w, v_w] + ([k_s, v_s] if quant else [])
+            if layer_index is not None:
+                with jax.named_scope("cache_write"):
                     # carried stack [L, P, ps, w]: layer i's pages are
                     # [i*P, (i+1)*P) of the flat pool, its trash page i*P
                     tables = tables + layer_index * ck.value.shape[1]
-                pos = wpos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
-                pos = jnp.minimum(pos, max_len - 1)        # [b, s] logical
-                page = jnp.take_along_axis(tables, pos // ps, axis=1)
-                page, off = page.reshape(-1), (pos % ps).reshape(-1)
-                for var, new in rows:
-                    # merging the leading axes is a bitcast (and a no-op on
-                    # one layer's own [P, ps, w] pool)
-                    pool = var.value.reshape((-1,) + var.value.shape[-2:])
-                    pool = pool.at[page, off].set(new)
-                    var.value = pool.reshape(var.value.shape)
-                    pools.append(pool)
+            # THE write (a page at a time where this call is one sequence
+            # over whole pages, every prefill program; else a row at a
+            # time) lives in a module of its own, imported here and not at
+            # the head of this file: a line added above would move every
+            # line below it, and with them every training program's key in
+            # the compile cache (models/gpt/resident.py has why)
+            from fleetx_tpu.models.gpt import paged_write
+            # merging the leading axes is a bitcast (and a no-op on one
+            # layer's own [P, ps, w] pool)
+            pools = paged_write.write_rows(
+                [c.value.reshape((-1,) + c.value.shape[-2:]) for c in cached],
+                [new.reshape(b * s, -1) for new in rows], tables, wpos,
+                max_len)
+            for var, pool in zip(cached, pools):
+                var.value = pool.reshape(var.value.shape)
             if quant:
                 kv_scales = tuple(pools[2:])
             if layer_index is None:

@@ -296,6 +296,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from fleetx_tpu.obs import http as obs_http
 from fleetx_tpu.obs.events import emit as obs_emit
 from fleetx_tpu.obs.tracing import span
+from fleetx_tpu.models.gpt import paged_write
 from fleetx_tpu.models.gpt.generation import (
     GenerationConfig,
     _top_p_cutoff_bisect,
@@ -2244,11 +2245,15 @@ class ServingEngine:
                 floats = _upload(at, _sampler_floats(req))
             return _upload(at, ints), floats, req.rng_key
 
-    def _guarded_prefill(self, req: Request, fn, args, bucket=None):
+    def _guarded_prefill(self, req: Request, fn, args, bucket: int):
         """One prefill device call through the fault-injection hook;
         stores the returned cache in the cache manager and returns the
         first token with the stream's carry key and the call's program
-        number (:meth:`_next_program`). Deliberately NOT
+        number (:meth:`_next_program`). The span's ``page_writes`` is the
+        model's own predicate on the call's shape (``paged_write.
+        page_writes``): the pages a pool and layer that the program
+        writes a page at a time, 0 where it writes a row at a time; the
+        metrics count the calls each way. Deliberately NOT
         under the hung-tick watchdog: prefill calls legitimately include
         fresh-bucket XLA compiles (seconds), and replay recovery
         re-prefills through here — a watchdog here would misread every
@@ -2258,12 +2263,14 @@ class ServingEngine:
         attempt = self._fault_prefills
         self._fault_prefills += 1
         program = self._next_program()
+        pages = paged_write.page_writes(1, bucket, self.page_size)
         with span("serving.prefill", request=req.id, bucket=bucket,
-                  program=program):
+                  program=program, page_writes=pages):
             faults.on_serving_prefill(attempt, req.id)
             with self._mesh_context():
                 cache, tok, carry_key = fn(*args)
         self.cache_manager.cache = cache
+        self.metrics.record_prefill_write(pages)
         return tok, carry_key, program
 
     def _bucket_rows(self, n: int, shared: int) -> int:

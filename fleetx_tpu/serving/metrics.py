@@ -307,6 +307,16 @@ class ServingMetrics:
             labels = {"engine": self.engine_label, "cause": cause}
             owned.append((self._flushed_family, labels))
             self._flushed[cause] = self._flushed_family.labels(**labels)
+        # the unit of a prefill program's cache write, decided by its shape
+        # (models/gpt/paged_write.py): a page at a time or a row at a time
+        self._c_prefill_page_writes = counter(
+            "fleetx_serving_prefill_page_writes_total",
+            "Prefill programs that wrote their keys and values a page at a "
+            "time")
+        self._c_prefill_row_writes = counter(
+            "fleetx_serving_prefill_row_writes_total",
+            "Prefill programs that wrote their keys and values a row at a "
+            "time")
         self._first_token_t: Optional[float] = None
         self._last_token_t: Optional[float] = None
         weakref.finalize(self, _drop_series, owned)
@@ -407,6 +417,13 @@ class ServingMetrics:
         tokens (the count rides the counter; per-chunk size is static)."""
         del tokens  # chunk size is a config constant; count is the signal
         self._c_prefill_chunks.inc()
+
+    def record_prefill_write(self, pages: int) -> None:
+        """One prefill program (an admission, a chunk, a replay) ran;
+        ``pages`` is what it wrote a page at a time a pool and layer, 0
+        where it wrote a row at a time."""
+        (self._c_prefill_page_writes if pages
+         else self._c_prefill_row_writes).inc()
 
     def observe_host_tier(self, store) -> None:
         """Per-tick sync from a :class:`HostPageStore`: gauges track its
@@ -821,6 +838,9 @@ class ServingMetrics:
                 int(c.value) for c in self._flushed.values()),
             **{f"decode_ticks_flushed_{cause}": int(c.value)
                for cause, c in self._flushed.items()},
+            # prefill programs by the unit of their cache write
+            "prefill_page_writes": int(self._c_prefill_page_writes.value),
+            "prefill_row_writes": int(self._c_prefill_row_writes.value),
             # crash-safety story: how often the engine recovered, what it
             # quarantined, what shutdown turned away, and what a tick costs
             "engine_recoveries": self.engine_recoveries,

@@ -52,8 +52,9 @@ def tiny():
 
 def _engine(tiny, **kwargs):
     model, params = tiny
+    kwargs.setdefault("prefill_bucket", 4)
     return ServingEngine(
-        model, params, slots=4, cache_len=32, prefill_bucket=4, page_size=8,
+        model, params, slots=4, cache_len=32, page_size=8,
         gen_cfg=GenerationConfig(decode_strategy="greedy",
                                  eos_token_id=10**6, pad_token_id=60),
         **kwargs)
@@ -101,16 +102,18 @@ def programs_by_span():
         log.removeHandler(book)
 
 
-def _admission(tiny):
-    eng = _engine(tiny)
+def _admission(tiny, **kwargs):
+    eng = _engine(tiny, **kwargs)
     _submit(eng, GREEDY, WARM_UP)
     eng.step()                      # an admission and a tick
     _submit(eng, SAMPLED)
     return eng.step, dict(prefill_args=[2], install=[1])
 
 
-def _chunks(tiny):
-    eng = _engine(tiny, prefill_chunk=4)
+def _chunks(tiny, **kwargs):
+    # (a chunk is one bucket)
+    eng = _engine(tiny, prefill_chunk=kwargs.get("prefill_bucket", 4),
+                  **kwargs)
     _submit(eng, GREEDY, WARM_UP)
     eng.drain()
     _submit(eng, SAMPLED)
@@ -118,11 +121,12 @@ def _chunks(tiny):
     def run():                      # chunks of 4, 4 and the final 2
         for _ in range(3):
             eng.step()
+    run.__self__ = eng              # as a bound ``eng.step`` has it
     return run, dict(prefill_args=[1, 1, 2], install=[1])
 
 
-def _replay(tiny):
-    eng = _engine(tiny)
+def _replay(tiny, **kwargs):
+    eng = _engine(tiny, **kwargs)
     _submit(eng, SAMPLED)
     eng.step()
     eng.step()
@@ -161,6 +165,39 @@ def test_admission_uploads_are_counted_and_start_no_program(tiny, path):
     # runs them
     if args:
         assert set(programs["serving.prefill"]) == {"prefill"}
+
+
+# ------------------------------------------------- the unit of the cache write
+
+@pytest.mark.parametrize("path,bucket,pages", [
+    (_admission, 8, [2]),           # 10 tokens in a bucket of 16: two pages
+    (_chunks, 8, [1, 1]),           # a chunk of 8 and the final 2 in 8
+    (_replay, 8, [2]),              # the history, 11-16 tokens, in 16
+    (_admission, 4, [0]),           # a bucket of 12 is no whole number of
+    (_chunks, 4, [0, 0, 0]),        # pages of 8: a row at a time
+    (_replay, 4, [0]),
+], ids=["admission", "chunks", "replay", "admission_rows", "chunks_rows",
+        "replay_rows"])
+def test_prefill_span_and_counters_say_the_unit_of_the_write(
+        tiny, path, bucket, pages):
+    """``serving.prefill``'s ``page_writes`` is the call's bucket over the
+    page size where that is whole (the model's own predicate on the
+    program's shape, ``paged_write.page_writes``), else 0, and the metrics
+    count the prefill programs each way."""
+    run, _ = path(tiny, prefill_bucket=bucket)
+    eng = run.__self__
+    before = eng.metrics.snapshot()
+    rec = get_recorder()
+    rec.clear()
+    run()
+    prefills = [s.attrs for s in rec.spans() if s.name == "serving.prefill"]
+    assert [a["page_writes"] for a in prefills] == pages
+    assert all(a["page_writes"] in (0, a["bucket"] // 8) for a in prefills)
+    after = eng.metrics.snapshot()
+    by_page = sum(1 for n in pages if n)
+    assert (after["prefill_page_writes"] - before["prefill_page_writes"],
+            after["prefill_row_writes"] - before["prefill_row_writes"]) == (
+        by_page, len(pages) - by_page)
 
 
 # ---------------------------------------------------------------- exactness
