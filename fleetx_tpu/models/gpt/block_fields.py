@@ -27,7 +27,7 @@ __all__ = ["BlockLayoutFields", "LAYOUT_FIELDS", "LAYER_TYPES", "check",
 # a module attribute and has to hash)
 LAYOUT_FIELDS = ("rope_layout", "sliding_window_layout", "layer_types")
 # the operators a layer of ``layer_types`` can name, under the source's names
-LAYER_TYPES = ("conv", "mamba", "full_attention")
+LAYER_TYPES = ("conv", "mamba", "full_attention", "latent_attention")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,10 +92,71 @@ class BlockLayoutFields:
     # counts one full-attention layer's pages): the pages of one WINDOW
     # layer, whose lanes keep only the rows a live query can still see
     decode_window_pages: Optional[int] = None
+    # ---- "latent_attention" (models/gpt/latent.py; every layer of the
+    # stack then is one): queries through a latent of ``q_lora_rank``, keys
+    # and values through ONE latent of ``kv_lora_rank`` a token beside ONE
+    # rotary key of ``qk_rope_head_dim`` shared by all heads (what the
+    # cache holds); a head's query and key are ``qk_nope_head_dim`` +
+    # ``qk_rope_head_dim`` wide, its value ``v_head_dim``. The rotary
+    # frequencies are YaRN's (``rope_scaling_*``, the source's block) over
+    # ``rope_theta``
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    rope_scaling_factor: float = 1.0
+    rope_scaling_beta_fast: float = 32.0
+    rope_scaling_beta_slow: float = 1.0
+    rope_scaling_mscale: float = 1.0
+    rope_scaling_mscale_all_dim: float = 0.0
+    rope_scaling_original_max_position: int = 4096
+    # ---- an expert layer that holds a SHARE (parallel/moe_share.py): the
+    # router is ``num_routed_experts`` wide and every token chooses among
+    # all of them; this program holds ``num_experts`` of them, from
+    # ``first_expert_held`` on, and computes their part of the result (None:
+    # every routed expert is held, the layer of parallel/moe.py). Gate
+    # ``sigmoid_topk`` with ``n_group`` > 1 chooses inside the
+    # ``topk_group`` groups (of ``n_group`` equal ones) whose two highest
+    # scores sum highest. ``num_shared_experts`` gated experts of
+    # ``ffn_hidden_size`` each see every token
+    num_routed_experts: Optional[int] = None
+    first_expert_held: int = 0
+    n_group: int = 1
+    topk_group: int = 1
+    num_shared_experts: int = 0
 
     @property
     def kv_heads(self) -> int:
         return self.num_key_value_heads or self.num_attention_heads
+
+    @property
+    def latent(self) -> bool:
+        """Whether the attention layers are latent attention."""
+        return "latent_attention" in (self.layer_types or ())
+
+    @property
+    def experts_held(self) -> Tuple[int, int]:
+        """``(first, count)`` of the routed experts this program holds."""
+        return self.first_expert_held, self.num_experts
+
+    @property
+    def routed_experts(self) -> int:
+        """The router's width: every expert a token can choose."""
+        return self.num_routed_experts or self.num_experts
+
+    @property
+    def expert_share(self) -> bool:
+        """Whether the expert layers are parallel/moe_share.py's: a held
+        share, a group-limited choice or a shared expert."""
+        return bool(self.num_routed_experts or self.n_group > 1
+                    or self.num_shared_experts)
+
+    @property
+    def rows_span_field(self) -> str:
+        """The name under which a tick's span gives the live rows of one
+        attention layer."""
+        return "latent_rows" if self.latent else "attn_rows"
 
     @property
     def layer_kinds(self) -> bool:
@@ -114,8 +175,27 @@ class BlockLayoutFields:
         a selective-scan layer's state ("ssm")."""
         kinds = set(self.layer_types or ("full_attention",))
         return tuple(name for name, kind in (
-            ("kv", "full_attention"), ("conv", "conv"), ("ssm", "mamba"))
-            if kind in kinds)
+            ("kv", "full_attention"), ("conv", "conv"), ("ssm", "mamba"),
+            ("latent", "latent_attention")) if kind in kinds)
+
+    def span_pairs(self, rows: int) -> dict:
+        """Span field of a call of ``rows`` rows through an expert layer
+        that holds a share: ALL the (token, expert) pairs its routers
+        choose, over every expert layer (how many of them meet a held expert
+        only the device knows: the counters ``moe_*_pairs``). Empty for any
+        other layer."""
+        if not (self.expert_mode and self.expert_share):
+            return {}
+        return {"pairs": rows * self.top_k
+                * (self.num_layers - self.num_dense_layers)}
+
+    def spans(self, rows: int, behind: int) -> dict:
+        """Span fields of a prefill call of ``rows`` tokens behind
+        ``behind`` cached ones over latent attention: the live rows ONE
+        layer attends over (and re-expands), and the pairs routed."""
+        if not self.latent:
+            return {}
+        return {"latent_rows": behind + rows, **self.span_pairs(rows)}
 
     @property
     def mamba_inner(self) -> int:
@@ -216,8 +296,8 @@ def _check_mixed(cfg) -> None:
             "size of its own or mixed layers: no test covers it "
             "(qk_norm_scope: head is the per-head norm)")
     sigmoid = cfg.expert_mode and cfg.gate == "sigmoid_topk"
-    if sigmoid and not 1 <= cfg.top_k <= cfg.num_experts:
-        raise ValueError(f"top_k {cfg.top_k} of {cfg.num_experts} experts")
+    if sigmoid and not 1 <= cfg.top_k <= cfg.routed_experts:
+        raise ValueError(f"top_k {cfg.top_k} of {cfg.routed_experts} experts")
     if (cfg.use_expert_bias or cfg.expert_bias_init_std) and not sigmoid:
         raise ValueError("use_expert_bias (expert_bias_init_std) without "
                          "gate: sigmoid_topk, the gate that reads the bias")
@@ -253,6 +333,7 @@ def _check_mixed(cfg) -> None:
         raise NotImplementedError(
             "layer_types with mlp_act other than swiglu, with biases or "
             "without rmsnorm: no test covers it")
+    _check_latent(cfg)
     if "mamba" in cfg.layer_types:
         if "conv" in cfg.layer_types:
             raise NotImplementedError(
@@ -277,12 +358,70 @@ def _check_mixed(cfg) -> None:
         raise NotImplementedError("router_input with layer_types")
 
 
+def _check_latent(cfg) -> None:
+    """The fields of latent attention and of an expert layer that holds a
+    share, each refusal with the field's name."""
+    share = cfg.expert_share
+    if share and not (cfg.expert_mode and cfg.gate == "sigmoid_topk"):
+        raise ValueError(
+            "num_routed_experts / n_group / num_shared_experts without "
+            "gate: sigmoid_topk over layer_types (parallel/moe_share.py)")
+    if share and cfg.use_expert_bias:
+        raise NotImplementedError(
+            "use_expert_bias with a held share or a group-limited choice: "
+            "no test covers a selection bias there")
+    first, count = cfg.experts_held
+    if share and not (0 <= first and first + count <= cfg.routed_experts
+                      and cfg.top_k <= cfg.routed_experts):
+        raise ValueError(
+            f"experts held [{first}, {first + count}) and top_k {cfg.top_k} "
+            f"of num_routed_experts {cfg.routed_experts}")
+    if cfg.n_group < 1 or cfg.routed_experts % cfg.n_group or not (
+            1 <= cfg.topk_group <= cfg.n_group) or (
+            cfg.n_group > 1 and cfg.routed_experts // cfg.n_group < 2):
+        raise ValueError(
+            f"n_group {cfg.n_group} (topk_group {cfg.topk_group}) over "
+            f"{cfg.routed_experts} routed experts: equal groups of at least "
+            "two, of which 1 .. n_group stay")
+    if not cfg.latent:
+        widths = [n for n in ("q_lora_rank", "kv_lora_rank",
+                              "qk_nope_head_dim", "qk_rope_head_dim",
+                              "v_head_dim") if getattr(cfg, n)]
+        if widths:
+            raise ValueError(f"{widths} without a latent_attention layer")
+        return
+    if set(cfg.layer_types) != {"latent_attention"}:
+        raise NotImplementedError(
+            "layer_types with latent_attention beside another operator: no "
+            "test covers a stack that mixes them")
+    missing = [n for n in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                           "qk_rope_head_dim", "v_head_dim")
+               if not getattr(cfg, n)]
+    if missing or cfg.qk_rope_head_dim % 2:
+        raise ValueError(
+            f"latent_attention needs {missing or 'an even qk_rope_head_dim'} "
+            "(the source states every width)")
+    if cfg.position_embedding != "rope" or cfg.qk_norm or cfg.kv_heads != (
+            cfg.num_attention_heads):
+        raise ValueError(
+            "latent_attention takes position_embedding: rope (its rotary "
+            "key), no qk_norm and no grouped heads: the latent has no head")
+    if cfg.rope_scaling_factor < 1.0:
+        raise ValueError(f"rope_scaling_factor {cfg.rope_scaling_factor}")
+
+
 def stack_of(model):
     """What ``GPTModel`` runs its layers with: its own ``_decoder_stack``
     (one scanned body over layers that hold the same parameters) or, for a
-    configuration with ``layer_types``, ``mixed_stack.MixedStack``."""
+    configuration with ``layer_types``, ``mixed_stack.MixedStack`` (for
+    latent attention its subclass ``latent.LatentStack``, which hands the
+    same body the latent operator and its two leaves)."""
     if not model.cfg.layer_types:
         return model._decoder_stack
+    if model.cfg.latent:
+        from fleetx_tpu.models.gpt.latent import LatentStack
+
+        return LatentStack(model.cfg, name="layers")
     from fleetx_tpu.models.gpt.mixed_stack import MixedStack
 
     return MixedStack(model.cfg, name="layers")

@@ -72,7 +72,7 @@ def _pages_of(cfg: GPTConfig):
             "engine sets both)")
     if cfg.layer_types:  # only the attention layers hold keys and values,
         # and are counted among themselves (models/gpt/mixed_stack.py)
-        return [full] * cfg.layer_types.count("full_attention")
+        return [full] * sum(t.endswith("attention") for t in cfg.layer_types)
     return [window if w else full for w in cfg.window_layers]
 
 
@@ -98,9 +98,9 @@ def init_cache(model, batch: int):
     pool = (total_pages(cfg), cfg.decode_page_size,
             cfg.kv_heads * cfg.head_dim)
 
-    def one(path, leaf):
-        kv = path[-1].key in ("cached_key", "cached_value")
-        return jnp.zeros(pool if kv else leaf.shape, leaf.dtype)
+    def one(path, x):
+        kv = path[-1].key in ("cached_key", "cached_value")  # (own widths)
+        return jnp.zeros((x.shape, pool[:2] + x.shape[-1:])[kv], x.dtype)
 
     return jax.tree_util.tree_map_with_path(one, shapes)
 
@@ -112,7 +112,7 @@ def write_rows(cfg: GPTConfig, k_pool, v_pool, tables, wpos, k, v, keep=None):
     The write itself is ``paged_write.write_rows``: a page at a time where
     the call is one sequence over whole pages, else a row at a time."""
     max_len = cfg.decode_cache_len or cfg.max_position_embeddings
-    b, s, width = k.shape
+    (b, s), width = k.shape[:2], -1  # (each pool's own width)
     return paged_write.write_rows(
         [k_pool, v_pool], [k.reshape(b * s, width), v.reshape(b * s, width)],
         tables, wpos, max_len, keep)

@@ -104,7 +104,7 @@ def layer_plan(cfg: GPTConfig) -> dict:
     recurrent kind), ``operator_index``, ``experts`` (1: expert layer, 0:
     dense), ``ffn_index``; the counts of every kind; and ``recurrent``, the
     name of the recurrent kind ("conv" | "mamba", None without one)."""
-    attention = np.asarray([t == "full_attention" for t in cfg.layer_types])
+    attention = np.asarray([t.endswith("attention") for t in cfg.layer_types])
     recurrent = next((t for t in ("mamba", "conv") if t in cfg.layer_types),
                      None)
     experts = np.arange(cfg.num_layers) >= cfg.num_dense_layers

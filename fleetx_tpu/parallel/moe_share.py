@@ -1,0 +1,199 @@
+"""An expert layer that holds a SHARE of the routed experts, beside shared
+experts that every token meets: ``DroplessMoEMLP`` (``parallel/moe.py``)
+for one chip of a deployment whose every layer is divided over several
+(``models/gpt/block_fields.py`` has the fields).
+
+- **The router keeps its published width.** Scores ``sigmoid(x W_g)`` in
+  float32 over ALL ``num_routed_experts``; with ``n_group`` > 1 a group's
+  score is the sum of its two highest, the ``topk_group`` best groups stay
+  and the ``top_k`` largest scores are chosen among what stays (the
+  DeepSeek-V3 family's group-limited choice, with no selection bias);
+  weights the chosen scores over their sum + 1e-20 (``norm_topk_prob``)
+  times ``routed_scaling_factor``.
+- **This program holds ``num_experts`` of them**, ``[first_expert_held,
+  first_expert_held + num_experts)``: the weights ``w_gate / w_up /
+  w_down`` ``[held, in, out]``. Only the (token, slot) pairs whose expert is
+  held are laid out for the grouped matmuls; the others take no row and no
+  kernel step and add nothing. What the absent experts would have added is
+  LEFT OUT: the layer's output is this share's part of the routed result.
+  Nothing stands in for the other chips or their exchange. With every
+  routed expert held and one group the layer is ``DroplessMoEMLP``'s
+  ``sigmoid_topk`` without a bias, value for value.
+- **Shared experts** (``num_shared_experts`` x ``ffn_hidden_size`` wide, one
+  gated MLP) see every token and are added once (scope ``moe_shared``).
+
+The counters in the cache's ``moe_stats`` leaf count what THIS program did:
+the pairs laid out here and the held experts that had a row
+(``GPTExecutor.counters`` reads them as ``moe_{tick,prefill}_pairs`` and
+``_experts_read``); all the pairs routed are ``rows x top_k`` of a call,
+which the host knows. The collection ``routing`` holds the input, ALL
+``top_k`` experts chosen (by their routed number), their weights and the
+output, for whoever holds the layer to a reference.
+
+Forward only, as the kernels it uses.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from fleetx_tpu.models.gpt import model as gpt_model
+from fleetx_tpu.parallel.moe import DroplessMoEMLP, _running_count
+
+__all__ = ["SharedMoEMLP", "group_limited_topk", "held_row_layout"]
+
+
+def _shared_expert(t, gate, up, down):
+    """The shared expert's gated MLP on every token ``t`` ``[n, h]`` (a
+    function of its own: ``perfbench/probe_axk1.py`` plants a fault here,
+    as in ``group_limited_topk`` and ``held_row_layout``)."""
+    return (jax.nn.silu(t @ gate) * (t @ up)) @ down
+
+
+def group_limited_topk(scores, top_k: int, n_group: int, topk_group: int):
+    """The ``top_k`` largest of ``scores`` ``[n, E]`` inside the
+    ``topk_group`` groups (of ``n_group`` equal, consecutive ones) whose two
+    highest scores sum highest: ``[n, top_k]`` expert numbers."""
+    if n_group > 1:
+        n, experts = scores.shape
+        grouped = scores.reshape(n, n_group, experts // n_group)
+        group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)     # [n, groups]
+        kept = jax.lax.top_k(group_score, topk_group)[1]
+        stays = (kept[..., None] == jnp.arange(n_group)).any(-2)
+        scores = jnp.where(jnp.repeat(stays, experts // n_group, axis=-1),
+                           scores, -jnp.inf)
+    return jax.lax.top_k(scores, top_k)[1]
+
+
+def held_row_layout(topk_idx, first: int, count: int, tm: int):
+    """``moe.expert_row_layout`` over the pairs whose expert lies in
+    ``[first, first + count)`` alone: ``(dest, src, sizes, tile_expert,
+    num_tiles, held)``. ``dest`` of a pair that is not held lies past the
+    rows (a scatter drops it, a gather clamps it: its weight is zeroed by
+    ``held`` ``[n, k]``); ``sizes`` ``[count]`` and the tiles are of the held
+    experts, numbered from 0. The rows are bounded as if every pair were
+    held. ``num_tiles`` is at least 1: where NO pair of the call is held (a
+    tick of few lanes) the kernels still step over tile 0, whose rows no
+    pair reads (their index maps name tile ``num_tiles - 1``, which must
+    exist)."""
+    n, k = topk_idx.shape
+    m = n * k
+    local = topk_idx.reshape(m).astype(jnp.int32) - first
+    held = (local >= 0) & (local < count)
+    onehot = ((local[:, None] == jnp.arange(count, dtype=jnp.int32))
+              ).astype(jnp.int32)                              # [m, held]
+    sizes = onehot.sum(axis=0)
+    at = jnp.clip(local, 0, count - 1)
+    rank = jnp.take_along_axis(_running_count(onehot), at[:, None],
+                               axis=1)[:, 0] - 1
+    padded = (sizes + tm - 1) // tm * tm
+    ends = jnp.cumsum(padded)
+    rows = m if tm == 1 else (-(-(m + count * (tm - 1)) // tm) + 1) * tm
+    dest = jnp.where(held, (ends - padded)[at] + rank, rows)
+    src = jnp.zeros((rows,), jnp.int32).at[dest].set(
+        jnp.arange(m, dtype=jnp.int32) // k, mode="drop")
+    first_row = jnp.arange(rows // tm, dtype=jnp.int32) * tm
+    tile_expert = jnp.minimum(
+        (ends[None, :] <= first_row[:, None]).sum(axis=1), count - 1)
+    return (dest, src, sizes, tile_expert.astype(jnp.int32),
+            jnp.maximum(ends[-1] // tm, 1), held.reshape(n, k))
+
+
+class SharedMoEMLP(DroplessMoEMLP):
+    """Module docstring. Called as ``DroplessMoEMLP`` is; its three expert
+    leaves carry the same names, so the layer loop hands it the same
+    ``expert_stack``."""
+
+    @nn.compact
+    def __call__(self, x, *, decode: bool = False, layer_index=None,
+                 expert_stack=None, router_input=None):
+        cfg = self.cfg
+        if router_input is not None or cfg.mlp_act != "swiglu":
+            raise NotImplementedError(
+                "a held share with a router input of its own or a gate "
+                "other than SiLU: no test covers it")
+        b, s, h = x.shape
+        (first, held_n), routed = cfg.experts_held, cfg.routed_experts
+        k, f, n, dt = cfg.top_k, cfg.ffn_size, b * s, cfg.dtype
+        router = nn.DenseGeneral(
+            features=routed, use_bias=False, dtype=jnp.float32,
+            param_dtype=jnp.float32, precision=jax.lax.Precision.HIGHEST,
+            kernel_init=nn.with_logical_partitioning(
+                gpt_model.default_kernel_init, ("embed", None)),
+            name="router")
+
+        def weight(name, shape, axes):
+            return self.param(
+                name, nn.with_logical_partitioning(
+                    gpt_model.default_kernel_init, axes), shape, jnp.float32)
+
+        w_gate = weight("w_gate", (held_n, h, f), ("expert", "embed", "mlp"))
+        w_up = weight("w_up", (held_n, h, f), ("expert", "embed", "mlp"))
+        w_down = weight("w_down", (held_n, f, h), ("expert", "mlp", "embed"))
+        shared_f = cfg.num_shared_experts * f
+        if shared_f:
+            shared = [weight(name, shape, axes) for name, shape, axes in (
+                ("shared_gate", (h, shared_f), ("embed", "mlp")),
+                ("shared_up", (h, shared_f), ("embed", "mlp")),
+                ("shared_down", (shared_f, h), ("mlp", "embed")))]
+
+        from fleetx_tpu.ops.pallas import moe_gmm
+        from fleetx_tpu.ops.pallas.flash_attention import kernels_enabled
+
+        kernel = (decode and expert_stack is not None
+                  and cfg.use_flash_attention and kernels_enabled())
+        # the tile of the pairs a share sees were routing even
+        tm = (moe_gmm.row_tile(max(n * k * held_n // routed, 1), held_n)
+              if kernel else 1)
+        tokens = x.reshape(n, h)
+        with jax.named_scope("moe_route"):
+            scores = jax.nn.sigmoid(router(tokens.astype(jnp.float32)))
+            scores = scores.astype(jnp.float32)  # (whatever the router's)
+            topk_idx = group_limited_topk(scores, k, cfg.n_group,
+                                          cfg.topk_group)
+            weights = jnp.take_along_axis(scores, topk_idx, axis=-1)
+            if cfg.norm_topk_prob:
+                weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+            weights = weights * cfg.routed_scaling_factor
+            dest, src, sizes, tile_expert, num_tiles, held = held_row_layout(
+                topk_idx, first, held_n, tm)
+            rows = tokens.astype(dt)[src]
+        probed = (self.is_mutable_collection("routing")
+                  and not self.is_initializing())
+        if probed:
+            self.sow("routing", "input", x)
+            self.sow("routing", "experts", topk_idx.reshape(b, s, k))
+            self.sow("routing", "weights", weights.reshape(b, s, k))
+        # (the pairs laid out HERE, which only the device knows)
+        self._count(sizes, sizes.sum(), s, decode, layer_index)
+        with jax.named_scope("moe_experts"):
+            if kernel:
+                w_gate, w_up, w_down = (w.astype(dt) for w in expert_stack)
+                hidden = moe_gmm.grouped_gate_up(
+                    rows, w_gate, w_up, tile_expert, num_tiles, tm=tm,
+                    layer=layer_index, act="silu")
+                out = moe_gmm.grouped_down(hidden, w_down, tile_expert,
+                                           num_tiles, tm=tm, layer=layer_index)
+            else:
+                w_gate, w_up, w_down = (w.astype(dt)
+                                        for w in (w_gate, w_up, w_down))
+                # rows past the held pairs belong to no group: ragged_dot
+                # leaves them zero
+                hidden = (jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes))
+                          * jax.lax.ragged_dot(rows, w_up, sizes))
+                out = jax.lax.ragged_dot(hidden, w_down, sizes)
+        with jax.named_scope("moe_route"):
+            picked = out[dest].reshape(n, k, h).astype(jnp.float32)
+            y = (jnp.where(held[..., None], picked, 0.0)
+                 * weights[..., None]).sum(axis=1)
+        if shared_f:
+            with jax.named_scope("moe_shared"):
+                gate, up, down = (w.astype(dt) for w in shared)
+                t = tokens.astype(dt)
+                y = y + _shared_expert(t, gate, up, down).astype(jnp.float32)
+        y = y.astype(dt).reshape(b, s, h)
+        if probed:
+            self.sow("routing", "output", y)
+        return y

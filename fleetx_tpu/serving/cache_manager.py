@@ -1493,6 +1493,16 @@ class PagedKVCacheManager(_LaneBook):
                 "ssm_state_resets": self.state_resets,
                 "kv_page_bytes_in_use": (self.pool.pages_in_use
                                          * self.page_bytes["kv"])}
+        if "latent" in self.state_kinds:
+            # one class of page, whose rows are latents: what is pinned by
+            # live requests, and what the trie holds for a later match
+            pool = self.pool
+            return {"latent_pages_in_use": pool.pages_in_use,
+                    "latent_pages_in_trie": len(pool._node_of_page),
+                    "latent_page_bytes": self.page_bytes["kv"],
+                    "state_bytes_lanes": 0,
+                    "kv_page_bytes_in_use": (pool.pages_in_use
+                                             * self.page_bytes["kv"])}
         if "conv" in self.state_kinds:
             pool, tail = self.pool, self.page_bytes["conv"]
             return {

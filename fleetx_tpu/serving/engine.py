@@ -2282,8 +2282,8 @@ class ServingEngine:
     def _scan_rows(self, n: int, shared: int) -> dict:
         """Span field of a prefill call over lane-resident state: the rows
         its selective scans run over, padding included."""
-        return ({"scan_rows": self._bucket_rows(n, shared)}
-                if self._lane_state else {})
+        return ({"scan_rows": self._bucket_rows(n, shared)} if self._lane_state
+                else self.model.cfg.spans(n, shared) if self._state_rows else {})
 
     def _paged_prefill_call(self, req: Request, suffix, shared, lane,
                             replay: bool = False):
@@ -2679,9 +2679,9 @@ class ServingEngine:
         rows = self.cache_manager.lengths[list(lanes)] + 1
         if self._state_rows:  # what ONE of its attention layers reads, and
             # the lanes whose lane-resident state the tick advances
-            return {"attn_rows": int(rows.sum()),
+            return {self.model.cfg.rows_span_field: int(rows.sum()),
                     **({"state_lanes": len(lanes)} if self._lane_state
-                       else {})}
+                       else self.model.cfg.span_pairs(self.slots))}
         if not self.window_pages:
             return {}
         return {"full_rows": int(rows.sum()), "window_rows": int(
