@@ -192,10 +192,16 @@ class BlockLayoutFields:
     def spans(self, rows: int, behind: int) -> dict:
         """Span fields of a prefill call of ``rows`` tokens behind
         ``behind`` cached ones over latent attention: the live rows ONE
-        layer attends over (and re-expands), and the pairs routed."""
+        layer attends over (and re-expands), the key rows the live steps of
+        the kernel ``fleetx_mla_prefill`` cover for them (whole blocks:
+        work and padding together), and the pairs routed."""
         if not self.latent:
             return {}
-        return {"latent_rows": behind + rows, **self.span_pairs(rows)}
+        from fleetx_tpu.ops.pallas.mla_prefill import key_rows
+
+        return {"latent_rows": behind + rows,
+                "latent_key_rows": key_rows(behind + rows),
+                **self.span_pairs(rows)}
 
     @property
     def mamba_inner(self) -> int:

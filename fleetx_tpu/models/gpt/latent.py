@@ -19,10 +19,16 @@ can be divided over ``mp``). Two forms read it:
   | v] = c_kv W_kvb`` a head, ``k = [k_nope | k_r]``, scores ``q k^T *
   scale``, causal softmax in float32, ``(P v) W_o``. A chunk attends over
   the lane's cached rows AND its own (written just before) in blocks of
-  ``KEY_BLOCK`` rows with a running maximum and sum, each block's keys and
-  values re-expanded from its latents (scope ``mla_kv_up``): the scores of
-  64 heads over 25,600 rows are never held whole, and blocks past the
-  chunk's last row are not computed;
+  rows with a running maximum and sum, each block's keys and values
+  re-expanded from its latents, and blocks past the chunk's last row are
+  not computed. Where the decode kernel runs (``use_flash_attention`` and
+  a TPU, or interpreted under ``FLEETX_FORCE_FLASH=1``) that is ONE kernel,
+  ``ops/pallas/mla_prefill.py``: a head's keys and values of a block are
+  expanded, scored and summed in VMEM, and neither they nor a score ever
+  exist in HBM. Elsewhere (the CPU, the tests) it is the kernel's plain
+  twin :func:`_chunk` in blocks of ``KEY_BLOCK`` rows (the re-expansion
+  under the scope ``mla_kv_up``), the same arithmetic in the same types,
+  whose ``[heads, s, KEY_BLOCK]`` float32 scores pass through HBM;
 - *absorbed* (a decode tick): ``q~ = q_nope W_UK^T``, scores ``([q~ | q_r]
   . [c_kv | k_r]) * scale``, ``o = (P c_kv) W_UV``, where ``W_UK`` and
   ``W_UV`` are the two halves of ``W_kvb`` a head: the kernel
@@ -34,9 +40,11 @@ can be divided over ``mp``). Two forms read it:
 + 1`` (YaRN's correction of the softmax's temperature).
 
 Device scopes (docs/OBSERVABILITY.md): ``mla_proj`` (the low-rank
-projections, norms, rotation, and the output projection), ``mla_kv_up``,
-``mla_attn_prefill``, ``mla_absorb``; the kernel is
-``fleetx_mla_decode_paged``.
+projections, norms, rotation, and the output projection),
+``mla_attn_prefill`` (a chunk's gather of its lane's rows and the kernel
+``fleetx_mla_prefill``; in the plain twin the scores, softmax and value
+products), ``mla_kv_up`` (the plain twin's re-expansion: the kernel has it
+inside), ``mla_absorb``; a tick's kernel is ``fleetx_mla_decode_paged``.
 
 :class:`LatentStack` is ``MixedStack`` with this operator as its attention
 kind, the two leaves at their own widths, the angles computed from the
@@ -67,7 +75,7 @@ from fleetx_tpu.models.gpt.model import (
 __all__ = ["KEY_BLOCK", "LatentAttention", "LatentStack", "rope_leaf_width",
            "softmax_scale", "yarn_frequencies", "yarn_tables"]
 
-# key rows of one block of a chunk's attention
+# key rows of one block of a chunk's attention in plain XLA (:func:`_chunk`)
 KEY_BLOCK = 1024
 _NEG = -1e30
 
@@ -241,9 +249,8 @@ class LatentAttention(nn.Module):
         table = tables[0] + jnp.asarray(layer_bases(cfg))[layer_index]
         with jax.named_scope("mla_attn_prefill"):  # the lane's rows, in order
             ckv = ckv_pool[table].reshape(-1, ckv_pool.shape[-1])
-            kr = kr_pool[table].reshape(-1, kr_pool.shape[-1])[
-                :, :cfg.qk_rope_head_dim]
-        return _chunk(cfg, q[0], w_kvb, ckv, kr, wpos[0], scale)[None]
+            kr = kr_pool[table].reshape(-1, kr_pool.shape[-1])
+        return _prefill(cfg, q[0], w_kvb, ckv, kr, wpos[0], scale)[None]
 
 
 # the seams ``perfbench/probe_axk1.py`` plants its faults in
@@ -266,23 +273,43 @@ def _expand(ckv, w_kvb, nope: int):
     return kv[..., :nope], kv[..., nope:]
 
 
-def _decode(cfg: GPTConfig, q_c, q_r, ckv_pool, kr_pool, tables, end, scale):
-    from fleetx_tpu.ops.pallas import mla_decode
+def _kernels(cfg: GPTConfig) -> bool:
+    """Whether both forms take their Pallas kernel (else its plain twin)."""
     from fleetx_tpu.ops.pallas.flash_attention import kernels_enabled
 
-    kernel = (mla_decode.mla_decode_paged
-              if cfg.use_flash_attention and kernels_enabled()
+    return cfg.use_flash_attention and kernels_enabled()
+
+
+def _decode(cfg: GPTConfig, q_c, q_r, ckv_pool, kr_pool, tables, end, scale):
+    from fleetx_tpu.ops.pallas import mla_decode
+
+    kernel = (mla_decode.mla_decode_paged if _kernels(cfg)
               else mla_decode.mla_decode_reference)
     return kernel(q_c, q_r, ckv_pool, kr_pool, tables=tables, end=end,
                   scale=scale)
 
 
+def _prefill(cfg: GPTConfig, q, w_kvb, ckv, kr, start, scale: float):
+    """One lane's chunk over its rows as gathered (``kr`` the leaf as held):
+    the kernel where ``_decode`` takes its own, else :func:`_chunk`."""
+    from fleetx_tpu.ops.pallas import mla_prefill
+
+    if not _kernels(cfg):
+        return _chunk(cfg, q, w_kvb, ckv, kr[:, :cfg.qk_rope_head_dim],
+                      start, scale)
+    with jax.named_scope("mla_attn_prefill"):
+        return mla_prefill.mla_prefill(
+            q, w_kvb, ckv, kr, start, nope=cfg.qk_nope_head_dim, scale=scale,
+            score_type=_SCORE_TYPE)
+
+
 def _chunk(cfg: GPTConfig, q, w_kvb, ckv, kr, start, scale: float):
-    """One lane's chunk, materialised: ``q`` ``[s, heads, nope + rope]`` at
-    positions ``start + [0, s)`` over the lane's rows ``ckv`` ``[t, c]`` and
-    ``kr`` ``[t, r]`` (its own among them), in blocks of ``KEY_BLOCK`` keys
-    with a running maximum and sum; blocks past the chunk's last row are
-    not computed. ``[s, heads, v]``."""
+    """One lane's chunk, materialised, in plain XLA (the kernel's twin: the
+    CPU, the tests): ``q`` ``[s, heads, nope + rope]`` at positions ``start
+    + [0, s)`` over the lane's rows ``ckv`` ``[t, c]`` and ``kr`` ``[t, r]``
+    (its own among them), in blocks of ``KEY_BLOCK`` keys with a running
+    maximum and sum; blocks past the chunk's last row are not computed.
+    ``[s, heads, v]``."""
     nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
     s, nh = q.shape[:2]
     t = ckv.shape[0]

@@ -321,6 +321,46 @@ def test_the_kernel_compiles_for_the_v5e_at_the_published_widths(
         pages * 16 * 640 * 2, rel=1e-3)
 
 
+def test_a_chunks_attention_compiled_for_the_v5e_holds_the_kernel_and_no_scores(
+        one_chip, monkeypatch):
+    """One layer's attention of the cell's 512-row chunk program at the
+    published widths, the lane's 1,600 pages gathered from the cell's pool:
+    with the kernels on it holds ``fleetx_mla_prefill`` and no float32
+    scores of 64 heads nor an expanded key or value of a block; the plain
+    twin holds both (so the text does tell)."""
+    from fleetx_tpu.ops.pallas import mla_prefill
+    from perfbench import harness
+
+    monkeypatch.setattr(mla_prefill, "_interpret", lambda: False)
+    cfg = GPTConfig.from_model_config(dict(harness.load_json(
+        "perfbench/configs/axk1-ep16-l6.json")["model"]))
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def attend(flash):
+        on = dataclasses.replace(cfg, use_flash_attention=flash)
+
+        def chunk(q, w_kvb, ckv_pool, kr_pool, table, start):
+            return latent._prefill(
+                on, q, w_kvb, ckv_pool[table].reshape(-1, 512),
+                kr_pool[table].reshape(-1, 128), start, 0.1)
+
+        pages = 6 * 38401
+        return jax.jit(chunk).lower(
+            spec((512, 64, 192)), spec((512, 64, 256)),
+            spec((pages, 16, 512)), spec((pages, 16, 128)),
+            spec((1600,), jnp.int32), spec((), jnp.int32)).compile().as_text()
+
+    monkeypatch.setenv("FLEETX_FORCE_FLASH", "1")
+    kernel, plain = attend(True), attend(False)
+    assert mla_prefill.KERNEL_NAME in kernel
+    assert mla_prefill.KERNEL_NAME not in plain
+    for array in ("f32[64,512,1024]", "bf16[1024,64,256]"):
+        assert array in plain and array not in kernel, array
+    assert "f32[64,512," not in kernel
+
+
 # ----------------------------------------------------------------- the gate
 
 def test_the_group_limited_choice_on_a_written_out_case():
