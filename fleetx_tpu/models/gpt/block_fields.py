@@ -189,14 +189,22 @@ class BlockLayoutFields:
         return {"pairs": rows * self.top_k
                 * (self.num_layers - self.num_dense_layers)}
 
-    def spans(self, rows: int, behind: int) -> dict:
-        """Span fields of a prefill call of ``rows`` tokens behind
-        ``behind`` cached ones over latent attention: the live rows ONE
-        layer attends over (and re-expands), the key rows the live steps of
-        the kernel ``fleetx_mla_prefill`` cover for them (whole blocks:
-        work and padding together), and the pairs routed."""
+    def spans(self, rows: int, behind: int, program_rows: int = 0) -> dict:
+        """Span fields of a prefill call of ``rows`` tokens (a program of
+        ``program_rows`` rows, padding included) behind ``behind`` cached
+        ones. Over latent attention: the live rows ONE layer attends over
+        (and re-expands), the key rows the live steps of the kernel
+        ``fleetx_mla_prefill`` cover for them (whole blocks: work and
+        padding together), and the pairs routed. Over grouped or window
+        attention layers of a shape the kernel ``fleetx_prefill_gqa``
+        takes: the key rows its live steps cover in one full and one window
+        layer (``hybrid.chunk_key_rows``)."""
         if not self.latent:
-            return {}
+            if not self.layer_kinds:
+                return {}
+            from fleetx_tpu.models.gpt.hybrid import chunk_key_rows
+
+            return chunk_key_rows(self, program_rows or rows, behind)
         from fleetx_tpu.ops.pallas.mla_prefill import key_rows
 
         return {"latent_rows": behind + rows,

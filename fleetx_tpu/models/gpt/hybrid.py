@@ -27,10 +27,16 @@ picks the one of the layer's kind; entry 0 is the layer's own trash page,
 as in the one-class pool. A window layer passes the decode kernels ``starts
 = max(0, end - window)``, which their ``starts`` / ``ends`` contract has
 always had, and a prefill chunk gathers the window plus the chunk and not
-the whole row.
+the whole row. One lane's chunk then runs ONE kernel a layer over the rows
+gathered, ``fleetx_prefill_gqa`` (``ops/pallas/prefill_gqa.py``: window and
+full layers alike, the window a scalar of the call; no step for a key block
+past the chunk's last row or before its first query's window), where the
+decode kernels run and the head size is whole 128-lane column blocks of the
+pool's rows (``_chunk_kernel``); every other call through the cache takes
+its plain twin ``grouped_attention`` over the same rows.
 
 Forward only where it differs from ``model.py``: a forward outside the
-cache takes the dense path (``grouped_attention``); training this block,
+cache takes the plain path (``grouped_attention``); training this block,
 with grouped heads and a window in the flash kernels, is ROADMAP R4.
 """
 
@@ -55,9 +61,12 @@ from fleetx_tpu.models.gpt.model import (
     _layer_norm,
     apply_rope,
 )
+from fleetx_tpu.ops.pallas import prefill_gqa
+from fleetx_tpu.ops.pallas.flash_attention import kernels_enabled
 
-__all__ = ["HybridDecoderLayer", "HybridSelfAttention", "grouped_attention",
-           "init_cache", "layer_bases", "total_pages", "write_rows"]
+__all__ = ["HybridDecoderLayer", "HybridSelfAttention", "chunk_key_rows",
+           "grouped_attention", "init_cache", "layer_bases", "total_pages",
+           "window_gather", "write_rows"]
 
 _NEG = -1e30  # a masked score: finite, so a row of padding stays finite
 
@@ -136,6 +145,38 @@ def grouped_attention(q, k, v, allowed):
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
     return out.reshape(b, s, heads, d)
+
+
+def window_gather(cfg: GPTConfig, wpos, s: int, n: int):
+    """``(pages, first)``: the logical pages of an ``n``-page lane that a
+    window layer gathers for a chunk of ``s`` rows at ``wpos`` (an int, or
+    ``[b]`` for ``first`` ``[b]``): the window plus the chunk."""
+    ps, window = cfg.decode_page_size, cfg.sliding_window
+    pages = min(n, -(-(window + s - 1) // ps) + 1)
+    xp = np if isinstance(wpos, int) else jnp
+    return pages, xp.clip((wpos - window + 1) // ps, 0, n - pages)
+
+
+def chunk_key_rows(cfg: GPTConfig, s: int, start: int) -> dict:
+    """Span fields of a chunk program of ``s`` rows at ``start``: the key
+    rows the live steps of the kernel ``fleetx_prefill_gqa`` cover in ONE
+    full and ONE window layer and key head (whole blocks: work and padding
+    together), for the kinds of layer the configuration has; none for a
+    shape the kernel does not take."""
+    ps = cfg.decode_page_size
+    if not prefill_gqa.takes(1, s, cfg.head_dim, ps):
+        return {}
+    n = -(-(cfg.decode_cache_len or cfg.max_position_embeddings) // ps)
+    fields = {}
+    if 0 in cfg.window_layers:
+        fields["attn_full_key_rows"] = prefill_gqa.key_rows(
+            start, s, 0, None, prefill_gqa.padded_rows(n * ps))
+    if 1 in cfg.window_layers:
+        pages, first = window_gather(cfg, start, s, n)
+        fields["attn_window_key_rows"] = prefill_gqa.key_rows(
+            start, s, int(first) * ps, cfg.sliding_window,
+            prefill_gqa.padded_rows(pages * ps))
+    return fields
 
 
 class HybridSelfAttention(SelfAttention):
@@ -292,30 +333,53 @@ class HybridSelfAttention(SelfAttention):
                 windowed, lambda: kernel(None),
                 lambda: kernel(jnp.maximum(end - window, 0)))
 
-        # dense (a prefill chunk; every call off the TPU): gather the rows
-        # the chunk's queries can see, the whole row for a full layer, the
-        # window plus the chunk for a window layer
+        # a prefill chunk (and every call the decode kernel does not take):
+        # gather the rows the chunk's queries can see, the whole row for a
+        # full layer, the window plus the chunk for a window layer; ONE
+        # lane's chunk then runs the kernel ``fleetx_prefill_gqa`` over
+        # them where ``_chunk_kernel`` says so, else ``grouped_attention``
+        kernel = self._chunk_kernel(b, s)
         q_pos = wpos[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
 
-        def dense(first, pages):
+        def dense(first, pages, window):
             held = tables if pages == n else jax.vmap(
                 lambda row, at: jax.lax.dynamic_slice(row, (at,), (pages,))
             )(tables, first)
+            if kernel:
+                # whole key blocks: the layer's trash page behind the last
+                # page gathered, at positions no query of the chunk sees
+                more = prefill_gqa.padded_rows(pages * ps) // ps - pages
+                held = jnp.concatenate([held, jnp.broadcast_to(
+                    jnp.asarray(layer_bases(cfg))[layer_index], (b, more))], 1)
+            keys = paged_gather_kv(k_pool, held)
+            values = paged_gather_kv(v_pool, held)
+            if kernel:
+                return prefill_gqa.prefill_gqa(
+                    q[0], keys[0], values[0], wpos[0], first[0] * ps,
+                    window=window)[None]
             k_pos = (first[:, None] * ps
                      + jnp.arange(pages * ps, dtype=jnp.int32)[None, :])
             allowed = self._visible(q_pos[:, :, None], k_pos[:, None, :],
                                     windowed)
-            return grouped_attention(q, paged_gather_kv(k_pool, held),
-                                     paged_gather_kv(v_pool, held),
-                                     allowed[:, None])
+            return grouped_attention(q, keys, values, allowed[:, None])
 
         def in_window():
-            pages = min(n, -(-(window + s - 1) // ps) + 1)
-            first = jnp.clip((wpos - window + 1) // ps, 0, n - pages)
-            return dense(first, pages)
+            pages, first = window_gather(cfg, wpos, s, n)
+            return dense(first, pages, window)
 
         return self._by_kind(
-            windowed, lambda: dense(jnp.zeros((b,), jnp.int32), n), in_window)
+            windowed, lambda: dense(jnp.zeros((b,), jnp.int32), n, None),
+            in_window)
+
+    def _chunk_kernel(self, b: int, s: int) -> bool:
+        """Whether this call's attention through the cache runs the chunk
+        kernel (``ops/pallas/prefill_gqa.py``): where the decode kernels
+        run (``use_flash_attention`` on a TPU, or forced on the CPU) outside
+        a multi-device mesh, on a shape the kernel takes."""
+        return (self.cfg.use_flash_attention and kernels_enabled()
+                and self._decode_shard_mesh() is None
+                and prefill_gqa.takes(b, s, self.cfg.head_dim,
+                                      self.cfg.decode_page_size))
 
 
 class HybridDecoderLayer(nn.Module):

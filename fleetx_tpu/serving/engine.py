@@ -2280,10 +2280,15 @@ class ServingEngine:
         return min(max(bucket, n), self.cache_len - shared)
 
     def _scan_rows(self, n: int, shared: int) -> dict:
-        """Span field of a prefill call over lane-resident state: the rows
-        its selective scans run over, padding included."""
-        return ({"scan_rows": self._bucket_rows(n, shared)} if self._lane_state
-                else self.model.cfg.spans(n, shared) if self._state_rows else {})
+        """Span fields of a prefill call: over lane-resident state the rows
+        its selective scans run over, padding included; over layers with a
+        kind what the configuration counts of its chunk kernels' work
+        (``block_fields.spans``)."""
+        cfg, bucket = self.model.cfg, self._bucket_rows(n, shared)
+        fields = {"scan_rows": bucket} if self._lane_state else {}
+        if getattr(cfg, "layer_kinds", False):
+            fields.update(cfg.spans(n, shared, bucket))
+        return fields
 
     def _paged_prefill_call(self, req: Request, suffix, shared, lane,
                             replay: bool = False):

@@ -361,6 +361,63 @@ def test_a_chunks_attention_compiled_for_the_v5e_holds_the_kernel_and_no_scores(
     assert "f32[64,512," not in kernel
 
 
+@pytest.mark.parametrize("rows, window_rows", [(512, 4624), (256, 4368)])
+def test_a_grouped_chunks_attention_compiled_for_the_v5e_holds_no_scores(
+        one_chip, monkeypatch, rows, window_rows):
+    """One layer's attention of the long-document cell's 512-row and
+    256-row chunk programs at SmallThinker's published widths (28 heads
+    over 4 of 128, a window of 4,096), both kinds of layer behind the
+    layer's own conditional, one lane's 800 pages gathered from the cell's
+    pool of two classes: with the kernels on it holds ``fleetx_prefill_gqa``
+    and no float32 scores of 28 heads over the lane's or the window's rows;
+    the plain twin holds both (so the text does tell)."""
+    from fleetx_tpu.models.gpt import hybrid
+    from fleetx_tpu.ops.pallas import prefill_gqa
+    from perfbench import harness
+
+    monkeypatch.setattr(prefill_gqa, "_interpret", lambda: False)
+    monkeypatch.setenv("FLEETX_FORCE_FLASH", "1")
+    cfg = dataclasses.replace(
+        GPTConfig.from_model_config(dict(harness.load_json(
+            "perfbench/configs/smallthinker-21b-a3b-l8.json")["model"])),
+        dtype=jnp.bfloat16, decode_cache_len=12800, decode_page_size=16,
+        decode_num_pages=24 * 800 + 1, decode_window_pages=24 * 289 + 1)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def attend(flash):
+        layer = hybrid.HybridSelfAttention(
+            dataclasses.replace(cfg, use_flash_attention=flash))
+        out_proj = jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, rows, 2560), jnp.bfloat16),
+            layer_index=0))["params"]["out_proj"]
+
+        def chunk(out_proj, q, k_pool, v_pool, tables, start, index):
+            return layer.apply(
+                {"params": {"out_proj": out_proj},
+                 "cache": {"cached_key": k_pool, "cached_value": v_pool,
+                           "cache_index": jnp.int32(0)}},
+                q, decode=True, cache_positions=start, block_tables=tables,
+                layer_index=index, phase="attend", mutable=["cache"])[0]
+
+        pool = (hybrid.total_pages(cfg), 16, 512)
+        return jax.jit(chunk).lower(
+            jax.tree.map(lambda x: spec(x.shape, x.dtype), out_proj),
+            spec((1, rows, 28, 128)), spec(pool), spec(pool),
+            spec((2, 1, 800), jnp.int32), spec((1,), jnp.int32),
+            spec((), jnp.int32)).compile().as_text()
+
+    kernel, plain = attend(True), attend(False)
+    assert kernel.count(prefill_gqa.KERNEL_NAME) >= 2   # one call a kind
+    assert prefill_gqa.KERNEL_NAME not in plain
+    for array in (f"f32[1,4,7,{rows},12800]",
+                  f"f32[1,4,7,{rows},{window_rows}]"):
+        assert array in plain and array not in kernel, array
+    assert f"f32[1,4,7,{rows}," not in kernel
+    assert f"f32[28,{rows}," not in kernel
+
+
 # ----------------------------------------------------------------- the gate
 
 def test_the_group_limited_choice_on_a_written_out_case():

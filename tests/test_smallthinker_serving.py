@@ -58,14 +58,13 @@ def build(**changes):
     return GPTForPretraining(GPTConfig(**{**SIZES, **changes}))
 
 
-@pytest.fixture(scope="module")
-def variables():
+def seeded(model):
     """Seeded weights. At width 64 with every weight at the initializer's
     0.02 the head dominates and the layers decide nothing, so the layers'
     matrices are scaled up and the norm weights moved off 1, until
     attention, the rotation, the window, both norms and the router all
     decide the logits (a fault in any of them then shows)."""
-    v = flax.core.meta.unbox(jax.jit(build().init)(
+    v = flax.core.meta.unbox(jax.jit(model.init)(
         jax.random.PRNGKey(0), np.zeros((1, 8), np.int32)))
 
     def stir(path, x):
@@ -76,6 +75,11 @@ def variables():
         return x * 8.0 if "layers" in name else x
 
     return jax.tree_util.tree_map_with_path(stir, v)
+
+
+@pytest.fixture(scope="module")
+def variables():
+    return seeded(build())
 
 
 def distance(system, want):
