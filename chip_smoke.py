@@ -12,8 +12,8 @@ the full width of GPT-345M (hidden 1024, 24 layers, 16 heads of 64, vocab
   Every step's loss must be finite and near ln(50304) = 10.83, and the
   compiled train step must hold the Mosaic custom calls of the flash
   forward, dq and dk/dv kernels.
-- serving leg: a ``ServingEngine`` built the way ``tools/bench_serving.py``
-  builds it (bf16, flash decode, 8 lanes, paged cache, page 16, prefill
+- serving leg: a ``ServingEngine`` at the serving defaults the cells
+  share (bf16, flash decode, 8 lanes, paged cache, page 16, prefill
   bucket 32) answering 8 requests with prompts of 32-192 tokens and 16-160
   new tokens. Every request must return exactly its token budget with
   ``finish_reason == "max_length"``, with no recovery, poison retirement or
@@ -123,7 +123,7 @@ def _mosaic_calls(hlo_text: str, kernel_name: str) -> int:
 
 def _token_file() -> str:
     """The ``{prefix}_ids.npy`` + ``{prefix}_idx.npz`` pair GPTDataset
-    reads (the recipe of tools/bench_matrix.py make_dataset) at the real
+    reads (256 random documents of 1500-2500 tokens, concatenated) at the real
     vocabulary, from a seed."""
     import numpy as np
 
@@ -224,7 +224,7 @@ def leg_train(dp: int, mp: int, dropout: float, steps: int) -> dict:
 
 
 def leg_serve(mp: int) -> dict:
-    """A ServingEngine at GPT-345M width (tools/bench_serving.py's build)
+    """A ServingEngine at GPT-345M width (bf16, flash decode, paged cache)
     answering REQUESTS; over an mp mesh when ``mp`` > 1."""
     device = _own_the_chip()
 

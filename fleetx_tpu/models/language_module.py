@@ -27,12 +27,12 @@ __all__ = ["LanguageModule", "GPTModule"]
 
 class LanguageModule(BasicModule):
     """Adds LM-style logging: loss, lr, avg step cost, ips (tokens/s) — the
-    ``ips:`` keyword line is what the benchmark harness parses (reference
+    ``ips:`` keyword line is the reference's log contract (reference
     run_benchmark.sh:20-22)."""
 
     def training_step_end(self, log: Dict) -> None:
         # mfu rides the same parsed line: tokens/s alone is not comparable
-        # across configs, and the BENCH_* records already report MFU — the
+        # across configs, and perfbench/run.py reports ``train_mfu`` — the
         # live log should speak the same language (docs/OBSERVABILITY.md).
         # "-" when XLA exposed no flops for this step program.
         mfu = log.get("mfu")

@@ -98,8 +98,8 @@ def _env_block(name: str, default: int) -> int:
     return val
 
 
-# overridable without code changes so block sizes can be swept per TPU
-# generation (bench harness: FLEETX_FLASH_BLOCK_Q=256 python bench.py).
+# overridable without code changes (nothing sweeps them any more: PR 30
+# read 128 / 256 / 512 within 1.5%; they go with ROADMAP D11's cure).
 # 512x512 default from the round-4 v5e sweep: at 345M/seq1024/b8 it measured
 # 23.8k tok/s vs 18.1k at 128x128 (the per-cell VPU work of online softmax
 # amortizes over bigger tiles, and fewer grid steps means less fixed

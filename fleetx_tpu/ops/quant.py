@@ -42,8 +42,8 @@ __all__ = [
 # may diverge from the bf16 reference only in its trailing this-fraction
 # of tokens (greedy decode is chaotic after a first argmax flip, so the
 # longest common prefix is the meaningful measure). Consumed by
-# tests/serving_parity.py (QUANT_ATOL) and the tools/bench_serving.py
-# int8 record — ONE number, change it here with hardware evidence.
+# tests/serving_parity.py (QUANT_ATOL), which every quantized serving
+# test reads — ONE number, change it here with hardware evidence.
 QUANT_PREFIX_BUDGET = 0.25
 
 

@@ -37,7 +37,7 @@ schedules exist:
   sequential schedule's v*(M + pp - 1). For M >> v*pp that is ~v x fewer
   scan ticks (collective permutes, loop iterations, per-tick dispatch),
   bought with dead-row work during the longer single fill/drain —
-  tools/bench_pp_bubble.py --virtual-pp measures the trade and gates it.
+  not measured on the chip: no benchmark cell runs a pipeline (PERF.md).
   The param layout equals the plain pipe layout with v*pp stage rows
   (row g holds global chunk g = layers [g*lpc, (g+1)*lpc)), so the
   remap helpers and checkpoint converters need no new scopes.

@@ -19,9 +19,11 @@ The wire format for inputs mirrors the outputs: a request "prompt" is
 one image, channels-last ``[H, W, C]`` float32, flattened and bit-cast
 to int32 (:func:`encode_floats`) — exactly ``H*W*C`` elements, which
 is what ``_validate`` enforces (and what makes cross-model dispatch
-mistakes fail loudly: a text prompt is never the right size). Batches
-need no padding — every image is the same shape — so there is exactly
-ONE jitted program per batch bucket. docs/SERVING.md
+mistakes fail loudly: a text prompt is never the right size). Every
+image is the same shape and every batch is padded to ``slots`` rows, so
+there is exactly ONE jitted program: a request's bytes do not depend on
+how many rode beside it (two batch shapes are two programs, and XLA
+may round them differently in the last bits). docs/SERVING.md
 "Heterogeneous fleet".
 """
 
@@ -32,7 +34,7 @@ from typing import List
 import jax
 import numpy as np
 
-from fleetx_tpu.serving.batch_engine import BatchingEngine, _bucket
+from fleetx_tpu.serving.batch_engine import BatchingEngine
 from fleetx_tpu.serving.model_protocol import ModelCapabilities
 
 __all__ = ["EmbeddingEngine", "decode_floats", "encode_floats"]
@@ -88,8 +90,7 @@ class EmbeddingEngine(BatchingEngine):
                 f"got {prompt.size}")
 
     def _run_batch(self, requests) -> List[List[int]]:
-        b = _bucket(len(requests), self.slots)
-        images = np.zeros((b,) + self.image_shape, np.float32)
+        images = np.zeros((self.slots,) + self.image_shape, np.float32)
         for i, r in enumerate(requests):
             images[i] = decode_floats(r.prompt).reshape(self.image_shape)
         out = np.asarray(self._fwd(self.params, images))

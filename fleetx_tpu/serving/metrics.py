@@ -277,7 +277,7 @@ class ServingMetrics:
             "Fresh (non-shared) pages claimed per admitted paged request")
         # how long a tick's decode was stalled by prefill work — under
         # chunking this is bounded by ~one chunk-sized call (the claim
-        # tools/bench_serving.py's chunked record prices)
+        # of docs/SERVING.md "Chunked prefill")
         self._h_prefill_stall = hist(
             "fleetx_serving_prefill_stall_ms",
             "Milliseconds a tick spent on prefill work (admissions + "

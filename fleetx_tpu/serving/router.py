@@ -121,8 +121,8 @@ drains every replica gracefully and finalizes the rest. Observability:
 ``fleetx_router_*`` metrics + ``replica_out`` / ``replica_back`` /
 ``replica_dead`` / ``request_migrated`` events
 (docs/OBSERVABILITY.md); chaos coverage in ``tools/chaos_check.py``
-(``router_kill``, ``router_saturation``) and the SLO goodput record in
-``tools/bench_serving.py`` (serving/workload.py generates the trace).
+(``router_kill``, ``router_saturation``) and the SLO goodput view in
+``tests/test_router.py`` (serving/workload.py generates the trace).
 """
 
 from __future__ import annotations

@@ -11,8 +11,8 @@ the rest keep meeting deadlines — and loses it for collapsing (everyone
 slow, nobody shed). This module is that measurement substrate
 (ROADMAP item 5): every later serving direction (disaggregated
 prefill/decode, heterogeneous fleets) is judged against it, and
-``tools/bench_serving.py`` banks its multi-replica record with a
-regression gate.
+``tools/chaos_check.py``'s router scenarios and ``tests/test_router.py``
+replay its traces.
 
 Three pieces, all host-only and engine-agnostic:
 
@@ -259,10 +259,10 @@ def disagg_spec(n_requests: int = 32, *,
     decodes — the shape where an arriving prefill steals the most
     decode ticks from in-flight requests on a colocated replica, and
     where shipping KV to a dedicated decode replica pays for itself.
-    One tenant, no bursts, no SLOs: ``tools/bench_serving.py`` replays
-    the trace through colocated and disaggregated routers and asserts
-    byte parity, so the spec stays deliberately minimal (the goodput
-    machinery is exercised by the router_slo record instead)."""
+    One tenant, no bursts, no SLOs: a caller replays the trace through
+    colocated and disaggregated routers and compares the streams byte
+    for byte, so the spec stays deliberately minimal (the goodput
+    machinery is exercised by the SLO traces instead)."""
     return WorkloadSpec(
         seed=seed, n_requests=n_requests, vocab=vocab,
         arrival_rate=1000.0,  # effectively simultaneous arrivals

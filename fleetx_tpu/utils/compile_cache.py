@@ -2,8 +2,8 @@
 
 Every entry point that compiles calls :func:`enable_compile_cache` before
 its first jit: ``tools/train.py``, ``eval.py``, ``export.py``,
-``inference.py``, the ``serve.py`` replica worker, ``bench.py``,
-``tools/bench_*.py`` and ``chip_smoke.py``. The directory's path is part
+``inference.py``, the ``serve.py`` replica worker, the drivers of
+``perfbench/`` and ``chip_smoke.py``. The directory's path is part
 of the cache's key, so a directory that moves between runs never hits;
 there are exactly two places it can be:
 

@@ -1,6 +1,6 @@
 """Hardware peak-FLOPs lookup shared by MFU accounting everywhere.
 
-One table (public spec sheets, dense bf16) so ``bench.py``'s BENCH_*
+One table (public spec sheets, dense bf16) so ``chip_smoke.py``'s
 records, the Trainer's live ``mfu`` gauge/log-line, and any future
 report all divide by the SAME peak — MFU numbers stay comparable across
 surfaces. A device the table does not list is an error, never a default:

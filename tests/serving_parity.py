@@ -36,8 +36,8 @@ import jax.numpy as jnp
 # In practice the tiny test models match 100% — the budget absorbs
 # near-tie argmax flips, not systematic drift (that is what the
 # tools/eval.py perplexity gate measures). ONE number and ONE prefix
-# measure, owned by ops/quant.py and shared with the
-# tools/bench_serving.py int8 record.
+# measure, owned by ops/quant.py and shared by every quantized
+# serving test.
 from fleetx_tpu.ops.quant import QUANT_PREFIX_BUDGET as QUANT_ATOL
 from fleetx_tpu.ops.quant import common_prefix_len  # noqa: F401  (re-export)
 

@@ -57,29 +57,57 @@ def test_repo_tree_is_clean():
     assert r.returncode == 0, r.stdout[-1500:]
 
 
+def _sources(*patterns) -> str:
+    """The text of every file under the repo that matches a glob pattern."""
+    import glob
+
+    text = []
+    for pat in patterns:
+        for path in glob.glob(os.path.join(REPO, pat), recursive=True):
+            with open(path) as f:
+                text.append(f.read())
+    return "\n".join(text)
+
+
 def test_env_vars_documented():
     """Drift gate (ISSUE 5): every ``FLEETX_*`` env var mentioned under
     fleetx_tpu/ and tools/ must appear in docs/ENV_VARS.md — this issue
     found FLEETX_FLASH_BLOCK_K read in ops/pallas/flash_attention.py but
     absent from the doc, and this test keeps that class of drift out."""
-    import glob
     import re
 
     with open(os.path.join(REPO, "docs", "ENV_VARS.md")) as f:
         doc = f.read()
-    reads = set()
-    for pat in ("fleetx_tpu/**/*.py", "tools/**/*.py"):
-        for path in glob.glob(os.path.join(REPO, pat), recursive=True):
-            with open(path) as f:
-                src = f.read()
-            # trailing [A-Z0-9]: an f-string prefix like "FLEETX_FLASH_"
-            # (dynamic name) reduces to its stem, which the doc's real
-            # entries cover as a substring
-            reads |= set(re.findall(r"FLEETX_[A-Z0-9_]*[A-Z0-9]", src))
+    # trailing [A-Z0-9]: an f-string prefix like "FLEETX_FLASH_" (dynamic
+    # name) reduces to its stem, which the doc's real entries cover as a
+    # substring
+    reads = set(re.findall(
+        r"FLEETX_[A-Z0-9_]*[A-Z0-9]",
+        _sources("fleetx_tpu/**/*.py", "tools/**/*.py")))
     missing = sorted(v for v in reads if v not in doc)
     assert not missing, (
         f"env vars read in code but undocumented in docs/ENV_VARS.md: "
         f"{missing}")
+
+
+def test_documented_env_vars_are_read():
+    """The table read the other way: every ``FLEETX_*`` / ``BENCH_*`` row
+    of docs/ENV_VARS.md names a variable some file of the program still
+    reads, so a deleted switch cannot keep its row."""
+    import re
+
+    with open(os.path.join(REPO, "docs", "ENV_VARS.md")) as f:
+        rows = [line for line in f if line.startswith("| `")]
+    documented = set()
+    for row in rows:
+        documented |= set(re.findall(
+            r"`((?:FLEETX|BENCH)_[A-Z0-9_]*[A-Z0-9])`", row.split("|")[1]))
+    assert len(documented) > 50, "env-var table not found (format changed?)"
+    src = _sources("fleetx_tpu/**/*.py", "tools/**/*.py", "perfbench/**/*.py",
+                   "bench.py", "chip_smoke.py", "tests/conftest.py")
+    unread = sorted(v for v in documented if v not in src)
+    assert not unread, (
+        f"rows of docs/ENV_VARS.md that no file reads any more: {unread}")
 
 
 def test_metric_names_linted_and_documented():
@@ -120,7 +148,7 @@ def test_shell_scripts_parse():
     import glob
 
     scripts = [
-        p for pat in ("projects/**/*.sh", "benchmarks/**/*.sh", "tools/*.sh")
+        p for pat in ("projects/**/*.sh", "tools/*.sh")
         for p in glob.glob(os.path.join(REPO, pat), recursive=True)
     ]
     assert len(scripts) >= 40, scripts  # the launch-script zoo is present

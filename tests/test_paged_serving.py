@@ -518,10 +518,7 @@ def test_paged_staggered_one_shot_parity(model_and_params):
 
 # ------------------------------------------------------------ the paged wins
 
-@pytest.mark.slow  # 33.1s baseline (PR 12 tier-1 budget audit): the
 def test_prefix_reuse_cuts_prefill_and_pages(model_and_params):
-    # prefix-hit/parity contract stays tier-1 via the bench_serving
-    # schema test's shared-prefix record assertions
     """N requests sharing a system prompt: the trie must cut prefill work
     and fresh pages, asserted against the no-reuse arithmetic via the
     ServingMetrics counters — tokens byte-identical to one-shot. (The

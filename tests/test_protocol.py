@@ -260,3 +260,24 @@ def test_results_are_exact_and_deterministic(zoo, kind):
     r3 = eng2.submit(_prompt(kind))
     res2 = eng2.drain()
     assert np.array_equal(res2[r3].tokens, res[r1].tokens)
+
+
+@pytest.mark.parametrize("others", [1, 3], ids=["1", "slots-1"])
+@pytest.mark.parametrize("kind", ["vit", "ernie"])
+def test_result_does_not_depend_on_batch_occupancy(zoo, kind, others):
+    """A request's bytes are the same alone in its batch and beside
+    ``others`` different requests: one program shape per engine (a
+    second batch shape is a second program, which XLA may round
+    differently in the last bits)."""
+    slots = 4
+    alone = _make(zoo, kind, slots=slots)
+    rid = alone.submit(_prompt(kind))
+    want = alone.drain()[rid].tokens
+    eng = _make(zoo, kind, slots=slots)
+    rid = eng.submit(_prompt(kind))
+    for salt in range(1, others + 1):
+        eng.submit(_prompt(kind, salt=salt))
+    tick = eng.step()
+    assert tick["admitted"] == 1 + others and tick["forwards"] == 1
+    got = eng.drain()
+    assert np.array_equal(got[rid].tokens, want)

@@ -339,10 +339,7 @@ def test_sigterm_requests_drain(tiny):
     assert signal.getsignal(signal.SIGTERM) is prev
 
 
-@pytest.mark.slow  # 10.2s baseline (PR 14 tier-1 budget audit): the
 def test_tick_wallclock_metrics_present(tiny):
-    # tick_ms_p50/p99 schema stays tier-1 via the bench faulted record's
-    # schema test (asserts both > 0 on a recovered engine)
     """Per-tick wall-clock percentiles ride the snapshot so recovery cost
     is observable next to steady-state ticks."""
     _, eng = _run(tiny)

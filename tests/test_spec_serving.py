@@ -240,11 +240,7 @@ def test_spec_proposer_kwarg_implies_spec(model_and_params):
         _engine(model, params, spec=False, spec_proposer=NgramProposer())
 
 
-@pytest.mark.slow  # 15.5s baseline (PR 14 tier-1 budget audit): the
 def test_spec_acceptance_on_repetitive_prompt(model_and_params):
-    # acceptance contract stays tier-1 via the bench spec record's
-    # schema test (tokens_per_tick_mean > 1 and acceptance_rate > 0
-    # asserted on the same repetitive-workload shape)
     """Acceptance-rate sanity: on a motif-repeating prompt with a long
     EOS-free decode, the n-gram proposer must accept far more than
     nothing — the whole point of prompt-lookup drafting."""

@@ -1,10 +1,9 @@
 """CLI helper tools: the parallel shell executor (reference
 ppfleetx/tools/multiprocess_tool.py), the Imagen text-embedding
 precompute tool (replacing the reference's in-process T5/DeBERTa encode,
-imagen/utils.py), and the serving-mode bench harness
-(tools/bench_serving.py, smoke-tested tiny on CPU)."""
+imagen/utils.py), the chaos driver's scenarios and the observability
+dump."""
 
-import importlib
 import json
 import subprocess
 import sys
@@ -70,379 +69,6 @@ def test_precompute_text_embeddings_hash(tmp_path):
     assert not np.array_equal(embeds[0], embeds[2])
     # rows are masked beyond caption length
     assert np.all(embeds[0][3:] == 0)
-
-
-@pytest.mark.slow  # 84.8s baseline (PR 17 tier-1 budget audit): the
-# full bench mode-matrix (static/continuous/shared-prefix/faulted/int8/
-# chunked/spec/mesh/sweep/router/disagg) re-runs every serving mode.
-# The record envelope + harness + parity contract stays tier-1 via
-# test_bench_serving_http_record_schema (same _model/_workload/
-# _run_continuous substrate, same schema shape), and each mode's
-# underlying engine contract has its own tier-1 suite (test_serving,
-# test_chunked_serving, test_spec_serving, test_quantized_serving,
-# test_mesh_serving, test_router, test_serving_disagg).
-def test_bench_serving_records_schema(monkeypatch):
-    """Serving bench on the tiny CPU config: static, continuous,
-    shared-prefix, faulted, int8, and (env-gated) page-sweep modes all
-    produce finite throughput records with the documented schema,
-    continuous tokens are byte-identical to static's (detail.parity —
-    the bench doubles as a scheduling-only comparison), the shared-prefix
-    warm pass reports the prefix-reuse counters, the int8 record carries
-    the precision/HBM comparison fields with tolerance parity asserted,
-    and each swept page size stays byte-identical."""
-    monkeypatch.setenv("BENCH_SERVING_TINY", "1")
-    monkeypatch.setenv("BENCH_SERVING_PAGE_SIZES", "8")
-    sys.path.insert(0, REPO)
-    import tools.bench_serving as bs
-
-    bs = importlib.reload(bs)  # re-read the _TINY env gate
-    import jax
-
-    recs = bs.serving_records(n_requests=6, slots=2)
-    # the mesh record degrades gracefully below 2 devices (the
-    # FLEETX_TEST_PLATFORM=real single-chip certification run)
-    has_mesh = jax.device_count() >= 2
-    want = ["gpt_345m_serving_static", "gpt_345m_serving_continuous",
-            "gpt_345m_serving_shared_prefix", "gpt_345m_serving_faulted",
-            "gpt_345m_serving_int8", "gpt_345m_serving_chunked",
-            "gpt_345m_serving_spec"]
-    if has_mesh:
-        want.append("gpt_345m_serving_mesh")
-    want.append("gpt_345m_serving_page_sweep")
-    want.append("gpt_345m_serving_router_slo")
-    want.append("gpt_345m_serving_disagg")
-    want.append("gpt_345m_serving_hetero")
-    want.append("gpt_345m_serving_router_qos")
-    assert [r["metric"] for r in recs] == want
-    static, cont, shared, faulted, int8, chunked, spec = recs[:7]
-    mesh = recs[7] if has_mesh else None
-    sweep = recs[-5]
-    router = recs[-4]
-    disagg = recs[-3]
-    hetero = recs[-2]
-    qos = recs[-1]
-    for r in recs:
-        if r["metric"] in ("gpt_345m_serving_router_slo",
-                           "gpt_345m_serving_disagg",
-                           "gpt_345m_serving_hetero",
-                           "gpt_345m_serving_router_qos"):
-            continue  # router-level records, asserted separately below
-        assert r["unit"] == "tokens/s"
-        assert np.isfinite(r["value"]) and r["value"] > 0
-        d = r["detail"]
-        assert d["requests"] == 6 and d["slots"] == 2
-        # the acceptance quartet: queue depth, occupancy, TTFT, tokens/s
-        assert np.isfinite(d["queue_depth_mean"])
-        assert 0 < d["slot_occupancy_mean"] <= 1
-        assert d["ttft_ms_p50"] > 0 and d["ttft_ms_p95"] >= d["ttft_ms_p50"]
-        assert d["useful_tokens"] > 0
-    # same useful work, byte-identical tokens, no dead padding in continuous
-    assert cont["detail"]["parity"] is True
-    assert cont["detail"]["useful_tokens"] == static["detail"]["useful_tokens"]
-    assert cont["detail"]["dead_token_frac"] == 0.0
-    assert static["detail"]["generated_tokens"] >= static["detail"]["useful_tokens"]
-    # the shared-prefix warm pass must actually hit the trie — every
-    # request reuses the system prompt's full pages — byte-identically
-    # to its own trie-cold pass
-    d = shared["detail"]
-    assert d["parity"] is True
-    assert d["prefix_hit_rate"] == 1.0
-    assert d["prefill_tokens_saved"] > 0
-    assert 0 < d["page_occupancy_peak"] <= 1
-    # the faulted run priced exactly one recovery, lost no bytes, and
-    # surfaced the crash-safety observability fields
-    d = faulted["detail"]
-    assert d["parity"] is True
-    assert d["engine_recoveries"] == 1
-    assert d["poison_retired"] == 0
-    assert 0 <= d["recovery_overhead_frac"] < 1
-    assert d["tick_ms_p50"] > 0 and d["tick_ms_p99"] >= d["tick_ms_p50"]
-    # the int8 record: precision labels, measured HBM halving, decode
-    # cost-model bytes both ways, and tolerance parity (>= 75% leading
-    # tokens vs bf16 — asserted inside serving_records too)
-    d = int8["detail"]
-    assert d["parity"] is True and d["parity_prefix_frac_min"] >= 0.75
-    assert d["kv_dtype"] == "int8" and d["weight_dtype"] == "int8"
-    assert 0 < d["kv_cache_bytes"] < 0.5 * d["kv_cache_bytes_bf16"]
-    assert 0 < d["kv_bytes_per_token"] < d["kv_bytes_per_token_bf16"]
-    assert 0 < d["weight_bytes"] < d["weight_bytes_bf16"]
-    assert d["speedup_vs_bf16"] > 0
-    # cost-model decode bytes: measurable on the CPU XLA path too, but
-    # the int8 < bf16 ordering is a FLASH-path (TPU) claim — the CPU
-    # dense fallback materializes dequantized f32 copies, so here we
-    # only pin that both precisions were measured
-    assert d["decode_bytes_per_token_int8"] is None or (
-        d["decode_bytes_per_token_int8"] > 0)
-    assert d["decode_bytes_per_token_bf16"] is None or (
-        d["decode_bytes_per_token_bf16"] > 0)
-    # the chunked record: byte parity with vs without chunking, chunks
-    # actually ran, TPOT/stall percentiles for both, and the spill
-    # sub-report shows the host tier sustaining the prefix hit rate the
-    # device-only pool loses under oversubscription
-    d = chunked["detail"]
-    assert d["parity"] is True and d["prefill_chunks"] > 0
-    assert d["tpot_ms_p99"] >= d["tpot_ms_p50"] > 0
-    assert d["unchunked"]["tpot_ms_p99"] > 0
-    assert d["tpot_p99_ratio_vs_unchunked"] > 0
-    assert d["prefill_stall_ms_p99"] > 0
-    sp = d["spill"]
-    assert sp["parity"] is True
-    assert sp["host_revived_pages"] > 0
-    assert sp["host_spilled_pages"] >= sp["host_revived_pages"]
-    assert (sp["prefix_hit_rate_host_on"]
-            > sp["prefix_hit_rate_host_off"])
-    assert (sp["prefill_tokens_saved_host_on"]
-            > sp["prefill_tokens_saved_host_off"])
-    # the speculative record: byte parity vs the non-speculative engine,
-    # a real multi-token multiplier (mean tokens-per-tick > 1 is the
-    # acceptance gate), the proposer economics (acceptance rate,
-    # proposed/accepted counters), a measured speedup-vs-baseline (a
-    # harness number at TINY sizes — the per-tick host sync dominates
-    # toy models; the perf claim is the TPU window's), and the k sweep
-    d = spec["detail"]
-    assert d["parity"] is True and d["proposer"] == "ngram"
-    assert d["spec_k"] == 4
-    assert d["tokens_per_tick_mean"] > 1
-    assert 0 < d["acceptance_rate"] <= 1
-    assert d["spec_accepted_tokens"] <= d["spec_proposed_tokens"]
-    assert d["speedup_vs_baseline"] > 0
-    assert d["ttft_ms_p50_baseline"] > 0
-    assert [s["k"] for s in d["k_sweep"]] == [2, 4, 8]
-    for s in d["k_sweep"]:
-        assert s["tokens_per_s"] > 0 and s["tokens_per_tick_mean"] > 1
-    # the mesh record: byte parity vs the single-device engine, the mp2
-    # shape reported, and PER-DEVICE cache bytes ~half the single-device
-    # engine's (the heads-over-mp shard is real)
-    if mesh is not None:
-        d = mesh["detail"]
-        assert d["parity"] is True
-        assert d["mesh"] == {"mp": 2} and d["mesh_devices"] == 2
-        assert (0 < d["kv_cache_bytes_per_device"]
-                < 0.6 * d["kv_cache_bytes_single_device"])
-        assert d["speedup_vs_single_device"] > 0
-    # the page sweep ran its swept size byte-identically and picked it
-    # (one size in the smoke — the tier-1 budget pays per swept size;
-    # the multi-size comparison is the TPU window's job)
-    d = sweep["detail"]
-    assert d["parity"] is True
-    assert [s["page_size"] for s in d["sweep"]] == [8]
-    assert d["best_page_size"] == 8
-    assert all(s["tokens_per_s"] > 0 for s in d["sweep"])
-    # the multi-replica SLO record (docs/SERVING.md "Multi-replica
-    # router"): at-saturation everything completes (goodput is the
-    # record's value), past-saturation the router sheds but never
-    # collapses, both passes name their seeded workload hash — the
-    # regression gate compares like against like
-    assert router["unit"] == "goodput_frac"
-    assert router["value"] == router["detail"]["at"]["goodput"]
-    d = router["detail"]
-    assert d["n_replicas"] == 2 and d["replica_slots"] == 2
-    assert len(d["workload_hash_at"]) == 16
-    assert len(d["workload_hash_past"]) == 16
-    at, past = d["at"], d["past"]
-    assert at["requests"] == past["requests"] == d["requests"]
-    assert at["completed_frac"] == 1.0 and 0 < at["goodput"] <= 1
-    assert at["ttft_ms_p50"] > 0 and at["ttft_ms_p99"] >= at["ttft_ms_p50"]
-    assert past["shed_frac"] > 0 and past["completed_frac"] > 0
-    assert set(past["finish_reasons"]) <= {
-        "eos", "max_length", "timeout", "rejected", "cache_full"}
-    assert set(at["goodput_per_tenant"]) <= {"chat", "template"}
-    # the disaggregated record (docs/SERVING.md "Disaggregated
-    # prefill/decode"): 1P+1D byte-identical to 2 colocated replicas,
-    # real pages/bytes on the wire with every shipped page revived
-    # remotely, latency percentiles both ways, and the shared-disk
-    # sub-pass shows a FRESH replica sustaining the prefix hit rate
-    # out of the content-addressed store
-    assert disagg["unit"] == "tokens/s"
-    assert np.isfinite(disagg["value"]) and disagg["value"] > 0
-    d = disagg["detail"]
-    assert d["parity"] is True
-    assert d["n_prefill"] == 1 and d["n_decode"] == 1
-    assert d["kv_pages_shipped"] > 0 and d["kv_bytes_shipped"] > 0
-    assert 0 < d["kv_pages_revived_remote"] <= d["kv_pages_shipped"]
-    for side in ("colocated", "disagg"):
-        s = d[side]
-        assert s["ttft_ms_p99"] >= s["ttft_ms_p50"] > 0
-        assert s["tpot_ms_p99"] >= s["tpot_ms_p50"] > 0
-    dt = d["disk_tier"]
-    assert dt["parity"] is True
-    assert dt["fresh_replica_disk_hits"] > 0
-    assert dt["prefill_tokens_saved_fresh_replica"] > 0
-    assert dt["disk_cache_bytes"] > 0
-    assert (dt["prefix_hit_rate_fresh_replica"]
-            > dt["prefix_hit_rate_disk_off"])
-    # the heterogeneous-fleet record (docs/SERVING.md "Heterogeneous
-    # fleet"): GPT decode stays byte-identical under mixed embedding
-    # traffic through one model-aware router, every request of both
-    # families terminates exactly once, and the detail prices each
-    # family's TTFT/throughput separately
-    assert hetero["unit"] == "tokens/s"
-    assert np.isfinite(hetero["value"]) and hetero["value"] > 0
-    d = hetero["detail"]
-    assert d["parity"] is True
-    assert d["requests"] == 12  # 6 GPT + 6 embedding
-    pm = d["per_model"]
-    assert pm["gpt"]["requests"] == pm["vit"]["requests"] == 6
-    assert pm["gpt"]["tokens_per_s"] > 0
-    assert pm["gpt"]["ttft_ms_p95"] >= pm["gpt"]["ttft_ms_p50"] > 0
-    assert pm["vit"]["vectors_per_s"] > 0
-    assert pm["vit"]["embedding_dim"] > 0
-    assert pm["vit"]["ttft_ms_p95"] >= pm["vit"]["ttft_ms_p50"] > 0
-    # the per-tenant QoS record (docs/SERVING.md "Per-tenant QoS &
-    # autoscaling"): at 2× measured saturation with a flooding tenant,
-    # DRR's well-behaved goodput strictly beats FIFO's on the SAME
-    # seeded trace, the well-behaved streams are byte-identical to the
-    # uncontended run, and the closed-loop autoscale sub-pass proves the
-    # pre-warmed newcomer prefix-hit on its first segment
-    assert qos["unit"] == "goodput_frac"
-    d = qos["detail"]
-    assert qos["value"] == d["goodput_well_drr"]
-    assert d["saturation_x"] == 2.0 and d["capacity_rps"] > 0
-    assert d["goodput_well_drr"] > d["goodput_well_fifo"]
-    assert d["parity_well_behaved"] is True
-    assert d["ttft_ms_p99_well_drr"] < d["ttft_ms_p99_well_fifo"]
-    assert d["preempted"] >= 0
-    assert len(d["workload_hash"]) == 16
-    assert set(d["per_tenant"]) == {"paid", "free", "flood"}
-    for t in ("paid", "free"):
-        assert d["per_tenant"][t]["drr_ttft_ms_p99"] > 0
-    asc = d["autoscale"]
-    assert asc["scale_ups"] >= 1
-    assert asc["new_replica_prefix_hits"] > 0
-    assert asc["prewarmed_tokens"] > 0
-    assert asc["segment2_completed"] == asc["segment2_requests"]
-
-
-def test_bench_serving_http_record_schema(monkeypatch):
-    """The --http bench record (tiny CPU config): the continuous
-    workload served through real RPC replica servers + router + the
-    OpenAI SSE API banks ``gpt_345m_serving_http`` with byte parity vs
-    the in-process engine asserted, both sides' TTFT/throughput in
-    detail, and the fleet shape recorded. This is the tier-1 gate for
-    the bench record envelope and the _model/_workload/_run_continuous
-    harness (the full mode matrix is slow-marked above)."""
-    monkeypatch.setenv("BENCH_SERVING_TINY", "1")
-    sys.path.insert(0, REPO)
-    import tools.bench_serving as bs
-
-    bs = importlib.reload(bs)  # re-read the _TINY env gate
-    rec = bs.http_record(n_requests=4, slots=2)
-    assert rec["metric"] == "gpt_345m_serving_http"
-    assert rec["unit"] == "tokens/s"
-    assert np.isfinite(rec["value"]) and rec["value"] > 0
-    assert rec["vs_baseline"] is None
-    d = rec["detail"]
-    assert d["requests"] == 4 and d["slots"] == 2 and d["replicas"] == 2
-    assert d["parity"] is True
-    assert d["useful_tokens"] > 0 and d["elapsed_s"] > 0
-    assert d["ttft_ms_p95"] >= d["ttft_ms_p50"] > 0
-    assert np.isfinite(d["ttft_ms_mean"])
-    # the in-process baseline rides along so the record prices the
-    # HTTP/RPC serving tax
-    assert np.isfinite(d["inproc_tokens_per_s"]) and d["inproc_tokens_per_s"] > 0
-    assert d["inproc_ttft_ms_p50"] > 0 and d["inproc_elapsed_s"] > 0
-
-
-@pytest.mark.slow  # real sockets + threads + two replica servers (~30s);
-# the DRR/preemption/tenant contracts stay tier-1 via test_router_qos.py,
-# the tenant header -> submit(tenant=) seam via
-# test_api.py's tenant tests, and the bench record envelope via
-# test_bench_serving_http_record_schema above
-def test_bench_http_qos_record_schema(monkeypatch):
-    """The --http multi-tenant QoS record (ISSUE 19 satellite): the same
-    seeded bursty multi-tenant trace replayed over real RPC replicas +
-    DRR router + the OpenAI SSE API with the X-Fleetx-Tenant header
-    banks ``gpt_345m_serving_router_qos_http`` — well-behaved byte
-    parity vs the in-process DRR replay asserted inside, shed confined
-    to the flooding tenant, and the tenant label live on the scrape."""
-    monkeypatch.setenv("BENCH_SERVING_TINY", "1")
-    sys.path.insert(0, REPO)
-    import tools.bench_serving as bs
-
-    bs = importlib.reload(bs)
-    rec = bs.http_qos_record(slots=2, replicas=2)
-    assert rec["metric"] == "gpt_345m_serving_router_qos_http"
-    assert rec["unit"] == "goodput_frac"
-    assert 0 < rec["value"] <= 1
-    d = rec["detail"]
-    assert d["parity_well_behaved"] is True
-    assert set(d["shed_tenants"]) <= {"flood"}
-    assert d["api_tenant_labels"] is True
-    assert len(d["workload_hash"]) == 16
-
-
-@pytest.mark.slow  # 18.3s (PR 18 tier-1 budget audit): the timing is
-# stubbed but the --tiny config still builds + jits every pipeline
-# schedule variant. The streamed-schedule math contract stays tier-1
-# via test_pipeline.py::test_virtual_pipeline_stream_compact_parity
-# (forward parity streamed vs sequential vs plain scan + param-layout
-# round-trip), and the bench record envelope stays tier-1 via
-# test_bench_serving_http_record_schema; the live streamed<sequential
-# timing gate was already the slow-tier test below.
-def test_pp_bubble_records_schema(monkeypatch, tmp_path):
-    """tools/bench_pp_bubble.py banks machine-readable records (ISSUE 12
-    satellite): predicted vs measured bubble per config, a streamed-vs-
-    sequential summary in --virtual-pp mode, and a JSON payload at
-    --out. Timing is stubbed here (deterministic, fast); the live
-    streamed<sequential gate is the slow-tier test below."""
-    sys.path.insert(0, REPO)
-    from tools import bench_pp_bubble as bpp
-
-    # plain stack fastest, streamed in between, sequential slowest ->
-    # measured bubbles 0.5 vs 0.75, streamed wins, gate passes
-    def fake_time(model, params, batch, mesh, repeats):
-        if mesh is None:
-            return 0.5
-        return 1.0 if getattr(model.cfg, "virtual_pp_stream") else 2.0
-
-    monkeypatch.setattr(bpp, "_time_grad", fake_time)
-    out = tmp_path / "pp_bubble.json"
-    recs = bpp.main(["--virtual-pp", "--tiny", "--gate",
-                     "--out", str(out)])
-    payload = json.loads(out.read_text())
-    assert [r["schedule"] for r in payload["records"]] == [
-        "streamed", "sequential"]
-    for rec in payload["records"]:
-        for key in ("pp", "virtual_pp", "num_microbatches", "step_s",
-                    "plain_stack_s", "model_bubble_fraction",
-                    "measured_bubble_fraction"):
-            assert key in rec, key
-        assert 0 <= rec["model_bubble_fraction"] < 1
-        assert 0 <= rec["measured_bubble_fraction"] < 1
-    summary = payload["virtual_pp_summary"]
-    assert summary["metric"] == "pp_bubble_virtual_pp"
-    assert summary["streamed_wins"] == summary["configs"] == 1
-    comp = summary["comparisons"][0]
-    assert comp["streamed_bubble"] == 0.5
-    assert comp["sequential_bubble"] == 0.75
-    # the predicted drain-tick fractions documented per schedule
-    assert bpp.predicted_bubble(2, 1, 4, "plain") == pytest.approx(1 / 5)
-    assert bpp.predicted_bubble(2, 2, 4, "streamed") == pytest.approx(3 / 7)
-    assert bpp.predicted_bubble(2, 2, 4, "sequential") == pytest.approx(1 / 5)
-
-    # non-virtual mode banks the plain-schedule sweep with the same keys
-    recs = bpp.main(["--tiny", "--out", str(out)])
-    payload = json.loads(out.read_text())
-    assert all(r["schedule"] == "plain" for r in payload["records"])
-    assert "virtual_pp_summary" not in payload
-
-
-@pytest.mark.slow  # three live jit-grad timings (~60s); the tier-1
-def test_pp_bubble_virtual_pp_gate_live(tmp_path):
-    # schema contract is test_pp_bubble_records_schema above
-    """The streamed virtual-chunk schedule must measure a strictly
-    smaller bubble than the sequential-chunk baseline at equal
-    (pp, v, M) — the ISSUE 12 regression gate, live (--gate raises
-    SystemExit when the streamed schedule loses)."""
-    sys.path.insert(0, REPO)
-    from tools import bench_pp_bubble as bpp
-
-    out = tmp_path / "pp_bubble.json"
-    bpp.main(["--virtual-pp", "--gate", "--pp", "2", "--out", str(out)])
-    payload = json.loads(out.read_text())
-    comp = payload["virtual_pp_summary"]["comparisons"][0]
-    assert comp["streamed_wins"]
-    assert comp["streamed_step_s"] < comp["sequential_step_s"]
 
 
 @pytest.mark.slow  # 9.8s on the slow-host baseline (PR 7 tier-1 budget audit)
