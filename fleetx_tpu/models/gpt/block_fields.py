@@ -20,6 +20,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
+import numpy as np
+
 __all__ = ["BlockLayoutFields", "LAYOUT_FIELDS", "LAYER_TYPES", "check",
            "layer_class", "stack_of"]
 
@@ -111,6 +113,14 @@ class BlockLayoutFields:
     rope_scaling_mscale: float = 1.0
     rope_scaling_mscale_all_dim: float = 0.0
     rope_scaling_original_max_position: int = 4096
+    # ---- a learned indexer before latent attention (``index_topk`` > 0:
+    # sparse attention, models/gpt/latent.py): ``index_n_heads`` heads of
+    # ``index_head_dim`` score every cached row of a query's lane from ONE
+    # key a token (``cached_index``, the pool's third leaf), and the query
+    # attends over the ``index_topk`` highest-scoring rows alone
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # ---- an expert layer that holds a SHARE (parallel/moe_share.py): the
     # router is ``num_routed_experts`` wide and every token chooses among
     # all of them; this program holds ``num_experts`` of them, from
@@ -134,6 +144,17 @@ class BlockLayoutFields:
     def latent(self) -> bool:
         """Whether the attention layers are latent attention."""
         return "latent_attention" in (self.layer_types or ())
+
+    @property
+    def indexed(self) -> bool:
+        """Whether a learned indexer selects the rows latent attention
+        reads."""
+        return self.index_topk > 0
+
+    def selected(self, rows):
+        """Of ``rows`` cached rows behind a query (itself among them), those
+        it attends over (an int or an array of them)."""
+        return np.minimum(rows, self.index_topk) if self.indexed else rows
 
     @property
     def experts_held(self) -> Tuple[int, int]:
@@ -207,9 +228,16 @@ class BlockLayoutFields:
             return chunk_key_rows(self, program_rows or rows, behind)
         from fleetx_tpu.ops.pallas.mla_prefill import key_rows
 
-        return {"latent_rows": behind + rows,
-                "latent_key_rows": key_rows(behind + rows),
-                **self.span_pairs(rows)}
+        fields = {"latent_rows": behind + rows,
+                  "latent_key_rows": key_rows(behind + rows),
+                  **self.span_pairs(rows)}
+        if self.indexed:
+            # summed over the call's rows: the index keys each scores (every
+            # row up to its own) and the rows it then attends over
+            each = behind + 1 + np.arange(rows)
+            fields.update(index_rows=int(each.sum()),
+                          selected_rows=int(self.selected(each).sum()))
+        return fields
 
     @property
     def mamba_inner(self) -> int:
@@ -380,10 +408,6 @@ def _check_latent(cfg) -> None:
         raise ValueError(
             "num_routed_experts / n_group / num_shared_experts without "
             "gate: sigmoid_topk over layer_types (parallel/moe_share.py)")
-    if share and cfg.use_expert_bias:
-        raise NotImplementedError(
-            "use_expert_bias with a held share or a group-limited choice: "
-            "no test covers a selection bias there")
     first, count = cfg.experts_held
     if share and not (0 <= first and first + count <= cfg.routed_experts
                       and cfg.top_k <= cfg.routed_experts):
@@ -400,7 +424,8 @@ def _check_latent(cfg) -> None:
     if not cfg.latent:
         widths = [n for n in ("q_lora_rank", "kv_lora_rank",
                               "qk_nope_head_dim", "qk_rope_head_dim",
-                              "v_head_dim") if getattr(cfg, n)]
+                              "v_head_dim", "index_n_heads", "index_head_dim",
+                              "index_topk") if getattr(cfg, n)]
         if widths:
             raise ValueError(f"{widths} without a latent_attention layer")
         return
@@ -422,6 +447,13 @@ def _check_latent(cfg) -> None:
             "key), no qk_norm and no grouped heads: the latent has no head")
     if cfg.rope_scaling_factor < 1.0:
         raise ValueError(f"rope_scaling_factor {cfg.rope_scaling_factor}")
+    sizes = (cfg.index_n_heads, cfg.index_head_dim, cfg.index_topk)
+    if any(sizes) and (min(sizes) < 1
+                       or cfg.index_head_dim < cfg.qk_rope_head_dim):
+        raise ValueError(
+            f"index_n_heads {sizes[0]}, index_head_dim {sizes[1]}, "
+            f"index_topk {sizes[2]}: the indexer needs all three, and a "
+            "head at least qk_rope_head_dim wide (its first columns rotate)")
 
 
 def stack_of(model):

@@ -64,11 +64,14 @@ from fleetx_tpu.models.gpt.model import (
 from fleetx_tpu.ops.pallas import prefill_gqa
 from fleetx_tpu.ops.pallas.flash_attention import kernels_enabled
 
-__all__ = ["HybridDecoderLayer", "HybridSelfAttention", "chunk_key_rows",
-           "grouped_attention", "init_cache", "layer_bases", "total_pages",
-           "window_gather", "write_rows"]
+__all__ = ["POOL_LEAVES", "HybridDecoderLayer", "HybridSelfAttention",
+           "chunk_key_rows", "grouped_attention", "init_cache", "layer_bases",
+           "total_pages", "window_gather", "write_rows"]
 
 _NEG = -1e30  # a masked score: finite, so a row of padding stays finite
+# the leaves of the flat pool, under the one block table (``cached_index``:
+# the third leaf of a latent pool whose model has an indexer)
+POOL_LEAVES = ("cached_key", "cached_value", "cached_index")
 
 
 def _pages_of(cfg: GPTConfig):
@@ -108,22 +111,26 @@ def init_cache(model, batch: int):
             cfg.kv_heads * cfg.head_dim)
 
     def one(path, x):
-        kv = path[-1].key in ("cached_key", "cached_value")  # (own widths)
+        kv = path[-1].key in POOL_LEAVES  # (own widths)
         return jnp.zeros((x.shape, pool[:2] + x.shape[-1:])[kv], x.dtype)
 
     return jax.tree_util.tree_map_with_path(one, shapes)
 
 
-def write_rows(cfg: GPTConfig, k_pool, v_pool, tables, wpos, k, v, keep=None):
+def write_rows(cfg: GPTConfig, k_pool, v_pool, tables, wpos, k, v, keep=None,
+               more=()):
     """The pools with this call's keys and values ``[b, s, width]`` written
     at positions ``wpos + [0, s)`` through ``tables`` (a layer's own:
     its base added). ``keep`` (a traced bool) False: nothing is written.
-    The write itself is ``paged_write.write_rows``: a page at a time where
-    the call is one sequence over whole pages, else a row at a time."""
+    ``more``: further ``(pool, rows)`` pairs under the same table (latent
+    attention's index keys), returned after the two. The write itself is
+    ``paged_write.write_rows``: a page at a time where the call is one
+    sequence over whole pages, else a row at a time."""
     max_len = cfg.decode_cache_len or cfg.max_position_embeddings
     (b, s), width = k.shape[:2], -1  # (each pool's own width)
     return paged_write.write_rows(
-        [k_pool, v_pool], [k.reshape(b * s, width), v.reshape(b * s, width)],
+        [k_pool, v_pool] + [pool for pool, _ in more],
+        [rows.reshape(b * s, width) for rows in [k, v] + [r for _, r in more]],
         tables, wpos, max_len, keep)
 
 

@@ -39,6 +39,33 @@ can be divided over ``mp``). Two forms read it:
 ``scale = (nope + rope)^-0.5 * m^2``, ``m = 0.1 * mscale_all_dim * ln(factor)
 + 1`` (YaRN's correction of the softmax's temperature).
 
+**The indexer** (``index_topk`` > 0: learned sparse attention, DeepSeek-V3.2;
+absent, nothing below is traced and the operator is the one above). Beside
+the projections above, from the SAME ``c_q`` and ``x``: ``qI = c_q W_Iq``
+(``index_n_heads`` heads of ``index_head_dim``, a head's first
+``qk_rope_head_dim`` columns rotated), ONE key a token ``kI = LayerNorm(x
+W_Ik)`` (first columns rotated alike), head weights ``w = (x W_Iw) *
+heads^-0.5 * head_dim^-0.5`` (float32). **``kI`` is the pool's third leaf**,
+``cached_index``, written, parked at a prefix and resumed with the other
+two. A query at position ``t`` scores every row ``s <= t`` of its lane,
+``I_ts = sum_j w_j ReLU(qI_j . kI_s)`` (float32), keeps the ``min(index_topk,
+t + 1)`` highest (EXACTLY: :func:`select_rows`; ties to the lower position)
+and attends over those alone, in both forms:
+
+- a chunk scores the lane's index keys in blocks of ``KEY_BLOCK`` rows up
+  to its last row (scope ``dsa_index``), selects by threshold, a mask ``[s,
+  t]`` with each row's own set (``dsa_select``), and attends materialised
+  UNDER THE MASK (``dsa_attn``): the kernel ``fleetx_dsa_prefill``
+  (``ops/pallas/mla_prefill.py`` with the mask in the place of its position
+  test), else :func:`_chunk` handed the mask;
+- a tick scores every lane's index keys (``dsa_index``), takes the
+  top ``index_topk`` positions a lane (``dsa_select``), GATHERS the chosen
+  rows of ``c_kv`` and ``k_r`` into a compact pool of ``index_topk`` rows a
+  lane in position order (``dsa_attn``) and runs the absorbed kernel
+  ``fleetx_mla_decode_paged`` over that, as it stands.
+
+A forward outside the cache masks its dense scores the same way.
+
 Device scopes (docs/OBSERVABILITY.md): ``mla_proj`` (the low-rank
 projections, norms, rotation, and the output projection),
 ``mla_attn_prefill`` (a chunk's gather of its lane's rows and the kernel
@@ -72,8 +99,9 @@ from fleetx_tpu.models.gpt.model import (
     default_kernel_init,
 )
 
-__all__ = ["KEY_BLOCK", "LatentAttention", "LatentStack", "rope_leaf_width",
-           "softmax_scale", "yarn_frequencies", "yarn_tables"]
+__all__ = ["KEY_BLOCK", "LatentAttention", "LatentStack", "index_scores",
+           "rope_leaf_width", "select_rows", "softmax_scale",
+           "yarn_frequencies", "yarn_tables"]
 
 # key rows of one block of a chunk's attention in plain XLA (:func:`_chunk`)
 KEY_BLOCK = 1024
@@ -173,6 +201,15 @@ class LatentAttention(nn.Module):
         w_o = self._weight("out_proj", (nh, vd, h), ("heads", "kv", "embed"))
         q_norm, kv_norm = (_latent_norm(cfg, "q_a_norm"),
                            _latent_norm(cfg, "kv_a_norm"))
+        if cfg.indexed:
+            ni, di = cfg.index_n_heads, cfg.index_head_dim
+            w_iq = self._weight("index_q_proj", (qr, ni, di),
+                                (None, "heads", "kv"))
+            w_ik = self._weight("index_k_proj", (h, di), ("embed", None))
+            w_iw = self._weight("index_w_proj", (h, ni), ("embed", None))
+            index_norm = nn.LayerNorm(
+                epsilon=cfg.norm_eps, dtype=cfg.dtype,
+                param_dtype=jnp.float32, name="index_k_norm")
         if phase == "attend":
             with jax.named_scope("attn_full"):
                 out = self._cached(x, w_kvb, cache_positions, block_tables,
@@ -183,15 +220,27 @@ class LatentAttention(nn.Module):
             raise ValueError("latent attention rotates: it is handed the "
                              "angles (LatentStack computes them)")
         with jax.named_scope("mla_proj"):
-            q = jnp.einsum("bsr,rhd->bshd", q_norm(x @ w_qa), w_qb)
+            c_q = q_norm(x @ w_qa)
+            q = jnp.einsum("bsr,rhd->bshd", c_q, w_qb)
             q = jnp.concatenate(
                 [q[..., :nope], apply_rope(q[..., nope:], rope)], axis=-1)
             latent = x @ w_kva
             ckv = kv_norm(latent[..., :c])
             kr = _rotated_key(latent[..., c:], rope)
+        if cfg.indexed:
+            with jax.named_scope("dsa_index"):
+                qi = jnp.einsum("bsr,rhd->bshd", c_q, w_iq)
+                qi = jnp.concatenate(
+                    [apply_rope(qi[..., :rot], rope), qi[..., rot:]], axis=-1)
+                ki = _rotated_index_key(index_norm(x @ w_ik), rope, rot)
+                head_w = _index_head_weights(
+                    (x @ w_iw).astype(jnp.float32) * (ni * di) ** -0.5)
         if phase == "project":  # (the leaf's width: ``rope_leaf_width``)
-            return q, ckv, jnp.pad(
+            kr = jnp.pad(
                 kr, ((0, 0), (0, 0), (0, rope_leaf_width(cfg) - rot)))
+            if cfg.indexed:  # the attend phase takes all three as its ``x``
+                return (q, qi, head_w), ckv, kr, ki
+            return q, ckv, kr
         # no cache (or its init): every position at once, materialised
         s = x.shape[1]
         pos = jnp.arange(s)
@@ -207,12 +256,28 @@ class LatentAttention(nn.Module):
                                  preferred_element_type=jnp.float32)
                       + jnp.einsum("bshd,btd->bhst", q[..., nope:], kr,
                                    preferred_element_type=jnp.float32))
+            if cfg.indexed:
+                with jax.named_scope("dsa_select"):
+                    index = index_scores(qi, head_w, ki)
+                    allowed = select_rows(index, _visible(allowed),
+                                          cfg.index_topk) & allowed
+                self._sow_selection(index, allowed)
             scores = jnp.where(allowed[:, None], scores * softmax_scale(cfg),
                                _NEG)
             probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
             out = jnp.einsum("bhst,bthv->bshv", probs, v)
         with jax.named_scope("mla_proj"):
             return jnp.einsum("bshv,hvd->bsd", out, w_o)
+
+    def _sow_selection(self, index, chosen):
+        """For whoever holds the indexer to a reference (the collection
+        ``routing``, where it is mutable): the rows each query attends over
+        ``[b, s, t]`` bool and, from a tick and from a forward outside the
+        cache, the index scores, float32 alike."""
+        if self.is_mutable_collection("routing"):
+            self.sow("routing", "index_sets", chosen)
+            if index is not None:
+                self.sow("routing", "index_scores", index)
 
     def _cached(self, q, w_kvb, cache_positions, block_tables, layer_index):
         """Attend through the pool as it stands (the caller has written the
@@ -222,6 +287,9 @@ class LatentAttention(nn.Module):
         ckv_pool = self.get_variable("cache", "cached_key")
         kr_pool = self.get_variable("cache", "cached_value")
         ps = cfg.decode_page_size
+        if cfg.indexed:
+            q, qi, head_w = q
+            ki_pool = self.get_variable("cache", "cached_index")
         b, s = q.shape[:2]
         wpos = cache_positions.astype(jnp.int32)
         tables = block_tables.astype(jnp.int32)
@@ -238,8 +306,18 @@ class LatentAttention(nn.Module):
                                  w_kvb[..., :nope])
             q_r = jnp.pad(q[:, 0, :, nope:], ((0, 0), (0, 0), (
                 0, kr_pool.shape[-1] - cfg.qk_rope_head_dim)))
-            out = _decode(cfg, q_c, q_r, ckv_pool, kr_pool, tables, end,
-                          scale)
+            if cfg.indexed:
+                out, index, chosen = _sparse_decode(
+                    cfg, q_c, q_r, qi[:, 0], head_w[:, 0],
+                    (ckv_pool, kr_pool, ki_pool), tables, end, scale)
+                if self.is_mutable_collection("routing"):
+                    self._sow_selection(index[:, None], jnp.zeros(
+                        (b, index.shape[1] + 1), bool).at[
+                            jnp.arange(b)[:, None], chosen].set(True)[
+                                :, None, :-1])
+            else:
+                out = _decode(cfg, q_c, q_r, ckv_pool, kr_pool, tables, end,
+                              scale)
             with jax.named_scope("mla_absorb"):
                 return _through_w_uv(out, w_kvb[..., nope:])[:, None]
         if b != 1:
@@ -250,11 +328,143 @@ class LatentAttention(nn.Module):
         with jax.named_scope("mla_attn_prefill"):  # the lane's rows, in order
             ckv = ckv_pool[table].reshape(-1, ckv_pool.shape[-1])
             kr = kr_pool[table].reshape(-1, kr_pool.shape[-1])
-        return _prefill(cfg, q[0], w_kvb, ckv, kr, wpos[0], scale)[None]
+        if not cfg.indexed:
+            return _prefill(cfg, q[0], w_kvb, ckv, kr, wpos[0], scale)[None]
+        with jax.named_scope("dsa_index"):
+            ki = ki_pool[table].reshape(-1, ki_pool.shape[-1])
+            scores = _chunk_index_scores(qi[0], head_w[0], ki, wpos[0])
+        with jax.named_scope("dsa_select"):
+            seen = (jnp.arange(ki.shape[0], dtype=jnp.int32)[None, :]
+                    <= wpos[0] + jnp.arange(s, dtype=jnp.int32)[:, None])
+            mask = select_rows(scores, _visible(seen), cfg.index_topk)
+        # (a chunk's scores, [rows, cache rows] float32 a layer, are not sown:
+        # 103 MB a layer of the check's program at the served sizes)
+        self._sow_selection(None, mask[None])
+        return _prefill(cfg, q[0], w_kvb, ckv, kr, wpos[0], scale,
+                        mask=mask)[None]
 
 
 # the seams ``perfbench/probe_axk1.py`` plants its faults in
 _SCORE_TYPE = jnp.float32     # what a chunk's scores are accumulated in
+
+
+_INDEX_TYPE = jnp.float32     # what an index score's products accumulate in
+
+
+def _rotated_index_key(ki, rope, rot: int):
+    """The indexer's key ``[b, s, d]``, its first ``rot`` columns rotated,
+    as the cache takes it."""
+    return jnp.concatenate([_rotated_key(ki[..., :rot], rope), ki[..., rot:]],
+                           axis=-1)
+
+
+def _index_act(dots):
+    """What a head's product passes before the heads are summed: ReLU."""
+    return jax.nn.relu(dots)
+
+
+def _index_head_weights(w):
+    """The heads' weights ``w_{t,j}`` as the sum takes them."""
+    return w
+
+
+def _visible(seen):
+    """The rows ``[s, t]`` bool a query may SELECT from: those it sees."""
+    return seen
+
+
+def index_scores(qi, w, ki):
+    """``I = sum_j w_j ReLU(qI_j . kI)``, float32: ``qi`` ``[..., s, heads,
+    d]``, ``w`` ``[..., s, heads]`` float32, ``ki`` ``[..., t, d]``; ``[...,
+    s, t]``."""
+    dots = jnp.einsum("...shd,...td->...sht", qi, ki,
+                      preferred_element_type=_INDEX_TYPE).astype(jnp.float32)
+    return (_index_act(dots) * w[..., None]).sum(-2)
+
+
+def select_rows(scores, valid, k: int):
+    """The ``k`` highest of ``scores`` ``[..., t]`` float32 among the rows
+    ``valid`` (all of them where there are no more than ``k``), a tie going
+    to the lower position: ``[..., t]`` bool. EXACT, and no sort: the
+    scores' bits, made to order as unsigned integers, are searched for the
+    ``k``-th largest a bit at a time (32 counts over the scores), and the
+    rows that tie with it are taken in order of position."""
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0, 0.0, scores), jnp.int32)   # (-0.0 is 0.0)
+    key = jax.lax.bitcast_convert_type(
+        jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits), jnp.uint32
+    ) ^ jnp.uint32(0x80000000)
+    key = jnp.where(valid, key, 0)          # (a finite score's key is > 0)
+    want = jnp.minimum(valid.sum(-1, keepdims=True), k)
+
+    def bit(i, kth):
+        higher = kth | (jnp.uint32(0x80000000) >> i.astype(jnp.uint32))
+        enough = (key >= higher).sum(-1, keepdims=True) >= want
+        return jnp.where(enough, higher, kth)
+
+    kth = jax.lax.fori_loop(0, 32, bit,
+                            jnp.zeros(key.shape[:-1] + (1,), jnp.uint32))
+    above, ties = key > kth, (key == kth) & valid
+    room = want - above.sum(-1, keepdims=True)
+    return above | (ties & (jnp.cumsum(ties, axis=-1) <= room))
+
+
+def _chunk_index_scores(qi, w, ki, start):
+    """One lane's chunk ``qi`` ``[s, heads, d]`` at positions ``start + [0,
+    s)`` against the lane's index keys ``ki`` ``[t, d]``, in blocks of
+    ``KEY_BLOCK`` keys up to the chunk's last row (the rest stay 0: no
+    query sees them): ``[s, t]`` float32."""
+    s, t = qi.shape[0], ki.shape[0]
+    block = min(KEY_BLOCK, t)
+    if t % block:
+        raise ValueError(f"a lane's {t} rows are no whole number of "
+                         f"{block}-row key blocks")
+
+    def one(i, out):
+        part = index_scores(
+            qi, w, jax.lax.dynamic_slice_in_dim(ki, i * block, block))
+        return jax.lax.dynamic_update_slice(out, part, (0, i * block))
+
+    return jax.lax.fori_loop(
+        0, jnp.minimum((start + s + block - 1) // block, t // block), one,
+        jnp.zeros((s, t), jnp.float32))
+
+
+def _sparse_decode(cfg: GPTConfig, q_c, q_r, qi, w, pools, tables, end,
+                   scale):
+    """A tick under the indexer: every lane's query ``qi`` ``[b, heads, d]``
+    scores the lane's index keys, the ``index_topk`` best positions of its
+    rows ``[0, end)`` are gathered, in position order, into a compact pool
+    of their own, and the absorbed form (:func:`_decode`) attends over
+    that. ``tables`` carry the layer's base. Beside the output, the scores
+    ``[b, t]`` and the positions chosen ``[b, index_topk]`` (``t`` where a
+    lane has fewer rows)."""
+    ckv_pool, kr_pool, ki_pool = pools
+    b, ps = q_c.shape[0], ckv_pool.shape[1]
+    t = tables.shape[1] * ps
+    k = min(cfg.index_topk, t)
+    with jax.named_scope("dsa_index"):
+        ki = ki_pool[tables].reshape(b, t, ki_pool.shape[-1])
+        scores = index_scores(qi[:, None], w[:, None], ki)[:, 0]
+    with jax.named_scope("dsa_select"):
+        seen = jnp.arange(t, dtype=jnp.int32)[None, :] < end[:, None]
+        chosen = jax.lax.top_k(
+            jnp.where(_visible(seen), scores, -jnp.inf), k)[1]
+        count = jnp.minimum(end, k)
+        # in position order; the places past ``count`` name no row
+        chosen = jnp.sort(jnp.where(
+            jnp.arange(k, dtype=jnp.int32)[None, :] < count[:, None],
+            chosen.astype(jnp.int32), t), axis=-1)
+    with jax.named_scope("dsa_attn"):
+        kp = -(-k // ps) * ps           # whole pages of the compact pool
+        at = jnp.minimum(jnp.pad(chosen, ((0, 0), (0, kp - k)),
+                                 constant_values=t), t - 1)
+        row = jnp.take_along_axis(tables, at // ps, axis=1) * ps + at % ps
+        ckv, kr = (pool.reshape(-1, pool.shape[-1])[row].reshape(
+            b * kp // ps, ps, pool.shape[-1]) for pool in (ckv_pool, kr_pool))
+        compact = jnp.arange(b * kp // ps, dtype=jnp.int32).reshape(b, -1)
+    return (_decode(cfg, q_c, q_r, ckv, kr, compact, count, scale), scores,
+            chosen)
 
 
 def _rotated_key(kr, rope):
@@ -289,27 +499,32 @@ def _decode(cfg: GPTConfig, q_c, q_r, ckv_pool, kr_pool, tables, end, scale):
                   scale=scale)
 
 
-def _prefill(cfg: GPTConfig, q, w_kvb, ckv, kr, start, scale: float):
+def _prefill(cfg: GPTConfig, q, w_kvb, ckv, kr, start, scale: float,
+             mask=None):
     """One lane's chunk over its rows as gathered (``kr`` the leaf as held):
-    the kernel where ``_decode`` takes its own, else :func:`_chunk`."""
+    the kernel where ``_decode`` takes its own, else :func:`_chunk`. Under
+    ``mask`` ``[s, t]`` bool (each query's selected rows, all of them
+    visible to it) the kernel is ``fleetx_dsa_prefill``."""
     from fleetx_tpu.ops.pallas import mla_prefill
 
     if not _kernels(cfg):
         return _chunk(cfg, q, w_kvb, ckv, kr[:, :cfg.qk_rope_head_dim],
-                      start, scale)
-    with jax.named_scope("mla_attn_prefill"):
+                      start, scale, mask)
+    with jax.named_scope("mla_attn_prefill" if mask is None else "dsa_attn"):
         return mla_prefill.mla_prefill(
             q, w_kvb, ckv, kr, start, nope=cfg.qk_nope_head_dim, scale=scale,
-            score_type=_SCORE_TYPE)
+            score_type=_SCORE_TYPE, mask=mask)
 
 
-def _chunk(cfg: GPTConfig, q, w_kvb, ckv, kr, start, scale: float):
+def _chunk(cfg: GPTConfig, q, w_kvb, ckv, kr, start, scale: float,
+           mask=None):
     """One lane's chunk, materialised, in plain XLA (the kernel's twin: the
     CPU, the tests): ``q`` ``[s, heads, nope + rope]`` at positions ``start
     + [0, s)`` over the lane's rows ``ckv`` ``[t, c]`` and ``kr`` ``[t, r]``
     (its own among them), in blocks of ``KEY_BLOCK`` keys with a running
     maximum and sum; blocks past the chunk's last row are not computed.
-    ``[s, heads, v]``."""
+    ``mask`` ``[s, t]`` bool: the rows each query attends over, of those it
+    sees (under scope ``dsa_attn`` then). ``[s, heads, v]``."""
     nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
     s, nh = q.shape[:2]
     t = ckv.shape[0]
@@ -319,6 +534,7 @@ def _chunk(cfg: GPTConfig, q, w_kvb, ckv, kr, start, scale: float):
                          f"{block}-row key blocks")
     q_pos = start + jnp.arange(s, dtype=jnp.int32)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
+    attn_scope = "mla_attn_prefill" if mask is None else "dsa_attn"
 
     def one(i, carry):
         top, total, acc = carry
@@ -326,7 +542,7 @@ def _chunk(cfg: GPTConfig, q, w_kvb, ckv, kr, start, scale: float):
         with jax.named_scope("mla_kv_up"):
             k_nope, v = _expand(
                 jax.lax.dynamic_slice_in_dim(ckv, at, block), w_kvb, nope)
-        with jax.named_scope("mla_attn_prefill"):
+        with jax.named_scope(attn_scope):
             scores = (jnp.einsum("shd,thd->hst", q_nope, k_nope,
                                  preferred_element_type=_SCORE_TYPE)
                       + jnp.einsum(
@@ -336,6 +552,8 @@ def _chunk(cfg: GPTConfig, q, w_kvb, ckv, kr, start, scale: float):
                               jnp.float32) * scale
             seen = (at + jnp.arange(block, dtype=jnp.int32)[None, :]
                     <= q_pos[:, None])[None]
+            if mask is not None:
+                seen &= jax.lax.dynamic_slice_in_dim(mask, at, block, 1)[None]
             scores = jnp.where(seen, scores, _NEG)
             new_top = jnp.maximum(top, scores.max(-1))
             p = jnp.where(seen, jnp.exp(scores - new_top[..., None]), 0.0)
@@ -401,6 +619,10 @@ class LatentStack(MixedStack):
                 (max(plan["counts"]["experts"], 1), 2 * len(MOE_STATS) * 2),
                 jnp.uint32),
         }
+        if cfg.indexed:
+            held["cached_index"] = self.variable(
+                "cache", "cached_index", jnp.zeros,
+                (1, ps, cfg.index_head_dim), cfg.dtype)
         return None if fresh else held
 
     def _decoder_stack(self, x, params, cache, plan, kinds, *, rope,

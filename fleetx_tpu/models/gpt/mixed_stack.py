@@ -83,6 +83,7 @@ import numpy as np
 from flax import linen as nn
 
 from fleetx_tpu.models.gpt.hybrid import (
+    POOL_LEAVES,
     HybridSelfAttention,
     layer_bases,
     write_rows,
@@ -585,6 +586,13 @@ class MixedStack(nn.Module):
                     (index, 0 if tick else lanes[0], 0))
             return y
 
+        # what an attention kind sows for whoever holds it to a reference
+        # (latent attention's index scores and sets), every layer's
+        probing = ["routing"] if probed else []
+
+        def first_sown(mut):
+            return {k: v[0] for k, v in mut.get("routing", {}).items()}
+
         def attention(value, index, *args, **kwargs):
             return attn_op.apply(
                 {"params": _at(params["attention"]["op"], index),
@@ -611,8 +619,8 @@ class MixedStack(nn.Module):
                 return mamba(value, index,
                              jnp.where(begins[:, None], 0, held))
 
-            def project():
-                return dict(zip("qkv", attention(
+            def project():  # (a fourth: the third leaf's rows)
+                return dict(zip(("q", "k", "v", "index"), attention(
                     normed("attention", index, value), index, rope=rope,
                     phase="project")))
 
@@ -637,25 +645,28 @@ class MixedStack(nn.Module):
                         rows & ~mixes, index, mixed["z"])
             elif recurrent == "mamba":
                 mixed["y"] = ssm_update(pools, mixed, held, mixes, index)
+            leaves = [n for n in POOL_LEAVES if n in pools]
             if counts["attention"]:
-                pools["cached_key"], pools["cached_value"] = write_rows(
+                pools.update(zip(leaves, write_rows(
                     cfg, pools["cached_key"], pools["cached_value"],
                     tables + jnp.asarray(layer_bases(cfg))[index], wpos,
-                    qkv["k"], qkv["v"], keep=mixes)
+                    qkv["k"], qkv["v"], keep=mixes,
+                    more=[(pools[n], qkv["index"]) for n in leaves[2:]])))
 
             def attend_step():
-                return attention(
+                out, mut = attention(
                     qkv["q"], index, decode=True, cache_positions=wpos,
-                    block_tables=tables, phase="attend", mutable=["cache"],
-                    variables={"cache": {n: pools[n] for n in (
-                        "cached_key", "cached_value")}})[0]
+                    block_tables=tables, phase="attend",
+                    mutable=["cache"] + probing,
+                    variables={"cache": {n: pools[n] for n in leaves}})
+                return out, first_sown(mut)
 
             def finish_step():
                 if recurrent == "conv":
-                    return mixed["y"]
-                return mamba_finish(mixed, index)
+                    return mixed["y"], {}
+                return mamba_finish(mixed, index), {}
 
-            return pick(mixes, both, attend_step, finish_step), pools
+            return (*pick(mixes, both, attend_step, finish_step), pools)
 
         def plain(value, mixes, index, pools):
             """The operator outside a cache: every position at once."""
@@ -667,10 +678,13 @@ class MixedStack(nn.Module):
                     (b, cfg.mamba_d_state, cfg.mamba_inner)))[0]
                 return mamba_finish(mixed, index)
 
-            return pick(
-                mixes, both,
-                lambda: attention(normed("attention", index, value), index,
-                                  key_mask, rope=rope), recur), pools
+            def attend():
+                out = attention(normed("attention", index, value), index,
+                                key_mask, rope=rope,
+                                mutable=probing or False)
+                return (out[0], first_sown(out[1])) if probed else (out, {})
+
+            return (*pick(mixes, both, attend, lambda: (recur(), {})), pools)
 
         def dense(value, index, stats):
             y = kinds["dense"][0].apply(
@@ -708,7 +722,7 @@ class MixedStack(nn.Module):
             with jax.named_scope("layer"):
                 stats = pools.pop("moe_stats", None)
                 with jax.named_scope("attn"):
-                    y, pools = (operator if cached else plain)(
+                    y, seen, pools = (operator if cached else plain)(
                         value, jnp.asarray(plan["attention"])[layer] == 1,
                         jnp.asarray(plan["operator_index"])[layer], pools)
                 value = value + y
@@ -719,15 +733,17 @@ class MixedStack(nn.Module):
                         value, jnp.asarray(plan["ffn_index"])[layer], stats)
                 if cached:
                     pools["moe_stats"] = stats
-            return (_constrain_act(value + y, cfg), pools), sown
+            return (_constrain_act(value + y, cfg), pools), (sown, seen)
 
-        (x, pools), sown = jax.lax.scan(
+        (x, pools), (sown, seen) = jax.lax.scan(
             body, (x, pools), jnp.arange(cfg.num_layers, dtype=jnp.int32))
         for name, leaf in pools.items():
             cache[name].value = leaf
         if probed:  # the expert layers' rows, as a layer scan would stack them
             for name, leaf in sown.items():
                 self.sow("routing", name, leaf[cfg.num_dense_layers:])
+            for name, leaf in seen.items():
+                self.sow("routing", name, leaf)
         return x
 
 

@@ -30,6 +30,13 @@ to expand it and ``2 s (nope + r + v)`` to score and sum it (the
 materialised form's; ``perfbench/flops_mla.py`` counts them), on ``(c +
 r_leaf) x 2`` bytes copied once a head: MXU-bound by a factor of ten.
 
+**Under a selection** (``mask``: a learned indexer's choice of rows for each
+query, ``models/gpt/latent.py``) the same steps run with the mask's block
+``[s, rows]`` int8 in the place of the position test, in EVERY block (a
+query's chosen rows lie anywhere behind it; each is visible to it, so the
+mask is the whole test): 1 byte a query and key row beside the work above.
+That kernel is named ``fleetx_dsa_prefill``.
+
 Named ``fleetx_mla_prefill`` in compiled HLO and in device traces
 (docs/OBSERVABILITY.md): 1 call a layer and chunk. No gradient.
 """
@@ -43,9 +50,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from fleetx_tpu.ops.pallas.flash_attention import _interpret
 
-__all__ = ["KERNEL_NAME", "BLOCK_ROWS", "key_rows", "mla_prefill"]
+__all__ = ["KERNEL_NAME", "SELECTED_KERNEL_NAME", "BLOCK_ROWS", "key_rows",
+           "mla_prefill"]
 
 KERNEL_NAME = "fleetx_mla_prefill"
+SELECTED_KERNEL_NAME = "fleetx_dsa_prefill"
 # cached rows of one grid step
 BLOCK_ROWS = 1024
 _NEG = -1e30
@@ -59,13 +68,14 @@ def key_rows(rows: int) -> int:
 
 
 def mla_prefill(q, w_kvb, ckv, kr, start, *, nope: int, scale: float,
-                score_type):
+                score_type, mask=None):
     """Materialised latent attention of ONE lane's chunk. ``q`` ``[s, heads,
     nope + rope]`` (rotated) at positions ``start + [0, s)``; ``w_kvb``
     ``[c, heads, nope + v]``; ``ckv`` ``[t, c]`` and ``kr`` ``[t, r_leaf]``
     the lane's cached rows in order, the chunk's own among them (``kr`` the
     leaf as held: ``r_leaf >= rope`` columns, zeros past the key); ``start``
-    an int32 scalar. ``[s, heads, v]``."""
+    an int32 scalar; ``mask`` ``[s, t]`` bool, where given, the rows each
+    query attends over (all visible to it). ``[s, heads, v]``."""
     s, heads, _ = q.shape
     t, c = ckv.shape
     r_leaf = kr.shape[-1]
@@ -85,8 +95,9 @@ def mla_prefill(q, w_kvb, ckv, kr, start, *, nope: int, scale: float,
     # the blocks up to the chunk's last row: the grid's own (dynamic) bound
     live = jnp.minimum((start[0] + s - 1) // rows, blocks - 1) + 1
 
-    def kernel(start_ref, qn_ref, qr_ref, w_ref, ckv_ref, kr_ref, o_ref,
-               m_scr, l_scr, acc_scr):
+    def kernel(start_ref, qn_ref, qr_ref, w_ref, ckv_ref, kr_ref, *rest):
+        mask_ref = rest[0] if mask is not None else None
+        o_ref, m_scr, l_scr, acc_scr = rest[mask is not None:]
         j = pl.program_id(1)
         first = start_ref[0]
         # a block whose last row is no later than the first query's: all seen
@@ -114,9 +125,10 @@ def mla_prefill(q, w_kvb, ckv, kr, start, *, nope: int, scale: float,
                   ).astype(jnp.float32) * scale             # [s, rows]
             if masked:
                 at = j * rows
-                seen = (at + jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
-                        <= first + jax.lax.broadcasted_iota(
-                            jnp.int32, (s, 1), 0))
+                seen = (mask_ref[...] != 0) if mask is not None else (
+                    at + jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+                    <= first + jax.lax.broadcasted_iota(
+                        jnp.int32, (s, 1), 0))
                 sc = jnp.where(seen, sc, _NEG)
                 v = jnp.where(
                     at + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
@@ -133,8 +145,11 @@ def mla_prefill(q, w_kvb, ckv, kr, start, *, nope: int, scale: float,
                 p.astype(v.dtype), v, preferred_element_type=jnp.float32)
             m_scr[...] = m_new
 
-        pl.when(jnp.logical_not(crosses))(lambda: step(False))
-        pl.when(crosses)(lambda: step(True))
+        if mask is not None:
+            step(True)
+        else:
+            pl.when(jnp.logical_not(crosses))(lambda: step(False))
+            pl.when(crosses)(lambda: step(True))
 
         @pl.when(j == pl.num_programs(1) - 1)
         def _finalize():
@@ -157,7 +172,9 @@ def mla_prefill(q, w_kvb, ckv, kr, start, *, nope: int, scale: float,
                       pl.BlockSpec((s, r_leaf), head_map),
                       pl.BlockSpec((c, kv_width), head_map),
                       pl.BlockSpec((rows, c), row_map),
-                      pl.BlockSpec((rows, r_leaf), row_map)],
+                      pl.BlockSpec((rows, r_leaf), row_map)]
+            + ([pl.BlockSpec((s, rows), lambda h, j, start_ref: (0, j))]
+               if mask is not None else []),
             out_specs=pl.BlockSpec((s, vd), head_map),
             scratch_shapes=[
                 pltpu.VMEM((s, 1), jnp.float32),     # running max
@@ -168,7 +185,8 @@ def mla_prefill(q, w_kvb, ckv, kr, start, *, nope: int, scale: float,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
-        name=KERNEL_NAME,
+        name=KERNEL_NAME if mask is None else SELECTED_KERNEL_NAME,
     )(start, q_nope, q_rope,
-      w_kvb.reshape(c, heads * kv_width), ckv, kr)
+      w_kvb.reshape(c, heads * kv_width), ckv, kr,
+      *(() if mask is None else (mask.astype(jnp.int8),)))
     return out.reshape(s, heads, vd)

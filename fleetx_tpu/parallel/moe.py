@@ -441,17 +441,22 @@ class DroplessMoEMLP(nn.Module):
         scores = jax.nn.sigmoid(logits)
         ranked = scores
         if cfg.use_expert_bias:
-            std = cfg.expert_bias_init_std
-            ranked = scores + self.param(
-                "expert_bias", nn.with_logical_partitioning(
-                    nn.initializers.normal(std) if std
-                    else nn.initializers.zeros_init(), (None,)),
-                (cfg.num_experts,), jnp.float32)
+            ranked = scores + self._expert_bias(cfg.num_experts)
         _, chosen = jax.lax.top_k(ranked, cfg.top_k)
         weights = jnp.take_along_axis(scores, chosen, axis=-1)
         if cfg.norm_topk_prob:
             weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
         return scores, weights * cfg.routed_scaling_factor, chosen
+
+    def _expert_bias(self, experts: int):
+        """The selection bias, a float32 leaf ``[experts]`` (drawn normal at
+        ``expert_bias_init_std`` from a seed; 0: zeros)."""
+        std = self.cfg.expert_bias_init_std
+        return self.param(
+            "expert_bias", nn.with_logical_partitioning(
+                nn.initializers.normal(std) if std
+                else nn.initializers.zeros_init(), (None,)),
+            (experts,), jnp.float32)
 
     def _count(self, sizes, pairs: int, seq: int, decode: bool, layer_index):
         """Add this call to the ``moe_stats`` leaf of the decode cache (the

@@ -7,9 +7,12 @@ for one chip of a deployment whose every layer is divided over several
   float32 over ALL ``num_routed_experts``; with ``n_group`` > 1 a group's
   score is the sum of its two highest, the ``topk_group`` best groups stay
   and the ``top_k`` largest scores are chosen among what stays (the
-  DeepSeek-V3 family's group-limited choice, with no selection bias);
-  weights the chosen scores over their sum + 1e-20 (``norm_topk_prob``)
-  times ``routed_scaling_factor``.
+  DeepSeek-V3 family's group-limited choice). Under ``use_expert_bias``
+  (the family's ``topk_method: "noaux_tc"``) a float32 leaf ``expert_bias``
+  ``[num_routed_experts]`` joins the scores for the groups' sums and for
+  the choice, NEVER for the weights; without it (A.X-K1) nothing is added.
+  Weights: the chosen RAW scores over their sum + 1e-20
+  (``norm_topk_prob``) times ``routed_scaling_factor``.
 - **This program holds ``num_experts`` of them**, ``[first_expert_held,
   first_expert_held + num_experts)``: the weights ``w_gate / w_up /
   w_down`` ``[held, in, out]``. Only the (token, slot) pairs whose expert is
@@ -52,10 +55,21 @@ def _shared_expert(t, gate, up, down):
     return (jax.nn.silu(t @ gate) * (t @ up)) @ down
 
 
-def group_limited_topk(scores, top_k: int, n_group: int, topk_group: int):
-    """The ``top_k`` largest of ``scores`` ``[n, E]`` inside the
-    ``topk_group`` groups (of ``n_group`` equal, consecutive ones) whose two
-    highest scores sum highest: ``[n, top_k]`` expert numbers."""
+def _weighed(scores, ranked):
+    """What the chosen experts' weights are taken from: the raw scores
+    (``ranked`` carries the selection bias; a fault is planted here)."""
+    del ranked
+    return scores
+
+
+def group_limited_topk(scores, top_k: int, n_group: int, topk_group: int,
+                       bias=None):
+    """The ``top_k`` largest of ``scores`` ``[n, E]`` (``+ bias`` ``[E]``,
+    where one is given: in the groups' sums and in the choice alike) inside
+    the ``topk_group`` groups (of ``n_group`` equal, consecutive ones) whose
+    two highest sum highest: ``[n, top_k]`` expert numbers."""
+    if bias is not None:
+        scores = scores + bias
     if n_group > 1:
         n, experts = scores.shape
         grouped = scores.reshape(n, n_group, experts // n_group)
@@ -132,6 +146,7 @@ class SharedMoEMLP(DroplessMoEMLP):
         w_gate = weight("w_gate", (held_n, h, f), ("expert", "embed", "mlp"))
         w_up = weight("w_up", (held_n, h, f), ("expert", "embed", "mlp"))
         w_down = weight("w_down", (held_n, f, h), ("expert", "mlp", "embed"))
+        bias = self._expert_bias(routed) if cfg.use_expert_bias else None
         shared_f = cfg.num_shared_experts * f
         if shared_f:
             shared = [weight(name, shape, axes) for name, shape, axes in (
@@ -151,9 +166,13 @@ class SharedMoEMLP(DroplessMoEMLP):
         with jax.named_scope("moe_route"):
             scores = jax.nn.sigmoid(router(tokens.astype(jnp.float32)))
             scores = scores.astype(jnp.float32)  # (whatever the router's)
-            topk_idx = group_limited_topk(scores, k, cfg.n_group,
-                                          cfg.topk_group)
-            weights = jnp.take_along_axis(scores, topk_idx, axis=-1)
+            # (without a bias, called as ever: a probe plants its own here)
+            topk_idx = group_limited_topk(
+                scores, k, cfg.n_group, cfg.topk_group,
+                **({} if bias is None else {"bias": bias}))
+            weights = jnp.take_along_axis(
+                _weighed(scores, scores if bias is None else scores + bias),
+                topk_idx, axis=-1)
             if cfg.norm_topk_prob:
                 weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
             weights = weights * cfg.routed_scaling_factor

@@ -1340,10 +1340,13 @@ class PagedKVCacheManager(_LaneBook):
         self.page_bytes = {"kv": 0, "conv": 0}
         self.lane_bytes = 0
         self.state_resets = 0
+        self.index_pool_bytes = 0
         for path, leaf in jax.tree_util.tree_flatten_with_path(self.cache)[0]:
             name = getattr(path[-1], "key", "")
             kind = {"cached_key": "kv", "cached_value": "kv",
-                    "conv_state": "conv"}.get(name)
+                    "cached_index": "kv", "conv_state": "conv"}.get(name)
+            if name == "cached_index":  # (a latent pool's third leaf)
+                self.index_pool_bytes = leaf_device_nbytes(leaf)
             if kind and self.state_kinds != ("kv",):
                 self.page_bytes[kind] += leaf_device_nbytes(leaf) // num_pages
             elif name in ("ssm_state", "ssm_conv"):
@@ -1500,6 +1503,8 @@ class PagedKVCacheManager(_LaneBook):
             return {"latent_pages_in_use": pool.pages_in_use,
                     "latent_pages_in_trie": len(pool._node_of_page),
                     "latent_page_bytes": self.page_bytes["kv"],
+                    # of them, what the indexer's keys take (0 without one)
+                    "index_pool_bytes": self.index_pool_bytes,
                     "state_bytes_lanes": 0,
                     "kv_page_bytes_in_use": (pool.pages_in_use
                                              * self.page_bytes["kv"])}

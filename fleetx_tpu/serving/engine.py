@@ -2430,7 +2430,8 @@ class ServingEngine:
                 self._fault_ctx = None
                 self._run_chunk(req)  # this tick's one chunk of budget
                 return
-            at.update(self._scan_rows(req.prompt_len - shared, shared))
+            at.update(self.metrics.record_selection(
+                self._scan_rows(req.prompt_len - shared, shared)))
             first = self._paged_prefill_call(
                 req, req.prompt[shared:], shared, req.slot)
             self._register_prefix(req)
@@ -2512,7 +2513,8 @@ class ServingEngine:
         tokens = req.prompt[start:end]
         self._fault_ctx = ("prefill", req.id)
         with span("serving.prefill_chunk", request=req.id, start=start,
-                  final=final, **self._scan_rows(end - start, start)):
+                  final=final, **self.metrics.record_selection(
+                      self._scan_rows(end - start, start))):
             out = self._paged_prefill_call(req, tokens, start, req.slot,
                                            replay=not final)
         self._fault_ctx = None
@@ -2684,9 +2686,16 @@ class ServingEngine:
         rows = self.cache_manager.lengths[list(lanes)] + 1
         if self._state_rows:  # what ONE of its attention layers reads, and
             # the lanes whose lane-resident state the tick advances
-            return {self.model.cfg.rows_span_field: int(rows.sum()),
-                    **({"state_lanes": len(lanes)} if self._lane_state
-                       else self.model.cfg.span_pairs(self.slots))}
+            cfg = self.model.cfg
+            fields = {cfg.rows_span_field: int(rows.sum()),
+                      **({"state_lanes": len(lanes)} if self._lane_state
+                         else cfg.span_pairs(self.slots))}
+            if cfg.indexed:
+                # a lane scores every index key behind its token and
+                # attends over the rows the indexer keeps of them
+                fields.update(index_rows=int(rows.sum()),
+                              selected_rows=int(cfg.selected(rows).sum()))
+            return fields
         if not self.window_pages:
             return {}
         return {"full_rows": int(rows.sum()), "window_rows": int(
@@ -2740,7 +2749,7 @@ class ServingEngine:
         program = self._next_program()
         with span("serving.decode", batch=len(active_ids),
                   inflight=int(before is not None), program=program,
-                  **self._decode_rows(lanes)):
+                  **self.metrics.record_selection(self._decode_rows(lanes))):
             cache, st, tok, done = self._run_device(run)
         self.cache_manager.cache = cache
         self._state = st

@@ -317,6 +317,15 @@ class ServingMetrics:
             "fleetx_serving_prefill_row_writes_total",
             "Prefill programs that wrote their keys and values a row at a "
             "time")
+        # under a learned indexer (models/gpt/latent.py): the index keys the
+        # queries scored and the rows they then attended over, a layer
+        self._c_index_rows = counter(
+            "fleetx_serving_index_rows_scored_total",
+            "Index keys scored by the queries of ticks and prefill calls, "
+            "in one layer")
+        self._c_selected_rows = counter(
+            "fleetx_serving_rows_selected_total",
+            "Cached rows the indexer kept for those queries, in one layer")
         self._first_token_t: Optional[float] = None
         self._last_token_t: Optional[float] = None
         weakref.finalize(self, _drop_series, owned)
@@ -424,6 +433,15 @@ class ServingMetrics:
         where it wrote a row at a time."""
         (self._c_prefill_page_writes if pages
          else self._c_prefill_row_writes).inc()
+
+    def record_selection(self, fields: dict) -> dict:
+        """The span fields of a tick or a prefill call, counted where they
+        carry an indexer's work (``index_rows``, ``selected_rows``);
+        returned as handed over."""
+        if "index_rows" in fields:
+            self._c_index_rows.inc(fields["index_rows"])
+            self._c_selected_rows.inc(fields["selected_rows"])
+        return fields
 
     def observe_host_tier(self, store) -> None:
         """Per-tick sync from a :class:`HostPageStore`: gauges track its
@@ -841,6 +859,8 @@ class ServingMetrics:
             # prefill programs by the unit of their cache write
             "prefill_page_writes": int(self._c_prefill_page_writes.value),
             "prefill_row_writes": int(self._c_prefill_row_writes.value),
+            "index_rows_scored": int(self._c_index_rows.value),
+            "rows_selected": int(self._c_selected_rows.value),
             # crash-safety story: how often the engine recovered, what it
             # quarantined, what shutdown turned away, and what a tick costs
             "engine_recoveries": self.engine_recoveries,

@@ -78,6 +78,12 @@ _CANNOT_APPLY = {
         "perfbench/flops.py gpt_param_count counts the GPT-2 block only "
         "(a benchmark PR's to extend); tests/perfbench/test_perfbench_axk1.py"
         " holds this configuration's count to the program's own model",
+    "tests/perfbench/test_perfbench_flops.py::"
+    "test_param_count_matches_the_programs_model"
+    "[perfbench/configs/dsv32-ep16-l5.json]":
+        "perfbench/flops.py gpt_param_count counts the GPT-2 block only "
+        "(a benchmark PR's to extend); tests/perfbench/test_perfbench_dsv32.py"
+        " holds this configuration's count to the program's own model",
 }
 # the same for every case of one test and cell: (node id's start, reason)
 _CANNOT_APPLY_FROM = (
@@ -95,6 +101,13 @@ _CANNOT_APPLY_FROM = (
      "PR 37 alone (a benchmark PR's to extend); tests/perfbench/"
      "test_perfbench_axk1.py makes this cell's traced rehearsal and holds "
      "every entry that lists it"),
+    ("tests/perfbench/test_perfbench_rehearsal.py::"
+     "test_traced_rehearsal_reports_a_shared_entry_in_each_cell_it_lists"
+     "[dsv32-l5-serve-longqa-sparse-",
+     "test_perfbench_rehearsal.py's TINY_REPORTS has a row for the cells of "
+     "PR 37 alone (a benchmark PR's to extend); tests/perfbench/"
+     "test_perfbench_dsv32.py makes this cell's traced rehearsal and holds "
+     "every entry that lists it"),
 )
 
 
@@ -105,10 +118,14 @@ _CANNOT_APPLY_FROM = (
 _WRITTEN_BEFORE = {
     "tests/perfbench/test_perfbench_lfm2.py::"
     "test_every_width_is_the_published_one_and_only_the_depth_is_cut":
-        ("jamba2-3b-serve-chat-peak", "axk1-l6-serve-docqa-latent"),
+        ("jamba2-3b-serve-chat-peak", "axk1-l6-serve-docqa-latent",
+         "dsv32-l5-serve-longqa-sparse"),
     "tests/perfbench/test_perfbench_jamba2.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
-        ("axk1-l6-serve-docqa-latent",),
+        ("axk1-l6-serve-docqa-latent", "dsv32-l5-serve-longqa-sparse"),
+    "tests/perfbench/test_perfbench_axk1.py::"
+    "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
+        ("dsv32-l5-serve-longqa-sparse",),
 }
 
 
@@ -136,6 +153,8 @@ def _benchmark_as_the_test_knew_it(request, monkeypatch):
                     m["workloads"] = [w for w in m["workloads"]
                                       if w not in later]
             data[group] = [m for m in data[group] if m.get("workloads", [1])]
+        used = {w["config"] for w in data["workloads"]}
+        data["configs"] = [c for c in data["configs"] if c["name"] in used]
         return data
 
     monkeypatch.setattr(harness, "load_json", load_json)

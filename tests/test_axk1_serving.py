@@ -611,7 +611,7 @@ def types_of(cfg):
     (dict(first_expert_held=14), "experts held"),
     (dict(n_group=3), "n_group"),
     (dict(topk_group=5), "n_group"),
-    (dict(use_expert_bias=True), "selection bias"),
+    (dict(index_topk=8), "index_topk"),   # (an indexer needs all three)
     (dict(gate="softmax_topk"), "sigmoid_topk"),
     (dict(rope_scaling_factor=0.5), "rope_scaling_factor"),
 ])

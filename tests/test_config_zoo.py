@@ -34,6 +34,7 @@ _EXPLICIT = {
     "serve_lfm2_8b_a1b_l14.yaml": 1,  # one replica on one chip
     "serve_jamba2_3b.yaml": 1,        # the whole model on one chip
     "serve_axk1_ep16_l6.yaml": 1,     # one chip's share of sixteen
+    "serve_dsv32_ep16_l5.yaml": 1,    # one chip's share of sixteen
 }
 
 # _base_ fragments: not launchable topologies on their own
