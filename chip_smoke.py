@@ -19,7 +19,10 @@ the full width of GPT-345M (hidden 1024, 24 layers, 16 heads of 64, vocab
   ``finish_reason == "max_length"``, with no recovery, poison retirement or
   fault event, the compiled decode tick must hold the paged decode
   kernel's Mosaic call, and neither the tick nor a prefill program may hold
-  a copy of the page pool among its temporaries.
+  a copy of the page pool among its temporaries. Then the paged decode
+  kernel alone at the chat cell's call, ten of 24 lanes without a token:
+  their output blocks, prefilled with NaN, must come back exact zeros, and
+  the busy lanes equal a call without the empty ones bit for bit.
 - on a host with four chips, the same two paths again over the mesh: the
   trainer at dp2 x mp2 (parameter shards on all four chips, first-step
   loss against the one-chip run on the same batch with dropout off) and
@@ -40,6 +43,7 @@ compiled programs go to the persistent compile cache
 """
 
 import argparse
+import contextlib
 import json
 import math
 import os
@@ -291,15 +295,108 @@ def leg_serve(mp: int) -> dict:
     assert calls, ("the decode tick holds no Mosaic call of "
                    f"{PAGED_KERNEL_NAME}: flash decode gave way")
     temporaries = _pool_stays_in_place(engine, tick)
+    # (one chip: the kernel takes a mesh through the engine alone)
+    empty_lanes = empty_lanes_are_zeros() if mp == 1 else None
     return {
         "device": device, "mesh": {"mp": mp}, "requests": len(REQUESTS),
         "tokens_generated": int(snap["tokens_generated"]),
         "ticks": int(snap["ticks"]), "drain_wall_s": round(wall_s, 1),
         "mosaic_calls": {PAGED_KERNEL_NAME: calls},
-        "temporaries": temporaries,
+        "temporaries": temporaries, "empty_lanes": empty_lanes,
         "kv_cache_bytes_per_device": int(snap["kv_cache_bytes"]),
         "tokens": tokens, "compile_cache_dir": cache_dir, **clock.report(),
     }
+
+
+@contextlib.contextmanager
+def nan_prefilled_outputs():
+    """Inside, every ``pl.pallas_call`` of a decode kernel (grid ``(lanes,
+    blocks)``, one output block a lane) first fills a lane's output block
+    with NaN, at the lane's step 0 and before the kernel's own code. A
+    block the kernel never writes then comes back NaN, on the chip as in
+    the interpreter, where it would else come back as whatever the buffer
+    held: zeros by luck, or a lane's result of two steps before.
+    ``tests/test_decode_attention.py`` holds the empty lanes to exact
+    zeros under it as :func:`empty_lanes_are_zeros` does here."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    real = pl.pallas_call
+
+    def prefilling(kernel, *args, grid_spec, **kwargs):
+        out_at = grid_spec.num_scalar_prefetch + len(grid_spec.in_specs)
+
+        def prefilled(*refs):
+            o_ref = refs[out_at]
+
+            @pl.when(pl.program_id(1) == 0)
+            def _prefill():
+                o_ref[...] = jnp.full(o_ref.shape, jnp.nan, o_ref.dtype)
+
+            kernel(*refs)
+
+        return real(prefilled, *args, grid_spec=grid_spec, **kwargs)
+
+    pl.pallas_call = prefilling
+    try:
+        yield
+    finally:
+        pl.pallas_call = real
+
+
+def empty_lanes_are_zeros() -> dict:
+    """The paged decode kernel at the chat cell's call (24 lanes of 64
+    pages of 16 rows, 16 heads of 128), ten lanes of it without a token
+    (``end`` 0: first, last, and two and three in a row), at several pages
+    a step, at one (``block_k`` = the page) and over an int8 pool: under
+    :func:`nan_prefilled_outputs` the empty lanes' blocks must be exact
+    zeros, and the busy lanes equal bit for bit a call that holds them
+    alone (the chain of copies passes over the empty ones)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fleetx_tpu.ops.pallas.decode_attention import (
+        flash_decode_paged_attention,
+    )
+    from fleetx_tpu.ops.quant import quantize_kv
+
+    lanes, n_row, ps, h, d = 24, 64, 16, 16, 128
+    rng = np.random.RandomState(0)
+    empty = np.asarray([0, 3, 4, 8, 12, 13, 14, 19, 22, 23])
+    ends = rng.randint(1, n_row * ps + 1, lanes).astype(np.int32)
+    ends[empty] = 0
+    tables = np.zeros((lanes, n_row), np.int32)
+    busy = np.flatnonzero(ends)
+    tables[busy] = 1 + np.arange(len(busy) * n_row).reshape(-1, n_row)
+    pool = (len(busy) * n_row + 1, ps, h * d)
+    q = jnp.asarray(rng.randn(lanes, 1, h, d), jnp.bfloat16)
+    k = jnp.asarray(rng.randn(*pool), jnp.bfloat16)
+    v = jnp.asarray(rng.randn(*pool), jnp.bfloat16)
+    k8, ks = quantize_kv(k.reshape(pool[:2] + (h, d)))
+    v8, vs = quantize_kv(v.reshape(pool[:2] + (h, d)))
+    int8 = dict(k_scale=ks[..., 0], v_scale=vs[..., 0])
+    checked = []
+    for name, k_pool, v_pool, kw in (
+            ("pages_16", k, v, {}), ("pages_1", k, v, dict(block_k=ps)),
+            ("int8", k8.reshape(pool), v8.reshape(pool), int8)):
+        def call(which, k_pool=k_pool, v_pool=v_pool, kw=kw):
+            with nan_prefilled_outputs():
+                return np.asarray(jax.jit(
+                    lambda q, t, e: flash_decode_paged_attention(
+                        q, k_pool, v_pool, tables=t, end=e, **kw))(
+                    q[which], jnp.asarray(tables[which]),
+                    jnp.asarray(ends[which])).astype(jnp.float32))
+
+        whole = call(np.arange(lanes))
+        assert not whole[empty].any(), (   # (a NaN is truthy too)
+            name, "an empty lane's output block is not zeros",
+            whole[empty].reshape(len(empty), -1)[:, :4])
+        assert np.isfinite(whole[busy]).all() and whole[busy].any(), name
+        assert np.array_equal(whole[busy], call(busy)), (
+            name, "a busy lane differs from the call without empty lanes")
+        checked.append(name)
+    return {"empty_lanes": len(empty), "checked": checked}
 
 
 def _pool_stays_in_place(engine, tick) -> dict:

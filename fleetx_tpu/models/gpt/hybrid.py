@@ -321,6 +321,7 @@ class HybridSelfAttention(SelfAttention):
         tables = block_tables.astype(jnp.int32)
         if tables.ndim == 3:     # [class, lanes, pages]: 0 full, 1 window
             tables = jnp.where(windowed, tables[1], tables[0])
+        own = tables    # no layer's base added: page 0 is the trash page
         tables = tables + jnp.asarray(layer_bases(cfg))[layer_index]
         if k is not None:  # (phase "attend": the caller has written them)
             ck.value, cv.value = write_rows(cfg, ck.value, cv.value, tables,
@@ -330,7 +331,7 @@ class HybridSelfAttention(SelfAttention):
 
         if s == 1 and self._flash_decode_ok(None, n * ps, deterministic,
                                             tile_len=ps):
-            end = wpos + 1
+            end = paged_write.decode_end(own, wpos, ps)
 
             def kernel(starts):
                 return flash_decode_paged_attention(

@@ -91,6 +91,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
+from fleetx_tpu.models.gpt import paged_write
 from fleetx_tpu.models.gpt.hybrid import layer_bases
 from fleetx_tpu.models.gpt.mixed_stack import MixedStack
 from fleetx_tpu.models.gpt.model import (
@@ -295,11 +296,7 @@ class LatentAttention(nn.Module):
         tables = block_tables.astype(jnp.int32)
         scale = softmax_scale(cfg)
         if s == 1:
-            # a lane whose row went to the trash page is no token
-            live = jnp.take_along_axis(
-                tables, jnp.minimum(wpos // ps, tables.shape[1] - 1)[:, None],
-                axis=1)[:, 0] != 0
-            end = jnp.where(live, wpos + 1, 0)
+            end = paged_write.decode_end(tables, wpos, ps)
             tables = tables + jnp.asarray(layer_bases(cfg))[layer_index]
             with jax.named_scope("mla_absorb"):
                 q_c = jnp.einsum("bhd,chd->bhc", q[:, 0, :, :nope],

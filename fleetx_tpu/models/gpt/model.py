@@ -629,7 +629,7 @@ class SelfAttention(nn.Module):
             else:
                 idx.value = idx.value.at[layer_index].set(jnp.max(wpos) + s)
             if s == 1:
-                decode_end = wpos + 1  # [b]: per-row live logical length
+                decode_end = paged_write.decode_end(block_tables, wpos, ps)
             k_pos = jnp.arange(max_len)
             q_pos = wpos[:, None] + jnp.arange(s)[None, :]  # [b, s] logical
             causal = (k_pos[None, None, :] <= q_pos[:, :, None])[:, None, :, :]

@@ -32,7 +32,21 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["page_writes", "write_rows"]
+__all__ = ["decode_end", "page_writes", "write_rows"]
+
+
+def decode_end(tables, wpos, page_size: int):
+    """``[b]`` ends of the windows a one-row call's lanes attend over:
+    ``wpos + 1``, and 0 for a lane whose row went to the trash page. Such a
+    lane decodes no token (the serving engine pins a lane without one to the
+    last row of its table, which a free lane's zeroed table sends to page
+    0), so its window is empty and the decode kernels run no step for it.
+    A lane that holds a real page there attends as ever. ``tables`` is the
+    lanes' ``[b, pages of a row]`` as the engine hands it over: NO layer's
+    base added, so page 0 is the trash page."""
+    page = jnp.minimum(wpos // page_size, tables.shape[1] - 1)
+    live = jnp.take_along_axis(tables, page[:, None], axis=1)[:, 0] != 0
+    return jnp.where(live, wpos + 1, 0)
 
 
 def page_writes(batch: int, rows: int, page_size: int) -> int:

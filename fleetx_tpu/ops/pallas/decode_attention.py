@@ -46,6 +46,14 @@ What's different from the training kernel:
 - forward-only: decode never differentiates, so there is no VJP, no lse
   output, and no dropout plumbing.
 
+Empty windows: a batch row with ``end <= starts`` attends over nothing. The
+serving engine's tick carries such rows, the lanes that decode no token
+(the models hand them ``end = 0``: ``models/gpt/paged_write.decode_end``),
+and they cost the kernels nothing: no step runs for the row and no copy is
+started for it, its K/V index map repeats one block (never the table at
+-1), and its output block is written once, at the row's step 0, as exact
+zeros. The paged kernel's chain of copies passes over such rows.
+
 Grouped-query heads: the cache may hold FEWER heads than the query has
 (``kv_heads * d`` lanes a row; query head r reads key head ``r // group``).
 The kernels are the same walk with the block-diagonal query built for the
@@ -101,11 +109,11 @@ tile, so a step of one 16-row page cost what a step of 256 rows costs
 - P > 1 (:func:`_paged_block_call`): the pools stay in HBM and a live
   step's LIVE pages are copied, one async copy a page through the row's
   table, side by side into a double-buffered ``[2, P * page_size, h*d]``
-  VMEM tile; each live step starts the next live step's copies before it
-  waits for its own. Pages of the block outside ``[first, last]`` are not
-  copied and their rows are masked by position, so a call reads the rows'
-  live pages, rounded up to pages and never to blocks; a step wholly
-  outside the window does nothing.
+  VMEM tile; each live step starts the next live step's copies (the next
+  lane's that HAS a window) before it waits for its own. Pages of the
+  block outside ``[first, last]`` are not copied and their rows are masked
+  by position, so a call reads the rows' live pages, rounded up to pages
+  and never to blocks; a step wholly outside the window does nothing.
 - P = 1 (a page is ``block_k`` rows already, or a page is below the pool
   dtype's packed tile, as a 16-row int8 page is half a (32, 128) tile and
   P of them would need a relayout to lie side by side): a step is a page,
@@ -349,8 +357,12 @@ def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
     jm = pl.program_id(1)
     start = starts_ref[bi]
     end = ends_ref[bi]
-    first_jm = start // major
-    last_jm = (end - 1) // major
+    # an empty window (a lane that decodes no token, module docstring
+    # "Empty windows") is the lane's step 0 and nothing else: the state is
+    # zeroed and finalized there, so its output block is exact zeros
+    empty = end <= start
+    first_jm = jnp.where(empty, 0, start // major)
+    last_jm = jnp.where(empty, 0, (end - 1) // major)
     tiles = major // block_k
     rows, hd = acc_scr.shape
     d = hd // (heads // group)
@@ -366,7 +378,7 @@ def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    @pl.when((jm >= first_jm) & (jm <= last_jm))
+    @pl.when(~empty & (jm >= first_jm) & (jm <= last_jm))
     def _step():
         k_rows, v_rows, ks_rows, vs_rows = (
             (k_ref, v_ref, ks_ref, vs_ref) if gather is None else gather())
@@ -414,8 +426,9 @@ def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(jm == last_jm)
     def _finalize():
         l = l_scr[:]
-        # the window always holds the query's own position, so l > 0; the
-        # guard keeps a (contract-violating) empty window finite, not NaN
+        # a live window holds the query's own position, so l > 0; an empty
+        # one arrives with l and acc as _init left them, and the guard
+        # makes its block 0 / 1
         l_safe = jnp.where(l > 0.0, l, 1.0)
         out = jnp.where(diag, acc_scr[:] / l_safe, 0.0)
         if group == 1:
@@ -446,7 +459,8 @@ def _kv_index_map(major: int):
 
     def index_map(bi, jm, starts_ref, ends_ref):
         first = starts_ref[bi] // major
-        last = (ends_ref[bi] - 1) // major
+        # an empty window: every step repeats block ``first``
+        last = jnp.maximum((ends_ref[bi] - 1) // major, first)
         return bi, jnp.clip(jm, first, last), 0
 
     return index_map
@@ -652,7 +666,9 @@ def _paged_kv_index_map(page_size: int):
 
     def index_map(bi, jm, starts_ref, ends_ref, tables_ref):
         first = starts_ref[bi] // page_size
-        last = (ends_ref[bi] - 1) // page_size
+        # an empty window: every step repeats page ``first``, and the
+        # table is never read at -1
+        last = jnp.maximum((ends_ref[bi] - 1) // page_size, first)
         return tables_ref[bi, jnp.clip(jm, first, last)], 0, 0
 
     return index_map
@@ -691,11 +707,12 @@ def _paged_block_call(q, pools, starts_b, ends_b, tables_b, pages: int):
     whatever an earlier step left, finite, and ``_decode_kernel`` masks
     them by position), so the bytes a call reads are the lanes' live
     pages, never rounded up to blocks. Each live step starts the NEXT live
-    step's copies (this lane's next block, else the next lane's first)
-    into the other half before it waits for its own, so the gather runs
-    under the step before it; a dead step does nothing. Both grid axes are
-    sequential: the buffer half and whether it is already on its way pass
-    from step to step in SMEM (``state``)."""
+    step's copies (this lane's next block, else the first block of the
+    next lane that HAS a window, ``next_live``: the lanes between decode no
+    token) into the other half before it waits for its own, so the gather
+    runs under the step before it; a dead step does nothing. Both grid axes
+    are sequential: the buffer half and whether it is already on its way
+    pass from step to step in SMEM (``state``)."""
     b, _, h, d = q.shape
     ps = pools[0].shape[1]
     n_pages = tables_b.shape[1]
@@ -704,7 +721,14 @@ def _paged_block_call(q, pools, starts_b, ends_b, tables_b, pages: int):
     width = pools[0].shape[-1]
     q_in, q_block, group = _query_operand(q, width)
 
-    def kernel(starts_ref, ends_ref, tables_ref, q_ref, *rest):
+    # the chain passes over empty lanes: each lane's next lane WITH a
+    # window (``b`` behind the last), so that a lane's last step starts that
+    # lane's first copies however many free lanes lie between
+    live = jnp.where(ends_b > starts_b, jnp.arange(b, dtype=jnp.int32), b)
+    next_live = jnp.append(jax.lax.cummin(live, reverse=True)[1:],
+                           jnp.int32(b))
+
+    def kernel(starts_ref, ends_ref, tables_ref, next_ref, q_ref, *rest):
         pool_refs, rest = rest[:n_ops], rest[n_ops:]
         o_ref, m_scr, l_scr, acc_scr = rest[:4]
         bufs, (sem, state) = rest[4:4 + n_ops], rest[4 + n_ops:]
@@ -752,10 +776,10 @@ def _paged_block_call(q, pools, starts_b, ends_b, tables_b, pages: int):
                 copies(bi, jm, half, wait=False)
 
             same = jm < (ends_ref[bi] - 1) // rows
-            lane = jnp.minimum(jnp.where(same, bi, bi + 1), b - 1)
+            nxt = next_ref[bi]
+            ahead = same | (nxt < b)
+            lane = jnp.where(same, bi, jnp.minimum(nxt, b - 1))
             blk = jnp.where(same, jm + 1, starts_ref[lane] // rows)
-            ahead = same | ((bi + 1 < b)
-                            & (ends_ref[lane] > starts_ref[lane]))
 
             @pl.when(ahead)
             def _next():
@@ -773,7 +797,7 @@ def _paged_block_call(q, pools, starts_b, ends_b, tables_b, pages: int):
                        group=group)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b, -(-n_pages // pages)),
         in_specs=[pl.BlockSpec(q_block, _q_index_map)]
         + [pl.BlockSpec(memory_space=pl.ANY)] * n_ops,
@@ -793,7 +817,7 @@ def _paged_block_call(q, pools, starts_b, ends_b, tables_b, pages: int):
         ),
         interpret=_interpret(),
         name=PAGED_KERNEL_NAME,
-    )(starts_b, ends_b, tables_b, q_in, *pools)
+    )(starts_b, ends_b, tables_b, next_live, q_in, *pools)
     return _heads_of(out, q.shape, group)
 
 

@@ -2589,12 +2589,18 @@ class ServingEngine:
             self._active[req.slot] = req
 
     def _decode_fn(self, params, cache, st, tables, all_greedy: bool):
-        """Jitted: ONE decode token for every slot (inactive slots ride
-        along with writes pinned to the last cache row — which a freed
-        lane's zeroed block table re-routes to the trash page — outputs
-        ignored). ``tables`` is the device block tables. ``all_greedy``
-        is static — greedy-only ticks take a bare argmax and skip the
-        sampler's top-k sort / top-p bisection / rng split."""
+        """Jitted: ONE decode token for every slot. An inactive slot
+        rides along with its write pinned to the last cache row, which a
+        freed lane's zeroed block table re-routes to the trash page: the
+        model reads that off the table and hands the decode kernel an
+        EMPTY window for the lane (``paged_write.decode_end``), so it
+        costs the kernel no step and no copy; a lane that is inactive in
+        this tick but owns a real page at the last row (parked, or
+        mid-prefill with a request that fills the row) attends as a live
+        one. Either way its outputs are ignored. ``tables`` is the device
+        block tables. ``all_greedy`` is static — greedy-only ticks take a
+        bare argmax and skip the sampler's top-k sort / top-p bisection /
+        rng split."""
         params = self._dequant_params(params)
         active = st["active"]
         lengths = st["lengths"]
@@ -2748,6 +2754,7 @@ class ServingEngine:
         before = self._inflight
         program = self._next_program()
         with span("serving.decode", batch=len(active_ids),
+                  empty_lanes=self.slots - len(lanes),
                   inflight=int(before is not None), program=program,
                   **self.metrics.record_selection(self._decode_rows(lanes))):
             cache, st, tok, done = self._run_device(run)

@@ -137,6 +137,52 @@ def test_chunked_flash_interpret_parity(tiny_flash):
         assert_token_parity(a, b, err_msg=f"req {i} (flash-interpret)")
 
 
+def test_free_and_mid_prefill_lanes_change_no_token(tiny_flash, monkeypatch):
+    """More lanes than requests, and one long prompt mid-prefill in chunks
+    (its request fills the row, so the lane is inactive in those ticks and
+    OWNS a real page at the last row) while two requests decode: the paged
+    decode kernel (interpreted) is handed an empty window for the free
+    lanes, the row for the mid-prefill one, and every request's greedy
+    tokens are those of the same request served alone. Each
+    ``serving.decode`` span counts the slots dispatched without a token as
+    ``empty_lanes``."""
+    from fleetx_tpu.obs.tracing import get_recorder
+
+    monkeypatch.setenv("FLEETX_FORCE_FLASH", "1")
+    model, params = tiny_flash
+    rng = np.random.RandomState(17)
+    prompts = [rng.randint(1, 61, (n,)).astype(np.int32) for n in (5, 9, 28)]
+    news = (14, 11, 4)      # 28 + 4: the long request fills its row of 32
+
+    def serve(eng, which):
+        rids = [eng.submit(prompts[i], max_length=news[i]) for i in which]
+        res = eng.drain()
+        return [np.asarray(res[r].tokens) for r in rids]
+
+    alone = _engine(model, params, slots=5, prefill_chunk=6)
+    want = [serve(alone, [i])[0] for i in range(3)]
+
+    eng = _engine(model, params, slots=5, prefill_chunk=6)
+    rec = get_recorder()
+    rec.clear()
+    short = [eng.submit(prompts[i], max_length=news[i]) for i in (0, 1)]
+    eng.step()
+    long_rid = eng.submit(prompts[2], max_length=news[2])
+    mid_prefill_ticks = 0
+    while eng._prefilling or len(eng.scheduler):
+        eng.step()
+        if eng._prefilling:     # its pages were handed out at admission
+            mid_prefill_ticks += int(eng.cache_manager.tables[:, -1].any())
+    assert mid_prefill_ticks >= 3
+    res = eng.drain()
+    for rid, tokens in zip(short + [long_rid], want):
+        assert_token_parity(np.asarray(res[rid].tokens), tokens,
+                            err_msg=f"request {rid}")
+    ticks = [s for s in rec.spans() if s.name == "serving.decode"]
+    assert ticks and all(
+        s.attrs["empty_lanes"] == 5 - s.attrs["batch"] >= 2 for s in ticks)
+
+
 def test_chunked_parity_at_cache_capacity_edge(tiny):
     """Regression (PR 11 review): a chunk whose PADDED bucket would
     cross ``cache_len`` must cap at the remaining span (prompt_len 31 in

@@ -209,30 +209,79 @@ def test_pages_per_step(page, block_k, pages):
         1 if page < 32 else pages)
 
 
-def test_paged_kernel_with_free_and_empty_lanes_interleaved():
-    """A lane whose window is empty (``end`` <= ``start``: no live step)
-    between busy lanes must not break the chain in which a live step
-    starts the next live step's copies: the lanes after it still read
-    their own pages."""
+# lane -> (pages held, end, start): empty lanes first and last, two in a
+# row (2 and 3; with ``starts`` lane 3's window is ``end`` <= ``start`` in a
+# later block), busy ones that end in a first block, begin in a later one
+# and fill the row
+_EMPTY_LANE_WINDOWS = ((0, 0, 0), (10, 300, 0), (0, 0, 0), (10, 100, 300),
+                       (3, 70, 0), (10, 290, 270), (20, 640, 0), (0, 0, 0))
+
+
+@pytest.mark.parametrize("with_starts", [False, True])
+@pytest.mark.parametrize("kv_dtype,group", [
+    ("bfloat16", 1), ("bfloat16", 4), ("int8", 1)])  # (int8 takes group 1)
+@pytest.mark.parametrize("pages", [8, 1])
+def test_paged_kernel_with_free_and_empty_lanes_interleaved(
+        pages, kv_dtype, group, with_starts):
+    """A lane whose window is empty (``end`` = 0, or ``end`` <= ``start``:
+    a lane that decodes no token) is no step and no copy, and its output
+    block is EXACT zeros, under a NaN prefill that a block the kernel
+    never writes keeps (``chip_smoke.nan_prefilled_outputs``). Between,
+    before and behind busy lanes it does not break the chain in which a
+    live step starts the next live step's copies: the busy lanes read their
+    own pages, and equal bit for bit a call that holds the busy lanes
+    alone. At several pages a step (``pages`` 8 of 32 rows) and at one
+    (``block_k`` = the page size), over a bf16 and an int8 pool, with every
+    key head its own and shared by 4 query heads."""
+    from chip_smoke import nan_prefilled_outputs
+
     rng = np.random.RandomState(5)
-    b, ps, h, d, n_row = 4, 16, 4, 32, 40
-    tables = jnp.asarray(_rows(n_row, range(1, 21), range(21, 41),
-                               range(41, 61), range(61, 81)))
+    if (kv_dtype, pages, jax.default_backend()) == ("int8", 8, "tpu"):
+        pytest.skip("Mosaic refuses the copy of a scale page ([rows, heads]: "
+                    "no 128 lanes) at several pages a step, on any tree; "
+                    "the engine's 16-row int8 pages take one a step")
+    # (on the chip a copied row is whole 128-lane tiles: 2 key heads of 64)
+    b, ps, h, d, n_row = len(_EMPTY_LANE_WINDOWS), 32, 8, 64, 20
+    kvh = h // group
+    held = np.cumsum([0] + [n for n, _, _ in _EMPTY_LANE_WINDOWS])
+    tables = jnp.asarray(_rows(n_row, *(
+        range(1 + lo, 1 + hi) for lo, hi in zip(held, held[1:]))))
+    ends = jnp.asarray([e for _, e, _ in _EMPTY_LANE_WINDOWS], jnp.int32)
+    starts = jnp.asarray([s for _, _, s in _EMPTY_LANE_WINDOWS], jnp.int32)
+    if not with_starts:
+        ends = jnp.where(ends > starts, ends, 0)
+        starts = jnp.zeros_like(starts)
+    busy = np.flatnonzero(np.asarray(ends > starts))
+    assert list(busy) == [1, 4, 5, 6]
     q = jnp.asarray(rng.randn(b, 1, h, d), jnp.bfloat16)
-    k = jnp.asarray(rng.randn(81, ps, h, d), jnp.bfloat16)
-    v = jnp.asarray(rng.randn(81, ps, h, d), jnp.bfloat16)
-    ends = jnp.asarray([300, 0, 100, 290], jnp.int32)
-    starts = jnp.asarray([0, 0, 100, 270], jnp.int32)
-    out = flash_decode_paged_attention(
-        q, _fold(k), _fold(v), tables=tables, end=ends, starts=starts)
-    gather = lambda x: x[tables].reshape(b, n_row * ps, h, d)
-    ref = _dense_window_attention(
+    k = jnp.asarray(rng.randn(int(held[-1]) + 1, ps, kvh, d), jnp.bfloat16)
+    v = jnp.asarray(rng.randn(int(held[-1]) + 1, ps, kvh, d), jnp.bfloat16)
+    scales, pool_k, pool_v = {}, k, v
+    if kv_dtype == "int8":
+        pool_k, ks = quantize_kv(k)
+        pool_v, vs = quantize_kv(v)
+        scales = dict(k_scale=ks[..., 0], v_scale=vs[..., 0])
+        k, v = dequantize_kv(pool_k, ks), dequantize_kv(pool_v, vs)
+
+    def call(lanes):
+        with nan_prefilled_outputs():
+            return np.asarray(flash_decode_paged_attention(
+                q[lanes], _fold(pool_k), _fold(pool_v), tables=tables[lanes],
+                end=ends[lanes], block_k=pages * ps,
+                starts=starts[lanes] if with_starts else None, **scales
+            ).astype(jnp.float32))
+
+    assert _pages_per_step(ps, n_row, [pool_k], pages * ps) == pages
+    out = call(np.arange(b))
+    gather = lambda x: x[tables].reshape(b, n_row * ps, kvh, d)
+    ref = _dense_grouped(
         q.astype(jnp.float32), gather(k).astype(jnp.float32),
         gather(v).astype(jnp.float32), ends, starts)
-    for lane in (0, 3):  # lanes 1 and 2 have no key to attend to
-        np.testing.assert_allclose(
-            np.asarray(out[lane], np.float32), np.asarray(ref[lane]),
-            rtol=3e-2, atol=3e-2)
+    np.testing.assert_allclose(out[busy], np.asarray(ref)[busy],
+                               rtol=3e-2, atol=3e-2)
+    empty = np.setdiff1d(np.arange(b), busy)
+    assert np.array_equal(out[empty], np.zeros_like(out[empty])), out[empty]
+    np.testing.assert_array_equal(out[busy], call(busy))
 
 
 def _lower_for_tpu(monkeypatch, fn, *args):
