@@ -324,7 +324,7 @@ class Trainer:
             self._obs_ckpt_seconds.labels(phase=phase)
         self._flops_per_step = None  # lazy; False = no MFU on this run
         self._peak_flops = None  # set with _flops_per_step
-        self._hbm_bytes_per_step = None  # same contract as _flops_per_step
+        self._hbm_bytes_per_step = self._collectives_per_step = None  # as _flops_per_step
         self._cost_cache = {}  # name -> (abstract-args spec, Compiled, cost)
 
     # ------------------------------------------------------------------ init
@@ -926,8 +926,8 @@ class Trainer:
                             t_last = time.time()
                             ips_total = tokens_per_batch / dt
                             lr = float(self.lr_schedule(step))
-                            mfu = self._step_mfu(dt)
-                            hbm = self._step_hbm_bytes()
+                            mfu, hbm = self._step_mfu(dt), self._step_hbm_bytes()
+                            collectives = self._step_collectives(once=True)
                             self._obs_loss.set(float(losses))
                             self._obs_lr.set(lr)
                             self._obs_step_time.observe(dt)
@@ -945,8 +945,8 @@ class Trainer:
                                     "ips_total": ips_total,
                                     "ips": ips_total / max(
                                         jax.process_count(), 1),
-                                    "lr": lr,
-                                    "mfu": mfu,
+                                    "lr": lr, "mfu": mfu,
+                                    "collectives": collectives,
                                 }
                             )
                     if (self.eval_freq and valid_data is not None
@@ -1555,3 +1555,37 @@ class Trainer:
             self._prof_running = False
             if summary:
                 self._print_summary()
+
+    def _step_collectives(self, once: bool = False) -> Optional[Dict[str, int]]:
+        """How many collectives of each kind the compiled train step holds
+        (``collective_matmul.count_collectives`` of its text), for the
+        ``fleetx_train_step_collectives`` gauge: what says whether the
+        tensor-parallel products carry their collectives (collective-
+        permutes in place of the layer loops' activation-sized
+        all-reduces). Tried once, then cached, same contract as the flops;
+        ``once`` asks for None from every call but the one that fills it
+        (the counts ride the first TRAIN line alone). The method and its
+        gauge family sit down here, not beside the other gauges: the
+        compile cache's keys hold the line of every frame above a traced
+        call, ``fit`` and ``evaluate`` among them."""
+        if self._collectives_per_step is not None and once:
+            return None
+        if self._collectives_per_step is None:
+            from fleetx_tpu.parallel.collective_matmul import count_collectives
+
+            self._collectives_per_step = False
+            try:
+                text = self.compiled_text("train")
+            except Exception:  # noqa: BLE001 — observability never aborts
+                text = None
+            if text:
+                self._collectives_per_step = count_collectives(text)
+                gauge = get_registry().gauge(
+                    "fleetx_train_step_collectives",
+                    "Collective instructions of each kind in the compiled "
+                    "train step's text (-start counted, -done not; a "
+                    "scanned layer loop's body counts once whatever the "
+                    "depth)", labelnames=("kind",))
+                for kind, n in self._collectives_per_step.items():
+                    gauge.labels(kind=kind).set(float(n))
+        return self._collectives_per_step or None

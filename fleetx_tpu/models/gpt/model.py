@@ -199,8 +199,8 @@ class GPTConfig(block_fields.BlockLayoutFields):
 
 def _dense(features, logical_axes, name, use_bias=True, dtype=jnp.bfloat16):
     """Dense with logical-axis-partitioned kernel; bias follows the kernel's
-    output axes. The logical axes are what make this 'column parallel'
-    (out axis on mp) or 'row parallel' (in axis on mp) under the rules."""
+    output axes. The axes make it column (out on mp) or row parallel (in on mp)."""
+    from fleetx_tpu.parallel import collective_matmul
     return nn.DenseGeneral(
         features=features,
         axis=-1,
@@ -209,13 +209,13 @@ def _dense(features, logical_axes, name, use_bias=True, dtype=jnp.bfloat16):
         param_dtype=jnp.float32,
         kernel_init=nn.with_logical_partitioning(default_kernel_init, logical_axes),
         bias_init=nn.with_logical_partitioning(nn.initializers.zeros_init(), logical_axes[1:]),
-        name=name,
+        name=name, dot_general=collective_matmul.for_kernel(logical_axes),
     )
 
 
 def attn_out_dense(hidden_size, dtype, name="out_proj", use_bias=True):
-    """Row-parallel attention output projection [.., heads, kv] -> [.., embed]
-    — shared by GPT/ERNIE/ViT attention blocks."""
+    """Row-parallel [.., heads, kv] -> [.., embed]; GPT/ERNIE/ViT share it."""
+    from fleetx_tpu.parallel import collective_matmul
     return nn.DenseGeneral(
         features=hidden_size,
         axis=(-2, -1),
@@ -226,7 +226,7 @@ def attn_out_dense(hidden_size, dtype, name="out_proj", use_bias=True):
             default_kernel_init, ("heads", "kv", "embed")
         ),
         bias_init=nn.with_logical_partitioning(nn.initializers.zeros_init(), ("norm",)),
-        name=name,
+        name=name, dot_general=collective_matmul.for_kernel(("heads", "kv", "embed")),
     )
 
 
@@ -821,7 +821,7 @@ class DecoderLayer(nn.Module):
         )
         y = _dropout(cfg, "attn_dropout")(y, deterministic=deterministic)
         x = residual + y
-        residual = x
+        x = residual = _constrain_act(x, cfg)
         y = _layer_norm(cfg, "norm2")(x)
         if cfg.expert_mode and cfg.gate == "softmax_topk":
             from fleetx_tpu.parallel.moe import DroplessMoEMLP
@@ -840,13 +840,13 @@ class DecoderLayer(nn.Module):
         return _constrain_act(x, cfg)
 
 
-def _constrain_act(x, cfg: GPTConfig):
-    """Activation sharding: batch over data axes; seq over mp iff sequence
-    parallel (replaces the reference's explicit ScatterOp/GatherOp layout
-    management, sequence_parallel_utils.py:83-136)."""
-    if x.ndim == 3:
-        return nn.with_logical_constraint(x, ("act_batch", "act_seq", "act_embed"))
-    return x
+def _constrain_act(x, cfg: GPTConfig, point="residual"):
+    """Activation sharding at a named point of the block (sharding.ACT_AXES):
+    batch over the data axes; between two blocks, seq over mp iff sequence parallel."""
+    from fleetx_tpu.parallel.sharding import ACT_AXES, with_logical_constraint
+    axes = ACT_AXES[point]
+    return with_logical_constraint(x, axes) if x.ndim == len(axes) else x
+
 
 
 class _ScanLayer(nn.Module):
@@ -946,7 +946,7 @@ class GPTModel(nn.Module):
                                 decode=decode, cache_positions=cache_positions,
                                 block_tables=block_tables, rope=rope)
         x = _layer_norm(cfg, "final_norm")(x)
-        return _constrain_act(x, cfg)
+        return _constrain_act(x, cfg, "whole")
 
     def _expert_stack(self):
         """The three expert weights of ALL layers as the layer loop holds

@@ -31,15 +31,15 @@ class LanguageModule(BasicModule):
     run_benchmark.sh:20-22)."""
 
     def training_step_end(self, log: Dict) -> None:
-        # mfu rides the same parsed line: tokens/s alone is not comparable
-        # across configs, and perfbench/run.py reports ``train_mfu`` — the
-        # live log should speak the same language (docs/OBSERVABILITY.md).
-        # "-" when XLA exposed no flops for this step program.
+        # mfu rides the same parsed line (perfbench/run.py reports ``train_mfu``,
+        # docs/OBSERVABILITY.md; "-" when XLA exposed no flops for this step),
+        # and on the FIRST line the compiled step's collectives by kind, which
+        # the Trainer hands over once. (No line more: model code below is traced.)
         mfu = log.get("mfu")
         logger.train(
             "[train] epoch: %d, batch: %d, loss: %.9f, avg_batch_cost: %.5f sec, "
             "speed: %.2f step/s, ips_total: %.0f tokens/s, ips: %.0f tokens/s, "
-            "mfu: %s, learning rate: %.3e",
+            "mfu: %s, learning rate: %.3e%s",
             log["epoch"],
             log["batch"],
             log["loss"],
@@ -48,7 +48,7 @@ class LanguageModule(BasicModule):
             log["ips_total"],
             log["ips"],
             ("%.4f" % mfu) if mfu is not None else "-",
-            log["lr"],
+            log["lr"], "".join(", %s: %d" % kv for kv in (log.get("collectives") or {}).items()),
         )
 
     def validation_step_end(self, log: Dict) -> None:

@@ -8,10 +8,20 @@ reference's per-strategy wrappers:
 - ZeRO sharding stages 1-3 (distributed/apis/sharding.py:30-147) become the
   ``fsdp`` mesh axis applied to optimizer state (stage 1/2) and additionally
   to parameters (stage 3).
-- Megatron sequence parallel (sequence_parallel_utils.py:40-395) becomes an
-  activation sharding constraint putting the ``seq`` logical axis on ``mp``;
-  XLA's collective-matmul pass emits the same all-gather/reduce-scatter
-  overlap the hand-written ScatterOp/GatherOp/ReduceScatterOp provided.
+- Megatron sequence parallel (sequence_parallel_utils.py:40-395) becomes
+  activation sharding constraints: between two tensor-parallel blocks the
+  rows live sharded over ``mp`` (``act_seq``, :data:`ACT_AXES`), so a
+  column-parallel product gathers its input rows and a row-parallel one
+  scatters its sums, as ScatterOp/GatherOp/ReduceScatterOp did by hand.
+  On by default where ``mp > 1`` (utils/config.py). Left to the
+  partitioner those gathers and scatters are synchronous, and neither of
+  the compiler's own overlap passes helps (utils/xla_flags.py has what the
+  chip showed), so the products of a block carry their collectives
+  themselves: ``parallel/collective_matmul.py``, one ``shard_map`` a
+  product, used wherever the rows are sharded over ``mp`` alone and no
+  pipeline stage is in the way; elsewhere (context parallel, ``pp > 1``)
+  the partitioner's synchronous pairs stand, a column product's output
+  laid out by name.
 
 Models annotate params/activations with logical axis names (flax
 ``nn.with_partitioning`` / ``logical_to_mesh``); these tables translate
@@ -23,10 +33,14 @@ from __future__ import annotations
 import math
 from typing import Any, List, Optional, Sequence, Tuple
 
+import jax
 from flax import linen as nn
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from fleetx_tpu.parallel.mesh import ambient_mesh
 
 __all__ = [
+    "ACT_AXES",
     "make_rules",
     "logical_to_mesh_sharding",
     "param_shardings",
@@ -36,6 +50,18 @@ __all__ = [
 ]
 
 Rules = Sequence[Tuple[str, Any]]
+
+# Logical axes of the activations at the named points of a block
+# (models/gpt/model.py ``_constrain_act``). Between two tensor-parallel
+# blocks (``residual``: a layer's input, both residual sums) the rows live
+# where ``act_seq`` puts them; ``whole`` is the head's input, which holds
+# every row the device's ``cp`` share has (``act_seq_tp``). A
+# column-parallel product's output is laid out by the product itself
+# (parallel/collective_matmul.py).
+ACT_AXES = {
+    "residual": ("act_batch", "act_seq", "act_embed"),
+    "whole": ("act_batch", "act_seq_tp", "act_embed"),
+}
 
 
 def make_rules(
@@ -84,6 +110,9 @@ def make_rules(
         rules.append(("act_seq", "mp"))
     else:
         rules.append(("act_seq", None))
+    # the sequence axis INSIDE a tensor-parallel block (a column product's
+    # output, ACT_AXES): every row of the device's cp share, never mp
+    rules.append(("act_seq_tp", "cp" if context_parallel else None))
     rules.append(("act_batch", ("dp", "fsdp")))
     rules.append(("act_embed", None))
     return rules
@@ -102,8 +131,25 @@ def param_shardings(abstract_vars, mesh: Mesh, rules: Rules):
 
 
 def with_logical_constraint(x, logical_axes: Tuple[Optional[str], ...]):
-    """Annotate an activation with logical axes (no-op outside a mesh ctx)."""
-    return nn.with_logical_constraint(x, P(*logical_axes))
+    """Constrain an activation to the mesh axes its logical axes map to
+    under the rules in force, on the mesh :func:`use_mesh` entered.
+
+    flax's own ``nn.with_logical_constraint`` looks for the mesh in
+    ``jax.sharding.get_abstract_mesh()``, which the ``with mesh:`` context
+    that ``use_mesh`` enters does not set: under the Trainer it returned
+    ``x`` untouched (flax 0.12.3), so the mesh is handed over here. Entries
+    that do not divide their dimension are dropped (:func:`_fit_spec`), and
+    a spec that shards nothing (no mesh, no rules, every named axis of
+    extent 1) adds no constraint: the program of a one-chip run holds no
+    trace of the call."""
+    mesh = ambient_mesh()
+    if mesh is None:
+        return x
+    spec = _fit_spec(P(*nn.logical_to_mesh_axes(tuple(logical_axes))),
+                     x.shape, mesh)
+    if all(mesh.shape[a] == 1 for entry in spec for a in _spec_axes(entry)):
+        return x
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
 def _spec_axes(entry) -> Tuple[str, ...]:
@@ -142,7 +188,6 @@ def serving_param_shardings(abstract_params, params, mesh: Mesh,
     drop their axes via :func:`_fit_spec`, so scales end up replicated
     unless their channel axis is genuinely sharded. Leaves with no
     metadata (or paths the abstract tree lacks) replicate."""
-    from jax.sharding import NamedSharding
     from jax.tree_util import tree_flatten_with_path, tree_map_with_path
 
     logical = nn.get_partition_spec(abstract_params)

@@ -185,7 +185,16 @@ def process_dist_config(cfg: AttrDict, nranks: Optional[int] = None) -> AttrDict
             f"!= device count {nranks}"
         )
     # Sequence parallel rides the mp axis (Megatron-style); flag lives in Model.
+    # Unset, it is the program's: on where there is an mp axis to ride,
+    # unless the configured sequence length does not divide over it (the
+    # layout changes no sum, only where the rows between two
+    # tensor-parallel blocks live; PERF.md, PR 48).
     model = cfg.get("Model") or {}
+    if model.get("sequence_parallel") in (None, ""):
+        seq = ((cfg.get("Data") or {}).get("Train") or {}).get(
+            "dataset", {}).get("max_seq_len")
+        model["sequence_parallel"] = bool(
+            mp > 1 and not (seq and int(seq) % (mp * cp)))
     if model.get("sequence_parallel") and mp <= 1:
         logger.warning("sequence_parallel=True with mp_degree<=1 has no effect; disabling")
         model["sequence_parallel"] = False

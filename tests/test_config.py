@@ -213,3 +213,33 @@ def test_reference_yaml_schema_launches(tmp_path):
     assert cfg.Model.vocab_size == 50304
     assert cfg.Global.global_batch_size == 8
     assert cfg.Optimizer.lr.name == "CosineAnnealingWithWarmupDecay"
+
+
+@pytest.mark.parametrize("case,overrides,nranks,expected", [
+    ("unset-mp2", ["Distributed.mp_degree=2"], 4, True),
+    ("unset-mp1", [], 4, False),
+    ("unset-mp2-length-unknown",
+     ["Distributed.mp_degree=2", "Data.Train.dataset.max_seq_len="], 4, True),
+    ("unset-mp2-length-does-not-divide",
+     ["Distributed.mp_degree=2", "Data.Train.dataset.max_seq_len=1023"], 4,
+     False),
+    ("unset-mp2-cp2-length-divides-by-mp-alone",
+     ["Distributed.mp_degree=2", "Distributed.cp_degree=2",
+      "Data.Train.dataset.max_seq_len=1022"], 4, False),
+    ("false-mp2",
+     ["Distributed.mp_degree=2", "Model.sequence_parallel=False"], 4, False),
+    ("true-mp2",
+     ["Distributed.mp_degree=2", "Model.sequence_parallel=True"], 4, True),
+    ("true-mp1", ["Model.sequence_parallel=True"], 4, False),
+])
+def test_sequence_parallel_default(case, overrides, nranks, expected):
+    """``Model.sequence_parallel`` unset is the program's to decide: on
+    where there is an ``mp`` axis and the configured sequence length (if
+    any) divides over ``mp * cp``; an explicit value is honoured, but for
+    ``True`` at ``mp`` 1, which has nothing to ride. The published
+    configurations leave it unset and inherit this."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cfg = get_config(
+        os.path.join(repo, "configs/nlp/gpt/pretrain_gpt_1.3B_dp8.yaml"),
+        overrides=["Distributed.dp_degree="] + overrides, nranks=nranks)
+    assert cfg.Model.sequence_parallel is expected

@@ -3,6 +3,8 @@ dp/mp/fsdp sharding, grad accumulation matches the big-batch step,
 checkpoint save/load resumes exactly, and every topology of the N1C8 grid
 reads the one-device run's losses."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -367,9 +369,12 @@ def test_sentry_skip_resume_epoch_and_consumed_samples(tmp_path, eight_devices):
 # schedule and never the math
 TOPOLOGIES = {
     "DP8-MP1-PP1": {"Distributed.dp_degree": 8},
+    # mp > 1 runs sequence-sharded activations by default (utils/config.py);
+    # the explicit-False twin keeps Megatron's all-reduce form alive
     "DP4-MP2-PP1": {"Distributed.dp_degree": 4, "Distributed.mp_degree": 2},
-    "DP4-MP2-PP1-SP": {"Distributed.dp_degree": 4, "Distributed.mp_degree": 2,
-                       "Model.sequence_parallel": True},
+    "DP4-MP2-PP1-noSP": {"Distributed.dp_degree": 4,
+                         "Distributed.mp_degree": 2,
+                         "Model.sequence_parallel": False},
     "DP2-MP2-PP2": {"Distributed.dp_degree": 2, "Distributed.mp_degree": 2,
                     "Distributed.pp_degree": 2},
     "DP2-Sharding4": {"Distributed.dp_degree": 2,
@@ -416,3 +421,69 @@ def test_losses_agree_across_topologies(tmp_path, eight_devices,
     np.testing.assert_allclose(losses, one_device_losses,
                                rtol=TOPOLOGY_LOSS_RTOL)
     assert losses[-1] < losses[0], losses  # and it trains
+
+
+def _compiled_step_collectives(tmp_path, caplog, **over):
+    """The dp2 x mp2 step of the tiny GPT-1.3B (the benchmark cell's CPU
+    rehearsal size) after one step of ``fit``: the four counts of the gauge
+    ``fleetx_train_step_collectives``, the first TRAIN line, and what
+    ``Model.sequence_parallel`` resolved to."""
+    import json
+    import logging
+
+    from fleetx_tpu.obs.registry import get_registry
+    from fleetx_tpu.parallel.collective_matmul import COLLECTIVE_KINDS
+    from fleetx_tpu.utils.log import logger
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "perfbench/configs/gpt-1.3b.json")) as f:
+        tiny = json.load(f)["tiny"]["model"]
+    tmp_path.mkdir()
+    cfg = _cfg(tmp_path, nranks=4, **{
+        **{f"Model.{k}": v for k, v in tiny.items()},
+        "Distributed.dp_degree": 2, "Distributed.mp_degree": 2,
+        "Distributed.sharding.sharding_degree": 1,
+        "Global.local_batch_size": 4, "Global.micro_batch_size": 4,
+        "Engine.max_steps": 2, "Engine.logging_freq": 1, **over})
+    trainer = Trainer(cfg, build_module(cfg))
+    caplog.clear()
+    logger.propagate = True
+    try:
+        with caplog.at_level(logging.INFO, logger="fleetx_tpu"):
+            trainer.fit(_batches(cfg, 2))
+    finally:
+        logger.propagate = False
+    gauge = get_registry().gauge("fleetx_train_step_collectives",
+                                 labelnames=("kind",))
+    counts = {kind: int(gauge.labels(kind=kind).value)
+              for kind in COLLECTIVE_KINDS}
+    assert counts == trainer._step_collectives()
+    lines = [r.message for r in caplog.records if "ips_total" in r.message]
+    return counts, lines, bool(cfg.Model.sequence_parallel)
+
+
+def test_default_layout_trades_all_reduces_for_gathers(tmp_path, eight_devices,
+                                                       caplog):
+    """The compiled dp2 x mp2 step, by the gauge: with the default layout
+    (sequence-sharded activations between the tensor-parallel products) the
+    four products of a block carry their own collectives
+    (parallel/collective_matmul.py): collective-permutes, one a product in
+    the forward loop and their mirrors in the backward one, where
+    ``Model.sequence_parallel: False`` keeps the activation-sized
+    all-reduces (which the CPU partitioner counts the same either way: it
+    writes what is left of a reduce-scatter as an all-reduce and a slice;
+    on the v5e compiler they fall from 16 to 11, PERF.md, PR 48). The four
+    counts ride the first TRAIN line and no later one."""
+    default, lines, resolved = _compiled_step_collectives(
+        tmp_path / "default", caplog)
+    assert resolved
+    assert len(lines) == 2
+    for kind, n in default.items():
+        assert f", {kind}: {n}" in lines[0], lines[0]
+        assert kind not in lines[1], lines[1]
+    twin, _, resolved = _compiled_step_collectives(
+        tmp_path / "twin", caplog, **{"Model.sequence_parallel": False})
+    assert not resolved
+    assert default["all-reduce"] <= twin["all-reduce"] + 2, (default, twin)
+    assert (default["collective-permute"]
+            >= twin["collective-permute"] + 8), (default, twin)
