@@ -5,7 +5,9 @@ the block's input; and for a stack whose layers do not even hold the same
 parameters (``layer_types``: gated short-convolution layers beside attention
 layers, Mamba-1 selective-scan layers beside attention layers, dense
 feed-forward layers before expert layers or throughout, a sigmoid router
-with a selection bias). ``GPTConfig`` inherits them, ``check`` is the part of
+with a selection bias, window and full attention layers side by side with an
+output gate and norms after each part as well as before). ``GPTConfig``
+inherits them, ``check`` is the part of
 its ``__post_init__`` that refuses what nobody wrote, ``layer_class`` picks
 the layer that runs the first group (``models/gpt/hybrid.py``) and
 ``stack_of`` the stack that runs the second (``models/gpt/mixed_stack.py``).
@@ -29,7 +31,8 @@ __all__ = ["BlockLayoutFields", "LAYOUT_FIELDS", "LAYER_TYPES", "check",
 # a module attribute and has to hash)
 LAYOUT_FIELDS = ("rope_layout", "sliding_window_layout", "layer_types")
 # the operators a layer of ``layer_types`` can name, under the source's names
-LAYER_TYPES = ("conv", "mamba", "full_attention", "latent_attention")
+LAYER_TYPES = ("conv", "mamba", "full_attention", "sliding_attention",
+               "latent_attention")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,7 +68,11 @@ class BlockLayoutFields:
     # "mamba" the Mamba-1 selective scan (mixed_stack.MambaMixer): inner
     # width ``mamba_expand * hidden_size``, a state of ``mamba_d_state`` a
     # channel held in float32, a causal depthwise filter of
-    # ``mamba_d_conv`` taps, ``dt`` of rank ``mamba_dt_rank``
+    # ``mamba_d_conv`` taps, ``dt`` of rank ``mamba_dt_rank``;
+    # "sliding_attention" the same grouped attention through the window
+    # ``sliding_window`` (a stack may say which of its attention layers are
+    # window layers here, under the source's name, or in
+    # ``sliding_window_layout``; where it gives both they agree)
     layer_types: Optional[Tuple[str, ...]] = None
     conv_L_cache: int = 3
     mamba_expand: int = 2
@@ -90,6 +97,18 @@ class BlockLayoutFields:
     use_expert_bias: bool = False
     expert_bias_init_std: float = 0.0
     routed_scaling_factor: float = 1.0
+    # ---- what a ``layer_types`` stack of grouped attention layers may add
+    # to the block (mixed_stack.py, hybrid.py). ``attention_gate``
+    # "sigmoid": the heads' output times ``sigmoid(a W_g)`` before the
+    # out-projection, ``a`` the normed input the queries are made from and
+    # ``W_g`` a projection of its own, as wide as the queries'.
+    # ``sandwich_norm``: an RMSNorm with a weight of its own on each part's
+    # OUTPUT before it joins the residual stream (``x + norm(attn(norm(x)))``
+    # and the same around the feed-forward part). ``embedding_multiplier``:
+    # the embedding rows times this before the first layer
+    attention_gate: str = "none"
+    sandwich_norm: bool = False
+    embedding_multiplier: float = 1.0
     # serving, set by the engine beside ``decode_num_pages`` (which then
     # counts one full-attention layer's pages): the pages of one WINDOW
     # layer, whose lanes keep only the rows a live query can still see
@@ -194,7 +213,8 @@ class BlockLayoutFields:
         every attention layer and, in a gated short-convolution layer, the
         operator's last inputs ("conv"); once a lane and outside the pool,
         a selective-scan layer's state ("ssm")."""
-        kinds = set(self.layer_types or ("full_attention",))
+        kinds = {"full_attention" if t == "sliding_attention" else t
+                 for t in self.layer_types or ("full_attention",)}
         return tuple(name for name, kind in (
             ("kv", "full_attention"), ("conv", "conv"), ("ssm", "mamba"),
             ("latent", "latent_attention")) if kind in kinds)
@@ -225,7 +245,8 @@ class BlockLayoutFields:
                 return {}
             from fleetx_tpu.models.gpt.hybrid import chunk_key_rows
 
-            return chunk_key_rows(self, program_rows or rows, behind)
+            return {**chunk_key_rows(self, program_rows or rows, behind),
+                    **self.span_pairs(rows)}
         from fleetx_tpu.ops.pallas.mla_prefill import key_rows
 
         fields = {"latent_rows": behind + rows,
@@ -249,7 +270,12 @@ class BlockLayoutFields:
         """1 for every layer that attends through the window."""
         if not self.sliding_window:
             return (0,) * self.num_layers
-        return tuple(self.sliding_window_layout or (1,) * self.num_layers)
+        if self.sliding_window_layout:
+            return tuple(self.sliding_window_layout)
+        if "sliding_attention" in (self.layer_types or ()):
+            return tuple(int(t == "sliding_attention")
+                         for t in self.layer_types)
+        return (1,) * self.num_layers
 
     @property
     def rope_layers(self) -> Tuple[int, ...]:
@@ -257,6 +283,16 @@ class BlockLayoutFields:
         if self.position_embedding != "rope":
             return (0,) * self.num_layers
         return tuple(self.rope_layout or (1,) * self.num_layers)
+
+    def of_attention_layers(self, per_layer) -> tuple:
+        """``per_layer``'s entries of the layers that hold keys and values,
+        in order: an attention layer of a ``layer_types`` stack is counted
+        among the attention layers alone (mixed_stack.py hands it that
+        index, and the pool's pages follow it); every layer elsewhere."""
+        if not self.layer_types:
+            return tuple(per_layer)
+        return tuple(v for v, t in zip(per_layer, self.layer_types)
+                     if t.endswith("attention"))
 
 
 def check(cfg) -> None:
@@ -358,10 +394,19 @@ def _check_mixed(cfg) -> None:
             f"mamba_expand {cfg.mamba_expand}, mamba_d_state "
             f"{cfg.mamba_d_state}, mamba_d_conv {cfg.mamba_d_conv}: widths "
             "of at least 1 and a filter of at least 2 taps")
+    if cfg.attention_gate not in ("none", "sigmoid"):
+        raise ValueError(f"attention_gate={cfg.attention_gate!r}; choose "
+                         "none | sigmoid")
     if not cfg.layer_types:
         if cfg.num_dense_layers or cfg.dense_ffn_hidden_size:
             raise ValueError("num_dense_layers / dense_ffn_hidden_size "
                              "without layer_types")
+        if (cfg.attention_gate != "none" or cfg.sandwich_norm
+                or cfg.embedding_multiplier != 1.0):
+            raise NotImplementedError(
+                "attention_gate / sandwich_norm / embedding_multiplier "
+                "without layer_types: the stack of models/gpt/mixed_stack.py "
+                "is the one that runs them")
         return
     if bool(cfg.num_dense_layers) != bool(cfg.dense_ffn_hidden_size):
         raise ValueError("num_dense_layers and dense_ffn_hidden_size (the "
@@ -384,18 +429,33 @@ def _check_mixed(cfg) -> None:
         if not cfg.mamba_dt_rank:
             raise ValueError("layer_types with mamba layers needs "
                              "mamba_dt_rank (the source states it)")
-    for field, why in (("sliding_window", "window layers"),
-                       ("use_recompute", "training this stack (ROADMAP R5)")):
-        if getattr(cfg, field):
-            raise NotImplementedError(f"{field} with layer_types: {why} in "
-                                      "a stack of mixed operators")
-    if cfg.rope_layout and any(cfg.rope_layout):
-        # all zeros is the one layout taken: NO layer rotates (a model
-        # without positions of any kind, under position_embedding: rope,
-        # which also keeps the learned position table out of the tree)
+    if cfg.use_recompute:
         raise NotImplementedError(
-            "rope_layout with layer_types: rotating some layers and not "
-            "others in a stack of mixed operators (all 0: none rotates)")
+            "use_recompute with layer_types: training this stack (ROADMAP "
+            "R5) in a stack of mixed operators")
+    named = tuple(int(t == "sliding_attention") for t in cfg.layer_types)
+    if any(named) and (not cfg.sliding_window or cfg.window_layers != named):
+        raise ValueError(
+            "layer_types with sliding_attention layers needs sliding_window, "
+            "and a sliding_window_layout that marks the same layers")
+    attention_only = not set(cfg.layer_types) - {"full_attention",
+                                                 "sliding_attention"}
+    mixed_rope = cfg.rope_layout and 0 < sum(cfg.rope_layout) < cfg.num_layers
+    if not attention_only:
+        # beside a recurrent operator or latent attention only what a test
+        # covers: one class of page; every layer rotating or (rope_layout
+        # all 0) none, which also keeps the position table out of the tree
+        added = [n for n, on in (
+            ("sliding_window", cfg.sliding_window),
+            ("rope_layout (rotating some layers and not others)", mixed_rope),
+            ("attention_gate", cfg.attention_gate != "none"),
+            ("sandwich_norm", cfg.sandwich_norm),
+            ("embedding_multiplier", cfg.embedding_multiplier != 1.0)) if on]
+        if added:
+            raise NotImplementedError(
+                f"{added} in a layer_types stack with conv, mamba or "
+                "latent_attention layers: no test covers it (a stack of "
+                "full_attention | sliding_attention layers takes them)")
     if cfg.router_input != "mlp_norm":
         raise NotImplementedError("router_input with layer_types")
 

@@ -82,10 +82,10 @@ def _pages_of(cfg: GPTConfig):
             "a paged decode cache over window layers needs "
             "decode_window_pages beside decode_num_pages (the serving "
             "engine sets both)")
-    if cfg.layer_types:  # only the attention layers hold keys and values,
-        # and are counted among themselves (models/gpt/mixed_stack.py)
-        return [full] * sum(t.endswith("attention") for t in cfg.layer_types)
-    return [window if w else full for w in cfg.window_layers]
+    # (of a ``layer_types`` stack only the attention layers hold keys and
+    # values, and are counted among themselves: models/gpt/mixed_stack.py)
+    return [window if w else full
+            for w in cfg.of_attention_layers(cfg.window_layers)]
 
 
 def layer_bases(cfg: GPTConfig) -> np.ndarray:
@@ -168,13 +168,14 @@ def chunk_key_rows(cfg: GPTConfig, s: int, start: int) -> dict:
     """Span fields of a chunk program of ``s`` rows at ``start``: the key
     rows the live steps of the kernel ``fleetx_prefill_gqa`` cover in ONE
     full and ONE window layer and key head (whole blocks: work and padding
-    together), for the kinds of layer the configuration has; none for a
-    shape the kernel does not take."""
+    together), for the kinds of layer the configuration has, and the rows
+    of queries each of those steps takes; none for a shape the kernel does
+    not take."""
     ps = cfg.decode_page_size
     if not prefill_gqa.takes(1, s, cfg.head_dim, ps):
         return {}
     n = -(-(cfg.decode_cache_len or cfg.max_position_embeddings) // ps)
-    fields = {}
+    fields = {"attn_query_rows": s}  # the program's rows, padding included
     if 0 in cfg.window_layers:
         fields["attn_full_key_rows"] = prefill_gqa.key_rows(
             start, s, 0, None, prefill_gqa.padded_rows(n * ps))
@@ -186,10 +187,21 @@ def chunk_key_rows(cfg: GPTConfig, s: int, start: int) -> dict:
     return fields
 
 
+def _gate_reads(x):
+    """What the output gate's projection reads: the layer's normed input,
+    which the queries are made from (a function of its own:
+    ``perfbench/probe_trinity.py`` plants a fault here)."""
+    return x
+
+
 class HybridSelfAttention(SelfAttention):
     """``SelfAttention`` with ``kv_heads`` key/value heads of ``head_dim``
     and a kind a layer (module docstring). The out-projection, the
-    flash-decode dispatch check and the kernels are the base's."""
+    flash-decode dispatch check and the kernels are the base's. Under
+    ``attention_gate: sigmoid`` the heads' output is multiplied by
+    ``sigmoid(x W_g)`` before the out-projection (``gate_proj``, as wide as
+    the queries', reading what they read; device scope ``attn_gate``).
+    ``layer_index`` counts the layers that hold keys and values."""
 
     @nn.compact
     def __call__(self, x, attn_mask=None, *, deterministic=True, decode=False,
@@ -199,18 +211,24 @@ class HybridSelfAttention(SelfAttention):
         the cache itself between them (``models/gpt/mixed_stack.py``):
         "project" returns ``(q, k, v)`` as the cache takes them, "attend"
         takes that ``q`` as ``x`` and attends through the cache as it
-        stands."""
+        stands (under an output gate ``q`` carries the gate's rows behind
+        its heads from the one phase to the other)."""
         cfg = self.cfg
         nh, kvh, hd = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
-        if phase == "attend":
-            windowed = jnp.asarray(cfg.window_layers, bool)[layer_index]
-            return self._out_proj(checkpoint_name(self._paged_attention(
-                x, None, None, cache_positions, block_tables, layer_index,
-                windowed, deterministic), "core_attn_out"))
         if layer_index is None:
             raise NotImplementedError(
                 "layers with a kind run under the layer scan, which hands "
                 "each its index (scan_layers)")
+        gated = cfg.attention_gate == "sigmoid"
+        windowed = jnp.asarray(cfg.of_attention_layers(cfg.window_layers),
+                               bool)[layer_index]
+        if phase == "attend":
+            x, gate = jnp.split(x, 2, axis=-2) if gated else (x, None)
+            return self._out_proj(self._gate(checkpoint_name(
+                self._paged_attention(
+                    x, None, None, cache_positions, block_tables,
+                    layer_index, windowed, deterministic),
+                "core_attn_out"), gate))
         if not deterministic and cfg.attention_probs_dropout_prob > 0.0:
             raise NotImplementedError(
                 "attention dropout over grouped heads or a window "
@@ -233,15 +251,19 @@ class HybridSelfAttention(SelfAttention):
             q, k = (nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype,
                                param_dtype=jnp.float32, name=name)(t)
                     for name, t in (("q_norm", q), ("k_norm", k)))
-        windowed = jnp.asarray(cfg.window_layers, bool)[layer_index]
+        gate = None
+        if gated:
+            with jax.named_scope("attn_gate"):
+                gate = proj((nh, hd), name="gate_proj")(_gate_reads(x))
         if rope is not None and any(cfg.rope_layers):
-            rotates = jnp.asarray(cfg.rope_layers, bool)[layer_index]
+            rotates = jnp.asarray(cfg.of_attention_layers(cfg.rope_layers),
+                                  bool)[layer_index]
             q = jnp.where(rotates, apply_rope(q, rope), q)
             k = jnp.where(rotates, apply_rope(k, rope), k)
         b, s = q.shape[:2]
         k, v = k.reshape(b, s, kvh * hd), v.reshape(b, s, kvh * hd)
         if phase == "project":
-            return q, k, v
+            return (jnp.concatenate([q, gate], -2) if gated else q), k, v
 
         out = None
         if decode:
@@ -263,7 +285,17 @@ class HybridSelfAttention(SelfAttention):
                 allowed = allowed & attn_mask.astype(bool)
             out = grouped_attention(q, k, v, allowed[None, None])
         out = checkpoint_name(out, "core_attn_out")
-        return self._out_proj(out)
+        return self._out_proj(self._gate(out, gate))
+
+    def _gate(self, out, gate):
+        """The heads' output ``[b, s, heads, d]`` under the output gate:
+        times ``sigmoid(gate)``, the sigmoid in float32 (as it is without
+        one)."""
+        if gate is None:
+            return out
+        with jax.named_scope("attn_gate"):
+            return (out * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(
+                out.dtype)
 
     def _visible(self, q_pos, k_pos, windowed):
         """Whether the query at ``q_pos`` sees the key at ``k_pos``."""

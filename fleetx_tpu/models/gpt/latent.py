@@ -582,10 +582,6 @@ class LatentStack(MixedStack):
         rope = (jnp.ones((1, 1, cfg.qk_rope_head_dim // 2), jnp.float32),) * 2
         kinds["attention"] = (LatentAttention(cfg, parent=None), (x,),
                               {"layer_index": jnp.int32(0), "rope": rope})
-        if cfg.expert_share:
-            from fleetx_tpu.parallel.moe_share import SharedMoEMLP
-
-            kinds["experts"] = (SharedMoEMLP(cfg, parent=None), (x,), {})
         return kinds
 
     def _cache(self, decode: bool, plan: dict, lanes: int):

@@ -69,6 +69,19 @@ are no tokens (a padded bucket's tail, a lane that is not decoding) change
 nothing: the call is handed which rows are tokens, as its ``attn_mask``
 ``[batch, rows]``.
 
+**Attention layers of two kinds.** Whether an attention layer attends
+through the window (``sliding_attention``) or over its whole row, and whether
+it rotates its queries and keys (``rope_layout``), is data of the one body,
+as in ``hybrid.py``: the layer looks both up by its place among the
+attention layers. The pool then holds two classes of page over the attention
+layers (``hybrid.layer_bases``), the call is handed both block tables ``[2,
+lanes, pages]`` and the body picks the one of the layer's kind before it
+writes and attends. Such a stack (attention layers alone) may also gate the
+heads' output (``attention_gate``, in ``HybridSelfAttention``), norm each
+part's output before it joins the stream (``sandwich_norm``: a third stack
+``post_norm`` beside every kind's ``norm`` and ``op``; device scope
+``post_norm``) and scale the embedding rows (``embedding_multiplier``).
+
 Forward only: training this stack is ROADMAP R5.
 """
 
@@ -345,7 +358,10 @@ class MixedStack(nn.Module):
         x = jnp.zeros((1, 1, cfg.hidden_size), cfg.dtype)
         dense_cfg = dataclasses.replace(
             cfg, ffn_hidden_size=cfg.dense_ffn_hidden_size or cfg.ffn_size)
-        from fleetx_tpu.parallel.moe import DroplessMoEMLP
+        if cfg.expert_share:  # a held share, beside shared experts
+            from fleetx_tpu.parallel.moe_share import SharedMoEMLP as Experts
+        else:
+            from fleetx_tpu.parallel.moe import DroplessMoEMLP as Experts
 
         rope = (jnp.ones((1, 1, cfg.head_dim // 2), jnp.float32),) * 2
         return {
@@ -356,7 +372,7 @@ class MixedStack(nn.Module):
             "attention": (HybridSelfAttention(cfg, parent=None), (x,),
                           {"layer_index": jnp.int32(0), "rope": rope}),
             "dense": (MLP(dense_cfg, parent=None), (x,), {}),
-            "experts": (DroplessMoEMLP(cfg, parent=None), (x,), {}),
+            "experts": (Experts(cfg, parent=None), (x,), {}),
         }
 
     @nn.compact
@@ -372,7 +388,14 @@ class MixedStack(nn.Module):
             params[name] = self.param(
                 name, lambda rng, m=module, n=count, a=args, k=kwargs: {
                     "norm": _stacked(_norm(cfg), n, rng, a[0]),
-                    "op": _stacked(m, n, rng, *a, **k)})
+                    "op": _stacked(m, n, rng, *a, **k),
+                    **({"post_norm": _stacked(_norm(cfg), n, rng, a[0])}
+                       if cfg.sandwich_norm else {})})
+        if cfg.embedding_multiplier != 1.0:
+            with jax.named_scope("embed"):
+                # (in float32: bfloat16 would round the multiplier itself)
+                x = (x.astype(jnp.float32)
+                     * cfg.embedding_multiplier).astype(x.dtype)
         cache = self._cache(decode, plan, lanes=x.shape[0])
         if decode and cache is not None and (cache_positions is None
                                              or block_tables is None):
@@ -424,10 +447,10 @@ class MixedStack(nn.Module):
             held["ssm_conv"] = self.variable(
                 "cache", "ssm_conv", jnp.zeros,
                 (n, lanes, (cfg.mamba_d_conv - 1) * d), cfg.dtype)
-        else:
+        elif counts["conv"]:
             held["conv_state"] = self.variable(
                 "cache", "conv_state", jnp.zeros,
-                (max(counts["conv"], 1) * cfg.decode_num_pages, rows,
+                (counts["conv"] * cfg.decode_num_pages, rows,
                  cfg.hidden_size), cfg.dtype)
         held.update({
             "moe_stats": self.variable(
@@ -479,6 +502,20 @@ class MixedStack(nn.Module):
         def normed(kind, index, value):
             return norm.apply({"params": _at(params[kind]["norm"], index)},
                               value)
+
+        def joins(kind, index, value):
+            """What a part's output ``value`` adds to the stream: itself,
+            or under ``sandwich_norm`` its norm by the kind's own weight."""
+            if not cfg.sandwich_norm:
+                return value
+            with jax.named_scope("post_norm"):
+                return norm.apply(
+                    {"params": _at(params[kind]["post_norm"], index)}, value)
+
+        # two classes of page: the call's tables are [class, lanes, pages],
+        # 0 full, 1 window, and a layer takes its kind's
+        windowed = jnp.asarray(cfg.of_attention_layers(cfg.window_layers),
+                               bool)
 
         def pick(flag, counts, yes, no, *args):
             """``yes`` or ``no`` by the layer's kind; a conditional only
@@ -637,6 +674,8 @@ class MixedStack(nn.Module):
             else:
                 mixed, qkv = jax.lax.cond(mixes, project_step, recur_step)
             pools = dict(pools)
+            own = (jnp.where(windowed[index], tables[1], tables[0])
+                   if tables.ndim == 3 else tables)
             if recurrent == "conv":
                 with jax.named_scope("cache_write"), \
                         jax.named_scope("conv_state"):
@@ -649,22 +688,22 @@ class MixedStack(nn.Module):
             if counts["attention"]:
                 pools.update(zip(leaves, write_rows(
                     cfg, pools["cached_key"], pools["cached_value"],
-                    tables + jnp.asarray(layer_bases(cfg))[index], wpos,
+                    own + jnp.asarray(layer_bases(cfg))[index], wpos,
                     qkv["k"], qkv["v"], keep=mixes,
                     more=[(pools[n], qkv["index"]) for n in leaves[2:]])))
 
             def attend_step():
                 out, mut = attention(
                     qkv["q"], index, decode=True, cache_positions=wpos,
-                    block_tables=tables, phase="attend",
+                    block_tables=own, phase="attend",
                     mutable=["cache"] + probing,
                     variables={"cache": {n: pools[n] for n in leaves}})
-                return out, first_sown(mut)
+                return joins("attention", index, out), first_sown(mut)
 
             def finish_step():
-                if recurrent == "conv":
-                    return mixed["y"], {}
-                return mamba_finish(mixed, index), {}
+                y = (mixed["y"] if recurrent == "conv"
+                     else mamba_finish(mixed, index))
+                return joins(recurrent, index, y), {}
 
             return (*pick(mixes, both, attend_step, finish_step), pools)
 
@@ -682,9 +721,12 @@ class MixedStack(nn.Module):
                 out = attention(normed("attention", index, value), index,
                                 key_mask, rope=rope,
                                 mutable=probing or False)
-                return (out[0], first_sown(out[1])) if probed else (out, {})
+                out, sown = ((out[0], first_sown(out[1])) if probed
+                             else (out, {}))
+                return joins("attention", index, out), sown
 
-            return (*pick(mixes, both, attend, lambda: (recur(), {})), pools)
+            return (*pick(mixes, both, attend, lambda: (
+                joins(recurrent, index, recur()), {})), pools)
 
         def dense(value, index, stats):
             y = kinds["dense"][0].apply(
@@ -692,7 +734,7 @@ class MixedStack(nn.Module):
                 normed("dense", index, value))
             # where an expert layer gives its routing a dense layer gives
             # zeros of the same shapes: the two are branches of one conditional
-            return y, stats, (zeros_like_of(
+            return joins("dense", index, y), stats, (zeros_like_of(
                 lambda: experts(value, index, stats)[2])
                 if counts["experts"] else {})
 
@@ -714,7 +756,7 @@ class MixedStack(nn.Module):
                 stats = mut["cache"]["moe_stats"]
             sown = ({k: v[0] for k, v in mut["routing"].items()}
                     if probed else {})
-            return y, stats, sown
+            return joins("experts", index, y), stats, sown
 
         def body(carry, layer):
             value, pools = carry[0], dict(carry[1])

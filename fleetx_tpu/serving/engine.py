@@ -2687,8 +2687,9 @@ class ServingEngine:
         rows its kernel calls read for the lanes it is dispatched for, in
         ONE full layer (every row up to the token being written) and in
         ONE window layer (the window's rows of those); over two kinds of
-        state, the rows of one attention layer. Empty with one class and
-        one kind."""
+        state, the rows of one attention layer; over an expert layer that
+        holds a share, the pairs its routers choose. Empty with one class
+        and one kind."""
         rows = self.cache_manager.lengths[list(lanes)] + 1
         if self._state_rows:  # what ONE of its attention layers reads, and
             # the lanes whose lane-resident state the tick advances
@@ -2704,8 +2705,10 @@ class ServingEngine:
             return fields
         if not self.window_pages:
             return {}
+        cfg = self.model.cfg
         return {"full_rows": int(rows.sum()), "window_rows": int(
-            np.minimum(rows, self.model.cfg.sliding_window).sum())}
+            np.minimum(rows, cfg.sliding_window).sum()),
+            **cfg.span_pairs(self.slots)}
 
     def _sync_cause(self) -> Optional[str]:
         """Why this engine reads every tick before it dispatches the next,

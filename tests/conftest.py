@@ -84,6 +84,20 @@ _CANNOT_APPLY = {
         "perfbench/flops.py gpt_param_count counts the GPT-2 block only "
         "(a benchmark PR's to extend); tests/perfbench/test_perfbench_dsv32.py"
         " holds this configuration's count to the program's own model",
+    "tests/perfbench/test_perfbench_flops.py::"
+    "test_param_count_matches_the_programs_model"
+    "[perfbench/configs/trinity-large-ep8-l5.json]":
+        "perfbench/flops.py gpt_param_count counts the GPT-2 block only "
+        "(a benchmark PR's to extend); tests/perfbench/"
+        "test_perfbench_trinity.py holds this configuration's count to the "
+        "program's own model",
+    "tests/perfbench/test_perfbench_traffic.py::"
+    "test_order_seed_pins_tenants_and_lengths_and_leaves_the_seed_the_tokens"
+    "[mixed-longshort]":
+        "its last line knows ONE closed-loop file with `order_seed`, "
+        "longdoc-gen (a benchmark PR's to extend); tests/perfbench/"
+        "test_perfbench_trinity.py holds this file's pinned order and the "
+        "seed's tokens",
 }
 # the same for every case of one test and cell: (node id's start, reason)
 _CANNOT_APPLY_FROM = (
@@ -108,6 +122,13 @@ _CANNOT_APPLY_FROM = (
      "PR 37 alone (a benchmark PR's to extend); tests/perfbench/"
      "test_perfbench_dsv32.py makes this cell's traced rehearsal and holds "
      "every entry that lists it"),
+    ("tests/perfbench/test_perfbench_rehearsal.py::"
+     "test_traced_rehearsal_reports_a_shared_entry_in_each_cell_it_lists"
+     "[trinity-l5-serve-mixed-longshort-",
+     "test_perfbench_rehearsal.py's TINY_REPORTS has a row for the cells of "
+     "PR 37 alone (a benchmark PR's to extend); tests/perfbench/"
+     "test_perfbench_trinity.py makes this cell's traced rehearsal and holds "
+     "every entry that lists it"),
 )
 
 
@@ -119,13 +140,17 @@ _WRITTEN_BEFORE = {
     "tests/perfbench/test_perfbench_lfm2.py::"
     "test_every_width_is_the_published_one_and_only_the_depth_is_cut":
         ("jamba2-3b-serve-chat-peak", "axk1-l6-serve-docqa-latent",
-         "dsv32-l5-serve-longqa-sparse"),
+         "dsv32-l5-serve-longqa-sparse", "trinity-l5-serve-mixed-longshort"),
     "tests/perfbench/test_perfbench_jamba2.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
-        ("axk1-l6-serve-docqa-latent", "dsv32-l5-serve-longqa-sparse"),
+        ("axk1-l6-serve-docqa-latent", "dsv32-l5-serve-longqa-sparse",
+         "trinity-l5-serve-mixed-longshort"),
     "tests/perfbench/test_perfbench_axk1.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
-        ("dsv32-l5-serve-longqa-sparse",),
+        ("dsv32-l5-serve-longqa-sparse", "trinity-l5-serve-mixed-longshort"),
+    "tests/perfbench/test_perfbench_dsv32.py::"
+    "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
+        ("trinity-l5-serve-mixed-longshort",),
 }
 
 

@@ -35,6 +35,7 @@ _EXPLICIT = {
     "serve_jamba2_3b.yaml": 1,        # the whole model on one chip
     "serve_axk1_ep16_l6.yaml": 1,     # one chip's share of sixteen
     "serve_dsv32_ep16_l5.yaml": 1,    # one chip's share of sixteen
+    "serve_trinity_large_ep8_l5.yaml": 1,  # one chip's share of eight
 }
 
 # _base_ fragments: not launchable topologies on their own

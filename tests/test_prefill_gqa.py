@@ -176,16 +176,21 @@ def test_a_chunks_span_fields_count_the_kernels_key_rows(rows, behind, full,
                                                          window):
     """Whole 1,024-row blocks from the one that holds the first query's
     oldest visible key (counted from the first row gathered) to the one
-    that holds the program's last row."""
-    fields = CFG.spans(rows, behind, -(-rows // 256) * 256)
+    that holds the program's last row; and the program's rows of queries,
+    padding included (``perfbench/flops_gqa_prefill.py`` counts a step's
+    work from them)."""
+    program = -(-rows // 256) * 256
+    fields = CFG.spans(rows, behind, program)
     assert fields == {"attn_full_key_rows": full,
-                      "attn_window_key_rows": window}
+                      "attn_window_key_rows": window,
+                      "attn_query_rows": program}
     small = dataclasses.replace(CFG, head_size=64)
     assert small.spans(rows, behind) == {}
     assert GPTConfig().spans(rows, behind) == {}
     one_kind = dataclasses.replace(CFG, sliding_window=None,
                                    sliding_window_layout=None)
     assert one_kind.spans(rows, behind, 512) == {
+        "attn_query_rows": 512,
         "attn_full_key_rows": prefill_gqa.key_rows(behind, 512, 0, None, LANE)}
 
 
