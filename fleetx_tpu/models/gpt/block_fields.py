@@ -230,6 +230,34 @@ class BlockLayoutFields:
         return {"pairs": rows * self.top_k
                 * (self.num_layers - self.num_dense_layers)}
 
+    def decode_kernel_steps(self, lanes: int, head_shards: int = 1) -> int:
+        """Span field ``kernel_steps`` of a decode tick of ``lanes`` lanes:
+        the grid steps of the kernel ``fleetx_decode_paged`` over ALL the
+        attention layers' calls, each layer's by the function the kernel
+        sizes its own grid with (``decode_attention.paged_grid``: a full
+        layer's call walks the table, a window layer's its window), over a
+        pool whose row holds this device's ``1 / head_shards`` of the key
+        heads. 0 over latent attention, another kernel's."""
+        if self.latent:
+            return 0
+        import jax
+        import jax.numpy as jnp
+
+        from fleetx_tpu.ops.pallas.decode_attention import paged_grid
+
+        ps = self.decode_page_size
+        quant = self.decode_kv_dtype == "int8"
+        heads = self.kv_heads // head_shards
+        pool = jax.ShapeDtypeStruct(
+            (1, ps, heads * self.head_dim), jnp.int8 if quant else self.dtype)
+        scale = jax.ShapeDtypeStruct((1, ps, heads), jnp.float32)
+        pools = [pool] * 2 + [scale] * (2 * quant)
+        kinds = self.of_attention_layers(self.window_layers)
+        return lanes * sum(
+            paged_grid(pools, self.decode_cache_len // ps,
+                       max_live=self.sliding_window if windowed else None)[1]
+            for windowed in kinds)
+
     def spans(self, rows: int, behind: int, program_rows: int = 0) -> dict:
         """Span fields of a prefill call of ``rows`` tokens (a program of
         ``program_rows`` rows, padding included) behind ``behind`` cached

@@ -365,13 +365,18 @@ class HybridSelfAttention(SelfAttention):
                                             tile_len=ps):
             end = paged_write.decode_end(own, wpos, ps)
 
-            def kernel(starts):
+            # the kernel's grid follows what a lane can have live
+            # (``decode_attention.paged_grid``): a full layer's call walks
+            # the table; a window layer's, told the window, walks from the
+            # block its window starts in the steps ``window`` rows can touch
+            def kernel(starts, max_live):
                 return flash_decode_paged_attention(
-                    q, k_pool, v_pool, tables=tables, end=end, starts=starts)
+                    q, k_pool, v_pool, tables=tables, end=end, starts=starts,
+                    max_live=max_live)
 
             return self._by_kind(
-                windowed, lambda: kernel(None),
-                lambda: kernel(jnp.maximum(end - window, 0)))
+                windowed, lambda: kernel(None, None),
+                lambda: kernel(jnp.maximum(end - window, 0), window))
 
         # a prefill chunk (and every call the decode kernel does not take):
         # gather the rows the chunk's queries can see, the whole row for a

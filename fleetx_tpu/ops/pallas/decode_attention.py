@@ -98,14 +98,27 @@ pages and each batch row addresses its logical window through a block
 table of page indices (serving/cache_manager.py), which rides scalar
 prefetch next to ``starts``/``ends``. The body is THE SAME online-softmax
 walk (:func:`_decode_kernel`); what differs is how a step's rows reach
-VMEM, and how many they are. A grid step covers **P consecutive logical
-pages** of a row, grid ``(b, ceil(pages of a row / P))``, where P follows
-from the shapes the call sees (:func:`_pages_per_step`): ``block_k`` rows
-(``FLEETX_DECODE_BLOCK_K``, 256: the contiguous kernel's tile) over the
-page size, capped at the table's width: 16 pages of 16 rows a step. The
-MXU pays a matmul by the weight tiles it loads, not by the rows of the
-tile, so a step of one 16-row page cost what a step of 256 rows costs
-(PERF.md, PR 30).
+VMEM, and how many they are. The grid follows what a lane can have live
+(:func:`paged_grid`), in both its axes:
+- **rows a step, from the row's width.** A grid step covers **P consecutive
+  logical pages** of a row, one compute tile: ``block_k`` rows
+  (``FLEETX_DECODE_BLOCK_K``, 256: the contiguous kernel's tile) where a
+  row is 2,048 bfloat16 lanes or more, and as many more rows of a narrower
+  row as hold the same 1 MB (:func:`_pages_per_step`), capped at the
+  table's width: 16 pages of 16 rows where a row is 16 heads of 128, 32
+  where it is 8, 64 where it is 4 heads of 128 or 8 of 64. The MXU pays a
+  matmul by the weight tiles it loads, not by the rows of the tile, so a
+  step of one 16-row page cost what a step of 256 rows costs (PERF.md,
+  PR 30); and a step pays its launch, its products' set-up and its copies'
+  starts beside its bytes, so a narrow row's step of 256 rows ran at 40% of
+  its bytes' pace where the full row's runs at 70-85% (PERF.md, PR 50).
+- **steps a lane, from a bound on its window.** Step jm of a lane is
+  logical block ``starts // rows + jm``: a lane's steps begin in the block
+  its window does, and the grid is ``(b, steps)`` with ``steps`` what
+  ``max_live`` rows can touch (one more than the blocks they fill), never
+  more than ``ceil(pages of a row / P)``, which is the grid of a call that
+  gives no bound. A window layer (models/gpt/hybrid.py) hands its window
+  over, so its grid is as long as the window and not as the table.
 - P > 1 (:func:`_paged_block_call`): the pools stay in HBM and a live
   step's LIVE pages are copied, one async copy a page through the row's
   table, side by side into a double-buffered ``[2, P * page_size, h*d]``
@@ -114,9 +127,10 @@ tile, so a step of one 16-row page cost what a step of 256 rows costs
   block outside ``[first, last]`` are not copied and their rows are masked
   by position, so a call reads the rows' live pages, rounded up to pages
   and never to blocks; a step wholly outside the window does nothing.
-- P = 1 (a page is ``block_k`` rows already, or a page is below the pool
-  dtype's packed tile, as a 16-row int8 page is half a (32, 128) tile and
-  P of them would need a relayout to lie side by side): a step is a page,
+- P = 1 (a page is over half of ``block_k`` rows already, or a page is
+  below the pool dtype's packed tile, as a 16-row int8 page is half a
+  (32, 128) tile and P of them would need a relayout to lie side by side):
+  a step is a page, every page of the table a step whatever the bound,
   streamed by the BlockSpec pipeline like the contiguous kernel's blocks
   with ``major == page_size``; the index map gathers physical page
   ``table[b, jm]``, and dead steps clamp into the live ``[first, last]``
@@ -154,6 +168,7 @@ __all__ = [
     "decode_mesh_shardable",
     "fit_decode_blocks",
     "paged_gather_kv",
+    "paged_grid",
 ]
 
 # Stable kernel names: they appear in the compiled HLO's custom-call
@@ -257,7 +272,7 @@ def _decode_specs(mesh, batch: Optional[int]):
 
 
 def _sharded_decode(mesh, starts_b, ends_b, operands, tables=None,
-                    block_k=None, block_major=None):
+                    block_k=None, block_major=None, max_live=None):
     """shard_map both decode kernels over (heads -> mp; contiguous
     batch -> dp/fsdp when it divides). Without this, GSPMD treats the
     Pallas call as an opaque custom call and REPLICATES the sharded
@@ -305,7 +320,7 @@ def _sharded_decode(mesh, starts_b, ends_b, operands, tables=None,
         ks, vs = scales if scales else (None, None)
         return flash_decode_paged_attention(
             q, k, v, tables=tables, end=ends, starts=starts,
-            block_k=block_k, k_scale=ks, v_scale=vs)
+            block_k=block_k, k_scale=ks, v_scale=vs, max_live=max_live)
 
     in_specs = ((P(None), P(None), P(None, None), q_spec)
                 + (kv_spec,) * (len(operands) - 1))
@@ -318,9 +333,11 @@ def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr, *, block_k: int, major: int,
                    scale: float, heads: int, ks_ref=None, vs_ref=None,
                    gather=None, group: int = 1):
-    """Grid step (batch bi, K/V major block jm): online-softmax update of
-    ALL heads' single query row against the live tiles of the resident
-    major block.
+    """Grid step (batch bi, step jm): online-softmax update of ALL heads'
+    single query row against the live tiles of the resident major block.
+    Step jm is logical block ``blk`` = jm of the row, or, under ``gather``,
+    block ``start // major + jm``: counted from the block the row's window
+    starts in, so that a row's live steps are its first ones.
 
     Refs are lane-dense (module docstring "Layout"): ``q_ref``/``o_ref``
     [1, h*d], ``k_ref``/``v_ref`` [major, h*d]. The query becomes a
@@ -345,9 +362,9 @@ def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
     transposed scale tile (module docstring "Int8 KV").
 
     ``gather`` (the paged kernel at several pages a step,
-    :func:`_paged_block_call`) is called at the top of a live step and
-    returns the cache refs in place of the four above: the step's pages
-    copied side by side into VMEM.
+    :func:`_paged_block_call`) is called with ``blk`` at the top of a live
+    step and returns the cache refs in place of the four above: the block's
+    pages copied side by side into VMEM.
 
     ``group`` > 1 (module docstring "Grouped-query heads"): the cache holds
     ``heads // group`` heads, ``q_ref`` is the block-diagonal ``[rows,
@@ -361,8 +378,12 @@ def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
     # "Empty windows") is the lane's step 0 and nothing else: the state is
     # zeroed and finalized there, so its output block is exact zeros
     empty = end <= start
-    first_jm = jnp.where(empty, 0, start // major)
-    last_jm = jnp.where(empty, 0, (end - 1) // major)
+    base = 0 if gather is None else start // major
+    blk = base + jm
+    first = jnp.where(empty, base, start // major)
+    # never past the grid's last step, whatever ``end`` says
+    last = jnp.where(empty, base, jnp.minimum(
+        (end - 1) // major, base + pl.num_programs(1) - 1))
     tiles = major // block_k
     rows, hd = acc_scr.shape
     d = hd // (heads // group)
@@ -372,16 +393,17 @@ def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
     diag = ((row if group == 1 else row // group)
             == jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 1) // d)
 
-    @pl.when(jm == first_jm)
+    @pl.when(blk == first)
     def _init():
         m_scr[:] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    @pl.when(~empty & (jm >= first_jm) & (jm <= last_jm))
+    @pl.when(~empty & (blk >= first) & (blk <= last))
     def _step():
         k_rows, v_rows, ks_rows, vs_rows = (
-            (k_ref, v_ref, ks_ref, vs_ref) if gather is None else gather())
+            (k_ref, v_ref, ks_ref, vs_ref) if gather is None
+            else gather(blk))
         mm_dt = _mm_dtype(q_ref.dtype)
         # select in f32: the iota mask has the 32-bit tile layout, which
         # Mosaic will not relayout onto a packed bf16 operand
@@ -389,9 +411,9 @@ def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
                           ).astype(mm_dt) if group == 1
                 else q_ref[:].astype(mm_dt))
         # local tile range intersecting the valid window [start, end)
-        t_lo = jnp.clip((start - jm * major) // block_k, 0, tiles)
+        t_lo = jnp.clip((start - blk * major) // block_k, 0, tiles)
         t_hi = jnp.clip(
-            (end - jm * major + block_k - 1) // block_k, 0, tiles
+            (end - blk * major + block_k - 1) // block_k, 0, tiles
         )
 
         def body(t, carry):
@@ -402,7 +424,7 @@ def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
             s = _dot(q_bd, k_blk, _NT) * scale  # [rows, block_k]
             if ks_rows is not None:
                 s = s * _scale_rows(ks_rows[pl.ds(row0, block_k), :], rows)
-            k_row = (jm * major + t * block_k
+            k_row = (blk * major + t * block_k
                      + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1))
             s = jnp.where((k_row >= start) & (k_row < end), s, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -423,7 +445,7 @@ def _decode_kernel(starts_ref, ends_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[:] = l
         acc_scr[:] = acc
 
-    @pl.when(jm == last_jm)
+    @pl.when(blk == last)
     def _finalize():
         l = l_scr[:]
         # a live window holds the query's own position, so l > 0; an empty
@@ -680,25 +702,74 @@ def _packed_rows(dtype) -> int:
     return 32 // jnp.dtype(dtype).itemsize
 
 
+# The row the paged kernel's step was sized at: 16 heads of 128 in bfloat16
+# (PERF.md, PR 30). A step of ``block_k`` such rows holds 1 MB of one cache
+# operand in one half of its buffer, and a narrower row's step holds as many
+# more rows as make the same bytes.
+_FULL_ROW_BYTES = 2048 * 2
+# pages whose copies are started unrolled (8: 1.7% slower, 32: level)
+_START_UNROLL = 16
+
+
 def _pages_per_step(page_size: int, n_pages: int, operands,
                     block_k: Optional[int]) -> int:
-    """P, the pages one grid step of the paged kernel covers: ``block_k``
-    rows (default ``DEFAULT_DECODE_BLOCK_K``) over the page size, capped at
-    the table's width. 1 (a step is a page) where a page is ``block_k``
-    rows already, or where a page of some cache operand is below that
-    dtype's packed tile (an int8 page of 16 rows is half a (32, 128)
-    tile), so P of them cannot lie side by side without a relayout."""
+    """P, the pages one grid step of the paged kernel covers, which is one
+    compute tile: ``block_k`` rows (default ``DEFAULT_DECODE_BLOCK_K``) of
+    ``_FULL_ROW_BYTES`` or more, and as many more of a narrower row of the
+    K pool as hold the same bytes (256 rows of 2,048 bfloat16 lanes, 512 of
+    1,024, 1,024 of 512), over the page size, capped at the table's width.
+    A step costs its launch, its products' set-up and its copies' starts
+    whatever the bytes, so at 256 rows a row of 512 lanes ran at 37-41% of
+    its bytes' pace where a row of 2,048 runs at 70-85% (PERF.md, PR 50).
+    1 (a step is a page) where a page is over half of ``block_k`` already,
+    or where a page of some cache operand is below that dtype's packed tile
+    (an int8 page of 16 rows is half a (32, 128) tile), so P of them cannot
+    lie side by side without a relayout."""
     want = DEFAULT_DECODE_BLOCK_K if block_k is None else block_k
-    if any(page_size % _packed_rows(x.dtype) for x in operands):
+    if want // page_size <= 1 or any(
+            page_size % _packed_rows(x.dtype) for x in operands):
         return 1
-    return max(1, min(want // page_size, n_pages))
+    k = operands[0]
+    row_bytes = k.shape[-1] * jnp.dtype(k.dtype).itemsize
+    rows = want * max(1, _FULL_ROW_BYTES // row_bytes)
+    return max(1, min(rows // page_size, n_pages))
 
 
-def _paged_block_call(q, pools, starts_b, ends_b, tables_b, pages: int):
+def paged_grid(pools, n_pages: int, block_k: Optional[int] = None,
+               max_live: Optional[int] = None):
+    """``(P, steps)``: the pages one grid step of the paged kernel covers
+    (:func:`_pages_per_step`) and the steps it walks a lane, for the cache
+    operands ``pools`` (arrays or shapes ``[num_pages, page_size, width]``;
+    K first), a table of ``n_pages`` pages a lane and ``max_live``, a static
+    bound on the rows of a lane's window (None: the table's). The kernel
+    sizes its grid by this function and the serving engine counts a tick's
+    steps by it (``serving.decode``'s ``kernel_steps``).
+
+    At P > 1 a lane's steps are consecutive blocks from the one its window
+    starts in, so they number what ``max_live`` rows can touch: one more
+    than the blocks they fill, and never more than the table has. At P = 1
+    a step is a page of the table, every one."""
+    page_size = pools[0].shape[1]
+    pages = _pages_per_step(page_size, n_pages, pools, block_k)
+    steps = -(-n_pages // pages)
+    if pages > 1 and max_live is not None:
+        steps = min(steps, -(-max_live // (pages * page_size)) + 1)
+    return pages, steps
+
+
+def _paged_block_call(q, pools, starts_b, ends_b, tables_b, pages: int,
+                      steps: int):
     """The paged kernel at ``pages`` > 1 pages a grid step: grid
-    ``(b, ceil(n_pages / pages))``, and a step's tile is ``pages *
+    ``(b, steps)`` (:func:`paged_grid`), and a step's tile is ``pages *
     page_size`` logical rows of one lane, contiguous in VMEM, so the two
-    products run on a ``block_k``-row tile as in the contiguous kernel.
+    products run on ONE tile of that many rows as in the contiguous kernel
+    (tiles of 256 rows inside a step of 1,024 took a third longer at 512
+    lanes: PERF.md, PR 50).
+
+    Step ``jm`` of a lane is logical block ``starts // rows + jm``: a
+    lane's steps begin where its window does, so a window layer's grid is
+    as long as the window and not as the table. Blocks behind ``end``, and
+    behind the table, are dead steps.
 
     The pools stay in HBM (``pl.ANY``). A live step's pages are gathered
     through the lane's table by one async copy a LIVE page into its place
@@ -710,15 +781,17 @@ def _paged_block_call(q, pools, starts_b, ends_b, tables_b, pages: int):
     step's copies (this lane's next block, else the first block of the
     next lane that HAS a window, ``next_live``: the lanes between decode no
     token) into the other half before it waits for its own, so the gather
-    runs under the step before it; a dead step does nothing. Both grid axes
-    are sequential: the buffer half and whether it is already on its way
-    pass from step to step in SMEM (``state``)."""
+    runs under the step before it; a dead step does nothing. The waits are
+    one for each power of two in the number of pages copied, not one a
+    page. Both grid axes are sequential: the buffer half and whether it is
+    already on its way pass from step to step in SMEM (``state``)."""
     b, _, h, d = q.shape
     ps = pools[0].shape[1]
     n_pages = tables_b.shape[1]
     rows = pages * ps
     n_ops = len(pools)
     width = pools[0].shape[-1]
+    group_pages = min(pages, _START_UNROLL)
     q_in, q_block, group = _query_operand(q, width)
 
     # the chain passes over empty lanes: each lane's next lane WITH a
@@ -753,41 +826,69 @@ def _paged_block_call(q, pools, starts_b, ends_b, tables_b, pages: int):
             lo = jnp.clip(first - blk * pages, 0, pages)
             hi = jnp.clip(last + 1 - blk * pages, 0, pages)
 
-            def one(i, carry):
+            if wait:
+                # the semaphore counts bytes, whatever copies brought them:
+                # one wait for each power of two in the number of pages on
+                # their way, not one a page
+                on_way = hi - lo
+                for k in (1 << e for e in range(pages.bit_length())):
+                    @pl.when((on_way & k) != 0)
+                    def _wait():
+                        for buf in bufs:
+                            part = buf.at[half, pl.ds(0, k * ps), :]
+                            pltpu.make_async_copy(part, part,
+                                                  sem.at[half]).wait()
+                return
+
+            def start(i, carry):
                 page = tables_ref[lane, blk * pages + i]
                 row0 = pl.multiple_of(i * ps, ps)
                 for pool, buf in zip(pool_refs, bufs):
-                    dma = pltpu.make_async_copy(
+                    pltpu.make_async_copy(
                         pool.at[page], buf.at[half, pl.ds(row0, ps), :],
-                        sem.at[half])
-                    if wait:
-                        dma.wait()
-                    else:
-                        dma.start()
+                        sem.at[half]).start()
                 return carry
 
-            jax.lax.fori_loop(lo, hi, one, 0)
+            # a start is a table read and a descriptor an operand on the
+            # scalar core, which a step pays for beside its products: whole
+            # groups of pages go unrolled (a tenth off a call's time at 512
+            # lanes: PERF.md, PR 50), the ragged ends of the window a page
+            # at a time
+            g_lo, g_hi = -(-lo // group_pages), hi // group_pages
+            lead = jnp.minimum(g_lo * group_pages, hi)
 
-        def gather():
+            def start_group(g, carry):
+                for j in range(group_pages):
+                    start(g * group_pages + j, carry)
+                return carry
+
+            jax.lax.fori_loop(lo, lead, start, 0)
+            jax.lax.fori_loop(g_lo, g_hi, start_group, 0)
+            jax.lax.fori_loop(jnp.maximum(g_hi * group_pages, lead), hi,
+                              start, 0)
+
+        def gather(blk):
             half = state[0]
 
             @pl.when(state[1] == 0)
             def _own():
-                copies(bi, jm, half, wait=False)
+                copies(bi, blk, half, wait=False)
 
-            same = jm < (ends_ref[bi] - 1) // rows
+            # this lane's next block, where the window reaches it and the
+            # grid has a step for it
+            same = (blk < (ends_ref[bi] - 1) // rows) & (jm + 1 < steps)
             nxt = next_ref[bi]
             ahead = same | (nxt < b)
             lane = jnp.where(same, bi, jnp.minimum(nxt, b - 1))
-            blk = jnp.where(same, jm + 1, starts_ref[lane] // rows)
+            nblk = jnp.where(same, blk + 1, starts_ref[lane] // rows)
 
             @pl.when(ahead)
             def _next():
-                copies(lane, blk, 1 - half, wait=False)
+                copies(lane, nblk, 1 - half, wait=False)
 
             state[0] = 1 - half
             state[1] = ahead.astype(jnp.int32)
-            copies(bi, jm, half, wait=True)
+            copies(bi, blk, half, wait=True)
             views = [buf.at[half] for buf in bufs]
             return views if n_ops == 4 else views + [None, None]
 
@@ -798,7 +899,7 @@ def _paged_block_call(q, pools, starts_b, ends_b, tables_b, pages: int):
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, -(-n_pages // pages)),
+        grid=(b, steps),
         in_specs=[pl.BlockSpec(q_block, _q_index_map)]
         + [pl.BlockSpec(memory_space=pl.ANY)] * n_ops,
         out_specs=pl.BlockSpec(q_block, _q_index_map),
@@ -849,6 +950,7 @@ def flash_decode_paged_attention(
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     mesh=None,
+    max_live: Optional[int] = None,
 ) -> jax.Array:
     """Single-query attention against a PAGED kv cache; q is [b, 1, h, d].
 
@@ -867,10 +969,15 @@ def flash_decode_paged_attention(
 
     ``page_size`` must be a multiple of 8 (callers pre-screen with
     :func:`decode_flash_supported` on the page size). ``block_k`` is the
-    rows of a compute tile, as in the contiguous kernel: a grid step
-    gathers ``block_k // page_size`` pages of the row into one tile
-    (module docstring "Paged variant"), and where a step stays one page
-    ``block_k`` tiles within it (largest divisor wins).
+    rows of a compute tile of a row of 2,048 bfloat16 lanes, as in the
+    contiguous kernel: a grid step gathers the pages of one tile, of as many
+    more rows as a narrower row leaves room for (:func:`paged_grid`, module
+    docstring "Paged variant"), and where a step stays one page ``block_k``
+    tiles within it (largest divisor wins).
+    ``max_live`` is a STATIC bound on ``end - starts`` that the caller
+    vouches for (a window layer's window): the grid then walks a lane the
+    steps that many rows can touch and not the table's; rows of a window
+    beyond it would be left out.
     ``mesh`` runs the kernel per-shard over the local head slice of the
     page pools (tables replicated) — see :func:`flash_decode_attention`.
     """
@@ -881,12 +988,13 @@ def flash_decode_paged_attention(
     operands = _cache_operands(k_pages, v_pages, k_scale, v_scale)
     if meshed:
         return _sharded_decode(mesh, starts_b, ends_b, [q] + operands,
-                               tables=tables_b, block_k=block_k)
+                               tables=tables_b, block_k=block_k,
+                               max_live=max_live)
     page_size = k_pages.shape[1]
-    pages = _pages_per_step(page_size, tables.shape[1], operands, block_k)
+    pages, steps = paged_grid(operands, tables.shape[1], block_k, max_live)
     if pages > 1:
         return _paged_block_call(q, operands, starts_b, ends_b, tables_b,
-                                 pages)
+                                 pages, steps)
     # a step is one page (the gather unit); block_k tiles inside it
     block_k, major = fit_decode_blocks(page_size, block_k, page_size)
     if block_k is None or major != page_size:

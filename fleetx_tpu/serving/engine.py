@@ -771,6 +771,7 @@ class ServingEngine:
         # programs dispatched so far (_next_program)
         self._programs = 0
         self._state = self._replicate(self._init_state())
+        self._kernel_steps = self._decode_kernel_steps()
         # buffer donation halves cache HBM residency on TPU; skipped on
         # CPU/interpret runs where XLA would only warn about it
         donate = jax.default_backend() == "tpu"
@@ -2710,6 +2711,29 @@ class ServingEngine:
             np.minimum(rows, cfg.sliding_window).sum()),
             **cfg.span_pairs(self.slots)}
 
+    def _decode_kernel_steps(self) -> dict:
+        """Span field of every ``serving.decode``: the grid steps of the
+        paged decode kernel over all the layers' calls of a tick, a constant
+        of the engine's shapes (``cfg.decode_kernel_steps``); none where a
+        tick does not run that kernel (off the chip unless forced, latent
+        attention's own kernel, heads a mesh does not divide)."""
+        from fleetx_tpu.ops.pallas.decode_attention import (
+            decode_flash_supported,
+            decode_mesh_shardable,
+        )
+
+        cfg = self.model.cfg
+        if not (cfg.use_flash_attention
+                and decode_flash_supported(self.page_size)):
+            return {}
+        shards = 1
+        if self.mesh is not None and self.mesh.size > 1:
+            if not decode_mesh_shardable(self.mesh, cfg.num_attention_heads):
+                return {}
+            shards = dict(self.mesh.shape).get("mp", 1)
+        steps = cfg.decode_kernel_steps(self.slots, shards)
+        return {"kernel_steps": steps} if steps else {}
+
     def _sync_cause(self) -> Optional[str]:
         """Why this engine reads every tick before it dispatches the next,
         by what it already is (None: it keeps one in flight): a
@@ -2759,6 +2783,7 @@ class ServingEngine:
         with span("serving.decode", batch=len(active_ids),
                   empty_lanes=self.slots - len(lanes),
                   inflight=int(before is not None), program=program,
+                  **self._kernel_steps,
                   **self.metrics.record_selection(self._decode_rows(lanes))):
             cache, st, tok, done = self._run_device(run)
         self.cache_manager.cache = cache

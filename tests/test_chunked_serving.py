@@ -183,6 +183,67 @@ def test_free_and_mid_prefill_lanes_change_no_token(tiny_flash, monkeypatch):
         s.attrs["empty_lanes"] == 5 - s.attrs["batch"] >= 2 for s in ticks)
 
 
+# stack -> GPTConfig fields: the one block of models/gpt/model.py, and the
+# grouped-head stack of models/gpt/hybrid.py with one full and three window
+# layers (a window of 16 rows) over two classes of page
+_KERNEL_STEP_STACKS = {
+    "gpt": dict(num_layers=2, num_attention_heads=2, ffn_hidden_size=64),
+    "hybrid": dict(
+        num_layers=4, num_attention_heads=8, num_key_value_heads=2,
+        head_size=16, ffn_hidden_size=32, num_experts=8, gate="softmax_topk",
+        top_k=2, norm_topk_prob=True, position_embedding="rope",
+        rope_layout=(0, 1, 1, 1), sliding_window=16,
+        sliding_window_layout=(0, 1, 1, 1), norm="rmsnorm", mlp_act="reglu",
+        use_bias=False, tie_word_embeddings=False,
+        router_input="block_input", expert_mode=True, family="smallthinker"),
+}
+
+
+@pytest.mark.parametrize("stack", list(_KERNEL_STEP_STACKS))
+def test_decode_span_counts_the_kernels_grid_steps(monkeypatch, stack):
+    """Each ``serving.decode`` span carries ``kernel_steps``: the grid steps
+    of ``fleetx_decode_paged`` over all the layers' calls of a tick, from
+    the function the kernel sizes its grid with
+    (``decode_attention.paged_grid``). Rows of 1,024 in steps of 256 (the
+    step of a full-width row here): a full layer's call walks 4 steps a
+    lane, a window layer's (16 rows) 2, and the calls the tick traces have
+    those grids."""
+    import fleetx_tpu.ops.pallas.decode_attention as da
+    from fleetx_tpu.obs.tracing import get_recorder
+
+    monkeypatch.setenv("FLEETX_FORCE_FLASH", "1")
+    monkeypatch.setattr(da, "_FULL_ROW_BYTES", 0)
+    traced, real = [], da._paged_block_call
+
+    def recording(q, pools, starts, ends, tables, pages, steps):
+        traced.append((q.shape[0], steps))
+        return real(q, pools, starts, ends, tables, pages, steps)
+
+    monkeypatch.setattr(da, "_paged_block_call", recording)
+    cfg = GPTConfig(
+        vocab_size=61, hidden_size=32, max_position_embeddings=1024,
+        hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
+        dtype=jnp.float32, use_flash_attention=True,
+        **_KERNEL_STEP_STACKS[stack])
+    model = GPTForPretraining(cfg)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    eng = _engine(model, params, slots=3, cache_len=1024, prefill_chunk=8,
+                  prefill_bucket=8)
+    rec = get_recorder()
+    rec.clear()
+    eng.submit(np.arange(1, 12, dtype=np.int32), max_length=3)
+    eng.drain()
+    windows = {"gpt": (None, None), "hybrid": (None, 16, 16, 16)}[stack]
+    width = cfg.kv_heads * cfg.head_dim
+    pool = [jax.ShapeDtypeStruct((1, 8, width), jnp.float32)] * 2
+    grids = [da.paged_grid(pool, 1024 // 8, max_live=w)[1] for w in windows]
+    assert grids == [{None: 4, 16: 2}[w] for w in windows]
+    ticks = [s for s in rec.spans() if s.name == "serving.decode"]
+    assert ticks and all(
+        s.attrs["kernel_steps"] == 3 * sum(grids) for s in ticks)
+    assert set(traced) == {(3, steps) for steps in grids}
+
+
 def test_chunked_parity_at_cache_capacity_edge(tiny):
     """Regression (PR 11 review): a chunk whose PADDED bucket would
     cross ``cache_len`` must cap at the remaining span (prompt_len 31 in

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import fleetx_tpu.ops.pallas.decode_attention as da
 from fleetx_tpu.models.gpt.generation import GenerationConfig, generate
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
 from fleetx_tpu.ops.pallas.decode_attention import (
@@ -23,6 +24,7 @@ from fleetx_tpu.ops.pallas.decode_attention import (
     fit_decode_blocks,
     flash_decode_attention,
     flash_decode_paged_attention,
+    paged_grid,
 )
 from fleetx_tpu.ops.quant import dequantize_kv, quantize_kv
 
@@ -189,24 +191,53 @@ def test_paged_kernel_at_engine_shapes(case, h, d, kv_dtype):
                                rtol=3e-2, atol=3e-2)
 
 
-@pytest.mark.parametrize("page,block_k,pages", [
-    (16, None, 16),   # the serve cells: block_k 256 over page 16
-    (16, 128, 8),
-    (32, None, 8),
-    (256, None, 1),   # a page is block_k rows already
-    (512, None, 1),
+@pytest.mark.parametrize("page,block_k,lanes,n_row,pages", [
+    (16, None, 2048, 64, 16),   # the full-head serve cells: 256 rows a step
+    (16, None, 2048, 6, 6),     # capped at the table's width
+    (16, None, 1024, 3200, 32),  # Trinity's row: 512 rows
+    (16, None, 512, 800, 64),   # SmallThinker's and LFM2's: 1,024 rows
+    (16, None, 512, 19, 19),
+    (16, None, 128, 64, 64),    # Jamba2's row: the table
+    (16, None, 4096, 64, 16),   # a wider row: block_k rows, never fewer
+    (16, 128, 2048, 80, 8),     # FLEETX_DECODE_BLOCK_K=128: half the rows
+    (16, 128, 512, 80, 32),
+    (32, None, 2048, 64, 8),
+    (256, None, 512, 64, 1),    # a page is block_k rows already
+    (512, None, 512, 64, 1),
 ])
-def test_pages_per_step(page, block_k, pages):
-    """P follows from the shapes a call sees: block_k over the page size,
-    capped at the table's width, and 1 where a page of some cache operand
-    is below its dtype's packed tile."""
-    pool = lambda dt: jnp.zeros((3, page, 256), dt)
+def test_pages_per_step(page, block_k, lanes, n_row, pages):
+    """P follows from the shapes a call sees: block_k rows of a row of
+    2,048 bf16 lanes over the page size, as many more of a narrower row as
+    hold the same bytes, capped at the table's width, and 1 where a page of
+    some cache operand is below its dtype's packed tile."""
+    pool = lambda dt, width=lanes: jax.ShapeDtypeStruct((3, page, width), dt)
     bf16 = [pool(jnp.bfloat16)] * 2
-    assert _pages_per_step(page, 64, bf16, block_k) == pages
-    assert _pages_per_step(page, 6, bf16, block_k) == min(pages, 6)
-    int8 = [pool(jnp.int8)] * 2 + [jnp.zeros((3, page, 2), jnp.float32)] * 2
-    assert _pages_per_step(page, 64, int8, block_k) == (
-        1 if page < 32 else pages)
+    assert _pages_per_step(page, n_row, bf16, block_k) == pages
+    assert paged_grid(bf16, n_row, block_k) == (pages, -(-n_row // pages))
+    int8 = [pool(jnp.int8)] * 2 + [pool(jnp.float32, lanes // 128)] * 2
+    got = _pages_per_step(page, n_row, int8, block_k)
+    assert got == 1 if page < 32 else got >= pages
+
+
+@pytest.mark.parametrize("max_live,steps", [
+    (None, 13), (4096, 5), (4097, 6), (1024, 2), (1, 2), (20000, 13)])
+def test_paged_grid_walks_what_the_bound_can_touch(max_live, steps):
+    """The steps of a lane at SmallThinker's row (1,024 rows a step, a table
+    of 800 pages): one more than the blocks ``max_live`` rows fill, since a
+    window starts anywhere in a block, and never more than the table's."""
+    pool = [jax.ShapeDtypeStruct((9, 16, 512), jnp.bfloat16)] * 2
+    assert paged_grid(pool, 800, None, max_live) == (64, steps)
+    # at one page a step (the int8 pool's 16-row pages) the bound is unused
+    int8 = ([jax.ShapeDtypeStruct((9, 16, 512), jnp.int8)] * 2
+            + [jax.ShapeDtypeStruct((9, 16, 4), jnp.float32)] * 2)
+    assert paged_grid(int8, 800, None, max_live) == (1, 800)
+
+
+def _step_of_block_k(monkeypatch):
+    """Make a grid step of the paged kernel ``block_k`` rows whatever the
+    row's width (the kernel gives a narrow row more: ``_FULL_ROW_BYTES``),
+    so that a test's small table is several steps."""
+    monkeypatch.setattr(da, "_FULL_ROW_BYTES", 0)
 
 
 # lane -> (pages held, end, start): empty lanes first and last, two in a
@@ -222,7 +253,7 @@ _EMPTY_LANE_WINDOWS = ((0, 0, 0), (10, 300, 0), (0, 0, 0), (10, 100, 300),
     ("bfloat16", 1), ("bfloat16", 4), ("int8", 1)])  # (int8 takes group 1)
 @pytest.mark.parametrize("pages", [8, 1])
 def test_paged_kernel_with_free_and_empty_lanes_interleaved(
-        pages, kv_dtype, group, with_starts):
+        monkeypatch, pages, kv_dtype, group, with_starts):
     """A lane whose window is empty (``end`` = 0, or ``end`` <= ``start``:
     a lane that decodes no token) is no step and no copy, and its output
     block is EXACT zeros, under a NaN prefill that a block the kernel
@@ -271,7 +302,9 @@ def test_paged_kernel_with_free_and_empty_lanes_interleaved(
                 starts=starts[lanes] if with_starts else None, **scales
             ).astype(jnp.float32))
 
-    assert _pages_per_step(ps, n_row, [pool_k], pages * ps) == pages
+    _step_of_block_k(monkeypatch)
+    assert paged_grid([_fold(pool_k)], n_row, pages * ps) == (
+        pages, n_row // pages + (pages == 8))
     out = call(np.arange(b))
     gather = lambda x: x[tables].reshape(b, n_row * ps, kvh, d)
     ref = _dense_grouped(
@@ -306,30 +339,149 @@ def _pallas_calls(jaxpr):
             yield from _pallas_calls(sub)
 
 
-@pytest.mark.parametrize("lanes,n_row,block_k,pages", [
-    (24, 64, None, 16),   # gpt1.3b-serve-chat-steady-v3
-    (16, 64, None, 16),   # gpt1.3b-serve-docs-batch
-    (32, 80, None, 16),   # olmoe-l8-serve-gen-batch
-    (32, 80, 128, 8),     # FLEETX_DECODE_BLOCK_K=128: half the rows a step
-])
-def test_paged_kernel_grid_at_the_serve_cells(monkeypatch, lanes, n_row,
-                                              block_k, pages):
-    """The three serve cells' decode call (page 16, 16 heads of 128, a
-    bf16 pool) lowers for the TPU as ``fleetx_decode_paged`` with
-    ceil(pages of a row / P) grid steps a lane: the mechanism of PR 30 has
-    no hit share, it engages on every call of a shape or on none, and the
-    grid says which."""
-    h, d, ps = 16, 128, 16
-    q = jnp.zeros((lanes, 1, h, d), jnp.bfloat16)
-    kv = jnp.zeros((lanes * n_row + 1, ps, h * d), jnp.bfloat16)
-    tables = jnp.zeros((lanes, n_row), jnp.int32)
-    ends = jnp.full((lanes,), n_row * ps, jnp.int32)
-    fn = lambda q, kv, t, e: flash_decode_paged_attention(
-        q, kv, kv, tables=t, end=e, block_k=block_k)
-    traced, text = _lower_for_tpu(monkeypatch, fn, q, kv, tables, ends)
+# cell -> (lanes, pages a row, query heads, key heads, head size, the window
+# of the call or None, block_k, the grid's steps a lane): every decode call
+# of the seven serve cells that run the kernel, at its real shape
+_SERVE_CELL_CALLS = {
+    "gpt1.3b-serve-chat-steady-v3": (24, 64, 16, 16, 128, None, None, 4),
+    "gpt1.3b-serve-docs-batch": (16, 64, 16, 16, 128, None, None, 4),
+    "olmoe-l8-serve-gen-batch": (32, 80, 16, 16, 128, None, None, 5),
+    # FLEETX_DECODE_BLOCK_K=128: half the rows a step
+    "olmoe-l8-serve-gen-batch.block_k128": (32, 80, 16, 16, 128, None, 128,
+                                            10),
+    "smallthinker-l8-serve-longdoc-gen.full": (24, 800, 28, 4, 128, None,
+                                               None, 13),
+    "smallthinker-l8-serve-longdoc-gen.window": (24, 800, 28, 4, 128, 4096,
+                                                 None, 5),
+    "trinity-l5-serve-mixed-longshort.full": (8, 3200, 48, 8, 128, None,
+                                              None, 100),
+    "trinity-l5-serve-mixed-longshort.window": (8, 3200, 48, 8, 128, 4096,
+                                                None, 9),
+    "lfm2-l14-serve-agent-prefix": (48, 304, 32, 8, 64, None, None, 5),
+    "jamba2-3b-serve-chat-peak": (256, 64, 20, 1, 128, None, None, 1),
+}
+
+
+def _cell_call(cell):
+    """``(fn, argument shapes, steps)`` of one of ``_SERVE_CELL_CALLS``:
+    page 16, a bf16 pool, the window layer's ``starts`` and bound."""
+    lanes, n_row, h, kv, d, window, block_k, steps = _SERVE_CELL_CALLS[cell]
+    spec = jax.ShapeDtypeStruct
+    args = (spec((lanes, 1, h, d), jnp.bfloat16),
+            spec((lanes * 64 + 1, 16, kv * d), jnp.bfloat16),
+            spec((lanes, n_row), jnp.int32), spec((lanes,), jnp.int32))
+
+    def fn(q, pool, tables, ends):
+        return flash_decode_paged_attention(
+            q, pool, pool, tables=tables, end=ends, block_k=block_k,
+            starts=window and jnp.maximum(ends - window, 0), max_live=window)
+
+    return fn, args, steps
+
+
+@pytest.mark.parametrize("cell", list(_SERVE_CELL_CALLS))
+def test_paged_kernel_grid_at_the_serve_cells(monkeypatch, cell):
+    """Every serve cell's decode call lowers for the TPU as
+    ``fleetx_decode_paged`` with the grid its shapes give: the mechanisms of
+    PR 30 and PR 50 have no hit share, they engage on every call of a shape
+    or on none, and the grid says which. The three full-head cells (16 heads
+    of 128: 2,048 lanes) keep ``(lanes, ceil(pages of a row / 16))``; a
+    narrower row takes more rows a step, and a window layer's call walks
+    the steps its window can touch and not the table's."""
+    fn, args, steps = _cell_call(cell)
+    lanes, n_row, _, kv, d, window, block_k, _ = _SERVE_CELL_CALLS[cell]
+    if kv * d == 2048 and block_k is None:
+        assert steps == -(-n_row // 16)
+    assert paged_grid([args[1]], n_row, block_k, window)[1] == steps
+    traced, text = _lower_for_tpu(monkeypatch, fn, *args)
     (call,) = _pallas_calls(traced.jaxpr.jaxpr)
-    assert call.params["grid_mapping"].grid == (lanes, -(-n_row // pages))
+    assert call.params["grid_mapping"].grid == (lanes, steps)
     assert f'kernel_name = "{PAGED_KERNEL_NAME}"' in text
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("cell", [
+    c for c, call in _SERVE_CELL_CALLS.items() if call[3] * call[4] < 2048])
+def test_narrow_rows_compile_for_the_v5e(one_chip, monkeypatch, cell):
+    """The calls whose step PR 50 made longer (rows of 512, 1,024 and 128
+    lanes: 64, 32 and 64 pages a step, one tile each) through the chip's own
+    compiler, without the chip: Mosaic takes the tile and its four buffer
+    halves fit VMEM."""
+    fn, args, _ = _cell_call(cell)
+    monkeypatch.setattr(da, "_interpret", lambda: False)
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in args]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert PAGED_KERNEL_NAME in text
+
+
+# lane -> (end, what the test says of it) under a window of 100 rows, where a
+# step is 64 rows (8 pages of 8) and the table 30 pages: 3.75 steps
+_WINDOW_LANES = (
+    (237, "starts mid-page and mid-step; its last block, the table's "
+          "fourth, runs past the table"),
+    (0, "empty"),
+    (50, "end below the window: starts at 0"),
+    (0, "empty"),
+    (0, "empty"),
+    (192, "ends on a step's edge"),
+    (130, "touches three steps: the most 100 rows can"),
+    (64, "one whole first block"),
+)
+
+
+@pytest.mark.parametrize("window", [100, 20])
+@pytest.mark.parametrize("group", [7, 6, 4])
+def test_paged_kernel_walks_a_window_from_where_it_starts(monkeypatch, group,
+                                                          window):
+    """A window layer's call (``starts`` = ``end - window``, ``max_live`` =
+    the window): step jm of a lane is the block its window starts in plus
+    jm, so the grid is as long as the window (3 steps a lane for 100 rows, 2
+    for 20: shorter than a step) and not as the table (4). Against the dense
+    path over grouped heads (groups of 7, 6 and 4: SmallThinker's, Trinity's
+    and LFM2's), with empty lanes between live ones in the chain of copies;
+    and bit for bit the call that walks the whole table, whose blocks lie
+    where these do."""
+    rng = np.random.RandomState(7)
+    # (on the chip a copied row is whole 128-lane tiles: 2 key heads of 64)
+    ps, n_row, kvh, d, block_k = 8, 30, 2, 64, 64
+    b, h = len(_WINDOW_LANES), group * kvh
+    ends = jnp.asarray([e for e, _ in _WINDOW_LANES], jnp.int32)
+    starts = jnp.maximum(ends - window, 0)
+    q = jnp.asarray(rng.randn(b, 1, h, d), jnp.float32)
+    k = jnp.asarray(rng.randn(b * n_row + 1, ps, kvh, d), jnp.float32)
+    v = jnp.asarray(rng.randn(b * n_row + 1, ps, kvh, d), jnp.float32)
+    tables = jnp.asarray(
+        1 + rng.permutation(b * n_row).reshape(b, n_row), jnp.int32)
+    _step_of_block_k(monkeypatch)
+    assert paged_grid([_fold(k)], n_row, block_k, window) == (
+        8, {100: 3, 20: 2}[window])
+
+    def call(max_live):
+        return np.asarray(flash_decode_paged_attention(
+            q, _fold(k), _fold(v), tables=tables, end=ends, starts=starts,
+            block_k=block_k, max_live=max_live))
+
+    got = call(window)
+    gather = lambda x: x[tables].reshape(b, n_row * ps, kvh, d)
+    want = np.asarray(_dense_grouped(q, gather(k), gather(v), ends, starts))
+    busy = np.flatnonzero(np.asarray(ends))
+    np.testing.assert_allclose(got[busy], want[busy], rtol=_TOL, atol=_TOL)
+    empty = np.setdiff1d(np.arange(b), busy)
+    assert np.array_equal(got[empty], np.zeros_like(got[empty]))
+    np.testing.assert_array_equal(got, call(None))
 
 
 @pytest.mark.parametrize("mp", [1, 2])
@@ -436,7 +588,7 @@ def test_grouped_kernels_lower_for_tpu_at_the_smallthinker_cell(monkeypatch,
                                                                 paged):
     """``smallthinker-l8-serve-longdoc-gen``'s decode call (24 lanes, 28
     query heads over 4 key heads of 128, page 16, 800 pages a row, a bf16
-    pool of both classes) passes the TPU lowering as the block kernel, 16
+    pool of both classes) passes the TPU lowering as the block kernel, 64
     pages a step; the contiguous kernel at the same heads too."""
     lanes, h, kv, d, ps, n_row = 24, 28, 4, 128, 16, 800
     q = jnp.zeros((lanes, 1, h, d), jnp.bfloat16)
@@ -450,7 +602,7 @@ def test_grouped_kernels_lower_for_tpu_at_the_smallthinker_cell(monkeypatch,
         traced, text = _lower_for_tpu(monkeypatch, fn, q, pool, tables, ends,
                                       starts)
         (call,) = _pallas_calls(traced.jaxpr.jaxpr)
-        assert call.params["grid_mapping"].grid == (lanes, n_row // 16)
+        assert call.params["grid_mapping"].grid == (lanes, -(-n_row // 64))
         assert f'kernel_name = "{PAGED_KERNEL_NAME}"' in text
     else:
         cache = jnp.zeros((lanes, 1024, kv * d), jnp.bfloat16)
@@ -463,7 +615,7 @@ def test_grouped_kernel_lowers_for_tpu_at_the_jamba2_cell(monkeypatch):
     """``jamba2-3b-serve-chat-peak``'s decode call (256 lanes, 20 query
     heads over ONE key head of 128, page 16, 64 pages a row, a bf16 pool of
     its two attention layers) passes the TPU lowering as the block kernel,
-    16 pages a step."""
+    the 64 pages of a row in one step."""
     lanes, h, d, ps, n_row = 256, 20, 128, 16, 64
     q = jnp.zeros((lanes, 1, h, d), jnp.bfloat16)
     pool = jnp.zeros((2 * 16385, ps, d), jnp.bfloat16)
@@ -473,7 +625,7 @@ def test_grouped_kernel_lowers_for_tpu_at_the_jamba2_cell(monkeypatch):
         q, kv_, kv_, tables=t, end=e)
     traced, text = _lower_for_tpu(monkeypatch, fn, q, pool, tables, ends)
     (call,) = _pallas_calls(traced.jaxpr.jaxpr)
-    assert call.params["grid_mapping"].grid == (lanes, n_row // 16)
+    assert call.params["grid_mapping"].grid == (lanes, 1)
     assert f'kernel_name = "{PAGED_KERNEL_NAME}"' in text
 
 
@@ -644,7 +796,7 @@ def test_grouped_kernels_lower_for_tpu_at_the_lfm2_cell(monkeypatch, paged):
     """``lfm2-l14-serve-agent-prefix``'s decode call (48 lanes, 32 query
     heads over 8 key heads of 64, page 16, 304 pages a row, the bf16 pool of
     its three attention layers) passes the TPU lowering as the block kernel,
-    16 pages a step; the contiguous kernel at the same heads too."""
+    64 pages a step; the contiguous kernel at the same heads too."""
     lanes, h, kv, d, ps, n_row = 48, 32, 8, 64, 16, 304
     q = jnp.zeros((lanes, 1, h, d), jnp.bfloat16)
     ends = jnp.full((lanes,), 4000, jnp.int32)
@@ -655,7 +807,7 @@ def test_grouped_kernels_lower_for_tpu_at_the_lfm2_cell(monkeypatch, paged):
             q, kv_, kv_, tables=t, end=e)
         traced, text = _lower_for_tpu(monkeypatch, fn, q, pool, tables, ends)
         (call,) = _pallas_calls(traced.jaxpr.jaxpr)
-        assert call.params["grid_mapping"].grid == (lanes, n_row // 16)
+        assert call.params["grid_mapping"].grid == (lanes, -(-n_row // 64))
         assert f'kernel_name = "{PAGED_KERNEL_NAME}"' in text
     else:
         cache = jnp.zeros((lanes, 1024, kv * d), jnp.bfloat16)
