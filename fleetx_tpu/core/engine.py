@@ -330,7 +330,16 @@ class Trainer:
     # ------------------------------------------------------------------ init
     def init_state(self, sample_batch: Dict[str, np.ndarray]) -> TrainState:
         """Create sharded params + optimizer state directly on the mesh
-        (never materializing an unsharded copy on one device)."""
+        (never materializing an unsharded copy on one device). One
+        ``train.build`` span (docs/OBSERVABILITY.md "Start-up"), with
+        ``restored`` where the run's own resumable step was loaded."""
+        with span("train.build") as at:
+            state = self._init_state(sample_batch)
+            if self._restored_step is not None:
+                at["restored"] = True
+        return state
+
+    def _init_state(self, sample_batch):
         micro = self._microbatch(sample_batch)
 
         def _init(rng):
@@ -633,6 +642,9 @@ class Trainer:
             # same contexts as _in_context: without the logical axis
             # rules, with_logical_constraint silently no-ops and we'd
             # trace (and fully recompile) a differently-sharded program
+            # (this jax finds the trace, the lowering and the executable
+            # of a step that has run in memory: 3-4 ms on a v5e's host at
+            # PR 51, no second lowering)
             with use_mesh(self.mesh), nn.logical_axis_rules(list(self.rules)):
                 compiled = fn.lower(*args, **kwargs).compile()
             cached = (spec, compiled, compiled.cost_analysis())
@@ -863,7 +875,9 @@ class Trainer:
                     rng = dist_env.data_rank_key(step)
                 # a DISPATCH span: it ends when the step is enqueued; the
                 # wait for the device is train.loss_fetch below
-                with span("train.step", step=step):
+                with span("train.step", step=step) as at:
+                    if "train" not in self._abstract_args:
+                        at["first"] = True  # this call traces and compiles
                     self.state, metrics = train_step(self.state, device_batch,
                                                      rng)
                 skipped = False
@@ -1308,9 +1322,10 @@ class Trainer:
             "fast_dropout": bool(model_cfg.get("fast_dropout", True)),
         }
 
+    @span("train.restore")
     def load(self, step: Optional[int] = None):
-        """Restore; resumes step count, epoch, and data order
-        (consumed_samples -> sampler, eager_engine.py:286-288).
+        """Restore (one ``train.restore`` span); resumes step count, epoch,
+        and data order (consumed_samples -> sampler, eager_engine.py:286-288).
 
         On auto-restore (``step=None``) a corrupt/truncated checkpoint —
         e.g. a kill that landed between an async save and its finalize —

@@ -22,8 +22,8 @@ or reaches its budget clears its own ``active`` bit inside the program),
 so tick n+1 needs nothing that tick n's tokens decide. ``_tick_decode``
 therefore dispatches tick n and only THEN reads tick n-1
 (``serving.fetch``, ``serving.emit``): the result's way back, the emit
-loop, ``serving.observe`` and the caller's work between two ``step()``
-calls all run while tick n is on the device. What follows from it:
+loop, the metrics block after the tick and the caller's work between two
+``step()`` calls all run while tick n is on the device. What follows from it:
 
 - the host's position (``cache_manager.lengths``) is the position AS
   DISPATCHED: it advances by one for every lane a tick is dispatched for,
@@ -459,6 +459,10 @@ class ServingEngine:
     """Continuous-batching serving loop over decode lanes and a page
     pool (module docstring)."""
 
+    # construction is one span (docs/OBSERVABILITY.md "Start-up"): what it
+    # traces, compiles or loads lies inside it as jit.* spans that name it
+    # as their parent
+    @span("serving.build")
     def __init__(self, model, variables, *, slots: Optional[int] = None,
                  cache_len: Optional[int] = None,
                  gen_cfg: Optional[GenerationConfig] = None,
@@ -1071,21 +1075,20 @@ class ServingEngine:
             except Exception as exc:  # noqa: BLE001 — THE crash-safety seam
                 summary = self._handle_tick_fault(snap, exc)
         self._ticks += 1
-        with span("serving.observe"):
-            self.metrics.observe_tick(self.scheduler.queue_depth,
-                                      len(self._active), self._now() - t0)
-            self.metrics.observe_pages(self.cache_manager.pages_in_use,
-                                       self.cache_manager.usable_pages)
-            if self._dram_store is not None:
-                self.metrics.observe_host_tier(self._dram_store)
-            if self._disk_store is not None:
-                self.metrics.observe_disk_tier(self._disk_store)
-            self.metrics.observe_queue_tokens(
-                self.scheduler.queued_tokens() + sum(
-                    r.prompt_len - r.prefill_pos
-                    for r in self._prefilling.values()))
-            if self.log_every and self._ticks % self.log_every == 0:
-                self.metrics.log_snapshot()
+        self.metrics.observe_tick(self.scheduler.queue_depth,
+                                  len(self._active), self._now() - t0)
+        self.metrics.observe_pages(self.cache_manager.pages_in_use,
+                                   self.cache_manager.usable_pages)
+        if self._dram_store is not None:
+            self.metrics.observe_host_tier(self._dram_store)
+        if self._disk_store is not None:
+            self.metrics.observe_disk_tier(self._disk_store)
+        self.metrics.observe_queue_tokens(
+            self.scheduler.queued_tokens() + sum(
+                r.prompt_len - r.prefill_pos
+                for r in self._prefilling.values()))
+        if self.log_every and self._ticks % self.log_every == 0:
+            self.metrics.log_snapshot()
         summary.setdefault("recovered", False)
         summary.setdefault("chunked", 0)
         summary["queue_depth"] = self.scheduler.queue_depth
@@ -1105,8 +1108,7 @@ class ServingEngine:
         tick's prefill budget is ONE chunk-sized device call — a chunk
         of the in-flight prompt or one short admission — so decode never
         stalls longer (the ``prefill_stall_ms`` histogram measures it)."""
-        with span("serving.expire"):
-            timed_out = self._expire_queued(self._now())
+        timed_out = self._expire_queued(self._now())
         admitted = 0
         chunked = 0
         prefill_t0 = self._now()
@@ -1135,12 +1137,11 @@ class ServingEngine:
         elif self._active or self._inflight is not None:
             retired = self._tick_decode(commit)
         # fresh clock: prefill/decode above may have eaten the deadline
-        with span("serving.expire"):
-            now = self._now()
-            if self._inflight is not None and self._overdue(now):
-                # a deadline's partial result keeps the token in flight
-                retired += self._collect("evict", commit)
-            timed_out += self._expire_active(now)
+        now = self._now()
+        if self._inflight is not None and self._overdue(now):
+            # a deadline's partial result keeps the token in flight
+            retired += self._collect("evict", commit)
+        timed_out += self._expire_active(now)
         return {"admitted": admitted, "decoded": self._delivered,
                 "chunked": chunked, "retired": retired + timed_out,
                 "timed_out": timed_out}
@@ -2246,7 +2247,8 @@ class ServingEngine:
                 floats = _upload(at, _sampler_floats(req))
             return _upload(at, ints), floats, req.rng_key
 
-    def _guarded_prefill(self, req: Request, fn, args, bucket: int):
+    def _guarded_prefill(self, req: Request, fn, args, bucket: int,
+                         first: bool):
         """One prefill device call through the fault-injection hook;
         stores the returned cache in the cache manager and returns the
         first token with the stream's carry key and the call's program
@@ -2254,7 +2256,10 @@ class ServingEngine:
         model's own predicate on the call's shape (``paged_write.
         page_writes``): the pages a pool and layer that the program
         writes a page at a time, 0 where it writes a row at a time; the
-        metrics count the calls each way. Deliberately NOT
+        metrics count the calls each way; ``first`` marks the call a
+        bucket's program was minted for (the one that traces and compiles
+        it or loads it from the cache: the ``jit.*`` spans inside this
+        one say which, docs/OBSERVABILITY.md). Deliberately NOT
         under the hung-tick watchdog: prefill calls legitimately include
         fresh-bucket XLA compiles (seconds), and replay recovery
         re-prefills through here — a watchdog here would misread every
@@ -2266,7 +2271,9 @@ class ServingEngine:
         program = self._next_program()
         pages = paged_write.page_writes(1, bucket, self.page_size)
         with span("serving.prefill", request=req.id, bucket=bucket,
-                  program=program, page_writes=pages):
+                  program=program, page_writes=pages) as at:
+            if first:
+                at["first"] = True
             faults.on_serving_prefill(attempt, req.id)
             with self._mesh_context():
                 cache, tok, carry_key = fn(*args)
@@ -2304,7 +2311,8 @@ class ServingEngine:
         lands on the last prompt token."""
         bucket = self._bucket_rows(len(suffix), shared)
         fn = self._prefill_jits.get(bucket)
-        if fn is None:
+        first = fn is None
+        if first:
             fn = self._prefill_jits[bucket] = \
                 self._make_paged_prefill(bucket)
         if not self.cache_manager.prepare_span(lane, shared, len(suffix)):
@@ -2316,7 +2324,7 @@ class ServingEngine:
             self.cache_manager.lane_tables(lane))
         args = (self.params, self.cache_manager.cache, ints, floats, key)
         tok, carry_key, program = self._guarded_prefill(
-            req, fn, args, bucket=bucket)
+            req, fn, args, bucket=bucket, first=first)
         return None if replay else (tok, carry_key, floats, program)
 
     def _claim_storage(self, req: Request) -> int:

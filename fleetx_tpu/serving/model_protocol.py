@@ -354,9 +354,12 @@ def device_counters_of(engine):
             return {}
         eng._settle("other")
         try:
-            # and the pool by class of page, which is host state
+            # and the pool by class of page, which is host state, as is
+            # start-up's work count: the buckets whose prefill program the
+            # engine has minted (each traced and compiled, or loaded, once)
             return {**eng.executor.counters(eng.cache_manager.cache),
-                    **eng.cache_manager.class_counters()}
+                    **eng.cache_manager.class_counters(),
+                    "prefill_programs": len(eng._prefill_jits)}
         except RuntimeError as err:
             # a scrape from another thread met a cache buffer the running
             # tick had just donated: nothing to report now, and the event

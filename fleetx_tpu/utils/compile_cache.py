@@ -43,8 +43,17 @@ def enable_compile_cache() -> Optional[str]:
     Also drops jax's size/time floors so every program is kept — the
     small per-bucket serving jits are many, and re-compiling each of them
     cold is what a chip run would otherwise pay on every call — and makes
-    op_names and source lines part of the key (below)."""
+    op_names and source lines part of the key (below).
+
+    Whatever the backend, the first call also puts the program's listener
+    on jax's compile events (``fleetx_tpu/obs/compiles.py``: every trace,
+    lowering, compile and cache load from here on is a span with its
+    program's name, and seconds on a counter)."""
     import jax
+
+    from fleetx_tpu.obs import compiles
+
+    compiles.install()
 
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:

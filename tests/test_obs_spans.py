@@ -167,19 +167,22 @@ def test_decode_only_tick_has_one_leaf_span_per_phase(ticked):
     assert names.count("serving.tick") == 1
     assert not _named(spans, "serving.admit")
     tick = _named(spans, "serving.tick")[0]
-    for name in ("serving.expire", "serving.grow", "serving.decode",
-                 "serving.fetch", "serving.emit"):
+    # the two deadline sweeps are the tick's own time, under no span
+    assert not _named(spans, "serving.expire")
+    for name in ("serving.grow", "serving.decode", "serving.fetch",
+                 "serving.emit"):
         found = _named(spans, name)
         assert found, name
         assert all(s.parent == "serving.tick" for s in found), name
         assert all(tick.start_s <= s.start_s and s.end_s <= tick.end_s
                    for s in found), name
-    # the snapshot before the tick and the metrics block after it are the
-    # tick's siblings; the re-commit after the delivered tokens is the tick's
+    # the snapshot before the tick is the tick's sibling, the metrics
+    # block after it lies under no span; the re-commit after the delivered
+    # tokens is the tick's
     snapshot, commit = _named(spans, "serving.snapshot")
-    (observe,) = _named(spans, "serving.observe")
+    assert not _named(spans, "serving.observe")
     assert snapshot.parent is None and snapshot.end_s <= tick.start_s
-    assert observe.parent is None and observe.start_s >= tick.end_s
+    assert spans[-1] is tick
     # ONE emit span for sixteen lanes, never one per lane
     (emit,) = _named(spans, "serving.emit")
     assert commit.parent == "serving.tick" and commit.start_s >= emit.end_s
@@ -192,6 +195,61 @@ def test_decode_only_tick_has_one_leaf_span_per_phase(ticked):
                            "reads": decode.attrs["program"] - 1}
     assert len(spans) - names.count("serving.tick") \
         - names.count("serving.decode") <= 10
+
+
+def test_construction_is_one_span_that_holds_what_it_compiled():
+    from fleetx_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()  # what every entry point calls before its first jit
+    rec = get_recorder()
+    rec.clear()
+    eng = _engine()
+    spans = rec.spans()
+    (build,) = _named(spans, "serving.build")
+    assert build.parent is None and spans[-1] is build
+    assert build.attrs == {} and eng._prefill_jits == {}
+    # what construction compiled lies inside it and names it as parent
+    # (the weights' and the pool's bytes are gauges: `_publish_quant_
+    # metrics`); no span of a tick was opened
+    inside = [s for s in spans if s.name.startswith("jit.")
+              and s.end_s >= build.start_s]  # (the model's init came before)
+    assert inside and all(s.parent == "serving.build" and build.start_s <= s.start_s
+               and s.end_s <= build.end_s for s in inside)
+    assert {s.name.split(".")[0] for s in spans} == {"serving", "jit"}
+    assert not _named(spans, "serving.tick")
+
+
+def test_a_buckets_first_prefill_says_so_and_holds_its_compile():
+    from fleetx_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    eng = _engine()
+    rng = np.random.default_rng(1)
+    rec = get_recorder()
+    rec.clear()
+    for n in (3, 4, 7, 6, 2):  # buckets 4, 4, 8, 8, 4
+        eng.submit(rng.integers(1, 60, n, dtype=np.int32), max_length=n + 2)
+    eng.drain()
+    spans = rec.spans()
+    prefills = _named(spans, "serving.prefill")
+    assert [(s.attrs["bucket"], s.attrs.get("first")) for s in prefills] == [
+        (4, True), (4, None), (8, True), (8, None), (4, None)]
+    assert eng.metrics.snapshot()["prefill_programs"] == 2
+    compiles = [s for s in _named(spans, "jit.compile")
+                if s.attrs["fun_name"] == "jit(prefill)"]
+    assert len(compiles) == 2
+    for compile_, first in zip(compiles, [prefills[0], prefills[2]]):
+        # found by thread and time, named as its parent
+        assert compile_.parent == "serving.prefill"
+        assert compile_.thread_id == first.thread_id
+        assert first.start_s <= compile_.start_s
+        assert compile_.end_s <= first.end_s
+    # the tick that compiled is the jit.compile's parent: serving.decode
+    # carries no attr for it
+    (tick,) = [s for s in _named(spans, "jit.compile")
+               if s.attrs["fun_name"] == "jit(_decode_fn)"]
+    assert tick.parent == "serving.decode"
+    assert not any("first" in s.attrs for s in _named(spans, "serving.decode"))
 
 
 def test_the_wait_for_the_device_is_in_fetch_not_in_decode(ticked):
@@ -443,6 +501,50 @@ def test_fit_spans_the_batch_the_fetches_and_the_log_line(tmp_path):
     assert [s.parent for s in logs] == ["train.callback"] * 2
     for step, fetch in zip(_named(spans, "train.step"), fetches[::2]):
         assert step.end_s <= fetch.start_s  # train.step ends at dispatch
+
+
+def test_trainer_construction_restore_and_first_step_are_spans(tmp_path):
+    from fleetx_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    trainer, data = _trainer_and_data(tmp_path)
+    rec = get_recorder()
+    rec.clear()
+    trainer.fit(data)
+    spans = rec.spans()
+    (build,) = _named(spans, "train.build")
+    assert build.parent is None and build.attrs == {}
+    assert not _named(spans, "train.restore")
+    # the init program compiled inside the construction span
+    assert [s for s in _named(spans, "jit.compile")
+            if s.parent == "train.build" and s.attrs["fun_name"] == "jit(_init)"]
+    steps = _named(spans, "train.step")
+    assert [s.attrs for s in steps] == [{"step": 0, "first": True},
+                                        {"step": 1}]
+    (compiled,) = [s for s in _named(spans, "jit.compile")
+                   if s.attrs["fun_name"] == "jit(train_step)"
+                   and s.parent == "train.step"]
+    assert steps[0].start_s <= compiled.start_s
+    assert compiled.end_s <= steps[0].end_s
+    # the first log line asks for the step's AOT twin: this jax finds the
+    # trace, the lowering and the executable in memory, so nothing is
+    # built under train.log (no second lowering)
+    assert not [s for s in spans if s.name in ("jit.lower", "jit.compile")
+                and s.parent == "train.log"
+                and "train_step" in s.attrs["fun_name"]]
+    trainer.save()
+    trainer.wait_for_checkpoints()
+
+    resumed, _ = _trainer_and_data(tmp_path)
+    rec.clear()
+    resumed.init_state(data[0])
+    spans = rec.spans()
+    (build,) = _named(spans, "train.build")
+    assert build.attrs == {"restored": True}
+    (restore,) = _named(spans, "train.restore")
+    assert restore.parent == "train.build"
+    assert build.start_s <= restore.start_s and restore.end_s <= build.end_s
+    assert int(resumed.state.step) == 2
 
 
 # ------------------------------------------------- scopes on the programs
