@@ -2149,14 +2149,15 @@ class ServingEngine:
         are never read."""
         return tokens if self._state_rows else None
 
-    def _first_token(self, logits, true_len, eos, min_new, greedy,
+    def _first_token(self, logits, wants, eos, min_new, greedy,
                      temperature, top_k, top_p, key):
         """Traced tail of every prefill body: sample the first token from
-        the logits of the last true prompt position (EOS suppressed while
-        ``min_new`` > 0)."""
-        with jax.named_scope("sampler"):
-            last = jax.lax.dynamic_slice_in_dim(
-                logits[0], true_len - 1, 1, axis=0).astype(jnp.float32)
+        ``logits`` ``[1, 1, vocab]``, those of the last true prompt
+        position, the one row the forward was asked for (EOS suppressed
+        while ``min_new`` > 0). A call that wants no token (``wants``
+        false: an intermediate chunk, a replay) runs no sampler and
+        returns token 0, which nobody reads."""
+        def sample(last):
             vocab = last.shape[-1]
             last = jnp.where(
                 (jnp.arange(vocab)[None, :] == eos) & (min_new > 0),
@@ -2164,6 +2165,11 @@ class ServingEngine:
             return self.executor.sample(
                 last, key[None], greedy[None], temperature[None],
                 top_k[None], top_p[None], topk_cap=self.topk_cap)[0]
+
+        with jax.named_scope("sampler"):
+            return jax.lax.cond(
+                wants, sample, lambda last: jnp.zeros((), jnp.int32),
+                logits[0].astype(jnp.float32))
 
     def _make_paged_prefill(self, bucket_len: int):
         """Jitted paged prefill-on-insert for prompt SUFFIXES bucketed to
@@ -2180,9 +2186,10 @@ class ServingEngine:
 
         def prefill(params, cache, ints, floats, key):
             # as _prefill_ints packed them
-            true_len, wpos, eos, min_new, greedy, top_k = ints[:6]
-            table = ints[6:6 + n_table].reshape(table_shape)
-            suffix = ints[6 + n_table:]
+            true_len, wpos, eos, min_new, greedy, top_k, wants = ints[:7]
+            wants = wants != 0
+            table = ints[7:7 + n_table].reshape(table_shape)
+            suffix = ints[7 + n_table:]
             # the admission's one split of the request's stream: the
             # sampler's key, and the carry the lane install takes (the
             # bits of the eager split; a replay call drops both)
@@ -2199,10 +2206,13 @@ class ServingEngine:
                 self._row_mask((jnp.arange(bucket_len) < true_len)[None]),
                 cache_positions=wpos[None],
                 # [pages] -> [1, pages]; two classes [2, pages] -> [2, 1, .]
-                block_tables=jnp.expand_dims(table, -2))
+                block_tables=jnp.expand_dims(table, -2),
+                # the head runs on the one row the sampler reads, and on
+                # none in a call that wants no token
+                logit_rows=jnp.where(wants, true_len - 1, -1)[None])
             cache = self._pin_cache(cache)
             return cache, self._first_token(
-                logits, true_len, eos, min_new, greedy != 0, floats[0],
+                logits, wants, eos, min_new, greedy != 0, floats[0],
                 top_k, floats[1], step_key), carry_key
 
         return jax.jit(
@@ -2211,17 +2221,20 @@ class ServingEngine:
     @staticmethod
     def _prefill_ints(tokens, bucket: int, wpos: int, table, *,
                       eos: int = -1, min_new: int = 0, greedy: bool = True,
-                      top_k: int = 0) -> np.ndarray:
-        """The int32 operand of a prefill call, built on the host: six
+                      top_k: int = 0, wants_token: bool = False) -> np.ndarray:
+        """The int32 operand of a prefill call, built on the host: seven
         scalars, the lane's table row (of every class), then ``tokens``
-        right-padded to ``bucket``. The defaults are a replay's inert
-        sampler: greedy argmax, no filter, nothing suppressed (the result
-        is discarded)."""
+        right-padded to ``bucket``. ``wants_token`` says whether the
+        caller reads the call's token: the program runs head and sampler
+        only then. The defaults are a replay's: no token wanted, and the
+        inert sampler (greedy argmax, no filter, nothing suppressed)
+        beside it."""
         table = np.asarray(table, np.int32).ravel()
-        ints = np.zeros(6 + table.size + bucket, np.int32)
-        ints[:6] = len(tokens), wpos, eos, min_new, greedy, top_k
-        ints[6:6 + table.size] = table
-        ints[6 + table.size:][:len(tokens)] = tokens
+        ints = np.zeros(7 + table.size + bucket, np.int32)
+        ints[:7] = (len(tokens), wpos, eos, min_new, greedy, top_k,
+                    wants_token)
+        ints[7:7 + table.size] = table
+        ints[7 + table.size:][:len(tokens)] = tokens
         return ints
 
     def _prefill_args(self, req: Request, tokens, bucket: int, replay: bool,
@@ -2232,8 +2245,8 @@ class ServingEngine:
         with the prompt padded to its bucket, and an admission's float32
         pair): no device program runs here. Returns the operands after
         params and cache: ``(ints, floats, key)``. A replay call (K/V
-        only, also an intermediate chunk) takes the inert sampler, whose
-        float32 pair is the engine's resident constant."""
+        only, also an intermediate chunk) wants no token, and its float32
+        pair is the engine's resident constant."""
         with span("serving.prefill_args", request=req.id, bucket=bucket,
                   transfers=0) as at:
             if replay:
@@ -2243,7 +2256,7 @@ class ServingEngine:
                 ints = self._prefill_ints(
                     tokens, bucket, wpos, table, eos=req.eos_token_id,
                     min_new=req.min_new_tokens, greedy=req.greedy,
-                    top_k=req.top_k)
+                    top_k=req.top_k, wants_token=True)
                 floats = _upload(at, _sampler_floats(req))
             return _upload(at, ints), floats, req.rng_key
 
@@ -2305,10 +2318,12 @@ class ServingEngine:
         returns ``(first_token, carry_key, sampler_floats)``, all on the
         device, and the call's program number; replay returns None.
         Chunked prefill reuses this call verbatim — an intermediate
-        chunk is exactly a ``replay`` call (KV writes only, inert
-        sampler, no rng consumed) at its chunk's write offset, and the
-        final chunk is exactly an admission call whose ``true_len``
-        lands on the last prompt token."""
+        chunk is exactly a ``replay`` call (KV writes only: the program
+        runs neither head nor sampler, no rng consumed) at its chunk's
+        write offset, and the final chunk is exactly an admission call
+        whose ``true_len`` lands on the last prompt token. One compiled
+        program a bucket serves both: whether a token is wanted is an
+        operand (``_prefill_ints``)."""
         bucket = self._bucket_rows(len(suffix), shared)
         fn = self._prefill_jits.get(bucket)
         first = fn is None
@@ -2325,6 +2340,7 @@ class ServingEngine:
         args = (self.params, self.cache_manager.cache, ints, floats, key)
         tok, carry_key, program = self._guarded_prefill(
             req, fn, args, bucket=bucket, first=first)
+        self.metrics.record_prefill_call(wants_token=not replay)
         return None if replay else (tok, carry_key, floats, program)
 
     def _claim_storage(self, req: Request) -> int:

@@ -317,6 +317,17 @@ class ServingMetrics:
             "fleetx_serving_prefill_row_writes_total",
             "Prefill programs that wrote their keys and values a row at a "
             "time")
+        # whether a prefill program ran head and sampler: it does where its
+        # caller reads a token (an admission, a final chunk) and not where
+        # it only writes the cache (an intermediate chunk, a replay)
+        self._c_prefill_token_calls = counter(
+            "fleetx_serving_prefill_token_calls_total",
+            "Prefill calls that computed a first token: the head on the "
+            "one row sampled from, and the sampler")
+        self._c_prefill_headless_calls = counter(
+            "fleetx_serving_prefill_headless_calls_total",
+            "Prefill calls that computed no token: cache writes alone, "
+            "neither head nor sampler")
         # under a learned indexer (models/gpt/latent.py): the index keys the
         # queries scored and the rows they then attended over, a layer
         self._c_index_rows = counter(
@@ -433,6 +444,12 @@ class ServingMetrics:
         where it wrote a row at a time."""
         (self._c_prefill_page_writes if pages
          else self._c_prefill_row_writes).inc()
+
+    def record_prefill_call(self, wants_token: bool) -> None:
+        """One prefill call ran: with head and sampler for the token its
+        caller reads, or headless, the cache writes alone."""
+        (self._c_prefill_token_calls if wants_token
+         else self._c_prefill_headless_calls).inc()
 
     def record_selection(self, fields: dict) -> dict:
         """The span fields of a tick or a prefill call, counted where they
@@ -859,6 +876,11 @@ class ServingMetrics:
             # prefill programs by the unit of their cache write
             "prefill_page_writes": int(self._c_prefill_page_writes.value),
             "prefill_row_writes": int(self._c_prefill_row_writes.value),
+            # and by whether they computed a token (head and sampler)
+            "prefill_token_calls": int(
+                self._c_prefill_token_calls.value),
+            "prefill_headless_calls": int(
+                self._c_prefill_headless_calls.value),
             "index_rows_scored": int(self._c_index_rows.value),
             "rows_selected": int(self._c_selected_rows.value),
             # crash-safety story: how often the engine recovered, what it

@@ -191,11 +191,23 @@ class ModelExecutor:
         raise NotImplementedError
 
     def forward(self, params, cache, ids, positions, mask=None, *,
-                cache_positions=None, block_tables=None):
+                cache_positions=None, block_tables=None, logit_rows=None):
         """One cached forward: ``(logits, new_cache)``. Serves bucketed
         prefill (multi-token ``ids``) and the decode tick (one token per
         lane) through the same seam; ``cache_positions`` are per-lane
-        write offsets, ``block_tables`` the paged indirection."""
+        write offsets, ``block_tables`` the paged indirection.
+
+        ``logit_rows`` says which rows' logits the caller will read, and
+        the head runs on those alone. None (the default) is every row:
+        ``[b, rows, vocab]``, what the tick (one row a lane) and the
+        speculative verify call (every proposed row) read. An int32
+        ``[b]`` is ONE row a batch element: ``[b, 1, vocab]``, the row
+        sliced from the backbone's output before the head's product, so
+        no ``[rows, vocab]`` array exists (a prefill samples from its
+        last true row). A negative entry asks for no row: where every
+        entry is negative the head is not run (an intermediate chunk, a
+        replay) and the logits returned are not to be read. The cache
+        written is the same whatever the rows."""
         raise NotImplementedError
 
     def sample(self, logits, keys, greedy, temperature, top_k, top_p, *,
@@ -309,14 +321,20 @@ class GPTExecutor(ModelExecutor):
         return init_decode_cache(self.model, batch)
 
     def forward(self, params, cache, ids, positions, mask=None, *,
-                cache_positions=None, block_tables=None):
+                cache_positions=None, block_tables=None, logit_rows=None):
         import jax
 
         from fleetx_tpu.models.gpt.generation import decode_step
+        from fleetx_tpu.models.gpt.head import row_logits_step
 
         # device-trace scope: under it the layer scan's own slices and
         # updates move the KV cache (docs/OBSERVABILITY.md, parts)
         with jax.named_scope("cached_forward"):
+            if logit_rows is not None:  # body and head apart
+                return row_logits_step(
+                    self.model, params, cache, ids, positions, mask,
+                    cache_positions=cache_positions,
+                    block_tables=block_tables, logit_rows=logit_rows)
             return decode_step(self.model, params, cache, ids, positions,
                                mask, cache_positions=cache_positions,
                                block_tables=block_tables)

@@ -491,3 +491,144 @@ def test_host_store_survives_recovery(tiny):
         one_shot_tokens(model, params, p.astype(np.int32), 4,
                         gen_cfg=GREEDY),
         err_msg="post-recovery revived decode")
+
+
+# ----------------------------------- the head follows what the caller reads
+
+# stack -> GPTConfig fields, one for each module that holds a stack: the
+# block of models/gpt/model.py under the tied word table, hybrid.py's
+# grouped heads with window layers (an ``lm_head`` of its own),
+# mixed_stack.py's operators of two kinds (conv state beside the pages),
+# latent.py's latent attention
+_HEAD_STACKS = dict(
+    _KERNEL_STEP_STACKS,
+    mixed=dict(
+        num_layers=3, num_attention_heads=8, num_key_value_heads=2,
+        ffn_hidden_size=32, dense_ffn_hidden_size=96, num_dense_layers=1,
+        layer_types=("conv", "full_attention", "conv"), conv_L_cache=3,
+        num_experts=8, gate="sigmoid_topk", top_k=2, norm_topk_prob=True,
+        routed_scaling_factor=1.0, use_expert_bias=True,
+        position_embedding="rope", norm="rmsnorm", mlp_act="swiglu",
+        use_bias=False, qk_norm=True, qk_norm_scope="head",
+        tie_word_embeddings=True, expert_mode=True, family="lfm2"),
+    latent=dict(
+        num_layers=2, num_attention_heads=4, ffn_hidden_size=32,
+        position_embedding="rope", norm="rmsnorm", mlp_act="swiglu",
+        use_bias=False, tie_word_embeddings=False,
+        layer_types=("latent_attention",) * 2, num_dense_layers=2,
+        dense_ffn_hidden_size=96, q_lora_rank=24, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16))
+_VOCAB, _BUCKET = 61, 8
+
+
+def _stack_engine(stack, **kw):
+    cfg = GPTConfig(
+        vocab_size=_VOCAB, hidden_size=32, max_position_embeddings=64,
+        hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
+        dtype=jnp.float32, use_flash_attention=False, **_HEAD_STACKS[stack])
+    model = GPTForPretraining(cfg)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    return _engine(model, params, cache_len=64, prefill_chunk=_BUCKET,
+                   prefill_bucket=_BUCKET, **kw)
+
+
+def _vocab_wide(jaxpr):
+    """``(primitive, shape)`` of every value a program computes whose last
+    axis is the vocabulary, through every nested program (the layer scan,
+    both sides of a conditional)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        found += [(eqn.primitive.name, tuple(v.aval.shape))
+                  for v in eqn.outvars
+                  if getattr(v.aval, "shape", ())[-1:] == (_VOCAB,)]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _vocab_wide(sub)
+    return found
+
+
+@pytest.mark.parametrize("stack", list(_HEAD_STACKS))
+def test_prefill_program_applies_the_head_to_the_one_row_it_samples(stack):
+    """The prefill program of a bucket of 8 rows holds ONE head product, of
+    one row, and no value of ``rows x vocab`` elements anywhere: everything
+    as wide as the vocabulary is one row (the logits, the sampler's
+    passes over them). The reader reads what it is meant to: a program
+    around the executor's default ``forward`` holds the ``[1, 8, vocab]``
+    product the prefill program held before."""
+    eng = _stack_engine(stack)
+    cache = eng.cache_manager.cache
+    ints = eng._prefill_ints((), _BUCKET, 0, eng.cache_manager.lane_tables(0))
+    wide = _vocab_wide(jax.make_jaxpr(eng._make_paged_prefill(_BUCKET))(
+        eng.params, cache, ints, eng._inert_floats,
+        jax.random.PRNGKey(0)).jaxpr)
+    assert [s for p, s in wide if p == "dot_general"] == [(1, 1, _VOCAB)]
+    assert wide and all(int(np.prod(s)) == _VOCAB for _, s in wide), wide
+
+    table = jnp.expand_dims(jnp.asarray(eng.cache_manager.lane_tables(0)), -2)
+    every = _vocab_wide(jax.make_jaxpr(lambda params, cache: eng.executor.forward(
+        params, cache, jnp.zeros((1, _BUCKET), jnp.int32),
+        jnp.arange(_BUCKET)[None], eng._row_mask(jnp.ones((1, _BUCKET), bool)),
+        cache_positions=jnp.zeros((1,), jnp.int32),
+        block_tables=table))(eng.params, cache).jaxpr)
+    assert ("dot_general", (1, _BUCKET, _VOCAB)) in every
+
+
+def _first_token_state(eng, prompt, **sampling):
+    """Admit ``prompt`` alone and stop when its first token is out: the
+    token, the lane's rng carry, and every position's cache rows by leaf
+    (``[layers, pages, page, width]`` read through the lane's table)."""
+    rid = eng.submit(prompt, max_length=4, **sampling)
+    while not eng.emitted_tokens(rid):
+        eng.step()
+    slot = next(iter(eng._active))
+    table = eng.cache_manager.lane_tables(slot)
+    rows = [np.asarray(leaf)[:, table].reshape(
+        leaf.shape[0], -1, leaf.shape[-1])[:, :len(prompt)]
+        for leaf in jax.tree.leaves(eng.cache_manager.cache)
+        if leaf.ndim == 4]
+    return (eng.emitted_tokens(rid)[0], np.asarray(eng._state["rng"][slot]),
+            rows)
+
+
+@pytest.mark.parametrize("sampling", [
+    {}, dict(decode_strategy="sampling", temperature=0.9, top_k=5, seed=11)],
+    ids=["greedy", "sampled"])
+def test_headless_chunks_leave_the_one_call_prefills_state(tiny, sampling):
+    """A prompt of 19 tokens in chunks of 8, 8 and 3, of which the first
+    two want no token (their programs run neither head nor sampler),
+    against the same prompt in one call: the same first token, the same
+    rng carry (only the call that samples splits the request's stream)
+    and the same rows in the lane's pages."""
+    model, params = tiny
+    prompt = np.random.RandomState(3).randint(1, 61, (19,)).astype(np.int32)
+    one = _engine(model, params, slots=1)
+    tok, rng, rows = _first_token_state(one, prompt, **sampling)
+    chunked = _engine(model, params, slots=1, prefill_chunk=8)
+    c_tok, c_rng, c_rows = _first_token_state(chunked, prompt, **sampling)
+    snap = chunked.metrics.snapshot()
+    assert (snap["prefill_headless_calls"],
+            snap["prefill_token_calls"]) == (2, 1)
+    assert one.metrics.snapshot()["prefill_headless_calls"] == 0
+    assert c_tok == tok
+    np.testing.assert_array_equal(c_rng, rng)
+    assert rows and len(rows) == len(c_rows)
+    for a, b in zip(c_rows, rows):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("stack", list(_HEAD_STACKS))
+def test_a_call_that_wants_no_token_writes_the_same_cache(stack):
+    """One program a bucket (the flag is an operand, not a shape): the
+    same jitted prefill, called for no token and for one, leaves every
+    cache leaf bit for bit the same and hands back the same rng carry."""
+    eng = _stack_engine(stack)
+    lane, _ = eng.cache_manager.alloc(0, np.arange(1, 8, dtype=np.int32))
+    assert eng.cache_manager.prepare_span(lane, 0, 7)
+    fn = eng._make_paged_prefill(_BUCKET)
+    outs = [fn(eng.params, eng.cache_manager.cache, eng._prefill_ints(
+        np.arange(1, 8), _BUCKET, 0, eng.cache_manager.lane_tables(lane),
+        wants_token=wants), eng._inert_floats, jax.random.PRNGKey(4))
+        for wants in (False, True)]
+    (cache, tok, carry), (t_cache, _, t_carry) = outs
+    assert int(tok) == 0
+    np.testing.assert_array_equal(carry, t_carry)
+    jax.tree.map(np.testing.assert_array_equal, cache, t_cache)

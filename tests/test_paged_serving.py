@@ -589,3 +589,47 @@ def test_pool_exhaustion_retires_cache_full(model_and_params):
     assert 0 < len(res[starved].tokens) < 20  # partial output kept
     assert eng.cache_manager.pages_in_use == 0
     assert eng.cache_manager.pool.free_pages == 4
+
+
+def test_prefill_calls_counted_by_whether_they_computed_a_token(
+        model_and_params):
+    """Every prefill call is counted once, by whether its caller read a
+    token (head and sampler ran) or not: a chunked admission is headless
+    chunks and one final chunk, an admission in one call one program with
+    a token, a replay (here a request admitted with its history, through
+    the replay seam) headless. ``snapshot()`` and ``/metrics`` agree."""
+    from fleetx_tpu.obs import get_registry
+
+    model, params = model_and_params
+    eng = _engine(model, params, slots=2, prefill_chunk=8, prefix_cache=False)
+    rng = np.random.RandomState(2)
+    chunked, short, moved = (rng.randint(1, 97, (n,)).astype(np.int32)
+                             for n in (19, 4, 6))
+
+    def counts():
+        snap = eng.metrics.snapshot()
+        return (snap["prefill_token_calls"],
+                snap["prefill_headless_calls"])
+
+    assert counts() == (0, 0)
+    eng.submit(chunked, max_length=2)       # chunks of 8, 8 and 3
+    eng.drain()
+    assert counts() == (1, 2)
+    rid = eng.submit(short, max_length=3)   # under a chunk: one call
+    tokens = list(eng.drain()[rid].tokens)
+    assert counts() == (2, 2)
+    full = eng.submit(moved, max_length=5)
+    stream = list(eng.drain()[full].tokens)
+    assert counts() == (3, 2)
+    rid = eng.submit(moved, max_length=5, history=stream[:2])
+    assert list(eng.drain()[rid].tokens) == stream
+    assert counts() == (3, 3)               # a replay computes no token
+    snap = eng.metrics.snapshot()
+    assert sum(counts()) == (snap["prefill_page_writes"]
+                             + snap["prefill_row_writes"])
+    assert snap["prefill_chunks"] == 3 and len(tokens) == 3
+    text = get_registry().prometheus_text()
+    lab = f'engine="{eng.metrics.engine_label}"'
+    assert f"fleetx_serving_prefill_token_calls_total{{{lab}}} 3" in text
+    assert (f"fleetx_serving_prefill_headless_calls_total{{{lab}}} 3"
+            in text)

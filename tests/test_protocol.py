@@ -281,3 +281,60 @@ def test_result_does_not_depend_on_batch_occupancy(zoo, kind, others):
     assert tick["admitted"] == 1 + others and tick["forwards"] == 1
     got = eng.drain()
     assert np.array_equal(got[rid].tokens, want)
+
+
+# --------------------------------------------- the executor's forward seam
+
+
+def _forward_operands(eng, rows=8, batch=2):
+    """A cached forward of ``batch`` sequences of ``rows`` tokens through
+    the engine's own pool: each sequence in pages of its own."""
+    pages = eng.cache_len // eng.page_size  # a lane's whole table row
+    tables = 1 + np.arange(batch * pages, dtype=np.int32).reshape(batch, -1)
+    ids = np.random.RandomState(5).randint(1, 61, (batch, rows))
+    pos = np.broadcast_to(np.arange(rows, dtype=np.int32), (batch, rows))
+    return (eng.params, eng.cache_manager.cache, jnp.asarray(ids, jnp.int32),
+            jnp.asarray(pos)), dict(
+        cache_positions=jnp.zeros((batch,), jnp.int32),
+        block_tables=jnp.asarray(tables))
+
+
+def test_forward_returns_every_row_by_default(zoo):
+    """The tick's and the verify call's contract: ``forward`` without
+    ``logit_rows`` is ``decode_step`` (one ``model.apply``, body and
+    head), every row's logits, bit for bit."""
+    from fleetx_tpu.models.gpt.generation import decode_step
+
+    eng = _make(zoo, "gpt")
+    args, kw = _forward_operands(eng)
+    logits, cache = jax.jit(
+        lambda *a: eng.executor.forward(*a, **kw))(*args)
+    want, want_cache = jax.jit(lambda params, cache, ids, pos: decode_step(
+        eng.executor.model, params, cache, ids, pos, **kw))(*args)
+    assert logits.shape == (2, 8, 61) and logits.dtype == jnp.float32
+    np.testing.assert_array_equal(logits, want)
+    jax.tree.map(np.testing.assert_array_equal, cache, want_cache)
+
+
+@pytest.mark.parametrize("rows", [(7, 2), (0, -1), (-1, -1)],
+                         ids=["a_row_each", "one_wants_none", "none_wanted"])
+def test_forward_applies_the_head_to_the_rows_asked_for(zoo, rows):
+    """One row a sequence: ``[b, 1, vocab]``, the logits the default gives
+    for that row (the product of one row where the default's is of all, so
+    to float32 rounding), and the same cache whatever was asked for. A
+    sequence that asks for none has no logits to read; where none does the
+    head is not run and zeros come back."""
+    eng = _make(zoo, "gpt")
+    args, kw = _forward_operands(eng)
+    every, want_cache = jax.jit(
+        lambda *a: eng.executor.forward(*a, **kw))(*args)
+    logits, cache = jax.jit(lambda *a: eng.executor.forward(
+        *a, logit_rows=jnp.asarray(rows, jnp.int32), **kw))(*args)
+    assert logits.shape == (2, 1, 61) and logits.dtype == jnp.float32
+    jax.tree.map(np.testing.assert_array_equal, cache, want_cache)
+    for b, row in enumerate(rows):
+        if row >= 0:
+            np.testing.assert_allclose(logits[b, 0], every[b, row],
+                                       rtol=1e-5, atol=1e-6)
+    if max(rows) < 0:
+        assert not np.asarray(logits).any()
