@@ -6,7 +6,9 @@ parameters (``layer_types``: gated short-convolution layers beside attention
 layers, Mamba-1 selective-scan layers beside attention layers, dense
 feed-forward layers before expert layers or throughout, a sigmoid router
 with a selection bias, window and full attention layers side by side with an
-output gate and norms after each part as well as before). ``GPTConfig``
+output gate and norms after each part as well as before, double layers
+whose expert layer leaves the stream at the first half and lands after the
+second, a softmax router over routed and zero-compute experts). ``GPTConfig``
 inherits them, ``check`` is the part of
 its ``__post_init__`` that refuses what nobody wrote, ``layer_class`` picks
 the layer that runs the first group (``models/gpt/hybrid.py``) and
@@ -33,6 +35,8 @@ LAYOUT_FIELDS = ("rope_layout", "sliding_window_layout", "layer_types")
 # the operators a layer of ``layer_types`` can name, under the source's names
 LAYER_TYPES = ("conv", "mamba", "full_attention", "sliding_attention",
                "latent_attention")
+# the gates that choose under a selection bias (``use_expert_bias``)
+_BIAS_GATES = ("sigmoid_topk", "softmax_bias_topk")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -154,6 +158,30 @@ class BlockLayoutFields:
     n_group: int = 1
     topk_group: int = 1
     num_shared_experts: int = 0
+    # ---- gate "softmax_bias_topk" (parallel/moe_share.py): scores
+    # ``softmax(router(x))`` in float32 over ``num_routed_experts +
+    # num_zero_experts`` outputs; the ``top_k`` largest of ``score +
+    # expert_bias`` chosen (``use_expert_bias``: the CHOICE only); weights
+    # the chosen RAW scores times ``routed_scaling_factor`` (over their sum
+    # under ``norm_topk_prob``). The last ``num_zero_experts`` outputs are
+    # ZERO-COMPUTE experts: one chosen returns its input, weighed; it holds
+    # no weights, takes no row of the grouped matmuls and is computed where
+    # the token lives
+    num_zero_experts: int = 0
+    # ---- shortcut-connected feed-forward parts (LongCat-Flash; every entry
+    # of ``layer_types`` then is one HALF of a double layer, a
+    # ``latent_attention``): every half runs its attention and a dense MLP
+    # of ``dense_ffn_hidden_size``; at an EVEN half the expert layer reads
+    # the dense MLP's normed input and its output LEAVES the stream's path,
+    # to LAND on the stream after the next (odd) half's dense MLP. The
+    # experts' stack has ``num_layers // 2`` entries and no norm of its own
+    moe_shortcut: bool = False
+    # latent attention: the queries times ``sqrt(hidden_size /
+    # q_lora_rank)`` after ``W_qb``, the compressed keys/values times
+    # ``sqrt(hidden_size / kv_lora_rank)`` after their low-rank norm (what
+    # the cache then holds); the rotary key is not scaled
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
 
     @property
     def kv_heads(self) -> int:
@@ -186,11 +214,36 @@ class BlockLayoutFields:
         return self.num_routed_experts or self.num_experts
 
     @property
+    def router_width(self) -> int:
+        """The router's outputs: the routed experts and, after them, the
+        zero-compute ones."""
+        return self.routed_experts + self.num_zero_experts
+
+    @property
     def expert_share(self) -> bool:
         """Whether the expert layers are parallel/moe_share.py's: a held
-        share, a group-limited choice or a shared expert."""
+        share, a group-limited choice, a shared expert, zero-compute
+        experts or the softmax gate that chooses under a bias."""
         return bool(self.num_routed_experts or self.n_group > 1
-                    or self.num_shared_experts)
+                    or self.num_shared_experts or self.num_zero_experts
+                    or self.gate == "softmax_bias_topk")
+
+    @property
+    def expert_layers(self) -> int:
+        """The stack's expert layers: one a double layer under
+        ``moe_shortcut``, else every layer after the leading dense ones."""
+        if self.moe_shortcut:
+            return self.num_layers // 2
+        return self.num_layers - self.num_dense_layers
+
+    @property
+    def mla_scales(self) -> Tuple[float, float]:
+        """``(s_q, s_kv)``: what latent attention multiplies its queries
+        and its compressed keys/values by (1.0: not at all)."""
+        return tuple(
+            float(np.sqrt(self.hidden_size / rank)) if on else 1.0
+            for on, rank in ((self.mla_scale_q_lora, self.q_lora_rank),
+                             (self.mla_scale_kv_lora, self.kv_lora_rank)))
 
     @property
     def rows_span_field(self) -> str:
@@ -227,8 +280,7 @@ class BlockLayoutFields:
         other layer."""
         if not (self.expert_mode and self.expert_share):
             return {}
-        return {"pairs": rows * self.top_k
-                * (self.num_layers - self.num_dense_layers)}
+        return {"pairs": rows * self.top_k * self.expert_layers}
 
     def decode_kernel_steps(self, lanes: int, head_shards: int = 1) -> int:
         """Span field ``kernel_steps`` of a decode tick of ``lanes`` lanes:
@@ -401,16 +453,23 @@ def _check_mixed(cfg) -> None:
             "qk_norm over the whole projection with grouped heads, a head "
             "size of its own or mixed layers: no test covers it "
             "(qk_norm_scope: head is the per-head norm)")
-    sigmoid = cfg.expert_mode and cfg.gate == "sigmoid_topk"
-    if sigmoid and not 1 <= cfg.top_k <= cfg.routed_experts:
-        raise ValueError(f"top_k {cfg.top_k} of {cfg.routed_experts} experts")
-    if (cfg.use_expert_bias or cfg.expert_bias_init_std) and not sigmoid:
+    biased = cfg.expert_mode and cfg.gate in _BIAS_GATES
+    if biased and not 1 <= cfg.top_k <= cfg.router_width:
+        raise ValueError(f"top_k {cfg.top_k} of {cfg.router_width} experts")
+    if (cfg.use_expert_bias or cfg.expert_bias_init_std) and not biased:
         raise ValueError("use_expert_bias (expert_bias_init_std) without "
-                         "gate: sigmoid_topk, the gate that reads the bias")
-    if sigmoid and not cfg.layer_types:
+                         "gate: sigmoid_topk | softmax_bias_topk, the gates "
+                         "that read the bias")
+    if biased and not cfg.layer_types:
         raise NotImplementedError(
-            "gate: sigmoid_topk without layer_types: the stack of "
+            f"gate: {cfg.gate} without layer_types: the stack of "
             "models/gpt/mixed_stack.py is the one that runs it")
+    if cfg.num_zero_experts < 0 or (cfg.num_zero_experts and not (
+            cfg.expert_mode and cfg.gate == "softmax_bias_topk")):
+        raise ValueError(
+            f"num_zero_experts {cfg.num_zero_experts} without gate: "
+            "softmax_bias_topk over layer_types, the gate that weighs a "
+            "zero-compute expert (parallel/moe_share.py)")
     if not 0 <= cfg.num_dense_layers <= cfg.num_layers:
         raise ValueError(f"num_dense_layers {cfg.num_dense_layers} of "
                          f"num_layers {cfg.num_layers}")
@@ -430,20 +489,28 @@ def _check_mixed(cfg) -> None:
             raise ValueError("num_dense_layers / dense_ffn_hidden_size "
                              "without layer_types")
         if (cfg.attention_gate != "none" or cfg.sandwich_norm
-                or cfg.embedding_multiplier != 1.0):
+                or cfg.embedding_multiplier != 1.0 or cfg.moe_shortcut):
             raise NotImplementedError(
-                "attention_gate / sandwich_norm / embedding_multiplier "
-                "without layer_types: the stack of models/gpt/mixed_stack.py "
-                "is the one that runs them")
+                "attention_gate / sandwich_norm / embedding_multiplier / "
+                "moe_shortcut without layer_types: the stack of "
+                "models/gpt/mixed_stack.py is the one that runs them")
+        scales = [n for n in ("mla_scale_q_lora", "mla_scale_kv_lora")
+                  if getattr(cfg, n)]
+        if scales:
+            raise ValueError(f"{scales} without a latent_attention layer")
         return
-    if bool(cfg.num_dense_layers) != bool(cfg.dense_ffn_hidden_size):
+    _check_shortcut(cfg)
+    if not cfg.moe_shortcut and (
+            bool(cfg.num_dense_layers) != bool(cfg.dense_ffn_hidden_size)):
         raise ValueError("num_dense_layers and dense_ffn_hidden_size (the "
                          "dense layers' width) come together")
     if cfg.num_dense_layers < cfg.num_layers and not (
-            cfg.expert_mode and cfg.gate in ("softmax_topk", "sigmoid_topk")):
+            cfg.expert_mode
+            and cfg.gate in ("softmax_topk", *_BIAS_GATES)):
         raise ValueError(
             "layer_types: the layers after num_dense_layers are dropless "
-            "experts (gate: softmax_topk | sigmoid_topk, num_experts > 1)")
+            "experts (gate: softmax_topk | sigmoid_topk | softmax_bias_topk, "
+            "num_experts > 1)")
     if cfg.mlp_act != "swiglu" or cfg.use_bias or cfg.norm != "rmsnorm":
         raise NotImplementedError(
             "layer_types with mlp_act other than swiglu, with biases or "
@@ -488,20 +555,52 @@ def _check_mixed(cfg) -> None:
         raise NotImplementedError("router_input with layer_types")
 
 
+def _check_shortcut(cfg) -> None:
+    """``moe_shortcut``: halves of double layers, each refusal with the
+    field's name."""
+    if not cfg.moe_shortcut:
+        return
+    others = sorted(set(cfg.layer_types) - {"latent_attention"})
+    if others or cfg.sliding_window:
+        raise NotImplementedError(
+            f"moe_shortcut beside {others or 'sliding_window'}: every half "
+            "of a shortcut-connected double layer is a latent_attention "
+            "layer; no test covers the shortcut beside a recurrent kind or "
+            "a window")
+    if cfg.num_layers % 2 or cfg.num_dense_layers or not (
+            cfg.dense_ffn_hidden_size):
+        raise ValueError(
+            f"moe_shortcut over num_layers {cfg.num_layers}, "
+            f"num_dense_layers {cfg.num_dense_layers}, dense_ffn_hidden_size "
+            f"{cfg.dense_ffn_hidden_size}: an even number of halves, no "
+            "leading dense layer, and the width of the dense MLP every half "
+            "runs")
+    if not (cfg.expert_mode and cfg.expert_share):
+        raise NotImplementedError(
+            "moe_shortcut without an expert layer of parallel/moe_share.py "
+            "(num_routed_experts / num_zero_experts / gate: "
+            "softmax_bias_topk): no test covers it")
+
+
 def _check_latent(cfg) -> None:
     """The fields of latent attention and of an expert layer that holds a
     share, each refusal with the field's name."""
     share = cfg.expert_share
-    if share and not (cfg.expert_mode and cfg.gate == "sigmoid_topk"):
+    if share and not (cfg.expert_mode and cfg.gate in _BIAS_GATES):
         raise ValueError(
             "num_routed_experts / n_group / num_shared_experts without "
-            "gate: sigmoid_topk over layer_types (parallel/moe_share.py)")
+            "gate: sigmoid_topk | softmax_bias_topk over layer_types "
+            "(parallel/moe_share.py)")
     first, count = cfg.experts_held
     if share and not (0 <= first and first + count <= cfg.routed_experts
-                      and cfg.top_k <= cfg.routed_experts):
+                      and cfg.top_k <= cfg.router_width):
         raise ValueError(
             f"experts held [{first}, {first + count}) and top_k {cfg.top_k} "
             f"of num_routed_experts {cfg.routed_experts}")
+    if cfg.gate == "softmax_bias_topk" and cfg.n_group > 1:
+        raise NotImplementedError(
+            f"n_group {cfg.n_group} under gate: softmax_bias_topk: no test "
+            "covers a group limit over softmax scores")
     if cfg.n_group < 1 or cfg.routed_experts % cfg.n_group or not (
             1 <= cfg.topk_group <= cfg.n_group) or (
             cfg.n_group > 1 and cfg.routed_experts // cfg.n_group < 2):
@@ -513,7 +612,8 @@ def _check_latent(cfg) -> None:
         widths = [n for n in ("q_lora_rank", "kv_lora_rank",
                               "qk_nope_head_dim", "qk_rope_head_dim",
                               "v_head_dim", "index_n_heads", "index_head_dim",
-                              "index_topk") if getattr(cfg, n)]
+                              "index_topk", "mla_scale_q_lora",
+                              "mla_scale_kv_lora") if getattr(cfg, n)]
         if widths:
             raise ValueError(f"{widths} without a latent_attention layer")
         return
@@ -536,6 +636,10 @@ def _check_latent(cfg) -> None:
     if cfg.rope_scaling_factor < 1.0:
         raise ValueError(f"rope_scaling_factor {cfg.rope_scaling_factor}")
     sizes = (cfg.index_n_heads, cfg.index_head_dim, cfg.index_topk)
+    if any(sizes) and (cfg.mla_scale_q_lora or cfg.mla_scale_kv_lora):
+        raise NotImplementedError(
+            "mla_scale_q_lora / mla_scale_kv_lora under a learned indexer "
+            "(index_topk): no test covers it")
     if any(sizes) and (min(sizes) < 1
                        or cfg.index_head_dim < cfg.qk_rope_head_dim):
         raise ValueError(

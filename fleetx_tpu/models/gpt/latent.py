@@ -5,8 +5,12 @@ body: ``layer_types`` entries ``latent_attention``
 **The operator**, a token ``x``: ``c_q = RMSNorm(x W_qa)``; ``q = c_q W_qb``,
 a head ``[q_nope | q_r]``; ``[c_kv | k_r] = x W_kva``; ``c_kv = RMSNorm
 (c_kv)``; ``q_r, k_r`` rotated (ONE rotary key for all heads), the angles
-YaRN's (:func:`yarn_frequencies`). **The cache holds ``c_kv`` and ``k_r``
-and nothing else**: the pool's two leaves ``cached_key`` (``c_kv``,
+YaRN's (:func:`yarn_frequencies`). Under ``mla_scale_q_lora`` /
+``mla_scale_kv_lora`` (LongCat-Flash) ``q`` is multiplied by ``sqrt(hidden /
+q_lora_rank)`` and the normed ``c_kv`` by ``sqrt(hidden / kv_lora_rank)``,
+each in float32 before its cast; ``k_r`` is not, and the pool holds ``c_kv``
+as scaled, which is what both forms below read. **The cache holds ``c_kv``
+and ``k_r`` and nothing else**: the pool's two leaves ``cached_key`` (``c_kv``,
 ``kv_lora_rank`` a row) and ``cached_value`` (``k_r``, ``qk_rope_head_dim``
 a row, held in the 128 lanes a tile has: :func:`rope_leaf_width`), flat over
 the layers under the ONE block table, written by
@@ -164,8 +168,11 @@ def rope_leaf_width(cfg: GPTConfig) -> int:
     return -(-cfg.qk_rope_head_dim // 128) * 128
 
 
-def _latent_norm(cfg: GPTConfig, name: str):
-    return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype,
+def _latent_norm(cfg: GPTConfig, name: str, scaled: bool = False):
+    """A low-rank norm; ``scaled``: its output stays float32 for the scale
+    that follows (``mla_scale_kv_lora``), which casts it."""
+    return nn.RMSNorm(epsilon=cfg.norm_eps,
+                      dtype=jnp.float32 if scaled else cfg.dtype,
                       param_dtype=jnp.float32, name=name)
 
 
@@ -200,8 +207,9 @@ class LatentAttention(nn.Module):
         w_kvb = self._weight("kv_b_proj", (c, nh, nope + vd),
                              (None, "heads", "kv"))
         w_o = self._weight("out_proj", (nh, vd, h), ("heads", "kv", "embed"))
+        s_q, s_kv = cfg.mla_scales
         q_norm, kv_norm = (_latent_norm(cfg, "q_a_norm"),
-                           _latent_norm(cfg, "kv_a_norm"))
+                           _latent_norm(cfg, "kv_a_norm", scaled=s_kv != 1.0))
         if cfg.indexed:
             ni, di = cfg.index_n_heads, cfg.index_head_dim
             w_iq = self._weight("index_q_proj", (qr, ni, di),
@@ -222,11 +230,18 @@ class LatentAttention(nn.Module):
                              "angles (LatentStack computes them)")
         with jax.named_scope("mla_proj"):
             c_q = q_norm(x @ w_qa)
-            q = jnp.einsum("bsr,rhd->bshd", c_q, w_qb)
+            if s_q == 1.0:
+                q = jnp.einsum("bsr,rhd->bshd", c_q, w_qb)
+            else:  # (scaled in float32, before the product is cast)
+                q = (jnp.einsum("bsr,rhd->bshd", c_q, w_qb,
+                                preferred_element_type=jnp.float32)
+                     * s_q).astype(cfg.dtype)
             q = jnp.concatenate(
                 [q[..., :nope], apply_rope(q[..., nope:], rope)], axis=-1)
             latent = x @ w_kva
             ckv = kv_norm(latent[..., :c])
+            if s_kv != 1.0:  # (the norm's float32 output: ONE rounding)
+                ckv = (ckv * s_kv).astype(cfg.dtype)
             kr = _rotated_key(latent[..., c:], rope)
         if cfg.indexed:
             with jax.named_scope("dsa_index"):
@@ -596,7 +611,7 @@ class LatentStack(MixedStack):
                 "a contiguous decode cache over latent attention (one-shot "
                 "generate()): serve the model through ServingEngine, whose "
                 "page pool holds the latents")
-        from fleetx_tpu.parallel.moe import MOE_STATS
+        from fleetx_tpu.parallel.moe_share import stats_words
 
         ps = cfg.decode_page_size
         fresh = not self.has_variable("cache", "cached_key")
@@ -609,7 +624,7 @@ class LatentStack(MixedStack):
                 (1, ps, rope_leaf_width(cfg)), cfg.dtype),
             "moe_stats": self.variable(
                 "cache", "moe_stats", jnp.zeros,
-                (max(plan["counts"]["experts"], 1), 2 * len(MOE_STATS) * 2),
+                (max(plan["counts"]["experts"], 1), stats_words(cfg)),
                 jnp.uint32),
         }
         if cfg.indexed:

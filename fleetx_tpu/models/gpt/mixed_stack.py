@@ -82,6 +82,17 @@ part's output before it joins the stream (``sandwich_norm``: a third stack
 ``post_norm`` beside every kind's ``norm`` and ``op``; device scope
 ``post_norm``) and scale the embedding rows (``embedding_multiplier``).
 
+**Shortcut-connected feed-forward parts** (``moe_shortcut``, over latent
+attention layers: LongCat-Flash). Every entry of ``layer_types`` is then one
+HALF of a double layer: each half runs its attention and a dense MLP, the
+expert layer reads the dense MLP's normed input at an EVEN half (its stack
+holds no norm of its own) and its output LEAVES the stream's path, to LAND on
+the dense MLP's output of the NEXT half (scope ``moe_shortcut``). It is one
+more ``[b, s, h]`` in the scan's carry, there only where the configuration
+asks: a stack without it traces the program it traced before
+(``tests/test_longcat_serving.py`` holds their jaxprs to digests), and the
+body still holds each kind once.
+
 Forward only: training this stack is ROADMAP R5.
 """
 
@@ -117,16 +128,24 @@ def layer_plan(cfg: GPTConfig) -> dict:
     ``num_layers`` entries: ``attention`` (1: attention, 0: the stack's
     recurrent kind), ``operator_index``, ``experts`` (1: expert layer, 0:
     dense), ``ffn_index``; the counts of every kind; and ``recurrent``, the
-    name of the recurrent kind ("conv" | "mamba", None without one)."""
+    name of the recurrent kind ("conv" | "mamba", None without one).
+
+    Under ``moe_shortcut`` every entry is one HALF of a double layer: each
+    runs the dense MLP (``ffn_index`` its place among ALL halves) and
+    ``experts`` marks the EVEN halves, where the expert layer leaves
+    (``shortcut_index`` its place in the experts' own stack, ``N`` entries
+    for ``2N`` halves)."""
     attention = np.asarray([t.endswith("attention") for t in cfg.layer_types])
     recurrent = next((t for t in ("mamba", "conv") if t in cfg.layer_types),
                      None)
-    experts = np.arange(cfg.num_layers) >= cfg.num_dense_layers
+    halves = np.arange(cfg.num_layers)
+    experts = (halves % 2 == 0 if cfg.moe_shortcut
+               else halves >= cfg.num_dense_layers)
 
     def place(mask):  # the layer's index among the layers of its own kind
         return np.where(mask, np.cumsum(mask) - 1, np.cumsum(~mask) - 1)
 
-    return {"attention": attention.astype(np.int32),
+    plan = {"attention": attention.astype(np.int32),
             "operator_index": place(attention).astype(np.int32),
             "experts": experts.astype(np.int32),
             "ffn_index": place(experts).astype(np.int32),
@@ -136,6 +155,11 @@ def layer_plan(cfg: GPTConfig) -> dict:
                        "attention": int(attention.sum()),
                        "dense": int((~experts).sum()),
                        "experts": int(experts.sum())}}
+    if cfg.moe_shortcut:
+        plan.update(ffn_index=halves.astype(np.int32),
+                    shortcut_index=(halves // 2).astype(np.int32))
+        plan["counts"]["dense"] = cfg.num_layers
+    return plan
 
 
 def state_rows(cfg: GPTConfig) -> int:
@@ -385,9 +409,14 @@ class MixedStack(nn.Module):
             count = plan["counts"][name]
             if not count:
                 continue
+            # (under the shortcut the experts read the dense MLP's normed
+            # input: their stack holds no norm)
+            normless = cfg.moe_shortcut and name == "experts"
             params[name] = self.param(
-                name, lambda rng, m=module, n=count, a=args, k=kwargs: {
-                    "norm": _stacked(_norm(cfg), n, rng, a[0]),
+                name, lambda rng, m=module, n=count, a=args, k=kwargs,
+                normless=normless: {
+                    **({} if normless else {
+                        "norm": _stacked(_norm(cfg), n, rng, a[0])}),
                     "op": _stacked(m, n, rng, *a, **k),
                     **({"post_norm": _stacked(_norm(cfg), n, rng, a[0])}
                        if cfg.sandwich_norm else {})})
@@ -421,7 +450,7 @@ class MixedStack(nn.Module):
                 "a contiguous decode cache over layers with a convolution "
                 "state (one-shot generate()): serve the model through "
                 "ServingEngine, whose page pool holds both kinds of state")
-        from fleetx_tpu.parallel.moe import MOE_STATS
+        from fleetx_tpu.parallel.moe_share import stats_words
 
         ps, rows = cfg.decode_page_size, state_rows(cfg)
         if ps % rows:
@@ -455,8 +484,7 @@ class MixedStack(nn.Module):
         held.update({
             "moe_stats": self.variable(
                 "cache", "moe_stats", jnp.zeros,
-                (max(counts["experts"], 1), 2 * len(MOE_STATS) * 2),
-                jnp.uint32),
+                (max(counts["experts"], 1), stats_words(cfg)), jnp.uint32),
         })
         return None if fresh else held
 
@@ -728,17 +756,24 @@ class MixedStack(nn.Module):
             return (*pick(mixes, both, attend, lambda: (
                 joins(recurrent, index, recur()), {})), pools)
 
+        # (``a`` of the two ``_on`` functions is a thunk of the part's normed
+        # input: the weights' slices are traced before it, as they were when
+        # each kind normed its own input inline)
+        def dense_on(a, index):
+            return kinds["dense"][0].apply(
+                {"params": _at(params["dense"]["op"], index)}, a())
+
         def dense(value, index, stats):
-            y = kinds["dense"][0].apply(
-                {"params": _at(params["dense"]["op"], index)},
-                normed("dense", index, value))
+            y = dense_on(lambda: normed("dense", index, value), index)
             # where an expert layer gives its routing a dense layer gives
             # zeros of the same shapes: the two are branches of one conditional
             return joins("dense", index, y), stats, (zeros_like_of(
                 lambda: experts(value, index, stats)[2])
                 if counts["experts"] else {})
 
-        def experts(value, index, stats):
+        def experts_on(a, index, stats):
+            """The expert layer ``index`` of its own stack on the normed
+            stream ``a()``: its output, the counters, what it sowed."""
             held = params["experts"]["op"]
             variables = {"params": _at(held, index)}
             mutable = ["routing"] if probed else []
@@ -747,7 +782,7 @@ class MixedStack(nn.Module):
                 mutable.append("cache")
             with jax.named_scope("moe_mlp"):
                 y, mut = kinds["experts"][0].apply(
-                    variables, normed("experts", index, value),
+                    variables, a(),
                     decode=cached, layer_index=index if cached else None,
                     expert_stack=tuple(held[k] for k in (
                         "w_gate", "w_up", "w_down")) if cached else None,
@@ -756,7 +791,40 @@ class MixedStack(nn.Module):
                 stats = mut["cache"]["moe_stats"]
             sown = ({k: v[0] for k, v in mut["routing"].items()}
                     if probed else {})
+            return y, stats, sown
+
+        def experts(value, index, stats):
+            y, stats, sown = experts_on(
+                lambda: normed("experts", index, value), index, stats)
             return joins("experts", index, y), stats, sown
+
+        def shortcut_ffn(value, layer, stats, shortcut):
+            """A half's feed-forward part under ``moe_shortcut``: the dense
+            MLP on ``a``, the half's normed stream; at an even half the
+            expert layer reads the same ``a`` and its output LEAVES as the
+            new ``shortcut``; at an odd half the shortcut carried LANDS on
+            the dense MLP's output (scope ``moe_shortcut``). Returns what
+            joins the stream, the counters, what was sown, the shortcut."""
+            index = jnp.asarray(plan["ffn_index"])[layer]
+            leaving = jnp.asarray(plan["experts"])[layer] == 1
+            a = normed("dense", index, value)
+
+            def leaves(stats, shortcut):
+                del shortcut
+                return experts_on(
+                    lambda: a,
+                    jnp.asarray(plan["shortcut_index"])[layer], stats)
+
+            def stays(stats, shortcut):
+                return shortcut, stats, zeros_like_of(
+                    lambda: leaves(stats, shortcut)[2])
+
+            shortcut, stats, sown = jax.lax.cond(leaving, leaves, stays,
+                                                 stats, shortcut)
+            y = dense_on(lambda: a, index)
+            with jax.named_scope("moe_shortcut"):
+                y = y + _landing(leaving, shortcut)
+            return y, stats, sown, shortcut
 
         def body(carry, layer):
             value, pools = carry[0], dict(carry[1])
@@ -769,24 +837,43 @@ class MixedStack(nn.Module):
                         jnp.asarray(plan["operator_index"])[layer], pools)
                 value = value + y
                 with jax.named_scope("mlp"):
-                    y, stats, sown = pick(
-                        jnp.asarray(plan["experts"])[layer] == 1,
-                        (counts["experts"], counts["dense"]), experts, dense,
-                        value, jnp.asarray(plan["ffn_index"])[layer], stats)
+                    if cfg.moe_shortcut:
+                        y, stats, sown, shortcut = shortcut_ffn(
+                            value, layer, stats, carry[2])
+                    else:
+                        y, stats, sown = pick(
+                            jnp.asarray(plan["experts"])[layer] == 1,
+                            (counts["experts"], counts["dense"]), experts,
+                            dense, value,
+                            jnp.asarray(plan["ffn_index"])[layer], stats)
                 if cached:
                     pools["moe_stats"] = stats
-            return (_constrain_act(value + y, cfg), pools), (sown, seen)
+            value = _constrain_act(value + y, cfg)
+            # the carry gains the shortcut only where the configuration asks
+            return ((value, pools, shortcut) if cfg.moe_shortcut
+                    else (value, pools)), (sown, seen)
 
-        (x, pools), (sown, seen) = jax.lax.scan(
-            body, (x, pools), jnp.arange(cfg.num_layers, dtype=jnp.int32))
+        (x, pools, *_), (sown, seen) = jax.lax.scan(
+            body, (x, pools, jnp.zeros_like(x)) if cfg.moe_shortcut
+            else (x, pools), jnp.arange(cfg.num_layers, dtype=jnp.int32))
         for name, leaf in pools.items():
             cache[name].value = leaf
         if probed:  # the expert layers' rows, as a layer scan would stack them
+            expert_rows = (slice(0, None, 2) if cfg.moe_shortcut
+                           else slice(cfg.num_dense_layers, None))
             for name, leaf in sown.items():
-                self.sow("routing", name, leaf[cfg.num_dense_layers:])
+                self.sow("routing", name, leaf[expert_rows])
             for name, leaf in seen.items():
                 self.sow("routing", name, leaf)
         return x
+
+
+def _landing(leaving, shortcut):
+    """What of the shortcut lands on a half's dense MLP output: all of it
+    at an odd half, nothing at the even half it has just left at (a
+    function of its own: ``perfbench/probe_longcat.py`` plants a fault
+    here)."""
+    return jnp.where(leaving, jnp.zeros_like(shortcut), shortcut)
 
 
 def _pages(cfg: GPTConfig, tables, pos, index):

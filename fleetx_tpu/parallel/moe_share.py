@@ -25,11 +25,30 @@ for one chip of a deployment whose every layer is divided over several
 - **Shared experts** (``num_shared_experts`` x ``ffn_hidden_size`` wide, one
   gated MLP) see every token and are added once (scope ``moe_shared``).
 
+- **Gate ``softmax_bias_topk``** (LongCat-Flash): the scores are
+  ``softmax(x W_g)`` in float32 over ``num_routed_experts +
+  num_zero_experts`` outputs, the choice is the ``top_k`` largest of ``score
+  + expert_bias`` (ONE choice function, :func:`group_limited_topk`, with one
+  group), and the weights are the chosen RAW scores times
+  ``routed_scaling_factor`` (not renormalised unless ``norm_topk_prob``).
+- **Zero-compute experts** are the router's LAST ``num_zero_experts``
+  outputs: one chosen returns its input, weighed. It holds no weights and
+  needs no exchange, so it is computed where the token lives: HERE, for
+  every token of the call, whatever share of the routed experts is held
+  (as a shared expert is). Its number lies past every held expert's, so
+  ``held_row_layout`` gives it no row, no tile and no kernel step; its part
+  of the result is ``(sum of the chosen zero experts' weights) * x``, under
+  the scope ``moe_zero``.
+
 The counters in the cache's ``moe_stats`` leaf count what THIS program did:
 the pairs laid out here and the held experts that had a row
 (``GPTExecutor.counters`` reads them as ``moe_{tick,prefill}_pairs`` and
 ``_experts_read``); all the pairs routed are ``rows x top_k`` of a call,
-which the host knows. The collection ``routing`` holds the input, ALL
+which the host knows. With zero-compute experts the leaf is ``ZERO_WORDS``
+wider (:func:`stats_words`): the pairs whose expert is zero-compute, of
+ticks and of prefills (``moe_{tick,prefill}_zero_pairs``), and the most
+and the fewest routed (non-zero) experts any ONE row of a tick chose
+(``moe_tick_routed_pairs_max`` / ``_min``: what a token costs varies). The collection ``routing`` holds the input, ALL
 ``top_k`` experts chosen (by their routed number), their weights and the
 output, for whoever holds the layer to a reference.
 
@@ -43,9 +62,39 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from fleetx_tpu.models.gpt import model as gpt_model
-from fleetx_tpu.parallel.moe import DroplessMoEMLP, _running_count
+from fleetx_tpu.parallel.moe import (
+    MOE_STATS,
+    DroplessMoEMLP,
+    _running_count,
+)
 
-__all__ = ["SharedMoEMLP", "group_limited_topk", "held_row_layout"]
+__all__ = ["SharedMoEMLP", "ZERO_WORDS", "group_limited_topk",
+           "held_row_layout", "stats_words", "zero_counters"]
+
+# the words a configuration with zero-compute experts adds to a layer's
+# ``moe_stats``, after ``MOE_STATS``'s: the zero pairs of ticks and of
+# prefills (two words each, low then high), then the most routed
+# (non-zero) experts and the most zero-compute experts one row of a tick
+# chose (a maximum each: top_k less the second is the fewest routed)
+ZERO_WORDS = 6
+
+
+def stats_words(cfg) -> int:
+    """Words of one layer's ``moe_stats``."""
+    return 2 * len(MOE_STATS) * 2 + (ZERO_WORDS if cfg.num_zero_experts else 0)
+
+
+def zero_counters(words, top_k: int) -> dict:
+    """The counters of the ``ZERO_WORDS`` words ``[layers, ZERO_WORDS]``
+    (``serving/model_protocol.py`` adds them to ``MOE_STATS``'s)."""
+    import numpy as np
+
+    words = np.asarray(words).astype(np.uint64)
+    pairs = (words[:, 0:4:2] + (words[:, 1:4:2] << np.uint64(32))).sum(0)
+    return {"moe_tick_zero_pairs": int(pairs[0]),
+            "moe_prefill_zero_pairs": int(pairs[1]),
+            "moe_tick_routed_pairs_max": int(words[:, 4].max()),
+            "moe_tick_routed_pairs_min": top_k - int(words[:, 5].max())}
 
 
 def _shared_expert(t, gate, up, down):
@@ -60,6 +109,23 @@ def _weighed(scores, ranked):
     (``ranked`` carries the selection bias; a fault is planted here)."""
     del ranked
     return scores
+
+
+def _scored(logits, gate: str):
+    """The router's scores, float32: ``sigmoid`` of each output, or under
+    ``softmax_bias_topk`` the softmax over ALL of them (zero-compute
+    experts among them; a fault is planted here)."""
+    if gate == "softmax_bias_topk":
+        return jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    return jax.nn.sigmoid(logits).astype(jnp.float32)  # (whatever its type)
+
+
+def _zero_experts(tokens, weights, zero):
+    """What the chosen zero-compute experts give: each its input, weighed,
+    so ``(sum of their weights) * x``, float32 (``zero`` ``[n, k]`` marks
+    them; a fault is planted here)."""
+    by = jnp.where(zero, weights, 0.0).sum(-1, keepdims=True)
+    return by * tokens.astype(jnp.float32)
 
 
 def group_limited_topk(scores, top_k: int, n_group: int, topk_group: int,
@@ -131,8 +197,9 @@ class SharedMoEMLP(DroplessMoEMLP):
         b, s, h = x.shape
         (first, held_n), routed = cfg.experts_held, cfg.routed_experts
         k, f, n, dt = cfg.top_k, cfg.ffn_size, b * s, cfg.dtype
+        width = cfg.router_width      # the zero-compute experts come last
         router = nn.DenseGeneral(
-            features=routed, use_bias=False, dtype=jnp.float32,
+            features=width, use_bias=False, dtype=jnp.float32,
             param_dtype=jnp.float32, precision=jax.lax.Precision.HIGHEST,
             kernel_init=nn.with_logical_partitioning(
                 gpt_model.default_kernel_init, ("embed", None)),
@@ -146,7 +213,7 @@ class SharedMoEMLP(DroplessMoEMLP):
         w_gate = weight("w_gate", (held_n, h, f), ("expert", "embed", "mlp"))
         w_up = weight("w_up", (held_n, h, f), ("expert", "embed", "mlp"))
         w_down = weight("w_down", (held_n, f, h), ("expert", "mlp", "embed"))
-        bias = self._expert_bias(routed) if cfg.use_expert_bias else None
+        bias = self._expert_bias(width) if cfg.use_expert_bias else None
         shared_f = cfg.num_shared_experts * f
         if shared_f:
             shared = [weight(name, shape, axes) for name, shape, axes in (
@@ -160,12 +227,11 @@ class SharedMoEMLP(DroplessMoEMLP):
         kernel = (decode and expert_stack is not None
                   and cfg.use_flash_attention and kernels_enabled())
         # the tile of the pairs a share sees were routing even
-        tm = (moe_gmm.row_tile(max(n * k * held_n // routed, 1), held_n)
+        tm = (moe_gmm.row_tile(max(n * k * held_n // width, 1), held_n)
               if kernel else 1)
         tokens = x.reshape(n, h)
         with jax.named_scope("moe_route"):
-            scores = jax.nn.sigmoid(router(tokens.astype(jnp.float32)))
-            scores = scores.astype(jnp.float32)  # (whatever the router's)
+            scores = _scored(router(tokens.astype(jnp.float32)), cfg.gate)
             # (without a bias, called as ever: a probe plants its own here)
             topk_idx = group_limited_topk(
                 scores, k, cfg.n_group, cfg.topk_group,
@@ -187,6 +253,9 @@ class SharedMoEMLP(DroplessMoEMLP):
             self.sow("routing", "weights", weights.reshape(b, s, k))
         # (the pairs laid out HERE, which only the device knows)
         self._count(sizes, sizes.sum(), s, decode, layer_index)
+        if cfg.num_zero_experts:
+            zero = topk_idx >= routed
+            self._count_zero(zero, s, decode, layer_index)
         with jax.named_scope("moe_experts"):
             if kernel:
                 w_gate, w_up, w_down = (w.astype(dt) for w in expert_stack)
@@ -212,7 +281,36 @@ class SharedMoEMLP(DroplessMoEMLP):
                 gate, up, down = (w.astype(dt) for w in shared)
                 t = tokens.astype(dt)
                 y = y + _shared_expert(t, gate, up, down).astype(jnp.float32)
+        if cfg.num_zero_experts:
+            with jax.named_scope("moe_zero"):
+                y = y + _zero_experts(tokens, weights, zero)
         y = y.astype(dt).reshape(b, s, h)
         if probed:
             self.sow("routing", "output", y)
         return y
+
+    def _count_zero(self, zero, seq: int, decode: bool, layer_index):
+        """Add the call's zero-compute pairs (``zero`` ``[n, k]`` bool) to
+        the layer's ``ZERO_WORDS`` of ``moe_stats``, as ``_count`` adds its
+        own; of a tick also the most routed and the most zero-compute
+        experts one row chose."""
+        if not decode or not self.has_variable("cache", "moe_stats"):
+            return
+        stats = self.get_variable("cache", "moe_stats")
+        layer = () if layer_index is None else (layer_index,)
+
+        def word(i):  # the layer scan carries the whole stack [L, words]
+            return (*layer, 2 * len(MOE_STATS) * 2 + i)
+
+        with jax.named_scope("moe_route"):
+            each = zero.sum(-1).astype(jnp.uint32)             # a row's
+            low = 0 if seq == 1 else 2
+            was = stats[word(low)]
+            now = was + each.sum()                 # wraps at 2**32 ...
+            value = stats.at[word(low)].set(now).at[word(low + 1)].add(
+                (now < was).astype(jnp.uint32))    # ... into the high word
+            if seq == 1:
+                value = value.at[word(4)].max(
+                    jnp.uint32(zero.shape[-1]) - each.min()).at[word(5)].max(
+                        each.max())
+            self.put_variable("cache", "moe_stats", value)

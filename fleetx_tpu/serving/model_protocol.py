@@ -292,7 +292,11 @@ class GPTExecutor(ModelExecutor):
                   if "moe_stats" in jax.tree_util.keystr(path)]
         if not leaves:
             return {}
-        words = np.concatenate([np.asarray(leaf).astype(np.uint64).reshape(
+        base = 2 * len(MOE_STATS) * 2      # (a leaf's first words; the rest
+        # are the zero-compute experts', parallel/moe_share.py)
+        leaves = [np.asarray(leaf).reshape(-1, leaf.shape[-1])
+                  for leaf in leaves]
+        words = np.concatenate([leaf[:, :base].astype(np.uint64).reshape(
             -1, 2, len(MOE_STATS), 2) for leaf in leaves])
         per_layer = words[..., 0] + (words[..., 1] << np.uint64(32))
         stats = per_layer.sum(axis=0)            # [ticks | prefills, stat]
@@ -308,6 +312,12 @@ class GPTExecutor(ModelExecutor):
             # the calls (each weighted by the pairs it routed)
             out[f"moe_{kind}_load_max_over_mean"] = (
                 largest * experts / pairs if pairs else 0.0)
+        if leaves[0].shape[1] > base:
+            from fleetx_tpu.parallel.moe_share import zero_counters
+
+            out.update(zero_counters(
+                np.concatenate([leaf[:, base:] for leaf in leaves]),
+                int(self.model.cfg.top_k)))
         return out
 
     def resident_params(self, params):
