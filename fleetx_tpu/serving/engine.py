@@ -66,9 +66,38 @@ loop, the metrics block after the tick and the caller's work between two
   recovery computes them again and nothing is emitted twice. The
   snapshot re-commits after every delivery, so tokens that were emitted
   stay emitted whatever fails later in the step.
-- an admission still waits for its prefill's first token before the lane
-  install (``_finish_first_token``); that wait also covers the tick in
-  flight before it.
+- an admission's first token stays in flight the same way
+  (``_dispatch_first_token`` / ``_read_first_tokens``): the lane install
+  takes the prefill's token as the device scalar the program returned and
+  decides the lane's ``active`` bit from it on the device, so the install
+  is dispatched right behind the prefill and the host reads the token
+  (``serving.first_token``: TTFT, the callback, finish / park / activate)
+  only after a LATER program has been dispatched: the next admission's
+  prefill in the same ``step()``, or the step's tick. The device's line
+  reads prefill A, install A, prefill B, install B, ..., tick with no
+  hole. Between install and read the admission is an entry of
+  ``_first_tokens`` and, unless ``max_new_tokens`` <= 1, already in the
+  active set, where the tick's dispatch counts its unread token as it
+  counts the unread tick's (``pending_of``). NONE is unread when
+  ``step()`` returns: what nothing was dispatched behind is read at the
+  end of the step (``first_tokens_flushed_idle``), so whatever acts on
+  exact state outside a step finds the host where it always was. An
+  engine that reads every tick at once (``_sync_cause``) reads every first
+  token right after its install, and so does, at most once every
+  ``_PROBE_PERIOD_S``, the first admission of a step
+  (``first_tokens_flushed_probe``): the sample of admissions whose
+  ``serving.admit`` span holds its own wait, for readers of an admission's
+  host time as the span less that wait. A first token that turns out to
+  be EOS is learnt one dispatch late: the install left the lane inactive, the
+  tick already dispatched carries the slot in its ``lanes`` map and its
+  token for it is dropped like any other departed request's. A device
+  error of prefill A surfaces at B's dispatch or at A's read, and is A's
+  failed prefill (one strike for A); B, dispatched behind it and unread,
+  is dropped with the rollback and re-admitted from the queue's head. The
+  snapshot holds an admission whose token is unread as NOT yet admitted
+  and re-bases after each read. ``metrics.snapshot()`` counts
+  ``first_tokens_overlapped`` against ``first_tokens_flushed`` (by cause);
+  ``serving.first_token`` carries ``overlapped=0|1``.
 
 Cache storage is PAGED, the engine's one layout: K/V live in a shared
 ``[num_pages, page_size, heads*head_dim]`` pool, each request holds a
@@ -280,6 +309,7 @@ Crash safety (docs/RESILIENCE.md serving-recovery):
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import functools
@@ -311,7 +341,11 @@ from fleetx_tpu.serving.cache_manager import (
     window_lane_pages,
 )
 from fleetx_tpu.resilience.faults import faults
-from fleetx_tpu.serving.inflight import InflightTick, pending_of
+from fleetx_tpu.serving.inflight import (
+    InflightFirstToken,
+    InflightTick,
+    pending_of,
+)
 from fleetx_tpu.serving.metrics import ServingMetrics
 from fleetx_tpu.serving.scheduler import FIFOScheduler, Request
 from fleetx_tpu.serving.spec import build_proposer
@@ -368,6 +402,15 @@ def _env_float(name: str, default: float) -> float:
         return float(os.environ.get(name, default))
     except ValueError:
         return default
+
+
+# How often an admission's first token is read AT ONCE, inside its own
+# ``serving.admit`` span as before PR 55 (``first_tokens_flushed_probe``):
+# with first tokens in flight no admission's span holds its own wait, and
+# a reader that takes an admission's host time as the span less that wait
+# (perfbench's ``admit_host_ms_p50``) needs some that do. Each one costs the
+# chip the 3-4 ms of idle the others save: at most 0.8% of the wall clock.
+_PROBE_PERIOD_S = 0.5
 
 
 # An admission's operands cross to the device PACKED, one host-built
@@ -772,6 +815,10 @@ class ServingEngine:
         # "Tick order"), and the lanes whose tokens this step() delivered
         self._inflight: Optional[InflightTick] = None
         self._delivered = 0
+        # admissions whose lane is installed and whose first token is
+        # unread, oldest first; empty whenever step() has returned
+        self._first_tokens: collections.deque = collections.deque()
+        self._probed_at = self._now()  # the last admission read at once
         # programs dispatched so far (_next_program)
         self._programs = 0
         self._state = self._replicate(self._init_state())
@@ -789,6 +836,9 @@ class ServingEngine:
         # leave the committed cache/state buffers untouched
         self._probe_jit = jax.jit(self._decode_fn, static_argnums=(4,))
         self._admit_jit = jax.jit(self._admit_fn, donate_argnums=())
+        # the install's token operand where the host packs the token itself
+        # (a replay, a shipped admission): resident, never read
+        self._no_token = jax.device_put(np.zeros((), np.int32))
         # a replay call's temperature and top_p: inert, and resident
         self._inert_floats = jax.device_put(np.ones(2, np.float32))
         self._deactivate_jit = jax.jit(_deactivate)
@@ -1103,8 +1153,8 @@ class ServingEngine:
         batched decode step dispatched and the one before it read
         (module docstring "Tick order"), retirements, active-deadline
         sweep. ``commit`` re-bases the transactional snapshot after each
-        completed phase (see :meth:`step`): an admission, a chunk, a
-        tick's tokens delivered. With chunking enabled the
+        completed phase (see :meth:`step`): an admission whose first token
+        was read, a chunk, a tick's tokens delivered. With chunking enabled the
         tick's prefill budget is ONE chunk-sized device call — a chunk
         of the in-flight prompt or one short admission — so decode never
         stalls longer (the ``prefill_stall_ms`` histogram measures it)."""
@@ -1122,9 +1172,29 @@ class ServingEngine:
         else:
             while (len(self.scheduler)
                    and self._can_admit(self.scheduler.peek())):
-                self._admit(self.scheduler.pop_next())
+                req = self.scheduler.pop_next()
+                try:
+                    self._admit(req)
+                except Exception:
+                    # a device error of an EARLIER prefill can surface at
+                    # this dispatch: it is the failed prefill of the oldest
+                    # admission whose token no longer comes back
+                    lost = next((first for first in self._first_tokens
+                                 if not self._readable(first.tok)), None)
+                    if lost is not None:
+                        self._fault_ctx = self._fault_of(lost)
+                    raise
                 admitted += 1
-                commit()  # an admission that completed stays admitted
+                # the admission before this one is read now, with this
+                # one's programs behind it on the device: an admission
+                # whose token was read stays admitted
+                self._read_first_tokens(commit, keep=1)
+                if not (self._first_tokens
+                        and self._first_tokens[-1].req is req):
+                    # it completed inside _admit (a replay, a shipped one,
+                    # a first chunk, a read at once: _sync_cause, probe);
+                    # one left unread is to the snapshot still in the queue
+                    commit()
                 if self.prefill_chunk:
                     break  # one prefill-shaped device call per tick
         if admitted or chunked:
@@ -1136,6 +1206,9 @@ class ServingEngine:
                 retired = self._tick_decode_spec(commit)
         elif self._active or self._inflight is not None:
             retired = self._tick_decode(commit)
+        # no tick was dispatched behind what is still unread (no lane
+        # left to decode for): nothing stays unread when step() returns
+        self._read_first_tokens(commit, cause="idle")
         # fresh clock: prefill/decode above may have eaten the deadline
         now = self._now()
         if self._inflight is not None and self._overdue(now):
@@ -1256,12 +1329,19 @@ class ServingEngine:
         consumed donated buffers, so rollback restores host truth and
         :meth:`recover` rebuilds the device side from it. Metrics stay
         monotonic (a rolled-back tick's gauge samples are not unwound)."""
-        reqs = (list(self.scheduler.snapshot()) + list(self._active.values())
+        # an admission whose first token is unread is NOT yet admitted: a
+        # rollback drops its programs with the device state and puts it
+        # back at the queue's head, as it was before its admission
+        unread = [first.req for first in self._first_tokens]
+        active = {slot: r for slot, r in self._active.items()
+                  if not any(r is u for u in unread)}
+        reqs = (list(self.scheduler.snapshot()) + list(active.values())
                 + list(self._prefilling.values())
                 + list(self._prefilled.values()))
         return {
             "queue": self.scheduler.snapshot(),
-            "active": dict(self._active),
+            "unread": unread,
+            "active": active,
             "prefilling": dict(self._prefilling),
             "prefilled": dict(self._prefilled),
             "results": dict(self._results),
@@ -1277,11 +1357,15 @@ class ServingEngine:
             # advanced
             "reqs": [(r, r.slot, r.admit_time, r.first_token_time,
                       len(r.tokens), r.prefill_pos, r.phase,
-                      r.spec_proposed, r.spec_accepted) for r in reqs],
+                      r.spec_proposed, r.spec_accepted) for r in reqs]
+            + [(r, None, None, None, 0, 0, "queued", r.spec_proposed,
+                r.spec_accepted) for r in unread],
         }
 
     def _restore(self, snap) -> None:
         self.scheduler.restore(snap["queue"])
+        for req in reversed(snap["unread"]):
+            self.scheduler.requeue(req)
         self._active = snap["active"]
         self._prefilling = snap["prefilling"]
         self._prefilled = snap["prefilled"]
@@ -1303,8 +1387,11 @@ class ServingEngine:
         committed); the queue and every request are exactly pre-tick."""
         ctx, self._fault_ctx = self._fault_ctx, None
         # the unread tick goes with the device state it came from: its
-        # tokens never reached host truth, so replay computes them again
+        # tokens never reached host truth, so replay computes them again;
+        # so do the unread first tokens, whose requests the snapshot holds
+        # as queued
         self._inflight = None
+        self._first_tokens.clear()
         with span("serving.rollback", tick=self._ticks):
             self._restore(snap)
         victim = ctx[1] if ctx else None
@@ -2076,18 +2163,25 @@ class ServingEngine:
             self.kv_dtype, self.weight_dtype, kv_bytes, weight_bytes,
             kv_cache_bytes=self.cache_manager.cache_nbytes())
 
-    def _admit_fn(self, st, ints, floats, key):
+    def _admit_fn(self, st, ints, tok, floats, key):
         """Jitted: install one request's scalars (``ints`` as
         ``_install_lane`` packed them, ``floats`` temperature and top_p)
         into slot ``slot`` of the device state — ``decoded=1`` for a
         fresh admission (first token just sampled), ``decoded=n`` when
         replay recovery reinstalls a request that already emitted ``n``
-        tokens."""
-        (slot, tok, length, decoded, active, eos, max_new, min_new, greedy,
+        tokens. The lane's token is ``tok``, the scalar a prefill program
+        returned, still on the device, where ``ints`` packs none (-1), and
+        the lane is live if it is wanted and that token neither is its
+        EOS nor uses up its budget: what the host would decide, had it
+        read the token first."""
+        (slot, packed, length, decoded, wanted, eos, max_new, min_new, greedy,
          top_k) = ints
+        tok = jnp.where(packed < 0, tok, packed)
+        active = ((wanted != 0) & ~((eos >= 0) & (tok == eos))
+                  & (decoded < max_new))
         lane = {
             "last_tok": tok, "lengths": length, "decoded": decoded,
-            "active": active != 0, "eos": eos, "max_new": max_new,
+            "active": active, "eos": eos, "max_new": max_new,
             "min_new": min_new, "greedy": greedy != 0,
             "temperature": floats[0], "top_k": top_k, "top_p": floats[1],
             "rng": key,
@@ -2363,24 +2457,32 @@ class ServingEngine:
             at["shared"] = int(shared)
             return shared
 
-    def _install_lane(self, req: Request, *, tok: int, length: int,
+    def _install_lane(self, req: Request, *, tok, length: int,
                       decoded: int, active: bool, carry_key,
-                      floats=None) -> None:
+                      floats=None) -> int:
         """Install one request's decode-lane scalars into the device
         state (shared by fresh admission, replay recovery and a shipped
         admission): ten int32 in one upload; ``floats`` is the float32
         pair the admission's prefill already sent, uploaded here when no
-        such call was made."""
+        such call was made. ``tok`` is the lane's last token: an ``int``
+        the host knows (packed with the others), or the device scalar a
+        prefill program returned, which the host has not read. ``active``
+        is whether the lane is WANTED live; the program also looks at the
+        token (``_admit_fn``). Returns the install's program number."""
+        program = self._next_program()
         with span("serving.install", request=req.id, transfers=0,
-                  program=self._next_program()) as at:
+                  program=program) as at:
+            on_host = isinstance(tok, int)
             ints = np.asarray(
-                [req.slot, tok, length, decoded, active, req.eos_token_id,
-                 req.max_new_tokens, req.min_new_tokens, req.greedy,
-                 req.top_k], np.int32)
+                [req.slot, tok if on_host else -1, length, decoded, active,
+                 req.eos_token_id, req.max_new_tokens, req.min_new_tokens,
+                 req.greedy, req.top_k], np.int32)
             if floats is None:
                 floats = _upload(at, _sampler_floats(req))
             self._state = self._admit_jit(
-                self._state, _upload(at, ints), floats, carry_key)
+                self._state, _upload(at, ints),
+                self._no_token if on_host else tok, floats, carry_key)
+        return program
 
     def _register_prefix(self, req: Request) -> None:
         """Enter the request's prompt pages into the prefix trie (host
@@ -2461,11 +2563,16 @@ class ServingEngine:
                 req, req.prompt[shared:], shared, req.slot)
             self._register_prefix(req)
             self._fault_ctx = None
-            self._prefill_strikes.pop(req.id, None)  # survived its prefill
             now = self._now()
             req.admit_time = now
             self.metrics.record_admit(now - req.submit_time)
-            self._finish_first_token(req, *first)
+            self._dispatch_first_token(req, *first)
+            if (now - self._probed_at >= _PROBE_PERIOD_S
+                    and len(self._first_tokens) == 1):
+                # the sampled admission that holds its own wait (nothing
+                # older is unread, so this is the reading engine's path)
+                self._probed_at = now
+                self._read_first_tokens(cause="probe")
 
     def _admit_shipped(self, req: Request) -> None:
         """Admit a request whose prompt KV arrived from a PREFILL-role
@@ -2549,8 +2656,7 @@ class ServingEngine:
             return
         self._register_prefix(req)
         del self._prefilling[req.slot]
-        self._prefill_strikes.pop(req.id, None)
-        self._finish_first_token(req, *out)
+        self._dispatch_first_token(req, *out)
 
     def _chunk_tick(self):
         """Advance the mid-prefill request by ONE chunk this tick —
@@ -2573,15 +2679,69 @@ class ServingEngine:
         self._run_chunk(req)
         return 1, []
 
-    def _finish_first_token(self, req: Request, tok, carry_key, floats,
-                            program: int) -> None:
-        """Shared admission tail: wait for the prefill's first token
-        (``serving.first_token``: the host-visible prefill wait, which
-        ``reads`` the request's last prefill ``program``), then
-        install the decode lane, record TTFT, fire the callback, route
-        to the active set or straight to retirement."""
-        tok = int(self._fetch("serving.first_token", tok, request=req.id,
-                              reads=program)[0])
+    def _dispatch_first_token(self, req: Request, tok, carry_key, floats,
+                              program: int) -> None:
+        """Shared admission tail, the DISPATCH half (module docstring,
+        "Tick order"): install the decode lane with the prefill's token
+        still on the device, right behind the prefill ``program``, and
+        keep the admission in ``_first_tokens`` until
+        :meth:`_read_first_tokens` reads it, with a later program behind
+        it. Meanwhile the request is where the tick's dispatch sees it as
+        live, unless this token is its only one. A PREFILL-role replica
+        never decodes: its lane is installed INERT (any stray decode tick
+        stays off its pages) and the read parks it. An engine that reads
+        every tick at once reads this token at once too."""
+        parked = self.role == "prefill"
+        installed = self._install_lane(
+            req, tok=tok, length=req.prompt_len, decoded=1,
+            active=not parked, carry_key=carry_key, floats=floats)
+        self._first_tokens.append(
+            InflightFirstToken(tok, req, program, installed))
+        if req.max_new_tokens > 1 and not parked:
+            req.phase = "active"
+            self._active[req.slot] = req
+        cause = self._sync_cause()
+        if cause:
+            self._read_first_tokens(cause=cause)
+
+    def _read_first_tokens(self, commit=lambda: None, cause: str = "idle",
+                           keep: int = 0) -> int:
+        """The READ half: the host sync of every unread first token but
+        the newest ``keep``, oldest first, each followed by ``commit`` (an
+        admission whose token was read stays admitted, whatever fails
+        later in this step). Returns how many were read. One with a
+        program dispatched behind its install counts as overlapped; else
+        ``cause`` (one of ``inflight.FLUSH_CAUSES``) says why it was read
+        with nothing behind it."""
+        read = 0
+        while len(self._first_tokens) > keep:
+            self._read_first_token(self._first_tokens.popleft(), cause)
+            commit()
+            read += 1
+        return read
+
+    def _read_first_token(self, first: InflightFirstToken,
+                          cause: str) -> None:
+        """Wait for one prefill's first token (``serving.first_token``:
+        the host-visible prefill wait, which ``reads`` the request's last
+        prefill ``program``), record TTFT, fire the callback, and leave the
+        request in the active set, park it, or retire it: the device lane
+        is already what the token decided (``_admit_fn``)."""
+        req = first.req
+        overlapped = self._programs > first.installed
+        try:
+            tok = int(self._fetch(
+                "serving.first_token", first.tok, request=req.id,
+                reads=first.program, overlapped=int(overlapped))[0])
+        except Exception:
+            # the device error of a prefill surfaces here
+            self._fault_ctx = self._fault_of(first)
+            raise
+        if overlapped:
+            self.metrics.record_first_token_overlapped()
+        else:
+            self.metrics.record_first_token_flushed(cause)
+        self._prefill_strikes.pop(req.id, None)  # survived its prefill
         now = self._now()
         req.first_token_time = now
         req.tokens.append(tok)
@@ -2589,29 +2749,37 @@ class ServingEngine:
         self.metrics.record_tokens(1)
         done_eos = req.eos_token_id >= 0 and tok == req.eos_token_id
         done = done_eos or req.max_new_tokens <= 1
-        # a PREFILL-role replica never decodes: an unfinished request
-        # parks for export_kv() with its lane INERT (active=False keeps
-        # any stray decode tick off its pages)
-        parked = self.role == "prefill" and not done
-        self._install_lane(req, tok=tok, length=req.prompt_len, decoded=1,
-                           active=not done and not parked,
-                           carry_key=carry_key, floats=floats)
         # callback AFTER the device state is consistent: a raising callback
         # then retires exactly this request and can't leave the slot half-
-        # installed (previously it unwound _admit between cache scatter and
-        # state install)
+        # installed
         if not self._emit_token(req, tok, done):
             self._retire_error(req, now)
         elif done:
             self._finalize(req, "eos" if done_eos else "max_length", now)
-        elif parked:
+        elif self.role == "prefill":
             req.phase = "prefilled"
             self._prefilled[req.slot] = req
             obs_emit("prefill_parked", request=req.id,
                      prompt_len=req.prompt_len)
-        else:
-            req.phase = "active"
-            self._active[req.slot] = req
+
+    def _fault_of(self, first: InflightFirstToken):
+        """The ``_fault_ctx`` of a first token that does not come back: its
+        request's failed prefill, unless the tick dispatched before it is
+        unreadable too (then that tick failed first: a failed tick)."""
+        tick = self._inflight
+        if (tick is None or tick.program > first.program
+                or self._readable(tick.tok)):
+            return ("prefill", first.req.id)
+        return None
+
+    @staticmethod
+    def _readable(array) -> bool:
+        """Whether a device result still comes back to the host."""
+        try:
+            np.asarray(array)
+            return True
+        except Exception:  # noqa: BLE001 — the question this answers
+            return False
 
     def _decode_fn(self, params, cache, st, tables, all_greedy: bool):
         """Jitted: ONE decode token for every slot. An inactive slot
@@ -2678,7 +2846,8 @@ class ServingEngine:
         only the token says so, and the device carries it inactive."""
         tick, lengths = self._inflight, self.cache_manager.lengths
         return {slot: req for slot, req in self._active.items()
-                if (len(req.tokens) + pending_of(tick, slot, req)
+                if (len(req.tokens)
+                    + pending_of(tick, self._first_tokens, slot, req)
                     < req.max_new_tokens)
                 and lengths[slot] < self.cache_len}
 
@@ -2696,7 +2865,7 @@ class ServingEngine:
                     continue  # the read a dry pool forced (below) finished it
                 if self.cache_manager.ensure_page(slot):
                     continue
-                if self._inflight is not None:
+                if self._inflight is not None or self._first_tokens:
                     retired += self._collect("pool_dry", commit)
                     if (self._active.get(slot) is not req
                             or self.cache_manager.ensure_page(slot)):
@@ -2768,8 +2937,9 @@ class ServingEngine:
         return "watchdog" if self.tick_timeout_s > 0 else None
 
     def _tick_decode(self, commit=lambda: None):
-        """Dispatch tick n, THEN read tick n-1 (module docstring "Tick
-        order"). Returns the ids retired by what was read."""
+        """Dispatch tick n, THEN read tick n-1 and the first tokens of
+        this step's last admissions (module docstring "Tick order").
+        Returns the ids retired by the tick that was read."""
         retired = self._grow_pages(commit)
         lanes = self._live_lanes()
         if not lanes:
@@ -2821,6 +2991,9 @@ class ServingEngine:
             # the wait for tick n-1 is HERE, with tick n on the device
             self.metrics.record_tick_overlapped()
             retired += self._deliver(before, commit)
+        # and the wait for this step's last admissions, in the order the
+        # device runs them: tick n-1, their prefills, tick n
+        self._read_first_tokens(commit)
         cause = self._sync_cause()
         if cause:
             retired += self._collect(cause, commit)
@@ -2828,13 +3001,16 @@ class ServingEngine:
 
     def _collect(self, cause: str, commit=lambda: None) -> list:
         """Read the tick in flight NOW, with no tick behind it on the
-        device (``cause``: one of ``inflight.FLUSH_CAUSES``); nothing to
-        do without one. Returns the ids its tokens retired."""
+        device (``cause``: one of ``inflight.FLUSH_CAUSES``), and after it
+        the first tokens a step has left unread; nothing to do without
+        either. Returns the ids the tick's tokens retired."""
         tick, self._inflight = self._inflight, None
-        if tick is None:
-            return []
-        retired = self._deliver(tick, commit, flushed=cause)
-        self.metrics.record_tick_flushed(cause)
+        retired = []
+        if tick is not None:
+            retired = self._deliver(tick, commit, flushed=cause)
+            self.metrics.record_tick_flushed(cause)
+        # inside a step, what was admitted behind that tick (a dry pool)
+        self._read_first_tokens(commit, cause=cause)
         return retired
 
     def _settle(self, cause: str) -> None:

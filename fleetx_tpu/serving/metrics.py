@@ -37,6 +37,15 @@ from fleetx_tpu.serving.inflight import FLUSH_CAUSES
 __all__ = ["ServingMetrics"]
 
 
+def _in_flight(what: str, overlapped, flushed: Dict) -> Dict:
+    """Snapshot keys of one kind of result kept in flight: ``<what>_
+    overlapped``, ``<what>_flushed`` and ``<what>_flushed_<cause>``."""
+    by_cause = {cause: int(c.value) for cause, c in flushed.items()}
+    return {f"{what}_overlapped": int(overlapped.value),
+            f"{what}_flushed": sum(by_cause.values()),
+            **{f"{what}_flushed_{cause}": n for cause, n in by_cause.items()}}
+
+
 def _drop_series(owned) -> None:
     """weakref.finalize target: remove every registry series a
     ServingMetrics instance owned (its ``engine=<n>`` label is unique,
@@ -298,15 +307,30 @@ class ServingMetrics:
         self._c_ticks_overlapped = counter(
             "fleetx_serving_decode_ticks_overlapped_total",
             "Decode ticks dispatched while the tick before was unread")
-        self._flushed_family = reg.counter(
+
+        def by_cause(name, help_):
+            family = reg.counter(name, help_, ("engine", "cause"))
+            children = {}
+            for cause in FLUSH_CAUSES:
+                labels = {"engine": self.engine_label, "cause": cause}
+                owned.append((family, labels))
+                children[cause] = family.labels(**labels)
+            return children
+
+        self._flushed: Dict[str, object] = by_cause(
             "fleetx_serving_decode_ticks_flushed_total",
             "Decode ticks read with no tick behind them on the device, by "
-            "cause", ("engine", "cause"))
-        self._flushed: Dict[str, object] = {}
-        for cause in FLUSH_CAUSES:
-            labels = {"engine": self.engine_label, "cause": cause}
-            owned.append((self._flushed_family, labels))
-            self._flushed[cause] = self._flushed_family.labels(**labels)
+            "cause")
+        # an admission's first token kept in flight the same way: read
+        # with a later program already dispatched behind its lane install,
+        # against read with nothing behind it, by cause
+        self._c_firsts_overlapped = counter(
+            "fleetx_serving_first_tokens_overlapped_total",
+            "First tokens read with a later program already dispatched")
+        self._firsts_flushed: Dict[str, object] = by_cause(
+            "fleetx_serving_first_tokens_flushed_total",
+            "First tokens read with no program dispatched behind their "
+            "lane install, by cause")
         # the unit of a prefill program's cache write, decided by its shape
         # (models/gpt/paged_write.py): a page at a time or a row at a time
         self._c_prefill_page_writes = counter(
@@ -541,6 +565,16 @@ class ServingMetrics:
         """A decode tick was read with no tick behind it on the device
         (``cause``: one of ``inflight.FLUSH_CAUSES``)."""
         self._flushed[cause].inc()
+
+    def record_first_token_overlapped(self) -> None:
+        """A first token was read with a later program already dispatched
+        behind its lane install."""
+        self._c_firsts_overlapped.inc()
+
+    def record_first_token_flushed(self, cause: str) -> None:
+        """A first token was read with nothing dispatched behind its lane
+        install (``cause``: one of ``inflight.FLUSH_CAUSES``)."""
+        self._firsts_flushed[cause].inc()
 
     def observe_pages(self, pages_in_use: int, pages_total: int) -> None:
         """Per-tick page-pool gauge sample (paged mode only)."""
@@ -868,11 +902,12 @@ class ServingMetrics:
             "spec_tokens_per_tick_mean": self._h_spec_tokens.mean,
             # the tick in flight (engine.py "Tick order"): overlapped +
             # flushed = the decode ticks that were read
-            "decode_ticks_overlapped": int(self._c_ticks_overlapped.value),
-            "decode_ticks_flushed": sum(
-                int(c.value) for c in self._flushed.values()),
-            **{f"decode_ticks_flushed_{cause}": int(c.value)
-               for cause, c in self._flushed.items()},
+            **_in_flight("decode_ticks", self._c_ticks_overlapped,
+                         self._flushed),
+            # the first tokens likewise: overlapped + flushed = the
+            # admissions whose first token was read
+            **_in_flight("first_tokens", self._c_firsts_overlapped,
+                         self._firsts_flushed),
             # prefill programs by the unit of their cache write
             "prefill_page_writes": int(self._c_prefill_page_writes.value),
             "prefill_row_writes": int(self._c_prefill_row_writes.value),

@@ -86,6 +86,7 @@ def ticked():
     dispatched a step earlier). Returns ``(admission tick's spans,
     decode-only tick's spans)`` from the process recorder."""
     eng = _engine()
+    eng._probed_at = float("inf")   # no admission sampled to be read at once
     rng = np.random.default_rng(0)
     for _ in range(LANES):
         eng.submit(rng.integers(1, 60, 5, dtype=np.int32), max_length=6)
@@ -128,6 +129,10 @@ def test_admission_spans_nest_under_admit_with_their_attrs(ticked):
                             "serving.prefill_args", "serving.prefill",
                             "serving.first_token", "serving.install"}
     admit = by_name["serving.admit"][0]
+    # the wait for the first token is no longer the admission's: the token
+    # stays in flight, and the tick reads it after a later dispatch
+    (first_token,) = by_name.pop("serving.first_token")
+    assert first_token.parent == "serving.tick"
     for name, spans in by_name.items():
         if name != "serving.admit":
             assert all(s.parent == "serving.admit" for s in spans), name
@@ -147,10 +152,16 @@ def test_admission_spans_nest_under_admit_with_their_attrs(ticked):
     prefill = by_name["serving.prefill"][0].attrs["program"]
     assert install.attrs == {"request": request, "transfers": 1,
                              "program": prefill + 1}
-    assert by_name["serving.first_token"][0].end_s <= install.start_s
-    # admit now ends after the first token was fetched and the lane
-    # installed: its documented meaning
-    assert admit.end_s >= by_name["serving.install"][-1].end_s
+    # the install is dispatched right behind the prefill, with the token
+    # still on the device; the read that names the prefill's program comes
+    # after the next admission's prefill was dispatched
+    install_span = by_name["serving.install"][-1]
+    assert install_span.end_s <= admit.end_s <= first_token.start_s
+    assert first_token.attrs == {"request": request, "reads": prefill,
+                                 "overlapped": 1}
+    behind = next(s for s in _named(admission, "serving.prefill")
+                  if s.attrs["program"] == prefill + 2)
+    assert behind.end_s <= first_token.start_s
     # at most 6 new spans an admission, the re-commit of the snapshot
     # among them
     new = [s for s in mine if s.name not in ("serving.admit",

@@ -10,6 +10,11 @@ the new order has to get right on its own: the span order and the two
 counters, who a token belongs to when its request left between dispatch and
 collection, a fault with a tick unread, a dry pool, and the row counts of the
 ``serving.decode`` span.
+
+Section (g) is the same for an admission's FIRST token, which stays in flight
+like the tick's: the lane install takes it on the device, and the host reads
+it only after a later program was dispatched (the next admission's prefill,
+or the step's tick); none is unread when ``step()`` returns.
 """
 
 import flax
@@ -28,6 +33,7 @@ from fleetx_tpu.obs import get_event_log
 from fleetx_tpu.obs.tracing import get_recorder
 from fleetx_tpu.resilience.faults import faults
 from fleetx_tpu.serving import ServingEngine
+from fleetx_tpu.serving import engine as engine_module
 from fleetx_tpu.serving.inflight import FLUSH_CAUSES
 
 SYNC = dict(tick_timeout_s=60.0)   # the watchdog armed: no tick in flight
@@ -79,6 +85,13 @@ def _clean_faults():
     faults.reset()
     yield
     faults.reset()
+
+
+@pytest.fixture(autouse=True)
+def _no_probe(monkeypatch):
+    """The engine's sampled admission that is read at once goes by the wall
+    clock; the cases here count reads, so only the probe's own case has it."""
+    monkeypatch.setattr(engine_module, "_PROBE_PERIOD_S", float("inf"))
 
 
 class Client:
@@ -612,3 +625,393 @@ def test_window_rows_count_the_dispatched_programs_rows(smallthinker_weights):
     assert {a["window_rows"] for a in got} == {smallthinker.WINDOW}
     assert [(a["full_rows"], a["window_rows"]) for a in got] == [
         (a["full_rows"], a["window_rows"]) for a in want]
+
+
+# ------------------------------ (g) an admission's first token in flight
+
+_JITS = ("_decode_jit", "_probe_jit", "_admit_jit", "_prefill_jits")
+
+
+@pytest.fixture(scope="module")
+def same(gpt):
+    """``same(**kw)``: a GPT engine of this section. Engines built with EQUAL
+    keyword arguments run the jitted programs of the first one built with
+    them (some thirty engines over a dozen settings: the suite's time
+    limit); every setting still runs what an engine built with it builds,
+    and sections (a) to (f) build each engine whole."""
+    built = {}
+
+    def engine(**kw):
+        made = gpt_engine(gpt, **kw)
+        key = repr(sorted({"slots": 3, **kw}.items()))
+        jits = built.setdefault(key, {n: getattr(made, n) for n in _JITS})
+        for name, jit in jits.items():
+            setattr(made, name, jit)
+        return made
+    return engine
+
+
+@pytest.fixture(scope="module")
+def installer(same):
+    """``install(**lane) -> (active, last_tok)`` of lane 1 after the
+    engine's own install program ran on a fresh lane state."""
+    engine = same()
+
+    def install(tok, packed=-1, wanted=1, eos=-1, max_new=8, decoded=1):
+        ints = np.asarray([1, packed, 5, decoded, wanted, eos, max_new, 0, 1,
+                           0], np.int32)
+        st = engine._admit_jit(engine._state, ints, np.int32(tok),
+                               np.ones(2, np.float32), jax.random.PRNGKey(0))
+        return bool(st["active"][1]), int(st["last_tok"][1])
+    return install
+
+
+@pytest.mark.parametrize("lane, active, last_tok", [
+    (dict(tok=7), True, 7),                             # the common case
+    (dict(tok=7, eos=7), False, 7),                     # its first token is EOS
+    (dict(tok=7, eos=9), True, 7),
+    (dict(tok=0, eos=-1), True, 0),                     # no EOS: 0 is a token
+    (dict(tok=7, max_new=1), False, 7),                 # a budget of one
+    (dict(tok=7, max_new=2), True, 7),
+    (dict(tok=7, wanted=0), False, 7),                  # parked for export
+    (dict(tok=7, wanted=0, eos=7), False, 7),
+    (dict(tok=3, packed=11), True, 11),                 # a replay packs its own
+    (dict(tok=3, packed=11, eos=11), False, 11),
+    (dict(tok=11, packed=0, eos=11), True, 0),          # a packed 0 is a token
+    (dict(tok=3, packed=11, decoded=4, max_new=4), False, 11),
+    (dict(tok=3, packed=11, decoded=3, max_new=4), True, 11),
+], ids=lambda v: "-".join(f"{k}{x}" for k, x in v.items())
+    if isinstance(v, dict) else None)
+def test_the_install_decides_the_lane_from_the_token_on_the_device(
+        installer, lane, active, last_tok):
+    """``active = wanted & ~(eos >= 0 & tok == eos) & (decoded < max_new)``
+    over the token the prefill left on the device, or the one the host
+    packed (a replay, a shipped admission): what the host decided when it
+    read the token first."""
+    assert installer(**lane) == (active, last_tok)
+
+
+def test_pending_of_counts_the_unread_tick_and_the_unread_first_token():
+    from fleetx_tpu.serving.inflight import (InflightFirstToken,
+                                              InflightTick, pending_of)
+
+    mine, other = object(), object()
+    tick = InflightTick(tok=None, done=None, lanes={0: mine, 2: other},
+                        program=9)
+    first = InflightFirstToken(tok=None, req=mine, program=5, installed=6)
+    assert pending_of(None, (), 0, mine) == 0
+    assert pending_of(tick, (), 0, mine) == 1
+    assert pending_of(tick, (), 1, mine) == 0     # another lane's token
+    assert pending_of(tick, (), 2, mine) == 0     # the lane's other tenant
+    assert pending_of(None, [first], 0, mine) == 1
+    assert pending_of(tick, [first], 0, mine) == 2
+    assert pending_of(tick, [first], 2, other) == 1
+
+
+def _first_token_counts(engine):
+    snap = engine.metrics.snapshot(device=False)   # reads no tick in flight
+    return snap["first_tokens_overlapped"], snap["first_tokens_flushed"]
+
+
+def test_a_first_token_is_read_after_a_later_program_was_dispatched(same):
+    """Three admissions in one step: the device's line reads prefill A,
+    install A, prefill B, install B, prefill C, install C, tick; A's token
+    is read once B's prefill is dispatched, B's once C's is, C's behind the
+    tick."""
+    engine = same()
+    client = Client(engine)
+    for n in (4, 6, 5):
+        client.submit(np.arange(1, n + 1), max_length=8)
+    get_recorder().clear()
+    engine.step()
+    spans = get_recorder().spans()
+    dispatches = sorted(
+        (s for s in spans if "program" in s.attrs),
+        key=lambda s: s.attrs["program"])
+    assert [s.name for s in dispatches] == [
+        "serving.prefill", "serving.install"] * 3 + ["serving.decode"]
+    reads = [s for s in spans if s.name == "serving.first_token"]
+    assert [s.attrs["request"] for s in reads] == client.ids
+    for read in reads:
+        prefill = next(s for s in dispatches
+                       if s.attrs["program"] == read.attrs["reads"])
+        install, later = dispatches[dispatches.index(prefill) + 1:][:2]
+        assert (prefill.name, install.name) == ("serving.prefill",
+                                                "serving.install")
+        assert prefill.attrs["request"] == install.attrs["request"] \
+            == read.attrs["request"]
+        assert later.name in ("serving.prefill", "serving.decode")
+        assert install.end_s <= later.start_s
+        assert later.end_s <= read.start_s
+        assert read.attrs["overlapped"] == 1
+    assert _first_token_counts(engine) == (3, 0)
+    assert not engine._first_tokens and engine._inflight is not None
+    assert all(len(stream) == 1 for stream in client.streams.values())
+    _assert_stream_is_result(client.outcome())
+
+
+def _burst(engine, vocab):
+    """Bursts of admissions (several a step, more requests than lanes),
+    budgets of one token among them; every other request samples from its
+    own seeded stream, the rest are greedy."""
+    rng = np.random.default_rng(9)
+    client = Client(engine)
+    work = ((7, 6), (3, 1), (12, 9), (5, 4), (9, 1), (4, 7), (10, 3), (6, 5))
+    for i, (n, new) in enumerate(work):
+        kw = dict(max_length=new)
+        if i % 2:
+            kw.update(decode_strategy="sampling", seed=40 + i,
+                      temperature=0.8, top_k=24, top_p=0.95)
+        client.submit(rng.integers(1, vocab, n), **kw)
+        if i in (4, 6):
+            engine.step()
+            assert not engine._first_tokens
+    return client.outcome()
+
+
+@pytest.mark.parametrize("family,kw", [
+    ("gpt", {}), ("gpt", dict(prefill_chunk=4)), ("olmoe", {})],
+    ids=["gpt", "gpt-chunked", "olmoe"])
+def test_bursts_of_admissions_equal_the_synchronous_engines(
+        same, olmoe_weights, family, kw):
+    """Same programs, same sampler, same rng order: every stream is bitwise
+    the synchronous order's, callbacks in each request's order, and the
+    overlapping engine did overlap."""
+    def serve(**more):
+        if family == "gpt":
+            engine, vocab = same(**kw, **more), 96
+        else:
+            engine, vocab = olmoe.engine_of(
+                olmoe.build(), olmoe_weights, **more), 512
+        out = _burst(engine, vocab)
+        assert engine.cache_manager.pages_in_use == 0
+        return out, _first_token_counts(engine)
+
+    (got, (overlapped, flushed)), (want, sync) = serve(), serve(**SYNC)
+    assert got == want
+    _assert_stream_is_result(got)
+    assert [len(t) for _, t, _ in got] == [6, 1, 9, 4, 1, 7, 3, 5]
+    assert overlapped + flushed == 8 and overlapped >= 6
+    assert sync == (0, 8)
+
+
+@pytest.mark.parametrize("how", ["eos", "one_token"])
+def test_a_first_token_that_ends_its_request_leaves_the_lane_inert(same,
+                                                                   how):
+    """The host learns that the first token was the last (EOS, or a budget
+    of one) one dispatch late: the install left the device lane inactive,
+    the tick already dispatched carries the slot and its token for it is
+    nobody's, the pages are free at the read."""
+    prompts = [np.arange(3, 9), np.arange(20, 27), np.arange(40, 44)]
+
+    def serve(first_kw, **kw):
+        engine = same(slots=2, **kw)
+        client = Client(engine)
+        client.submit(prompts[0], max_length=6)
+        engine.step()
+        client.submit(prompts[1], **first_kw)
+        client.submit(prompts[2], max_length=5)
+        return engine, client
+
+    _, client = serve(dict(max_length=6))
+    first = client.outcome()[1][1][0]
+    ending = (dict(max_length=6, eos_token_id=first) if how == "eos"
+              else dict(max_length=1))
+    engine, client = serve(ending)
+    pages_before = engine.cache_manager.pages_in_use
+    engine.step()
+    rid = client.ids[1]
+    # it ended at the read, in the step that admitted it
+    assert client.streams[rid] == [(first, True)]
+    result = engine.result(rid)
+    assert list(result.tokens) == [first]
+    assert result.finish_reason == ("eos" if how == "eos" else "max_length")
+    assert engine._active.keys() == {0} and not engine._first_tokens
+    assert not np.asarray(engine._state["active"])[1]
+    assert engine.cache_manager.pages_in_use == pages_before
+    tick = engine._inflight
+    if how == "eos":
+        # only the token says EOS: the tick was dispatched for its lane too
+        assert tick.lanes[1].id == rid
+    else:
+        # the host counts a budget itself: no tick for that lane
+        assert set(tick.lanes) == {0}
+    got = client.outcome()
+    _, want_client = serve(ending, **SYNC)
+    assert got == want_client.outcome()
+    _assert_stream_is_result(got)
+    assert [len(t) for _, t, _ in got] == [6, 1, 5]
+    assert _first_token_counts(engine) == (3, 0)
+    assert engine.cache_manager.pages_in_use == 0
+    engine.cache_manager.pool.check_invariants()
+
+
+@pytest.mark.parametrize("kw, cause", [(SYNC, "watchdog"),
+                                       (dict(spec=True, spec_k=2), "spec")])
+def test_an_engine_that_reads_every_tick_at_once_reads_first_tokens_at_once(
+        same, kw, cause):
+    """Every first token is read right after its install, before any later
+    dispatch, under the engine's one cause."""
+    engine = same(**kw)
+    client = Client(engine)
+    for n in (4, 6, 5):
+        client.submit(np.arange(1, n + 1), max_length=4)
+    get_recorder().clear()
+    engine.step()
+    spans = [s for s in get_recorder().spans()
+             if s.name in ("serving.prefill", "serving.install",
+                           "serving.first_token") and (
+                 "program" in s.attrs or "reads" in s.attrs)]
+    assert [s.name for s in spans] == [
+        "serving.prefill", "serving.install", "serving.first_token"] * 3
+    assert all(s.attrs["overlapped"] == 0 for s in spans[2::3])
+    engine.drain()
+    snap = engine.metrics.snapshot()
+    assert snap["first_tokens_overlapped"] == 0
+    assert snap["first_tokens_flushed"] == 3 \
+        == snap[f"first_tokens_flushed_{cause}"]
+
+
+@pytest.mark.parametrize("surfaces", ["at_its_read", "at_the_next_dispatch"])
+@pytest.mark.parametrize("tick_lost", [False, True],
+                         ids=["prefill", "tick_before_it"])
+def test_a_first_token_read_that_fails_is_that_requests_failed_prefill(
+        same, tick_lost, surfaces):
+    """A device error of prefill A surfaces when A's token is read, with B
+    already dispatched behind it, or already when B's prefill is dispatched:
+    one strike for A either way, nothing of either was emitted, both go back
+    to the queue's head in their order and are admitted again. Where the
+    tick dispatched BEFORE A is unreadable too, it is that tick that
+    failed."""
+    def serve(arm):
+        engine = same()
+        client = Client(engine)
+        rng = np.random.default_rng(21)
+        client.submit(rng.integers(1, 96, 5), max_length=7)
+        engine.step()
+        engine.step()
+        assert engine._inflight is not None
+        for n, new in ((8, 5), (3, 6)):
+            client.submit(rng.integers(1, 96, n), max_length=new)
+        if arm:
+            dispatch, armed = engine._dispatch_first_token, [True]
+
+            def lose(req, *rest):
+                dispatch(req, *rest)
+                if armed and req.id == client.ids[1]:
+                    armed.clear()
+                    engine._first_tokens[-1].tok = _Unreadable()
+                    if tick_lost:
+                        engine._inflight.tok = _Unreadable()
+                    if surfaces == "at_the_next_dispatch":
+                        def lost(*args, **kw):
+                            del engine._paged_prefill_call   # this once
+                            raise RuntimeError("device lost")
+
+                        engine._paged_prefill_call = lost
+
+            engine._dispatch_first_token = lose
+            summary = engine.step()
+            assert summary["recovered"] and summary["admitted"] == 0
+            # nothing of A or B was emitted, and they wait in their order
+            assert client.ids[1] not in client.streams
+            assert client.ids[2] not in client.streams
+            assert [r.id for r in engine.scheduler.snapshot()] == \
+                client.ids[1:]
+            assert not engine._first_tokens and engine._inflight is None
+            strikes = {} if tick_lost else {client.ids[1]: 1}
+            assert engine._prefill_strikes == strikes
+            fault = get_event_log().find("tick_fault")[-1].attrs
+            assert fault["during_prefill"] == (not tick_lost)
+            assert fault["request"] == (None if tick_lost else client.ids[1])
+        out = client.outcome()
+        assert engine.cache_manager.pages_in_use == 0
+        engine.cache_manager.pool.check_invariants()
+        return out, engine
+
+    (clean, _), (faulted, engine) = serve(False), serve(True)
+    assert faulted == clean
+    _assert_stream_is_result(faulted)
+    assert engine.metrics.engine_recoveries == 1
+    assert not engine._prefill_strikes     # the second admission survived
+
+
+def test_a_sampled_admission_is_read_at_once_inside_its_own_span(
+        same, monkeypatch):
+    """Once a period the first admission of a step holds its own wait, as
+    every admission did before first tokens stayed in flight (what reads an
+    admission's host time as the span less that wait needs some that do):
+    the read lies inside ``serving.admit``, counts as flushed by ``probe``,
+    and the streams are the synchronous engine's."""
+    monkeypatch.setattr(engine_module, "_PROBE_PERIOD_S", 3600.0)
+
+    def serve(due, **kw):
+        engine = same(**kw)
+        engine._probed_at -= 7200.0 if due else 0.0
+        client = Client(engine)
+        for n in (4, 6, 5):
+            client.submit(np.arange(1, n + 1), max_length=4)
+        get_recorder().clear()
+        engine.step()
+        spans = get_recorder().spans()
+        out = client.outcome()
+        return out, spans, client.ids, engine.metrics.snapshot()
+
+    got, spans, ids, snap = serve(True)
+    want, _, _, sync = serve(True, **SYNC)
+    assert got == want
+    reads = {s.attrs["request"]: s for s in spans
+             if s.name == "serving.first_token"}
+    admits = {s.attrs["request"]: s for s in spans
+              if s.name == "serving.admit"}
+    # the step's first admission holds its wait; the next is due a period
+    # later, so the other two stay in flight
+    first, *rest = ids
+    assert reads[first].parent == "serving.admit"
+    assert admits[first].start_s <= reads[first].start_s
+    assert reads[first].end_s <= admits[first].end_s
+    assert reads[first].attrs["overlapped"] == 0
+    for rid in rest:
+        assert reads[rid].parent == "serving.tick"
+        assert reads[rid].attrs["overlapped"] == 1
+    assert (snap["first_tokens_overlapped"], snap["first_tokens_flushed"],
+            snap["first_tokens_flushed_probe"]) == (2, 1, 1)
+    assert snap["decode_ticks_flushed_probe"] == 0
+    assert sync["first_tokens_flushed_probe"] == 0
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_no_first_token_is_unread_when_step_returns(same, seed):
+    """Over a random script of arrivals (budgets of one token, sampled
+    lanes and an EOS met wherever it falls among them): after every ``step()`` nothing is
+    unread but the one tick, the streams are the synchronous engine's, and
+    overlapped plus flushed first tokens are the admissions."""
+    def serve(**kw):
+        engine = same(slots=3, **kw)
+        client = Client(engine)
+        rng = np.random.default_rng(100 + seed)
+        for _ in range(14):
+            for _ in range(rng.integers(0, 4)):
+                kw = dict(max_length=int(rng.integers(1, 7)))
+                if rng.random() < 0.3:
+                    kw.update(eos_token_id=int(rng.integers(1, 96)))
+                if rng.random() < 0.3:
+                    kw.update(decode_strategy="sampling",
+                              seed=int(rng.integers(1, 999)), top_k=16)
+                client.submit(rng.integers(1, 96, rng.integers(2, 12)), **kw)
+            engine.step()
+            assert not engine._first_tokens
+            for slot, req in engine._active.items():
+                assert req.tokens, (slot, req.id)
+        out = client.outcome()
+        assert not engine._first_tokens and engine._inflight is None
+        return out, engine
+
+    (got, engine), (want, _) = serve(), serve(**SYNC)
+    assert got == want
+    _assert_stream_is_result(got)
+    snap = engine.metrics.snapshot()
+    assert (snap["first_tokens_overlapped"] + snap["first_tokens_flushed"]
+            == snap["admitted"] == len(got))
+    assert snap["first_tokens_flushed"] == snap["first_tokens_flushed_idle"]
+    assert engine.cache_manager.pages_in_use == 0
