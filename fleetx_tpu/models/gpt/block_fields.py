@@ -3,7 +3,8 @@ grouped-query heads, a head size of its own, window and full attention
 layers mixed, rotary and position-free layers mixed, a router that reads
 the block's input; and for a stack whose layers do not even hold the same
 parameters (``layer_types``: gated short-convolution layers beside attention
-layers, Mamba-1 selective-scan layers beside attention layers, dense
+layers, Mamba-1 selective-scan layers beside attention layers, KDA
+delta-rule linear attention layers beside gated attention layers, dense
 feed-forward layers before expert layers or throughout, a sigmoid router
 with a selection bias, window and full attention layers side by side with an
 output gate and norms after each part as well as before, double layers
@@ -26,15 +27,23 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-__all__ = ["BlockLayoutFields", "LAYOUT_FIELDS", "LAYER_TYPES", "check",
-           "layer_class", "stack_of"]
+__all__ = ["BlockLayoutFields", "LANE_STATE_LEAVES", "LAYOUT_FIELDS",
+           "LAYER_TYPES", "RECURRENT_TYPES", "check", "layer_class",
+           "stack_of"]
 
 # per-layer lists (a YAML or JSON list becomes a tuple: the configuration is
 # a module attribute and has to hash)
 LAYOUT_FIELDS = ("rope_layout", "sliding_window_layout", "layer_types")
 # the operators a layer of ``layer_types`` can name, under the source's names
-LAYER_TYPES = ("conv", "mamba", "full_attention", "sliding_attention",
+LAYER_TYPES = ("conv", "mamba", "kda", "full_attention", "sliding_attention",
                "latent_attention")
+# the recurrent operators (a stack holds ONE beside attention), and of them
+# those whose state is held ONCE A LANE, outside the page pool: the state
+# kind's name and the cache leaves a lane holds of it, ``[layers of the
+# kind, lanes, ...]`` each (serving/cache_manager.py "Kinds of state")
+RECURRENT_TYPES = ("conv", "mamba", "kda")
+LANE_STATE_LEAVES = {"mamba": ("ssm", ("ssm_state", "ssm_conv")),
+                     "kda": ("kda", ("kda_state", "kda_conv"))}
 # the gates that choose under a selection bias (``use_expert_bias``)
 _BIAS_GATES = ("sigmoid_topk", "softmax_bias_topk")
 
@@ -83,6 +92,19 @@ class BlockLayoutFields:
     mamba_d_state: int = 16
     mamba_d_conv: int = 4
     mamba_dt_rank: Optional[int] = None
+    # "kda" (mixed_stack.KDAMixer: Kimi Delta Attention, a gated delta-rule
+    # linear attention): ``kda_num_heads`` heads of ``kda_head_dim`` (keys
+    # and values alike), each of q, k, v through a causal depthwise filter
+    # of ``kda_conv_size`` taps; a per-channel log decay and the output gate
+    # through low-rank projections of ``kda_gate_rank``; ``beta =
+    # sigmoid(..)``, doubled under ``kda_neg_eigval`` (the transition then
+    # has eigenvalues in (-1, 1)); a state ``[heads, d, d]`` float32 a layer
+    # and lane, held once a lane
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_size: int = 4
+    kda_gate_rank: int = 0
+    kda_neg_eigval: bool = False
     # the first ``num_dense_layers`` layers take the dense MLP, of width
     # ``dense_ffn_hidden_size``; the others experts of ``ffn_hidden_size``
     # (``num_dense_layers == num_layers``: no expert layer at all)
@@ -265,12 +287,21 @@ class BlockLayoutFields:
         """What a lane keeps: in the page pool, keys and values ("kv") in
         every attention layer and, in a gated short-convolution layer, the
         operator's last inputs ("conv"); once a lane and outside the pool,
-        a selective-scan layer's state ("ssm")."""
+        a selective-scan layer's state ("ssm") or a delta-rule layer's
+        ("kda")."""
         kinds = {"full_attention" if t == "sliding_attention" else t
                  for t in self.layer_types or ("full_attention",)}
         return tuple(name for name, kind in (
             ("kv", "full_attention"), ("conv", "conv"), ("ssm", "mamba"),
-            ("latent", "latent_attention")) if kind in kinds)
+            ("kda", "kda"), ("latent", "latent_attention")) if kind in kinds)
+
+    @property
+    def lane_state(self) -> Tuple[str, Tuple[str, ...]]:
+        """``(state kind, its cache leaves)`` of the state a lane holds ONCE
+        A LANE, outside the page pool (``LANE_STATE_LEAVES``); ``("", ())``
+        for a model without such a kind."""
+        return next((LANE_STATE_LEAVES[t] for t in self.layer_types or ()
+                     if t in LANE_STATE_LEAVES), ("", ()))
 
     def span_pairs(self, rows: int) -> dict:
         """Span field of a call of ``rows`` rows through an expert layer
@@ -344,6 +375,11 @@ class BlockLayoutFields:
     def mamba_inner(self) -> int:
         """A selective-scan layer's inner width."""
         return self.mamba_expand * self.hidden_size
+
+    @property
+    def kda_inner(self) -> int:
+        """A delta-rule layer's width: its heads side by side."""
+        return self.kda_num_heads * self.kda_head_dim
 
     @property
     def window_layers(self) -> Tuple[int, ...]:
@@ -516,11 +552,25 @@ def _check_mixed(cfg) -> None:
             "layer_types with mlp_act other than swiglu, with biases or "
             "without rmsnorm: no test covers it")
     _check_latent(cfg)
+    recurrent = [t for t in RECURRENT_TYPES if t in cfg.layer_types]
+    if len(recurrent) > 1:
+        raise NotImplementedError(
+            f"layer_types with {' AND '.join(recurrent)} layers: no test "
+            "covers a stack with two recurrent operators")
+    kda = [n for n in ("kda_num_heads", "kda_head_dim", "kda_gate_rank",
+                       "kda_neg_eigval") if getattr(cfg, n)]
+    if "kda" in cfg.layer_types:
+        if min(cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_gate_rank) < 1 \
+                or cfg.kda_conv_size < 2:
+            raise ValueError(
+                f"layer_types with kda layers needs kda_num_heads "
+                f"{cfg.kda_num_heads}, kda_head_dim {cfg.kda_head_dim}, "
+                f"kda_gate_rank {cfg.kda_gate_rank} of at least 1 and "
+                f"kda_conv_size {cfg.kda_conv_size} of at least 2 (the "
+                "source states them)")
+    elif kda:
+        raise ValueError(f"{kda} without a kda layer")
     if "mamba" in cfg.layer_types:
-        if "conv" in cfg.layer_types:
-            raise NotImplementedError(
-                "layer_types with conv AND mamba layers: no test covers a "
-                "stack with both recurrent operators")
         if not cfg.mamba_dt_rank:
             raise ValueError("layer_types with mamba layers needs "
                              "mamba_dt_rank (the source states it)")
@@ -543,14 +593,18 @@ def _check_mixed(cfg) -> None:
         added = [n for n, on in (
             ("sliding_window", cfg.sliding_window),
             ("rope_layout (rotating some layers and not others)", mixed_rope),
-            ("attention_gate", cfg.attention_gate != "none"),
+            # (the gate beside delta-rule layers is covered:
+            # tests/test_solar2_serving.py)
+            ("attention_gate", cfg.attention_gate != "none"
+             and "kda" not in cfg.layer_types),
             ("sandwich_norm", cfg.sandwich_norm),
             ("embedding_multiplier", cfg.embedding_multiplier != 1.0)) if on]
         if added:
             raise NotImplementedError(
-                f"{added} in a layer_types stack with conv, mamba or "
+                f"{added} in a layer_types stack with conv, mamba, kda or "
                 "latent_attention layers: no test covers it (a stack of "
-                "full_attention | sliding_attention layers takes them)")
+                "full_attention | sliding_attention layers takes them, one "
+                "with kda layers the attention_gate)")
     if cfg.router_input != "mlp_norm":
         raise NotImplementedError("router_input with layer_types")
 

@@ -1,7 +1,8 @@
 """The decoder stack for layers that do not hold the same parameters
 (``layer_types``, ``models/gpt/block_fields.py``): gated short-convolution
-layers or Mamba-1 selective-scan layers beside grouped-query attention
-layers, dense feed-forward layers before expert layers or throughout.
+layers, Mamba-1 selective-scan layers or KDA delta-rule linear attention
+layers beside grouped-query attention layers, dense feed-forward layers
+before expert layers or throughout.
 
 **The form.** ``model.GPTModel._decoder_stack`` scans one flax body over
 layers that share one parameter tree; here a convolution layer holds ``[h,
@@ -9,7 +10,7 @@ layers that share one parameter tree; here a convolution layer holds ``[h,
 filter, the low-rank ``dt`` path, ``A`` and ``D``, an attention layer four
 projections (and two ``[head]`` norms), a dense layer a wide MLP and an
 expert layer a router and its experts. The parameters are therefore STACKED
-BY KIND (the operators ``conv``, ``mamba``, ``attention``; the feed-forward
+BY KIND (the operators ``conv``, ``mamba``, ``kda``, ``attention``; the feed-forward
 parts ``dense``, ``experts``: each the kind's own module's tree with the
 kind's layers as the leading axis), and ONE ``lax.scan`` body runs every
 layer: it looks the layer's two kinds and its place in each kind's stack up
@@ -59,7 +60,14 @@ of its pages). A call that starts at position 0 starts from zero, whatever
 the lane held (no program resets a lane); a later chunk of a prefill and a
 decode tick start from what the lane holds and write it back in place (the
 tick through ``ops/pallas/ssm_scan.py``'s step kernel, which aliases the
-whole leaf). Nothing outlives the lane's request: a prefix hit would need
+whole leaf). A delta-rule layer's state has the same home and the same
+lifecycle (``KDAMixer``): ``S`` ``[d_k, heads, d_v]`` float32 a lane and
+layer (4 MB at 64 heads of 128: thirteen times the selective scan's) in
+``kda_state`` ``[kda layers, lanes, d_k, heads, d_v]`` and the last
+``kda_conv_size - 1`` rows of its three filtered projections side by side
+in ``kda_conv``, advanced by ``ops/pallas/kda.py``'s two kernels. Which
+leaves a lane-resident kind holds is ``block_fields.LANE_STATE_LEAVES``'s
+to say. Nothing outlives the lane's request: a prefix hit would need
 the state as it stood at the match's end, a snapshot this stack does not
 take (ROADMAP R5), so the engine refuses prefix reuse for it.
 
@@ -106,6 +114,10 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
+from fleetx_tpu.models.gpt.block_fields import (
+    LANE_STATE_LEAVES,
+    RECURRENT_TYPES,
+)
 from fleetx_tpu.models.gpt.hybrid import (
     POOL_LEAVES,
     HybridSelfAttention,
@@ -119,8 +131,8 @@ from fleetx_tpu.models.gpt.model import (
     _dense,
 )
 
-__all__ = ["MambaMixer", "MixedStack", "ShortConv", "layer_plan",
-           "state_rows"]
+__all__ = ["KDAMixer", "MambaMixer", "MixedStack", "ShortConv",
+           "layer_plan", "state_rows"]
 
 
 def layer_plan(cfg: GPTConfig) -> dict:
@@ -128,7 +140,7 @@ def layer_plan(cfg: GPTConfig) -> dict:
     ``num_layers`` entries: ``attention`` (1: attention, 0: the stack's
     recurrent kind), ``operator_index``, ``experts`` (1: expert layer, 0:
     dense), ``ffn_index``; the counts of every kind; and ``recurrent``, the
-    name of the recurrent kind ("conv" | "mamba", None without one).
+    name of the recurrent kind ("conv" | "mamba" | "kda", None without one).
 
     Under ``moe_shortcut`` every entry is one HALF of a double layer: each
     runs the dense MLP (``ffn_index`` its place among ALL halves) and
@@ -136,7 +148,7 @@ def layer_plan(cfg: GPTConfig) -> dict:
     (``shortcut_index`` its place in the experts' own stack, ``N`` entries
     for ``2N`` halves)."""
     attention = np.asarray([t.endswith("attention") for t in cfg.layer_types])
-    recurrent = next((t for t in ("mamba", "conv") if t in cfg.layer_types),
+    recurrent = next((t for t in RECURRENT_TYPES if t in cfg.layer_types),
                      None)
     halves = np.arange(cfg.num_layers)
     experts = (halves % 2 == 0 if cfg.moe_shortcut
@@ -152,6 +164,7 @@ def layer_plan(cfg: GPTConfig) -> dict:
             "recurrent": recurrent,
             "counts": {"conv": cfg.layer_types.count("conv"),
                        "mamba": cfg.layer_types.count("mamba"),
+                       "kda": cfg.layer_types.count("kda"),
                        "attention": int(attention.sum()),
                        "dense": int((~experts).sum()),
                        "experts": int(experts.sum())}}
@@ -242,10 +255,10 @@ def _begins(wpos):
 
 
 @contextlib.contextmanager
-def _moving_lane_state():
+def _moving_lane_state(leaf: str = "ssm_state"):
     """The device scope of what moves lane-resident state outside the
-    kernels (``cache_write/ssm_state``)."""
-    with jax.named_scope("cache_write"), jax.named_scope("ssm_state"):
+    kernels (``cache_write/ssm_state``, ``cache_write/kda_state``)."""
+    with jax.named_scope("cache_write"), jax.named_scope(leaf):
         yield
 
 
@@ -253,6 +266,27 @@ def _state_rows(rows):
     """The rows ``[b, s]`` of a call that advance the lane-resident state:
     those that are tokens."""
     return rows
+
+
+def _filter_inputs(conv_rows, x, taps: int):
+    """A causal depthwise filter's inputs for the call's rows ``x`` ``[b, s,
+    width]`` behind the ``taps - 1`` rows a lane holds of them, ``conv_rows``
+    ``[b, (taps - 1) x width]`` side by side (None: zeros). Returns what the
+    caller keeps (of a call of several rows ``xs`` ``[b, taps - 1 + s,
+    width]``, those of the state and the call together; of a call of ONE row
+    ``tail``, the rows held after it, side by side again: a one-row call
+    never leaves that form) and the ``taps`` shifted views the filter sums
+    over."""
+    b, s, width = x.shape
+    if conv_rows is None:
+        conv_rows = jnp.zeros((b, (taps - 1) * width), x.dtype)
+    conv_rows = conv_rows.astype(x.dtype)
+    if s == 1:
+        return ({"tail": jnp.concatenate([conv_rows[:, width:], x[:, 0]], -1)},
+                [conv_rows[:, None, i * width:(i + 1) * width]
+                 for i in range(taps - 1)] + [x])
+    xs = jnp.concatenate([conv_rows.reshape(b, taps - 1, width), x], 1)
+    return {"xs": xs}, [xs[:, i:i + s] for i in range(taps)]
 
 
 class MambaMixer(nn.Module):
@@ -298,17 +332,7 @@ class MambaMixer(nn.Module):
             lambda key, shape, dtype: _torch_conv_init(
                 key, shape + (taps,), dtype)[:, 0], ("mlp",)),
             (d,), jnp.float32)
-        if conv_rows is None:
-            conv_rows = jnp.zeros((b, (taps - 1) * d), uz.dtype)
-        conv_rows = conv_rows.astype(uz.dtype)
-        if s == 1:
-            kept = {"tail": jnp.concatenate([conv_rows[:, d:], u[:, 0]], -1)}
-            taken = [conv_rows[:, None, i * d:(i + 1) * d]
-                     for i in range(taps - 1)] + [u]
-        else:
-            xs = jnp.concatenate([conv_rows.reshape(b, taps - 1, d), u], 1)
-            kept = {"xs": xs}
-            taken = [xs[:, i:i + s] for i in range(taps)]
+        kept, taken = _filter_inputs(conv_rows, u, taps)
         u = nn.silu(bias.astype(jnp.float32) + sum(
             w[:, i].astype(jnp.float32) * t.astype(jnp.float32)
             for i, t in enumerate(taken)))
@@ -343,6 +367,107 @@ class MambaMixer(nn.Module):
         cfg = self.cfg
         y = _gated(mixed["y"], mixed["u"], mixed["z"],
                    mixed["D"]).astype(cfg.dtype)
+        return _dense(cfg.hidden_size, ("mlp", "embed"), "out_proj",
+                      use_bias=False, dtype=cfg.dtype)(y)
+
+
+def _kda_a_log_init(key, shape, dtype=jnp.float32):
+    """A head's ``A_log``: ``log(uniform(1, 16))``, as the family's public
+    port draws it (Mamba-2's)."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _l2_normed(x):
+    """``x / ||x||_2`` along the head's values (eps 1e-6 under the root)."""
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def _low_rank(rank: int, width: int, name: str, dtype, bias_init=None):
+    """``(x W_a) W_b (+ b)``: a gate's projection through ``rank``, the bias
+    on the second alone; float32 parameters."""
+    def apply(x):
+        x = nn.DenseGeneral(rank, use_bias=False, dtype=dtype,
+                            param_dtype=jnp.float32, name=name + "_a")(x)
+        return nn.DenseGeneral(
+            width, dtype=dtype, param_dtype=jnp.float32, name=name + "_b",
+            bias_init=bias_init or nn.initializers.zeros_init())(x)
+    return apply
+
+
+class KDAMixer(nn.Module):
+    """Kimi Delta Attention (a gated delta-rule linear attention), ``heads``
+    = ``kda_num_heads`` of ``d`` = ``kda_head_dim``: ``[q~ | k~ | v~] =
+    qkv_proj(a)``; each through a causal depthwise filter of
+    ``kda_conv_size`` taps (no bias, one filter a channel and stream) and a
+    SiLU; ``q = l2(q') d^-0.5``, ``k = l2(k')`` per head; the log decay ``g
+    = -exp(A_log[head]) softplus((a W_fa) W_fb + dt_bias)`` ``[heads, d]``;
+    ``beta = sigmoid(a W_b)`` ``[heads]``, doubled under ``kda_neg_eigval``;
+    the delta rule (``ops/pallas/kda.py``); ``out_proj(rms_o(o) * sigmoid((a
+    W_ga) W_gb + b_g))`` with ``rms_o``'s weight ``[d]``. The filters'
+    output, ``q, k, v``, ``g``, ``beta``, the state and ``o`` are float32;
+    the decay's and ``beta``'s small projections take float32 inputs at the
+    backend's default matmul precision.
+
+    In two phases, as :class:`MambaMixer` is (the layer loop keeps the state
+    itself): ``phase="project"`` takes ``a`` and ``conv_rows`` ``[b,
+    (taps - 1) x 3 x heads x d]`` (the filters' inputs at the positions
+    before the call's first, side by side) and returns what the delta rule
+    takes and the filter rows the caller keeps (``xs`` of a call of several
+    rows, ``tail`` of a call of one); ``phase="finish"`` takes that
+    dictionary with the rule's ``o`` in it. Without a phase: every position
+    at once from a zero state."""
+
+    cfg: GPTConfig
+
+    @nn.compact
+    def __call__(self, a, conv_rows=None, *, phase=None):
+        cfg = self.cfg
+        heads, d, taps = cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_conv_size
+        width = 3 * heads * d
+        if phase == "finish":
+            return self._finish(a)
+        b, s = a.shape[:2]
+        qkv = _dense(width, ("embed", "mlp"), "qkv_proj", use_bias=False,
+                     dtype=cfg.dtype)(a)
+        w = self.param("conv_kernel", nn.with_logical_partitioning(
+            _torch_conv_init, ("mlp", None)), (width, taps), jnp.float32)
+        kept, taken = _filter_inputs(conv_rows, qkv, taps)
+        with jax.named_scope("kda_conv"):
+            q, k, v = (t.reshape(b, s, heads, d) for t in jnp.split(
+                nn.silu(sum(w[:, i].astype(jnp.float32)
+                            * t.astype(jnp.float32)
+                            for i, t in enumerate(taken))), 3, axis=-1))
+        a32 = a.astype(jnp.float32)
+        decay = _low_rank(
+            cfg.kda_gate_rank, heads * d, "f", jnp.float32,
+            nn.with_logical_partitioning(_dt_bias_init, ("mlp",)))(a32)
+        a_log = self.param("A_log", _kda_a_log_init, (heads,), jnp.float32)
+        beta = jax.nn.sigmoid(nn.DenseGeneral(
+            heads, use_bias=False, dtype=jnp.float32, param_dtype=jnp.float32,
+            name="b_proj")(a32))
+        gate = _low_rank(cfg.kda_gate_rank, heads * d, "g", cfg.dtype)(a)
+        # (a served tree may hold every leaf in the compute dtype)
+        mixed = {"q": _l2_normed(q) * d ** -0.5, "k": _l2_normed(k), "v": v,
+                 "g": (-jnp.exp(a_log.astype(jnp.float32))[:, None]
+                       * jax.nn.softplus(decay.reshape(b, s, heads, d))),
+                 "beta": beta * 2.0 if cfg.kda_neg_eigval else beta,
+                 "gate": gate, **kept}
+        if phase == "project":
+            return mixed
+        from fleetx_tpu.ops.pallas.kda import kda_chunk_plain
+
+        rule = [mixed[n] for n in ("q", "k", "v", "g", "beta")]
+        mixed["o"] = jax.vmap(kda_chunk_plain)(
+            *rule, jnp.zeros((b, d, heads, d)))[0]
+        return self._finish(mixed)
+
+    def _finish(self, mixed):
+        cfg = self.cfg
+        o = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
+                       param_dtype=jnp.float32, name="o_norm")(mixed["o"])
+        b, s = o.shape[:2]
+        y = (o.reshape(b, s, -1) * jax.nn.sigmoid(
+            mixed["gate"].astype(jnp.float32))).astype(cfg.dtype)
         return _dense(cfg.hidden_size, ("mlp", "embed"), "out_proj",
                       use_bias=False, dtype=cfg.dtype)(y)
 
@@ -393,6 +518,7 @@ class MixedStack(nn.Module):
                      (x, jnp.zeros((1, state_rows(cfg), cfg.hidden_size),
                                    cfg.dtype)), {}),
             "mamba": (MambaMixer(cfg, parent=None), (x,), {}),
+            "kda": (KDAMixer(cfg, parent=None), (x,), {}),
             "attention": (HybridSelfAttention(cfg, parent=None), (x,),
                           {"layer_index": jnp.int32(0), "rope": rope}),
             "dense": (MLP(dense_cfg, parent=None), (x,), {}),
@@ -440,8 +566,9 @@ class MixedStack(nn.Module):
         """The cache collection's variables (None outside a cached forward
         and at its init, which only declares them): the attention layers'
         flat pool (``hybrid.init_cache`` sizes it), the convolution layers'
-        tail pages or the selective-scan layers' state of every lane (the
-        init's batch is the lanes), the expert layers' counters."""
+        tail pages or the selective-scan or delta-rule layers' state of
+        every lane (the init's batch is the lanes), the expert layers'
+        counters."""
         cfg = self.cfg
         if not decode:
             return None
@@ -476,6 +603,17 @@ class MixedStack(nn.Module):
             held["ssm_conv"] = self.variable(
                 "cache", "ssm_conv", jnp.zeros,
                 (n, lanes, (cfg.mamba_d_conv - 1) * d), cfg.dtype)
+        elif counts["kda"]:
+            # [d_k, heads, d_v]: eight heads along a register's sublanes
+            # (ops/pallas/kda.py); the filter rows as the scan's are kept
+            n, d = counts["kda"], cfg.kda_head_dim
+            held["kda_state"] = self.variable(
+                "cache", "kda_state", jnp.zeros,
+                (n, lanes, d, cfg.kda_num_heads, d), jnp.float32)
+            held["kda_conv"] = self.variable(
+                "cache", "kda_conv", jnp.zeros,
+                (n, lanes, (cfg.kda_conv_size - 1) * 3 * cfg.kda_inner),
+                cfg.dtype)
         elif counts["conv"]:
             held["conv_state"] = self.variable(
                 "cache", "conv_state", jnp.zeros,
@@ -505,20 +643,21 @@ class MixedStack(nn.Module):
             wpos = cache_positions.astype(jnp.int32)
             rows = (jnp.ones((b, s), bool) if rows is None
                     else rows.astype(bool))
-            if recurrent == "mamba":
+            if recurrent in LANE_STATE_LEAVES:
                 # column 0: the lane, where its state is held (module
                 # docstring); a tick is handed every lane in order
+                state_leaf, rows_leaf = LANE_STATE_LEAVES[recurrent][1]
                 lanes, tables = tables[:, 0], tables[:, 1:]
-                tick = s == 1 and b == pools["ssm_state"].shape[1]
+                tick = s == 1 and b == pools[state_leaf].shape[1]
                 if not tick and b != 1:
                     raise NotImplementedError(
                         "state held once a lane takes a tick over every lane "
                         f"in order or a call of ONE lane, not {b} of "
-                        f"{pools['ssm_state'].shape[1]} lanes")
+                        f"{pools[state_leaf].shape[1]} lanes")
                 begins, advancing = _begins(wpos), _state_rows(rows)
         norm = _norm(cfg)
         conv_op, attn_op = kinds["conv"][0], kinds["attention"][0]
-        mamba_op = kinds["mamba"][0]
+        mamba_op, kda_op = kinds["mamba"][0], kinds["kda"][0]
 
         def zeros_like_of(fn):
             """What ``fn()`` returns, as zeros: the other branch's share of
@@ -588,6 +727,29 @@ class MixedStack(nn.Module):
                     {"params": _at(params["mamba"]["op"], index)}, mixed,
                     phase="finish")
 
+        def kda(value, index, held=None):
+            """The delta-rule operator's projections (``KDAMixer`` phase
+            "project") from the filter rows ``held``; ``g`` and ``beta``
+            zero in the rows that are no tokens, which then leave ``S``
+            alone."""
+            a = normed("kda", index, value)
+            with jax.named_scope("kda_mix"):
+                mixed = kda_op.apply(
+                    {"params": _at(params["kda"]["op"], index)}, a, held,
+                    phase="project")
+            if held is not None:
+                mixed["g"] = jnp.where(advancing[..., None, None],
+                                       mixed["g"], 0.0)
+                mixed["beta"] = jnp.where(advancing[..., None],
+                                          mixed["beta"], 0.0)
+            return mixed
+
+        def kda_finish(mixed, index):
+            with jax.named_scope("kda_mix"):
+                return kda_op.apply(
+                    {"params": _at(params["kda"]["op"], index)}, mixed,
+                    phase="finish")
+
         def scan(mixed, h0, skip=None):
             from fleetx_tpu.ops.pallas.ssm_scan import selective_scan
 
@@ -604,10 +766,27 @@ class MixedStack(nn.Module):
         def filter_rows(conv_pool, index):
             """The filter rows the call's lanes hold, side by side ``[b,
             (taps - 1) x d]``."""
-            with _moving_lane_state():
+            with _moving_lane_state(state_leaf):
                 return conv_pool[index] if tick else (
                     jax.lax.dynamic_slice_in_dim(conv_pool[index], lanes[0],
                                                  1, axis=0))
+
+        def keep_filter_rows(pools, mixed, held, mixes, index, taps):
+            """``pools[rows_leaf]`` takes the filter's inputs at the last
+            ``taps - 1`` positions that are tokens: a lane with no token
+            keeps the rows it held (``xs`` begins with them), as a layer of
+            another kind does."""
+            with _moving_lane_state(state_leaf):
+                if s == 1:
+                    last = jnp.where(advancing, mixed["tail"], held)
+                else:
+                    last = jax.lax.dynamic_slice_in_dim(
+                        mixed["xs"], advancing.sum().astype(jnp.int32),
+                        taps - 1, axis=1)
+                last = jnp.where(mixes, held, last.reshape(b, -1))[None]
+                pools[rows_leaf] = jax.lax.dynamic_update_slice(
+                    pools[rows_leaf], last.astype(pools[rows_leaf].dtype),
+                    (index, 0 if tick else lanes[0], 0))
 
         def ssm_update(pools, mixed, held, mixes, index):
             """``y`` of the call's rows; ``pools`` (the caller's own dict)
@@ -627,29 +806,49 @@ class MixedStack(nn.Module):
                     y = y[:, None]
             else:
                 at = (index, lanes[0], 0, 0)
-                with _moving_lane_state():
+                with _moving_lane_state(state_leaf):
                     h0 = jnp.where(fresh[:, None, None], 0.0,
                                    jax.lax.dynamic_slice(
                                        state, at, (1, 1) + state.shape[2:])[0])
                 y, h = scan(mixed, h0, skip=mixes)
-                with _moving_lane_state():
+                with _moving_lane_state(state_leaf):
                     state = jax.lax.dynamic_update_slice(state, h[None], at)
             pools["ssm_state"] = state
-            with _moving_lane_state():
-                # the filter's inputs at the last positions that are tokens:
-                # a lane with no token keeps the rows it held (``xs`` begins
-                # with them)
-                if s == 1:
-                    last = jnp.where(advancing, mixed["tail"], held)
-                else:
-                    last = jax.lax.dynamic_slice_in_dim(
-                        mixed["xs"], advancing.sum().astype(jnp.int32),
-                        cfg.mamba_d_conv - 1, axis=1)
-                last = jnp.where(mixes, held, last.reshape(b, -1))[None]
-                pools["ssm_conv"] = jax.lax.dynamic_update_slice(
-                    pools["ssm_conv"], last.astype(pools["ssm_conv"].dtype),
-                    (index, 0 if tick else lanes[0], 0))
+            keep_filter_rows(pools, mixed, held, mixes, index,
+                             cfg.mamba_d_conv)
             return y
+
+        def kda_update(pools, mixed, held, mixes, index):
+            """``o`` of the call's rows; ``pools`` takes the leaves with the
+            lanes' state advanced over them, in place, as ``ssm_update``
+            does. A layer of another kind (``mixes``) is skipped by the
+            kernels and keeps the filter rows held."""
+            from fleetx_tpu.ops.pallas.kda import kda_chunk, kda_step
+
+            state, fresh = pools["kda_state"], begins & ~mixes
+            rule = [mixed[n] for n in ("q", "k", "v", "g", "beta")]
+            if tick:
+                with jax.named_scope("kda_mix"), jax.named_scope("kda_step"):
+                    o, state = kda_step(
+                        state, index, *(t[:, 0] for t in rule), fresh,
+                        skip=mixes, kernel=cfg.use_flash_attention)
+                    o = o[:, None]
+            else:
+                at = (index, lanes[0], 0, 0, 0)
+                with _moving_lane_state(state_leaf):
+                    s0 = jnp.where(fresh[0], 0.0, jax.lax.dynamic_slice(
+                        state, at, (1, 1) + state.shape[2:])[0, 0])
+                with jax.named_scope("kda_mix"), jax.named_scope("kda_chunk"):
+                    o, last = kda_chunk(*(t[0] for t in rule), s0, skip=mixes,
+                                        kernel=cfg.use_flash_attention)
+                    o = o[None]
+                with _moving_lane_state(state_leaf):
+                    state = jax.lax.dynamic_update_slice(
+                        state, last[None, None], at)
+            pools["kda_state"] = state
+            keep_filter_rows(pools, mixed, held, mixes, index,
+                             cfg.kda_conv_size)
+            return o
 
         # what an attention kind sows for whoever holds it to a reference
         # (latent attention's index scores and sets), every layer's
@@ -673,16 +872,16 @@ class MixedStack(nn.Module):
             finishes (attends; the mixer's gate and output projection). A
             pool that a conditional hands back is copied whole by XLA: 1.3
             GB in every attention layer at the served sizes."""
-            held = (filter_rows(pools["ssm_conv"], index)
-                    if recurrent == "mamba" else None)
+            held = (filter_rows(pools[rows_leaf], index)
+                    if recurrent in LANE_STATE_LEAVES else None)
 
             def recur():
                 if recurrent == "conv":
                     return dict(zip(("y", "z"),
                                     conv(value, index, pools["conv_state"])))
                 # (a lane that begins a sequence begins from zeros)
-                return mamba(value, index,
-                             jnp.where(begins[:, None], 0, held))
+                return (kda if recurrent == "kda" else mamba)(
+                    value, index, jnp.where(begins[:, None], 0, held))
 
             def project():  # (a fourth: the third leaf's rows)
                 return dict(zip(("q", "k", "v", "index"), attention(
@@ -712,6 +911,8 @@ class MixedStack(nn.Module):
                         rows & ~mixes, index, mixed["z"])
             elif recurrent == "mamba":
                 mixed["y"] = ssm_update(pools, mixed, held, mixes, index)
+            elif recurrent == "kda":
+                mixed["o"] = kda_update(pools, mixed, held, mixes, index)
             leaves = [n for n in POOL_LEAVES if n in pools]
             if counts["attention"]:
                 pools.update(zip(leaves, write_rows(
@@ -730,16 +931,29 @@ class MixedStack(nn.Module):
 
             def finish_step():
                 y = (mixed["y"] if recurrent == "conv"
+                     else kda_finish(mixed, index) if recurrent == "kda"
                      else mamba_finish(mixed, index))
                 return joins(recurrent, index, y), {}
 
-            return (*pick(mixes, both, attend_step, finish_step), pools)
+            y, seen = pick(mixes, both, attend_step, finish_step)
+            if probed and recurrent == "kda":
+                # what the delta rule was handed and gave, every layer's
+                # (zeros in a layer of another kind), for whoever holds the
+                # rule to a reference ON THE ROWS IT REALLY SAW
+                seen = {**seen, **{"kda_" + n: mixed[n] for n in (
+                    "q", "k", "v", "g", "beta", "o")}}
+            return y, seen, pools
 
         def plain(value, mixes, index, pools):
             """The operator outside a cache: every position at once."""
             def recur():
                 if recurrent == "conv":
                     return conv(value, index)[0]
+                if recurrent == "kda":
+                    with jax.named_scope("kda_mix"):
+                        return kda_op.apply(
+                            {"params": _at(params["kda"]["op"], index)},
+                            normed("kda", index, value))
                 mixed = mamba(value, index)
                 mixed["y"] = scan(mixed, jnp.zeros(
                     (b, cfg.mamba_d_state, cfg.mamba_inner)))[0]

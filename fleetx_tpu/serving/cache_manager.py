@@ -80,14 +80,19 @@ tiers and the page ship between replicas do not carry tails and are
 refused for such a model at the engine's construction;
 :meth:`PagedKVCacheManager.class_counters` counts the snapshots and the
 bytes of either kind. ONCE A LANE (selective-scan layers, state kind
-"ssm"): a state of hundreds of kilobytes a layer cannot be kept a page, so
+"ssm"; delta-rule linear attention layers, state kind "kda"): a state of
+hundreds of kilobytes or of megabytes a layer cannot be kept a page, so
 the cache tree holds it in leaves indexed by the LANE (``ssm_state``,
-``ssm_conv``: ``[layers, lanes, ...]``), beside the pool and outside it.
+``ssm_conv``; ``kda_state``, ``kda_conv``: ``[layers, lanes, ...]``), beside
+the pool and outside it. Which kind a model holds so, and in which leaves,
+the MODEL says (``cfg.lane_state``: ``models/gpt/block_fields.py``
+``LANE_STATE_LEAVES``); nothing here knows a kind by name.
 The manager's part is the address: :attr:`PagedKVCacheManager.tables` then
 has the lane's own index as column 0 of every row, before the pages, and
 the model reads and writes the lane's state there. Its lifecycle is the
 lane's and needs no program: a call at position 0 begins from zero (every
-:meth:`PagedKVCacheManager.alloc` is counted as ``ssm_state_resets``), a
+:meth:`PagedKVCacheManager.alloc` is counted under the kind's own name,
+``ssm_state_resets`` or ``kda_state_resets``), a
 later chunk and a tick carry on from what the lane holds, a freed lane's
 state is dead weight until the next request begins over it. Nothing of it
 is shared: prefix reuse is refused for such a model at construction (a
@@ -1295,12 +1300,16 @@ class PagedKVCacheManager(_LaneBook):
                 f"{page_size})")
         # the kinds of state a lane keeps (module docstring, "Kinds of
         # state"): keys and values, a convolution's tail rows in the pool,
-        # a selective scan's state once a lane
+        # a selective scan's or a delta rule's state once a lane (the model
+        # names that kind and the leaves it holds)
         self.state_kinds = tuple(getattr(cfg, "state_kinds", ("kv",)))
-        self.lane_state = "ssm" in self.state_kinds
+        self.lane_state_kind, lane_leaves = getattr(cfg, "lane_state",
+                                                    ("", ()))
+        self.lane_state = bool(self.lane_state_kind)
         if self.lane_state and prefix_cache:
             raise ValueError(
-                "prefix reuse over selective-scan layers: a lane's state is "
+                "prefix reuse over selective-scan or delta-rule layers "
+                f"(state kind {self.lane_state_kind!r}): a lane's state is "
                 "kept once a lane, not at page boundaries, so a matched "
                 "prefix has no state to resume from (prefix_cache=False)")
         self._init_lanes(slots)
@@ -1349,7 +1358,7 @@ class PagedKVCacheManager(_LaneBook):
                 self.index_pool_bytes = leaf_device_nbytes(leaf)
             if kind and self.state_kinds != ("kv",):
                 self.page_bytes[kind] += leaf_device_nbytes(leaf) // num_pages
-            elif name in ("ssm_state", "ssm_conv"):
+            elif name in lane_leaves:
                 self.lane_bytes += leaf_device_nbytes(leaf) // slots
 
     # ------------------------------------------------------ host spill tier
@@ -1493,7 +1502,7 @@ class PagedKVCacheManager(_LaneBook):
             return {
                 # every lane's state is resident, whoever holds the lane
                 "state_bytes_lanes": self.slots * self.lane_bytes,
-                "ssm_state_resets": self.state_resets,
+                f"{self.lane_state_kind}_state_resets": self.state_resets,
                 "kv_page_bytes_in_use": (self.pool.pages_in_use
                                          * self.page_bytes["kv"])}
         if "latent" in self.state_kinds:

@@ -641,7 +641,8 @@ class ServingEngine:
         # state held once a lane (cache_manager.py "Kinds of state") is
         # nothing a matched prefix could resume
         self._state_rows = self.capabilities.state_kinds != ("kv",)
-        self._lane_state = "ssm" in self.capabilities.state_kinds
+        self._lane_state = getattr(model.cfg, "lane_state",
+                                   ("", ()))[0]  # the kind's name, or ""
         if self._lane_state:
             self.capabilities.require(
                 supports_prefix_cache=bool(prefix_cache))
@@ -1997,7 +1998,7 @@ class ServingEngine:
         if self._state_rows:
             out["state_bytes"] = {
                 "kv": classes["kv_page_bytes_in_use"],
-                "ssm" if self._lane_state else "conv": (
+                self._lane_state or "conv": (
                     classes["state_bytes_lanes"]
                     + classes.get("state_bytes_snapshots", 0))}
         return out
@@ -2396,7 +2397,7 @@ class ServingEngine:
 
     def _scan_rows(self, n: int, shared: int) -> dict:
         """Span fields of a prefill call: over lane-resident state the rows
-        its selective scans run over, padding included; over layers with a
+        its scans or delta rules run over, padding included; over layers with a
         kind what the configuration counts of its chunk kernels' work
         (``block_fields.spans``)."""
         cfg, bucket = self.model.cfg, self._bucket_rows(n, shared)
@@ -2890,7 +2891,7 @@ class ServingEngine:
             cfg = self.model.cfg
             fields = {cfg.rows_span_field: int(rows.sum()),
                       **({"state_lanes": len(lanes)} if self._lane_state
-                         else cfg.span_pairs(self.slots))}
+                         else {}), **cfg.span_pairs(self.slots)}
             if cfg.indexed:
                 # a lane scores every index key behind its token and
                 # attends over the rows the indexer keeps of them

@@ -107,8 +107,11 @@ class ModelCapabilities:
     #: model with selective-scan layers, "ssm" (the scan's state and its
     #: filter's last inputs, held ONCE A LANE outside the pool and updated
     #: in place): no page holds it, so a prefix hit has nothing to resume
-    #: and prefix reuse is refused. Neither recurrent kind is spilled to
-    #: the host tiers or shipped between replicas
+    #: and prefix reuse is refused; for a model with delta-rule linear
+    #: attention layers, "kda" (the rule's matrix state a head and its
+    #: three filters' last inputs: the same home, the same refusals; which
+    #: kind is lane-resident is ``cfg.lane_state``'s to say). No recurrent
+    #: kind is spilled to the host tiers or shipped between replicas
     state_kinds: tuple = ("kv",)
     supports_host_spill: bool = True
 
@@ -139,16 +142,18 @@ _FEATURES = {
     "supports_mesh": "a serving mesh: no test covers it",
     "supports_prefix_cache": "prefix reuse: its window-attention layers "
                              "release a prefix's pages once the window has "
-                             "passed them, or its selective-scan layers keep "
+                             "passed them, or its selective-scan or "
+                             "delta-rule layers keep "
                              "their state once a lane, with no snapshot at "
                              "the match's end to resume from",
     "supports_roles": "a prefill or decode role: the pages of its window "
                       "class, the tail pages of its convolution state, or "
-                      "the lane-resident state of its selective-scan "
-                      "layers, are not shipped between replicas",
+                      "the lane-resident state of its selective-scan or "
+                      "delta-rule layers, are not shipped between replicas",
     "supports_host_spill": "a host or disk page tier: the tail pages of its "
                            "convolution state, or the lane-resident state "
-                           "of its selective-scan layers, are not spilled",
+                           "of its selective-scan or delta-rule layers, are "
+                           "not spilled",
 }
 
 
@@ -269,7 +274,8 @@ class GPTExecutor(ModelExecutor):
             supports_int8_kv=dense,
             supports_mesh=dense,
             page_classes=("full", "window") if windowed else ("full",),
-            supports_prefix_cache=not windowed and "ssm" not in state,
+            supports_prefix_cache=not windowed and not getattr(
+                model.cfg, "lane_state", ("", ()))[0],
             supports_roles=not windowed and not recurrent,
             state_kinds=state,
             supports_host_spill=not recurrent,

@@ -98,6 +98,12 @@ _CANNOT_APPLY = {
         "(a benchmark PR's to extend); tests/perfbench/"
         "test_perfbench_longcat.py holds this configuration's count to the "
         "program's own model",
+    "tests/perfbench/test_perfbench_flops.py::"
+    "test_param_count_matches_the_programs_model"
+    "[perfbench/configs/solar-open2-ep16-l8.json]":
+        "perfbench/flops.py gpt_param_count counts the GPT-2 block only "
+        "(a benchmark PR's to extend); tests/test_solar2_serving.py holds "
+        "this configuration's count to the program's own model",
     "tests/perfbench/test_perfbench_traffic.py::"
     "test_order_seed_pins_tenants_and_lengths_and_leaves_the_seed_the_tokens"
     "[mixed-longshort]":
@@ -143,6 +149,13 @@ _CANNOT_APPLY_FROM = (
      "PR 37 alone (a benchmark PR's to extend); tests/perfbench/"
      "test_perfbench_longcat.py makes this cell's traced rehearsal and holds "
      "every entry that lists it"),
+    ("tests/perfbench/test_perfbench_rehearsal.py::"
+     "test_traced_rehearsal_reports_a_shared_entry_in_each_cell_it_lists"
+     "[solar2-l8-serve-docreason-mixed-",
+     "test_perfbench_rehearsal.py's TINY_REPORTS has a row for the cells of "
+     "PR 37 alone (a benchmark PR's to extend); tests/perfbench/"
+     "test_perfbench_solar2.py makes this cell's traced rehearsal and holds "
+     "the entries that list it"),
 )
 
 
@@ -158,26 +171,35 @@ _SETUP_ENTRIES = ("setup_trace_lower_s", "setup_cache_load_s",
 # (PR 53: the LongCat-Flash cell and the two entries it brought)
 _LONGCAT = ("longcat-l4-serve-rollout-skewed", "moe_zero_pairs_share",
             "moe_zero_busy_share")
+# (PR 56: the Solar-Open2 cell and the five entries it brought)
+_SOLAR2 = ("solar2-l8-serve-docreason-mixed", "kda_mix_busy_share",
+           "kda_chunk_busy_share", "kda_step_busy_share",
+           "kda_chunk_roofline", "kda_step_roofline")
 _WRITTEN_BEFORE = {
     "tests/perfbench/test_perfbench_lfm2.py::"
     "test_every_width_is_the_published_one_and_only_the_depth_is_cut":
         ("jamba2-3b-serve-chat-peak", "axk1-l6-serve-docqa-latent",
          "dsv32-l5-serve-longqa-sparse", "trinity-l5-serve-mixed-longshort")
-        + _LONGCAT,
+        + _LONGCAT + _SOLAR2,
     "tests/perfbench/test_perfbench_jamba2.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
         ("axk1-l6-serve-docqa-latent", "dsv32-l5-serve-longqa-sparse",
-         "trinity-l5-serve-mixed-longshort") + _SETUP_ENTRIES + _LONGCAT,
+         "trinity-l5-serve-mixed-longshort") + _SETUP_ENTRIES + _LONGCAT
+        + _SOLAR2,
     "tests/perfbench/test_perfbench_axk1.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
         ("dsv32-l5-serve-longqa-sparse", "trinity-l5-serve-mixed-longshort")
-        + _SETUP_ENTRIES + _LONGCAT,
+        + _SETUP_ENTRIES + _LONGCAT + _SOLAR2,
     "tests/perfbench/test_perfbench_dsv32.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
-        ("trinity-l5-serve-mixed-longshort",) + _SETUP_ENTRIES + _LONGCAT,
+        ("trinity-l5-serve-mixed-longshort",) + _SETUP_ENTRIES + _LONGCAT
+        + _SOLAR2,
     "tests/perfbench/test_perfbench_trinity.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
-        _SETUP_ENTRIES + _LONGCAT,
+        _SETUP_ENTRIES + _LONGCAT + _SOLAR2,
+    "tests/perfbench/test_perfbench_longcat.py::"
+    "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
+        _SOLAR2,
 }
 
 

@@ -367,6 +367,10 @@ def test_the_lanes_state_is_counted_once_a_lane(variables):
     assert counters["ssm_state_resets"] == 1
     assert engine.health()["state_bytes"] == {"kv": 0, "ssm": 3 * lane}
     assert engine.capabilities.state_kinds == ("kv", "ssm")
+    # the once-a-lane home knows the kind and its leaves from the MODEL
+    # (``cfg.lane_state``), under whose name the resets are counted
+    assert build().cfg.lane_state == ("ssm", ("ssm_state", "ssm_conv"))
+    assert engine.cache_manager.lane_state_kind == "ssm"
     # the lane's address leads its row of the table
     np.testing.assert_array_equal(engine.cache_manager.tables[:, 0],
                                   np.arange(3))

@@ -375,7 +375,7 @@ def test_the_stack_builds_from_the_published_list_with_no_unused_parameter():
     assert len(types) == 24 and types[:14] == config["layer_types"]
     model = build(num_layers=24, layer_types=tuple(types))
     plan = mixed_stack.layer_plan(model.cfg)
-    assert plan["counts"] == {"conv": 18, "mamba": 0, "attention": 6,
+    assert plan["counts"] == {"conv": 18, "mamba": 0, "kda": 0, "attention": 6,
                               "dense": 2, "experts": 22}
     held = flax.core.meta.unbox(jax.jit(model.init)(
         jax.random.PRNGKey(0), np.zeros((1, 8), np.int32)))

@@ -89,7 +89,7 @@ def test_the_plan_gives_every_half_its_places():
     assert plan["ffn_index"].tolist() == [0, 1, 2, 3]      # a dense MLP a half
     assert plan["experts"].tolist() == [1, 0, 1, 0]        # leaves at even halves
     assert plan["shortcut_index"].tolist() == [0, 0, 1, 1]  # the experts' own stack
-    assert plan["counts"] == {"conv": 0, "mamba": 0, "attention": 4,
+    assert plan["counts"] == {"conv": 0, "mamba": 0, "kda": 0, "attention": 4,
                               "dense": 4, "experts": 2}
     assert cfg.expert_layers == 2 and cfg.router_width == 12
     assert cfg.span_pairs(5) == {"pairs": 5 * 3 * 2}

@@ -118,8 +118,9 @@ def test_dense_layers_count_nothing_in_the_expert_counters():
 
     cfg = dataclasses.replace(GPTConfig(**SIZES), decode_cache_len=32,
                               decode_num_pages=9, decode_page_size=8)
-    assert layer_plan(cfg)["counts"] == {"conv": 2, "mamba": 0, "attention": 1,
-                                         "dense": 1, "experts": 2}
+    assert layer_plan(cfg)["counts"] == {"conv": 2, "mamba": 0, "kda": 0,
+                                         "attention": 1, "dense": 1,
+                                         "experts": 2}
     cache = init_decode_cache(GPTForPretraining(cfg), 2)["gpt"]["layers"]
     assert cache["moe_stats"].shape == (2, 16)
     assert cache["cached_key"].shape == (9, 8, 32)        # one attention layer
