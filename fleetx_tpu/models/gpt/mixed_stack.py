@@ -742,13 +742,25 @@ class MixedStack(nn.Module):
                                        mixed["g"], 0.0)
                 mixed["beta"] = jnp.where(advancing[..., None],
                                           mixed["beta"], 0.0)
+            if held is not None and not tick:
+                # a call of one lane's rows leaves the conditional as the
+                # projections lay it out, ``[b, s, heads x d]``, which is
+                # what the chunk kernel reads: ``[b, s, heads, d]`` across
+                # the conditional is another tiling, a copy there and back
+                for name in ("q", "k", "v", "g"):
+                    mixed[name] = mixed[name].reshape(b, s, -1)
             return mixed
+
+        def by_head(rows):
+            """``[b, s, heads x d]`` (or by head already) ``[b, s, heads,
+            d]``."""
+            return rows.reshape(b, s, cfg.kda_num_heads, -1)
 
         def kda_finish(mixed, index):
             with jax.named_scope("kda_mix"):
                 return kda_op.apply(
-                    {"params": _at(params["kda"]["op"], index)}, mixed,
-                    phase="finish")
+                    {"params": _at(params["kda"]["op"], index)},
+                    {**mixed, "o": by_head(mixed["o"])}, phase="finish")
 
         def scan(mixed, h0, skip=None):
             from fleetx_tpu.ops.pallas.ssm_scan import selective_scan
@@ -839,9 +851,10 @@ class MixedStack(nn.Module):
                     s0 = jnp.where(fresh[0], 0.0, jax.lax.dynamic_slice(
                         state, at, (1, 1) + state.shape[2:])[0, 0])
                 with jax.named_scope("kda_mix"), jax.named_scope("kda_chunk"):
-                    o, last = kda_chunk(*(t[0] for t in rule), s0, skip=mixes,
-                                        kernel=cfg.use_flash_attention)
-                    o = o[None]
+                    o, last = kda_chunk(
+                        *(by_head(t)[0] for t in rule[:4]), rule[4][0], s0,
+                        skip=mixes, kernel=cfg.use_flash_attention)
+                    o = o.reshape(b, s, -1)
                 with _moving_lane_state(state_leaf):
                     state = jax.lax.dynamic_update_slice(
                         state, last[None, None], at)
@@ -940,8 +953,9 @@ class MixedStack(nn.Module):
                 # what the delta rule was handed and gave, every layer's
                 # (zeros in a layer of another kind), for whoever holds the
                 # rule to a reference ON THE ROWS IT REALLY SAW
-                seen = {**seen, **{"kda_" + n: mixed[n] for n in (
-                    "q", "k", "v", "g", "beta", "o")}}
+                seen = {**seen, "kda_beta": mixed["beta"],
+                        **{"kda_" + n: by_head(mixed[n])
+                           for n in ("q", "k", "v", "g", "o")}}
             return y, seen, pools
 
         def plain(value, mixes, index, pools):

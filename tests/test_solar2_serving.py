@@ -213,20 +213,110 @@ def _rows(rng, rows, heads=2, d=32, decay=3.0):
             2.0 * jax.nn.sigmoid(draw(rows, heads)))
 
 
-def test_the_chunk_kernel_is_its_plain_twin(interpreted, rows=272, decay=3.0):
-    """2 heads of 32, interpreted: 272 rows in blocks of 16 (the state
-    resident from block to block) whose summed log decay passes -250 in a
-    block; ``skip`` hands the state back."""
+def _state(rng):
+    """A state that is not zero, in its home layout ``[d_k, heads, d_v]``."""
+    return jnp.asarray(rng.standard_normal((32, 2, 32)), jnp.float32)
+
+
+@pytest.mark.parametrize("rows,decay", [
+    (16, 3.0), (96, 0.1), (272, 3.0), (272, 0.1), (512, 3.0), (20, 3.0)])
+def test_the_chunk_kernel_is_its_plain_twin(interpreted, rows, decay):
+    """2 heads of 32, interpreted, from a state that is not zero: one
+    sub-block a block (16 rows; 272 in 17 blocks, the state resident from
+    one to the next), two (96 in blocks of 32), four (512 in blocks of 64:
+    the sub-blocks below the diagonal go through the products); at decay 3.0
+    every channel's summed log decay over a block of 64 rows is under -100
+    (``exp(-cumsum g)`` overflows float32 at 88); no block divides 20 rows
+    (the plain scan itself); ``skip`` hands the state back."""
     rng = np.random.default_rng(rows)
     operands = _rows(rng, rows, decay=decay)
-    s0 = jnp.asarray(rng.standard_normal((32, 2, 32)), jnp.float32)
-    o, s = kda.kda_chunk(*operands, s0)
+    s0 = _state(rng)
+    if decay == 3.0 and rows >= 64:
+        assert float(operands[3][:64].sum(0).max()) < -100
+    # (one trace for both calls: interpreted, the block's unrolled body is
+    # most of a case's seconds)
+    run = jax.jit(lambda *a: kda.kda_chunk(*a[:-1], skip=a[-1]))
+    o, s = run(*operands, s0, False)
     want_o, want_s = kda.kda_chunk_plain(*operands, s0)
     assert np.isfinite(np.asarray(s)).all()
     np.testing.assert_allclose(o, want_o, atol=2e-5)
     np.testing.assert_allclose(s, want_s, atol=2e-5)
-    _, kept = kda.kda_chunk(*operands, s0, skip=jnp.asarray(True))
-    np.testing.assert_array_equal(kept, s0)
+    np.testing.assert_array_equal(run(*operands, s0, True)[1], s0)
+
+
+def test_rows_that_are_no_token_leave_the_state_of_the_rows_before(
+        interpreted, rows=96):
+    """The last third padded (``g = beta = 0``, the caller's mask): the
+    state is the one the live rows left, whole blocks of padding and a
+    block's padded tail alike."""
+    rng = np.random.default_rng(3)
+    q, k, v, g, beta = _rows(rng, rows, decay=0.5)
+    live = jnp.arange(rows) < 2 * rows // 3 + 8        # ends inside a block
+    padded = (q, k, v, jnp.where(live[:, None, None], g, 0.0),
+              jnp.where(live[:, None], beta, 0.0))
+    s0 = _state(rng)
+    _, s = kda.kda_chunk(*padded, s0)
+    count = int(live.sum())
+    _, want = kda.kda_chunk_plain(*(t[:count] for t in padded), s0)
+    np.testing.assert_allclose(s, want, atol=1e-6)
+
+
+def test_the_state_carried_across_blocks_is_two_calls_of_half_the_rows(
+        interpreted, rows=128):
+    """Two blocks of 64 rows in one call, the state resident between them,
+    against a call a block: the same arithmetic (to 2e-6: two programs of
+    the interpreter, fused differently)."""
+    rng = np.random.default_rng(4)
+    operands = _rows(rng, rows, decay=0.5)
+    s0 = _state(rng)
+    run = jax.jit(kda.kda_chunk)
+    o, s = run(*operands, s0)
+    first, mid = run(*(t[:rows // 2] for t in operands), s0)
+    second, last = run(*(t[rows // 2:] for t in operands), mid)
+    np.testing.assert_allclose(o, jnp.concatenate([first, second]),
+                               atol=2e-6)
+    np.testing.assert_allclose(s, last, atol=2e-6)
+
+
+def test_no_exponent_is_positive_and_every_product_is_float32(
+        interpreted, monkeypatch):
+    """An ``exp`` that answers NaN to a positive exponent changes nothing
+    (the one exponent taken is a row's own log decay; the decay between two
+    rows is a product of those), and the kernel's products take float32
+    operands at ``HIGHEST``, Mosaic's float32 contraction (its default is
+    one bfloat16 pass)."""
+    class Strict:
+        def __getattr__(self, name):
+            return getattr(jnp, name)
+
+        @staticmethod
+        def exp(x):
+            return jnp.where(x > 0, jnp.nan, jnp.exp(x))
+
+    rng = np.random.default_rng(5)
+    operands = _rows(rng, 32, decay=3.0)
+    s0 = _state(rng)
+    want_o, want_s = kda.kda_chunk(*operands, s0)
+    monkeypatch.setattr(kda, "jnp", Strict())
+    o, s = kda.kda_chunk(*operands, s0)
+    np.testing.assert_allclose(o, want_o, atol=2e-6)    # (a NaN fails it)
+    np.testing.assert_allclose(s, want_s, atol=2e-6)
+
+    def products(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                yield eqn
+            for param in eqn.params.values():
+                for inner in param if isinstance(param, tuple) else (param,):
+                    inner = getattr(inner, "jaxpr", inner)
+                    if hasattr(inner, "eqns"):
+                        yield from products(inner)
+
+    found = list(products(jax.make_jaxpr(kda.kda_chunk)(*operands, s0).jaxpr))
+    assert found
+    for eqn in found:
+        assert {str(v.aval.dtype) for v in eqn.invars} == {"float32"}
+        assert set(eqn.params["precision"]) == {jax.lax.Precision.HIGHEST}
 
 
 def test_the_step_kernel_updates_one_layer_of_the_leaf_in_place(interpreted):
@@ -430,21 +520,22 @@ def one_chip():
 
 def test_the_kernels_compile_for_the_v5e_at_the_published_widths(
         one_chip, monkeypatch):
-    """64 heads of 128: a chunk of 512 rows of one lane, and the tick's step
-    over the cell's whole leaf ``[6, 48, 128, 64, 128]``, aliased to its
-    output (no copy of it in the program)."""
+    """64 heads of 128: a chunk of 512 rows of one lane and the last bucket's
+    256, and the tick's step over the cell's whole leaf ``[6, 48, 128, 64,
+    128]``, aliased to its output (no copy of it in the program)."""
     monkeypatch.setattr(kda, "_interpret", lambda: False)
     monkeypatch.setenv("FLEETX_FORCE_FLASH", "1")
 
     def spec(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    heads, d, rows, lanes, layers = 64, 128, 512, 48, 6
-    row = spec((rows, heads, d))
-    text = jax.jit(lambda *a: kda.kda_chunk(*a[:-1], skip=a[-1])).lower(
-        row, row, row, row, spec((rows, heads)), spec((d, heads, d)),
-        spec((), jnp.bool_)).compile().as_text()
-    assert kda.CHUNK_KERNEL_NAME in text
+    heads, d, lanes, layers = 64, 128, 48, 6
+    for rows in (512, 256):
+        row = spec((rows, heads, d))
+        text = jax.jit(lambda *a: kda.kda_chunk(*a[:-1], skip=a[-1])).lower(
+            row, row, row, row, spec((rows, heads)), spec((d, heads, d)),
+            spec((), jnp.bool_)).compile().as_text()
+        assert kda.CHUNK_KERNEL_NAME in text
     row = spec((lanes, heads, d))
     leaf = (layers, lanes, d, heads, d)
     compiled = jax.jit(
