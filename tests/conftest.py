@@ -10,6 +10,7 @@ never run anywhere.
 """
 
 import os
+import subprocess
 
 _REAL = os.environ.get("FLEETX_TEST_PLATFORM") == "real"
 
@@ -237,6 +238,22 @@ def _benchmark_as_the_test_knew_it(request, monkeypatch):
     if hasattr(request.module, "BENCH"):  # (a file that read it as it loaded)
         monkeypatch.setattr(request.module, "BENCH",
                             load_json("BENCHMARK.json"))
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_makereport(item, call):
+    """A subprocess that outlived its ``timeout=`` fails its test with what
+    it had written (``TimeoutExpired`` holds it; its message does not)."""
+    report = (yield).get_result()
+    error = call.excinfo and call.excinfo.value
+    if isinstance(error, subprocess.TimeoutExpired):
+        for name in ("stdout", "stderr"):
+            text = getattr(error, name) or ""
+            if isinstance(text, bytes):
+                text = text.decode(errors="replace")
+            report.sections.append(
+                (f"{name} of the subprocess cut at {error.timeout} s",
+                 text[-4000:]))
 
 
 def pytest_collection_modifyitems(items):

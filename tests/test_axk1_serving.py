@@ -16,6 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from serving_parity import plain_greedy, sharing_programs, traced_apply
+
 from fleetx_tpu.models.gpt import block_fields, latent
 from fleetx_tpu.models.gpt.generation import (GenerationConfig,
                                               init_decode_cache)
@@ -71,7 +73,7 @@ def paged(model, pages=13, page=8, cache_len=96):
         decode_page_size=page))
 
 
-def forward(model, params, cache, ids, at, tables, rows=None):
+def _forward(model, params, cache, ids, at, tables, rows=None):
     pos = at[:, None] + jnp.arange(ids.shape[1])[None]
     logits, mut = model.apply(
         {"params": params, "cache": cache}, ids, pos, rows, decode=True,
@@ -79,9 +81,20 @@ def forward(model, params, cache, ids, at, tables, rows=None):
     return logits, mut["cache"]
 
 
+def traced_anew():
+    """``_forward`` as one program a model and a shape of call, as the engine
+    runs it (eagerly every primitive of every tick is a dispatch of its own).
+    A test that patches what a trace reads takes one of its own: jax keeps a
+    trace by the function's identity."""
+    return jax.jit(lambda *args: _forward(*args), static_argnums=0)
+
+
+forward = traced_anew()
+
+
 def test_the_plain_forward_is_the_reference(built, tokens, reference):
     model, variables = built
-    plain = model.apply(variables, jnp.asarray(tokens[None]))[0]
+    plain = traced_apply(model, variables, jnp.asarray(tokens[None]))[0]
     assert np.abs(np.asarray(plain) - reference).max() < TOL
 
 
@@ -129,6 +142,7 @@ def test_key_blocks_past_the_chunk_are_not_computed_and_nothing_changes(
     """With key blocks of 16 rows a chunk behind 32 rows runs three of the
     lane's six blocks: the same logits."""
     monkeypatch.setattr(latent, "KEY_BLOCK", 16)
+    forward = traced_anew()
     model, variables = built
     served = paged(model)
     cache = init_decode_cache(served, 1)
@@ -142,6 +156,7 @@ def test_key_blocks_past_the_chunk_are_not_computed_and_nothing_changes(
     assert np.abs(np.asarray(jnp.concatenate(out)) - reference).max() < TOL
 
 
+@sharing_programs
 def engine_of(model, variables, **kwargs):
     from fleetx_tpu.serving import ServingEngine
 
@@ -162,20 +177,14 @@ def test_the_engine_serves_it_cold_and_on_a_hit_on_latent_pages(built):
     rng = np.random.default_rng(1)
     document = rng.integers(1, 128, 64, dtype=np.int32)
 
-    def greedy(prompt, n):
-        toks = list(prompt)
-        for _ in range(n):
-            logits = model.apply(variables, jnp.asarray([toks]))
-            toks.append(int(jnp.argmax(logits[0, -1])))
-        return toks[len(prompt):]
-
     saved = []
     for q in range(3):
         prompt = np.concatenate([document, rng.integers(
             1, 128, 10 + q, dtype=np.int32)])
         rid = engine.submit(prompt, max_length=6)
         result = engine.drain()[rid]
-        assert [int(t) for t in result.tokens] == greedy(prompt, 6)
+        assert [int(t) for t in result.tokens] == plain_greedy(
+            model, variables, prompt, 6)
         saved.append(engine.metrics.snapshot()["prefill_tokens_saved"])
     assert saved == [0, 64, 128]
     snap = engine.metrics.snapshot()
@@ -267,6 +276,7 @@ def test_the_tick_through_the_kernel_is_the_tick_without_it(
     """The model's absorbed tick with the Pallas kernel (interpreted) and
     with its plain twin."""
     monkeypatch.setenv("FLEETX_FORCE_FLASH", "1")
+    forward = traced_anew()
     model, variables = built
     out = {}
     for flash in (False, True):
@@ -443,9 +453,9 @@ def test_the_group_limited_choice_on_a_written_out_case():
 def layer_of(cls, cfg, x, params=None, **kwargs):
     layer = cls(cfg)
     if params is None:
-        params = flax.core.meta.unbox(layer.init(jax.random.PRNGKey(1), x))[
-            "params"]
-    return layer.apply({"params": params}, x, **kwargs), params
+        params = flax.core.meta.unbox(jax.jit(layer.init)(
+            jax.random.PRNGKey(1), x))["params"]
+    return traced_apply(layer, {"params": params}, x, **kwargs), params
 
 
 def test_one_group_and_every_expert_held_is_todays_sigmoid_topk():
@@ -532,15 +542,16 @@ def test_a_call_with_no_held_pair_still_names_a_tile_and_adds_nothing(
     cfg = GPTConfig.from_model_config({**base, "first_expert_held": 4})
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 1, 64), jnp.float32)
     layer = moe_share.SharedMoEMLP(cfg)
-    params = flax.core.meta.unbox(layer.init(jax.random.PRNGKey(1), x))[
-        "params"]
+    params = flax.core.meta.unbox(jax.jit(layer.init)(
+        jax.random.PRNGKey(1), x))["params"]
     # a router that sends every token to experts 0-2, none of them held
     router = np.zeros((64, 16), np.float32)
     router[:, :3] = 1.0
     params = {**params, "router": {"kernel": jnp.asarray(router)}}
     x = jnp.abs(x)  # (so that the three columns' scores are the largest)
     stack = tuple(params[k][None] for k in ("w_gate", "w_up", "w_down"))
-    got, mut = layer.apply(
+    got, mut = traced_apply(
+        layer,
         {"params": params, "cache": {"moe_stats": jnp.zeros((1, 16),
                                                             jnp.uint32)}},
         x, decode=True, layer_index=jnp.int32(0), expert_stack=stack,

@@ -35,7 +35,7 @@ CFG = GPTConfig(
 def model_and_params():
     model = GPTForPretraining(CFG)
     tokens = jnp.zeros((2, 4), jnp.int32)
-    params = model.init(jax.random.PRNGKey(3), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(3), tokens)
     return model, params
 
 

@@ -144,7 +144,8 @@ def _donating_programs(eng, tokens):
 @pytest.mark.parametrize("tokens", [1, 8], ids=["tick", "prefill"])
 def test_donated_cache_aliases_the_output(kv_dtype, tokens):
     model = GPTForPretraining(CFG)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
     eng = ServingEngine(
         model, params, slots=LANES, cache_len=CACHE_LEN, page_size=PAGE,
         prefill_bucket=8, kv_dtype=kv_dtype or "bf16",

@@ -140,7 +140,7 @@ def test_model_fused_ce_matches_logits_path():
 
     plain = GPTForPretraining(GPTConfig(**base))
     fused = GPTForPretraining(GPTConfig(**base, fused_ce=True))
-    params = plain.init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(plain.init)(jax.random.PRNGKey(0), tokens)
 
     def loss_plain(p):
         return pretraining_loss(plain.apply(p, tokens), labels, mask)

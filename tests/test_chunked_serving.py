@@ -28,7 +28,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from serving_parity import assert_token_parity, one_shot_tokens
+from serving_parity import (assert_token_parity, one_shot_tokens,
+                            sharing_programs)
 
 from fleetx_tpu.models.gpt.generation import GenerationConfig
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
@@ -47,7 +48,8 @@ def tiny():
         hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
         dtype=jnp.float32, use_flash_attention=False)
     model = GPTForPretraining(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     return model, params
 
 
@@ -59,7 +61,8 @@ def tiny_flash():
         hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
         dtype=jnp.float32, use_flash_attention=True)  # interpret on CPU
     model = GPTForPretraining(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     return model, params
 
 
@@ -70,6 +73,7 @@ def _clean_faults():
     faults.reset()
 
 
+@sharing_programs
 def _engine(model, params, **kw):
     kw.setdefault("slots", 2)
     kw.setdefault("cache_len", 32)
@@ -226,9 +230,10 @@ def test_decode_span_counts_the_kernels_grid_steps(monkeypatch, stack):
         dtype=jnp.float32, use_flash_attention=True,
         **_KERNEL_STEP_STACKS[stack])
     model = GPTForPretraining(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
-    eng = _engine(model, params, slots=3, cache_len=1024, prefill_chunk=8,
-                  prefill_bucket=8)
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    eng = _engine.__wrapped__(model, params, slots=3, cache_len=1024,
+                              prefill_chunk=8, prefill_bucket=8)
     rec = get_recorder()
     rec.clear()
     eng.submit(np.arange(1, 12, dtype=np.int32), max_length=3)
@@ -527,9 +532,12 @@ def _stack_engine(stack, **kw):
         hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
         dtype=jnp.float32, use_flash_attention=False, **_HEAD_STACKS[stack])
     model = GPTForPretraining(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
-    return _engine(model, params, cache_len=64, prefill_chunk=_BUCKET,
-                   prefill_bucket=_BUCKET, **kw)
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    # (weights of its own every call: nothing to share programs with)
+    return _engine.__wrapped__(model, params, cache_len=64,
+                               prefill_chunk=_BUCKET, prefill_bucket=_BUCKET,
+                               **kw)
 
 
 def _vocab_wide(jaxpr):

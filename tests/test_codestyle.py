@@ -1,8 +1,11 @@
 """The style gate's own tests (reference codestyle/test_docstring_checker.py)."""
 
+import ast
 import os
 import subprocess
 import sys
+
+import pytest
 
 REPO = __file__.rsplit("/tests/", 1)[0]
 
@@ -12,7 +15,7 @@ def _run_checker(tmp_path, source, *args):
     f.write_text(source)
     return subprocess.run(
         [sys.executable, f"{REPO}/codestyle/docstring_checker.py", str(f), *args],
-        capture_output=True, text=True,
+        capture_output=True, text=True, timeout=60,
     )
 
 
@@ -52,7 +55,7 @@ def test_repo_tree_is_clean():
     r = subprocess.run(
         [sys.executable, f"{REPO}/codestyle/docstring_checker.py",
          f"{REPO}/fleetx_tpu"],
-        capture_output=True, text=True,
+        capture_output=True, text=True, timeout=120,
     )
     assert r.returncode == 0, r.stdout[-1500:]
 
@@ -154,7 +157,68 @@ def test_shell_scripts_parse():
     assert len(scripts) >= 40, scripts  # the launch-script zoo is present
     bad = []
     for s in scripts:
-        r = subprocess.run(["bash", "-n", s], capture_output=True, text=True)
+        r = subprocess.run(["bash", "-n", s], capture_output=True, text=True,
+                           timeout=30)
         if r.returncode != 0:
             bad.append((s, r.stderr[:200]))
     assert not bad, bad
+
+
+def _unbounded_waits(source):
+    """Line numbers of ``source``'s calls that wait without a bound of 180 s
+    or less: ``subprocess.run(``, ``urlopen(``, and a ``.wait(`` or ``.join(``
+    whose bound is neither a ``timeout=`` nor its one number
+    (``sep.join(parts)`` is handed something else and is no wait)."""
+    lines = []
+    for node in ast.walk(ast.parse(source)):
+        if not (isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Attribute)):
+            continue
+        name, on = node.func.attr, getattr(node.func.value, "id", None)
+        bound = next((k.value for k in node.keywords if k.arg == "timeout"),
+                     None)
+        if name in ("wait", "join"):
+            if node.args and bound is None:
+                bound = node.args[0]
+                if not (isinstance(bound, ast.Constant)
+                        and isinstance(bound.value, (int, float))):
+                    continue
+        elif not ((name == "run" and on == "subprocess")
+                  or name == "urlopen"):
+            continue
+        if bound is None or (isinstance(bound, ast.Constant)
+                             and not 0 < bound.value <= 180):
+            lines.append(node.lineno)
+    return lines
+
+
+def test_every_wait_in_the_tests_has_a_bound():
+    """No ``subprocess.run(``, ``.wait(``, thread ``.join(`` or ``urlopen(``
+    under ``tests/*.py`` without a ``timeout=``, and none above 180 s:
+    ``pytest-timeout`` is not installed, so a wait that never returns would
+    cost the whole run its clock and name no test."""
+    import glob
+
+    unbounded = []
+    for path in sorted(glob.glob(os.path.join(REPO, "tests", "*.py"))):
+        with open(path) as f:
+            unbounded += [f"{os.path.relpath(path, REPO)}:{line}"
+                          for line in _unbounded_waits(f.read())]
+    assert not unbounded, unbounded
+
+
+@pytest.mark.parametrize("call,waits_unbounded", [
+    ("subprocess.run(cmd, capture_output=True)", True),
+    ("subprocess.run(cmd, timeout=500)", True),
+    ("subprocess.run(cmd, timeout=120)", False),
+    ("proc.wait()", True),
+    ("proc.wait(300)", True),
+    ("proc.wait(timeout=30)", False),
+    ("thread.join()", True),
+    ("thread.join(60)", False),
+    ("', '.join(parts)", False),
+    ("urllib.request.urlopen(url)", True),
+    ("urllib.request.urlopen(url, timeout=10)", False),
+])
+def test_the_bound_on_waits_bites(call, waits_unbounded):
+    assert bool(_unbounded_waits(f"x = {call}\n")) == waits_unbounded

@@ -196,7 +196,7 @@ def test_model_cp_attention_dropout_runs(eight_devices):
         np.random.default_rng(0).integers(0, 64, (2, 32)), jnp.int32
     )
     with use_mesh(mesh):
-        params = model.init(jax.random.PRNGKey(0), tokens)
+        params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
         logits = jax.jit(
             lambda p, t: model.apply(
                 p, t, deterministic=False,
@@ -228,7 +228,7 @@ def test_model_cp_flash_under_remat(eight_devices):
     labels = jnp.asarray(rng.integers(0, 64, (2, 32)), jnp.int32)
     mask = jnp.ones((2, 32), jnp.float32)
     with use_mesh(mesh):
-        params = model.init(jax.random.PRNGKey(0), tokens)
+        params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
 
         def loss(p):
             return pretraining_loss(model.apply(p, tokens), labels, mask)

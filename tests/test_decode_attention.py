@@ -50,7 +50,8 @@ _TOL = 1e-5
 @pytest.fixture(scope="module")
 def model_and_params():
     model = GPTForPretraining(CFG)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     return model, params
 
 
@@ -127,15 +128,21 @@ def _rows(n_row, *rows):
 # name -> (block tables, ends, starts). At page 16 and the default block_k
 # a grid step of the bf16 pool covers P = min(16, pages a row) pages (the
 # int8 pool of 16-row pages keeps P = 1: module docstring "Paged variant").
+# Interpreted, a case costs what TRACING its step's P unrolled page copies
+# costs (3.1 s of lowering at P = 16, 0.25 s of running: my measurement, PR
+# 58), whatever its rows, lanes or heads, so off the chip the long rows take
+# ``_INTERPRETED_BLOCK_K`` = 64 rows a step, P = 4: every window below lies
+# in a 64-row block as it lies in a 256-row one (260 = 4 x 64 + 4, 512 =
+# 8 x 64, 300 inside a block, 70 = 17 x 4 + 2).
 _PAGED_CASES = {
     # 6 pages a row (P = 6, one block a lane): shared prefix pages, a
     # left-padded row, a lane on the trash page
     "row6": (_rows(6, [1, 2, 3, 4, 5, 6], [1, 2, 7, 8],
                    [9, 10, 11, 12, 13, 14], [15, 16, 17, 18]),
              [96, 50, 81, 17], [0, 0, 5, 0]),
-    # 80 pages a row (P = 16, five blocks): windows that end inside a
-    # block's first page (257 + 3), on a block's edge (512) and in a last
-    # partial page; ``starts`` inside the second block; shared prefix
+    # 80 pages a row (P = 16, five blocks; at P = 4, twenty): windows that
+    # end inside a block's first page (257 + 3), on a block's edge (512) and
+    # in a last partial page; ``starts`` inside a block; shared prefix
     "row80": (_rows(80, range(1, 18), range(18, 50),
                     list(range(1, 9)) + list(range(50, 122)),
                     range(130, 160)),
@@ -144,10 +151,14 @@ _PAGED_CASES = {
     # length) beside busy ones, at the chat cell's 64 pages a row
     "free64": (_rows(64, range(1, 28), [], range(28, 40)),
                [430, 1024, 177], [0, 0, 0]),
-    # a width P does not divide (70 = 4 x 16 + 6): the last block is short
+    # a width P does not divide (70 = 4 x 16 + 6 = 17 x 4 + 2): the last
+    # block is short
     "row70": (_rows(70, range(1, 71), range(71, 76)),
               [1120, 66], [0, 17]),
 }
+
+
+_INTERPRETED_BLOCK_K = 64
 
 
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
@@ -179,9 +190,12 @@ def test_paged_kernel_at_engine_shapes(case, h, d, kv_dtype):
         v = dequantize_kv(v8, vs)          # kernel reconstructs, in f32
     else:
         kern_k, kern_v = k, v
+    # (row6 keeps its step of the table's width; the chip the engine's 16)
+    block_k = (None if case == "row6" or jax.default_backend() == "tpu"
+               else _INTERPRETED_BLOCK_K)
     out = flash_decode_paged_attention(
         q, _fold(kern_k), _fold(kern_v), tables=tables, end=ends,
-        starts=starts, **scales)
+        starts=starts, block_k=block_k, **scales)
     gather = lambda x: x[tables].reshape(b, n_row * ps, h, d)
     ref = _dense_window_attention(
         q.astype(jnp.float32), gather(k).astype(jnp.float32),
@@ -251,7 +265,7 @@ _EMPTY_LANE_WINDOWS = ((0, 0, 0), (10, 300, 0), (0, 0, 0), (10, 100, 300),
 @pytest.mark.parametrize("with_starts", [False, True])
 @pytest.mark.parametrize("kv_dtype,group", [
     ("bfloat16", 1), ("bfloat16", 4), ("int8", 1)])  # (int8 takes group 1)
-@pytest.mark.parametrize("pages", [8, 1])
+@pytest.mark.parametrize("pages", [3, 1])
 def test_paged_kernel_with_free_and_empty_lanes_interleaved(
         monkeypatch, pages, kv_dtype, group, with_starts):
     """A lane whose window is empty (``end`` = 0, or ``end`` <= ``start``:
@@ -261,13 +275,16 @@ def test_paged_kernel_with_free_and_empty_lanes_interleaved(
     before and behind busy lanes it does not break the chain in which a
     live step starts the next live step's copies: the busy lanes read their
     own pages, and equal bit for bit a call that holds the busy lanes
-    alone. At several pages a step (``pages`` 8 of 32 rows) and at one
-    (``block_k`` = the page size), over a bf16 and an int8 pool, with every
-    key head its own and shared by 4 query heads."""
+    alone. At several pages a step (``pages`` 3 of 32 rows: a step's unrolled
+    copies are what an interpreted case costs, and 3 keep what 8 showed, a
+    window that ends in the first block, windows that begin in a later one,
+    a short last block: 20 = 6 x 3 + 2) and at one (``block_k`` = the page
+    size), over a bf16 and an int8 pool, with every key head its own and
+    shared by 4 query heads."""
     from chip_smoke import nan_prefilled_outputs
 
     rng = np.random.RandomState(5)
-    if (kv_dtype, pages, jax.default_backend()) == ("int8", 8, "tpu"):
+    if (kv_dtype, pages, jax.default_backend()) == ("int8", 3, "tpu"):
         pytest.skip("Mosaic refuses the copy of a scale page ([rows, heads]: "
                     "no 128 lanes) at several pages a step, on any tree; "
                     "the engine's 16-row int8 pages take one a step")
@@ -304,7 +321,7 @@ def test_paged_kernel_with_free_and_empty_lanes_interleaved(
 
     _step_of_block_k(monkeypatch)
     assert paged_grid([_fold(pool_k)], n_row, pages * ps) == (
-        pages, n_row // pages + (pages == 8))
+        pages, -(-n_row // pages))
     out = call(np.arange(b))
     gather = lambda x: x[tables].reshape(b, n_row * ps, kvh, d)
     ref = _dense_grouped(
@@ -428,7 +445,7 @@ def test_narrow_rows_compile_for_the_v5e(one_chip, monkeypatch, cell):
 
 
 # lane -> (end, what the test says of it) under a window of 100 rows, where a
-# step is 64 rows (8 pages of 8) and the table 30 pages: 3.75 steps
+# step is 64 rows (4 pages of 16) and the table 15 pages: 3.75 steps
 _WINDOW_LANES = (
     (237, "starts mid-page and mid-step; its last block, the table's "
           "fourth, runs past the table"),
@@ -456,7 +473,7 @@ def test_paged_kernel_walks_a_window_from_where_it_starts(monkeypatch, group,
     where these do."""
     rng = np.random.RandomState(7)
     # (on the chip a copied row is whole 128-lane tiles: 2 key heads of 64)
-    ps, n_row, kvh, d, block_k = 8, 30, 2, 64, 64
+    ps, n_row, kvh, d, block_k = 16, 15, 2, 64, 64
     b, h = len(_WINDOW_LANES), group * kvh
     ends = jnp.asarray([e for e, _ in _WINDOW_LANES], jnp.int32)
     starts = jnp.maximum(ends - window, 0)
@@ -467,7 +484,7 @@ def test_paged_kernel_walks_a_window_from_where_it_starts(monkeypatch, group,
         1 + rng.permutation(b * n_row).reshape(b, n_row), jnp.int32)
     _step_of_block_k(monkeypatch)
     assert paged_grid([_fold(k)], n_row, block_k, window) == (
-        8, {100: 3, 20: 2}[window])
+        4, {100: 3, 20: 2}[window])
 
     def call(max_live):
         return np.asarray(flash_decode_paged_attention(
@@ -565,7 +582,7 @@ def test_grouped_heads_match_the_dense_path(path, heads, kv_heads):
         got = flash_decode_paged_attention(
             q, jnp.asarray(pool_k), jnp.asarray(pool_v),
             tables=jnp.asarray(order, jnp.int32), end=ends, starts=starts,
-            block_k=ps if path == "paged_page" else 4 * ps)
+            block_k=ps if path == "paged_page" else 2 * ps)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=_TOL, atol=_TOL)
 
@@ -786,7 +803,7 @@ def test_group_4_at_head_size_64_matches_the_dense_path(path):
         got = flash_decode_paged_attention(
             q, jnp.asarray(pool_k), jnp.asarray(pool_v),
             tables=jnp.asarray(order, jnp.int32), end=ends,
-            block_k=ps if path == "paged_page" else 4 * ps)
+            block_k=ps if path == "paged_page" else 2 * ps)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=_TOL, atol=_TOL)
 

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from serving_parity import plain_greedy, sharing_programs, traced_apply
+
 from fleetx_tpu.models.gpt import latent
 from fleetx_tpu.models.gpt.generation import (GenerationConfig,
                                               init_decode_cache)
@@ -123,8 +125,8 @@ def sown(routing):
 def test_the_plain_forward_is_the_reference_and_prunes(built, tokens,
                                                        reference):
     model, variables = built
-    plain, mut = model.apply(variables, jnp.asarray(tokens[None]),
-                             mutable=["routing"])
+    plain, mut = traced_apply(model, variables, jnp.asarray(tokens[None]),
+                              mutable=["routing"])
     assert np.abs(np.asarray(plain[0]) - reference["logits"]).max() < TOL
     # (3) the index scores and the sets, every layer and position
     mine = sown(mut["routing"])
@@ -281,6 +283,7 @@ def test_a_chunks_attention_compiles_for_the_v5e_at_the_published_widths(
 
 # ------------------------------------------------------------ the engine
 
+@sharing_programs
 def engine_of(model, variables, **kwargs):
     from fleetx_tpu.serving import ServingEngine
 
@@ -308,20 +311,14 @@ def test_the_engine_serves_it_cold_and_on_a_hit_with_spans_and_counters(
     rng = np.random.default_rng(1)
     document = rng.integers(1, 128, 96, dtype=np.int32)
 
-    def greedy(prompt, n):
-        toks = list(prompt)
-        for _ in range(n):
-            logits = model.apply(variables, jnp.asarray([toks]))
-            toks.append(int(jnp.argmax(logits[0, -1])))
-        return toks[len(prompt):]
-
     saved = []
     for q in range(3):
         prompt = np.concatenate([document, rng.integers(
             1, 128, 10 + q, dtype=np.int32)])
         rid = engine.submit(prompt, max_length=6)
         result = engine.drain()[rid]
-        assert [int(t) for t in result.tokens] == greedy(prompt, 6)
+        assert [int(t) for t in result.tokens] == plain_greedy(
+            model, variables, prompt, 6)
         saved.append(engine.metrics.snapshot()["prefill_tokens_saved"])
     assert saved == [0, 96, 192]
     snap = engine.metrics.snapshot()
@@ -427,17 +424,18 @@ def test_a_prompt_of_at_most_index_topk_rows_is_the_model_without_the_indexer(
             "op": {k: v for k, v in op.items()
                    if not k.startswith("index_")}}}}}
     short = tokens[:TOPK]
-    with_it = model.apply(variables, jnp.asarray(short[None]))
-    without = off.apply({"params": params}, jnp.asarray(short[None]))
+    with_it = traced_apply(model, variables, jnp.asarray(short[None]))
+    without = traced_apply(off, {"params": params}, jnp.asarray(short[None]))
     assert (np.asarray(with_it) == np.asarray(without)).all()
     a = cached_logits(model, variables["params"], short, (16,))
     b = cached_logits(off, params, short, (16,))
     assert np.abs(a - b).max() < 2e-6
     # and one row further the two part
     longer = tokens[:TOPK + 40]
-    assert np.abs(np.asarray(model.apply(variables, jnp.asarray(longer[None])))
-                  - np.asarray(off.apply({"params": params},
-                                         jnp.asarray(longer[None])))
+    assert np.abs(np.asarray(traced_apply(model, variables,
+                                          jnp.asarray(longer[None])))
+                  - np.asarray(traced_apply(off, {"params": params},
+                                            jnp.asarray(longer[None])))
                   ).max() > 10 * TOL
 
 
@@ -446,9 +444,9 @@ def test_a_prompt_of_at_most_index_topk_rows_is_the_model_without_the_indexer(
 def layer_of(cfg, x, params=None):
     layer = moe_share.SharedMoEMLP(cfg)
     if params is None:
-        params = flax.core.meta.unbox(layer.init(jax.random.PRNGKey(1), x))[
-            "params"]
-    return layer.apply({"params": params}, x), params
+        params = flax.core.meta.unbox(jax.jit(layer.init)(
+            jax.random.PRNGKey(1), x))["params"]
+    return traced_apply(layer, {"params": params}, x), params
 
 
 def test_the_bias_joins_the_groups_sums_and_the_choice_on_a_written_out_case():

@@ -72,7 +72,7 @@ def test_wikitext_ppl_cli(tmp_path, byte_vocab):
     cfg = _eval_cfg(tmp_path, str(corpus), "False", byte_vocab)
     r = subprocess.run(
         [sys.executable, f"{REPO}/tools/eval.py", "-c", cfg],
-        capture_output=True, text=True, timeout=500,
+        capture_output=True, text=True, timeout=120,
         env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin",
              "FLEETX_LOG_LEVEL": "INFO", "HOME": "/root"},
     )
@@ -114,7 +114,7 @@ def test_lambada_cloze_cli(tmp_path, byte_vocab):
     cfg = _eval_cfg(tmp_path, str(data), "True", byte_vocab)
     r = subprocess.run(
         [sys.executable, f"{REPO}/tools/eval.py", "-c", cfg],
-        capture_output=True, text=True, timeout=500,
+        capture_output=True, text=True, timeout=120,
         env={"JAX_PLATFORMS": "cpu", "PATH": "/usr/bin:/bin",
              "FLEETX_LOG_LEVEL": "INFO", "HOME": "/root"},
     )

@@ -366,7 +366,7 @@ def test_block_env_override_validation():
              "import fleetx_tpu.ops.pallas.flash_attention"],
             env={**__import__("os").environ, "FLEETX_FLASH_BLOCK_Q": bad,
                  "JAX_PLATFORMS": "cpu"},
-            capture_output=True, text=True,
+            capture_output=True, text=True, timeout=120,
         )
         assert proc.returncode != 0, bad
         assert "FLEETX_FLASH_BLOCK_Q" in proc.stderr, proc.stderr[-500:]

@@ -27,7 +27,7 @@ CFG = GPTConfig(
 def model_and_params():
     model = GPTForPretraining(CFG)
     tokens = jnp.zeros((2, 8), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     return model, params
 
 

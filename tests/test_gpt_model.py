@@ -35,7 +35,7 @@ def _data(b=2, s=16, vocab=128, seed=0):
 def test_forward_shapes():
     tokens, _, _ = _data()
     model = GPTForPretraining(TINY)
-    params = model.init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     logits = model.apply(params, tokens)
     assert logits.shape == (2, 16, 128)
     assert logits.dtype == jnp.float32
@@ -44,7 +44,7 @@ def test_forward_shapes():
 def test_scan_param_stacking():
     tokens, _, _ = _data()
     model = GPTForPretraining(TINY)
-    variables = model.init(jax.random.PRNGKey(0), tokens)
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     layer_params = variables["params"]["gpt"]["layers"]["layer"]
     qkv = layer_params["attn"]["qkv_proj"]["kernel"]
     value = qkv.value if hasattr(qkv, "value") else qkv
@@ -59,7 +59,7 @@ def test_scan_vs_unrolled_same_loss():
     m_unroll = GPTForPretraining(
         GPTConfig(**{**TINY.__dict__, "scan_layers": False})
     )
-    v_scan = m_scan.init(jax.random.PRNGKey(0), tokens)
+    v_scan = jax.jit(m_scan.init)(jax.random.PRNGKey(0), tokens)
     # map scanned params [L, ...] -> unrolled layer_i params
     import flax
 
@@ -94,7 +94,7 @@ def test_recompute_matches_no_recompute(granularity):
             }
         )
     )
-    params = base.init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(base.init)(jax.random.PRNGKey(0), tokens)
 
     def loss_fn(model):
         def f(p):
@@ -123,7 +123,7 @@ def test_no_recompute_layers_unrolled():
         }
     )
     model = GPTForPretraining(cfg)
-    params = model.init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     assert "layer_0" in params["params"]["gpt"]
     logits = model.apply(params, tokens)
     assert logits.shape == (2, 16, 128)
@@ -133,7 +133,7 @@ def test_causality():
     """Changing a future token must not change past logits."""
     tokens, _, _ = _data()
     model = GPTForPretraining(TINY)
-    params = model.init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     l1 = model.apply(params, tokens)
     tokens2 = tokens.at[:, -1].set((tokens[:, -1] + 1) % 128)
     l2 = model.apply(params, tokens2)
@@ -146,7 +146,7 @@ def test_causality():
 def test_loss_masking():
     tokens, labels, mask = _data()
     model = GPTForPretraining(TINY)
-    params = model.init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     logits = model.apply(params, tokens)
     full = pretraining_loss(logits, labels, mask)
     assert np.isfinite(float(full))
@@ -161,7 +161,7 @@ def test_dropout_determinism_keys():
     """Same dropout key → same loss; different key → different loss."""
     tokens, labels, mask = _data()
     model = GPTForPretraining(TINY)
-    params = model.init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     k1, k2 = jax.random.PRNGKey(1), jax.random.PRNGKey(2)
     a = model.apply(params, tokens, deterministic=False, rngs={"dropout": k1})
     b = model.apply(params, tokens, deterministic=False, rngs={"dropout": k1})
@@ -174,5 +174,5 @@ def test_unfused_qkv():
     tokens, _, _ = _data()
     cfg = GPTConfig(**{**TINY.__dict__, "fuse_attn_qkv": False})
     model = GPTForPretraining(cfg)
-    params = model.init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     assert model.apply(params, tokens).shape == (2, 16, 128)

@@ -99,7 +99,7 @@ def test_model_level_determinism():
                     fast_dropout=True)
     model = GPTForPretraining(cfg)
     tokens = jnp.arange(32)[None, :] % 128
-    params = model.init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     k1, k2 = jax.random.PRNGKey(1), jax.random.PRNGKey(2)
     a = model.apply(params, tokens, deterministic=False, rngs={"dropout": k1})
     b = model.apply(params, tokens, deterministic=False, rngs={"dropout": k1})
@@ -130,7 +130,7 @@ def test_fast_dropout_false_end_to_end():
                     fast_dropout=False)
     model = GPTForPretraining(cfg)
     tokens = jnp.arange(32)[None, :] % 128
-    params = model.init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
 
     def loss(params):
         logits = model.apply(params, tokens, deterministic=False,

@@ -55,7 +55,8 @@ def zoo():
         hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
         dtype=jnp.float32, use_flash_attention=False)
     gpt = GPTForPretraining(gcfg)
-    gpt_vars = gpt.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    gpt_vars = jax.jit(gpt.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
 
     ecfg = ErnieConfig(
         vocab_size=97, hidden_size=32, num_layers=1, num_attention_heads=2,
@@ -63,8 +64,8 @@ def zoo():
         hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
         dtype=jnp.float32)
     ernie = ErnieForPretraining(ecfg)
-    ernie_vars = ernie.init(jax.random.PRNGKey(0),
-                            jnp.zeros((2, 8), jnp.int32))
+    ernie_vars = jax.jit(ernie.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
 
     vcfg = ViTConfig(image_size=8, patch_size=4, in_channels=3,
                      num_classes=0, hidden_size=32, num_layers=1,

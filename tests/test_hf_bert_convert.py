@@ -73,7 +73,7 @@ def test_cli_artifact_serves(tmp_path, tiny_bert_ckpt):
     r = subprocess.run(
         [sys.executable, f"{REPO}/tools/convert_hf_bert.py",
          "--hf-dir", hf_dir, "--output", out],
-        capture_output=True, text=True,
+        capture_output=True, text=True, timeout=120,
     )
     assert r.returncode == 0, r.stderr[-2000:]
 

@@ -37,7 +37,7 @@ def test_converted_logits_match_transformers(tmp_path, tiny_hf_ckpt):
     r = subprocess.run(
         [sys.executable, f"{REPO}/tools/convert_hf_gpt2.py",
          "--hf-dir", hf_dir, "--output", out],
-        capture_output=True, text=True,
+        capture_output=True, text=True, timeout=120,
     )
     assert r.returncode == 0, r.stderr[-2000:]
 
@@ -62,7 +62,7 @@ def test_vocab_padding_preserves_real_logits(tmp_path, tiny_hf_ckpt):
     r = subprocess.run(
         [sys.executable, f"{REPO}/tools/convert_hf_gpt2.py",
          "--hf-dir", hf_dir, "--output", out, "--pad-vocab-multiple", "64"],
-        capture_output=True, text=True,
+        capture_output=True, text=True, timeout=120,
     )
     assert r.returncode == 0, r.stderr[-2000:]
 
@@ -87,7 +87,7 @@ def test_gpt_module_warm_starts_from_converted_artifact(tmp_path, tiny_hf_ckpt):
     r = subprocess.run(
         [sys.executable, f"{REPO}/tools/convert_hf_gpt2.py",
          "--hf-dir", hf_dir, "--output", out],
-        capture_output=True, text=True,
+        capture_output=True, text=True, timeout=120,
     )
     assert r.returncode == 0, r.stderr[-2000:]
 
@@ -137,7 +137,7 @@ def test_int8_quantized_artifact_close_to_fp32(tmp_path, tiny_hf_ckpt):
     r = subprocess.run(
         [sys.executable, f"{REPO}/tools/convert_hf_gpt2.py",
          "--hf-dir", hf_dir, "--output", out, "--quantize", "int8"],
-        capture_output=True, text=True,
+        capture_output=True, text=True, timeout=120,
     )
     assert r.returncode == 0, r.stderr[-2000:]
 

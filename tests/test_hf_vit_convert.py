@@ -69,7 +69,7 @@ def test_cli_artifact_serves(tmp_path, tiny_vit_ckpt):
     r = subprocess.run(
         [sys.executable, f"{REPO}/tools/convert_hf_vit.py",
          "--hf-dir", hf_dir, "--output", out, "--num-classes", "7"],
-        capture_output=True, text=True,
+        capture_output=True, text=True, timeout=120,
     )
     assert r.returncode == 0, r.stderr[-2000:]
 

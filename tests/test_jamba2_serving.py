@@ -28,6 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from serving_parity import computed_once, sharing_programs, traced_apply
+
 from fleetx_tpu.models.gpt.generation import GenerationConfig
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
 from fleetx_tpu.ops.pallas import ssm_scan
@@ -51,7 +53,7 @@ MODEL = dict(
     use_bias=False, tie_word_embeddings=True)
 SIZES = dict(MODEL, hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
              family="jamba2", use_flash_attention=False, dtype=jnp.float32)
-reference = jamba2_f32.configured(MODEL)
+reference = computed_once(jamba2_f32.configured(MODEL))
 TOKENS = np.random.default_rng(0).integers(1, 512, (2, 56), dtype=np.int32)
 
 
@@ -85,6 +87,10 @@ def distance(logits, expected) -> float:
     return float(np.abs(np.asarray(logits) - expected).max() / expected.std())
 
 
+served_of = sharing_programs(Served)
+
+
+@sharing_programs
 def engine_of(model, variables, **kw):
     kw = {"slots": 3, "page_size": PAGE, "prefill_bucket": 8,
           "cache_len": CACHE_LEN, **kw}
@@ -95,7 +101,7 @@ def engine_of(model, variables, **kw):
 
 
 def test_full_forward_matches_the_reference(variables):
-    logits = build().apply(variables, TOKENS)
+    logits = traced_apply(build(), variables, TOKENS)
     assert distance(logits, reference(variables["params"], TOKENS)) < TOL
 
 
@@ -104,7 +110,7 @@ def test_fused_projections_match_too(variables):
     held = flax.core.meta.unbox(jax.jit(model.init)(
         jax.random.PRNGKey(1), np.zeros((1, 8), np.int32)))
     assert "qkv_proj" in held["params"]["gpt"]["layers"]["attention"]["op"]
-    assert distance(model.apply(held, TOKENS[:1]),
+    assert distance(traced_apply(model, held, TOKENS[:1]),
                     reference(held["params"], TOKENS[:1])) < TOL
 
 
@@ -116,7 +122,7 @@ def test_prefill_then_decode_through_the_lane_state(variables, chunk):
     through the lane's state: every one of the 56 logit rows."""
     engine = engine_of(build(), variables)
     tokens = TOKENS[0]
-    logits, _ = Served(engine).sequence(tokens, 40, CHUNK, chunk)
+    logits, _ = served_of(engine).sequence(tokens, 40, CHUNK, chunk)
     expected = reference(variables["params"], tokens)[40 - CHUNK:]
     assert distance(logits, expected) < TOL
     engine.cache_manager.pool.check_invariants()
@@ -125,8 +131,8 @@ def test_prefill_then_decode_through_the_lane_state(variables, chunk):
 
 def test_chunked_prefill_gives_what_one_shot_gives(variables):
     engine = engine_of(build(), variables)
-    whole, whole_state = Served(engine).sequence(TOKENS[1], 44, CHUNK)
-    chunked, state = Served(engine).sequence(TOKENS[1], 44, CHUNK, CHUNK)
+    whole, whole_state = served_of(engine).sequence(TOKENS[1], 44, CHUNK)
+    chunked, state = served_of(engine).sequence(TOKENS[1], 44, CHUNK, CHUNK)
     assert distance(chunked, whole) < TOL
     # the first layer is handed the same rows either way: its state differs
     # by float32's own rounding at most (the cell's limit holds it there)
@@ -142,7 +148,7 @@ def test_a_padded_bucket_leaves_the_state_bit_for_bit(variables):
     rows that are no tokens (one program, so the same sums in the same
     order): neither ``h`` nor the filter's rows can tell."""
     engine = engine_of(build(), variables)
-    served, manager, held = Served(engine), engine.cache_manager, []
+    served, manager, held = served_of(engine), engine.cache_manager, []
     for padding in (TOKENS[0][21:32], TOKENS[1][21:32]):
         lane, _ = manager.alloc(-1, TOKENS[0][:21])
         ids = np.concatenate([TOKENS[0][:21], padding])
@@ -277,7 +283,7 @@ def test_the_engine_with_the_kernels_on_matches_the_reference(monkeypatch,
     key head) in interpret mode under the engine's own prefill and tick."""
     monkeypatch.setenv("FLEETX_FORCE_FLASH", "1")
     engine = engine_of(build(use_flash_attention=True), variables)
-    logits, _ = Served(engine).sequence(TOKENS[0][:48], 40, CHUNK, CHUNK)
+    logits, _ = served_of(engine).sequence(TOKENS[0][:48], 40, CHUNK, CHUNK)
     expected = reference(variables["params"], TOKENS[0][:48])[40 - CHUNK:]
     assert distance(logits, expected) < TOL
 

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from serving_parity import computed_once, sharing_programs, traced_apply
+
 from fleetx_tpu.models.gpt import mixed_stack
 from fleetx_tpu.models.gpt.generation import GenerationConfig
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
@@ -57,7 +59,7 @@ MODEL = dict(
 SIZES = dict(MODEL, hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
              expert_mode=True, family="lfm2", use_flash_attention=False,
              dtype=jnp.float32)
-reference = lfm2_f32.configured(MODEL)
+reference = computed_once(lfm2_f32.configured(MODEL))
 TOKENS = np.random.default_rng(0).integers(1, 512, (2, 56), dtype=np.int32)
 
 
@@ -93,6 +95,7 @@ def distance(logits, expected) -> float:
     return float(np.abs(np.asarray(logits) - expected).max() / expected.std())
 
 
+@sharing_programs
 def engine_of(model, variables, **kw):
     kw = {"slots": 3, "page_size": PAGE, "prefill_chunk": CHUNK,
           "prefill_bucket": 8, "prefix_cache": True, **kw}
@@ -103,7 +106,7 @@ def engine_of(model, variables, **kw):
 
 
 def test_full_forward_matches_the_reference(variables):
-    logits = build().apply(variables, TOKENS)
+    logits = traced_apply(build(), variables, TOKENS)
     assert distance(logits, reference(variables["params"], TOKENS)) < TOL
 
 
@@ -112,12 +115,15 @@ def test_fused_projections_match_too(variables):
     held = flax.core.meta.unbox(jax.jit(model.init)(
         jax.random.PRNGKey(1), np.zeros((1, 8), np.int32)))
     assert "qkv_proj" in held["params"]["gpt"]["layers"]["attention"]["op"]
-    assert distance(model.apply(held, TOKENS[:1]),
+    assert distance(traced_apply(model, held, TOKENS[:1]),
                     reference(held["params"], TOKENS[:1])) < TOL
 
 
-def served_logits(engine, tokens, prompt_len):
-    out = Served(engine).sequence(tokens, prompt_len, CHUNK)
+served_of = sharing_programs(Served)
+
+
+def served_logits(engine, tokens, prompt_len, served=served_of):
+    out = served(engine).sequence(tokens, prompt_len, CHUNK)
     return out["logits"], out["matched"]
 
 
@@ -146,7 +152,7 @@ def test_a_prefix_hit_resumes_the_state_bit_for_bit(variables, boundary):
                             rng.integers(1, 512, 11, dtype=np.int32)])
     engine.submit(other, max_length=2)
     engine.drain()
-    manager, served = engine.cache_manager, Served(engine)
+    manager, served = engine.cache_manager, served_of(engine)
 
     def run(cold):
         with trie_off(manager.pool) if cold else contextlib.nullcontext():
@@ -260,7 +266,7 @@ def _faulty_logits(variables, fault, monkeypatch):
             **held["params"]["gpt"], "layers": layers}}}
     if changes:
         held = _declared_only(model, held)
-    return model.apply(held, TOKENS)
+    return traced_apply(model, held, TOKENS)
 
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
@@ -280,7 +286,8 @@ def test_the_tolerance_bites_through_the_pool(variables, fault):
             np.int32), max_length=2)
         engine.drain()
     with probe_lfm2.state_read("zeroed"):   # in every one-lane call
-        logits, matched = served_logits(engine, tokens, 40)
+        # (the fault is in the trace: a check of its own, traced in here)
+        logits, matched = served_logits(engine, tokens, 40, Served)
     assert matched == (24 if fault == "zeroed_at_a_hit" else 0)
     expected = reference(variables["params"], tokens)[40 - CHUNK:]
     assert distance(logits, expected) > 30 * TOL
@@ -421,11 +428,12 @@ def test_grouped_heads_take_a_per_head_qk_norm():
         "use_expert_bias", "expert_bias_init_std", "num_experts", "top_k",
         "expert_mode", "norm_topk_prob")}
     model = GPTForPretraining(GPTConfig(**{**sizes, "num_layers": 2}))
-    held = flax.core.meta.unbox(model.init(jax.random.PRNGKey(0),
-                                           np.zeros((1, 8), np.int32)))
+    held = flax.core.meta.unbox(jax.jit(model.init)(
+        jax.random.PRNGKey(0), np.zeros((1, 8), np.int32)))
     attn = held["params"]["gpt"]["layers"]["layer"]["attn"]
     assert attn["q_norm"]["scale"].shape == (2, 8)
-    assert np.isfinite(np.asarray(model.apply(held, TOKENS[:1]))).all()
+    assert np.isfinite(np.asarray(
+        traced_apply(model, held, TOKENS[:1]))).all()
 
 
 def test_the_configuration_is_the_catalogs_and_its_count_the_programs_own():

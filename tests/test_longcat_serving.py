@@ -19,6 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from serving_parity import traced_apply
+
 from fleetx_tpu.models.gpt import mixed_stack
 from fleetx_tpu.models.gpt.generation import (GenerationConfig,
                                               init_decode_cache)
@@ -110,7 +112,7 @@ def test_the_experts_stack_holds_no_norm_and_the_router_every_output(built):
 
 def test_the_plain_forward_is_the_reference(built, tokens, reference):
     model, variables = built
-    plain = model.apply(variables, jnp.asarray(tokens[None]))[0]
+    plain = traced_apply(model, variables, jnp.asarray(tokens[None]))[0]
     assert np.abs(np.asarray(plain) - reference).max() < TOL
 
 
@@ -201,9 +203,9 @@ def layer_of(cfg, x, params=None, **kwargs):
     layer = moe_share.SharedMoEMLP(cfg)
     if params is None:
         params = flax.core.meta.unbox(
-            layer.init(jax.random.PRNGKey(1), x))["params"]
-    out, mut = layer.apply({"params": params}, x, mutable=["routing"],
-                           **kwargs)
+            jax.jit(layer.init)(jax.random.PRNGKey(1), x))["params"]
+    out, mut = traced_apply(layer, {"params": params}, x,
+                            mutable=["routing"], **kwargs)
     return out, params, {k: v[0] for k, v in mut["routing"].items()}
 
 
@@ -289,12 +291,12 @@ def test_the_counters_count_zero_pairs_and_what_a_token_costs():
     cfg = GPTConfig.from_model_config(SIZES)
     layer = moe_share.SharedMoEMLP(cfg)
     x = jax.random.normal(jax.random.PRNGKey(5), (6, 1, 64), jnp.float32)
-    params = flax.core.meta.unbox(layer.init(jax.random.PRNGKey(1), x))[
-        "params"]
+    params = flax.core.meta.unbox(jax.jit(layer.init)(
+        jax.random.PRNGKey(1), x))["params"]
     stats = jnp.zeros((3, moe_share.stats_words(cfg)), jnp.uint32)
-    _, mut = layer.apply({"params": params, "cache": {"moe_stats": stats}}, x,
-                         decode=True, layer_index=jnp.int32(1),
-                         mutable=["cache", "routing"])
+    _, mut = traced_apply(
+        layer, {"params": params, "cache": {"moe_stats": stats}}, x,
+        decode=True, layer_index=jnp.int32(1), mutable=["cache", "routing"])
     chose = np.asarray(mut["routing"]["experts"][0]).reshape(6, 3)
     words = np.asarray(mut["cache"]["moe_stats"])
     assert not words[[0, 2]].any()
@@ -307,8 +309,8 @@ def test_the_counters_count_zero_pairs_and_what_a_token_costs():
                         "moe_tick_routed_pairs_max": int(3 - zero.min()),
                         "moe_tick_routed_pairs_min": int(3 - zero.max())}
     # a longer call is a prefill's: its pairs alone, no maximum
-    _, mut = layer.apply(
-        {"params": params, "cache": {"moe_stats": stats}},
+    _, mut = traced_apply(
+        layer, {"params": params, "cache": {"moe_stats": stats}},
         x.reshape(1, 6, 64), decode=True, layer_index=jnp.int32(0),
         mutable=["cache"])
     words = np.asarray(mut["cache"]["moe_stats"])

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from serving_parity import sharing_programs
+
 from fleetx_tpu.models.gpt.generation import GenerationConfig
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
 from fleetx_tpu.parallel.mesh import MeshConfig, build_mesh
@@ -50,7 +52,8 @@ PROMPTS = [np.asarray([1, 2, 3], np.int32),
 @pytest.fixture(scope="module")
 def model_and_params():
     model = GPTForPretraining(CFG)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     return model, params
 
 
@@ -59,6 +62,7 @@ def mp2(eight_devices):
     return build_mesh(MeshConfig(mp=2), eight_devices[:2])
 
 
+@sharing_programs
 def _engine(model, params, **kw):
     kw.setdefault("slots", 3)
     kw.setdefault("cache_len", 32)
@@ -139,10 +143,10 @@ def test_mesh_flash_decode_takes_sharded_kernels(model_and_params, mp2,
     monkeypatch.setattr(da, "flash_decode_paged_attention", wrap_paged)
     monkeypatch.setattr(da, "flash_decode_attention", wrap_contig)
 
-    want_paged = _run(_engine(flash_model, params))
+    want_paged = _run(_engine.__wrapped__(flash_model, params))
     assert calls["paged"] and all(m is None for m in calls["paged"])
     calls["paged"].clear()
-    got_paged = _run(_engine(flash_model, params, mesh=mp2))
+    got_paged = _run(_engine.__wrapped__(flash_model, params, mesh=mp2))
     # the decode tick dispatched the PAGED kernel with the mesh — the
     # dense fallback was not taken, and the call went through shard_map
     assert calls["paged"], "mp2 decode never reached the paged flash kernel"
@@ -242,8 +246,8 @@ def test_dp_mesh_one_shot_flash_guard(eight_devices, monkeypatch):
 
     flash_model = GPTForPretraining(
         dataclasses.replace(CFG, use_flash_attention=True))
-    params = flash_model.init(jax.random.PRNGKey(0),
-                              jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(flash_model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     gcfg = dataclasses.replace(GREEDY, max_length=3, eos_token_id=-1)
     calls = []
     orig = da.flash_decode_attention

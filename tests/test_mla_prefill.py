@@ -137,9 +137,10 @@ def test_the_models_chunks_through_the_kernel_are_the_reference(
                         lambda *a, **k: traced.append(1) or real(*a, **k))
     cache = init_decode_cache(served, 1)
     table = jnp.arange(1, 13, dtype=jnp.int32)[None]
+    forward = axk1.traced_anew()    # (the recording entry is in the trace)
     out, at = [], 0
     for n in chunks:
-        logits, cache = axk1.forward(
+        logits, cache = forward(
             served, variables["params"], cache,
             jnp.asarray(tokens[None, at:at + n]), jnp.asarray([at]), table)
         out.append(logits[0])

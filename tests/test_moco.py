@@ -16,11 +16,11 @@ from fleetx_tpu.models.vision.resnet import ResNetConfig, ResNet, build_resnet
 def test_resnet_backbone_shapes():
     model = build_resnet("resnet18", width=16, dtype=jnp.float32)
     imgs = jnp.zeros((2, 32, 32, 3))
-    vars_ = model.init(jax.random.PRNGKey(0), imgs)
+    vars_ = jax.jit(model.init)(jax.random.PRNGKey(0), imgs)
     feats = model.apply(vars_, imgs)
     assert feats.shape == (2, 16 * 8)  # width * 2^3, basic blocks
     logits = build_resnet("resnet50", width=16, num_classes=7, dtype=jnp.float32)
-    vars_ = logits.init(jax.random.PRNGKey(0), imgs)
+    vars_ = jax.jit(logits.init)(jax.random.PRNGKey(0), imgs)
     assert logits.apply(vars_, imgs).shape == (2, 7)
 
 

@@ -60,7 +60,7 @@ def test_moe_mlp_forward_and_grad():
     )
     layer = MoEMLP(cfg)
     x = jnp.asarray(np.random.RandomState(0).randn(2, 16, 32), jnp.float32)
-    vars_ = layer.init(jax.random.PRNGKey(0), x)
+    vars_ = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
     y, mut = layer.apply(vars_, x, mutable=["intermediates"])
     assert y.shape == x.shape
     assert "balance_loss" in mut["intermediates"]
@@ -163,7 +163,7 @@ def test_scatter_dispatch_matches_einsum():
             moe_dispatch=mode,
         )
         layer = MoEMLP(cfg)
-        vars_ = layer.init(jax.random.PRNGKey(0), x)
+        vars_ = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
         outs[mode] = np.asarray(layer.apply(vars_, x))
     np.testing.assert_allclose(outs["scatter"], outs["einsum"],
                                rtol=2e-5, atol=2e-5)
@@ -191,7 +191,7 @@ def test_moe_e16_on_mesh_with_capacity_drops(eight_devices):
     mesh = Mesh(np.array(eight_devices).reshape(1, 4, 2, 1, 1),
                 ("pp", "dp", "fsdp", "cp", "mp"))
     with mesh, nn.logical_axis_rules(make_rules()):
-        vars_ = layer.init(jax.random.PRNGKey(0), x)
+        vars_ = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
         y, grads = jax.jit(
             jax.value_and_grad(
                 lambda v: (layer.apply(v, x) ** 2).mean()

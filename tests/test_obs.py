@@ -296,7 +296,8 @@ def test_live_engine_exposes_prometheus_and_flips_healthz():
         hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
         dtype=jnp.float32, use_flash_attention=False)
     model = GPTForPretraining(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     eng = ServingEngine(
         model, params, slots=2, cache_len=16, prefill_bucket=4,
         gen_cfg=GenerationConfig(decode_strategy="greedy",
@@ -377,7 +378,8 @@ def test_healthz_json_body_carries_rotate_out_reason():
         hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
         dtype=jnp.float32, use_flash_attention=False)
     model = GPTForPretraining(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     eng = ServingEngine(
         model, params, slots=2, cache_len=16, prefill_bucket=4,
         gen_cfg=GenerationConfig(decode_strategy="greedy",
@@ -447,7 +449,8 @@ def test_healthz_fails_after_recovery_exhausted():
         hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
         dtype=jnp.float32, use_flash_attention=False)
     model = GPTForPretraining(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     eng = ServingEngine(
         model, params, slots=1, cache_len=16, prefill_bucket=4,
         max_recoveries=0,

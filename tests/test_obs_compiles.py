@@ -192,7 +192,7 @@ def test_the_same_program_comes_back_from_the_cache_as_a_hit(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
                JAX_COMPILATION_CACHE_DIR=str(tmp_path))
     proc = subprocess.run([sys.executable, "-c", _CACHED], env=env,
-                          capture_output=True, text=True, timeout=300)
+                          capture_output=True, text=True, timeout=60)
     assert proc.returncode == 0, proc.stderr[-2000:]
     said = json.loads(proc.stdout.strip().splitlines()[-1])
     names = [name for name, _, _ in said["spans"]]

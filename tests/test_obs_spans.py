@@ -14,6 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from serving_parity import sharing_programs
+
 from fleetx_tpu.obs import SpanRecorder, get_recorder, span
 
 
@@ -47,6 +49,7 @@ def test_default_ring_and_truncated_export_says_so(monkeypatch):
 LANES = 16
 
 
+@sharing_programs
 def _engine(**kwargs):
     from fleetx_tpu.models.gpt.generation import GenerationConfig
     from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
@@ -58,7 +61,8 @@ def _engine(**kwargs):
         hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
         dtype=jnp.float32, use_flash_attention=False)
     model = GPTForPretraining(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     return ServingEngine(
         model, params, slots=LANES, cache_len=16, prefill_bucket=4,
         page_size=8,
@@ -214,7 +218,7 @@ def test_construction_is_one_span_that_holds_what_it_compiled():
     enable_compile_cache()  # what every entry point calls before its first jit
     rec = get_recorder()
     rec.clear()
-    eng = _engine()
+    eng = _engine.__wrapped__()     # (what an engine compiles is the test)
     spans = rec.spans()
     (build,) = _named(spans, "serving.build")
     assert build.parent is None and spans[-1] is build
@@ -234,7 +238,7 @@ def test_a_buckets_first_prefill_says_so_and_holds_its_compile():
     from fleetx_tpu.utils.compile_cache import enable_compile_cache
 
     enable_compile_cache()
-    eng = _engine()
+    eng = _engine.__wrapped__()     # (its programs' first compile is the test)
     rng = np.random.default_rng(1)
     rec = get_recorder()
     rec.clear()
@@ -599,7 +603,7 @@ def _no_scopes(monkeypatch):
 
 
 def _decode_tick_text():
-    eng = _engine()
+    eng = _engine.__wrapped__()     # (traced anew: under the scopes or not)
     eng.submit(np.asarray([1, 2, 3], np.int32), max_length=6)
     eng.step()
     compiled = eng.compiled_decode()

@@ -26,6 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from serving_parity import computed_once, sharing_programs, traced_apply
+
 from fleetx_tpu.models.gpt import model as gpt_model
 from fleetx_tpu.models.gpt.generation import (
     GenerationConfig,
@@ -47,7 +49,7 @@ SIZES = dict(
     gate="softmax_topk", top_k=2, position_embedding="rope", norm="rmsnorm",
     mlp_act="swiglu", use_bias=False, qk_norm=True, tie_word_embeddings=False,
     family="olmoe", use_flash_attention=False, dtype=jnp.float32)
-reference = functools.partial(olmoe_f32.logits, top_k=2)
+reference = computed_once(functools.partial(olmoe_f32.logits, top_k=2))
 
 
 def build(**changes):
@@ -121,7 +123,8 @@ TOKENS = np.random.default_rng(0).integers(1, 512, (3, 40), dtype=np.int32)
 
 
 def test_full_forward_matches_the_reference(variables):
-    assert distance(build().apply(variables, TOKENS), TOKENS, variables) < TOL
+    assert distance(traced_apply(build(), variables, TOKENS), TOKENS,
+                    variables) < TOL
 
 
 def test_unrolled_layers_match_too(variables):
@@ -131,7 +134,7 @@ def test_unrolled_layers_match_too(variables):
     for i in range(2):
         gpt[f"layer_{i}"] = jax.tree.map(lambda x: x[i], layer)
     unrolled = {"params": {**variables["params"], "gpt": gpt}}
-    got = build(scan_layers=False).apply(unrolled, TOKENS)
+    got = traced_apply(build(scan_layers=False), unrolled, TOKENS)
     assert distance(got, TOKENS, variables) < TOL
 
 
@@ -246,6 +249,7 @@ def test_the_grouped_matmul_kernels_match_ragged_dot(layer):
                              layer=jnp.int32(0))
 
 
+@sharing_programs
 def engine_of(model, v, **kw):
     return ServingEngine(
         model, v, slots=4, cache_len=CACHE_LEN, page_size=PAGE, num_pages=40,
@@ -379,6 +383,6 @@ def test_a_dense_gated_block_trains(variables):
             return gpt_model.pretraining_loss(
                 logits, TOKENS[:1, :16], jnp.ones((1, 16)))
 
-        grads = jax.grad(loss)(v["params"])
+        grads = jax.jit(jax.grad(loss))(v["params"])   # (one program)
         assert all(np.isfinite(g).all() and np.abs(g).max() > 0
                    for g in jax.tree.leaves(grads))

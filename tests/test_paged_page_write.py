@@ -25,6 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from serving_parity import sharing_programs
+
 from fleetx_tpu.models.gpt import paged_write
 from fleetx_tpu.models.gpt.generation import GenerationConfig
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
@@ -86,6 +88,18 @@ def _operands(layers, dtype, rows, seed=0):
     return pools, new
 
 
+def _written_by(write):
+    """One jitted program a form: where the span lies (offset, table, the
+    mask's value) is an operand, so the 96 cases compile the 32 shapes of
+    call they are (layout, dtype, masked or not, rows) and not one each."""
+    return jax.jit(lambda pools, new, tables, wpos, *keep: write(
+        pools, new, tables, wpos, CACHE_LEN, *keep))
+
+
+_PAGE_FORM = _written_by(paged_write.write_rows)
+_ROW_FORM = _written_by(row_form)
+
+
 @pytest.mark.parametrize("layout,dtype,keep,span", CASES,
                          ids=["-".join(c) for c in CASES])
 def test_page_form_leaves_the_row_forms_bits(layout, dtype, keep, span):
@@ -100,11 +114,7 @@ def test_page_form_leaves_the_row_forms_bits(layout, dtype, keep, span):
     args = (pools, new, tables, wpos) + (() if keep is None
                                          else (jnp.asarray(keep),))
 
-    def run(write):
-        return jax.jit(lambda pools, new, tables, wpos, *keep: write(
-            pools, new, tables, wpos, CACHE_LEN, *keep))(*args)
-
-    got, want = run(paged_write.write_rows), run(row_form)
+    got, want = _PAGE_FORM(*args), _ROW_FORM(*args)
     trash = layer * PAGES
     for before, a, b in zip(pools, got, want):
         a, b = (np.delete(np.asarray(x.astype(jnp.float32)), trash, axis=0)
@@ -149,10 +159,11 @@ def tiny():
         hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
         dtype=jnp.float32, use_flash_attention=False)
     model = GPTForPretraining(cfg)
-    return model, model.init(jax.random.PRNGKey(0),
-                             jnp.zeros((2, 8), jnp.int32))
+    return model, jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
 
 
+@sharing_programs
 def _engine(tiny, **kwargs):
     model, params = tiny
     return ServingEngine(
@@ -174,9 +185,9 @@ def test_an_engine_on_the_row_form_holds_the_same_cache(
     first = rng.integers(1, 60, 29, dtype=np.int32)
     second = np.concatenate([first[:16], rng.integers(1, 60, 9, np.int32)])
 
-    def serve():
-        eng = _engine(tiny, cache_len=64, page_size=8, kv_dtype=kv_dtype,
-                      prefill_chunk=chunk)
+    def serve():   # (traced anew: the second under the patch below)
+        eng = _engine.__wrapped__(tiny, cache_len=64, page_size=8,
+                                  kv_dtype=kv_dtype, prefill_chunk=chunk)
         ids = [eng.submit(p, max_length=5) for p in (first, second)]
         done = eng.drain()
         snap = eng.metrics.snapshot()

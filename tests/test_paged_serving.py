@@ -30,7 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from serving_parity import assert_token_parity, one_shot_tokens
+from serving_parity import (assert_token_parity, one_shot_tokens,
+                            sharing_programs)
 
 from fleetx_tpu.models.gpt.generation import GenerationConfig
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
@@ -60,10 +61,12 @@ GREEDY = GenerationConfig(decode_strategy="greedy", eos_token_id=10**6,
 @pytest.fixture(scope="module")
 def model_and_params():
     model = GPTForPretraining(CFG)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     return model, params
 
 
+@sharing_programs
 def _engine(model, params, **kw):
     kw.setdefault("slots", 3)
     kw.setdefault("cache_len", 32)
@@ -353,7 +356,8 @@ def test_host_store_payload_bytes_roundtrip():
         hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
         dtype=jnp.float32, use_flash_attention=False)
     model = GPTForPretraining(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     eng = ServingEngine(
         model, params, slots=1, cache_len=16, prefill_bucket=4,
         page_size=8,

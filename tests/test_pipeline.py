@@ -45,7 +45,7 @@ def test_pipeline_param_remap_roundtrip():
 
     model = GPTForPretraining(GPTConfig(**BASE))
     tokens = jnp.zeros((1, 4), jnp.int32)
-    v = model.init(jax.random.PRNGKey(0), tokens)
+    v = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     v = {"params": jax.tree.map(
         lambda x: x.value if hasattr(x, "value") else x, flax.core.unfreeze(v["params"]),
         is_leaf=lambda x: hasattr(x, "value"),
@@ -70,7 +70,7 @@ def test_pipeline_matches_sequential():
     tokens = jnp.asarray(
         np.random.RandomState(0).randint(0, 128, (4, 16)), jnp.int32
     )
-    v_seq = seq_model.init(jax.random.PRNGKey(0), tokens)
+    v_seq = jax.jit(seq_model.init)(jax.random.PRNGKey(0), tokens)
     v_pipe = _remap_scan_params_to_pipeline(v_seq, 2, 2)
     out_seq = seq_model.apply(v_seq, tokens)
     out_pipe = pipe_model.apply(v_pipe, tokens)
@@ -91,7 +91,7 @@ def test_pipeline_grads_match_sequential():
     tokens = jnp.asarray(rng.randint(0, 128, (4, 16)), jnp.int32)
     labels = jnp.asarray(rng.randint(0, 128, (4, 16)), jnp.int32)
     mask = jnp.ones((4, 16), jnp.float32)
-    v_seq = seq_model.init(jax.random.PRNGKey(0), tokens)
+    v_seq = jax.jit(seq_model.init)(jax.random.PRNGKey(0), tokens)
     v_pipe = _remap_scan_params_to_pipeline(v_seq, 2, 2)
 
     def loss(model, v):
@@ -226,7 +226,7 @@ def test_pipeline_per_example_mask_matches_sequential():
     valid = 1 - pad
     attn_mask = jnp.asarray(valid[:, None, None, :])  # [b, 1, 1, kv]
 
-    v_seq = seq_model.init(jax.random.PRNGKey(0), tokens)
+    v_seq = jax.jit(seq_model.init)(jax.random.PRNGKey(0), tokens)
     v_pipe = _remap_scan_params_to_pipeline(v_seq, 2, 2)
     out_seq = seq_model.apply(v_seq, tokens, None, attn_mask)
     out_pipe = pipe_model.apply(v_pipe, tokens, None, attn_mask)
@@ -255,7 +255,7 @@ def test_virtual_pipeline_stream_compact_parity():
     seq_model = GPTForPretraining(GPTConfig(**cfg))
     rng = np.random.RandomState(0)
     tokens = jnp.asarray(rng.randint(0, 128, (4, 8)), jnp.int32)
-    v_seq = seq_model.init(jax.random.PRNGKey(0), tokens)
+    v_seq = jax.jit(seq_model.init)(jax.random.PRNGKey(0), tokens)
     unboxed = {"params": jax.tree.map(
         lambda x: x.value if hasattr(x, "value") else x,
         flax.core.unfreeze(v_seq["params"]),
@@ -314,7 +314,7 @@ def test_virtual_pipeline_matches_sequential(pp, v, stream):
     tokens = jnp.asarray(rng.randint(0, 128, (4, 16)), jnp.int32)
     labels = jnp.asarray(rng.randint(0, 128, (4, 16)), jnp.int32)
 
-    v_seq = seq_model.init(jax.random.PRNGKey(0), tokens)
+    v_seq = jax.jit(seq_model.init)(jax.random.PRNGKey(0), tokens)
     unboxed = {"params": jax.tree.map(
         lambda x: x.value if hasattr(x, "value") else x,
         flax.core.unfreeze(v_seq["params"]),

@@ -1,12 +1,15 @@
 """The prefill kernel over grouped heads (``ops/pallas/prefill_gqa.py``,
 ``fleetx_prefill_gqa``) interpreted on the CPU at the published head size
-and the kernel's own key block, against its plain twin
+and a key block of 256 rows (a quarter of the kernel's own: a chunk stands
+in a lane of four blocks, under a window of one, as it does at 1,024, and
+the dense twin's products are a sixteenth), against its plain twin
 ``hybrid.grouped_attention`` over the same gathered rows: full and window
 layers, every place a chunk can stand in its lane, rows past the chunk and
 before the window poisoned, what chooses the kernel, the model's chunks
 through both classes of page through it, and the span fields that count its
-key rows. (Compiled for a described v5e at the published widths under the
-one topology fixture of ``tests/test_axk1_serving.py``.)"""
+key rows at the kernel's own block. (Compiled for a described v5e at the
+published widths and the kernel's own block under the one topology fixture
+of ``tests/test_axk1_serving.py``.)"""
 
 import dataclasses
 
@@ -22,15 +25,26 @@ from fleetx_tpu.ops.pallas import prefill_gqa
 from perfbench.drivers.serve_closed_loop_swa import Served
 from perfbench.reference import smallthinker_f32
 
-D, PAGE, WINDOW = 128, 16, 1024
-BLOCK = prefill_gqa.BLOCK_ROWS
-LANE = 4 * BLOCK                    # a lane of four key blocks
+D, PAGE = 128, 16
+BLOCK = 256                         # interpreted (``interpreted_block``)
+WINDOW, LANE = BLOCK, 4 * BLOCK     # a lane of four key blocks
 GROUPS = {"7_over_1": (14, 2), "20_over_1": (20, 1)}   # heads, key heads
 # a float32 sum in another order; one bfloat16 step of values near 2
 TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 CFG = GPTConfig.from_model_config(dict(
     st.MODEL, head_size=D, sliding_window=WINDOW, max_position_embeddings=LANE,
     decode_cache_len=LANE, decode_page_size=PAGE))
+# the same at the kernel's own block: what the span fields count
+OWN_WINDOW, OWN_LANE = prefill_gqa.BLOCK_ROWS, 4 * prefill_gqa.BLOCK_ROWS
+OWN = dataclasses.replace(CFG, sliding_window=OWN_WINDOW,
+                          max_position_embeddings=OWN_LANE,
+                          decode_cache_len=OWN_LANE)
+
+
+@pytest.fixture()
+def interpreted_block(monkeypatch):
+    """Key blocks of ``BLOCK`` rows while ``_kernel`` is traced and run."""
+    monkeypatch.setattr(prefill_gqa, "BLOCK_ROWS", BLOCK)
 
 
 def operands(s, heads, kv_heads, dtype, seed=0):
@@ -78,18 +92,19 @@ def both(kind, start, q, k, v, poison=False):
     return np.asarray(got, np.float32), np.asarray(want, np.float32)
 
 
-STARTS = {"first": lambda s: 0, "inside_the_first_block": lambda s: 96,
-          "block_edge": lambda s: BLOCK, "past_the_window": lambda s: 2600,
+STARTS = {"first": lambda s: 0, "inside_the_first_block": lambda s: 24,
+          "block_edge": lambda s: BLOCK, "past_the_window": lambda s: 650,
           "last_chunk": lambda s: LANE - s}
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("group", sorted(GROUPS))
-@pytest.mark.parametrize("s", [256, 512])
+@pytest.mark.parametrize("s", [64, 128])
 @pytest.mark.parametrize("start", sorted(STARTS))
 @pytest.mark.parametrize("kind", ["full", "window"])
-def test_the_kernel_is_grouped_attention(kind, start, s, group, dtype):
+def test_the_kernel_is_grouped_attention(interpreted_block, kind, start, s,
+                                         group, dtype):
     start = STARTS[start](s)
     got, want = both(kind, start, *operands(s, *GROUPS[group], dtype,
                                             seed=start + s))
@@ -99,14 +114,15 @@ def test_the_kernel_is_grouped_attention(kind, start, s, group, dtype):
 @pytest.mark.parametrize("start", ["inside_the_first_block", "block_edge",
                                    "past_the_window"])
 @pytest.mark.parametrize("kind", ["full", "window"])
-def test_rows_no_query_sees_change_nothing_whatever_they_hold(kind, start):
+def test_rows_no_query_sees_change_nothing_whatever_they_hold(
+        interpreted_block, kind, start):
     """The rows past the chunk, in its last live block and in every block
     behind it, and in a window layer the rows before the first query's
     window (a released page's entry points at the trash page), as NaN: not
     a bit of the output moves."""
-    args = operands(256, 14, 2, jnp.float32)
-    clean, _ = both(kind, STARTS[start](256), *args)
-    poisoned, _ = both(kind, STARTS[start](256), *args, poison=True)
+    args = operands(64, 14, 2, jnp.float32)
+    clean, _ = both(kind, STARTS[start](64), *args)
+    poisoned, _ = both(kind, STARTS[start](64), *args, poison=True)
     assert np.isfinite(clean).all()
     assert (poisoned == clean).all()
 
@@ -156,8 +172,8 @@ def test_chunked_prefill_then_decode_through_both_page_classes(
     real = prefill_gqa.prefill_gqa
     monkeypatch.setattr(prefill_gqa, "prefill_gqa",
                         lambda *a, **k: traced.append(1) or real(*a, **k))
-    engine = st.engine_of(st.build(**MODEL, use_flash_attention=flash),
-                          variables)
+    engine = st.engine_of.__wrapped__(
+        st.build(**MODEL, use_flash_attention=flash), variables)
     row = st.TOKENS[0]
     mine = Served(engine, st.CHUNK).sequence(row, 44)
     want = np.asarray(smallthinker_f32.configured(MODEL)(
@@ -180,18 +196,19 @@ def test_a_chunks_span_fields_count_the_kernels_key_rows(rows, behind, full,
     padding included (``perfbench/flops_gqa_prefill.py`` counts a step's
     work from them)."""
     program = -(-rows // 256) * 256
-    fields = CFG.spans(rows, behind, program)
+    fields = OWN.spans(rows, behind, program)
     assert fields == {"attn_full_key_rows": full,
                       "attn_window_key_rows": window,
                       "attn_query_rows": program}
-    small = dataclasses.replace(CFG, head_size=64)
+    small = dataclasses.replace(OWN, head_size=64)
     assert small.spans(rows, behind) == {}
     assert GPTConfig().spans(rows, behind) == {}
-    one_kind = dataclasses.replace(CFG, sliding_window=None,
+    one_kind = dataclasses.replace(OWN, sliding_window=None,
                                    sliding_window_layout=None)
     assert one_kind.spans(rows, behind, 512) == {
         "attn_query_rows": 512,
-        "attn_full_key_rows": prefill_gqa.key_rows(behind, 512, 0, None, LANE)}
+        "attn_full_key_rows": prefill_gqa.key_rows(behind, 512, 0, None,
+                                                   OWN_LANE)}
 
 
 def test_the_engine_threads_the_span_fields(variables, monkeypatch):

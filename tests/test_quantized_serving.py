@@ -33,7 +33,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from serving_parity import QUANT_ATOL, assert_token_parity, one_shot_tokens
+from serving_parity import (QUANT_ATOL, assert_token_parity, one_shot_tokens,
+                            sharing_programs)
 
 from fleetx_tpu.models.gpt.generation import GenerationConfig
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
@@ -61,7 +62,8 @@ MAX_NEW = 5
 @pytest.fixture(scope="module")
 def model_and_params():
     model = GPTForPretraining(CFG)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     return model, params
 
 
@@ -80,6 +82,7 @@ def reference(model_and_params, prompts):
             for p in prompts]
 
 
+@sharing_programs
 def _engine(model, params, **kw):
     kw.setdefault("slots", 2)
     kw.setdefault("cache_len", 32)

@@ -20,7 +20,7 @@ def _loss_and_grads(cfg, dropout_key=None):
     model = GPTForPretraining(cfg)
     tokens = (jnp.arange(64).reshape(2, 32) * 7) % cfg.vocab_size
     labels = jnp.roll(tokens, -1, axis=1)
-    params = model.init(jax.random.PRNGKey(0), tokens)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     train = {} if dropout_key is None else dict(
         deterministic=False, rngs={"dropout": dropout_key})
 
@@ -144,7 +144,7 @@ def test_core_attn_runs_the_flash_forward_once_a_layer(
                    scan_layers=scan_layers)
         model = GPTForPretraining(cfg)
         tokens = (jnp.arange(128).reshape(4, 32) * 7) % cfg.vocab_size
-        params = model.init(jax.random.PRNGKey(0), tokens)
+        params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
         with use_mesh(mesh) if sharded else contextlib.nullcontext():
             jaxpr = jax.make_jaxpr(jax.grad(
                 lambda p: model.apply(p, tokens).astype(jnp.float32).sum()

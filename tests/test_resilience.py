@@ -327,7 +327,8 @@ GEN = 4  # every request decodes 4 tokens (one one-shot compile bucket)
 @pytest.fixture(scope="module")
 def serving_model():
     model = GPTForPretraining(SCFG)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     return model, params
 
 

@@ -15,6 +15,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from serving_parity import sharing_programs
+
 from fleetx_tpu.models.gpt.generation import GenerationConfig
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
 from fleetx_tpu.obs import get_event_log
@@ -47,7 +49,8 @@ def tiny():
         hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
         dtype=jnp.float32, use_flash_attention=False)
     model = GPTForPretraining(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     return model, params
 
 
@@ -63,6 +66,7 @@ GEN = GenerationConfig(decode_strategy="greedy", eos_token_id=10**6,
                       pad_token_id=60, max_length=8)
 
 
+@sharing_programs
 def _engine(tiny, **kw):
     model, params = tiny
     gen_cfg = kw.pop("gen_cfg", GEN)

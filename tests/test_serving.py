@@ -14,7 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from serving_parity import assert_token_parity, one_shot_tokens
+from serving_parity import (assert_token_parity, one_shot_tokens,
+                            sharing_programs)
 
 from fleetx_tpu.models.gpt.generation import GenerationConfig, generate
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
@@ -39,10 +40,12 @@ GREEDY = GenerationConfig(decode_strategy="greedy", eos_token_id=10**6,
 @pytest.fixture(scope="module")
 def model_and_params():
     model = GPTForPretraining(CFG)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     return model, params
 
 
+@sharing_programs
 def _engine(model, params, **kw):
     kw.setdefault("slots", 3)
     kw.setdefault("cache_len", 32)

@@ -15,6 +15,7 @@
 """
 
 import contextlib
+import functools
 import logging
 import re
 
@@ -22,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+
+from serving_parity import sharing_programs
 
 from fleetx_tpu.models.gpt.generation import GenerationConfig
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
@@ -46,10 +49,12 @@ def tiny():
         hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
         dtype=jnp.float32, use_flash_attention=False)
     model = GPTForPretraining(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     return model, params
 
 
+@sharing_programs
 def _engine(tiny, **kwargs):
     model, params = tiny
     kwargs.setdefault("prefill_bucket", 4)
@@ -246,12 +251,19 @@ def test_lane_state_after_install_is_what_twelve_scalar_writes_gave(
                                       np.asarray(want), err_msg=name)
 
 
-def plain_stream(tiny, request, topk_cap):
-    """``request``'s tokens as the engine has always drawn them, written
-    down eagerly: a full forward over the sequence so far, every sampler
-    operand one ``jnp.asarray(x, dtype)``, one eager split of the
-    request's key an emitted token (greedy consumes none), EOS suppressed
-    under the minimum length."""
+@pytest.fixture(scope="module")
+def plain_stream(tiny):
+    """``plain_stream(greedy, topk_cap)``: the tokens of ``GREEDY`` or of
+    ``SAMPLED`` as the engine has always drawn them, written down eagerly: a
+    full forward over the sequence so far, every sampler operand one
+    ``jnp.asarray(x, dtype)``, one eager split of the request's key an
+    emitted token (greedy consumes none), EOS suppressed under the minimum
+    length. Once a request: every path is held to the same stream."""
+    return functools.cache(functools.partial(_plain_stream, tiny))
+
+
+def _plain_stream(tiny, greedy, topk_cap):
+    request = GREEDY if greedy else SAMPLED
     model, params = tiny
     ids, out = list(PROMPT), []
     carry = jax.random.PRNGKey(request.get("seed", 0))
@@ -308,7 +320,7 @@ def _through_shipping(tiny, request):
     "through", [_through_admission, _through_chunks, _through_replay,
                 _through_shipping],
     ids=["admission", "chunks", "replay", "shipped"])
-def test_tokens_are_the_plain_streams(tiny, through, request_):
+def test_tokens_are_the_plain_streams(tiny, plain_stream, through, request_):
     eng, tokens = through(tiny, request_)
-    assert list(tokens) == plain_stream(tiny, request_, eng.topk_cap)
+    assert list(tokens) == plain_stream(request_ is GREEDY, eng.topk_cap)
     assert len(tokens) == NEW       # EOS under the minimum length, then none

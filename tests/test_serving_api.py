@@ -27,6 +27,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from serving_parity import sharing_programs
+
 from fleetx_tpu.models.gpt.generation import GenerationConfig
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
 from fleetx_tpu.obs import get_event_log
@@ -55,7 +57,8 @@ def tiny():
         hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
         dtype=jnp.float32, use_flash_attention=False)
     model = GPTForPretraining(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     return model, params
 
 
@@ -73,6 +76,7 @@ def _clean_faults():
     gc.collect()
 
 
+@sharing_programs
 def _engine(tiny, **kw):
     model, params = tiny
     return ServingEngine(model, params, slots=kw.pop("slots", 3),

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from serving_parity import sharing_programs
+
 from fleetx_tpu.models.gpt.generation import GenerationConfig
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
 from fleetx_tpu.serving import (
@@ -58,10 +60,12 @@ PROMPTS = [np.asarray([1, 2, 3], np.int32),
 @pytest.fixture(scope="module")
 def model_and_params():
     model = GPTForPretraining(CFG)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     return model, params
 
 
+@sharing_programs
 def _engine(model, params, **kw):
     kw.setdefault("slots", 3)
     kw.setdefault("cache_len", 32)

@@ -17,6 +17,8 @@ it only after a later program was dispatched (the next admission's prefill,
 or the step's tick); none is unread when ``step()`` returns.
 """
 
+import functools
+
 import flax
 import jax
 import jax.numpy as jnp
@@ -26,6 +28,7 @@ import pytest
 import test_lfm2_serving as lfm2
 import test_olmoe_serving as olmoe
 import test_smallthinker_serving as smallthinker
+from serving_parity import sharing_programs
 
 from fleetx_tpu.models.gpt.generation import GenerationConfig
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
@@ -70,7 +73,11 @@ def gpt():
     return model, _stirred(model)
 
 
+@sharing_programs
 def gpt_engine(gpt, **kw):
+    """Some sixty engines over two dozen settings: each setting is traced
+    and compiled once (``sharing_programs``), and every engine still runs
+    what an engine built with its setting builds."""
     model, variables = gpt
     kw = {"slots": 3, "cache_len": 32, "page_size": 8, "prefill_bucket": 4,
           **kw}
@@ -629,26 +636,10 @@ def test_window_rows_count_the_dispatched_programs_rows(smallthinker_weights):
 
 # ------------------------------ (g) an admission's first token in flight
 
-_JITS = ("_decode_jit", "_probe_jit", "_admit_jit", "_prefill_jits")
-
-
 @pytest.fixture(scope="module")
 def same(gpt):
-    """``same(**kw)``: a GPT engine of this section. Engines built with EQUAL
-    keyword arguments run the jitted programs of the first one built with
-    them (some thirty engines over a dozen settings: the suite's time
-    limit); every setting still runs what an engine built with it builds,
-    and sections (a) to (f) build each engine whole."""
-    built = {}
-
-    def engine(**kw):
-        made = gpt_engine(gpt, **kw)
-        key = repr(sorted({"slots": 3, **kw}.items()))
-        jits = built.setdefault(key, {n: getattr(made, n) for n in _JITS})
-        for name, jit in jits.items():
-            setattr(made, name, jit)
-        return made
-    return engine
+    """``same(**kw)``: a GPT engine of this section."""
+    return functools.partial(gpt_engine, gpt)
 
 
 @pytest.fixture(scope="module")

@@ -19,7 +19,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from serving_parity import assert_token_parity
+from serving_parity import assert_token_parity, sharing_programs
 
 from fleetx_tpu.models.gpt.generation import GenerationConfig
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
@@ -53,7 +53,8 @@ def tiny():
         hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
         dtype=jnp.float32, use_flash_attention=False)
     model = GPTForPretraining(cfg)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     return model, params
 
 
@@ -64,6 +65,7 @@ def _clean_faults():
     faults.reset()
 
 
+@sharing_programs
 def _engine(tiny, **kw):
     model, params = tiny
     gen_cfg = kw.pop("gen_cfg", None) or GenerationConfig(

@@ -12,6 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from serving_parity import traced_apply
+
 from fleetx_tpu.models.gpt.model import GPTConfig
 from fleetx_tpu.parallel.moe import DroplessMoEMLP
 
@@ -30,7 +32,8 @@ def layer(**changes):
     module = DroplessMoEMLP(cfg)
     x = jnp.asarray(np.random.default_rng(0).normal(size=(2, 9, 32)),
                     jnp.float32)
-    params = flax.core.meta.unbox(module.init(jax.random.PRNGKey(1), x))
+    params = flax.core.meta.unbox(jax.jit(module.init)(
+        jax.random.PRNGKey(1), x))
     params = jax.tree.map(lambda w: w * 8.0 if w.ndim == 2 else w, params)
     return module, params, x
 
@@ -63,7 +66,7 @@ def written_out(params, x, *, bias=True, normalise=True, scaling=1.0, k=4):
 ])
 def test_the_gate_is_its_definition(changes, kw):
     module, params, x = layer(**changes)
-    y, sown = module.apply(params, x, mutable=["routing"])
+    y, sown = traced_apply(module, params, x, mutable=["routing"])
     want, chosen, weights = written_out(params, x, **kw)
     np.testing.assert_allclose(np.asarray(y), want, rtol=2e-4, atol=2e-5)
     routing = sown["routing"]
@@ -85,7 +88,7 @@ def test_the_bias_moves_the_choice_and_never_the_weight():
         lambda path, w: jnp.full_like(w, 0.3)
         if "expert_bias" in jax.tree_util.keystr(path) else w, params)
     np.testing.assert_allclose(
-        np.asarray(module.apply(flat, x)),
+        np.asarray(traced_apply(module, flat, x)),
         written_out(params, x, bias=False)[0], rtol=2e-4, atol=2e-5)
 
 
@@ -99,11 +102,12 @@ def test_the_kernels_run_the_gate_at_the_new_proportions(monkeypatch):
                              params["params"][k]])
                   for k in ("w_gate", "w_up", "w_down"))
     cache = {"moe_stats": jnp.zeros((2, 16), jnp.uint32)}
-    y, mut = module.apply({**params, "cache": cache}, x, decode=True,
+    y, mut = traced_apply(module, {**params, "cache": cache}, x, decode=True,
                           expert_stack=stack, layer_index=jnp.int32(1),
                           mutable=["cache"])
-    np.testing.assert_allclose(np.asarray(y), np.asarray(module.apply(params, x)),
-                               rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(y), np.asarray(traced_apply(module, params, x)),
+        rtol=2e-4, atol=2e-5)
     stats = np.asarray(mut["cache"]["moe_stats"])
     assert not stats[0].any() and stats[1].any()
 

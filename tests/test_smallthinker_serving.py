@@ -29,6 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from serving_parity import computed_once, sharing_programs
+
 from fleetx_tpu.models.gpt.generation import GenerationConfig
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
 from fleetx_tpu.obs.tracing import get_recorder
@@ -50,7 +52,7 @@ MODEL = dict(
 SIZES = dict(MODEL, hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
              expert_mode=True, family="smallthinker",
              use_flash_attention=False, dtype=jnp.float32)
-reference = smallthinker_f32.configured(MODEL)
+reference = computed_once(smallthinker_f32.configured(MODEL))
 TOKENS = np.random.default_rng(0).integers(1, 512, (2, 56), dtype=np.int32)
 
 
@@ -93,6 +95,10 @@ def full_forward(model, v, tokens=TOKENS):
         v["params"], tokens)
 
 
+served_of = sharing_programs(Served)
+
+
+@sharing_programs
 def engine_of(model, v, lanes=3, **kw):
     return ServingEngine(
         model, v, slots=lanes, cache_len=CACHE_LEN, page_size=PAGE,
@@ -130,7 +136,7 @@ def test_chunked_prefill_then_decode_through_both_page_classes(variables):
     engine = engine_of(build(), variables)
     pool = engine.cache_manager.window_pool
     for row in TOKENS:
-        mine = Served(engine, CHUNK).sequence(row, 44)
+        mine = served_of(engine, CHUNK).sequence(row, 44)
         want = np.asarray(reference(variables["params"], row))
         # the last chunk's 16 positions and the 12 decode steps
         assert len(mine["logits"]) == 28
@@ -184,7 +190,7 @@ def test_the_tolerance_bites(variables, fault):
 def test_the_tolerance_bites_through_the_pool(variables, fault):
     """And a wrong window shows through chunked prefill and decode."""
     engine = engine_of(build(**FAULTS[fault]), variables)
-    mine = Served(engine, CHUNK).sequence(TOKENS[0], 44)
+    mine = served_of(engine, CHUNK).sequence(TOKENS[0], 44)
     want = np.asarray(reference(variables["params"], TOKENS[0]))
     assert distance(mine["logits"], want[-len(mine["logits"]):]) >= 30 * TOL
 

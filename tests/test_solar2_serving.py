@@ -29,6 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from serving_parity import computed_once, traced_apply
+
 from fleetx_tpu.models.gpt.generation import GenerationConfig
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
 from fleetx_tpu.ops.pallas import kda
@@ -44,7 +46,7 @@ CONFIG = harness.load_json("perfbench", "configs", "solar-open2-ep16-l8.json")
 MODEL = dict(harness.with_tiny(CONFIG, True)["model"], vocab_size=256,
              max_position_embeddings=512)
 SIZES = dict(MODEL, use_flash_attention=False, dtype="float32")
-reference = solar2_f32.configured(MODEL)
+reference = computed_once(solar2_f32.configured(MODEL))
 TOKENS = np.random.default_rng(0).integers(1, 256, (2, 56), dtype=np.int32)
 
 
@@ -103,7 +105,7 @@ def served(engine):
 # ------------------------------------------------- the stack and the reference
 
 def test_full_forward_matches_the_reference(variables):
-    logits = build().apply(variables, TOKENS[:1])
+    logits = traced_apply(build(), variables, TOKENS[:1])
     assert distance(logits[0], reference(variables["params"], TOKENS[0])) < TOL
 
 
@@ -166,7 +168,8 @@ def test_chunked_prefill_then_ticks_are_the_reference(engine, served,
 def test_the_negative_eigenvalue_is_really_applied(variables):
     """``kda_neg_eigval`` off halves ``beta``: the logits then stand far
     from the reference's (which doubles it)."""
-    logits = build(kda_neg_eigval=False).apply(variables, TOKENS[:1])
+    logits = traced_apply(build(kda_neg_eigval=False), variables,
+                          TOKENS[:1])
     assert distance(logits[0], reference(variables["params"], TOKENS[0])) > 0.05
 
 
@@ -183,7 +186,7 @@ def test_a_strong_decay_stays_finite_and_is_the_reference(variables):
         return jnp.full_like(x, 3.0) if "f_b']['bias" in name else x
 
     held = jax.tree_util.tree_map_with_path(strong, variables)
-    logits = build().apply(held, TOKENS[1:])[0]
+    logits = traced_apply(build(), held, TOKENS[1:])[0]
     assert np.isfinite(np.asarray(logits)).all()
     theirs = reference(held["params"], TOKENS[1], with_parts=True,
                        states_at=(56,))
@@ -255,7 +258,7 @@ def test_rows_that_are_no_token_leave_the_state_of_the_rows_before(
     padded = (q, k, v, jnp.where(live[:, None, None], g, 0.0),
               jnp.where(live[:, None], beta, 0.0))
     s0 = _state(rng)
-    _, s = kda.kda_chunk(*padded, s0)
+    _, s = jax.jit(kda.kda_chunk)(*padded, s0)
     count = int(live.sum())
     _, want = kda.kda_chunk_plain(*(t[:count] for t in padded), s0)
     np.testing.assert_allclose(s, want, atol=1e-6)
@@ -329,14 +332,15 @@ def test_the_step_kernel_updates_one_layer_of_the_leaf_in_place(interpreted):
                 jnp.where(idle, 0.0, beta))
     state = jnp.asarray(rng.standard_normal((2, 3, 32, 2, 32)), jnp.float32)
     fresh = jnp.asarray([False, True, False])
-    o, new = kda.kda_step(state, jnp.asarray(1), *operands, fresh)
+    step = jax.jit(kda.kda_step)
+    o, new = step(state, jnp.asarray(1), *operands, fresh)
     want_o, want = kda.kda_step_plain(state, 1, *operands, fresh)
     np.testing.assert_allclose(o, want_o, atol=2e-5)
     np.testing.assert_allclose(new, want, atol=2e-5)
     np.testing.assert_array_equal(new[0], state[0])     # the other layer
     np.testing.assert_array_equal(new[1, 2], state[1, 2])   # the idle lane
-    _, kept = kda.kda_step(state, jnp.asarray(1), *operands, fresh,
-                           skip=jnp.asarray(True))
+    _, kept = step(state, jnp.asarray(1), *operands, fresh,
+                   skip=jnp.asarray(True))
     np.testing.assert_array_equal(kept, state)
 
 
@@ -351,7 +355,7 @@ def test_the_parts_all_the_shares_give_add_up_to_the_uncut_layer():
     cfg = GPTConfig.from_model_config({**SIZES, "first_expert_held": 0})
     x = jax.random.normal(jax.random.PRNGKey(3), (1, 24, 64))
     whole = jax.random.normal(jax.random.PRNGKey(4), (3, 16, 64, 32)) * 0.2
-    v = flax.core.meta.unbox(moe_share.SharedMoEMLP(cfg).init(
+    v = flax.core.meta.unbox(jax.jit(moe_share.SharedMoEMLP(cfg).init)(
         jax.random.PRNGKey(5), x))["params"]
     v = {**v, "router": {"kernel": v["router"]["kernel"] * 20.0},
          "expert_bias": v["expert_bias"] * 4.0}
@@ -362,7 +366,7 @@ def test_the_parts_all_the_shares_give_add_up_to_the_uncut_layer():
         mine = {**v, "w_gate": whole[0, i * held:(i + 1) * held],
                 "w_up": whole[1, i * held:(i + 1) * held],
                 "w_down": whole[2, i * held:(i + 1) * held].swapaxes(1, 2)}
-        total = total + layer.apply({"params": mine}, x)
+        total = total + traced_apply(layer, {"params": mine}, x)
     shared = moe_share._shared_expert(
         x[0], v["shared_gate"], v["shared_up"], v["shared_down"])
     uncut = {"router": {"kernel": v["router"]["kernel"][None]},

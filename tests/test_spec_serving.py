@@ -36,7 +36,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from serving_parity import assert_token_parity, one_shot_tokens
+from serving_parity import (assert_token_parity, one_shot_tokens,
+                            sharing_programs)
 
 from fleetx_tpu.models.gpt.generation import GenerationConfig
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
@@ -64,7 +65,8 @@ MAX_NEW = 8
 @pytest.fixture(scope="module")
 def model_and_params():
     model = GPTForPretraining(CFG)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((2, 8), jnp.int32))
     return model, params
 
 
@@ -74,6 +76,7 @@ def prompts():
     return [rng.randint(1, 97, (n,)).astype(np.int32) for n in PROMPT_LENS]
 
 
+@sharing_programs
 def _engine(model, params, **kw):
     kw.setdefault("slots", 3)
     kw.setdefault("cache_len", 32)

@@ -24,7 +24,7 @@ def test_multiprocess_tool_runs_and_reports(tmp_path):
     r = subprocess.run(
         [sys.executable, f"{REPO}/tools/multiprocess_tool.py",
          "--num-proc", "4", "--cmd-file", str(cmd_file)],
-        capture_output=True, text=True,
+        capture_output=True, text=True, timeout=120,
     )
     assert r.returncode == 0, r.stderr
     assert len(list(out.iterdir())) == 8
@@ -37,7 +37,7 @@ def test_multiprocess_tool_nonzero_exit_on_failure(tmp_path):
     r = subprocess.run(
         [sys.executable, f"{REPO}/tools/multiprocess_tool.py",
          "--num-proc", "2", "--cmd-file", str(cmd_file)],
-        capture_output=True, text=True,
+        capture_output=True, text=True, timeout=120,
     )
     assert r.returncode == 1
     assert "1 failed" in r.stdout
@@ -56,7 +56,7 @@ def test_precompute_text_embeddings_hash(tmp_path):
         [sys.executable, f"{REPO}/tools/precompute_text_embeddings.py",
          "--input", str(caps), "--output-prefix", prefix,
          "--max-text-len", "8", "--cond-dim", "16"],
-        capture_output=True, text=True,
+        capture_output=True, text=True, timeout=120,
     )
     assert r.returncode == 0, r.stderr
     embeds = np.load(prefix + "_embeds.npy")
@@ -275,7 +275,7 @@ def test_precomputed_embeddings_feed_text_image_dataset(tmp_path):
         [sys.executable, f"{REPO}/tools/precompute_text_embeddings.py",
          "--input", str(caps), "--output-prefix", prefix,
          "--max-text-len", "8", "--cond-dim", "16"],
-        capture_output=True, text=True,
+        capture_output=True, text=True, timeout=120,
     )
     assert r.returncode == 0, r.stderr
     np.save(prefix + "_images.npy",

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from serving_parity import computed_once, sharing_programs
+
 from fleetx_tpu.models.gpt.generation import GenerationConfig
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
 from fleetx_tpu.obs.tracing import get_recorder
@@ -59,7 +61,7 @@ MODEL = dict(
 SIZES = dict(MODEL, hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
              expert_mode=True, family="trinity", use_flash_attention=False,
              dtype=jnp.float32)
-reference = trinity_f32.configured(MODEL)
+reference = computed_once(trinity_f32.configured(MODEL))
 TOKENS = np.random.default_rng(0).integers(1, 512, (2, 56), dtype=np.int32)
 
 
@@ -101,6 +103,10 @@ def full_forward(model, params, tokens=TOKENS):
     return jax.jit(lambda p, t: model.apply({"params": p}, t))(params, tokens)
 
 
+served_of = sharing_programs(Served)
+
+
+@sharing_programs
 def engine_of(model, v, lanes=3, **kw):
     return ServingEngine(
         model, v, slots=lanes, cache_len=CACHE_LEN, page_size=PAGE,
@@ -156,7 +162,7 @@ def test_chunked_prefill_then_decode_through_both_page_classes(variables):
     assert engine.capabilities.state_kinds == ("kv",)
     pool = engine.cache_manager.window_pool
     for row in TOKENS:
-        mine = Served(engine, CHUNK).sequence(row, 44)
+        mine = served_of(engine, CHUNK).sequence(row, 44)
         want = np.asarray(reference(variables["params"], row))
         assert len(mine["logits"]) == 28
         assert distance(mine["logits"], want[-28:]) <= TOL

@@ -26,7 +26,7 @@ TINY = ViTConfig(
 def test_vit_forward_shapes():
     model = ViT(TINY)
     imgs = jnp.zeros((2, 32, 32, 3))
-    vars_ = model.init(jax.random.PRNGKey(0), imgs)
+    vars_ = jax.jit(model.init)(jax.random.PRNGKey(0), imgs)
     logits = model.apply(vars_, imgs)
     assert logits.shape == (2, 10)
 
@@ -44,7 +44,7 @@ def test_droppath_train_vs_eval():
     cfg = ViTConfig(**{**TINY.__dict__, "drop_path_rate": 0.5})
     model = ViT(cfg)
     imgs = jnp.ones((4, 32, 32, 3))
-    vars_ = model.init(jax.random.PRNGKey(0), imgs)
+    vars_ = jax.jit(model.init)(jax.random.PRNGKey(0), imgs)
     eval1 = model.apply(vars_, imgs, deterministic=True)
     eval2 = model.apply(vars_, imgs, deterministic=True)
     np.testing.assert_array_equal(np.asarray(eval1), np.asarray(eval2))
@@ -158,7 +158,7 @@ def test_vit_flash_matches_xla(monkeypatch):
                        jnp.float32)
     xla_model = ViT(ViTConfig(**{**TINY.__dict__,
                                  "use_flash_attention": False}))
-    vars_ = xla_model.init(jax.random.PRNGKey(0), imgs)
+    vars_ = jax.jit(xla_model.init)(jax.random.PRNGKey(0), imgs)
     ref = xla_model.apply(vars_, imgs)
     monkeypatch.setenv("FLEETX_FORCE_FLASH", "1")
     out = ViT(TINY).apply(vars_, imgs)  # flash default ON
