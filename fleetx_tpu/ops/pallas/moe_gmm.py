@@ -18,13 +18,15 @@ Two kernels, named for the device trace (docs/OBSERVABILITY.md):
   float32, one rounding on the way out;
 - ``fleetx_moe_down``: ``a @ w_down[e]``.
 
-The row layout is bounded statically (``rows + experts * (tm - 1)``, plus
-one SPARE tile at the end that never holds rows) and filled dynamically:
-``num_tiles`` says how many tiles hold rows. Tiles after it are skipped:
-they run no product, re-name the last real tile's input blocks (no DMA)
-and all write the spare tile, so that nothing real is overwritten. Rows
-of padding inside a real tile compute garbage from whatever token the
-layout gathered there; nobody reads them.
+The row layout is bounded statically (``rows + experts * (tm - 1)``
+rounded up to a tile, plus one more: XLA needs the shapes) and filled
+dynamically: ``num_tiles``, at least 1, says how many tiles hold rows, and
+it is the BOUND of the grid's row axis, ``grid=(n // tn, num_tiles)``. A
+call walks the tiles that hold rows and no other (a step over a tile
+without rows cost 0.12-0.2 us here, once for every column block: PERF.md,
+PR 60); the rows of the result from tile ``num_tiles`` on are never
+written. Rows of padding inside a walked tile compute garbage from
+whatever token the layout gathered there; nobody reads them.
 
 The weights are the WHOLE layer stack ``[layers, experts, k, n]`` with
 ``layer`` saying which one: the layer is picked inside the index map too.
@@ -77,18 +79,15 @@ def _col_tile(k: int, n: int, itemsize: int) -> int:
     return tn
 
 
-def _kernel(te_ref, nt_ref, layer_ref, x_ref, *refs, act):
+def _kernel(te_ref, layer_ref, x_ref, *refs, act):
     del te_ref, layer_ref  # read by the index maps
     o_ref = refs[-1]
-
-    @pl.when(pl.program_id(1) < nt_ref[0])
-    def _():
-        x = x_ref[...]
-        out = jnp.dot(x, refs[0][...], preferred_element_type=jnp.float32)
-        if act is not None:
-            up = jnp.dot(x, refs[1][...], preferred_element_type=jnp.float32)
-            out = _ACTS[act](out) * up
-        o_ref[...] = out.astype(o_ref.dtype)
+    x = x_ref[...]
+    out = jnp.dot(x, refs[0][...], preferred_element_type=jnp.float32)
+    if act is not None:
+        up = jnp.dot(x, refs[1][...], preferred_element_type=jnp.float32)
+        out = _ACTS[act](out) * up
+    o_ref[...] = out.astype(o_ref.dtype)
 
 
 def _grouped(name, x, weights, tile_expert, num_tiles, tm, layer, act=None):
@@ -103,25 +102,18 @@ def _grouped(name, x, weights, tile_expert, num_tiles, tm, layer, act=None):
     if wk != k or rows % tm:
         raise ValueError(f"{name}: rows {x.shape} (tile {tm}) against "
                          f"weights {weights[0].shape}")
-    spare = rows // tm - 1  # the layout's last tile holds no rows
     tn = _col_tile(k, n, weights[0].dtype.itemsize)
-
-    def real(i, nt):  # a skipped tile re-names the last real one: no DMA
-        return jnp.minimum(i, nt[0] - 1)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=2,
         # columns outside, rows inside: along the rows the weight block
-        # changes only where the expert does
-        grid=(n // tn, spare),
-        in_specs=[pl.BlockSpec((tm, k),
-                               lambda j, i, te, nt, li: (real(i, nt), 0))]
+        # changes only where the expert does. The row bound is the traced
+        # count of tiles that hold rows
+        grid=(n // tn, num_tiles),
+        in_specs=[pl.BlockSpec((tm, k), lambda j, i, te, li: (i, 0))]
         + [pl.BlockSpec((None, None, k, tn),
-                        lambda j, i, te, nt, li: (li[0], te[real(i, nt)], 0, j))
+                        lambda j, i, te, li: (li[0], te[i], 0, j))
            for _ in weights],
-        out_specs=pl.BlockSpec(
-            (tm, tn),
-            lambda j, i, te, nt, li: (jnp.where(i < nt[0], i, spare), j)),
+        out_specs=pl.BlockSpec((tm, tn), lambda j, i, te, li: (i, j)),
     )
     out = pl.pallas_call(
         functools.partial(_kernel, act=act),
@@ -133,7 +125,6 @@ def _grouped(name, x, weights, tile_expert, num_tiles, tm, layer, act=None):
         interpret=_interpret(),
         name=name,
     )(tile_expert.astype(jnp.int32),
-      jnp.reshape(num_tiles, (1,)).astype(jnp.int32),
       jnp.reshape(layer, (1,)).astype(jnp.int32),
       x, *weights)
     return out
@@ -142,10 +133,11 @@ def _grouped(name, x, weights, tile_expert, num_tiles, tm, layer, act=None):
 def grouped_gate_up(x, w_gate, w_up, tile_expert, num_tiles, *, tm: int,
                     layer, act: str = "silu"):
     """``act(x @ w_gate[l, e]) * (x @ w_up[l, e])`` for rows ``x`` ``[rows,
-    k]`` laid out in ``tm``-row tiles (the last one spare), tile ``i`` of
-    expert ``tile_expert[i]``; weights the stack ``[layers, experts, k, n]``
-    and ``layer`` (a traced scalar) the layer ``l``. Tiles from
-    ``num_tiles`` on are skipped: their rows of the result are not defined."""
+    k]`` laid out in ``tm``-row tiles, tile ``i`` of expert
+    ``tile_expert[i]``; weights the stack ``[layers, experts, k, n]`` and
+    ``layer`` (a traced scalar) the layer ``l``. The grid walks the first
+    ``num_tiles`` tiles (a traced scalar, at least 1): the rows of the
+    result past them are not defined."""
     return _grouped(GATE_UP_KERNEL_NAME, x, (w_gate, w_up), tile_expert,
                     num_tiles, tm, layer, act)
 

@@ -252,10 +252,13 @@ class MoEMLP(nn.Module):
 
 # per layer, in the decode cache's ``moe_stats`` leaf: for one-token calls
 # (a decode tick) and for longer ones (a prefill) the calls, the
-# token-expert pairs routed, the experts that had a row, and the rows of
-# the largest expert, each summed over the calls as a count of two uint32
-# words, low then high (serving/model_protocol.py reads them)
-MOE_STATS = ("calls", "pairs", "experts_read", "largest_load")
+# token-expert pairs routed, the experts that had a row, the rows of the
+# largest expert, the tiles of rows the grouped matmuls walked (the ones
+# that held rows: their grid's bound) and the tiles the static layout laid,
+# each summed over the calls as a count of two uint32 words, low then high
+# (serving/model_protocol.py reads them)
+MOE_STATS = ("calls", "pairs", "experts_read", "largest_load",
+             "tiles_walked", "tiles_laid")
 
 
 # rows of one block of ``_running_count``
@@ -406,7 +409,8 @@ class DroplessMoEMLP(nn.Module):
                 self.sow("routing", "router_input", router_input)
             self.sow("routing", "experts", topk_idx.reshape(b, s, k))
             self.sow("routing", "weights", weights.reshape(b, s, k))
-        self._count(sizes, n * k, s, decode, layer_index)
+        self._count(sizes, n * k, (num_tiles, len(tile_expert) - (tm > 1)),
+                    s, decode, layer_index)
         with jax.named_scope("moe_experts"):
             if kernel:
                 w_gate, w_up, w_down = (w.astype(dt) for w in expert_stack)
@@ -458,10 +462,14 @@ class DroplessMoEMLP(nn.Module):
                 else nn.initializers.zeros_init(), (None,)),
             (experts,), jnp.float32)
 
-    def _count(self, sizes, pairs: int, seq: int, decode: bool, layer_index):
+    def _count(self, sizes, pairs: int, tiles, seq: int, decode: bool,
+               layer_index):
         """Add this call to the ``moe_stats`` leaf of the decode cache (the
         engine carries that tree from program to program and fetches none
-        of it: ``ServingMetrics.snapshot()`` does, through the executor)."""
+        of it: ``ServingMetrics.snapshot()`` does, through the executor).
+        ``tiles`` is the layout's ``(num_tiles, tiles that can hold
+        rows)``: what the kernels' grid walks and its static bound (the
+        spare tile at the end not counted)."""
         if not decode:
             return
         fresh = not self.has_variable("cache", "moe_stats")
@@ -472,10 +480,11 @@ class DroplessMoEMLP(nn.Module):
         with jax.named_scope("moe_route"):
             add = jnp.stack([jnp.uint32(1), jnp.uint32(pairs),
                              (sizes > 0).sum().astype(jnp.uint32),
-                             sizes.max().astype(jnp.uint32)])
+                             sizes.max().astype(jnp.uint32),
+                             jnp.uint32(tiles[0]), jnp.uint32(tiles[1])])
             low = 2 * ((0 if seq == 1 else len(MOE_STATS))
                        + jnp.arange(len(MOE_STATS)))
-            # the layer scan carries the whole stack [L, 16]
+            # the layer scan carries the whole stack [L, words]
             at = (low,) if layer_index is None else (layer_index, low)
             up = (low + 1,) if layer_index is None else (layer_index, low + 1)
             was = stats.value[at]

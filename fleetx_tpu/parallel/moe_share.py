@@ -43,8 +43,10 @@ for one chip of a deployment whose every layer is divided over several
 The counters in the cache's ``moe_stats`` leaf count what THIS program did:
 the pairs laid out here and the held experts that had a row
 (``GPTExecutor.counters`` reads them as ``moe_{tick,prefill}_pairs`` and
-``_experts_read``); all the pairs routed are ``rows x top_k`` of a call,
-which the host knows. With zero-compute experts the leaf is ``ZERO_WORDS``
+``_experts_read``), and the tiles of rows its kernels walked against the
+tiles the static layout laid (``_tiles_walked`` / ``_tiles_laid``); all
+the pairs routed are ``rows x top_k`` of a call, which the host knows.
+With zero-compute experts the leaf is ``ZERO_WORDS``
 wider (:func:`stats_words`): the pairs whose expert is zero-compute, of
 ticks and of prefills (``moe_{tick,prefill}_zero_pairs``), and the most
 and the fewest routed (non-zero) experts any ONE row of a tick chose
@@ -154,10 +156,11 @@ def held_row_layout(topk_idx, first: int, count: int, tm: int):
     rows (a scatter drops it, a gather clamps it: its weight is zeroed by
     ``held`` ``[n, k]``); ``sizes`` ``[count]`` and the tiles are of the held
     experts, numbered from 0. The rows are bounded as if every pair were
-    held. ``num_tiles`` is at least 1: where NO pair of the call is held (a
-    tick of few lanes) the kernels still step over tile 0, whose rows no
-    pair reads (their index maps name tile ``num_tiles - 1``, which must
-    exist)."""
+    held (a static shape, which XLA needs); ``num_tiles`` counts the tiles
+    that hold rows and is the bound of the kernels' grid over them, so a
+    call steps over those alone. It is at least 1: where NO pair of the
+    call is held (a tick of few lanes) the kernels step over tile 0, whose
+    rows no pair reads."""
     n, k = topk_idx.shape
     m = n * k
     local = topk_idx.reshape(m).astype(jnp.int32) - first
@@ -252,7 +255,9 @@ class SharedMoEMLP(DroplessMoEMLP):
             self.sow("routing", "experts", topk_idx.reshape(b, s, k))
             self.sow("routing", "weights", weights.reshape(b, s, k))
         # (the pairs laid out HERE, which only the device knows)
-        self._count(sizes, sizes.sum(), s, decode, layer_index)
+        self._count(sizes, sizes.sum(),
+                    (num_tiles, len(tile_expert) - (tm > 1)), s, decode,
+                    layer_index)
         if cfg.num_zero_experts:
             zero = topk_idx >= routed
             self._count_zero(zero, s, decode, layer_index)

@@ -288,9 +288,10 @@ class GPTExecutor(ModelExecutor):
         """The routing counts of ``parallel/moe.py`` ``DroplessMoEMLP``
         (its ``moe_stats`` cache leaves, per layer :data:`MOE_STATS` as
         two-word counts): per layer and per program the experts that had
-        a row and the largest-over-mean expert load, for one-token
-        programs (ticks) and longer ones (prefills), and the token-expert
-        pairs routed in all."""
+        a row, the largest-over-mean expert load and the tiles of rows
+        the grouped matmuls walked against the tiles their static layout
+        laid, for one-token programs (ticks) and longer ones (prefills),
+        and the token-expert pairs routed in all."""
         from fleetx_tpu.parallel.moe import MOE_STATS
 
         leaves = [leaf for path, leaf in
@@ -309,11 +310,15 @@ class GPTExecutor(ModelExecutor):
         experts = int(self.model.cfg.num_experts)
         out = {"moe_layers": len(per_layer),
                "moe_pairs_routed": int(stats[:, 1].sum())}
-        for kind, (calls, pairs, read, largest) in zip(("tick", "prefill"),
-                                                       stats.tolist()):
+        for kind, (calls, pairs, read, largest, walked, laid) in zip(
+                ("tick", "prefill"), stats.tolist()):
             out[f"moe_{kind}_layer_calls"] = calls
             out[f"moe_{kind}_pairs"] = pairs
             out[f"moe_{kind}_experts_read"] = read / calls if calls else 0.0
+            # (a layer and program, mean: walked / laid is the share of
+            # the static grid that the traffic fills)
+            out[f"moe_{kind}_tiles_walked"] = walked / calls if calls else 0.0
+            out[f"moe_{kind}_tiles_laid"] = laid / calls if calls else 0.0
             # the largest expert's rows over the mean expert's, over all
             # the calls (each weighted by the pairs it routed)
             out[f"moe_{kind}_load_max_over_mean"] = (

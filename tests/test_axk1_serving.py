@@ -104,7 +104,7 @@ def test_the_cache_holds_latents_alone_and_the_leaf_is_as_wide_as_held(built):
               jax.tree_util.tree_flatten_with_path(cache)[0]}
     # three layers' pages flat; c_kv 32 a row, k_r 8 held in a 128-lane tile
     assert shapes == {"cached_key": (39, 8, 32), "cached_value": (39, 8, 128),
-                      "moe_stats": (2, 16)}
+                      "moe_stats": (2, 24)}
     assert latent.rope_leaf_width(built[0].cfg) == 128
     assert built[0].cfg.state_kinds == ("latent",)
 
@@ -552,8 +552,8 @@ def test_a_call_with_no_held_pair_still_names_a_tile_and_adds_nothing(
     stack = tuple(params[k][None] for k in ("w_gate", "w_up", "w_down"))
     got, mut = traced_apply(
         layer,
-        {"params": params, "cache": {"moe_stats": jnp.zeros((1, 16),
-                                                            jnp.uint32)}},
+        {"params": params, "cache": {"moe_stats": jnp.zeros(
+            (1, moe_share.stats_words(cfg)), jnp.uint32)}},
         x, decode=True, layer_index=jnp.int32(0), expert_stack=stack,
         mutable=["cache"])
     # one tick call counted, no pair laid out here, no held expert read
@@ -562,6 +562,142 @@ def test_a_call_with_no_held_pair_still_names_a_tile_and_adds_nothing(
     want = (jax.nn.silu(t @ params["shared_gate"]) * (
         t @ params["shared_up"])) @ params["shared_down"]
     assert np.abs(np.asarray(got).reshape(-1, 64) - np.asarray(want)).max() < 1e-6
+
+
+def _pairs(kind, tokens, top_k, held, routed):
+    """``[tokens, top_k]`` experts of ``routed``, of which ``[0, held)`` are
+    held here: a share's proportions, no pair held, or groups that fill
+    their tiles (16 pairs every held expert)."""
+    rng = np.random.default_rng(tokens)
+    if kind == "share":
+        return np.stack([rng.permutation(routed)[:top_k]
+                         for _ in range(tokens)])
+    if kind == "none":
+        return held + np.stack([rng.permutation(routed - held)[:top_k]
+                                for _ in range(tokens)])
+    return (np.arange(tokens * top_k) % held).reshape(tokens, top_k)
+
+
+def _experts(rows, gate, up, down, tile_expert, num_tiles, layer, tm):
+    """Both grouped matmuls of ``ops/pallas/moe_gmm.py`` over one layout."""
+    from fleetx_tpu.ops.pallas import moe_gmm
+
+    hidden = moe_gmm.grouped_gate_up(rows, gate, up, tile_expert, num_tiles,
+                                     tm=tm, layer=layer)
+    return moe_gmm.grouped_down(hidden, down, tile_expert, num_tiles, tm=tm,
+                                layer=layer)
+
+
+@pytest.mark.parametrize("kind, tokens, top_k, held, routed, tm", [
+    ("share", 64, 4, 4, 64, 16),      # 20 tiles laid, 3-4 of them hold rows
+    ("share", 40, 3, 2, 32, 16),
+    ("none", 2, 3, 4, 16, 16),        # a tick of few lanes: ONE tile
+    ("full", 32, 2, 4, 4, 16),        # every walked tile is full
+    ("full", 64, 1, 2, 2, 32),
+])
+def test_the_kernels_walk_the_tiles_that_hold_rows_and_no_other(
+        kind, tokens, top_k, held, routed, tm):
+    """Both grouped matmuls (interpreted) against ``ragged_dot`` on the held
+    rows, with every row of the INPUT past ``num_tiles`` tiles set to NaN:
+    the bound of the grid's row axis is the traced ``num_tiles``, so no step
+    reads them, and no held row shows one."""
+    h, f = 128, 256
+    rng = np.random.default_rng(7)
+    idx = jnp.asarray(_pairs(kind, tokens, top_k, held, routed), jnp.int32)
+    x = jnp.asarray(rng.normal(size=(tokens, h)), jnp.float32)
+    gate, up, down = (jnp.asarray(rng.normal(size=s) * 0.1, jnp.float32)
+                      for s in ((2, held, h, f), (2, held, h, f),
+                                (2, held, f, h)))
+    dest, src, sizes, *_ = moe_share.held_row_layout(idx, 0, held, 1)
+    rows = x[src]
+    want = jax.lax.ragged_dot(
+        jax.nn.silu(jax.lax.ragged_dot(rows, gate[1], sizes))
+        * jax.lax.ragged_dot(rows, up[1], sizes), down[1], sizes)[dest]
+
+    dest, src, sizes, tile_expert, num_tiles, here = (
+        moe_share.held_row_layout(idx, 0, held, tm))
+    walked = max(int((-(-np.asarray(sizes) // tm)).sum()), 1)
+    laid = len(tile_expert) - 1
+    assert int(num_tiles) == walked <= laid == -(-(
+        tokens * top_k + held * (tm - 1)) // tm)
+    assert {"share": walked * 4 <= laid, "none": walked == 1,
+            "full": walked * tm == tokens * top_k}[kind]
+    rows = x[src].at[walked * tm:].set(jnp.nan)
+
+    def both(rows, tile_expert, num_tiles):
+        return _experts(rows, gate, up, down, tile_expert, num_tiles,
+                        jnp.int32(1), tm)
+
+    calls = [e.params["grid_mapping"] for e in jax.make_jaxpr(both)(
+        rows, tile_expert, num_tiles).jaxpr.eqns
+        if e.primitive.name == "pallas_call"]
+    assert [c.num_dynamic_grid_bounds for c in calls] == [1, 1]
+    got = jax.jit(both)(rows, tile_expert, num_tiles)[dest]
+    here = np.asarray(here).reshape(-1)
+    assert here.sum() == np.asarray(sizes).sum()
+    np.testing.assert_allclose(np.asarray(got)[here], np.asarray(want)[here],
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_the_expert_kernels_compile_for_the_v5e_under_the_traced_bound(
+        one_chip, monkeypatch):
+    """A chunk of 512 at the published widths (12 experts of 7,168 x 2,048,
+    269 tiles of 16 rows laid) through Mosaic's own passes: the traced
+    scalar as the bound of the grid's row axis is the chip's compiler's to
+    take or refuse, not the interpreter's."""
+    from fleetx_tpu.ops.pallas import moe_gmm
+
+    monkeypatch.setattr(moe_gmm, "_interpret", lambda: False)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(_experts, static_argnames="tm").lower(
+        spec((269 * 16, 7168)), spec((2, 12, 7168, 2048)),
+        spec((2, 12, 7168, 2048)), spec((2, 12, 2048, 7168)),
+        spec((269,), jnp.int32), spec((), jnp.int32),
+        spec((), jnp.int32), tm=16).compile().as_text()
+    assert moe_gmm.GATE_UP_KERNEL_NAME in text
+    assert moe_gmm.DOWN_KERNEL_NAME in text
+
+
+@pytest.mark.parametrize("kind, shape", [("tick", (6, 1)),
+                                         ("prefill", (1, 24))])
+def test_the_counters_read_the_tiles_walked_and_the_tiles_laid(
+        monkeypatch, kind, shape):
+    """``moe_{tick,prefill}_tiles_walked`` is the bound of the kernels' grid
+    over the tiles, ``sum(ceil(an expert's pairs / tm))`` and at least 1;
+    ``_tiles_laid`` the static count of tiles that can hold rows. A call of
+    one token a lane is a tick's, a longer one a prefill's."""
+    from fleetx_tpu.serving.model_protocol import GPTExecutor
+
+    monkeypatch.setenv("FLEETX_FORCE_FLASH", "1")
+    cfg = GPTConfig.from_model_config({
+        **SIZES, "num_routed_experts": 16, "num_experts": 4, "top_k": 3,
+        "n_group": 1, "topk_group": 1, "use_flash_attention": True})
+    layer = moe_share.SharedMoEMLP(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(6), (*shape, 64), jnp.float32)
+    params = flax.core.meta.unbox(jax.jit(layer.init)(
+        jax.random.PRNGKey(1), x))["params"]
+    stack = tuple(params[k][None] for k in ("w_gate", "w_up", "w_down"))
+    stats = jnp.zeros((2, moe_share.stats_words(cfg)), jnp.uint32)
+    _, mut = traced_apply(
+        layer, {"params": params, "cache": {"moe_stats": stats}}, x,
+        decode=True, layer_index=jnp.int32(0), expert_stack=stack,
+        mutable=["cache", "routing"])
+    chose = np.asarray(mut["routing"]["experts"][0]).reshape(-1) - 4
+    sizes = np.bincount(chose[(chose >= 0) & (chose < 4)], minlength=4)
+    pairs, tm = chose.size, 16
+    walked = max(int((-(-sizes // tm)).sum()), 1)
+    laid = -(-(pairs + 4 * (tm - 1)) // tm)
+    counters = GPTExecutor(GPTForPretraining(cfg)).counters(mut["cache"])
+    other = "prefill" if kind == "tick" else "tick"
+    assert counters[f"moe_{kind}_layer_calls"] == 1
+    assert counters[f"moe_{kind}_pairs"] == sizes.sum() > 0
+    assert counters[f"moe_{kind}_tiles_walked"] == walked
+    assert counters[f"moe_{kind}_tiles_laid"] == laid > walked
+    assert not any(counters[f"moe_{other}_{name}"] for name in (
+        "layer_calls", "pairs", "tiles_walked", "tiles_laid"))
 
 
 # ------------------------------------------------ what stays as it is today

@@ -155,7 +155,7 @@ def test_the_cache_has_three_leaves_under_one_table(built):
     shapes = {path[-1].key: leaf.shape for path, leaf in
               jax.tree_util.tree_flatten_with_path(cache)[0]}
     assert shapes == {"cached_key": (51, 8, 32), "cached_value": (51, 8, 128),
-                      "cached_index": (51, 8, 16), "moe_stats": (2, 16)}
+                      "cached_index": (51, 8, 16), "moe_stats": (2, 24)}
     assert built[0].cfg.state_kinds == ("latent",) and built[0].cfg.indexed
 
 

@@ -153,7 +153,7 @@ def test_the_pool_holds_the_latent_as_scaled_and_the_key_unscaled(
         params, tokens[:32], with_latents=True)
     pools = {p[-1].key: leaf for p, leaf in
              jax.tree_util.tree_flatten_with_path(cache)[0]}
-    assert pools["moe_stats"].shape == (2, 16 + moe_share.ZERO_WORDS)
+    assert pools["moe_stats"].shape == (2, 24 + moe_share.ZERO_WORDS)
     for half in range(4):        # a half's pages follow the last's (13 each)
         rows = np.arange(32)
         page = 1 + rows // 8 + half * 13
@@ -301,9 +301,11 @@ def test_the_counters_count_zero_pairs_and_what_a_token_costs():
     words = np.asarray(mut["cache"]["moe_stats"])
     assert not words[[0, 2]].any()
     zero = (chose >= 8).sum(-1)
-    assert words[1, 16] == zero.sum() and words[1, 18] == 0     # a tick's
-    assert words[1, 20] == 3 - zero.min() and words[1, 21] == zero.max()
-    counters = moe_share.zero_counters(words[:, 16:], 3)
+    base = moe_share.stats_words(cfg) - moe_share.ZERO_WORDS
+    assert words[1, base] == zero.sum() and words[1, base + 2] == 0  # a tick's
+    assert (words[1, base + 4] == 3 - zero.min()
+            and words[1, base + 5] == zero.max())
+    counters = moe_share.zero_counters(words[:, base:], 3)
     assert counters == {"moe_tick_zero_pairs": int(zero.sum()),
                         "moe_prefill_zero_pairs": 0,
                         "moe_tick_routed_pairs_max": int(3 - zero.min()),
@@ -314,7 +316,8 @@ def test_the_counters_count_zero_pairs_and_what_a_token_costs():
         x.reshape(1, 6, 64), decode=True, layer_index=jnp.int32(0),
         mutable=["cache"])
     words = np.asarray(mut["cache"]["moe_stats"])
-    assert words[0, 18] == zero.sum() and not words[0, [16, 20, 21]].any()
+    assert (words[0, base + 2] == zero.sum()
+            and not words[0, [base, base + 4, base + 5]].any())
 
 
 # ------------------------------------------------- refused, by the field's name
@@ -372,16 +375,19 @@ def test_no_shared_expert_and_no_leading_dense_layer_under_a_share(built):
 # the stacks without ``moe_shortcut`` trace the programs they traced then,
 # instruction for instruction. A PR that changes ``mixed_stack.py``'s body
 # for them on purpose takes the digests anew (``python
-# tests/test_longcat_serving.py`` prints them).
+# tests/test_longcat_serving.py`` prints them). Taken anew at PR 60, whose
+# two more counts a kind (``moe.MOE_STATS``: the tiles walked and laid)
+# widen the ``moe_stats`` leaf from 16 words to 24 and add two terms to the
+# sum ``_count`` makes: nothing else differs from the texts of the parent.
 UNCHANGED = {
     "perfbench/configs/lfm2-8b-a1b-l14.json": (
-        "97851c5e27901377", "03c00e595bb6fee5"),
+        "1ec650880ab8da35", "1fc7bbf953fae765"),
     "perfbench/configs/trinity-large-ep8-l5.json": (
-        "b9d480080bbe56ca", "6e8dac2f5e99f4a4"),
+        "70c1b453d98ead52", "87f001db3f5070e7"),
     "perfbench/configs/axk1-ep16-l6.json": (
-        "110b338a73d3ef6c", "56d923c3d07cb709"),
+        "48bcd44c0b0d768d", "56e2215bb59c6dbf"),
     "perfbench/configs/dsv32-ep16-l5.json": (
-        "5ceed40bed9b4e32", "591c67c83434e363"),
+        "74ba8f253a84bd04", "240f46306b2eb0f5"),
 }
 
 

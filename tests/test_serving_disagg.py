@@ -506,6 +506,11 @@ def test_router_disagg_parity_roles_and_health(model_and_params):
     # the aggregated /healthz body carries the same placement signals
     from fleetx_tpu.obs.http import healthz_payload, register_health
 
+    # (an engine an earlier test of this process shut down unregisters its
+    # probe when it is collected: do that now, or its 503 is the aggregate's)
+    import gc
+
+    gc.collect()
     register_health("serving", pre.health)
     try:
         ok, body = healthz_payload()

@@ -15,7 +15,7 @@ import pytest
 from serving_parity import traced_apply
 
 from fleetx_tpu.models.gpt.model import GPTConfig
-from fleetx_tpu.parallel.moe import DroplessMoEMLP
+from fleetx_tpu.parallel.moe import MOE_STATS, DroplessMoEMLP
 
 SIZES = dict(
     vocab_size=64, hidden_size=32, num_layers=3, num_attention_heads=4,
@@ -101,7 +101,7 @@ def test_the_kernels_run_the_gate_at_the_new_proportions(monkeypatch):
     stack = tuple(jnp.stack([jnp.zeros_like(params["params"][k]),
                              params["params"][k]])
                   for k in ("w_gate", "w_up", "w_down"))
-    cache = {"moe_stats": jnp.zeros((2, 16), jnp.uint32)}
+    cache = {"moe_stats": jnp.zeros((2, 2 * len(MOE_STATS) * 2), jnp.uint32)}
     y, mut = traced_apply(module, {**params, "cache": cache}, x, decode=True,
                           expert_stack=stack, layer_index=jnp.int32(1),
                           mutable=["cache"])
@@ -126,6 +126,6 @@ def test_dense_layers_count_nothing_in_the_expert_counters():
                                          "attention": 1, "dense": 1,
                                          "experts": 2}
     cache = init_decode_cache(GPTForPretraining(cfg), 2)["gpt"]["layers"]
-    assert cache["moe_stats"].shape == (2, 16)
+    assert cache["moe_stats"].shape == (2, 24)
     assert cache["cached_key"].shape == (9, 8, 32)        # one attention layer
     assert cache["conv_state"].shape == (2 * 9, 2, 32)    # two conv layers
