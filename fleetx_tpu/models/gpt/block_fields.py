@@ -28,8 +28,8 @@ from typing import Optional, Tuple
 import numpy as np
 
 __all__ = ["BlockLayoutFields", "LANE_STATE_LEAVES", "LAYOUT_FIELDS",
-           "LAYER_TYPES", "RECURRENT_TYPES", "check", "layer_class",
-           "stack_of"]
+           "LAYER_TYPES", "RECURRENT_TYPES", "VISION_FIELDS", "check",
+           "fold_mrope", "layer_class", "rows_in", "stack_of"]
 
 # per-layer lists (a YAML or JSON list becomes a tuple: the configuration is
 # a module attribute and has to hash)
@@ -166,6 +166,28 @@ class BlockLayoutFields:
     index_n_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
+    # The same indexer before GROUPED attention (a ``layer_types`` stack of
+    # ``full_attention`` layers with ``qk_norm_scope: head``,
+    # models/gpt/hybrid.py): its three projections read the layer's normed
+    # input (there is no query latent), the chosen rows are gathered from
+    # the K and V pages, and ALL ``index_head_dim`` columns rotate, in the
+    # axes ``index_rope_section`` gives (below)
+    index_rope_section: Optional[Tuple[int, ...]] = None
+    # ---- rotary positions of three axes (time, height, width): rotary pair
+    # ``j`` of a head's ``head_dim / 2`` takes the position of the axis
+    # whose section of ``mrope_section`` it falls in (three counts that sum
+    # to ``head_dim / 2``; None: one axis). A call is then handed positions
+    # ``[3, b, s]`` (``[b, s]``: the three alike), and a row's position is
+    # no longer its place in the cache (:func:`fold_mrope`)
+    mrope_section: Optional[Tuple[int, ...]] = None
+    # ---- a vision tower whose rows enter the stack beside token rows
+    # (models/vision/vit.py ``VisionTower``; serving/engine.py admits them):
+    # a group of ``hidden_size``, ``num_layers``, ``num_heads``,
+    # ``intermediate_size``, ``patch_size``, ``grid`` (the learned position
+    # table's side), ``merge`` (2: a row is 2 x 2 patches) and
+    # ``image_token_id`` (the id that marks an image's rows in a prompt); a
+    # mapping in a YAML, held as a sorted tuple of pairs (``vision_fields``)
+    vision: Optional[tuple] = None
     # ---- an expert layer that holds a SHARE (parallel/moe_share.py): the
     # router is ``num_routed_experts`` wide and every token chooses among
     # all of them; this program holds ``num_experts`` of them, from
@@ -219,6 +241,11 @@ class BlockLayoutFields:
         """Whether a learned indexer selects the rows latent attention
         reads."""
         return self.index_topk > 0
+
+    @property
+    def vision_fields(self) -> dict:
+        """The ``vision`` group as a mapping (empty without a tower)."""
+        return dict(self.vision or ())
 
     def selected(self, rows):
         """Of ``rows`` cached rows behind a query (itself among them), those
@@ -336,8 +363,10 @@ class BlockLayoutFields:
         scale = jax.ShapeDtypeStruct((1, ps, heads), jnp.float32)
         pools = [pool] * 2 + [scale] * (2 * quant)
         kinds = self.of_attention_layers(self.window_layers)
+        # (under an indexer the kernel walks the compact pool of chosen rows)
+        held = self.selected(self.decode_cache_len)
         return lanes * sum(
-            paged_grid(pools, self.decode_cache_len // ps,
+            paged_grid(pools, -(-int(held) // ps),
                        max_live=self.sliding_window if windowed else None)[1]
             for windowed in kinds)
 
@@ -357,19 +386,24 @@ class BlockLayoutFields:
             from fleetx_tpu.models.gpt.hybrid import chunk_key_rows
 
             return {**chunk_key_rows(self, program_rows or rows, behind),
-                    **self.span_pairs(rows)}
+                    **self.span_pairs(rows), **self._span_selection(
+                        rows, behind)}
         from fleetx_tpu.ops.pallas.mla_prefill import key_rows
 
-        fields = {"latent_rows": behind + rows,
-                  "latent_key_rows": key_rows(behind + rows),
-                  **self.span_pairs(rows)}
-        if self.indexed:
-            # summed over the call's rows: the index keys each scores (every
-            # row up to its own) and the rows it then attends over
-            each = behind + 1 + np.arange(rows)
-            fields.update(index_rows=int(each.sum()),
-                          selected_rows=int(self.selected(each).sum()))
-        return fields
+        return {"latent_rows": behind + rows,
+                "latent_key_rows": key_rows(behind + rows),
+                **self.span_pairs(rows),
+                **self._span_selection(rows, behind)}
+
+    def _span_selection(self, rows: int, behind: int) -> dict:
+        """Under an indexer, summed over a call's ``rows``: the index keys
+        each scores (every row up to its own) and the rows it then attends
+        over."""
+        if not self.indexed:
+            return {}
+        each = behind + 1 + np.arange(rows)
+        return {"index_rows": int(each.sum()),
+                "selected_rows": int(self.selected(each).sum())}
 
     @property
     def mamba_inner(self) -> int:
@@ -426,7 +460,15 @@ def check(cfg) -> None:
                 f"{name} has {len(value)} entries {value}; it needs "
                 f"num_layers = {cfg.num_layers} entries of "
                 + " | ".join(map(str, allowed)))
+    for name in ("mrope_section", "index_rope_section"):
+        if getattr(cfg, name) is not None:
+            object.__setattr__(cfg, name, tuple(
+                int(v) for v in getattr(cfg, name)) or None)
+    if cfg.vision is not None:
+        object.__setattr__(cfg, "vision", tuple(sorted(
+            dict(cfg.vision).items())) or None)
     _check_mixed(cfg)
+    _check_positions_and_tower(cfg)
     if cfg.router_input not in ("mlp_norm", "block_input"):
         raise ValueError(f"router_input={cfg.router_input!r}; choose "
                          "mlp_norm | block_input")
@@ -665,12 +707,17 @@ def _check_latent(cfg) -> None:
     if not cfg.latent:
         widths = [n for n in ("q_lora_rank", "kv_lora_rank",
                               "qk_nope_head_dim", "qk_rope_head_dim",
-                              "v_head_dim", "index_n_heads", "index_head_dim",
-                              "index_topk", "mla_scale_q_lora",
+                              "v_head_dim", "mla_scale_q_lora",
                               "mla_scale_kv_lora") if getattr(cfg, n)]
         if widths:
             raise ValueError(f"{widths} without a latent_attention layer")
+        _check_grouped_indexer(cfg)
         return
+    if cfg.index_rope_section:
+        raise ValueError(
+            "index_rope_section over latent attention: its indexer rotates "
+            "a head's first qk_rope_head_dim columns by the rotary key's "
+            "angles")
     if set(cfg.layer_types) != {"latent_attention"}:
         raise NotImplementedError(
             "layer_types with latent_attention beside another operator: no "
@@ -700,6 +747,117 @@ def _check_latent(cfg) -> None:
             f"index_n_heads {sizes[0]}, index_head_dim {sizes[1]}, "
             f"index_topk {sizes[2]}: the indexer needs all three, and a "
             "head at least qk_rope_head_dim wide (its first columns rotate)")
+
+
+def _check_grouped_indexer(cfg) -> None:
+    """The indexer's fields on a stack WITHOUT latent attention: all three,
+    over ``full_attention`` layers alone with per-head QK-norm, rotating."""
+    sizes = (cfg.index_n_heads, cfg.index_head_dim, cfg.index_topk)
+    if not any(sizes):
+        if cfg.index_rope_section:
+            raise ValueError("index_rope_section without an indexer")
+        return
+    if set(cfg.layer_types) != {"full_attention"} or (
+            cfg.qk_norm_scope != "head") or cfg.attention_gate != "none" or (
+            cfg.position_embedding != "rope") or cfg.rope_layout:
+        raise ValueError(
+            "index_n_heads / index_head_dim / index_topk without a "
+            "latent_attention layer take a layer_types stack of "
+            "full_attention layers alone (no window: the chosen rows are "
+            "gathered from ONE class of page), qk_norm_scope: head, every "
+            "layer rotating and no attention_gate: what "
+            "tests/test_keyevl2_serving.py covers")
+    if min(sizes) < 1 or cfg.index_head_dim % 2 or (
+            cfg.head_dim % cfg.index_head_dim):
+        raise ValueError(
+            f"index_n_heads {sizes[0]}, index_head_dim {sizes[1]}, "
+            f"index_topk {sizes[2]}: the indexer needs all three, and an "
+            "even head that divides head_dim (every pair of it rotates, at "
+            "every head_dim / index_head_dim-th of the heads' frequencies)")
+    section = cfg.index_rope_section
+    if section and (len(section) != 3 or min(section) < 0
+                    or sum(section) != cfg.index_head_dim // 2
+                    or not cfg.mrope_section):
+        raise ValueError(
+            f"index_rope_section {section}: three counts that sum to "
+            f"index_head_dim / 2 = {cfg.index_head_dim // 2}, beside "
+            "mrope_section")
+    if cfg.mrope_section and not section:
+        raise ValueError("mrope_section with an indexer needs "
+                         "index_rope_section (its pairs' axes)")
+
+
+VISION_FIELDS = ("hidden_size", "num_layers", "num_heads",
+                 "intermediate_size", "patch_size", "grid", "merge",
+                 "image_token_id")
+
+
+def _check_positions_and_tower(cfg) -> None:
+    """``mrope_section`` and the ``vision`` group, each refusal with the
+    field's name."""
+    section = cfg.mrope_section
+    if section is not None:
+        if cfg.position_embedding != "rope" or cfg.latent or not (
+                cfg.layer_types) or cfg.rope_layout:
+            raise NotImplementedError(
+                "mrope_section needs position_embedding: rope in a "
+                "layer_types stack of grouped attention layers, every layer "
+                "rotating (models/gpt/mixed_stack.py folds the three axes; "
+                "latent attention computes YaRN's angles itself)")
+        if len(section) != 3 or min(section) < 0 or (
+                sum(section) != cfg.head_dim // 2):
+            raise ValueError(
+                f"mrope_section {section}: three counts (time, height, "
+                f"width) that sum to head_dim / 2 = {cfg.head_dim // 2}")
+    if cfg.vision is None:
+        return
+    tower = cfg.vision_fields
+    missing = [n for n in VISION_FIELDS if n not in tower]
+    unknown = sorted(set(tower) - set(VISION_FIELDS))
+    if missing or unknown:
+        raise ValueError(f"vision group: missing {missing}, unknown "
+                         f"{unknown}; it holds {list(VISION_FIELDS)}")
+    if not cfg.layer_types or cfg.position_embedding != "rope":
+        raise NotImplementedError(
+            "a vision group needs a layer_types stack with rotary positions "
+            "(its rows enter models/gpt/mixed_stack.py's stack)")
+    if tower["hidden_size"] % tower["num_heads"] or tower["merge"] != 2 or (
+            min(tower["num_layers"], tower["grid"], tower["patch_size"]) < 1):
+        raise ValueError(
+            f"vision group {tower}: num_heads divides hidden_size, merge is "
+            "2 (a row is 2 x 2 patches), and layers, grid and patch_size "
+            "are at least 1")
+    if not 0 <= tower["image_token_id"] < cfg.vocab_size:
+        raise ValueError(f"vision.image_token_id {tower['image_token_id']} "
+                         f"outside the vocabulary of {cfg.vocab_size}")
+
+
+def rows_in(word_emb, input_ids, input_rows):
+    """The rows that enter the stack: the word table's at ``input_ids``,
+    and, where a caller hands ``input_rows`` = ``(rows [b, s, hidden],
+    is_image [b, s] bool)``, a tower's rows in the places ``is_image`` marks
+    (serving/engine.py: the rows a vision tower made of an image)."""
+    x = word_emb[input_ids]
+    if input_rows is None:
+        return x
+    rows, is_image = input_rows
+    import jax.numpy as jnp
+
+    return jnp.where(is_image[..., None], rows.astype(x.dtype), x)
+
+
+def fold_mrope(rope, section):
+    """``(cos, sin)`` ``[3, b, s, pairs]`` of the three axes' angles (what
+    ``model.rope_tables`` gives for positions ``[3, b, s]``) folded to ``[b,
+    s, pairs]``: pair ``j`` from the axis whose section it falls in.
+    Tables of ONE axis ``[b, s, pairs]`` pass as they are, so a text-only
+    call with three equal axes reads the same bits either way."""
+    if not section or rope[0].ndim == 3:
+        return rope
+    import jax.numpy as jnp
+
+    pick = jnp.asarray(np.repeat(np.arange(3), section))[None, None, None, :]
+    return tuple(jnp.take_along_axis(t, pick, axis=0)[0] for t in rope)
 
 
 def stack_of(model):

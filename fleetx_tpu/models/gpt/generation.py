@@ -184,7 +184,7 @@ def init_decode_cache(model, batch: int):
 
 
 def decode_step(model, params, cache, input_ids, position_ids, kv_mask=None,
-                cache_positions=None, block_tables=None):
+                cache_positions=None, block_tables=None, **rows_in):
     """One cached decode forward: ``(logits, new_cache)``.
 
     The single reusable step both the ``generate()`` loop body and the
@@ -205,7 +205,7 @@ def decode_step(model, params, cache, input_ids, position_ids, kv_mask=None,
         decode=True,
         cache_positions=cache_positions,
         block_tables=block_tables,
-        mutable=["cache"],
+        mutable=["cache"], **rows_in,
     )
     return logits, mut["cache"]
 

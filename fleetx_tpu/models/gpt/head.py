@@ -42,12 +42,14 @@ def _head(cfg, params, hidden):
 
 def row_logits_step(model, params, cache, input_ids, position_ids,
                     kv_mask=None, cache_positions=None, block_tables=None, *,
-                    logit_rows):
+                    logit_rows, **rows_in):
     """``decode_step`` for a caller that reads one row a batch element:
     ``(logits [b, 1, vocab], new_cache)``, the logits those of row
     ``logit_rows[b]``. A NEGATIVE entry asks for no row of its element;
     where every entry is negative the head is not run at all (the program
-    takes the other side of a conditional) and the logits are zeros."""
+    takes the other side of a conditional) and the logits are zeros.
+    ``rows_in``: ``input_rows=`` for a model that takes a tower's rows
+    beside ids (``block_fields.rows_in``)."""
     hidden, mut = GPTModel(model.cfg).apply(
         {"params": params["gpt"], "cache": cache["gpt"]},
         input_ids,
@@ -56,7 +58,7 @@ def row_logits_step(model, params, cache, input_ids, position_ids,
         decode=True,
         cache_positions=cache_positions,
         block_tables=block_tables,
-        mutable=["cache"],
+        mutable=["cache"], **rows_in,
     )
     rows = jnp.asarray(logit_rows, jnp.int32)
     # the model's own scope for its head, around the slice and the

@@ -35,6 +35,24 @@ decode kernels run and the head size is whole 128-lane column blocks of the
 pool's rows (``_chunk_kernel``); every other call through the cache takes
 its plain twin ``grouped_attention`` over the same rows.
 
+**Under a learned indexer** (``index_topk`` > 0 on a stack of
+``full_attention`` layers: ``block_fields._check_grouped_indexer``; the
+functions are ``models/gpt/indexer.py``'s, which latent attention calls
+too). Beside q, k and v the layer projects, FROM ITS NORMED INPUT ``a``
+(there is no query latent): ``qI = a W_Iq`` (``index_n_heads`` heads of
+``index_head_dim``), ONE key a row ``kI = LayerNorm(a W_Ik)``, head weights
+``wI = (a W_Iw) * heads^-0.5 * head_dim^-0.5`` (float32); every pair of
+``qI`` and ``kI`` rotates, by angles of their own (the call's ``rope`` is
+then four tables: the heads' two and the indexer's two,
+``mixed_stack.MixedStack``). ``kI`` is the pool's THIRD leaf
+``cached_index`` (held :func:`index_leaf_width` wide), written through
+``write_rows`` beside K and V. A tick scores each lane's rows (scope
+``dsa_index``), takes the top ``index_topk`` (``dsa_select``), gathers
+their K and V rows into a compact pool and runs ``fleetx_decode_paged`` over
+it (``dsa_attn``); a chunk scores, selects by threshold, and attends under
+its mask ``[s, rows]`` in ``fleetx_gqa_sparse_prefill`` (its plain twin:
+``grouped_attention`` handed the mask).
+
 Forward only where it differs from ``model.py``: a forward outside the
 cache takes the plain path (``grouped_attention``); training this block,
 with grouped heads and a window in the flash kernels, is ROADMAP R4.
@@ -50,7 +68,7 @@ import numpy as np
 from flax import linen as nn
 from jax.ad_checkpoint import checkpoint_name
 
-from fleetx_tpu.models.gpt import paged_write
+from fleetx_tpu.models.gpt import indexer, paged_write
 from fleetx_tpu.models.gpt.model import (
     MLP,
     GPTConfig,
@@ -65,13 +83,29 @@ from fleetx_tpu.ops.pallas import prefill_gqa
 from fleetx_tpu.ops.pallas.flash_attention import kernels_enabled
 
 __all__ = ["POOL_LEAVES", "HybridDecoderLayer", "HybridSelfAttention",
-           "chunk_key_rows", "grouped_attention", "init_cache", "layer_bases",
-           "total_pages", "window_gather", "write_rows"]
+           "chunk_key_rows", "grouped_attention", "index_leaf_width",
+           "init_cache", "layer_bases", "total_pages", "window_gather",
+           "write_rows"]
 
 _NEG = -1e30  # a masked score: finite, so a row of padding stays finite
 # the leaves of the flat pool, under the one block table (``cached_index``:
 # the third leaf of a latent pool whose model has an indexer)
 POOL_LEAVES = ("cached_key", "cached_value", "cached_index")
+
+
+def _rotated_index_key(ki, rope):
+    """The indexer's key ``[b, s, d]``, every pair rotated, as the cache
+    takes it (a seam: ``perfbench/probe_keyevl2.py`` plants a fault here)."""
+    return apply_rope(ki[:, :, None], rope)[:, :, 0]
+
+
+def index_leaf_width(cfg: GPTConfig) -> int:
+    """Columns of the index key's leaf: ``index_head_dim`` rounded up to the
+    device's 128-lane tile, the columns past the key zeros (a 64-wide leaf
+    occupies 128 lanes a row in HBM whatever its declared shape, and a page
+    of it cannot be copied out of that tiling by itself:
+    ``latent.rope_leaf_width``)."""
+    return -(-cfg.index_head_dim // 128) * 128
 
 
 def _pages_of(cfg: GPTConfig):
@@ -223,6 +257,10 @@ class HybridSelfAttention(SelfAttention):
         windowed = jnp.asarray(cfg.of_attention_layers(cfg.window_layers),
                                bool)[layer_index]
         if phase == "attend":
+            if cfg.indexed:  # (q, qI, wI): ``_check_grouped_indexer``: no gate
+                return self._out_proj(checkpoint_name(self._indexed_attention(
+                    *x, cache_positions, block_tables, layer_index),
+                    "core_attn_out"))
             x, gate = jnp.split(x, 2, axis=-2) if gated else (x, None)
             return self._out_proj(self._gate(checkpoint_name(
                 self._paged_attention(
@@ -258,10 +296,21 @@ class HybridSelfAttention(SelfAttention):
         if rope is not None and any(cfg.rope_layers):
             rotates = jnp.asarray(cfg.of_attention_layers(cfg.rope_layers),
                                   bool)[layer_index]
-            q = jnp.where(rotates, apply_rope(q, rope), q)
-            k = jnp.where(rotates, apply_rope(k, rope), k)
+            q = jnp.where(rotates, apply_rope(q, rope[:2]), q)
+            k = jnp.where(rotates, apply_rope(k, rope[:2]), k)
         b, s = q.shape[:2]
         k, v = k.reshape(b, s, kvh * hd), v.reshape(b, s, kvh * hd)
+        if cfg.indexed:
+            qi, ki, head_w = self._index_projections(x, rope)
+            if phase == "project":  # (the third leaf's rows, as it holds them)
+                return (q, qi, head_w), k, v, jnp.pad(ki, ((0, 0), (0, 0), (
+                    0, index_leaf_width(cfg) - cfg.index_head_dim)))
+            if decode:
+                raise NotImplementedError(
+                    "a cached forward under the indexer outside the layer "
+                    "loop's two phases (models/gpt/mixed_stack.py)")
+            return self._out_proj(checkpoint_name(self._indexed_dense(
+                q, k, v, qi, ki, head_w, attn_mask), "core_attn_out"))
         if phase == "project":
             return (jnp.concatenate([q, gate], -2) if gated else q), k, v
 
@@ -286,6 +335,118 @@ class HybridSelfAttention(SelfAttention):
             out = grouped_attention(q, k, v, allowed[None, None])
         out = checkpoint_name(out, "core_attn_out")
         return self._out_proj(self._gate(out, gate))
+
+    def _index_projections(self, x, rope):
+        """``(qI, kI, wI)`` of the layer's normed input ``x`` (module
+        docstring): ``[b, s, heads, d]`` and ``[b, s, d]`` rotated by the
+        indexer's own tables ``rope[2:]``, ``[b, s, heads]`` float32."""
+        cfg = self.cfg
+        ni, di = cfg.index_n_heads, cfg.index_head_dim
+        with jax.named_scope("dsa_index"):
+            qi = _dense((ni, di), ("embed", "heads", "kv"), "index_q_proj",
+                        use_bias=False, dtype=cfg.dtype)(x)
+            ki = nn.LayerNorm(
+                epsilon=cfg.norm_eps, dtype=cfg.dtype,
+                param_dtype=jnp.float32, name="index_k_norm")(
+                    _dense(di, ("embed", None), "index_k_proj",
+                           use_bias=False, dtype=cfg.dtype)(x))
+            head_w = indexer._index_head_weights(
+                _dense(ni, ("embed", None), "index_w_proj", use_bias=False,
+                       dtype=cfg.dtype)(x).astype(jnp.float32)
+                * (ni * di) ** -0.5)
+            if rope is not None:
+                qi = apply_rope(qi, rope[2:])
+                ki = _rotated_index_key(ki, rope[2:])
+        return qi, ki, head_w
+
+    def _indexed_dense(self, q, k, v, qi, ki, head_w, attn_mask):
+        """A forward outside the cache under the indexer: every position at
+        once, the dense scores masked by each query's own set."""
+        if attn_mask is not None:
+            raise NotImplementedError(
+                "a key mask under the indexer outside the cache: no test "
+                "covers it")
+        pos = jnp.arange(q.shape[1])
+        allowed = (pos[None, :] <= pos[:, None])[None]
+        with jax.named_scope("dsa_select"):
+            index = indexer.index_scores(qi, head_w, ki)
+            allowed = indexer.select_rows(
+                index, indexer._visible(allowed), self.cfg.index_topk
+            ) & allowed
+        indexer.sow_selection(self, index, allowed)
+        with jax.named_scope("dsa_attn"):
+            return grouped_attention(q, k, v, allowed[:, None])
+
+    def _indexed_attention(self, q, qi, head_w, cache_positions, block_tables,
+                           layer_index):
+        """Attend through the pool as it stands (the layer loop has written
+        the call's rows of all three leaves) under the indexer: a tick (one
+        row a lane) or a chunk of ONE lane."""
+        from fleetx_tpu.ops.pallas.decode_attention import (
+            flash_decode_paged_attention,
+            paged_gather_kv,
+        )
+
+        cfg = self.cfg
+        ps, di = cfg.decode_page_size, cfg.index_head_dim
+        k_pool, v_pool, ki_pool = (self.get_variable("cache", name)
+                                   for name in POOL_LEAVES)
+        b, s = q.shape[:2]
+        wpos = cache_positions.astype(jnp.int32)
+        own = block_tables.astype(jnp.int32)
+        base = jnp.asarray(layer_bases(cfg))[layer_index]
+        tables = own + base
+        n = tables.shape[1]
+        if s == 1:
+            end = paged_write.decode_end(own, wpos, ps)
+            t = n * ps
+            with jax.named_scope("dsa_index"):
+                ki = ki_pool[tables].reshape(b, t, -1)[..., :di]
+                scores = indexer.index_scores(qi, head_w, ki)[:, 0]
+            with jax.named_scope("dsa_select"):
+                seen = jnp.arange(t, dtype=jnp.int32)[None, :] < end[:, None]
+                chosen, count = indexer.top_rows(
+                    scores, indexer._visible(seen), end,
+                    min(cfg.index_topk, t))
+            if self.is_mutable_collection("routing"):
+                indexer.sow_selection(self, scores[:, None],
+                                      indexer.chosen_mask(chosen, t))
+            with jax.named_scope("dsa_attn"):
+                (keys, values), compact = indexer.gather_rows(
+                    (k_pool, v_pool), tables, chosen)
+                if self._flash_decode_ok(None, t, True, tile_len=ps):
+                    return flash_decode_paged_attention(
+                        q, keys, values, tables=compact, end=count)
+                live = (jnp.arange(compact.shape[1] * ps, dtype=jnp.int32)
+                        [None, :] < count[:, None])
+                return grouped_attention(
+                    q, paged_gather_kv(keys, compact),
+                    paged_gather_kv(values, compact), live[:, None, None, :])
+        if b != 1:
+            raise NotImplementedError(
+                "attention under the indexer takes a tick (one row a lane) "
+                f"or a chunk of ONE lane, not {b} lanes x {s} rows")
+        # whole key blocks: the layer's trash page behind the lane's last
+        more = prefill_gqa.padded_rows(n * ps) // ps - n
+        held = jnp.concatenate([tables, jnp.broadcast_to(base, (1, more))], 1)
+        with jax.named_scope("dsa_index"):
+            ki = paged_gather_kv(ki_pool, held)[0][:, :di]
+            scores = indexer._chunk_index_scores(qi[0], head_w[0], ki,
+                                                 wpos[0])
+        with jax.named_scope("dsa_select"):
+            seen = (jnp.arange(ki.shape[0], dtype=jnp.int32)[None, :]
+                    <= wpos[0] + jnp.arange(s, dtype=jnp.int32)[:, None])
+            mask = indexer.select_rows(scores, indexer._visible(seen),
+                                       cfg.index_topk)
+        # (a chunk's scores, [rows, cache rows] float32 a layer, are not sown)
+        indexer.sow_selection(self, None, mask[None])
+        with jax.named_scope("dsa_attn"):
+            keys = paged_gather_kv(k_pool, held)
+            values = paged_gather_kv(v_pool, held)
+            if self._chunk_kernel(b, s):
+                return prefill_gqa.gqa_sparse_prefill(
+                    q[0], keys[0], values[0], mask, wpos[0])[None]
+            return grouped_attention(q, keys, values, mask[None, None])
 
     def _gate(self, out, gate):
         """The heads' output ``[b, s, heads, d]`` under the output gate:

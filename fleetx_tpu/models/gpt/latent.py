@@ -89,6 +89,7 @@ Forward only, as the stack it is a kind of.
 from __future__ import annotations
 
 import math
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -97,6 +98,19 @@ from flax import linen as nn
 
 from fleetx_tpu.models.gpt import paged_write
 from fleetx_tpu.models.gpt.hybrid import layer_bases
+from fleetx_tpu.models.gpt.indexer import (  # noqa: F401 (its names are
+    # read from this module too: the probes' seams, the tests' functions)
+    _INDEX_TYPE,
+    KEY_BLOCK,
+    _index_act,
+    _index_head_weights,
+    _visible,
+    chosen_mask,
+    gather_rows,
+    select_rows,
+    top_rows,
+)
+from fleetx_tpu.models.gpt import indexer
 from fleetx_tpu.models.gpt.mixed_stack import MixedStack
 from fleetx_tpu.models.gpt.model import (
     GPTConfig,
@@ -108,8 +122,8 @@ __all__ = ["KEY_BLOCK", "LatentAttention", "LatentStack", "index_scores",
            "rope_leaf_width", "select_rows", "softmax_scale",
            "yarn_frequencies", "yarn_tables"]
 
-# key rows of one block of a chunk's attention in plain XLA (:func:`_chunk`)
-KEY_BLOCK = 1024
+# (``KEY_BLOCK``, ``indexer.py``'s: key rows of one block of a chunk's
+# attention in plain XLA, :func:`_chunk`, as of its index scores)
 _NEG = -1e30
 
 
@@ -277,23 +291,13 @@ class LatentAttention(nn.Module):
                     index = index_scores(qi, head_w, ki)
                     allowed = select_rows(index, _visible(allowed),
                                           cfg.index_topk) & allowed
-                self._sow_selection(index, allowed)
+                indexer.sow_selection(self, index, allowed)
             scores = jnp.where(allowed[:, None], scores * softmax_scale(cfg),
                                _NEG)
             probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
             out = jnp.einsum("bhst,bthv->bshv", probs, v)
         with jax.named_scope("mla_proj"):
             return jnp.einsum("bshv,hvd->bsd", out, w_o)
-
-    def _sow_selection(self, index, chosen):
-        """For whoever holds the indexer to a reference (the collection
-        ``routing``, where it is mutable): the rows each query attends over
-        ``[b, s, t]`` bool and, from a tick and from a forward outside the
-        cache, the index scores, float32 alike."""
-        if self.is_mutable_collection("routing"):
-            self.sow("routing", "index_sets", chosen)
-            if index is not None:
-                self.sow("routing", "index_scores", index)
 
     def _cached(self, q, w_kvb, cache_positions, block_tables, layer_index):
         """Attend through the pool as it stands (the caller has written the
@@ -323,10 +327,9 @@ class LatentAttention(nn.Module):
                     cfg, q_c, q_r, qi[:, 0], head_w[:, 0],
                     (ckv_pool, kr_pool, ki_pool), tables, end, scale)
                 if self.is_mutable_collection("routing"):
-                    self._sow_selection(index[:, None], jnp.zeros(
-                        (b, index.shape[1] + 1), bool).at[
-                            jnp.arange(b)[:, None], chosen].set(True)[
-                                :, None, :-1])
+                    indexer.sow_selection(
+                        self, index[:, None],
+                        chosen_mask(chosen, index.shape[1]))
             else:
                 out = _decode(cfg, q_c, q_r, ckv_pool, kr_pool, tables, end,
                               scale)
@@ -351,16 +354,16 @@ class LatentAttention(nn.Module):
             mask = select_rows(scores, _visible(seen), cfg.index_topk)
         # (a chunk's scores, [rows, cache rows] float32 a layer, are not sown:
         # 103 MB a layer of the check's program at the served sizes)
-        self._sow_selection(None, mask[None])
+        indexer.sow_selection(self, None, mask[None])
         return _prefill(cfg, q[0], w_kvb, ckv, kr, wpos[0], scale,
                         mask=mask)[None]
 
 
 # the seams ``perfbench/probe_axk1.py`` plants its faults in
 _SCORE_TYPE = jnp.float32     # what a chunk's scores are accumulated in
-
-
-_INDEX_TYPE = jnp.float32     # what an index score's products accumulate in
+# (the indexer's seams are ``indexer.py``'s, looked up HERE by its functions:
+# ``_SEAMS``; ``perfbench/probe_dsv32.py`` sets them on this module)
+_SEAMS = sys.modules[__name__]
 
 
 def _rotated_index_key(ki, rope, rot: int):
@@ -370,76 +373,14 @@ def _rotated_index_key(ki, rope, rot: int):
                            axis=-1)
 
 
-def _index_act(dots):
-    """What a head's product passes before the heads are summed: ReLU."""
-    return jax.nn.relu(dots)
-
-
-def _index_head_weights(w):
-    """The heads' weights ``w_{t,j}`` as the sum takes them."""
-    return w
-
-
-def _visible(seen):
-    """The rows ``[s, t]`` bool a query may SELECT from: those it sees."""
-    return seen
-
-
 def index_scores(qi, w, ki):
-    """``I = sum_j w_j ReLU(qI_j . kI)``, float32: ``qi`` ``[..., s, heads,
-    d]``, ``w`` ``[..., s, heads]`` float32, ``ki`` ``[..., t, d]``; ``[...,
-    s, t]``."""
-    dots = jnp.einsum("...shd,...td->...sht", qi, ki,
-                      preferred_element_type=_INDEX_TYPE).astype(jnp.float32)
-    return (_index_act(dots) * w[..., None]).sum(-2)
-
-
-def select_rows(scores, valid, k: int):
-    """The ``k`` highest of ``scores`` ``[..., t]`` float32 among the rows
-    ``valid`` (all of them where there are no more than ``k``), a tie going
-    to the lower position: ``[..., t]`` bool. EXACT, and no sort: the
-    scores' bits, made to order as unsigned integers, are searched for the
-    ``k``-th largest a bit at a time (32 counts over the scores), and the
-    rows that tie with it are taken in order of position."""
-    bits = jax.lax.bitcast_convert_type(
-        jnp.where(scores == 0, 0.0, scores), jnp.int32)   # (-0.0 is 0.0)
-    key = jax.lax.bitcast_convert_type(
-        jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits), jnp.uint32
-    ) ^ jnp.uint32(0x80000000)
-    key = jnp.where(valid, key, 0)          # (a finite score's key is > 0)
-    want = jnp.minimum(valid.sum(-1, keepdims=True), k)
-
-    def bit(i, kth):
-        higher = kth | (jnp.uint32(0x80000000) >> i.astype(jnp.uint32))
-        enough = (key >= higher).sum(-1, keepdims=True) >= want
-        return jnp.where(enough, higher, kth)
-
-    kth = jax.lax.fori_loop(0, 32, bit,
-                            jnp.zeros(key.shape[:-1] + (1,), jnp.uint32))
-    above, ties = key > kth, (key == kth) & valid
-    room = want - above.sum(-1, keepdims=True)
-    return above | (ties & (jnp.cumsum(ties, axis=-1) <= room))
+    """``indexer.index_scores`` under this module's seams."""
+    return indexer.index_scores(qi, w, ki, _SEAMS)
 
 
 def _chunk_index_scores(qi, w, ki, start):
-    """One lane's chunk ``qi`` ``[s, heads, d]`` at positions ``start + [0,
-    s)`` against the lane's index keys ``ki`` ``[t, d]``, in blocks of
-    ``KEY_BLOCK`` keys up to the chunk's last row (the rest stay 0: no
-    query sees them): ``[s, t]`` float32."""
-    s, t = qi.shape[0], ki.shape[0]
-    block = min(KEY_BLOCK, t)
-    if t % block:
-        raise ValueError(f"a lane's {t} rows are no whole number of "
-                         f"{block}-row key blocks")
-
-    def one(i, out):
-        part = index_scores(
-            qi, w, jax.lax.dynamic_slice_in_dim(ki, i * block, block))
-        return jax.lax.dynamic_update_slice(out, part, (0, i * block))
-
-    return jax.lax.fori_loop(
-        0, jnp.minimum((start + s + block - 1) // block, t // block), one,
-        jnp.zeros((s, t), jnp.float32))
+    """``indexer._chunk_index_scores`` under this module's seams."""
+    return indexer._chunk_index_scores(qi, w, ki, start, _SEAMS)
 
 
 def _sparse_decode(cfg: GPTConfig, q_c, q_r, qi, w, pools, tables, end,
@@ -460,21 +401,10 @@ def _sparse_decode(cfg: GPTConfig, q_c, q_r, qi, w, pools, tables, end,
         scores = index_scores(qi[:, None], w[:, None], ki)[:, 0]
     with jax.named_scope("dsa_select"):
         seen = jnp.arange(t, dtype=jnp.int32)[None, :] < end[:, None]
-        chosen = jax.lax.top_k(
-            jnp.where(_visible(seen), scores, -jnp.inf), k)[1]
-        count = jnp.minimum(end, k)
         # in position order; the places past ``count`` name no row
-        chosen = jnp.sort(jnp.where(
-            jnp.arange(k, dtype=jnp.int32)[None, :] < count[:, None],
-            chosen.astype(jnp.int32), t), axis=-1)
+        chosen, count = top_rows(scores, _visible(seen), end, k)
     with jax.named_scope("dsa_attn"):
-        kp = -(-k // ps) * ps           # whole pages of the compact pool
-        at = jnp.minimum(jnp.pad(chosen, ((0, 0), (0, kp - k)),
-                                 constant_values=t), t - 1)
-        row = jnp.take_along_axis(tables, at // ps, axis=1) * ps + at % ps
-        ckv, kr = (pool.reshape(-1, pool.shape[-1])[row].reshape(
-            b * kp // ps, ps, pool.shape[-1]) for pool in (ckv_pool, kr_pool))
-        compact = jnp.arange(b * kp // ps, dtype=jnp.int32).reshape(b, -1)
+        (ckv, kr), compact = gather_rows((ckv_pool, kr_pool), tables, chosen)
     return (_decode(cfg, q_c, q_r, ckv, kr, compact, count, scale), scores,
             chosen)
 

@@ -117,10 +117,12 @@ from flax import linen as nn
 from fleetx_tpu.models.gpt.block_fields import (
     LANE_STATE_LEAVES,
     RECURRENT_TYPES,
+    fold_mrope,
 )
 from fleetx_tpu.models.gpt.hybrid import (
     POOL_LEAVES,
     HybridSelfAttention,
+    index_leaf_width,
     layer_bases,
     write_rows,
 )
@@ -513,6 +515,9 @@ class MixedStack(nn.Module):
             from fleetx_tpu.parallel.moe import DroplessMoEMLP as Experts
 
         rope = (jnp.ones((1, 1, cfg.head_dim // 2), jnp.float32),) * 2
+        if cfg.indexed and not cfg.latent:  # (and the indexer's two tables)
+            rope += (jnp.ones((1, 1, cfg.index_head_dim // 2),
+                              jnp.float32),) * 2
         return {
             "conv": (ShortConv(cfg, parent=None),
                      (x, jnp.zeros((1, state_rows(cfg), cfg.hidden_size),
@@ -551,6 +556,7 @@ class MixedStack(nn.Module):
                 # (in float32: bfloat16 would round the multiplier itself)
                 x = (x.astype(jnp.float32)
                      * cfg.embedding_multiplier).astype(x.dtype)
+        rope = self._rope(rope)
         cache = self._cache(decode, plan, lanes=x.shape[0])
         if decode and cache is not None and (cache_positions is None
                                              or block_tables is None):
@@ -561,6 +567,28 @@ class MixedStack(nn.Module):
             key_mask=None if decode else attn_mask,
             deterministic=deterministic, cache_positions=cache_positions,
             block_tables=block_tables, rope=rope)
+
+    def _rope(self, rope):
+        """The angles the attention layers take, from the model's tables
+        ``(cos, sin)`` at the call's positions: as they are for ONE axis;
+        under ``mrope_section`` (positions ``[3, b, s]``: tables ``[3, b, s,
+        pairs]``) each pair from its axis (``block_fields.fold_mrope``);
+        under a grouped indexer the indexer's two tables behind the heads'
+        two: pair ``j`` of its ``index_head_dim / 2`` turns at
+        ``theta^(-2j / index_head_dim)``, which is the heads' pair ``j *
+        head_dim / index_head_dim``, in the axes ``index_rope_section``
+        gives."""
+        cfg = self.cfg
+        if rope is None or cfg.latent or not (cfg.mrope_section
+                                              or cfg.indexed):
+            return rope
+        with jax.named_scope("embed"):
+            heads = fold_mrope(rope, cfg.mrope_section)
+            if not cfg.indexed:
+                return heads
+            stride = cfg.head_dim // cfg.index_head_dim
+            return heads + fold_mrope(
+                tuple(t[..., ::stride] for t in rope), cfg.index_rope_section)
 
     def _cache(self, decode: bool, plan: dict, lanes: int):
         """The cache collection's variables (None outside a cached forward
@@ -591,6 +619,10 @@ class MixedStack(nn.Module):
             "cached_value": self.variable(
                 "cache", "cached_value", jnp.zeros, (1, ps, width), cfg.dtype),
         }
+        if cfg.indexed:  # the indexer's keys: the pool's third leaf
+            held["cached_index"] = self.variable(
+                "cache", "cached_index", jnp.zeros,
+                (1, ps, index_leaf_width(cfg)), cfg.dtype)
         if counts["mamba"]:
             # [d_state, inner] and [lanes, rows x inner]: no axis of 16 or of
             # 3 in the last two places, which the device's tiles would pad;

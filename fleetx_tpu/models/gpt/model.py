@@ -905,7 +905,7 @@ class GPTModel(nn.Module):
     @nn.compact
     def __call__(self, input_ids, position_ids=None, attn_mask=None, *,
                  deterministic=True, decode=False, cache_positions=None,
-                 block_tables=None):
+                 block_tables=None, input_rows=None):
         cfg = self.cfg
         word_emb = self.param(
             "word_embeddings",
@@ -935,7 +935,7 @@ class GPTModel(nn.Module):
                 # the angles once, for every layer (they ride the layer
                 # loop as a broadcast input)
                 rope = rope_tables(position_ids, cfg.head_dim, cfg.rope_theta)
-                x = word_emb[input_ids]
+                x = block_fields.rows_in(word_emb, input_ids, input_rows)
             else:
                 x = word_emb[input_ids] + pos_emb[position_ids]
             x = x.astype(cfg.dtype)
@@ -1050,7 +1050,7 @@ class GPTForPretraining(nn.Module):
     @nn.compact
     def __call__(self, input_ids, position_ids=None, attn_mask=None, *,
                  deterministic=True, decode=False, cache_positions=None,
-                 block_tables=None, labels=None):
+                 block_tables=None, labels=None, input_rows=None):
         backbone = GPTModel(self.cfg, name="gpt")
         x = backbone(
             input_ids,
@@ -1059,7 +1059,7 @@ class GPTForPretraining(nn.Module):
             deterministic=deterministic,
             decode=decode,
             cache_positions=cache_positions,
-            block_tables=block_tables,
+            block_tables=block_tables, input_rows=input_rows,
         )
         if self.cfg.tie_word_embeddings:
             word_emb = backbone.variables["params"]["word_embeddings"]

@@ -9,6 +9,17 @@ TPU-first: patch embedding is a Conv (maps to MXU), attention reuses the
 shared fused path (ops/attention.py), TP sharding is the same logical-axis
 annotation scheme as GPT/ERNIE so ViT-G/6B presets shard over mp/fsdp
 without model changes.
+
+**A tower served** (:class:`VisionTower`; ``serving/engine.py`` runs it a
+bucket of patches a program, docs/SERVING.md "Rows from a tower"): the same
+``ViTBlock``s over ONE image's patches with no class token, a learned
+position table of ``grid x grid`` entries interpolated bilinearly to the
+image's own grid, every patch seeing every patch of ITS image (the bucket's
+padding masked: ``kv_lens``), then a 2 x 2 merge and an MLP projector to the
+language model's width: ``h x w`` rows of an image of ``2h x 2w`` patches.
+Device scopes ``vit_attn``, ``vit_mlp`` (a block's two halves) and
+``vit_project`` (the merge and projector). Plain attention (head size 72 is
+no kernel's), a block of ``attn_q_block`` queries at a time.
 """
 
 from __future__ import annotations
@@ -32,7 +43,8 @@ from fleetx_tpu.ops.dropout import dropout_layer
 
 Dtype = Any
 
-__all__ = ["ViTConfig", "ViT", "VIT_PRESETS", "build_vision_model"]
+__all__ = ["ViTConfig", "ViT", "VIT_PRESETS", "VisionTower",
+           "build_vision_model", "image_patches", "tower_of"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,10 +72,20 @@ class ViTConfig:
     use_flash_attention: bool = True
     use_recompute: bool = False
     dtype: Dtype = jnp.bfloat16
+    # a served tower's (VisionTower): the MLP's width where it is no ratio
+    # of the hidden size, the norms' epsilon, and the queries a step of its
+    # plain attention takes (0: all at once)
+    mlp_hidden_size: Optional[int] = None
+    norm_eps: float = 1e-5
+    attn_q_block: int = 0
 
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_attention_heads
+
+    @property
+    def mlp_size(self) -> int:
+        return self.mlp_hidden_size or int(self.hidden_size * self.mlp_ratio)
 
     @property
     def num_patches(self) -> int:
@@ -122,37 +144,51 @@ class ViTBlock(nn.Module):
     drop_path: float = 0.0
 
     @nn.compact
-    def __call__(self, x, deterministic=True):
+    def __call__(self, x, deterministic=True, kv_lens=None):
+        """``kv_lens`` ``[b]``: the patches of each sequence that are real
+        (a served tower's padded bucket); None: all."""
         cfg = self.cfg
         nh, hd = cfg.num_attention_heads, cfg.head_dim
-        y = _layer_norm(cfg, "norm1")(x)
-        qkv = _dense((nh, 3 * hd), ("embed", "heads", "kv"), "qkv_proj", dtype=cfg.dtype,
-                     use_bias=cfg.qkv_bias)(y)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        dropout_rng = None
-        if cfg.attn_drop_rate > 0.0 and not deterministic:
-            dropout_rng = self.make_rng("dropout")
-        y = causal_attention(
-            q, k, v,
-            causal=False,
-            dropout_rate=cfg.attn_drop_rate,
-            dropout_rng=dropout_rng,
-            deterministic=deterministic,
-            # seq 197 (196 patches + cls) pads to 200 inside the dispatch
-            # (one kernel tile); use_flash_attention: False restores XLA
-            use_flash=cfg.use_flash_attention,
-        )
-        y = attn_out_dense(cfg.hidden_size, cfg.dtype)(y)
-        y = dropout_layer(cfg.drop_rate, "proj_drop", cfg.fast_dropout)(y, deterministic=deterministic)
-        x = x + DropPath(self.drop_path, name="drop_path1")(y, deterministic)
+        with jax.named_scope("vit_attn"):
+            y = _layer_norm(cfg, "norm1")(x)
+            qkv = _dense((nh, 3 * hd), ("embed", "heads", "kv"), "qkv_proj", dtype=cfg.dtype,
+                         use_bias=cfg.qkv_bias)(y)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            dropout_rng = None
+            if cfg.attn_drop_rate > 0.0 and not deterministic:
+                dropout_rng = self.make_rng("dropout")
 
-        y = _layer_norm(cfg, "norm2")(x)
-        y = _dense(int(cfg.hidden_size * cfg.mlp_ratio), ("embed", "mlp"), "fc1",
-                   dtype=cfg.dtype)(y)
-        y = nn.gelu(y, approximate=cfg.hidden_act != "gelu")
-        y = _dense(cfg.hidden_size, ("mlp", "embed"), "fc2", dtype=cfg.dtype)(y)
-        y = dropout_layer(cfg.drop_rate, "mlp_drop", cfg.fast_dropout)(y, deterministic=deterministic)
-        x = x + DropPath(self.drop_path, name="drop_path2")(y, deterministic)
+            def attend(q):
+                return causal_attention(
+                    q, k, v,
+                    causal=False, kv_lens=kv_lens,
+                    dropout_rate=cfg.attn_drop_rate,
+                    dropout_rng=dropout_rng,
+                    deterministic=deterministic,
+                    # seq 197 (196 patches + cls) pads to 200 inside the dispatch
+                    # (one kernel tile); use_flash_attention: False restores XLA
+                    use_flash=cfg.use_flash_attention,
+                )
+
+            block = cfg.attn_q_block
+            if block and q.shape[1] > block and q.shape[1] % block == 0:
+                # a block of queries at a time: [heads, block, patches] scores
+                y = jax.lax.map(attend, jnp.moveaxis(q.reshape(
+                    q.shape[0], -1, block, nh, hd), 1, 0))
+                y = jnp.moveaxis(y, 0, 1).reshape(q.shape)
+            else:
+                y = attend(q)
+            y = attn_out_dense(cfg.hidden_size, cfg.dtype)(y)
+            y = dropout_layer(cfg.drop_rate, "proj_drop", cfg.fast_dropout)(y, deterministic=deterministic)
+            x = x + DropPath(self.drop_path, name="drop_path1")(y, deterministic)
+
+        with jax.named_scope("vit_mlp"):
+            y = _layer_norm(cfg, "norm2")(x)
+            y = _dense(cfg.mlp_size, ("embed", "mlp"), "fc1", dtype=cfg.dtype)(y)
+            y = nn.gelu(y, approximate=cfg.hidden_act != "gelu")
+            y = _dense(cfg.hidden_size, ("mlp", "embed"), "fc2", dtype=cfg.dtype)(y)
+            y = dropout_layer(cfg.drop_rate, "mlp_drop", cfg.fast_dropout)(y, deterministic=deterministic)
+            x = x + DropPath(self.drop_path, name="drop_path2")(y, deterministic)
         return _constrain_act(x, cfg)
 
 
@@ -221,6 +257,102 @@ class ViT(nn.Module):
         logits = _dense(cfg.num_classes, ("embed", None), "head",
                         dtype=jnp.float32)(x.astype(jnp.float32))
         return logits
+
+
+class VisionTower(nn.Module):
+    """A vision tower served (module docstring). ``patches`` ``[P, patch x
+    patch x channels]`` of ONE image, in MERGE ORDER (:func:`image_patches`:
+    the four patches of a row side by side), the first ``grid[0] x
+    grid[1]`` of them real; ``grid`` int32 ``[2]``: the image's rows and
+    columns of PATCHES (traced: one program a bucket ``P``). Returns ``[P /
+    merge^2, out_size]``, the first ``grid[0] x grid[1] / merge^2`` of them
+    the image's rows in raster order."""
+
+    cfg: ViTConfig
+    out_size: int
+    merge: int = 2
+
+    @nn.compact
+    def __call__(self, patches, grid):
+        cfg, m = self.cfg, self.merge
+        side = cfg.image_size // cfg.patch_size
+        rows, cols = grid[0], grid[1]
+        with jax.named_scope("vit_embed"):
+            z = _dense(cfg.hidden_size, (None, "embed"), "patch_embed",
+                       dtype=cfg.dtype)(patches.astype(cfg.dtype))
+            table = self.param(
+                "pos_embed", nn.with_logical_partitioning(
+                    nn.initializers.normal(0.02), (None, None, "embed")),
+                (side, side, cfg.hidden_size), jnp.float32)
+            # patch i of the merge order: its block, then its place in it
+            i = jnp.arange(z.shape[0], dtype=jnp.int32)
+            block, k = i // (m * m), i % (m * m)
+            per_row = jnp.maximum(cols // m, 1)
+            r = (block // per_row) * m + k // m
+            c = (block % per_row) * m + k % m
+            z = z + interpolated(table, r, c, rows, cols).astype(cfg.dtype)
+        lens = (rows * cols)[None]
+        for n in range(cfg.num_layers):
+            z = ViTBlock(cfg, name=f"block_{n}")(z[None], kv_lens=lens)[0]
+        with jax.named_scope("vit_project"):
+            z = _layer_norm(cfg, "post_norm")(z)
+            z = _layer_norm(cfg, "merge_norm")(z)
+            z = z.reshape(z.shape[0] // (m * m), m * m * cfg.hidden_size)
+            z = _dense(z.shape[-1], ("embed", "mlp"), "project_in",
+                       dtype=cfg.dtype)(z)
+            return _dense(self.out_size, ("mlp", "embed"), "project_out",
+                          dtype=cfg.dtype)(nn.gelu(z, approximate=False))
+
+
+def interpolated(table, r, c, rows, cols):
+    """The position table ``[side, side, h]`` read bilinearly at the centres
+    of patches ``(r, c)`` ``[P]`` of a ``rows x cols`` grid (half-pixel
+    centres, edges clamped: ``align_corners=False``), float32 ``[P, h]``."""
+    side = table.shape[0]
+
+    def along(at, n):
+        y = jnp.clip((at.astype(jnp.float32) + 0.5) * side
+                     / n.astype(jnp.float32) - 0.5, 0.0, side - 1.0)
+        lo = jnp.floor(y).astype(jnp.int32)
+        return lo, jnp.minimum(lo + 1, side - 1), (y - lo)[:, None]
+
+    y0, y1, wy = along(r, rows)
+    x0, x1, wx = along(c, cols)
+    top = table[y0, x0] * (1.0 - wx) + table[y0, x1] * wx
+    low = table[y1, x0] * (1.0 - wx) + table[y1, x1] * wx
+    return top * (1.0 - wy) + low * wy
+
+
+def image_patches(image, patch: int, merge: int = 2):
+    """``image`` ``[H, W, C]`` (numpy; ``H`` and ``W`` whole ``patch x
+    merge``s) as its patches in MERGE ORDER ``[patches, patch x patch x
+    C]``: the ``merge x merge`` patches of output row ``(r, c)`` follow one
+    another, rows in raster order; a patch is its pixels in ``(y, x,
+    channel)`` order."""
+    height, width, channels = image.shape
+    h, w = height // (patch * merge), width // (patch * merge)
+    if (h * patch * merge, w * patch * merge) != (height, width) or not h * w:
+        raise ValueError(f"an image of {height} x {width} pixels is no whole "
+                         f"number of {patch * merge}-pixel rows and columns")
+    tiles = image.reshape(h, merge, patch, w, merge, patch, channels)
+    return tiles.transpose(0, 3, 1, 4, 2, 5, 6).reshape(
+        h * w * merge * merge, patch * patch * channels)
+
+
+def tower_of(cfg) -> Optional[VisionTower]:
+    """The tower a ``GPTConfig``'s ``vision`` group describes (None without
+    one), its rows as wide as the language model."""
+    group = cfg.vision_fields
+    if not group:
+        return None
+    return VisionTower(ViTConfig(
+        image_size=group["grid"] * group["patch_size"],
+        patch_size=group["patch_size"], hidden_size=group["hidden_size"],
+        num_layers=group["num_layers"],
+        num_attention_heads=group["num_heads"],
+        mlp_hidden_size=group["intermediate_size"], norm_eps=1e-6,
+        attn_q_block=1024, use_flash_attention=False, num_classes=0,
+        dtype=cfg.dtype), out_size=cfg.hidden_size, merge=group["merge"])
 
 
 def build_vision_model(name: str, **overrides) -> ViT:

@@ -45,6 +45,17 @@ five times the compile time, so a head is a grid step.
 Named ``fleetx_prefill_gqa`` in compiled HLO and in device traces
 (docs/OBSERVABILITY.md): 1 call a layer and chunk, under the layer's
 ``attn_full`` / ``attn_window`` scope. No gradient.
+
+**Under a learned indexer** (``models/gpt/indexer.py``: every query of the
+chunk attends over ITS OWN set of rows) the position test gives way to a
+mask: :func:`gqa_sparse_prefill`, the same grid and step over a full
+layer's rows with an int8 block ``[s, BLOCK_ROWS]`` of the mask ``[s,
+rows]`` copied beside the keys and values (as ``fleetx_dsa_prefill`` takes
+its own, ``ops/pallas/mla_prefill.py``), named ``fleetx_gqa_sparse_prefill``,
+under the scope ``dsa_attn``. It visits EVERY key block up to the chunk's
+last row, chosen from or not: a kernel that skips the blocks no row of the
+chunk chose is a later issue's. Its plain twin is ``hybrid.
+grouped_attention`` handed the mask.
 """
 
 from __future__ import annotations
@@ -57,10 +68,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from fleetx_tpu.ops.pallas.flash_attention import _interpret
 
-__all__ = ["KERNEL_NAME", "BLOCK_ROWS", "key_rows", "padded_rows",
-           "prefill_gqa", "takes"]
+__all__ = ["KERNEL_NAME", "SPARSE_KERNEL_NAME", "BLOCK_ROWS",
+           "gqa_sparse_prefill", "key_rows", "padded_rows", "prefill_gqa",
+           "takes"]
 
 KERNEL_NAME = "fleetx_prefill_gqa"
+SPARSE_KERNEL_NAME = "fleetx_gqa_sparse_prefill"
 # cached rows of one grid step
 BLOCK_ROWS = 1024
 _NO_WINDOW = 1 << 30
@@ -209,4 +222,91 @@ def prefill_gqa(q, k, v, start, base, window=None):
         interpret=_interpret(),
         name=KERNEL_NAME,
     )(scalars, q.reshape(s, heads * d), k, v)
+    return out.reshape(s, heads, d)
+
+
+def gqa_sparse_prefill(q, k, v, mask, start):
+    """Attention of ONE lane's chunk under a selection. ``q`` ``[s, heads,
+    d]`` at positions ``start + [0, s)``; ``k`` and ``v`` ``[t, kv_heads *
+    d]`` the lane's rows in order from position 0 (whole key blocks:
+    ``padded_rows``), the chunk's own among them; ``mask`` ``[s, t]`` bool,
+    the rows each query attends over (all of them rows it sees); ``start``
+    an int32 scalar. Scores in float32. ``[s, heads, d]``."""
+    s, heads, d = q.shape
+    t, width = k.shape
+    group = heads // (width // d)
+    rows = min(BLOCK_ROWS, t)
+    if t % rows:
+        raise ValueError(f"a lane's {t} gathered rows are no whole number "
+                         f"of {rows}-row key blocks (padded_rows)")
+    scale = 1.0 / (d ** 0.5)
+    start = jnp.asarray(start, jnp.int32).reshape((1,))
+    # the blocks up to the chunk's last row: the grid's own (dynamic) bound
+    live = jnp.minimum((start[0] + s - 1) // rows, t // rows - 1) + 1
+
+    def kernel(start_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, m_scr, l_scr,
+               acc_scr):
+        j = pl.program_id(1)
+
+        @pl.when(j == 0)
+        def _init():
+            m_scr[...] = jnp.full(m_scr.shape, _NEG, jnp.float32)
+            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+            acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+        # rows past the chunk's last (a trash page's, whatever they hold):
+        # out of both products
+        row = j * rows + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        held = row < start_ref[0] + s
+        keys = jnp.where(held, k_ref[...], jnp.zeros_like(k_ref[...]))
+        values = jnp.where(held, v_ref[...], jnp.zeros_like(v_ref[...]))
+        seen = mask_ref[...] != 0
+        sc = jax.lax.dot_general(
+            q_ref[...], keys, _NT, preferred_element_type=jnp.float32
+        ) * scale                                                 # [s, rows]
+        sc = jnp.where(seen, sc, _NEG)
+        m = m_scr[...]
+        m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+        p = jnp.where(seen, jnp.exp(sc - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = alpha * acc_scr[...] + jnp.dot(
+            p.astype(values.dtype), values,
+            preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
+
+        @pl.when(j == pl.num_programs(1) - 1)
+        def _finalize():
+            total = l_scr[...]
+            o_ref[...] = (acc_scr[...] / jnp.where(total > 0.0, total, 1.0)
+                          ).astype(o_ref.dtype)
+
+    def head_map(h, j, start_ref):
+        return 0, h
+
+    def row_map(h, j, start_ref):
+        return j, h // group
+
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(heads, live),
+            in_specs=[pl.BlockSpec((s, d), head_map),
+                      pl.BlockSpec((rows, d), row_map),
+                      pl.BlockSpec((rows, d), row_map),
+                      pl.BlockSpec((s, rows), lambda h, j, start_ref: (0, j))],
+            out_specs=pl.BlockSpec((s, d), head_map),
+            scratch_shapes=[
+                pltpu.VMEM((s, 1), jnp.float32),     # running max
+                pltpu.VMEM((s, 1), jnp.float32),     # normaliser
+                pltpu.VMEM((s, d), jnp.float32),     # accumulator
+            ]),
+        out_shape=jax.ShapeDtypeStruct((s, heads * d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret(),
+        name=SPARSE_KERNEL_NAME,
+    )(start, q.reshape(s, heads * d), k, v, mask.astype(jnp.int8))
     return out.reshape(s, heads, d)

@@ -822,11 +822,21 @@ class ServingEngine:
         self._probed_at = self._now()  # the last admission read at once
         # programs dispatched so far (_next_program)
         self._programs = 0
+        # positions apart from rows, rows from a tower (serving/rows_in.py):
+        # a model without the flags sees neither operand, and its programs
+        # are the ones they were
+        self._mrope = self.capabilities.mrope
         self._state = self._replicate(self._init_state())
         self._kernel_steps = self._decode_kernel_steps()
         # buffer donation halves cache HBM residency on TPU; skipped on
         # CPU/interpret runs where XLA would only warn about it
         donate = jax.default_backend() == "tpu"
+        self._donate_cache = donate
+        self._tower = None
+        if self.capabilities.takes_rows:
+            from fleetx_tpu.serving.rows_in import Tower
+
+            self._tower = Tower(self)
         # all_greedy is static: an all-greedy tick (the common serving mix
         # for deterministic decode) skips the sampler entirely — at most
         # two cached compilations
@@ -844,7 +854,6 @@ class ServingEngine:
         self._inert_floats = jax.device_put(np.ones(2, np.float32))
         self._deactivate_jit = jax.jit(_deactivate)
         self._prefill_jits = {}  # bucket_len -> jitted prefill
-        self._donate_cache = donate
         # speculative decoding (module docstring): default OFF — a spec-
         # disabled engine never touches the proposer/verify machinery and
         # stays byte-identical to the pre-spec engine. An explicit
@@ -910,7 +919,7 @@ class ServingEngine:
                seed: Optional[int] = None, rng_key: Optional[jax.Array] = None,
                on_token=None, queue_ttl_s: Optional[float] = None,
                deadline_s: Optional[float] = None,
-               history=None, kv_payloads=None) -> int:
+               history=None, kv_payloads=None, images=None) -> int:
         """Queue one request; returns its id. Kwargs override the engine's
         ``gen_cfg`` defaults per request; ``seed`` (or a raw ``rng_key``)
         pins this request's private sampling stream, ``on_token`` streams
@@ -945,7 +954,16 @@ class ServingEngine:
         the router can fall back to the replay path — and admission
         writes them straight into freshly allocated pages through the
         revive scatter: no prefill forward at all, byte-identical
-        decoding to the colocated engine."""
+        decoding to the colocated engine.
+
+        ``images`` (a ``takes_rows`` family: docs/SERVING.md "Rows from a
+        tower"): uint8 ``[height, width, 3]`` arrays, one for every image
+        whose rows the prompt marks with runs of ``image_token_id`` (``h x
+        w`` ids for an image of ``28h x 28w`` pixels at patch 14; a mismatch
+        raises here). A request with images cannot be shipped
+        (``kv_payloads``, a prefill or decode role: the handoff ships ids)
+        nor carry ``history`` (a replay across replicas knows ids alone):
+        each raises by name."""
         if self._shutting_down:
             self.metrics.record_drain_reject()
             obs_emit("drain_reject", engine=self.metrics.engine_label)
@@ -970,6 +988,26 @@ class ServingEngine:
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size == 0:
             raise ValueError("empty prompt")
+        laid = None
+        if images is not None:
+            if self._tower is None:
+                raise ValueError(
+                    f"model family {self.model_family!r} takes no images "
+                    "(capabilities.takes_rows=False)")
+            for name, given in (("kv_payloads", kv_payloads is not None),
+                                ("history", history is not None),
+                                (f"role={self.role!r}", self.role != "both")):
+                if given:
+                    raise ValueError(
+                        f"a request with images cannot take {name}: the "
+                        "handoff between replicas ships token ids, and an "
+                        "image's rows have none (docs/SERVING.md \"Rows "
+                        "from a tower\")")
+        if self._tower is not None:
+            from fleetx_tpu.serving.rows_in import layout
+
+            # (a prompt that marks image rows and brings no image raises)
+            laid = layout(prompt, images, self.model.cfg.vision_fields)
         g = self.gen_cfg
         strategy = decode_strategy or g.decode_strategy
         if strategy not in ("greedy", "sampling"):
@@ -1067,6 +1105,8 @@ class ServingEngine:
         # _admit routes a non-empty list through the replay prefill seam
         req.tokens.extend(hist)
         req.kv_payloads = decoded_pages
+        if laid is not None and laid[3]:
+            req.keys, req.positions, req.rope_delta, req.images = laid
         self.scheduler.submit(req)
         self.metrics.record_submit()
         return rid
@@ -1490,6 +1530,8 @@ class ServingEngine:
             self._tables_dev = None
             self._tables_version = -1
             self._state = self._replicate(self._init_state())
+            if self._tower is not None:
+                self._tower.reset()
             # the HOST spill tier survives the rebuild: its entries are
             # keyed by token-chunk path, not trie-node identity, so
             # replayed/requeued prompts revive them from the new pool
@@ -1568,7 +1610,9 @@ class ServingEngine:
         n = len(req.tokens)
         history = np.concatenate(
             [req.prompt, np.asarray(req.tokens[:-1], np.int32)])
-        alloc = self.cache_manager.alloc(req.id, history)
+        req.staged = set()
+        alloc = self.cache_manager.alloc(req.id,
+                                         self._trie_keys(req, history))
         if alloc is None:
             raise RuntimeError(
                 f"replay alloc failed for request {req.id} "
@@ -2036,6 +2080,9 @@ class ServingEngine:
             "top_k": jnp.zeros((s,), jnp.int32),
             "top_p": jnp.ones((s,), jnp.float32),
             "rng": jnp.zeros((s, 2), jnp.uint32),
+            # (what a lane's rotary position stands past its cache row)
+            **({"rope_delta": jnp.zeros((s,), jnp.int32)}
+               if self._mrope else {}),
         }
 
     def _dequant_params(self, params):
@@ -2176,7 +2223,7 @@ class ServingEngine:
         EOS nor uses up its budget: what the host would decide, had it
         read the token first."""
         (slot, packed, length, decoded, wanted, eos, max_new, min_new, greedy,
-         top_k) = ints
+         top_k) = ints[:10]
         tok = jnp.where(packed < 0, tok, packed)
         active = ((wanted != 0) & ~((eos >= 0) & (tok == eos))
                   & (decoded < max_new))
@@ -2187,6 +2234,8 @@ class ServingEngine:
             "temperature": floats[0], "top_k": top_k, "top_p": floats[1],
             "rng": key,
         }
+        if self._mrope:  # an eleventh int
+            lane["rope_delta"] = ints[10]
         return {name: st[name].at[slot].set(value)
                 for name, value in lane.items()}
 
@@ -2200,6 +2249,17 @@ class ServingEngine:
                 [req.prompt, np.asarray(req.tokens[:-1], np.int32)])
         return req.prompt
 
+    @staticmethod
+    def _trie_keys(req: Request, tokens):
+        """What the prefix trie (and the tiers behind it) takes for
+        ``tokens`` of ``req``: the ids, or for a prompt with images its
+        rows' keys (``rows_in.trie_keys``)."""
+        if req.keys is None:
+            return tokens
+        from fleetx_tpu.serving.rows_in import trie_keys
+
+        return trie_keys(req, tokens)
+
     def _can_admit(self, req: Request) -> bool:
         """FIFO-head admission judgment: a free decode lane and enough
         free pages for the head's prompt plus any migrated history
@@ -2210,7 +2270,8 @@ class ServingEngine:
         # a dry run of the prefix match, once a tick while the head of the
         # queue waits: host work between two admissions
         with span("serving.can_admit", request=req.id):
-            return self.cache_manager.can_admit(self._admission_tokens(req))
+            return self.cache_manager.can_admit(
+                self._trie_keys(req, self._admission_tokens(req)))
 
     def _device_tables(self):
         """Device copy of the block tables, re-uploaded only when the
@@ -2278,13 +2339,14 @@ class ServingEngine:
         # state [1 + pages] (the lane before its pages)
         table_shape = self.cache_manager.lane_tables(0).shape
         n_table = int(np.prod(table_shape))
+        tower = self._tower
 
-        def prefill(params, cache, ints, floats, key):
+        def prefill(params, cache, ints, floats, key, *stage):
             # as _prefill_ints packed them
             true_len, wpos, eos, min_new, greedy, top_k, wants = ints[:7]
             wants = wants != 0
             table = ints[7:7 + n_table].reshape(table_shape)
-            suffix = ints[7 + n_table:]
+            suffix = ints[7 + n_table:][:bucket_len]
             # the admission's one split of the request's stream: the
             # sampler's key, and the carry the lane install takes (the
             # bits of the eager split; a replay call drops both)
@@ -2296,6 +2358,16 @@ class ServingEngine:
             # the live window (or on the trash page) — cache_manager.py
             pos = jnp.minimum(wpos + jnp.arange(bucket_len, dtype=jnp.int32),
                               max_pos - 1)[None, :]
+            rows_in = {}
+            if self._mrope:  # the rows' own positions, three axes each
+                pos = jnp.minimum(ints[-3 * bucket_len:].reshape(
+                    3, 1, bucket_len), max_pos - 1)
+            if tower is not None:  # the tower's rows, where the ids mark them
+                rows_in["input_rows"] = (
+                    jax.lax.dynamic_slice_in_dim(
+                        stage[0], wpos, bucket_len)[None],
+                    ((suffix == tower.image_token_id)
+                     & (jnp.arange(bucket_len) < true_len))[None])
             logits, cache = self.executor.forward(
                 params, cache, ids, pos,
                 self._row_mask((jnp.arange(bucket_len) < true_len)[None]),
@@ -2304,7 +2376,8 @@ class ServingEngine:
                 block_tables=jnp.expand_dims(table, -2),
                 # the head runs on the one row the sampler reads, and on
                 # none in a call that wants no token
-                logit_rows=jnp.where(wants, true_len - 1, -1)[None])
+                logit_rows=jnp.where(wants, true_len - 1, -1)[None],
+                **rows_in)
             cache = self._pin_cache(cache)
             return cache, self._first_token(
                 logits, wants, eos, min_new, greedy != 0, floats[0],
@@ -2316,20 +2389,26 @@ class ServingEngine:
     @staticmethod
     def _prefill_ints(tokens, bucket: int, wpos: int, table, *,
                       eos: int = -1, min_new: int = 0, greedy: bool = True,
-                      top_k: int = 0, wants_token: bool = False) -> np.ndarray:
+                      top_k: int = 0, wants_token: bool = False,
+                      positions=None) -> np.ndarray:
         """The int32 operand of a prefill call, built on the host: seven
         scalars, the lane's table row (of every class), then ``tokens``
         right-padded to ``bucket``. ``wants_token`` says whether the
         caller reads the call's token: the program runs head and sampler
         only then. The defaults are a replay's: no token wanted, and the
         inert sampler (greedy argmax, no filter, nothing suppressed)
-        beside it."""
+        beside it. ``positions`` ``[3, len(tokens)]`` (a model whose
+        positions are not its rows): three more rows of ``bucket`` behind
+        the tokens."""
         table = np.asarray(table, np.int32).ravel()
-        ints = np.zeros(7 + table.size + bucket, np.int32)
+        more = 0 if positions is None else 3 * bucket
+        ints = np.zeros(7 + table.size + bucket + more, np.int32)
         ints[:7] = (len(tokens), wpos, eos, min_new, greedy, top_k,
                     wants_token)
         ints[7:7 + table.size] = table
         ints[7 + table.size:][:len(tokens)] = tokens
+        if more:
+            ints[-more:].reshape(3, bucket)[:, :len(tokens)] = positions
         return ints
 
     def _prefill_args(self, req: Request, tokens, bucket: int, replay: bool,
@@ -2344,14 +2423,20 @@ class ServingEngine:
         pair is the engine's resident constant."""
         with span("serving.prefill_args", request=req.id, bucket=bucket,
                   transfers=0) as at:
+            positions = None
+            if self._mrope:
+                from fleetx_tpu.serving.rows_in import row_positions
+
+                positions = row_positions(req, wpos, len(tokens))
             if replay:
-                ints = self._prefill_ints(tokens, bucket, wpos, table)
+                ints = self._prefill_ints(tokens, bucket, wpos, table,
+                                          positions=positions)
                 floats = self._inert_floats
             else:
                 ints = self._prefill_ints(
                     tokens, bucket, wpos, table, eos=req.eos_token_id,
                     min_new=req.min_new_tokens, greedy=req.greedy,
-                    top_k=req.top_k, wants_token=True)
+                    top_k=req.top_k, wants_token=True, positions=positions)
                 floats = _upload(at, _sampler_floats(req))
             return _upload(at, ints), floats, req.rng_key
 
@@ -2433,6 +2518,11 @@ class ServingEngine:
             req, suffix, bucket, replay, shared,
             self.cache_manager.lane_tables(lane))
         args = (self.params, self.cache_manager.cache, ints, floats, key)
+        if self._tower is not None:
+            # the images among these rows, encoded now: in this step's
+            # prefill slot, right before the program that takes their rows
+            self._tower.stage_rows(req, shared, len(suffix))
+            args += (self._tower.stage,)
         tok, carry_key, program = self._guarded_prefill(
             req, fn, args, bucket=bucket, first=first)
         self.metrics.record_prefill_call(wants_token=not replay)
@@ -2443,7 +2533,8 @@ class ServingEngine:
         ``req.slot`` and returns the shared-prefix token count (trie +
         host-revived)."""
         with span("serving.claim", request=req.id, shared=0) as at:
-            alloc = self.cache_manager.alloc(req.id, req.prompt)
+            alloc = self.cache_manager.alloc(
+                req.id, self._trie_keys(req, req.prompt))
             if alloc is None:  # _can_admit() passed, so this is an
                 raise RuntimeError(  # invariant breach — fail loudly
                     f"paged alloc failed after admission check for request "
@@ -2477,7 +2568,8 @@ class ServingEngine:
             ints = np.asarray(
                 [req.slot, tok if on_host else -1, length, decoded, active,
                  req.eos_token_id, req.max_new_tokens, req.min_new_tokens,
-                 req.greedy, req.top_k], np.int32)
+                 req.greedy, req.top_k]
+                + ([req.rope_delta] if self._mrope else []), np.int32)
             if floats is None:
                 floats = _upload(at, _sampler_floats(req))
             self._state = self._admit_jit(
@@ -2489,7 +2581,8 @@ class ServingEngine:
         """Enter the request's prompt pages into the prefix trie (host
         work that runs while its prefill is still on the device)."""
         with span("serving.install", request=req.id):
-            self.cache_manager.register_prefix(req.slot, req.prompt)
+            self.cache_manager.register_prefix(
+                req.slot, self._trie_keys(req, req.prompt))
 
     def _next_program(self) -> int:
         """The number of the program about to be dispatched: one count
@@ -2548,6 +2641,15 @@ class ServingEngine:
             # was resumed from the matched pages' tails
             at["matched"] = int(shared)
             at["state_resumed"] = bool(shared and self._state_rows)
+            if req.images:
+                # the trie has been matched BEFORE any tower call: an image
+                # wholly inside the match is neither encoded nor prefilled
+                req.staged = set()
+                rows = int((req.keys < 0).sum())
+                spared = self._tower.skipped(req, shared)
+                at.update(images=len(req.images), image_rows=rows,
+                          images_skipped=spared)
+                self.metrics.record_images(rows, spared)
             if (self.prefill_chunk
                     and req.prompt_len - shared > self.prefill_chunk):
                 req.prefill_pos = shared
@@ -2802,9 +2904,15 @@ class ServingEngine:
         with jax.named_scope("lanes"):
             wpos = jnp.where(active, lengths, self.cache_len - 1)
             posid = jnp.where(active, jnp.minimum(lengths, max_pos - 1), 0)
+            posid = posid[:, None]
+            if self._mrope:  # a decoded row: the three axes alike
+                posid = jnp.broadcast_to(jnp.where(
+                    active, jnp.minimum(lengths + st["rope_delta"],
+                                        max_pos - 1), 0)[None, :, None],
+                    (3,) + posid.shape)
         logits, cache = self.executor.forward(
             params, cache, st["last_tok"][:, None],
-            posid[:, None], self._row_mask(active[:, None]),
+            posid, self._row_mask(active[:, None]),
             cache_positions=wpos,
             block_tables=tables)
         with jax.named_scope("sampler"):
@@ -2886,9 +2994,10 @@ class ServingEngine:
         holds a share, the pairs its routers choose. Empty with one class
         and one kind."""
         rows = self.cache_manager.lengths[list(lanes)] + 1
-        if self._state_rows:  # what ONE of its attention layers reads, and
+        cfg = self.model.cfg
+        if self._state_rows or getattr(cfg, "indexed", False):
+            # what ONE of its attention layers reads, and
             # the lanes whose lane-resident state the tick advances
-            cfg = self.model.cfg
             fields = {cfg.rows_span_field: int(rows.sum()),
                       **({"state_lanes": len(lanes)} if self._lane_state
                          else {}), **cfg.span_pairs(self.slots)}

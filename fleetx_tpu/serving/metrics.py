@@ -361,6 +361,22 @@ class ServingMetrics:
         self._c_selected_rows = counter(
             "fleetx_serving_rows_selected_total",
             "Cached rows the indexer kept for those queries, in one layer")
+        # rows from a vision tower (docs/SERVING.md "Rows from a tower")
+        self._c_image_rows = counter(
+            "fleetx_serving_image_rows_total",
+            "Prompt rows admitted that a vision tower made, not the word "
+            "table (their images' rows, a trie hit's among them)")
+        self._c_images_encoded = counter(
+            "fleetx_serving_images_encoded_total",
+            "Images the vision tower encoded (one tower program each)")
+        self._c_images_skipped = counter(
+            "fleetx_serving_images_skipped_total",
+            "Images of admitted prompts that lay wholly inside the prefix "
+            "trie's match and were neither encoded nor prefilled")
+        self._c_tower_patches = counter(
+            "fleetx_serving_tower_patches_total",
+            "Patches the tower's programs encoded (the images' own, without "
+            "their buckets' padding)")
         self._first_token_t: Optional[float] = None
         self._last_token_t: Optional[float] = None
         weakref.finalize(self, _drop_series, owned)
@@ -483,6 +499,17 @@ class ServingMetrics:
             self._c_index_rows.inc(fields["index_rows"])
             self._c_selected_rows.inc(fields["selected_rows"])
         return fields
+
+    def record_images(self, rows: int, skipped: int) -> None:
+        """An admission with images: the prompt rows that are a tower's,
+        and how many of its images the trie's match spared the tower."""
+        self._c_image_rows.inc(rows)
+        self._c_images_skipped.inc(skipped)
+
+    def record_tower(self, patches: int) -> None:
+        """One tower program ran, over an image of ``patches`` patches."""
+        self._c_images_encoded.inc()
+        self._c_tower_patches.inc(patches)
 
     def observe_host_tier(self, store) -> None:
         """Per-tick sync from a :class:`HostPageStore`: gauges track its
@@ -918,6 +945,10 @@ class ServingMetrics:
                 self._c_prefill_headless_calls.value),
             "index_rows_scored": int(self._c_index_rows.value),
             "rows_selected": int(self._c_selected_rows.value),
+            "image_rows": int(self._c_image_rows.value),
+            "images_encoded": int(self._c_images_encoded.value),
+            "images_skipped": int(self._c_images_skipped.value),
+            "tower_patches": int(self._c_tower_patches.value),
             # crash-safety story: how often the engine recovered, what it
             # quarantined, what shutdown turned away, and what a tick costs
             "engine_recoveries": self.engine_recoveries,

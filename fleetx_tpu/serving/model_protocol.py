@@ -114,6 +114,16 @@ class ModelCapabilities:
     #: kind is spilled to the host tiers or shipped between replicas
     state_kinds: tuple = ("kv",)
     supports_host_spill: bool = True
+    #: rows that are not tokens: the model takes ``[rows, hidden]`` rows of
+    #: a vision tower beside ids (``submit(prompt, images=...)``; the
+    #: engine then runs the tower, keys the prefix trie by a row's key and
+    #: hands every prefill program its rows: docs/SERVING.md "Rows from a
+    #: tower"). ``mrope``: its rotary positions have three axes and are not
+    #: the cache row, so a lane carries ``rope_delta`` and every program
+    #: takes positions ``[3, rows]`` ("Positions apart from rows"). A model
+    #: without them never sees either operand
+    takes_rows: bool = False
+    mrope: bool = False
 
     def as_dict(self) -> dict:
         """JSON-ready form for the ``/healthz`` report (a tuple reads as
@@ -147,12 +157,14 @@ _FEATURES = {
                              "their state once a lane, with no snapshot at "
                              "the match's end to resume from",
     "supports_roles": "a prefill or decode role: the pages of its window "
-                      "class, the tail pages of its convolution state, or "
-                      "the lane-resident state of its selective-scan or "
-                      "delta-rule layers, are not shipped between replicas",
+                      "class, the tail pages of its convolution state, the "
+                      "lane-resident state of its selective-scan or "
+                      "delta-rule layers, or the pages of a flat pool over "
+                      "mixed layers, are not shipped between replicas",
     "supports_host_spill": "a host or disk page tier: the tail pages of its "
-                           "convolution state, or the lane-resident state "
-                           "of its selective-scan or delta-rule layers, are "
+                           "convolution state, the lane-resident state "
+                           "of its selective-scan or delta-rule layers, or "
+                           "the pages of a flat pool over mixed layers, are "
                            "not spilled",
 }
 
@@ -212,7 +224,12 @@ class ModelExecutor:
         last true row). A negative entry asks for no row: where every
         entry is negative the head is not run (an intermediate chunk, a
         replay) and the logits returned are not to be read. The cache
-        written is the same whatever the rows."""
+        written is the same whatever the rows.
+
+        Implementations of a ``takes_rows`` family also take
+        ``input_rows=(rows [b, s, hidden], is_image [b, s])``: the rows
+        that enter the stack where ``is_image`` marks them, in the place of
+        the word table's."""
         raise NotImplementedError
 
     def sample(self, logits, keys, greedy, temperature, top_k, top_p, *,
@@ -264,6 +281,14 @@ class GPTExecutor(ModelExecutor):
         dense = dense and not kinds
         state = tuple(getattr(model.cfg, "state_kinds", ("kv",)))
         recurrent = state != ("kv",)
+        # a ``layer_types`` stack keeps ONE flat pool over its layers
+        # (hybrid.init_cache): a page's payload, as the spill tiers and the
+        # page ship read it, would be one layer's. The stack of
+        # full-attention layers under an indexer (three leaves) is the
+        # first that could ask for either with its trie on, and is refused
+        # both; every other family keeps the flags it had (ROADMAP R9)
+        flat = bool(getattr(model.cfg, "layer_types", None)) and bool(
+            getattr(model.cfg, "indexed", False))
         self.capabilities = ModelCapabilities(
             family=family or getattr(model.cfg, "family", "gpt"),
             has_kv_cache=True,
@@ -276,9 +301,11 @@ class GPTExecutor(ModelExecutor):
             page_classes=("full", "window") if windowed else ("full",),
             supports_prefix_cache=not windowed and not getattr(
                 model.cfg, "lane_state", ("", ()))[0],
-            supports_roles=not windowed and not recurrent,
+            supports_roles=not windowed and not recurrent and not flat,
             state_kinds=state,
-            supports_host_spill=not recurrent,
+            supports_host_spill=not recurrent and not flat,
+            takes_rows=bool(getattr(model.cfg, "vision", None)),
+            mrope=bool(getattr(model.cfg, "mrope_section", None)),
         )
 
     def bind(self, model):
@@ -342,7 +369,8 @@ class GPTExecutor(ModelExecutor):
         return init_decode_cache(self.model, batch)
 
     def forward(self, params, cache, ids, positions, mask=None, *,
-                cache_positions=None, block_tables=None, logit_rows=None):
+                cache_positions=None, block_tables=None, logit_rows=None,
+                **rows_in):
         import jax
 
         from fleetx_tpu.models.gpt.generation import decode_step
@@ -355,10 +383,11 @@ class GPTExecutor(ModelExecutor):
                 return row_logits_step(
                     self.model, params, cache, ids, positions, mask,
                     cache_positions=cache_positions,
-                    block_tables=block_tables, logit_rows=logit_rows)
+                    block_tables=block_tables, logit_rows=logit_rows,
+                    **rows_in)
             return decode_step(self.model, params, cache, ids, positions,
                                mask, cache_positions=cache_positions,
-                               block_tables=block_tables)
+                               block_tables=block_tables, **rows_in)
 
     def sample(self, logits, keys, greedy, temperature, top_k, top_p, *,
                topk_cap: int):

@@ -75,6 +75,23 @@ class Request:
     # cleared) by the engine's shipped-KV admission; a request whose
     # shipped admission rolled back re-admits through the replay seam
     kv_payloads: Any = dataclasses.field(default=None, repr=False)
+    # rows that are not tokens and positions that are not rows (docs/
+    # SERVING.md "Rows from a tower", "Positions apart from rows"), derived
+    # by the engine at submit for a model that takes them: ``keys`` [prompt_len]
+    # int64, what the prefix trie is keyed by (a text row's id; an image
+    # row's hash, negative); ``positions`` [3, prompt_len] int32, the rotary
+    # position of every prompt row on the three axes; ``rope_delta``, what a
+    # decoded row's position stands past its cache row; ``images``, one
+    # ``{"start", "grid", "patches"}`` a prompt image (its first row, its
+    # patches' rows and columns, its uint8 patches in merge order) and
+    # ``staged``, those of them whose rows the tower has written for the
+    # admission under way. None / empty for a request of token ids alone
+    keys: Optional[np.ndarray] = dataclasses.field(default=None, repr=False)
+    positions: Optional[np.ndarray] = dataclasses.field(default=None,
+                                                        repr=False)
+    rope_delta: int = 0
+    images: list = dataclasses.field(default_factory=list, repr=False)
+    staged: set = dataclasses.field(default_factory=set, repr=False)
 
     @property
     def prompt_len(self) -> int:
