@@ -114,6 +114,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
+from fleetx_tpu.models.gpt import paged_write
 from fleetx_tpu.models.gpt.block_fields import (
     LANE_STATE_LEAVES,
     RECURRENT_TYPES,
@@ -134,7 +135,7 @@ from fleetx_tpu.models.gpt.model import (
 )
 
 __all__ = ["KDAMixer", "MambaMixer", "MixedStack", "ShortConv",
-           "layer_plan", "state_rows"]
+           "layer_plan", "mover_layers", "state_rows"]
 
 
 def layer_plan(cfg: GPTConfig) -> dict:
@@ -175,6 +176,20 @@ def layer_plan(cfg: GPTConfig) -> dict:
                     shortcut_index=(halves // 2).astype(np.int32))
         plan["counts"]["dense"] = cfg.num_layers
     return plan
+
+
+def mover_layers(cfg) -> dict:
+    """Span fields of every prefill call and tick of a model with layer
+    types, constants of its plan: ``kv_write_layers``, the layers whose
+    key/value write lands (the attention layers), and ``state_layers``, the
+    layers whose recurrent state advances. In a layer of the other kind that
+    mover is skipped (``MixedStack``'s ``operator``). None without layer
+    types."""
+    if not getattr(cfg, "layer_types", None):
+        return {}
+    attention = layer_plan(cfg)["counts"]["attention"]
+    return {"kv_write_layers": attention,
+            "state_layers": cfg.num_layers - attention}
 
 
 def state_rows(cfg: GPTConfig) -> int:
@@ -835,9 +850,9 @@ class MixedStack(nn.Module):
         def ssm_update(pools, mixed, held, mixes, index):
             """``y`` of the call's rows; ``pools`` (the caller's own dict)
             takes the leaves with the lanes' state advanced over them, in
-            place. A layer of another kind (``mixes``) changes nothing: its
-            ``dt`` is zero, so ``h`` stays, and the filter rows written are
-            the ones held."""
+            place. A layer of another kind (``mixes``) changes nothing: the
+            kernels skip it, so ``h`` stays where it is, and the filter rows
+            written are the ones held."""
             from fleetx_tpu.ops.pallas.ssm_scan import selective_step
 
             state, fresh = pools["ssm_state"], begins & ~mixes
@@ -846,7 +861,7 @@ class MixedStack(nn.Module):
                     y, state = selective_step(
                         state, index, mixed["u"][:, 0], mixed["dt"][:, 0],
                         mixed["A"], mixed["B"][:, 0], mixed["C"][:, 0],
-                        fresh, kernel=cfg.use_flash_attention)
+                        fresh, skip=mixes, kernel=cfg.use_flash_attention)
                     y = y[:, None]
             else:
                 at = (index, lanes[0], 0, 0)
@@ -912,11 +927,20 @@ class MixedStack(nn.Module):
             """The operator over the cache, in three steps: a conditional
             that computes (the recurrent operator up to its state, reading
             what it starts from; the attention's queries, keys and values),
-            the writes of BOTH kinds of state outside every conditional (the
-            other kind's rows write nothing), and a conditional that
-            finishes (attends; the mixer's gate and output projection). A
-            pool that a conditional hands back is copied whole by XLA: 1.3
-            GB in every attention layer at the served sizes."""
+            the writes of BOTH kinds of state outside every conditional, and
+            a conditional that finishes (attends; the mixer's gate and output
+            projection). A pool that a conditional hands back is copied
+            whole by XLA (1.3 GB in every attention layer at the served
+            sizes), so the pools and leaves cross none: the mover of the
+            OTHER kind's state is handed the layer's kind as a scalar that
+            its kernel branches on, and does nothing. The step kernels run
+            one block through (``skip``: 10 us where ``fleetx_ssm_step``
+            moved 168 MB in 303), and where the stack has both kinds the
+            key/value write is ``fleetx_write_rows``, whose body sits under
+            ``keep`` (``paged_write.write_rows_or_skip``: a dropped write is
+            a launch, where the scatter walked every dropped update: 40 us a
+            layer of a 256-lane tick). A stack of one kind keeps the
+            scatter, and the program it had."""
             held = (filter_rows(pools[rows_leaf], index)
                     if recurrent in LANE_STATE_LEAVES else None)
 
@@ -959,7 +983,17 @@ class MixedStack(nn.Module):
             elif recurrent == "kda":
                 mixed["o"] = kda_update(pools, mixed, held, mixes, index)
             leaves = [n for n in POOL_LEAVES if n in pools]
-            if counts["attention"]:
+            if all(both):
+                # most layers of such a stack drop this write: the writer
+                # that branches on the layer's kind
+                based = own + jnp.asarray(layer_bases(cfg))[index]
+                news = [qkv[n] for n in ("k", "v", "index")[:len(leaves)]]
+                pools.update(zip(leaves, paged_write.write_rows_or_skip(
+                    [pools[n] for n in leaves],
+                    [new.reshape(b * s, -1) for new in news], based, wpos,
+                    cfg.decode_cache_len or cfg.max_position_embeddings,
+                    mixes, kernel=cfg.use_flash_attention)))
+            elif counts["attention"]:
                 pools.update(zip(leaves, write_rows(
                     cfg, pools["cached_key"], pools["cached_value"],
                     own + jnp.asarray(layer_bases(cfg))[index], wpos,

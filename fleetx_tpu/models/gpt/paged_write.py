@@ -1,6 +1,7 @@
 """THE write of a call's keys and values into the page pool, through the
-block table: ``SelfAttention._update_paged_cache`` (models/gpt/model.py) and
-``hybrid.write_rows`` (models/gpt/hybrid.py) both end here.
+block table: ``SelfAttention._update_paged_cache`` (models/gpt/model.py),
+``hybrid.write_rows`` (models/gpt/hybrid.py) and a stack of layer KINDS
+(models/gpt/mixed_stack.py, through :func:`write_rows_or_skip`) end here.
 
 One algorithm, rows through a table, whose UNIT follows the call's static
 shape (:func:`page_writes`):
@@ -10,21 +11,20 @@ shape (:func:`page_writes`):
   pair ``(page, offset)`` a row, one update a row;
 - **a page at a time** (one sequence's rows over a whole number of pages:
   every prefill program, every chunk, every replay): one table lookup and
-  one update a PAGE. The write position is a traced value that the model
-  cannot see to be page-aligned, so the form is right at ANY offset: the
-  span's first and last page are read, the new rows laid between what they
-  hold before and behind the span, and the ``rows // page_size + 1`` pages
-  written back. At an aligned offset the last of them holds none of the
-  span and is dropped, as is a page past the table (a span that ends at the
-  cache's end).
+  one update a PAGE, right at ANY offset (the model cannot see a traced
+  position to be page-aligned): the span's first and last page are read, the
+  new rows laid between what they hold before and behind the span, and the
+  ``rows // page_size + 1`` pages written back, less the last at an aligned
+  offset and any page past the table, which hold none of the span.
 
 Both forms leave the same bits in the same places outside the trash page
-(bucket-tail rows and zeroed table entries land there in both, in no
-defined order in either). Rows past the cache's last position are not a
-defined write in either form.
-
-A module of its own, and not a part of model.py, so that a change here moves
-no line of the code that training traces (models/gpt/resident.py has why).
+(bucket-tail rows and zeroed table entries land there in both, in no defined
+order in either); rows past the cache's last position are no defined write.
+A write under ``keep`` False (a layer of another kind in a scanned body) is
+DROPPED: by the scatter update for update, at its full price; by
+:func:`write_rows_or_skip`'s kernel for a launch. No ``lax.cond`` holds
+either: XLA copies a pool whole that a conditional hands back. New code goes
+to the file's END: these lines are in every traced program's cache key.
 """
 
 from __future__ import annotations
@@ -32,7 +32,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["decode_end", "page_writes", "write_rows"]
+__all__ = ["decode_end", "page_writes", "write_rows", "write_rows_or_skip"]
 
 
 def decode_end(tables, wpos, page_size: int):
@@ -111,3 +111,46 @@ def _by_page(pools, rows, table, wpos, max_len, keep):
         out.append(pool.at[target].set(
             span.reshape((n + 1,) + pool.shape[1:]), mode="drop"))
     return out
+
+
+def write_rows_or_skip(pools, rows, tables, wpos, max_len: int, keep, *,
+                       kernel: bool = True):
+    """:func:`write_rows` for a caller whose ``keep`` (a traced bool) is
+    False in most of its calls: a stack of layer kinds, whose one scanned
+    body writes keys and values in EVERY layer and keeps them in the
+    attention layers alone (``mixed_stack.py`` ``operator``; Jamba2: 2 of
+    28). The scatter of :func:`write_rows` cannot branch on ``keep``: it aims
+    a dropped row at a page past the pool and still walks every update (40
+    us a layer of a 256-lane tick, 16-21 us of a prefill's 17-49 pages,
+    alone on a v5e: PERF.md, PR 62), and a ``lax.cond`` around it hands the
+    pools back, which XLA copies whole. Here the write is the kernel ``fleetx_write_rows``
+    (``ops/pallas/write_rows.py``), which takes ``keep``, the pages and the
+    offsets as scalars and has its whole body under ``keep``: the pools are
+    aliased through it in place and cross no conditional, and a dropped
+    write costs the launch (5-8 us with the index arithmetic before it). It takes a tick (one row a lane) and the
+    page-at-a-time form; any other shape, a pool it cannot hold, or a
+    backend without the kernels (``kernel``) is :func:`write_rows`'s. The
+    index arithmetic is ``_by_row``'s and ``_by_page``'s, repeated here so
+    that no line above moves (the module docstring's last paragraph)."""
+    from fleetx_tpu.ops.pallas import write_rows as writer
+    from fleetx_tpu.ops.pallas.flash_attention import kernels_enabled
+
+    batch, ps = wpos.shape[0], pools[0].shape[1]
+    s = rows[0].shape[0] // batch
+    n = page_writes(batch, s, ps)
+    if not (kernel and kernels_enabled() and (n or s == 1)
+            and writer.takes(pools, rows, n + 1 if n else batch)):
+        return write_rows(pools, rows, tables, wpos, max_len, keep)
+    with jax.named_scope("cache_write"):
+        if not n:
+            pos = jnp.minimum(wpos, max_len - 1)
+            page = jnp.take_along_axis(tables, pos[:, None] // ps, axis=1)
+            return writer.write_a_row_a_lane(pools, rows, page, pos % ps,
+                                             keep)
+        table, index = tables[0], jnp.arange(n + 1, dtype=jnp.int32)
+        entry, lead = wpos[0] // ps + index, wpos[0] % ps
+        written = (entry < min(table.shape[0], max_len // ps)) & (
+            (index < n) | (lead > 0))
+        return writer.write_a_span(
+            pools, rows, lead, table[jnp.minimum(entry, table.shape[0] - 1)],
+            written, keep)

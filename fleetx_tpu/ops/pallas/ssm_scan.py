@@ -38,7 +38,9 @@ it to its output, and reads and writes only that layer's blocks: the leaf
 is updated in place (2.2 GB at the served size: a slice taken out, updated
 and put back would move it three times). Grid ``(lanes / 8, blocks of
 channels)``. A lane that is not decoding has ``dt = 0`` and keeps its
-state; ``fresh`` lanes start from zero.
+state; ``fresh`` lanes start from zero. Under ``skip`` (a layer of another
+kind, which has no ``h``) the grid is ONE row of steps on one block, copied
+through once: the layer's 168 MB at the served size stay where they are.
 
 Off the TPU both fall back to plain ``jax.numpy`` (a ``lax.scan`` over the
 rows), which is also what the interpret-mode tests compare the kernels
@@ -73,6 +75,12 @@ def _divisor(n: int, options) -> int:
 def _along_lanes(tile, copies: int):
     """``[N, 128]`` repeated along the lanes to ``[N, 128 * copies]``."""
     return tile if copies == 1 else jnp.concatenate([tile] * copies, axis=1)
+
+
+def _flag(skip):
+    """``skip`` (None, or a traced bool) as the ``[1]`` int32 a kernel is
+    handed ahead of its grid."""
+    return jnp.reshape(False if skip is None else skip, (1,)).astype(jnp.int32)
 
 
 def _advance(h, a, dt, u, b, c):
@@ -186,27 +194,34 @@ def selective_scan(u, dt, a, b, c, h0, *, skip=None, kernel: bool = True):
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
         name=SCAN_KERNEL_NAME,
-    )(jnp.reshape(False if skip is None else skip, (1,)).astype(jnp.int32),
-      u, dt, *wide, a, h0)
+    )(_flag(skip), u, dt, *wide, a, h0)
     return y, h.transpose(0, 2, 1, 3).reshape(batch, n, width)
 
 
 # ------------------------------------------------------------------- step
 
-def _step_kernel(layer_ref, fresh_ref, u_ref, dt_ref, b_ref, c_ref, a_ref,
-                 h_ref, y_ref, out_ref, *, lanes: int, copies: int):
+def _step_kernel(layer_ref, skip_ref, fresh_ref, u_ref, dt_ref, b_ref, c_ref,
+                 a_ref, h_ref, y_ref, out_ref, *, lanes: int, copies: int):
     del layer_ref  # read by the index maps
     first = pl.program_id(0) * lanes
-    a = a_ref[...]
-    ys = []
-    for r in range(lanes):
-        h = jnp.where(fresh_ref[first + r] != 0, 0.0, h_ref[r])
-        h, y = _advance(h, a, dt_ref[r:r + 1, :], u_ref[r:r + 1, :],
-                        _along_lanes(b_ref[r], copies),
-                        _along_lanes(c_ref[r], copies))
-        out_ref[r] = h
-        ys.append(y)
-    y_ref[...] = jnp.concatenate(ys, axis=0)
+
+    @pl.when(skip_ref[0] == 0)
+    def _():
+        a = a_ref[...]
+        ys = []
+        for r in range(lanes):
+            h = jnp.where(fresh_ref[first + r] != 0, 0.0, h_ref[r])
+            h, y = _advance(h, a, dt_ref[r:r + 1, :], u_ref[r:r + 1, :],
+                            _along_lanes(b_ref[r], copies),
+                            _along_lanes(c_ref[r], copies))
+            out_ref[r] = h
+            ys.append(y)
+        y_ref[...] = jnp.concatenate(ys, axis=0)
+
+    # (a skipped call's steps all map to one block: copied through once)
+    @pl.when((skip_ref[0] != 0) & (pl.program_id(1) == 0))
+    def _():
+        out_ref[...] = h_ref[...]
 
 
 def _step_tiles(lanes: int, width: int):
@@ -215,41 +230,54 @@ def _step_tiles(lanes: int, width: int):
     return (group, block) if group and block else None
 
 
-def selective_step(state, layer, u, dt, a, b, c, fresh, *,
+def selective_step(state, layer, u, dt, a, b, c, fresh, *, skip=None,
                    kernel: bool = True):
     """``y`` ``[lanes, D]`` and the leaf ``state`` ``[layers, lanes, N, D]``
     with ``layer``'s states advanced by one row a lane (shapes as
-    :func:`selective_step_plain`). The kernel updates the leaf in place
+    :func:`selective_step_plain`); under ``skip`` (a traced bool) the leaf
+    as it was and ``y`` undefined. The kernel updates the leaf in place
     (module docstring); a caller that donates the leaf holds no copy."""
     lanes, width = u.shape
     n = a.shape[0]
     tiles = _step_tiles(lanes, width) if kernel and kernels_enabled() else None
     if tiles is None:
-        return selective_step_plain(state, layer, u, dt, a, b, c, fresh)
+        y, new = selective_step_plain(state, layer, u, dt, a, b, c, fresh)
+        return (y, new) if skip is None else (y, jnp.where(skip, state, new))
     group, bd = tiles
+    skip = _flag(skip)
     wide = [jnp.broadcast_to(t[..., None], (lanes, n, _LANES)) for t in (b, c)]
-    row_block = pl.BlockSpec((group, bd), lambda i, j, li, fr: (i, j))
-    bc_block = pl.BlockSpec((group, n, _LANES), lambda i, j, li, fr: (i, 0, 0))
+
+    def block(j, sk):
+        """The block of channels of step ``j``: a skipped call's all map to
+        the first, which is copied through once."""
+        return j * (1 - sk[0])
+
+    row_block = pl.BlockSpec((group, bd),
+                             lambda i, j, li, sk, fr: (i, block(j, sk)))
+    bc_block = pl.BlockSpec((group, n, _LANES),
+                            lambda i, j, li, sk, fr: (i, 0, 0))
     h_block = pl.BlockSpec((None, group, n, bd),
-                           lambda i, j, li, fr: (li[0], i, 0, j))
+                           lambda i, j, li, sk, fr: (li[0], i, 0, block(j, sk)))
     y, state = pl.pallas_call(
         functools.partial(_step_kernel, lanes=group, copies=bd // _LANES),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(lanes // group, width // bd),
+            num_scalar_prefetch=3,
+            # (a skipped call: one row of steps, not one a group of lanes)
+            grid=(jnp.where(skip[0] != 0, 1, lanes // group), width // bd),
             in_specs=[row_block, row_block, bc_block, bc_block,
-                      pl.BlockSpec((n, bd), lambda i, j, li, fr: (0, j)),
+                      pl.BlockSpec((n, bd),
+                                   lambda i, j, li, sk, fr: (0, block(j, sk))),
                       h_block],
             out_specs=[row_block, h_block]),
         out_shape=[jax.ShapeDtypeStruct((lanes, width), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        # operand 7 (after the two prefetched scalars): the leaf itself
-        input_output_aliases={7: 1},
+        # operand 8 (after the three prefetched scalars): the leaf itself
+        input_output_aliases={8: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
         name=STEP_KERNEL_NAME,
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), fresh.astype(jnp.int32),
-      u, dt, *wide, a, state)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), skip,
+      fresh.astype(jnp.int32), u, dt, *wide, a, state)
     return y, state

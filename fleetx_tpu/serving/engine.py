@@ -2464,7 +2464,7 @@ class ServingEngine:
         program = self._next_program()
         pages = paged_write.page_writes(1, bucket, self.page_size)
         with span("serving.prefill", request=req.id, bucket=bucket,
-                  program=program, page_writes=pages) as at:
+                  program=program, page_writes=pages, **self._plan) as at:
             if first:
                 at["first"] = True
             faults.on_serving_prefill(attempt, req.id)
@@ -3087,7 +3087,7 @@ class ServingEngine:
         with span("serving.decode", batch=len(active_ids),
                   empty_lanes=self.slots - len(lanes),
                   inflight=int(before is not None), program=program,
-                  **self._kernel_steps,
+                  **self._kernel_steps, **self._plan,
                   **self.metrics.record_selection(self._decode_rows(lanes))):
             cache, st, tok, done = self._run_device(run)
         self.cache_manager.cache = cache
@@ -3512,3 +3512,13 @@ class ServingEngine:
             ttft_s=(req.first_token_time or now) - req.submit_time,
             latency_s=now - req.submit_time,
         )
+
+    @functools.cached_property
+    def _plan(self) -> dict:
+        """Span fields of every prefill call and tick that are constants of
+        the model's plan of layers (``mixed_stack.mover_layers``): none for
+        a model without layer types. (At the file's end: no line above it
+        moves, so no program's compile-cache key does.)"""
+        from fleetx_tpu.models.gpt.mixed_stack import mover_layers
+
+        return mover_layers(self.model.cfg)

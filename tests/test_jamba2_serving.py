@@ -32,7 +32,7 @@ from serving_parity import computed_once, sharing_programs, traced_apply
 
 from fleetx_tpu.models.gpt.generation import GenerationConfig
 from fleetx_tpu.models.gpt.model import GPTConfig, GPTForPretraining
-from fleetx_tpu.ops.pallas import ssm_scan
+from fleetx_tpu.ops.pallas import ssm_scan, write_rows
 from fleetx_tpu.serving import ServingEngine
 from perfbench import flops_ssm, harness
 from perfbench.drivers.serve_closed_loop_ssm import (FIRST_STATE_TOL, Served,
@@ -275,6 +275,18 @@ def test_the_step_kernel_matches_the_lax_scan(monkeypatch, lanes, width):
     np.testing.assert_array_equal(got[[0, 2]], state[[0, 2]])
     keeps = idle & ~np.asarray(fresh)
     np.testing.assert_array_equal(got[1][keeps], state[1][keeps])
+    # ``skip`` False is the same call, and True hands the leaf back as it
+    # was (the fresh lanes' states too): the kernel and the plain path
+    step = jax.jit(ssm_scan.selective_step, static_argnames=("kernel",))
+    for kernel in (True, False):
+        for kept, as_ever in zip(
+                step(*args, skip=jnp.bool_(False), kernel=kernel),
+                step(*args, kernel=kernel)):
+            # (on the CPU the fusions are XLA's to choose, the interpreted
+            # kernel's too: an ulp)
+            np.testing.assert_allclose(kept, as_ever, rtol=1e-4, atol=1e-6)
+        np.testing.assert_array_equal(
+            step(*args, skip=jnp.bool_(True), kernel=kernel)[1], state)
 
 
 def test_the_engine_with_the_kernels_on_matches_the_reference(monkeypatch,
@@ -311,6 +323,7 @@ def test_the_compiled_tick_holds_no_copy_of_a_state_leaf(monkeypatch,
     monkeypatch.setenv("FLEETX_FORCE_FLASH", "1")
     monkeypatch.setattr(ssm_scan, "_interpret", lambda: False)
     monkeypatch.setattr(da, "_interpret", lambda: False)
+    monkeypatch.setattr(write_rows, "_interpret", lambda: False)
     sizes = dict(hidden_size=256, num_attention_heads=2, head_size=128,
                  ffn_hidden_size=256, dense_ffn_hidden_size=256,
                  use_flash_attention=True, dtype=jnp.bfloat16)
@@ -325,19 +338,57 @@ def test_the_compiled_tick_holds_no_copy_of_a_state_leaf(monkeypatch,
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=sharding), tree)
 
-    leaf = next(x for path, x in jax.tree_util.tree_flatten_with_path(
-        engine.cache_manager.cache)[0] if path[-1].key == "ssm_state")
+    held = {path[-1].key: x for path, x in jax.tree_util.tree_flatten_with_path(
+        engine.cache_manager.cache)[0]}
+    leaf, pools = held["ssm_state"], [held["cached_key"], held["cached_value"]]
     tick = jax.jit(engine._decode_fn, static_argnums=(4,),
                    donate_argnums=(1, 2))
     compiled = tick.lower(
         abstract(engine.params), abstract(engine.cache_manager.cache),
         abstract(engine._state), abstract(jnp.asarray(
             engine.cache_manager.tables)), True).compile()
-    assert ssm_scan.STEP_KERNEL_NAME in compiled.as_text()
+    text = compiled.as_text()
+    assert ssm_scan.STEP_KERNEL_NAME in text
+    # the key and value pools likewise: written by the kernel that branches
+    # on the layer's kind, in place, and by no scatter
+    assert write_rows.KERNEL_NAME in text and "scatter(" not in text
     memory = compiled.memory_analysis()
-    assert memory.alias_size_in_bytes >= leaf.nbytes
-    assert memory.temp_size_in_bytes < leaf.nbytes, (
-        memory.temp_size_in_bytes, leaf.nbytes)
+    kept = leaf.nbytes + sum(pool.nbytes for pool in pools)
+    assert memory.alias_size_in_bytes >= kept
+    assert memory.temp_size_in_bytes < min(leaf.nbytes, pools[0].nbytes), (
+        memory.temp_size_in_bytes, leaf.nbytes, pools[0].nbytes)
+
+
+@pytest.mark.parametrize("lanes,rows", [(256, 1), (1, 768)],
+                         ids=["tick", "prefill"])
+def test_the_writer_compiles_for_the_v5e_at_the_cells_shapes(monkeypatch,
+                                                             lanes, rows):
+    """``fleetx_write_rows`` at the served shapes (two bfloat16 pools of
+    32,770 pages of 16 rows of 128; a tick's 256 lanes, a prefill's 768
+    rows), Mosaic's own passes and all: the pools aliased, no temporary."""
+    from jax.sharding import SingleDeviceSharding
+
+    from fleetx_tpu.models.gpt import paged_write
+
+    sharding = SingleDeviceSharding(_tpu_device())
+    monkeypatch.setenv("FLEETX_FORCE_FLASH", "1")
+    monkeypatch.setattr(write_rows, "_interpret", lambda: False)
+
+    def abstract(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    pool = abstract((32770, 16, 128), jnp.bfloat16)
+    new = abstract((lanes * rows, 128), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda pools, news, tables, wpos, keep: paged_write.write_rows_or_skip(
+            pools, news, tables, wpos, 1024, keep),
+        donate_argnums=(0,)).lower(
+            [pool, pool], [new, new], abstract((lanes, 64), jnp.int32),
+            abstract((lanes,), jnp.int32), abstract((), jnp.bool_)).compile()
+    assert write_rows.KERNEL_NAME in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * 32770 * 16 * 128 * 2
+    assert memory.temp_size_in_bytes < 1 << 20
 
 
 # ------------------------------------------- the configuration and the engine
@@ -413,6 +464,14 @@ def test_spans_carry_the_scans_rows_and_the_ticks_lanes(variables):
     assert admits == [16] and chunks == [16, 16, 8]
     assert ticks and all(0 < t["state_lanes"] <= 2 and t["attn_rows"] > 0
                          for t in ticks[-3:])
+    # the plan's constants on every program's span: the layers whose
+    # key/value write lands and the layers whose state advances
+    programs = [s.attrs for s in spans
+                if s.name in ("serving.prefill", "serving.decode")]
+    kinds = [t.endswith("attention") for t in TYPES]
+    assert len(programs) > len(ticks) and all(
+        (p["kv_write_layers"], p["state_layers"])
+        == (sum(kinds), len(kinds) - sum(kinds)) for p in programs)
 
 
 def test_a_stack_with_both_recurrent_kinds_is_refused():
