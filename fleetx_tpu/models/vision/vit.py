@@ -44,7 +44,7 @@ from fleetx_tpu.ops.dropout import dropout_layer
 Dtype = Any
 
 __all__ = ["ViTConfig", "ViT", "VIT_PRESETS", "VisionTower",
-           "build_vision_model", "image_patches", "tower_of"]
+           "build_vision_model", "image_patches", "output_grid", "tower_of"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -323,20 +323,28 @@ def interpolated(table, r, c, rows, cols):
     return top * (1.0 - wy) + low * wy
 
 
+def output_grid(shape, patch: int, merge: int = 2):
+    """``(h, w)``, the rows and columns of output rows an image of ``shape``
+    ``[H, W, C]`` makes (what :func:`image_patches` cuts it into, by its
+    shape alone); raises where it is no whole number of them."""
+    height, width, _ = shape
+    h, w = height // (patch * merge), width // (patch * merge)
+    if (h * patch * merge, w * patch * merge) != (height, width) or not h * w:
+        raise ValueError(f"an image of {height} x {width} pixels is no whole "
+                         f"number of {patch * merge}-pixel rows and columns")
+    return h, w
+
+
 def image_patches(image, patch: int, merge: int = 2):
     """``image`` ``[H, W, C]`` (numpy; ``H`` and ``W`` whole ``patch x
     merge``s) as its patches in MERGE ORDER ``[patches, patch x patch x
     C]``: the ``merge x merge`` patches of output row ``(r, c)`` follow one
     another, rows in raster order; a patch is its pixels in ``(y, x,
     channel)`` order."""
-    height, width, channels = image.shape
-    h, w = height // (patch * merge), width // (patch * merge)
-    if (h * patch * merge, w * patch * merge) != (height, width) or not h * w:
-        raise ValueError(f"an image of {height} x {width} pixels is no whole "
-                         f"number of {patch * merge}-pixel rows and columns")
-    tiles = image.reshape(h, merge, patch, w, merge, patch, channels)
+    h, w = output_grid(image.shape, patch, merge)
+    tiles = image.reshape(h, merge, patch, w, merge, patch, -1)
     return tiles.transpose(0, 3, 1, 4, 2, 5, 6).reshape(
-        h * w * merge * merge, patch * patch * channels)
+        h * w * merge * merge, -1)
 
 
 def tower_of(cfg) -> Optional[VisionTower]:

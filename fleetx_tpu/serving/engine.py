@@ -1004,10 +1004,10 @@ class ServingEngine:
                         "image's rows have none (docs/SERVING.md \"Rows "
                         "from a tower\")")
         if self._tower is not None:
-            from fleetx_tpu.serving.rows_in import layout
-
-            # (a prompt that marks image rows and brings no image raises)
-            laid = layout(prompt, images, self.model.cfg.vision_fields)
+            # what the ids and the images' SHAPES say, and every refusal (a
+            # prompt that marks image rows and brings no image raises): no
+            # byte of an image is read on this thread (serving/rows_in.py)
+            laid = self._tower.outline(prompt, images)
         g = self.gen_cfg
         strategy = decode_strategy or g.decode_strategy
         if strategy not in ("greedy", "sampling"):
@@ -1106,7 +1106,7 @@ class ServingEngine:
         req.tokens.extend(hist)
         req.kv_payloads = decoded_pages
         if laid is not None and laid[3]:
-            req.keys, req.positions, req.rope_delta, req.images = laid
+            self._tower.lay_out(req, laid)  # keys, patches: on its worker
         self.scheduler.submit(req)
         self.metrics.record_submit()
         return rid
@@ -2265,13 +2265,13 @@ class ServingEngine:
         free pages for the head's prompt plus any migrated history
         (page-granular admission: total live tokens gate entry, not
         worst-case lane capacity). A too-big head BLOCKS, preserving
-        arrival order deterministically; it unblocks as retiring requests
-        return pages."""
-        # a dry run of the prefix match, once a tick while the head of the
-        # queue waits: host work between two admissions
+        arrival order deterministically, and so does one whose images the
+        tower's worker still hashes (rows_in.py ``Tower.keyed``)."""
+        # a dry run of the prefix match, once a tick while the head waits
         with span("serving.can_admit", request=req.id):
-            return self.cache_manager.can_admit(
-                self._trie_keys(req, self._admission_tokens(req)))
+            return ((req.keyed is None or self._tower.keyed(req))
+                    and self.cache_manager.can_admit(self._trie_keys(
+                        req, self._admission_tokens(req))))
 
     def _device_tables(self):
         """Device copy of the block tables, re-uploaded only when the

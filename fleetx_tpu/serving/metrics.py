@@ -377,6 +377,20 @@ class ServingMetrics:
             "fleetx_serving_tower_patches_total",
             "Patches the tower's programs encoded (the images' own, without "
             "their buckets' padding)")
+        # the bytes of a prompt's images are read on the tower's worker
+        # thread (serving/rows_in.py "On which thread")
+        self._h_layout = hist(
+            "fleetx_serving_layout_ms",
+            "Milliseconds from the submit of a request with images until the "
+            "worker thread had hashed them all (its trie keys whole)")
+        self._c_layout_blocked = counter(
+            "fleetx_serving_layout_blocked_steps_total",
+            "Steps whose head of the queue waited for its trie keys, so "
+            "that nothing was admitted")
+        self._c_images_cut = counter(
+            "fleetx_serving_images_cut_total",
+            "Images whose patches the step loop took from the worker "
+            "thread, for the tower program about to encode them")
         self._first_token_t: Optional[float] = None
         self._last_token_t: Optional[float] = None
         weakref.finalize(self, _drop_series, owned)
@@ -510,6 +524,19 @@ class ServingMetrics:
         """One tower program ran, over an image of ``patches`` patches."""
         self._c_images_encoded.inc()
         self._c_tower_patches.inc(patches)
+
+    def observe_layout(self, seconds: float) -> None:
+        """A request's images are hashed, ``seconds`` after its submit
+        (called on the worker thread; the registry locks)."""
+        self._h_layout.observe(seconds * 1e3)
+
+    def record_layout_blocked(self) -> None:
+        """A step admitted nothing: the queue's head has no keys yet."""
+        self._c_layout_blocked.inc()
+
+    def record_cut(self) -> None:
+        """An image's patches were taken from the worker thread."""
+        self._c_images_cut.inc()
 
     def observe_host_tier(self, store) -> None:
         """Per-tick sync from a :class:`HostPageStore`: gauges track its
@@ -949,6 +976,10 @@ class ServingMetrics:
             "images_encoded": int(self._c_images_encoded.value),
             "images_skipped": int(self._c_images_skipped.value),
             "tower_patches": int(self._c_tower_patches.value),
+            "layout_ms_p50": self._h_layout.quantiles((50,))[0],
+            "layout_ms_max": self._h_layout.max,
+            "layout_blocked_steps": int(self._c_layout_blocked.value),
+            "images_cut": int(self._c_images_cut.value),
             # crash-safety story: how often the engine recovered, what it
             # quarantined, what shutdown turned away, and what a tick costs
             "engine_recoveries": self.engine_recoveries,

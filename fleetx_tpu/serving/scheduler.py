@@ -85,13 +85,17 @@ class Request:
     # ``{"start", "grid", "patches"}`` a prompt image (its first row, its
     # patches' rows and columns, its uint8 patches in merge order) and
     # ``staged``, those of them whose rows the tower has written for the
-    # admission under way. None / empty for a request of token ids alone
+    # admission under way; ``keyed``, the future of the worker thread that
+    # hashes the images, after which ``keys`` is whole and the request may
+    # be admitted (``patches`` is a future of that thread too, an image
+    # each). None / empty for a request of token ids alone
     keys: Optional[np.ndarray] = dataclasses.field(default=None, repr=False)
     positions: Optional[np.ndarray] = dataclasses.field(default=None,
                                                         repr=False)
     rope_delta: int = 0
     images: list = dataclasses.field(default_factory=list, repr=False)
     staged: set = dataclasses.field(default_factory=set, repr=False)
+    keyed: Any = dataclasses.field(default=None, repr=False)
 
     @property
     def prompt_len(self) -> int:

@@ -50,10 +50,9 @@ SIZES = dict(
 TOL = 2e-5    # float32 against float32, logits up to 0.5
 
 
-@pytest.fixture(scope="module")
-def built():
-    """The model, its weights with the tower's under ``vision`` and every
-    norm's scale moved off 1, and ONE engine for the whole file."""
+def tiny_model():
+    """The model and its weights, the tower's under ``vision`` and every
+    norm's scale moved off 1."""
     cfg = GPTConfig.from_model_config(SIZES)
     model = GPTForPretraining(cfg)
     variables = flax.core.meta.unbox(jax.jit(lambda k: model.init(
@@ -67,13 +66,20 @@ def built():
     params = jax.tree_util.tree_unflatten(shape, [
         leaf + 0.1 * rng.normal(size=leaf.shape).astype(np.float32)
         if path[-1].key == "scale" else leaf for path, leaf in leaves])
+    return model, {"params": params}
+
+
+@pytest.fixture(scope="module")
+def built():
+    """:func:`tiny_model` and ONE engine over it for the whole file."""
+    model, variables = tiny_model()
     engine = ServingEngine(
-        model, {"params": params}, slots=3, cache_len=256, page_size=8,
+        model, variables, slots=3, cache_len=256, page_size=8,
         num_pages=3 * 32 + 1, prefill_chunk=32, prefill_bucket=16,
         prefix_cache=True, gen_cfg=GenerationConfig(
             decode_strategy="greedy", eos_token_id=-1, pad_token_id=0,
             max_length=8))
-    return model, {"params": params}, engine
+    return model, variables, engine
 
 
 def session(seed, grids=((2, 3), (4, 2), (3, 3)), caption=6, tail=40):
