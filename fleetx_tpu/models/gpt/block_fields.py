@@ -105,6 +105,15 @@ class BlockLayoutFields:
     kda_conv_size: int = 4
     kda_gate_rank: int = 0
     kda_neg_eigval: bool = False
+    # ``kda_no_lora``: the decay's and the output gate's projections are
+    # FULL ``[hidden, heads x d]`` matrices (``kda_gate_rank`` 0; the decay's
+    # keeps ``dt_bias``, the gate's has no bias). ``kda_safe_gate``: the log
+    # decay is bounded, ``g = kda_lower_bound * sigmoid(exp(A_log) * (a W_f +
+    # dt_bias))`` in ``(kda_lower_bound, 0)``, in the place of ``-exp(A_log)
+    # * softplus(..)``
+    kda_no_lora: bool = False
+    kda_safe_gate: bool = False
+    kda_lower_bound: float = 0.0
     # the first ``num_dense_layers`` layers take the dense MLP, of width
     # ``dense_ffn_hidden_size``; the others experts of ``ffn_hidden_size``
     # (``num_dense_layers == num_layers``: no expert layer at all)
@@ -127,7 +136,9 @@ class BlockLayoutFields:
     # to the block (mixed_stack.py, hybrid.py). ``attention_gate``
     # "sigmoid": the heads' output times ``sigmoid(a W_g)`` before the
     # out-projection, ``a`` the normed input the queries are made from and
-    # ``W_g`` a projection of its own, as wide as the queries'.
+    # ``W_g`` a projection of its own, as wide as the queries'
+    # ("sigmoid_head": ONE value a head, ``W_g`` ``[hidden, heads]``: the
+    # gate of the latent layers beside delta-rule layers, latent.py).
     # ``sandwich_norm``: an RMSNorm with a weight of its own on each part's
     # OUTPUT before it joins the residual stream (``x + norm(attn(norm(x)))``
     # and the same around the feed-forward part). ``embedding_multiplier``:
@@ -226,6 +237,20 @@ class BlockLayoutFields:
     # the cache then holds); the rotary key is not scaled
     mla_scale_q_lora: bool = False
     mla_scale_kv_lora: bool = False
+    # ---- latent attention layers BESIDE delta-rule layers in one stack
+    # (``layer_types`` of ``kda`` and ``latent_attention``: the latent pool
+    # under the lane's pages and the matrix state under the lane, both in
+    # one scanned body). Such a stack may leave the query latent out
+    # (``q_lora_rank`` None: ``q = a W_q``), norm each head's query and the
+    # shared rotary key before the rotation (``qk_norm`` with
+    # ``qk_norm_scope: head``: weights ``[nope + rope]`` and ``[rope]``) and
+    # gate each head's output (``attention_gate: sigmoid_head``).
+    # The clamp of a gated expert's two products (the source's
+    # ``expert_swiglu_limit_list`` / ``share_expert_swiglu_limit_list``
+    # entry of a layer) is carried and REFUSED where it is not 0: no
+    # equation for it is published (ROADMAP R6)
+    expert_swiglu_limit: float = 0.0
+    shared_expert_swiglu_limit: float = 0.0
 
     @property
     def kv_heads(self) -> int:
@@ -235,6 +260,27 @@ class BlockLayoutFields:
     def latent(self) -> bool:
         """Whether the attention layers are latent attention."""
         return "latent_attention" in (self.layer_types or ())
+
+    @property
+    def latent_beside_kda(self) -> bool:
+        """Whether the stack holds latent attention layers beside
+        delta-rule layers (and nothing else)."""
+        return set(self.layer_types or ()) == {"kda", "latent_attention"}
+
+    @property
+    def held_group(self) -> Optional[int]:
+        """The router group whose experts are EXACTLY the share held here
+        (``n_group`` > 1 and ``[first_expert_held, + num_experts)`` one
+        whole group of at least 8 experts, the floor of a share that stands
+        for a deployment's: the tiny presets of the other share
+        configurations hold a group of 4 by coincidence, and their programs
+        stay what they were); None for any other share."""
+        if not (self.expert_mode and self.n_group > 1):
+            return None
+        size = self.routed_experts // self.n_group
+        first, count = self.experts_held
+        whole = count == size >= 8 and not first % size
+        return first // size if whole else None
 
     @property
     def indexed(self) -> bool:
@@ -559,9 +605,16 @@ def _check_mixed(cfg) -> None:
             f"mamba_expand {cfg.mamba_expand}, mamba_d_state "
             f"{cfg.mamba_d_state}, mamba_d_conv {cfg.mamba_d_conv}: widths "
             "of at least 1 and a filter of at least 2 taps")
-    if cfg.attention_gate not in ("none", "sigmoid"):
+    if cfg.attention_gate not in ("none", "sigmoid", "sigmoid_head"):
         raise ValueError(f"attention_gate={cfg.attention_gate!r}; choose "
-                         "none | sigmoid")
+                         "none | sigmoid | sigmoid_head")
+    if cfg.expert_swiglu_limit or cfg.shared_expert_swiglu_limit:
+        raise NotImplementedError(
+            f"expert_swiglu_limit {cfg.expert_swiglu_limit} / "
+            f"shared_expert_swiglu_limit {cfg.shared_expert_swiglu_limit}: "
+            "the clamp of a gated expert's products has no published "
+            "equation here (parallel/moe.py, moe_share.py compute none: "
+            "ROADMAP R6); only a limit of 0 (no clamp) is served")
     if not cfg.layer_types:
         if cfg.num_dense_layers or cfg.dense_ffn_hidden_size:
             raise ValueError("num_dense_layers / dense_ffn_hidden_size "
@@ -600,9 +653,12 @@ def _check_mixed(cfg) -> None:
             f"layer_types with {' AND '.join(recurrent)} layers: no test "
             "covers a stack with two recurrent operators")
     kda = [n for n in ("kda_num_heads", "kda_head_dim", "kda_gate_rank",
-                       "kda_neg_eigval") if getattr(cfg, n)]
+                       "kda_neg_eigval", "kda_no_lora", "kda_safe_gate",
+                       "kda_lower_bound") if getattr(cfg, n)]
     if "kda" in cfg.layer_types:
-        if min(cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_gate_rank) < 1 \
+        # (full projections have no rank: ``kda_no_lora`` takes its place)
+        if min(cfg.kda_num_heads, cfg.kda_head_dim,
+               cfg.kda_gate_rank or cfg.kda_no_lora) < 1 \
                 or cfg.kda_conv_size < 2:
             raise ValueError(
                 f"layer_types with kda layers needs kda_num_heads "
@@ -610,6 +666,16 @@ def _check_mixed(cfg) -> None:
                 f"kda_gate_rank {cfg.kda_gate_rank} of at least 1 and "
                 f"kda_conv_size {cfg.kda_conv_size} of at least 2 (the "
                 "source states them)")
+        if cfg.kda_no_lora and cfg.kda_gate_rank:
+            raise ValueError(
+                f"kda_no_lora with kda_gate_rank {cfg.kda_gate_rank}: full "
+                "decay and gate projections have no rank")
+        if cfg.kda_safe_gate != (cfg.kda_lower_bound < 0) or (
+                cfg.kda_lower_bound > 0):
+            raise ValueError(
+                f"kda_safe_gate {cfg.kda_safe_gate} with kda_lower_bound "
+                f"{cfg.kda_lower_bound}: the bounded log decay lies in "
+                "(kda_lower_bound, 0), a bound below 0 that comes with it")
     elif kda:
         raise ValueError(f"{kda} without a kda layer")
     if "mamba" in cfg.layer_types:
@@ -636,7 +702,8 @@ def _check_mixed(cfg) -> None:
             ("sliding_window", cfg.sliding_window),
             ("rope_layout (rotating some layers and not others)", mixed_rope),
             # (the gate beside delta-rule layers is covered:
-            # tests/test_solar2_serving.py)
+            # tests/test_solar2_serving.py; one value a head over the
+            # latent layers beside them: tests/test_ling3_serving.py)
             ("attention_gate", cfg.attention_gate != "none"
              and "kda" not in cfg.layer_types),
             ("sandwich_norm", cfg.sandwich_norm),
@@ -711,6 +778,10 @@ def _check_latent(cfg) -> None:
                               "mla_scale_kv_lora") if getattr(cfg, n)]
         if widths:
             raise ValueError(f"{widths} without a latent_attention layer")
+        if cfg.attention_gate == "sigmoid_head":
+            raise NotImplementedError(
+                "attention_gate: sigmoid_head without latent_attention "
+                "layers beside kda layers (models/gpt/latent.py applies it)")
         _check_grouped_indexer(cfg)
         return
     if cfg.index_rope_section:
@@ -718,25 +789,49 @@ def _check_latent(cfg) -> None:
             "index_rope_section over latent attention: its indexer rotates "
             "a head's first qk_rope_head_dim columns by the rotary key's "
             "angles")
-    if set(cfg.layer_types) != {"latent_attention"}:
+    beside_kda = cfg.latent_beside_kda
+    if set(cfg.layer_types) != {"latent_attention"} and not beside_kda:
         raise NotImplementedError(
-            "layer_types with latent_attention beside another operator: no "
-            "test covers a stack that mixes them")
+            "layer_types with latent_attention beside another operator than "
+            "kda: no test covers a stack that mixes them (latent layers "
+            "beside delta-rule layers: tests/test_ling3_serving.py)")
+    sizes = (cfg.index_n_heads, cfg.index_head_dim, cfg.index_topk)
+    if beside_kda and (any(sizes) or cfg.moe_shortcut or cfg.mla_scale_q_lora
+                       or cfg.mla_scale_kv_lora):
+        raise NotImplementedError(
+            "a learned indexer, moe_shortcut or mla_scale_* over latent "
+            "layers beside kda layers: no test covers it")
+    # (beside delta-rule layers the queries may come straight from the
+    # normed input: there is then no query latent to state)
     missing = [n for n in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
                            "qk_rope_head_dim", "v_head_dim")
-               if not getattr(cfg, n)]
+               if not getattr(cfg, n)
+               and not (beside_kda and n == "q_lora_rank")]
     if missing or cfg.qk_rope_head_dim % 2:
         raise ValueError(
             f"latent_attention needs {missing or 'an even qk_rope_head_dim'} "
             "(the source states every width)")
-    if cfg.position_embedding != "rope" or cfg.qk_norm or cfg.kv_heads != (
+    if cfg.position_embedding != "rope" or cfg.kv_heads != (
             cfg.num_attention_heads):
         raise ValueError(
             "latent_attention takes position_embedding: rope (its rotary "
-            "key), no qk_norm and no grouped heads: the latent has no head")
+            "key) and no grouped heads: the latent has no head")
+    if cfg.qk_norm and not (beside_kda and cfg.qk_norm_scope == "head"
+                            and not cfg.q_lora_rank):
+        raise ValueError(
+            "qk_norm over latent attention: only the per-head norm of queries "
+            "made WITHOUT a query latent (qk_norm_scope: head, q_lora_rank "
+            "None) beside kda layers, with a norm of the shared rotary key; "
+            "a query latent has its own norm (q_a_norm) and the latent has "
+            "no head")
+    if (cfg.attention_gate != "none") != (
+            beside_kda and cfg.attention_gate == "sigmoid_head"):
+        raise NotImplementedError(
+            f"attention_gate {cfg.attention_gate!r} over latent attention: "
+            "the latent layers beside kda layers take sigmoid_head (one "
+            "value a head), and nothing else takes that")
     if cfg.rope_scaling_factor < 1.0:
         raise ValueError(f"rope_scaling_factor {cfg.rope_scaling_factor}")
-    sizes = (cfg.index_n_heads, cfg.index_head_dim, cfg.index_topk)
     if any(sizes) and (cfg.mla_scale_q_lora or cfg.mla_scale_kv_lora):
         raise NotImplementedError(
             "mla_scale_q_lora / mla_scale_kv_lora under a learned indexer "

@@ -214,16 +214,27 @@ class LatentAttention(nn.Module):
         h, qr, c = cfg.hidden_size, cfg.q_lora_rank, cfg.kv_lora_rank
         # declared in every phase: the layer loop applies one slice of the
         # stack to each
-        w_qa = self._weight("q_a_proj", (h, qr), ("embed", None))
-        w_qb = self._weight("q_b_proj", (qr, nh, nope + rot),
-                            (None, "heads", "kv"))
+        if qr:
+            w_qa = self._weight("q_a_proj", (h, qr), ("embed", None))
+            w_qb = self._weight("q_b_proj", (qr, nh, nope + rot),
+                                (None, "heads", "kv"))
+        else:  # no query latent (beside delta-rule layers)
+            w_q = self._weight("q_proj", (h, nh, nope + rot),
+                               ("embed", "heads", "kv"))
         w_kva = self._weight("kv_a_proj", (h, c + rot), ("embed", None))
         w_kvb = self._weight("kv_b_proj", (c, nh, nope + vd),
                              (None, "heads", "kv"))
         w_o = self._weight("out_proj", (nh, vd, h), ("heads", "kv", "embed"))
         s_q, s_kv = cfg.mla_scales
-        q_norm, kv_norm = (_latent_norm(cfg, "q_a_norm"),
-                           _latent_norm(cfg, "kv_a_norm", scaled=s_kv != 1.0))
+        kv_norm = _latent_norm(cfg, "kv_a_norm", scaled=s_kv != 1.0)
+        if qr:
+            q_norm = _latent_norm(cfg, "q_a_norm")
+        if cfg.qk_norm:  # a head's query, and the rotary key all heads share
+            q_head_norm, kr_norm = (_latent_norm(cfg, "q_norm"),
+                                    _latent_norm(cfg, "k_rope_norm"))
+        gated = cfg.attention_gate == "sigmoid_head"
+        if gated:
+            w_gate = self._weight("gate_proj", (h, nh), ("embed", "heads"))
         if cfg.indexed:
             ni, di = cfg.index_n_heads, cfg.index_head_dim
             w_iq = self._weight("index_q_proj", (qr, ni, di),
@@ -234,29 +245,45 @@ class LatentAttention(nn.Module):
                 epsilon=cfg.norm_eps, dtype=cfg.dtype,
                 param_dtype=jnp.float32, name="index_k_norm")
         if phase == "attend":
+            x, gate = x if gated else (x, None)
             with jax.named_scope("attn_full"):
                 out = self._cached(x, w_kvb, cache_positions, block_tables,
                                    layer_index)
+            out = _head_gated(out, gate)
             with jax.named_scope("mla_proj"):
                 return jnp.einsum("bshv,hvd->bsd", out, w_o)
         if rope is None:
             raise ValueError("latent attention rotates: it is handed the "
                              "angles (LatentStack computes them)")
         with jax.named_scope("mla_proj"):
-            c_q = q_norm(x @ w_qa)
-            if s_q == 1.0:
-                q = jnp.einsum("bsr,rhd->bshd", c_q, w_qb)
-            else:  # (scaled in float32, before the product is cast)
-                q = (jnp.einsum("bsr,rhd->bshd", c_q, w_qb,
-                                preferred_element_type=jnp.float32)
-                     * s_q).astype(cfg.dtype)
+            if not qr:
+                q = jnp.einsum("bsd,dhk->bshk", x, w_q)
+            else:
+                c_q = q_norm(x @ w_qa)
+                if s_q == 1.0:
+                    q = jnp.einsum("bsr,rhd->bshd", c_q, w_qb)
+                else:  # (scaled in float32, before the product is cast)
+                    q = (jnp.einsum("bsr,rhd->bshd", c_q, w_qb,
+                                    preferred_element_type=jnp.float32)
+                         * s_q).astype(cfg.dtype)
+            if cfg.qk_norm:
+                with jax.named_scope("mla_qk_norm"):
+                    q = q_head_norm(q)
             q = jnp.concatenate(
                 [q[..., :nope], apply_rope(q[..., nope:], rope)], axis=-1)
             latent = x @ w_kva
             ckv = kv_norm(latent[..., :c])
             if s_kv != 1.0:  # (the norm's float32 output: ONE rounding)
                 ckv = (ckv * s_kv).astype(cfg.dtype)
-            kr = _rotated_key(latent[..., c:], rope)
+            key = latent[..., c:]
+            if cfg.qk_norm:
+                with jax.named_scope("mla_qk_norm"):
+                    key = _normed_rotary_key(kr_norm, key)
+            kr = _rotated_key(key, rope)
+        gate = None
+        if gated:
+            with jax.named_scope("attn_gate"):
+                gate = x @ w_gate  # (from the normed input the queries read)
         if cfg.indexed:
             with jax.named_scope("dsa_index"):
                 qi = jnp.einsum("bsr,rhd->bshd", c_q, w_iq)
@@ -270,7 +297,9 @@ class LatentAttention(nn.Module):
                 kr, ((0, 0), (0, 0), (0, rope_leaf_width(cfg) - rot)))
             if cfg.indexed:  # the attend phase takes all three as its ``x``
                 return (q, qi, head_w), ckv, kr, ki
-            return q, ckv, kr
+            # (under the head-wise gate ``q`` carries the gate's rows to the
+            # attend phase)
+            return (q, gate) if gated else q, ckv, kr
         # no cache (or its init): every position at once, materialised
         s = x.shape[1]
         pos = jnp.arange(s)
@@ -296,6 +325,7 @@ class LatentAttention(nn.Module):
                                _NEG)
             probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
             out = jnp.einsum("bhst,bthv->bshv", probs, v)
+        out = _head_gated(out, gate)
         with jax.named_scope("mla_proj"):
             return jnp.einsum("bshv,hvd->bsd", out, w_o)
 
@@ -407,6 +437,24 @@ def _sparse_decode(cfg: GPTConfig, q_c, q_r, qi, w, pools, tables, end,
         (ckv, kr), compact = gather_rows((ckv_pool, kr_pool), tables, chosen)
     return (_decode(cfg, q_c, q_r, ckv, kr, compact, count, scale), scores,
             chosen)
+
+
+def _head_gated(out, gate):
+    """The heads' output ``[b, s, heads, v]`` times ``sigmoid(gate)`` ``[b,
+    s, heads]``, one value a head, the sigmoid in float32 (as it is without
+    one; a seam: ``perfbench/probe_ling3.py`` plants a fault here)."""
+    if gate is None:
+        return out
+    with jax.named_scope("attn_gate"):
+        return (out * jax.nn.sigmoid(gate.astype(jnp.float32))[..., None]
+                ).astype(out.dtype)
+
+
+def _normed_rotary_key(norm, key):
+    """The shared rotary key ``[b, s, rope]`` through its norm, before the
+    rotation (a seam: ``perfbench/probe_ling3.py`` leaves the norm out
+    here)."""
+    return norm(key)
 
 
 def _rotated_key(kr, rope):
@@ -561,6 +609,9 @@ class LatentStack(MixedStack):
             held["cached_index"] = self.variable(
                 "cache", "cached_index", jnp.zeros,
                 (1, ps, cfg.index_head_dim), cfg.dtype)
+        # beside delta-rule layers a lane holds their state too, ONCE A
+        # LANE, under column 0 of its block table (mixed_stack.py "State")
+        held.update(self._lane_leaves(plan["counts"], lanes))
         return None if fresh else held
 
     def _decoder_stack(self, x, params, cache, plan, kinds, *, rope,

@@ -411,13 +411,28 @@ def _low_rank(rank: int, width: int, name: str, dtype, bias_init=None):
     return apply
 
 
+def _log_decay(cfg, a_log, decay, by_head):
+    """The log decay ``by_head`` = ``[b, s, heads, d]`` float32 of the
+    projected ``decay`` ``[b, s, heads x d]`` (``dt_bias`` in it):
+    ``-exp(A_log[head]) softplus(decay)``, or under ``kda_safe_gate`` the
+    bounded ``kda_lower_bound * sigmoid(exp(A_log[head]) * decay)``, which
+    lies in ``(kda_lower_bound, 0)``."""
+    if cfg.kda_safe_gate:
+        return cfg.kda_lower_bound * jax.nn.sigmoid(
+            jnp.exp(a_log)[:, None] * decay.reshape(by_head))
+    return -jnp.exp(a_log)[:, None] * jax.nn.softplus(decay.reshape(by_head))
+
+
 class KDAMixer(nn.Module):
     """Kimi Delta Attention (a gated delta-rule linear attention), ``heads``
     = ``kda_num_heads`` of ``d`` = ``kda_head_dim``: ``[q~ | k~ | v~] =
     qkv_proj(a)``; each through a causal depthwise filter of
     ``kda_conv_size`` taps (no bias, one filter a channel and stream) and a
     SiLU; ``q = l2(q') d^-0.5``, ``k = l2(k')`` per head; the log decay ``g
-    = -exp(A_log[head]) softplus((a W_fa) W_fb + dt_bias)`` ``[heads, d]``;
+    = -exp(A_log[head]) softplus((a W_fa) W_fb + dt_bias)`` ``[heads, d]``
+    (:func:`_log_decay`: bounded under ``kda_safe_gate``; under
+    ``kda_no_lora`` the decay and the gate each through ONE full matrix,
+    ``f_proj`` with ``dt_bias`` and ``g_proj`` without a bias);
     ``beta = sigmoid(a W_b)`` ``[heads]``, doubled under ``kda_neg_eigval``;
     the delta rule (``ops/pallas/kda.py``); ``out_proj(rms_o(o) * sigmoid((a
     W_ga) W_gb + b_g))`` with ``rms_o``'s weight ``[d]``. The filters'
@@ -455,18 +470,27 @@ class KDAMixer(nn.Module):
                             * t.astype(jnp.float32)
                             for i, t in enumerate(taken))), 3, axis=-1))
         a32 = a.astype(jnp.float32)
-        decay = _low_rank(
-            cfg.kda_gate_rank, heads * d, "f", jnp.float32,
-            nn.with_logical_partitioning(_dt_bias_init, ("mlp",)))(a32)
+        dt_bias = nn.with_logical_partitioning(_dt_bias_init, ("mlp",))
+        if cfg.kda_no_lora:  # the decay through ONE full matrix
+            decay = nn.DenseGeneral(
+                heads * d, dtype=jnp.float32, param_dtype=jnp.float32,
+                bias_init=dt_bias, name="f_proj")(a32)
+        else:
+            decay = _low_rank(cfg.kda_gate_rank, heads * d, "f", jnp.float32,
+                              dt_bias)(a32)
         a_log = self.param("A_log", _kda_a_log_init, (heads,), jnp.float32)
         beta = jax.nn.sigmoid(nn.DenseGeneral(
             heads, use_bias=False, dtype=jnp.float32, param_dtype=jnp.float32,
             name="b_proj")(a32))
-        gate = _low_rank(cfg.kda_gate_rank, heads * d, "g", cfg.dtype)(a)
+        if cfg.kda_no_lora:  # (and the gate; no bias)
+            gate = _dense(heads * d, ("embed", "mlp"), "g_proj",
+                          use_bias=False, dtype=cfg.dtype)(a)
+        else:
+            gate = _low_rank(cfg.kda_gate_rank, heads * d, "g", cfg.dtype)(a)
         # (a served tree may hold every leaf in the compute dtype)
         mixed = {"q": _l2_normed(q) * d ** -0.5, "k": _l2_normed(k), "v": v,
-                 "g": (-jnp.exp(a_log.astype(jnp.float32))[:, None]
-                       * jax.nn.softplus(decay.reshape(b, s, heads, d))),
+                 "g": _log_decay(cfg, a_log.astype(jnp.float32), decay,
+                                 (b, s, heads, d)),
                  "beta": beta * 2.0 if cfg.kda_neg_eigval else beta,
                  "gate": gate, **kept}
         if phase == "project":
@@ -638,6 +662,24 @@ class MixedStack(nn.Module):
             held["cached_index"] = self.variable(
                 "cache", "cached_index", jnp.zeros,
                 (1, ps, index_leaf_width(cfg)), cfg.dtype)
+        held.update(self._lane_leaves(counts, lanes))
+        if counts["conv"]:
+            held["conv_state"] = self.variable(
+                "cache", "conv_state", jnp.zeros,
+                (counts["conv"] * cfg.decode_num_pages, rows,
+                 cfg.hidden_size), cfg.dtype)
+        held.update({
+            "moe_stats": self.variable(
+                "cache", "moe_stats", jnp.zeros,
+                (max(counts["experts"], 1), stats_words(cfg)), jnp.uint32),
+        })
+        return None if fresh else held
+
+    def _lane_leaves(self, counts: dict, lanes: int) -> dict:
+        """The leaves of the state a lane holds ONCE A LANE: the
+        selective-scan layers' or the delta-rule layers' (none without
+        either)."""
+        cfg, held = self.cfg, {}
         if counts["mamba"]:
             # [d_state, inner] and [lanes, rows x inner]: no axis of 16 or of
             # 3 in the last two places, which the device's tiles would pad;
@@ -661,17 +703,7 @@ class MixedStack(nn.Module):
                 "cache", "kda_conv", jnp.zeros,
                 (n, lanes, (cfg.kda_conv_size - 1) * 3 * cfg.kda_inner),
                 cfg.dtype)
-        elif counts["conv"]:
-            held["conv_state"] = self.variable(
-                "cache", "conv_state", jnp.zeros,
-                (counts["conv"] * cfg.decode_num_pages, rows,
-                 cfg.hidden_size), cfg.dtype)
-        held.update({
-            "moe_stats": self.variable(
-                "cache", "moe_stats", jnp.zeros,
-                (max(counts["experts"], 1), stats_words(cfg)), jnp.uint32),
-        })
-        return None if fresh else held
+        return held
 
     def _decoder_stack(self, x, params, cache, plan, kinds, *, rows, key_mask,
                        deterministic, cache_positions, block_tables, rope):

@@ -70,8 +70,9 @@ from fleetx_tpu.parallel.moe import (
     _running_count,
 )
 
-__all__ = ["SharedMoEMLP", "ZERO_WORDS", "group_limited_topk",
-           "held_row_layout", "stats_words", "zero_counters"]
+__all__ = ["GROUP_WORDS", "SharedMoEMLP", "ZERO_WORDS", "extra_counters",
+           "group_limited_topk", "held_row_layout", "stats_words",
+           "zero_counters"]
 
 # the words a configuration with zero-compute experts adds to a layer's
 # ``moe_stats``, after ``MOE_STATS``'s: the zero pairs of ticks and of
@@ -81,9 +82,37 @@ __all__ = ["SharedMoEMLP", "ZERO_WORDS", "group_limited_topk",
 ZERO_WORDS = 6
 
 
+# the words a share that is exactly ONE ROUTER GROUP (``cfg.held_group``)
+# adds after those: the rows of ticks that chose an expert of the held
+# group, the tokens a deployment would send this chip (low word, high word)
+GROUP_WORDS = 2
+
+
 def stats_words(cfg) -> int:
     """Words of one layer's ``moe_stats``."""
-    return 2 * len(MOE_STATS) * 2 + (ZERO_WORDS if cfg.num_zero_experts else 0)
+    return (2 * len(MOE_STATS) * 2
+            + (ZERO_WORDS if cfg.num_zero_experts else 0)
+            + (GROUP_WORDS if cfg.held_group is not None else 0))
+
+
+def extra_counters(cfg, words) -> dict:
+    """The counters of the words ``[layers, ...]`` behind ``MOE_STATS``'s
+    (``serving/model_protocol.py`` adds them): the zero-compute experts'
+    (:func:`zero_counters`), then a held group's ``moe_tick_group_tokens``,
+    the rows of ticks (free lanes among them, as in ``moe_tick_pairs``) with
+    at least one chosen expert in the group held here: the pairs they
+    brought are ``moe_tick_pairs``."""
+    import numpy as np
+
+    out, at = {}, 0
+    if cfg.num_zero_experts:
+        out.update(zero_counters(words[:, :ZERO_WORDS], int(cfg.top_k)))
+        at = ZERO_WORDS
+    if cfg.held_group is not None:
+        group = np.asarray(words[:, at:at + GROUP_WORDS]).astype(np.uint64)
+        out["moe_tick_group_tokens"] = int(
+            (group[:, 0] + (group[:, 1] << np.uint64(32))).sum())
+    return out
 
 
 def zero_counters(words, top_k: int) -> dict:
@@ -261,6 +290,8 @@ class SharedMoEMLP(DroplessMoEMLP):
         if cfg.num_zero_experts:
             zero = topk_idx >= routed
             self._count_zero(zero, s, decode, layer_index)
+        if cfg.held_group is not None:
+            self._count_group(held, s, decode, layer_index)
         with jax.named_scope("moe_experts"):
             if kernel:
                 w_gate, w_up, w_down = (w.astype(dt) for w in expert_stack)
@@ -319,3 +350,21 @@ class SharedMoEMLP(DroplessMoEMLP):
                     jnp.uint32(zero.shape[-1]) - each.min()).at[word(5)].max(
                         each.max())
             self.put_variable("cache", "moe_stats", value)
+
+    def _count_group(self, held, seq: int, decode: bool, layer_index):
+        """Add a tick's rows that chose an expert of the held group
+        (``held`` ``[n, k]`` bool: the pairs laid out here) to the layer's
+        ``GROUP_WORDS`` of ``moe_stats``, as ``_count`` adds its own."""
+        if not decode or seq != 1 or not self.has_variable("cache",
+                                                           "moe_stats"):
+            return
+        stats = self.get_variable("cache", "moe_stats")
+        layer = () if layer_index is None else (layer_index,)
+        low = (*layer, stats_words(self.cfg) - GROUP_WORDS)
+        high = (*layer, stats_words(self.cfg) - GROUP_WORDS + 1)
+        with jax.named_scope("moe_route"):
+            was = stats[low]
+            now = was + held.any(-1).sum().astype(jnp.uint32)
+            # (wraps at 2**32 into the high word, as ``_count_zero``'s)
+            self.put_variable("cache", "moe_stats", stats.at[low].set(
+                now).at[high].add((now < was).astype(jnp.uint32)))

@@ -1504,7 +1504,12 @@ class PagedKVCacheManager(_LaneBook):
                 "state_bytes_lanes": self.slots * self.lane_bytes,
                 f"{self.lane_state_kind}_state_resets": self.state_resets,
                 "kv_page_bytes_in_use": (self.pool.pages_in_use
-                                         * self.page_bytes["kv"])}
+                                         * self.page_bytes["kv"]),
+                # the OTHER home of such a lane's state, where its pool's
+                # rows are latents (a lane with two homes)
+                **({"latent_pages_in_use": self.pool.pages_in_use,
+                    "latent_page_bytes": self.page_bytes["kv"]}
+                   if "latent" in self.state_kinds else {})}
         if "latent" in self.state_kinds:
             # one class of page, whose rows are latents: what is pinned by
             # live requests, and what the trie holds for a later match

@@ -988,7 +988,10 @@ class ServingMetrics:
             "tick_ms_p50": _ms(tick_p50),
             "tick_ms_p99": _ms(tick_p99),
             # what the model's programs counted on the device (an expert
-            # model's routing: ``moe_*``), fetched here and nowhere else
+            # model's routing: ``moe_*``; where the share held is one whole
+            # router group also ``moe_tick_group_tokens``, the ticks' rows
+            # that chose an expert of it, beside the pairs they brought,
+            # ``moe_tick_pairs``), fetched here and nowhere else
             **on_device,
         }
 

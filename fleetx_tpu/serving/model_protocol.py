@@ -327,7 +327,8 @@ class GPTExecutor(ModelExecutor):
         if not leaves:
             return {}
         base = 2 * len(MOE_STATS) * 2      # (a leaf's first words; the rest
-        # are the zero-compute experts', parallel/moe_share.py)
+        # are the zero-compute experts' and a held group's,
+        # parallel/moe_share.py)
         leaves = [np.asarray(leaf).reshape(-1, leaf.shape[-1])
                   for leaf in leaves]
         words = np.concatenate([leaf[:, :base].astype(np.uint64).reshape(
@@ -351,11 +352,11 @@ class GPTExecutor(ModelExecutor):
             out[f"moe_{kind}_load_max_over_mean"] = (
                 largest * experts / pairs if pairs else 0.0)
         if leaves[0].shape[1] > base:
-            from fleetx_tpu.parallel.moe_share import zero_counters
+            from fleetx_tpu.parallel.moe_share import extra_counters
 
-            out.update(zero_counters(
-                np.concatenate([leaf[:, base:] for leaf in leaves]),
-                int(self.model.cfg.top_k)))
+            out.update(extra_counters(
+                self.model.cfg,
+                np.concatenate([leaf[:, base:] for leaf in leaves])))
         return out
 
     def resident_params(self, params):

@@ -38,6 +38,7 @@ _EXPLICIT = {
     "serve_trinity_large_ep8_l5.yaml": 1,  # one chip's share of eight
     "serve_longcat_flash_ep32_l4.yaml": 1,  # one chip's share of thirty-two
     "serve_solar_open2_ep16_l8.yaml": 1,  # one chip's share of sixteen
+    "serve_ling3_flash_ep8_l7.yaml": 1,   # one chip's share of eight
     "serve_keye_vl2_30b_l6.yaml": 1,  # one pipeline stage of eight
 }
 
