@@ -205,6 +205,75 @@ def test_select_rows_is_the_stable_sorts_top_k(k):
     assert (got.sum(-1) == np.minimum(valid.sum(-1), k)).all()
 
 
+def stable_top_rows(scores, end, k):
+    """numpy: of each lane's rows ``[0, end)`` the ``k`` highest by a STABLE
+    sort (a tie to the lower position), in position order; ``t`` behind."""
+    lanes, t = scores.shape
+    out = np.full((lanes, k), t, np.int32)
+    for lane in range(lanes):
+        best = np.argsort(-scores[lane, :end[lane]], kind="stable")[:k]
+        out[lane, :len(best)] = np.sort(best)
+    return out
+
+
+@pytest.mark.parametrize("t,k", [
+    (256, 24), (1040, 64), (384, 1), (256, 256), (1040, 1040), (128, 200),
+    (1040, 1100)], ids=lambda v: str(v))
+def test_top_rows_is_the_stable_sorts_top_k_in_position_order(t, k):
+    """A table of whole 128-row blocks and not (1,040), ``k`` below, at and
+    above ``t``; lanes that hold 0, 1, ``k - 1``, ``k``, ``k + 1`` and ``t``
+    rows, then one of equal scores, one with ties across the ``k``-th
+    place, one of zeros of both signs, one of negative scores."""
+    rng = np.random.default_rng(t + k)
+    scores = rng.normal(size=(10, t)).astype(np.float32)
+    end = np.clip([0, 1, k - 1, k, k + 1, t, t, t, t, t - 3], 0, t).astype(
+        np.int32)
+    scores[6] = 0.5
+    scores[7, ::3] = np.sort(scores[7])[-min(k, t)]
+    scores[8] = np.abs(scores[8])
+    scores[8, ::2], scores[8, 1::4] = 0.0, -0.0
+    scores[9] = -np.abs(scores[9])
+    scores[9, 5::7] = scores[9, 5]
+    chosen, count = jax.jit(latent.top_rows, static_argnums=3)(
+        jnp.asarray(scores), jnp.arange(t)[None, :] < end[:, None],
+        jnp.asarray(end), k)
+    assert chosen.dtype == jnp.int32 and chosen.shape == (10, k)
+    assert (np.asarray(count) == np.minimum(end, k)).all()
+    # (position for position, the ``t`` of every place past ``count`` too)
+    assert (np.asarray(chosen) == stable_top_rows(scores, end, k)).all()
+
+
+def sorting_primitives(jaxpr):
+    """The equations of ``jaxpr``, and of every program nested in it, that
+    sort, take a top-k or scatter (``jnp.argsort`` is a ``sort``)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if any(word in eqn.primitive.name
+               for word in ("sort", "top_k", "scatter")):
+            found.append(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += sorting_primitives(sub)
+    return found
+
+
+# the tick of the tiny preset (perfbench/cells/dsv32-l5-serve-longqa-sparse
+# .json ``tiny``: 3 lanes of 256 rows, ``index_topk`` 24) and the cell's own
+@pytest.mark.parametrize("lanes,t,k", [(3, 256, 24), (6, 50176, 2048)])
+def test_a_ticks_selection_holds_no_sort(lanes, t, k):
+    def call(scores, end):
+        seen = jnp.arange(t, dtype=jnp.int32)[None, :] < end[:, None]
+        return latent.top_rows(scores, seen, end, k)
+
+    jaxpr = jax.make_jaxpr(call)(
+        jax.ShapeDtypeStruct((lanes, t), jnp.float32),
+        jax.ShapeDtypeStruct((lanes,), jnp.int32))
+    assert sorting_primitives(jaxpr.jaxpr) == []
+    # (the reader reads what it is meant to: the form this one replaced)
+    assert sorting_primitives(jax.make_jaxpr(
+        lambda x: jnp.sort(jax.lax.top_k(x, 2)[1]))(jnp.zeros(8)).jaxpr
+    ) == ["top_k", "sort"]
+
+
 def test_the_chunk_kernel_under_a_mask_is_its_plain_twin(monkeypatch):
     from fleetx_tpu.ops.pallas import mla_prefill
 

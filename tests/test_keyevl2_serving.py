@@ -252,6 +252,27 @@ def test_the_indexers_functions_exist_once():
                        indexer._chunk_index_scores(qi, w, ki, 11))
 
 
+# the tick of the tiny preset (perfbench/cells/keyevl2-l6-serve-pagesqa-
+# sparse.json ``tiny``: 3 lanes of 256 rows, ``index_topk`` 24) and the
+# cell's own, as ``hybrid.py`` calls it
+@pytest.mark.parametrize("lanes,t,k", [(3, 256, 24), (5, 33792, 2048)])
+def test_a_ticks_selection_holds_no_sort(lanes, t, k):
+    from tests.test_dsv32_serving import sorting_primitives
+
+    def call(scores, end):
+        seen = jnp.arange(t, dtype=jnp.int32)[None, :] < end[:, None]
+        return indexer.top_rows(scores, indexer._visible(seen), end,
+                                min(k, t))
+
+    jaxpr = jax.make_jaxpr(call)(
+        jax.ShapeDtypeStruct((lanes, t), jnp.float32),
+        jax.ShapeDtypeStruct((lanes,), jnp.int32))
+    assert sorting_primitives(jaxpr.jaxpr) == []
+    chosen, count = jaxpr.out_avals
+    assert (chosen.shape, chosen.dtype) == ((lanes, k), jnp.int32)
+    assert count.shape == (lanes,)
+
+
 def test_the_cache_has_three_leaves_under_one_table(built):
     engine = built[2]
     shapes = {path[-1].key: leaf.shape for path, leaf in
