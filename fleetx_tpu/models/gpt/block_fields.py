@@ -29,7 +29,8 @@ import numpy as np
 
 __all__ = ["BlockLayoutFields", "LANE_STATE_LEAVES", "LAYOUT_FIELDS",
            "LAYER_TYPES", "RECURRENT_TYPES", "VISION_FIELDS", "check",
-           "fold_mrope", "layer_class", "rows_in", "stack_of"]
+           "fold_mrope", "layer_class", "rows_in", "stack_of",
+           "unit_offset_norm"]
 
 # per-layer lists (a YAML or JSON list becomes a tuple: the configuration is
 # a module attribute and has to hash)
@@ -251,6 +252,35 @@ class BlockLayoutFields:
     # equation for it is published (ROADMAP R6)
     expert_swiglu_limit: float = 0.0
     shared_expert_swiglu_limit: float = 0.0
+    # ---- EVA attention (models/gpt/eva.py; every layer then is one):
+    # position ``p`` sees the rows of its own TUMBLING window ``[W * (p //
+    # W), p]`` exactly (``eva_window_size`` W) and, of every window before
+    # it, ONE pooled key/value row for each chunk of ``eva_chunk_size`` rows
+    # (two learned vectors a head, ``eva_mu`` and ``eva_phi``, pool a chunk
+    # when its last row exists), in one softmax. Serving keeps two classes
+    # of page for it, "summary" and "window"
+    # (serving/cache_manager.py "EVA's two classes")
+    eva_chunk_size: int = 0
+    eva_window_size: int = 0
+    # the residual stream's dtype where it is not the compute dtype
+    # ("float32": the blocks' sums in float32 under bfloat16 weights)
+    residual_dtype: Optional[str] = None
+    # RMSNorm as ``x^ * (1 + w)`` (the weight is the offset from one)
+    norm_unit_offset: bool = False
+    # prediction heads in ONE untied ``lm_head`` ``[heads * vocab, hidden]``:
+    # columns ``[vocab * j, vocab * (j + 1))`` predict the token at ``t + 1 +
+    # j``; serving samples head 0 (``GPTExecutor.forward``)
+    num_pred_heads: int = 1
+
+    @property
+    def eva(self) -> bool:
+        """Whether the attention layers are EVA's."""
+        return self.eva_window_size > 0
+
+    @property
+    def head_rows(self) -> int:
+        """Rows of the output head's table: every prediction head's."""
+        return self.vocab_size * self.num_pred_heads
 
     @property
     def kv_heads(self) -> int:
@@ -353,7 +383,8 @@ class BlockLayoutFields:
         return bool(self.kv_heads != self.num_attention_heads
                     or self.head_size or self.sliding_window
                     or self.rope_layout or self.router_input != "mlp_norm"
-                    or self.layer_types or self.qk_norm_scope != "projection")
+                    or self.layer_types or self.qk_norm_scope != "projection"
+                    or self.eva)
 
     @property
     def state_kinds(self) -> Tuple[str, ...]:
@@ -386,6 +417,47 @@ class BlockLayoutFields:
             return {}
         return {"pairs": rows * self.top_k * self.expert_layers}
 
+    @property
+    def eva_composed_pages(self) -> int:
+        """Pages of a lane's COMPOSED table under EVA (models/gpt/eva.py):
+        the summary pages of every window a cache row can have behind it,
+        then one window's pages."""
+        ps, held = self.decode_page_size, self.decode_cache_len
+        per_window = self.eva_window_size // self.eva_chunk_size // ps
+        return ((held - 1) // self.eva_window_size * per_window
+                + self.eva_window_size // ps)
+
+    def eva_rows(self, pos):
+        """``(window rows, summary rows)`` a query at position ``pos`` (an
+        int or an array of them) attends over in ONE EVA layer: the exact
+        rows of its own window up to itself, and one pooled row for every
+        chunk of the windows before."""
+        pos = np.asarray(pos)
+        window = self.eva_window_size
+        return (pos % window + 1,
+                pos // window * (window // self.eva_chunk_size))
+
+    def eva_spans(self, pos, rows=None) -> dict:
+        """Span fields of a program over EVA layers. A tick (``pos``: its
+        lanes' positions): the rows ONE layer attends over, summed over
+        the lanes, the positions they stand at (what full attention would
+        read), and the chunks the tick closes. A chunk of ``rows`` rows
+        behind ``pos``: the rows its queries attend over TOGETHER (the last
+        query's: what the chunk reads), and the chunks it closes."""
+        chunk = self.eva_chunk_size
+        if rows is None:
+            pos = np.asarray(pos)
+            exact, pooled = self.eva_rows(pos)
+            closed = int((pos % chunk == chunk - 1).sum())
+            at = int((pos + 1).sum())
+        else:
+            exact, pooled = self.eva_rows(pos + rows - 1)
+            closed = (pos + rows) // chunk - pos // chunk
+            at = pos + rows
+        return {"eva_window_rows": int(np.sum(exact)),
+                "eva_summary_rows": int(np.sum(pooled)),
+                "eva_positions": at, "eva_chunks_closed": closed}
+
     def decode_kernel_steps(self, lanes: int, head_shards: int = 1) -> int:
         """Span field ``kernel_steps`` of a decode tick of ``lanes`` lanes:
         the grid steps of the kernel ``fleetx_decode_paged`` over ALL the
@@ -411,6 +483,8 @@ class BlockLayoutFields:
         kinds = self.of_attention_layers(self.window_layers)
         # (under an indexer the kernel walks the compact pool of chosen rows)
         held = self.selected(self.decode_cache_len)
+        if self.eva:  # (the kernel walks a lane's composed table)
+            held = self.eva_composed_pages * ps
         return lanes * sum(
             paged_grid(pools, -(-int(held) // ps),
                        max_live=self.sliding_window if windowed else None)[1]
@@ -426,6 +500,8 @@ class BlockLayoutFields:
         attention layers of a shape the kernel ``fleetx_prefill_gqa``
         takes: the key rows its live steps cover in one full and one window
         layer (``hybrid.chunk_key_rows``)."""
+        if self.eva:
+            return self.eva_spans(behind, rows)
         if not self.latent:
             if not self.layer_kinds:
                 return {}
@@ -515,6 +591,7 @@ def check(cfg) -> None:
             dict(cfg.vision).items())) or None)
     _check_mixed(cfg)
     _check_positions_and_tower(cfg)
+    _check_eva(cfg)
     if cfg.router_input not in ("mlp_norm", "block_input"):
         raise ValueError(f"router_input={cfg.router_input!r}; choose "
                          "mlp_norm | block_input")
@@ -882,6 +959,55 @@ def _check_grouped_indexer(cfg) -> None:
                          "index_rope_section (its pairs' axes)")
 
 
+def _check_eva(cfg) -> None:
+    """EVA attention's fields and what rides with the configuration that
+    has them (a float32 stream, the unit-offset norm, several prediction
+    heads), each refusal with the field's name."""
+    if cfg.residual_dtype not in (None, "float32"):
+        raise ValueError(f"residual_dtype={cfg.residual_dtype!r}; choose "
+                         "float32 (None: the compute dtype)")
+    if cfg.residual_dtype and cfg.layer_types:
+        raise NotImplementedError(
+            "residual_dtype with layer_types: the stack of "
+            "models/gpt/mixed_stack.py carries the stream in the compute "
+            "dtype; no test covers another")
+    if cfg.norm_unit_offset and cfg.norm != "rmsnorm":
+        raise ValueError("norm_unit_offset without norm: rmsnorm")
+    if cfg.num_pred_heads < 1 or (cfg.num_pred_heads > 1
+                                  and cfg.tie_word_embeddings):
+        raise ValueError(
+            f"num_pred_heads {cfg.num_pred_heads}: at least 1, and more than "
+            "one only in an untied head (tie_word_embeddings: False): the "
+            "word table has one row a token")
+    chunk, window = cfg.eva_chunk_size, cfg.eva_window_size
+    if not (chunk or window):
+        return
+    if min(chunk, window) < 1 or window % chunk:
+        raise ValueError(
+            f"eva_chunk_size {chunk} and eva_window_size {window} come "
+            "together, whole chunks to a window")
+    beside = [n for n, on in (
+        ("sliding_window", cfg.sliding_window),
+        ("num_key_value_heads (grouped heads)",
+         cfg.kv_heads != cfg.num_attention_heads),
+        ("a latent (kv_lora_rank)", cfg.kv_lora_rank),
+        ("an indexer (index_topk)", cfg.index_topk),
+        ("layer_types", cfg.layer_types),
+        ("qk_norm", cfg.qk_norm),
+        ("an expert layer", cfg.expert_mode),
+        ("rope_layout", cfg.rope_layout)) if on]
+    if beside:
+        raise NotImplementedError(
+            f"EVA attention (eva_window_size) beside {beside}: nobody wrote "
+            "the pooled rows of a chunk for them (models/gpt/eva.py: as many "
+            "key heads as query heads, every layer alike)")
+    if cfg.position_embedding != "rope" or cfg.use_bias:
+        raise NotImplementedError(
+            "EVA attention takes position_embedding: rope (its keys are "
+            "pooled after the rotation) and no biases: what "
+            "tests/test_evabyte_serving.py covers")
+
+
 VISION_FIELDS = ("hidden_size", "num_layers", "num_heads",
                  "intermediate_size", "patch_size", "grid", "merge",
                  "image_token_id")
@@ -962,6 +1088,14 @@ def stack_of(model):
     latent attention its subclass ``latent.LatentStack``, which hands the
     same body the latent operator and its two leaves)."""
     if not model.cfg.layer_types:
+        if model.cfg.residual_dtype:
+            # the stream enters the layer loop in its own dtype (a row of
+            # the word table is exact in the compute dtype its weights have)
+            import jax.numpy as jnp
+
+            return lambda x, *args, **kwargs: model._decoder_stack(
+                x.astype(jnp.dtype(model.cfg.residual_dtype)), *args,
+                **kwargs)
         return model._decoder_stack
     if model.cfg.latent:
         from fleetx_tpu.models.gpt.latent import LatentStack
@@ -970,6 +1104,14 @@ def stack_of(model):
     from fleetx_tpu.models.gpt.mixed_stack import MixedStack
 
     return MixedStack(model.cfg, name="layers")
+
+
+def unit_offset_norm(cfg, name):
+    """RMSNorm as ``x^ * (1 + w)`` (``norm_unit_offset``; the module is
+    ``models/gpt/eva.py``'s)."""
+    from fleetx_tpu.models.gpt.eva import UnitOffsetRMSNorm
+
+    return UnitOffsetRMSNorm(cfg.norm_eps, cfg.dtype, name=name)
 
 
 def layer_class(cfg, default):

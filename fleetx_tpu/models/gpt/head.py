@@ -31,7 +31,9 @@ def _head(cfg, params, hidden):
     """``GPTForPretraining``'s head on ``hidden`` ``[b, rows, hidden]``:
     float32 logits ``[b, rows, vocab]`` from the tied word table or the
     untied ``lm_head`` (sharded over the vocabulary under a mesh: the one
-    product either way)."""
+    product either way; ``num_pred_heads`` heads side by side in one table
+    give ``[b, rows, heads * vocab]``, head ``j`` in columns ``[vocab * j,
+    vocab * (j + 1))``)."""
     emb = nn.meta.unbox(params["gpt"]["word_embeddings"]
                         if cfg.tie_word_embeddings else params["lm_head"])
     return jnp.einsum(
@@ -70,7 +72,7 @@ def row_logits_step(model, params, cache, input_ids, position_ids,
         logits = jax.lax.cond(
             jnp.any(rows >= 0),
             lambda row: _head(model.cfg, params, row),
-            lambda row: jnp.zeros((*row.shape[:2], model.cfg.vocab_size),
+            lambda row: jnp.zeros((*row.shape[:2], model.cfg.head_rows),
                                   jnp.float32),
             picked)
     return logits, {**cache, "gpt": mut["cache"]}

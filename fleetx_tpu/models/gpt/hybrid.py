@@ -111,6 +111,13 @@ def index_leaf_width(cfg: GPTConfig) -> int:
 def _pages_of(cfg: GPTConfig):
     """Pages of every layer in the flat pool, by the layer's kind."""
     full, window = cfg.decode_num_pages, cfg.decode_window_pages
+    if cfg.eva:  # every layer holds both classes: summary, then window
+        if window is None:
+            raise ValueError(
+                "a paged decode cache over EVA layers needs "
+                "decode_window_pages beside decode_num_pages (the summary "
+                "class's; the serving engine sets both)")
+        return [full + window] * cfg.num_layers
     if any(cfg.window_layers) and window is None:
         raise ValueError(
             "a paged decode cache over window layers needs "
@@ -293,6 +300,7 @@ class HybridSelfAttention(SelfAttention):
         if gated:
             with jax.named_scope("attn_gate"):
                 gate = proj((nh, hd), name="gate_proj")(_gate_reads(x))
+        k_raw = k
         if rope is not None and any(cfg.rope_layers):
             rotates = jnp.asarray(cfg.of_attention_layers(cfg.rope_layers),
                                   bool)[layer_index]
@@ -300,6 +308,11 @@ class HybridSelfAttention(SelfAttention):
             k = jnp.where(rotates, apply_rope(k, rope[:2]), k)
         b, s = q.shape[:2]
         k, v = k.reshape(b, s, kvh * hd), v.reshape(b, s, kvh * hd)
+        if cfg.eva:
+            return self._out_proj(checkpoint_name(self._eva_attention(
+                q, k, v, k_raw.reshape(k.shape), attn_mask, decode,
+                cache_positions, block_tables, layer_index, deterministic),
+                "core_attn_out"))
         if cfg.indexed:
             qi, ki, head_w = self._index_projections(x, rope)
             if phase == "project":  # (the third leaf's rows, as it holds them)
@@ -335,6 +348,31 @@ class HybridSelfAttention(SelfAttention):
             out = grouped_attention(q, k, v, allowed[None, None])
         out = checkpoint_name(out, "core_attn_out")
         return self._out_proj(self._gate(out, gate))
+
+    def _eva_attention(self, q, k, v, k_raw, rows, decode, cache_positions,
+                       block_tables, layer_index, deterministic):
+        """EVA attention (``models/gpt/eva.py``): through the two classes of
+        page in a cached forward, whose ``attn_mask`` ``rows`` says which
+        rows are tokens; every position at once outside the cache (and at
+        its init)."""
+        from fleetx_tpu.models.gpt import eva
+
+        cfg = self.cfg
+        mu, phi = eva.pool_vectors(self)
+        out = None
+        if decode:
+            if cfg.decode_num_pages is None:
+                raise NotImplementedError(
+                    "a contiguous decode cache over EVA layers (one-shot "
+                    "generate()): serve the model through ServingEngine, "
+                    "whose page pool holds both classes")
+            out = eva.paged_attention(
+                self, q, k, v, k_raw, cache_positions, block_tables,
+                layer_index, rows, mu, phi, deterministic)
+        if out is None:  # no cache (or its init): every position at once
+            out = eva.dense_attention(cfg, q, k, v, k_raw, mu, phi,
+                                      None if decode else rows)
+        return out
 
     def _index_projections(self, x, rope):
         """``(qI, kI, wI)`` of the layer's normed input ``x`` (module

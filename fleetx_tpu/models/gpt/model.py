@@ -781,9 +781,9 @@ def apply_rope(x, rope):
 
 
 def _layer_norm(cfg, name):
-    # ERNIE and ViT call this with configurations of their own, which
-    # have no ``norm`` kind: LayerNorm at 1e-5, as ever
-    eps = getattr(cfg, "norm_eps", 1e-5)
+    if getattr(cfg, "norm_unit_offset", False):  # (kept to the lines it had)
+        return block_fields.unit_offset_norm(cfg, name)
+    eps = getattr(cfg, "norm_eps", 1e-5)  # (ERNIE, ViT: no ``norm`` kind)
     if getattr(cfg, "norm", "layernorm") == "rmsnorm":
         return nn.RMSNorm(
             epsilon=eps, dtype=cfg.dtype, param_dtype=jnp.float32,
@@ -1071,7 +1071,7 @@ class GPTForPretraining(nn.Module):
                 nn.with_logical_partitioning(
                     nn.initializers.normal(self.cfg.initializer_range),
                     ("vocab", "embed")),
-                (self.cfg.vocab_size, self.cfg.hidden_size), jnp.float32)
+                (self.cfg.head_rows, self.cfg.hidden_size), jnp.float32)
         if labels is not None and self.cfg.fused_ce:
             # blockwise fused LM-head + CE: returns PER-TOKEN loss [b, s]
             # (callers apply loss_mask); the [b, s, vocab] logits never

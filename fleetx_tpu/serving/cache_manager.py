@@ -63,6 +63,22 @@ class allocates a lane's pages as its prefill chunks and decode ticks reach
 them and releases each page once every row of it lies behind the window of
 the lane's next query. Admission counts both classes.
 
+EVA's two classes (a model with EVA attention, ``models/gpt/eva.py``): every
+layer keeps BOTH. The class :class:`PagePool` holds is the SUMMARY class:
+one pooled row for every chunk of ``eva_chunk_size`` positions, its table
+addressed by chunk index, so the manager asks the pool for ``position //
+chunk`` wherever it asks another model's for a position (``_rows``;
+admission allocates the prompt's whole chunks, a tick's ``ensure_page`` the
+row of the chunk it stands in, whose pooled row is held from then on but
+attended only by queries of LATER windows). The WINDOW class is
+:class:`WindowPagePool` with ``tumbling``: its table is addressed by
+position, a lane holds the exact rows of its current window alone and gives
+every page back at once when it crosses a multiple of ``eva_window_size``.
+``tables`` is ``[2, lanes, pages of a row]``, summary (zeros behind its
+fewer entries) then window; the model composes the two (``eva.py``).
+Admission, ``free``, preemption and recovery move both, and a page is one
+chunk.
+
 Kinds of state, and their two homes (a model with recurrent layers,
 ``models/gpt/mixed_stack.py``). IN THE POOL (gated short-convolution
 layers): beside the keys and values of its attention
@@ -1156,20 +1172,37 @@ class WindowPagePool:
     model's ``starts`` keeps outside every read. So a lane never holds more
     than ``window + n`` tokens rounded out to pages (:attr:`lane_pages`
     for ``n`` up to ``span``), and a pool of ``lanes * lane_pages + 1``
-    pages cannot run dry. Page 0 is the trash page."""
+    pages cannot run dry. Page 0 is the trash page.
+
+    ``tumbling`` (EVA attention, module docstring "EVA's two classes"): the
+    window does not slide. The query at ``pos`` sees the rows ``[window *
+    (pos // window), pos]``, so ``prepare`` releases ALL the lane's pages at
+    once when ``pos`` crosses a multiple of ``window`` (counted in
+    :attr:`tumbled`) and a lane never holds more than ``window // page_size``
+    pages."""
 
     def __init__(self, num_pages: int, page_size: int, lanes: int,
-                 table_pages: int, window: int, span: int):
+                 table_pages: int, window: int, span: int,
+                 tumbling: bool = False):
         if window < 1 or span < 1:
             raise ValueError(f"window {window} and span {span} must be "
                              "positive")
+        if tumbling and (window % page_size or window % span):
+            raise ValueError(
+                f"a tumbling window of {window} rows holds whole pages "
+                f"({page_size}) and whole spans ({span}): a program never "
+                "straddles a boundary")
         self.num_pages = num_pages
         self.page_size = page_size
         self.lanes = lanes
         self.window = window
         self.span = span
-        self.lane_pages = min(table_pages,
-                              window_lane_pages(window, span, page_size))
+        self.tumbling = tumbling
+        self.tumbled = 0      # windows released whole, ever (tumbling)
+        # (a tumbling window's rows ``[window * (pos // window), pos]`` are
+        # never more than the window's own pages)
+        self.lane_pages = min(table_pages, window // page_size if tumbling
+                              else window_lane_pages(window, span, page_size))
         if num_pages < self.lane_pages + 1:
             raise ValueError(
                 f"window pool of {num_pages} pages cannot hold one lane's "
@@ -1211,6 +1244,9 @@ class WindowPagePool:
         moved = False
         # the first page a query from ``pos`` on can see
         lo = min(max(pos - self.window + 1, 0) // ps, row.shape[0])
+        if self.tumbling:  # its window's first: the one before goes whole
+            lo = min(pos // self.window * self.window // ps, row.shape[0])
+            self.tumbled += int(self.first[lane] < lo <= self.end[lane])
         for i in range(int(self.first[lane]), min(lo, int(self.end[lane]))):
             self._free.append(int(row[i]))
             row[i] = 0
@@ -1318,8 +1354,25 @@ class PagedKVCacheManager(_LaneBook):
         self.num_pages = num_pages
         self.host_store = host_store
         self._revive_jit = self._make_revive_jit()
+        # EVA's summary class (module docstring "EVA's two classes"): the
+        # class ``num_pages`` counts holds one row a CHUNK of positions, and
+        # the pool is asked for a position's chunk
+        self.eva_chunk = int(getattr(cfg, "eva_chunk_size", 0) or 0)
+        eva_window = int(getattr(cfg, "eva_window_size", 0) or 0)
+        if self.eva_chunk and (
+                prefix_cache or page_size != self.eva_chunk
+                or eva_window // self.eva_chunk % page_size
+                or not getattr(cfg, "decode_window_pages", None)):
+            raise ValueError(
+                "EVA attention's two classes of page need prefix_cache=False "
+                "(a matched prefix would need its summary pages alone: not "
+                f"built), page_size {page_size} == eva_chunk_size "
+                f"{self.eva_chunk} (a page of the window class is one "
+                "chunk), whole summary pages to a window, and "
+                "decode_window_pages (the serving engine sets it)")
         self.pool = PagePool(num_pages, page_size, slots,
-                             cache_len // page_size, prefix_cache,
+                             -(-self._rows(cache_len) // page_size),
+                             prefix_cache,
                              host_store=host_store,
                              spill_fn=self._spill_pages,
                              revive_fn=self._revive_pages)
@@ -1328,7 +1381,16 @@ class PagedKVCacheManager(_LaneBook):
         self.window_pool = None
         self.admits_refused = {"full": 0, "window": 0}
         window_pages = getattr(cfg, "decode_window_pages", None)
-        if window_pages:
+        if self.eva_chunk:
+            if window_span < 1 or window_span % self.eva_chunk:
+                raise ValueError(
+                    "EVA attention needs chunked prefill in whole chunks: "
+                    f"window_span {window_span} (the engine's prefill_chunk) "
+                    f"over eva_chunk_size {self.eva_chunk}")
+            self.window_pool = WindowPagePool(
+                window_pages, page_size, slots, cache_len // page_size,
+                eva_window, window_span, tumbling=True)
+        elif window_pages:
             if prefix_cache:
                 raise ValueError(
                     "prefix reuse over window-attention layers: a prefix's "
@@ -1475,7 +1537,28 @@ class PagedKVCacheManager(_LaneBook):
                  self.pool.tables], axis=1)
         if self.window_pool is None:
             return self.pool.tables
-        return np.stack([self.pool.tables, self.window_pool.tables])
+        return np.stack([self._as_wide(self.pool.tables),
+                         self.window_pool.tables])
+
+    def _rows(self, tokens: int) -> int:
+        """Rows of the class ``self.pool`` holds for ``tokens`` positions:
+        as many, or one a whole chunk under EVA."""
+        return tokens // self.eva_chunk if self.eva_chunk else tokens
+
+    def _pool_tokens(self, tokens):
+        """What ``self.pool`` is asked to hold for a prompt of ``tokens``:
+        the prompt, or as many entries as it has whole chunks under EVA (the
+        class has no trie: only the count is read)."""
+        return tokens[:self._rows(len(tokens))] if self.eva_chunk else tokens
+
+    def _as_wide(self, tables: np.ndarray) -> np.ndarray:
+        """``self.pool``'s tables (or one lane's row) as wide as the window
+        class's: EVA's summary class is addressed by chunk and has fewer
+        entries a lane, zeros (the trash page) behind them."""
+        more = self.window_pool.tables.shape[-1] - tables.shape[-1]
+        if not more:
+            return tables
+        return np.pad(tables, [(0, 0)] * (tables.ndim - 1) + [(0, more)])
 
     def lane_tables(self, slot: int) -> np.ndarray:
         """``slot``'s row of :attr:`tables` (of every class: ``[2, ...]``
@@ -1484,7 +1567,7 @@ class PagedKVCacheManager(_LaneBook):
             return np.concatenate([[np.int32(slot)], self.pool.tables[slot]])
         if self.window_pool is None:
             return self.pool.tables[slot]
-        return np.stack([self.pool.tables[slot],
+        return np.stack([self._as_wide(self.pool.tables[slot]),
                          self.window_pool.tables[slot]])
 
     @property
@@ -1537,6 +1620,16 @@ class PagedKVCacheManager(_LaneBook):
                                          * self.page_bytes["kv"])}
         if self.window_pool is None:
             return {}
+        if self.eva_chunk:
+            window = self.window_pool
+            return {"pages_in_use_summary": self.pool.pages_in_use,
+                    "pages_in_use_window": window.pages_in_use,
+                    "usable_pages_summary": self.pool.usable_pages,
+                    "usable_pages_window": window.usable_pages,
+                    "window_pages_recycled": window.recycled,
+                    "eva_windows_tumbled": window.tumbled,
+                    "admits_refused_summary": self.admits_refused["full"],
+                    "admits_refused_window": self.admits_refused["window"]}
         return {"pages_in_use_full": self.pool.pages_in_use,
                 "pages_in_use_window": self.window_pool.pages_in_use,
                 "usable_pages_full": self.pool.usable_pages,
@@ -1566,7 +1659,7 @@ class PagedKVCacheManager(_LaneBook):
         of every class."""
         if not self._free:
             return False
-        if not self.pool.can_admit(tokens):
+        if not self.pool.can_admit(self._pool_tokens(tokens)):
             self.admits_refused["full"] += 1
             return False
         if (self.window_pool is not None
@@ -1590,7 +1683,7 @@ class PagedKVCacheManager(_LaneBook):
                 f"prompt_len {len(tokens)} leaves no decode room "
                 f"(cache_len {self.cache_len})")
         lane = self._free[0]  # peek: only claim once pages are certain
-        shared = self.pool.alloc(lane, tokens)
+        shared = self.pool.alloc(lane, self._pool_tokens(tokens))
         if shared is None:
             return None
         claimed = self._claim_lane(request_id, len(tokens))
@@ -1608,7 +1701,7 @@ class PagedKVCacheManager(_LaneBook):
         (``lengths[slot]``), in every class; False = a pool is dry, caller
         retires the request."""
         pos = int(self.lengths[slot])
-        return self.pool.ensure_page(slot, pos) and (
+        return self.pool.ensure_page(slot, self._rows(pos)) and (
             self.window_pool is None or self.window_pool.prepare(slot, pos))
 
     def prepare_span(self, slot: int, pos: int, n: int) -> bool:

@@ -667,6 +667,8 @@ class ServingEngine:
         # ride is refused here, with its cause
         self.window_pages = None
         extra = {}
+        # EVA's two classes ("summary" beside "window": cache_manager.py)
+        self._eva = "summary" in self.capabilities.page_classes
         if "window" in self.capabilities.page_classes:
             self.capabilities.require(
                 supports_prefix_cache=bool(prefix_cache),
@@ -679,10 +681,15 @@ class ServingEngine:
                     "hold the window plus one chunk, and a whole-prompt "
                     "prefill through the dense path does not fit long "
                     "prompts)")
+            if self._eva:
+                self.num_pages = self._eva_pages(model.cfg, num_pages,
+                                                 prefill_bucket)
             # sized so that no lane can meet a dry window class
-            self.window_pages = self.slots * window_lane_pages(
-                model.cfg.sliding_window, self.prefill_chunk,
-                self.page_size) + 1
+            self.window_pages = self.slots * (
+                model.cfg.eva_window_size // self.page_size if self._eva
+                else window_lane_pages(
+                    model.cfg.sliding_window, self.prefill_chunk,
+                    self.page_size)) + 1
             extra["decode_window_pages"] = self.window_pages
         self.prefix_cache = (
             prefix_cache if prefix_cache is not None
@@ -2085,6 +2092,29 @@ class ServingEngine:
                if self._mrope else {}),
         }
 
+    def _eva_pages(self, cfg, num_pages, prefill_bucket) -> int:
+        """Pages of EVA's SUMMARY class (what ``num_pages`` counts for the
+        family; by default every lane's whole cache in pooled rows), after
+        refusing the shapes its programs cannot take, each with its cause:
+        a chunk program never straddles a window's boundary and pools whole
+        chunks, and a page of the window class is one chunk."""
+        chunk, window = cfg.eva_chunk_size, cfg.eva_window_size
+        bucket = (prefill_bucket
+                  or _env_int("FLEETX_SERVING_PREFILL_BUCKET", 32))
+        if (window % self.prefill_chunk or self.prefill_chunk % bucket
+                or bucket % chunk or self.page_size != chunk):
+            raise ValueError(
+                f"model family {self.model_family!r} has EVA attention "
+                f"(window {window}, chunk {chunk}): prefill_chunk "
+                f"{self.prefill_chunk} must divide the window (a chunk "
+                f"program never straddles a boundary), prefill_bucket "
+                f"{bucket} the prefill chunk and hold whole chunks, and "
+                f"page_size {self.page_size} be the chunk (a page of the "
+                "window class is one chunk)")
+        rows = self.cache_len // chunk
+        return (num_pages or _env_int("FLEETX_SERVING_PAGES", 0)
+                or self.slots * -(-rows // self.page_size) + 1)
+
     def _dequant_params(self, params):
         """Weight-only-int8 dequant seam, called INSIDE every jitted
         prefill/decode body: a no-op at bf16 (the resident tree is read as
@@ -2300,10 +2330,11 @@ class ServingEngine:
     def _row_mask(self, tokens):
         """``tokens`` ``[batch, rows]``, which rows of a cached forward are
         tokens, for a model whose recurrent state must not take a padded
-        row or an idle lane in (models/gpt/mixed_stack.py); None for a
-        model that keeps keys and values alone, whose writes of such rows
-        are never read."""
-        return tokens if self._state_rows else None
+        row or an idle lane in (models/gpt/mixed_stack.py), or whose
+        padded rows must close no chunk (EVA attention, models/gpt/eva.py);
+        None for a model that keeps keys and values alone, whose writes of
+        such rows are never read."""
+        return tokens if self._state_rows or self._eva else None
 
     def _first_token(self, logits, wants, eos, min_new, greedy,
                      temperature, top_k, top_p, key):
@@ -3010,6 +3041,8 @@ class ServingEngine:
         if not self.window_pages:
             return {}
         cfg = self.model.cfg
+        if self._eva:  # what ONE layer attends over, and the chunks closed
+            return cfg.eva_spans(rows - 1)
         return {"full_rows": int(rows.sum()), "window_rows": int(
             np.minimum(rows, cfg.sliding_window).sum()),
             **cfg.span_pairs(self.slots)}

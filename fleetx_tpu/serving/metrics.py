@@ -361,6 +361,14 @@ class ServingMetrics:
         self._c_selected_rows = counter(
             "fleetx_serving_rows_selected_total",
             "Cached rows the indexer kept for those queries, in one layer")
+        # under EVA attention (models/gpt/eva.py), beside the window class's
+        # own (``class_counters``: pages in use of each class,
+        # ``eva_windows_tumbled``): the chunks whose pooled row a program
+        # wrote, a layer
+        self._c_eva_chunks_closed = counter(
+            "fleetx_serving_eva_chunks_closed_total",
+            "Chunks of positions pooled into one summary row by ticks and "
+            "prefill calls, in one layer")
         # rows from a vision tower (docs/SERVING.md "Rows from a tower")
         self._c_image_rows = counter(
             "fleetx_serving_image_rows_total",
@@ -507,11 +515,13 @@ class ServingMetrics:
 
     def record_selection(self, fields: dict) -> dict:
         """The span fields of a tick or a prefill call, counted where they
-        carry an indexer's work (``index_rows``, ``selected_rows``);
-        returned as handed over."""
+        carry an indexer's work (``index_rows``, ``selected_rows``) or
+        EVA's (``eva_chunks_closed``); returned as handed over."""
         if "index_rows" in fields:
             self._c_index_rows.inc(fields["index_rows"])
             self._c_selected_rows.inc(fields["selected_rows"])
+        if "eva_chunks_closed" in fields:
+            self._c_eva_chunks_closed.inc(fields["eva_chunks_closed"])
         return fields
 
     def record_images(self, rows: int, skipped: int) -> None:
@@ -972,6 +982,7 @@ class ServingMetrics:
                 self._c_prefill_headless_calls.value),
             "index_rows_scored": int(self._c_index_rows.value),
             "rows_selected": int(self._c_selected_rows.value),
+            "eva_chunks_closed": int(self._c_eva_chunks_closed.value),
             "image_rows": int(self._c_image_rows.value),
             "images_encoded": int(self._c_images_encoded.value),
             "images_skipped": int(self._c_images_skipped.value),

@@ -39,6 +39,7 @@ scrape-don't-import discipline as the ``role`` field
 from __future__ import annotations
 
 import dataclasses
+import functools
 import weakref
 from typing import Optional
 
@@ -95,7 +96,10 @@ class ModelCapabilities:
     #: window-attention layers, "window" (a layer keeps what a live query
     #: can still see: serving/cache_manager.py "Two classes of page").
     #: Window pages are released behind the window, so a prompt's prefix
-    #: cannot be reused from them, nor shipped between replicas
+    #: cannot be reused from them, nor shipped between replicas. A model
+    #: with EVA attention keeps ("summary", "window") in EVERY layer: one
+    #: pooled row a chunk of positions, and the exact rows of a lane's
+    #: current TUMBLING window ("EVA's two classes")
     page_classes: tuple = ("full",)
     supports_prefix_cache: bool = True
     supports_roles: bool = True
@@ -151,6 +155,7 @@ _FEATURES = {
     "supports_int8_kv": "an int8 KV cache: no test covers it",
     "supports_mesh": "a serving mesh: no test covers it",
     "supports_prefix_cache": "prefix reuse: its window-attention layers "
+                             "(or its EVA layers' tumbling window) "
                              "release a prefix's pages once the window has "
                              "passed them, or its selective-scan or "
                              "delta-rule layers keep "
@@ -159,13 +164,15 @@ _FEATURES = {
     "supports_roles": "a prefill or decode role: the pages of its window "
                       "class, the tail pages of its convolution state, the "
                       "lane-resident state of its selective-scan or "
-                      "delta-rule layers, or the pages of a flat pool over "
-                      "mixed layers, are not shipped between replicas",
+                      "delta-rule layers, the pages of a flat pool over "
+                      "mixed layers, or the pooled rows of its EVA layers' "
+                      "summary class, are not shipped between replicas",
     "supports_host_spill": "a host or disk page tier: the tail pages of its "
                            "convolution state, the lane-resident state "
-                           "of its selective-scan or delta-rule layers, or "
-                           "the pages of a flat pool over mixed layers, are "
-                           "not spilled",
+                           "of its selective-scan or delta-rule layers, "
+                           "the pages of a flat pool over mixed layers, or "
+                           "the pooled rows of its EVA layers' summary "
+                           "class, are not spilled",
 }
 
 
@@ -289,6 +296,11 @@ class GPTExecutor(ModelExecutor):
         # both; every other family keeps the flags it had (ROADMAP R9)
         flat = bool(getattr(model.cfg, "layer_types", None)) and bool(
             getattr(model.cfg, "indexed", False))
+        # EVA attention (models/gpt/eva.py): a summary and a tumbling window
+        # class in every layer; what the window class refuses it refuses,
+        # and its pooled rows are neither spilled nor shipped
+        eva = bool(getattr(model.cfg, "eva", False))
+        windowed, flat = windowed or eva, flat or eva
         self.capabilities = ModelCapabilities(
             family=family or getattr(model.cfg, "family", "gpt"),
             has_kv_cache=True,
@@ -298,7 +310,8 @@ class GPTExecutor(ModelExecutor):
             supports_int8_weights=dense,
             supports_int8_kv=dense,
             supports_mesh=dense,
-            page_classes=("full", "window") if windowed else ("full",),
+            page_classes=(("summary", "window") if eva else
+                          ("full", "window") if windowed else ("full",)),
             supports_prefix_cache=not windowed and not getattr(
                 model.cfg, "lane_state", ("", ()))[0],
             supports_roles=not windowed and not recurrent and not flat,
@@ -380,15 +393,17 @@ class GPTExecutor(ModelExecutor):
         # device-trace scope: under it the layer scan's own slices and
         # updates move the KV cache (docs/OBSERVABILITY.md, parts)
         with jax.named_scope("cached_forward"):
-            if logit_rows is not None:  # body and head apart
-                return row_logits_step(
-                    self.model, params, cache, ids, positions, mask,
-                    cache_positions=cache_positions,
-                    block_tables=block_tables, logit_rows=logit_rows,
-                    **rows_in)
-            return decode_step(self.model, params, cache, ids, positions,
-                               mask, cache_positions=cache_positions,
-                               block_tables=block_tables, **rows_in)
+            step = decode_step if logit_rows is None else functools.partial(
+                row_logits_step, logit_rows=logit_rows)  # body and head apart
+            logits, cache = step(
+                self.model, params, cache, ids, positions, mask,
+                cache_positions=cache_positions, block_tables=block_tables,
+                **rows_in)
+        if getattr(self.model.cfg, "num_pred_heads", 1) > 1:
+            # of several prediction heads the FIRST is served (the next
+            # token); the model computes them all (models/gpt/head.py)
+            logits = logits[..., :self.model.cfg.vocab_size]
+        return logits, cache
 
     def sample(self, logits, keys, greedy, temperature, top_k, top_p, *,
                topk_cap: int):

@@ -118,12 +118,26 @@ _CANNOT_APPLY = {
         "perfbench/flops.py gpt_param_count counts the GPT-2 block only "
         "(a benchmark PR's to extend); tests/test_ling3_serving.py holds "
         "this configuration's count to the program's own model",
+    "tests/perfbench/test_perfbench_flops.py::"
+    "test_param_count_matches_the_programs_model"
+    "[perfbench/configs/evabyte-6.5b-pp4-l8.json]":
+        "perfbench/flops.py gpt_param_count counts the GPT-2 block only "
+        "(a benchmark PR's to extend); tests/perfbench/"
+        "test_perfbench_evabyte.py holds this configuration's count to the "
+        "program's own model",
     "tests/perfbench/test_perfbench_traffic.py::"
     "test_order_seed_pins_tenants_and_lengths_and_leaves_the_seed_the_tokens"
     "[mixed-longshort]":
         "its last line knows ONE closed-loop file with `order_seed`, "
         "longdoc-gen (a benchmark PR's to extend); tests/perfbench/"
         "test_perfbench_trinity.py holds this file's pinned order and the "
+        "seed's tokens",
+    "tests/perfbench/test_perfbench_traffic.py::"
+    "test_order_seed_pins_tenants_and_lengths_and_leaves_the_seed_the_tokens"
+    "[bytedocs-longctx]":
+        "its last line knows ONE closed-loop file with `order_seed`, "
+        "longdoc-gen (a benchmark PR's to extend); tests/perfbench/"
+        "test_perfbench_evabyte.py holds this file's pinned order and the "
         "seed's tokens",
 }
 # the same for every case of one test and cell: (node id's start, reason)
@@ -184,6 +198,13 @@ _CANNOT_APPLY_FROM = (
      "PR 37 alone (a benchmark PR's to extend); tests/perfbench/"
      "test_perfbench_ling3.py makes this cell's traced rehearsal and holds "
      "every entry that lists it"),
+    ("tests/perfbench/test_perfbench_rehearsal.py::"
+     "test_traced_rehearsal_reports_a_shared_entry_in_each_cell_it_lists"
+     "[evabyte-l8-serve-bytedocs-longctx-",
+     "test_perfbench_rehearsal.py's TINY_REPORTS has a row for the cells of "
+     "PR 37 alone (a benchmark PR's to extend); tests/perfbench/"
+     "test_perfbench_evabyte.py makes this cell's traced rehearsal and holds "
+     "every entry that lists it"),
 )
 
 
@@ -211,37 +232,44 @@ _KEYEVL2 = ("keyevl2-l6-serve-pagesqa-sparse",
 # (PR 64: the Ling-3.0-flash cell and the three entries it brought)
 _LING3 = ("ling3-l7-serve-reason-widebatch", "mla_qk_norm_busy_share",
           "moe_group_tokens_share", "latent_bytes_share")
+# (PR 66: the EvaByte cell and the five entries it brought)
+_EVABYTE = ("evabyte-l8-serve-bytedocs-longctx", "eva_decode_roofline",
+            "eva_pool_busy_share", "eva_summary_rows_share",
+            "eva_rows_read_share", "eva_summary_bytes_share")
 _WRITTEN_BEFORE = {
     "tests/perfbench/test_perfbench_lfm2.py::"
     "test_every_width_is_the_published_one_and_only_the_depth_is_cut":
         ("jamba2-3b-serve-chat-peak", "axk1-l6-serve-docqa-latent",
          "dsv32-l5-serve-longqa-sparse", "trinity-l5-serve-mixed-longshort")
-        + _LONGCAT + _SOLAR2 + _KEYEVL2 + _LING3,
+        + _LONGCAT + _SOLAR2 + _KEYEVL2 + _LING3 + _EVABYTE,
     "tests/perfbench/test_perfbench_jamba2.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
         ("axk1-l6-serve-docqa-latent", "dsv32-l5-serve-longqa-sparse",
          "trinity-l5-serve-mixed-longshort") + _SETUP_ENTRIES + _LONGCAT
-        + _SOLAR2 + _KEYEVL2 + _LING3,
+        + _SOLAR2 + _KEYEVL2 + _LING3 + _EVABYTE,
     "tests/perfbench/test_perfbench_axk1.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
         ("dsv32-l5-serve-longqa-sparse", "trinity-l5-serve-mixed-longshort")
-        + _SETUP_ENTRIES + _LONGCAT + _SOLAR2 + _KEYEVL2 + _LING3,
+        + _SETUP_ENTRIES + _LONGCAT + _SOLAR2 + _KEYEVL2 + _LING3 + _EVABYTE,
     "tests/perfbench/test_perfbench_dsv32.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
         ("trinity-l5-serve-mixed-longshort",) + _SETUP_ENTRIES + _LONGCAT
-        + _SOLAR2 + _KEYEVL2 + _LING3,
+        + _SOLAR2 + _KEYEVL2 + _LING3 + _EVABYTE,
     "tests/perfbench/test_perfbench_trinity.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
-        _SETUP_ENTRIES + _LONGCAT + _SOLAR2 + _KEYEVL2 + _LING3,
+        _SETUP_ENTRIES + _LONGCAT + _SOLAR2 + _KEYEVL2 + _LING3 + _EVABYTE,
     "tests/perfbench/test_perfbench_longcat.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
-        _SOLAR2 + _KEYEVL2 + _LING3,
+        _SOLAR2 + _KEYEVL2 + _LING3 + _EVABYTE,
     "tests/perfbench/test_perfbench_solar2.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
-        _KEYEVL2 + _LING3,
+        _KEYEVL2 + _LING3 + _EVABYTE,
     "tests/perfbench/test_perfbench_keyevl2.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
-        _LING3,
+        _LING3 + _EVABYTE,
+    "tests/perfbench/test_perfbench_ling3.py::"
+    "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
+        _EVABYTE,
 }
 
 

@@ -40,6 +40,7 @@ _EXPLICIT = {
     "serve_solar_open2_ep16_l8.yaml": 1,  # one chip's share of sixteen
     "serve_ling3_flash_ep8_l7.yaml": 1,   # one chip's share of eight
     "serve_keye_vl2_30b_l6.yaml": 1,  # one pipeline stage of eight
+    "serve_evabyte_6.5b_pp4_l8.yaml": 1,  # one pipeline stage of four
 }
 
 # _base_ fragments: not launchable topologies on their own
