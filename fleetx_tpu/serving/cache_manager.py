@@ -1654,19 +1654,25 @@ class PagedKVCacheManager(_LaneBook):
 
     # ---------------------------------------------------------- lifecycle
 
-    def can_admit(self, tokens) -> bool:
-        """A free lane AND enough free pages for this prompt right now,
-        of every class."""
+    def refusal(self, tokens) -> Optional[str]:
+        """What this prompt is short of right now: ``"lane"`` (none is
+        free), ``"pages"`` (of either class), or None: :meth:`alloc` would
+        succeed."""
         if not self._free:
-            return False
+            return "lane"
         if not self.pool.can_admit(self._pool_tokens(tokens)):
             self.admits_refused["full"] += 1
-            return False
+            return "pages"
         if (self.window_pool is not None
                 and not self.window_pool.can_admit(len(tokens))):
             self.admits_refused["window"] += 1
-            return False
-        return True
+            return "pages"
+        return None
+
+    def can_admit(self, tokens) -> bool:
+        """A free lane AND enough free pages for this prompt right now,
+        of every class."""
+        return self.refusal(tokens) is None
 
     def alloc(self, request_id: int, tokens) -> Optional[Tuple[int, int]]:
         """Claim the lowest free lane + a page chain for prompt ``tokens``.

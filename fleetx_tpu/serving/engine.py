@@ -346,7 +346,7 @@ from fleetx_tpu.serving.inflight import (
     InflightTick,
     pending_of,
 )
-from fleetx_tpu.serving.metrics import ServingMetrics
+from fleetx_tpu.serving.metrics import WAITS, ServingMetrics
 from fleetx_tpu.serving.scheduler import FIFOScheduler, Request
 from fleetx_tpu.serving.spec import build_proposer
 from fleetx_tpu.utils.log import logger
@@ -411,6 +411,11 @@ def _env_float(name: str, default: float) -> float:
 # (perfbench's ``admit_host_ms_p50``) needs some that do. Each one costs the
 # chip the 3-4 ms of idle the others save: at most 0.8% of the wall clock.
 _PROBE_PERIOD_S = 0.5
+
+# What a step carried, which its ``serving.tick`` says as it closes: the
+# admissions, chunks and tower programs it ran, the tokens it delivered,
+# and the prompt rows that went through its prefill programs (no padding).
+_CARRIED = ("admitted", "chunked", "tower", "decoded", "prefill_rows")
 
 
 # An admission's operands cross to the device PACKED, one host-built
@@ -826,6 +831,10 @@ class ServingEngine:
         # admissions whose lane is installed and whose first token is
         # unread, oldest first; empty whenever step() has returned
         self._first_tokens: collections.deque = collections.deque()
+        # the step's own account: what refused the head of the queue in
+        # this step, and what the step's prefill slot has carried so far
+        self._refused: Optional[str] = None
+        self._carried = {"tower": 0, "prefill_rows": 0}
         self._probed_at = self._now()  # the last admission read at once
         # programs dispatched so far (_next_program)
         self._programs = 0
@@ -971,152 +980,162 @@ class ServingEngine:
         (``kv_payloads``, a prefill or decode role: the handoff ships ids)
         nor carry ``history`` (a replay across replicas knows ids alone):
         each raises by name."""
-        if self._shutting_down:
-            self.metrics.record_drain_reject()
-            obs_emit("drain_reject", engine=self.metrics.engine_label)
-            raise ShuttingDown(
-                "engine is draining toward shutdown; submit to another "
-                "replica (in-flight requests are finishing under the "
-                "grace window)")
-        if self.max_queue and self.scheduler.queue_depth >= self.max_queue:
-            # dead entries must not hold live ones out: sweep TTL/deadline
-            # expiries before judging the bound (step() normally does this,
-            # but a submit burst between ticks sees the stale depth)
-            self._expire_queued(self._now())
-        if self.max_queue and self.scheduler.queue_depth >= self.max_queue:
-            self.metrics.record_reject()
-            obs_emit("queue_reject", engine=self.metrics.engine_label,
-                     queue_depth=self.scheduler.queue_depth)
-            raise QueueFull(
-                f"admission queue is full ({self.scheduler.queue_depth}/"
-                f"{self.max_queue} waiting, {self.cache_manager.active_count}"
-                f"/{self.slots} slots busy); retry later or raise "
-                "FLEETX_SERVING_MAX_QUEUE")
-        prompt = np.asarray(prompt, np.int32).reshape(-1)
-        if prompt.size == 0:
-            raise ValueError("empty prompt")
-        laid = None
-        if images is not None:
-            if self._tower is None:
-                raise ValueError(
-                    f"model family {self.model_family!r} takes no images "
-                    "(capabilities.takes_rows=False)")
-            for name, given in (("kv_payloads", kv_payloads is not None),
-                                ("history", history is not None),
-                                (f"role={self.role!r}", self.role != "both")):
-                if given:
+        # the engine's own time between two steps, under its own name
+        # (its refusals included: a refused submit has no ``request``)
+        with span("serving.submit") as at:
+            if self._shutting_down:
+                self.metrics.record_drain_reject()
+                obs_emit("drain_reject", engine=self.metrics.engine_label)
+                raise ShuttingDown(
+                    "engine is draining toward shutdown; submit to another "
+                    "replica (in-flight requests are finishing under the "
+                    "grace window)")
+            if self.max_queue and self.scheduler.queue_depth >= self.max_queue:
+                # dead entries must not hold live ones out: sweep TTL/deadline
+                # expiries before judging the bound (step() normally does this,
+                # but a submit burst between ticks sees the stale depth)
+                self._expire_queued(self._now())
+            if self.max_queue and self.scheduler.queue_depth >= self.max_queue:
+                self.metrics.record_reject()
+                obs_emit("queue_reject", engine=self.metrics.engine_label,
+                         queue_depth=self.scheduler.queue_depth)
+                raise QueueFull(
+                    f"admission queue is full ({self.scheduler.queue_depth}/"
+                    f"{self.max_queue} waiting, "
+                    f"{self.cache_manager.active_count}"
+                    f"/{self.slots} slots busy); retry later or raise "
+                    "FLEETX_SERVING_MAX_QUEUE")
+            prompt = np.asarray(prompt, np.int32).reshape(-1)
+            at["prompt_len"] = int(prompt.size)
+            if prompt.size == 0:
+                raise ValueError("empty prompt")
+            laid = None
+            if images is not None:
+                if self._tower is None:
                     raise ValueError(
-                        f"a request with images cannot take {name}: the "
-                        "handoff between replicas ships token ids, and an "
-                        "image's rows have none (docs/SERVING.md \"Rows "
-                        "from a tower\")")
-        if self._tower is not None:
-            # what the ids and the images' SHAPES say, and every refusal (a
-            # prompt that marks image rows and brings no image raises): no
-            # byte of an image is read on this thread (serving/rows_in.py)
-            laid = self._tower.outline(prompt, images)
-        g = self.gen_cfg
-        strategy = decode_strategy or g.decode_strategy
-        if strategy not in ("greedy", "sampling"):
-            raise ValueError(
-                f"decode_strategy {strategy!r} not servable by continuous "
-                "batching (beam search needs one-shot generate())")
-        limit = min(self.cache_len, self.model.cfg.max_position_embeddings)
-        if prompt.size >= limit:
-            raise ValueError(
-                f"prompt_len {prompt.size} leaves no decode room "
-                f"(cache/position limit {limit})")
-        max_new = int(max_length if max_length is not None else g.max_length)
-        if prompt.size + max_new > limit:
-            clamped = limit - prompt.size
-            logger.warning(
-                "serving: request %d max_length %d clamped to %d "
-                "(prompt %d + limit %d)", self._next_id, max_new, clamped,
-                prompt.size, limit)
-            max_new = clamped
-        min_new = min(int(min_length if min_length is not None
-                          else g.min_length), max_new)
-        eos = int(eos_token_id if eos_token_id is not None
-                  else (g.eos_token_id if g.eos_token_id is not None else -1))
-        vocab = self.model.cfg.vocab_size
-        tk = int(top_k if top_k is not None else g.top_k)
-        if tk <= 0 or tk >= vocab:
-            tk = 0  # no filter (matches _sample's vocab clamp)
-        elif tk > self.topk_cap:
-            logger.warning(
-                "serving: request %d top_k %d clamped to topk_cap %d "
-                "(FLEETX_SERVING_TOPK_CAP)", self._next_id, tk, self.topk_cap)
-            tk = self.topk_cap
-        hist = ([] if history is None
-                else [int(t) for t in np.asarray(history,
-                                                 np.int64).reshape(-1)])
-        if hist:
-            if eos >= 0 and hist[-1] == eos:
+                        f"model family {self.model_family!r} takes no images "
+                        "(capabilities.takes_rows=False)")
+                for name, given in (("kv_payloads", kv_payloads is not None),
+                                    ("history", history is not None),
+                                    (f"role={self.role!r}",
+                                     self.role != "both")):
+                    if given:
+                        raise ValueError(
+                            f"a request with images cannot take {name}: the "
+                            "handoff between replicas ships token ids, and an "
+                            "image's rows have none (docs/SERVING.md \"Rows "
+                            "from a tower\")")
+            if self._tower is not None:
+                # what the ids and the images' SHAPES say, and every refusal (a
+                # prompt that marks image rows and brings no image raises): no
+                # byte of an image is read on this thread (serving/rows_in.py)
+                laid = self._tower.outline(prompt, images)
+            g = self.gen_cfg
+            strategy = decode_strategy or g.decode_strategy
+            if strategy not in ("greedy", "sampling"):
                 raise ValueError(
-                    f"history of {len(hist)} tokens already ends in EOS "
-                    f"({eos}) — the request is terminal; do not migrate it")
-            if max_new <= len(hist):
+                    f"decode_strategy {strategy!r} not servable by continuous "
+                    "batching (beam search needs one-shot generate())")
+            limit = min(self.cache_len, self.model.cfg.max_position_embeddings)
+            if prompt.size >= limit:
                 raise ValueError(
-                    f"history ({len(hist)} tokens) meets or exceeds the "
-                    f"max_length budget ({max_new}) — the request is "
-                    "terminal; do not migrate it")
-        decoded_pages = None
-        if kv_payloads is not None:
-            if not hist:
-                raise ValueError(
-                    "kv_payloads without history: the prefill replica "
-                    "sampled the first token — pass it as history=[t0]")
-            need = -(-prompt.size // self.page_size)
-            if len(kv_payloads) != need:
-                raise ValueError(
-                    f"kv_payloads has {len(kv_payloads)} page blob(s); a "
-                    f"{prompt.size}-token prompt at page_size "
-                    f"{self.page_size} ships {need}")
-            # decode NOW, not at admission: payload_from_bytes verifies
-            # the crc32 trailer, so a corrupted ship fails this submit
-            # loudly and the request never enters the queue half-armed
-            decoded_pages = [
-                HostPageStore.payload_from_bytes(b)
-                if isinstance(b, (bytes, bytearray, memoryview)) else b
-                for b in kv_payloads]
-            for leaf in decoded_pages[0]:
-                # a page payload leaf is [..., page_size, lanes]
-                if leaf is not None and leaf.shape[-2] != self.page_size:
+                    f"prompt_len {prompt.size} leaves no decode room "
+                    f"(cache/position limit {limit})")
+            max_new = int(max_length if max_length is not None
+                          else g.max_length)
+            if prompt.size + max_new > limit:
+                clamped = limit - prompt.size
+                logger.warning(
+                    "serving: request %d max_length %d clamped to %d "
+                    "(prompt %d + limit %d)", self._next_id, max_new, clamped,
+                    prompt.size, limit)
+                max_new = clamped
+            min_new = min(int(min_length if min_length is not None
+                              else g.min_length), max_new)
+            eos = int(eos_token_id if eos_token_id is not None
+                      else (g.eos_token_id if g.eos_token_id is not None
+                            else -1))
+            vocab = self.model.cfg.vocab_size
+            tk = int(top_k if top_k is not None else g.top_k)
+            if tk <= 0 or tk >= vocab:
+                tk = 0  # no filter (matches _sample's vocab clamp)
+            elif tk > self.topk_cap:
+                logger.warning(
+                    "serving: request %d top_k %d clamped to topk_cap %d "
+                    "(FLEETX_SERVING_TOPK_CAP)", self._next_id, tk,
+                    self.topk_cap)
+                tk = self.topk_cap
+            hist = ([] if history is None
+                    else [int(t) for t in np.asarray(history,
+                                                     np.int64).reshape(-1)])
+            if hist:
+                if eos >= 0 and hist[-1] == eos:
                     raise ValueError(
-                        f"shipped pages carry {leaf.shape[-2]} rows; this "
-                        f"replica's page_size is {self.page_size} — "
-                        "disaggregated replicas must agree on page_size")
-        rid = self._next_id
-        self._next_id += 1
-        if rng_key is None:
-            rng_key = (jax.random.PRNGKey(int(seed)) if seed is not None
-                       else jax.random.fold_in(self._base_key, rid))
-        req = Request(
-            id=rid, prompt=prompt, max_new_tokens=max(max_new, 1),
-            min_new_tokens=min_new, eos_token_id=eos,
-            greedy=strategy == "greedy",
-            temperature=float(temperature if temperature is not None
-                              else g.temperature),
-            top_k=tk,
-            top_p=float(top_p if top_p is not None else g.top_p),
-            rng_key=rng_key, on_token=on_token,
-            submit_time=self._now(),
-            queue_ttl_s=float(queue_ttl_s if queue_ttl_s is not None
-                              else self.queue_ttl_s),
-            deadline_s=float(deadline_s if deadline_s is not None
-                             else self.deadline_s),
-        )
-        # admit-with-history: the pre-emitted tokens ARE the request's
-        # token list from the start (a queue-expiry or shutdown retirement
-        # before admission must still return them — zero token loss), and
-        # _admit routes a non-empty list through the replay prefill seam
-        req.tokens.extend(hist)
-        req.kv_payloads = decoded_pages
-        if laid is not None and laid[3]:
-            self._tower.lay_out(req, laid)  # keys, patches: on its worker
-        self.scheduler.submit(req)
-        self.metrics.record_submit()
-        return rid
+                        f"history of {len(hist)} tokens already ends in EOS "
+                        f"({eos}) — the request is terminal; do not migrate "
+                        "it")
+                if max_new <= len(hist):
+                    raise ValueError(
+                        f"history ({len(hist)} tokens) meets or exceeds the "
+                        f"max_length budget ({max_new}) — the request is "
+                        "terminal; do not migrate it")
+            decoded_pages = None
+            if kv_payloads is not None:
+                if not hist:
+                    raise ValueError(
+                        "kv_payloads without history: the prefill replica "
+                        "sampled the first token — pass it as history=[t0]")
+                need = -(-prompt.size // self.page_size)
+                if len(kv_payloads) != need:
+                    raise ValueError(
+                        f"kv_payloads has {len(kv_payloads)} page blob(s); a "
+                        f"{prompt.size}-token prompt at page_size "
+                        f"{self.page_size} ships {need}")
+                # decode NOW, not at admission: payload_from_bytes verifies
+                # the crc32 trailer, so a corrupted ship fails this submit
+                # loudly and the request never enters the queue half-armed
+                decoded_pages = [
+                    HostPageStore.payload_from_bytes(b)
+                    if isinstance(b, (bytes, bytearray, memoryview)) else b
+                    for b in kv_payloads]
+                for leaf in decoded_pages[0]:
+                    # a page payload leaf is [..., page_size, lanes]
+                    if leaf is not None and leaf.shape[-2] != self.page_size:
+                        raise ValueError(
+                            f"shipped pages carry {leaf.shape[-2]} rows; this "
+                            f"replica's page_size is {self.page_size} — "
+                            "disaggregated replicas must agree on page_size")
+            rid = at["request"] = self._next_id
+            self._next_id += 1
+            if rng_key is None:
+                rng_key = (jax.random.PRNGKey(int(seed)) if seed is not None
+                           else jax.random.fold_in(self._base_key, rid))
+            req = Request(
+                id=rid, prompt=prompt, max_new_tokens=max(max_new, 1),
+                min_new_tokens=min_new, eos_token_id=eos,
+                greedy=strategy == "greedy",
+                temperature=float(temperature if temperature is not None
+                                  else g.temperature),
+                top_k=tk,
+                top_p=float(top_p if top_p is not None else g.top_p),
+                rng_key=rng_key, on_token=on_token,
+                submit_time=self._now(),
+                queue_ttl_s=float(queue_ttl_s if queue_ttl_s is not None
+                                  else self.queue_ttl_s),
+                deadline_s=float(deadline_s if deadline_s is not None
+                                 else self.deadline_s),
+            )
+            # admit-with-history: the pre-emitted tokens ARE the request's
+            # token list from the start (a queue-expiry or shutdown retirement
+            # before admission must still return them — zero token loss), and
+            # _admit routes a non-empty list through the replay prefill seam
+            req.tokens.extend(hist)
+            req.kv_payloads = decoded_pages
+            if laid is not None and laid[3]:
+                self._tower.lay_out(req, laid)  # keys, patches: on its worker
+            self.scheduler.submit(req)
+            self.metrics.record_submit()
+            return rid
 
     def step(self) -> Dict:
         """One TRANSACTIONAL scheduler tick: the pure-host bookkeeping
@@ -1159,8 +1178,9 @@ class ServingEngine:
                 snap.update(fresh)
 
             try:
-                with span("serving.tick", tick=self._ticks):
+                with span("serving.tick", tick=self._ticks) as at:
                     summary = self._step_inner(commit)
+                    at.update({k: summary[k] for k in _CARRIED})
                 if (summary["decoded"] or summary["admitted"]
                         or summary["chunked"]):
                     # a productive device tick proves the engine is healthy
@@ -1172,27 +1192,30 @@ class ServingEngine:
                 raise
             except Exception as exc:  # noqa: BLE001 — THE crash-safety seam
                 summary = self._handle_tick_fault(snap, exc)
-        self._ticks += 1
-        self.metrics.observe_tick(self.scheduler.queue_depth,
-                                  len(self._active), self._now() - t0)
-        self.metrics.observe_pages(self.cache_manager.pages_in_use,
-                                   self.cache_manager.usable_pages)
-        if self._dram_store is not None:
-            self.metrics.observe_host_tier(self._dram_store)
-        if self._disk_store is not None:
-            self.metrics.observe_disk_tier(self._disk_store)
-        self.metrics.observe_queue_tokens(
-            self.scheduler.queued_tokens() + sum(
-                r.prompt_len - r.prefill_pos
-                for r in self._prefilling.values()))
-        if self.log_every and self._ticks % self.log_every == 0:
-            self.metrics.log_snapshot()
-        summary.setdefault("recovered", False)
-        summary.setdefault("chunked", 0)
-        summary["queue_depth"] = self.scheduler.queue_depth
-        summary["active_slots"] = len(self._active)
-        summary["prefilling"] = len(self._prefilling)
-        summary["prefilled"] = len(self._prefilled)
+        # the engine's own time behind the tick, under its own name: a
+        # device gap there is booked to it, not to the caller (``no span``)
+        with span("serving.observe"):
+            self._ticks += 1
+            self.metrics.observe_tick(self.scheduler.queue_depth,
+                                      len(self._active), self._now() - t0)
+            self.metrics.observe_pages(self.cache_manager.pages_in_use,
+                                       self.cache_manager.usable_pages)
+            if self._dram_store is not None:
+                self.metrics.observe_host_tier(self._dram_store)
+            if self._disk_store is not None:
+                self.metrics.observe_disk_tier(self._disk_store)
+            self.metrics.observe_queue_tokens(
+                self.scheduler.queued_tokens() + sum(
+                    r.prompt_len - r.prefill_pos
+                    for r in self._prefilling.values()))
+            if self.log_every and self._ticks % self.log_every == 0:
+                self.metrics.log_snapshot()
+            summary.setdefault("recovered", False)
+            summary.setdefault("chunked", 0)
+            summary["queue_depth"] = self.scheduler.queue_depth
+            summary["active_slots"] = len(self._active)
+            summary["prefilling"] = len(self._prefilling)
+            summary["prefilled"] = len(self._prefilled)
         return summary
 
     def _step_inner(self, commit=lambda: None) -> Dict:
@@ -1209,10 +1232,13 @@ class ServingEngine:
         timed_out = self._expire_queued(self._now())
         admitted = 0
         chunked = 0
+        self._refused = None
+        self._carried = {"tower": 0, "prefill_rows": 0}
         prefill_t0 = self._now()
         if self._prefilling:
             # FIFO holds: the mid-prefill request IS the admission head,
             # so nothing else admits until its chunks finish (or expire)
+            self._refused = "slot"
             n, expired = self._chunk_tick()
             chunked += n
             timed_out += expired
@@ -1244,6 +1270,7 @@ class ServingEngine:
                     # one left unread is to the snapshot still in the queue
                     commit()
                 if self.prefill_chunk:
+                    self._refused = "slot"
                     break  # one prefill-shaped device call per tick
         if admitted or chunked:
             self.metrics.observe_prefill_stall(self._now() - prefill_t0)
@@ -1265,7 +1292,7 @@ class ServingEngine:
         timed_out += self._expire_active(now)
         return {"admitted": admitted, "decoded": self._delivered,
                 "chunked": chunked, "retired": retired + timed_out,
-                "timed_out": timed_out}
+                "timed_out": timed_out, **self._carried}
 
     def cancel(self, request_id: int) -> bool:
         """Cancel a queued or in-flight request: its slot (if any) is freed
@@ -2296,12 +2323,20 @@ class ServingEngine:
         (page-granular admission: total live tokens gate entry, not
         worst-case lane capacity). A too-big head BLOCKS, preserving
         arrival order deterministically, and so does one whose images the
-        tower's worker still hashes (rows_in.py ``Tower.keyed``)."""
+        tower's worker still hashes (rows_in.py ``Tower.keyed``). What the
+        head is short of (``keys``, ``lane``, ``pages``: said where it is
+        decided) goes on the span as ``refused`` and stays ``_refused``
+        for the step's account (:meth:`_lanes_at_dispatch`)."""
         # a dry run of the prefix match, once a tick while the head waits
-        with span("serving.can_admit", request=req.id):
-            return ((req.keyed is None or self._tower.keyed(req))
-                    and self.cache_manager.can_admit(self._trie_keys(
-                        req, self._admission_tokens(req))))
+        with span("serving.can_admit", request=req.id) as at:
+            if req.keyed is not None and not self._tower.keyed(req):
+                refused = "keys"
+            else:
+                refused = self.cache_manager.refusal(self._trie_keys(
+                    req, self._admission_tokens(req)))
+            if refused:
+                at["refused"] = self._refused = refused
+            return refused is None
 
     def _device_tables(self):
         """Device copy of the block tables, re-uploaded only when the
@@ -2472,11 +2507,13 @@ class ServingEngine:
             return _upload(at, ints), floats, req.rng_key
 
     def _guarded_prefill(self, req: Request, fn, args, bucket: int,
-                         first: bool):
+                         rows: int, first: bool):
         """One prefill device call through the fault-injection hook;
         stores the returned cache in the cache manager and returns the
         first token with the stream's carry key and the call's program
-        number (:meth:`_next_program`). The span's ``page_writes`` is the
+        number (:meth:`_next_program`). The span's ``rows`` are the tokens
+        the call holds (its ``bucket`` less the padding), which the step
+        counts as ``prefill_rows``; its ``page_writes`` is the
         model's own predicate on the call's shape (``paged_write.
         page_writes``): the pages a pool and layer that the program
         writes a page at a time, 0 where it writes a row at a time; the
@@ -2494,8 +2531,10 @@ class ServingEngine:
         self._fault_prefills += 1
         program = self._next_program()
         pages = paged_write.page_writes(1, bucket, self.page_size)
+        self._carried["prefill_rows"] += rows
         with span("serving.prefill", request=req.id, bucket=bucket,
-                  program=program, page_writes=pages, **self._plan) as at:
+                  rows=rows, program=program, page_writes=pages,
+                  **self._plan) as at:
             if first:
                 at["first"] = True
             faults.on_serving_prefill(attempt, req.id)
@@ -2555,7 +2594,7 @@ class ServingEngine:
             self._tower.stage_rows(req, shared, len(suffix))
             args += (self._tower.stage,)
         tok, carry_key, program = self._guarded_prefill(
-            req, fn, args, bucket=bucket, first=first)
+            req, fn, args, bucket=bucket, rows=len(suffix), first=first)
         self.metrics.record_prefill_call(wants_token=not replay)
         return None if replay else (tok, carry_key, floats, program)
 
@@ -2779,7 +2818,8 @@ class ServingEngine:
         tokens = req.prompt[start:end]
         self._fault_ctx = ("prefill", req.id)
         with span("serving.prefill_chunk", request=req.id, start=start,
-                  final=final, **self.metrics.record_selection(
+                  rows=end - start, final=final,
+                  **self.metrics.record_selection(
                       self._scan_rows(end - start, start))):
             out = self._paged_prefill_call(req, tokens, start, req.slot,
                                            replay=not final)
@@ -2991,6 +3031,32 @@ class ServingEngine:
                     < req.max_new_tokens)
                 and lengths[slot] < self.cache_len}
 
+    def _lanes_at_dispatch(self, batch: int) -> dict:
+        """Span fields of a tick: where every lane that is not among the
+        ``batch`` it decodes for stands at its dispatch, after the step's
+        admission phase. ``lanes_finishing``: the request's LAST token is
+        in flight (active and not live, :meth:`_live_lanes`; and one
+        admitted for a single token, which is unread); ``lanes_prefilling``:
+        held by a prompt mid-prefill, or parked; ``lanes_waiting``: free,
+        with a request queued for it, and ``waiting_on`` says what the head
+        of the queue was refused for in this step (``_refused``: ``slot``,
+        ``pages``, ``keys``; a lane that came free only after the
+        admission phase, by a dry pool's read, waits for the next step's
+        ``slot``); ``lanes_unasked``: free beyond the queue. With ``batch``
+        they add up to the slots."""
+        single = sum(1 for first in self._first_tokens
+                     if self._active.get(first.req.slot) is not first.req)
+        free = self.cache_manager.free_count
+        waiting = min(free, len(self.scheduler))
+        fields = {"lanes_finishing": len(self._active) - batch + single,
+                  "lanes_prefilling": (len(self._prefilling)
+                                       + len(self._prefilled)),
+                  "lanes_waiting": waiting, "lanes_unasked": free - waiting}
+        if waiting:
+            refused = self._refused
+            fields["waiting_on"] = refused if refused in WAITS else "slot"
+        return fields
+
     def _grow_pages(self, commit=lambda: None) -> list:
         """Grow-on-demand BEFORE the write: any live lane whose next
         position crosses into an unallocated page claims one now; a dry
@@ -3119,6 +3185,8 @@ class ServingEngine:
         program = self._next_program()
         with span("serving.decode", batch=len(active_ids),
                   empty_lanes=self.slots - len(lanes),
+                  **self.metrics.record_lane_steps(
+                      len(lanes), self._lanes_at_dispatch(len(lanes))),
                   inflight=int(before is not None), program=program,
                   **self._kernel_steps, **self._plan,
                   **self.metrics.record_selection(self._decode_rows(lanes))):

@@ -34,7 +34,30 @@ from typing import Dict, Optional
 from fleetx_tpu.obs.registry import MetricsRegistry, get_registry
 from fleetx_tpu.serving.inflight import FLUSH_CAUSES
 
-__all__ = ["ServingMetrics"]
+__all__ = ["LANE_STATES", "WAITS", "ServingMetrics", "lane_steps"]
+
+# What the head of the queue can wait for while a lane stands free: the
+# step's one prefill ``slot`` (a prompt mid-prefill holds it, or this
+# step's one prefill-shaped call was spent), ``pages`` of the pool, or its
+# images' trie ``keys`` (``engine._lanes_at_dispatch``).
+WAITS = ("slot", "pages", "keys")
+# Where a lane can stand when a tick is dispatched: one lane-step of
+# ``fleetx_serving_lane_steps_total{state}`` a lane and dispatched tick.
+LANE_STATES = ("decoding", "finishing", "prefilling",
+               *(f"waiting_{what}" for what in WAITS), "unasked")
+
+
+def lane_steps(decoding: int, fields: dict) -> Dict[str, int]:
+    """One dispatched tick's lanes by state (those of ``LANE_STATES`` that
+    it has), from the lanes it decodes for and the lane fields of its
+    ``serving.decode`` span (``engine._lanes_at_dispatch``)."""
+    steps = {"decoding": decoding,
+             "finishing": fields["lanes_finishing"],
+             "prefilling": fields["lanes_prefilling"],
+             "unasked": fields["lanes_unasked"]}
+    if fields["lanes_waiting"]:
+        steps["waiting_" + fields["waiting_on"]] = fields["lanes_waiting"]
+    return steps
 
 
 def _in_flight(what: str, overlapped, flushed: Dict) -> Dict:
@@ -313,14 +336,17 @@ class ServingMetrics:
             "fleetx_serving_decode_ticks_overlapped_total",
             "Decode ticks dispatched while the tick before was unread")
 
-        def by_cause(name, help_):
-            family = reg.counter(name, help_, ("engine", "cause"))
+        def by_label(name, help_, label, values):
+            family = reg.counter(name, help_, ("engine", label))
             children = {}
-            for cause in FLUSH_CAUSES:
-                labels = {"engine": self.engine_label, "cause": cause}
+            for value in values:
+                labels = {"engine": self.engine_label, label: value}
                 owned.append((family, labels))
-                children[cause] = family.labels(**labels)
+                children[value] = family.labels(**labels)
             return children
+
+        def by_cause(name, help_):
+            return by_label(name, help_, "cause", FLUSH_CAUSES)
 
         self._flushed: Dict[str, object] = by_cause(
             "fleetx_serving_decode_ticks_flushed_total",
@@ -336,6 +362,13 @@ class ServingMetrics:
             "fleetx_serving_first_tokens_flushed_total",
             "First tokens read with no program dispatched behind their "
             "lane install, by cause")
+        # where every lane stood at each tick's dispatch (the step's own
+        # account, docs/OBSERVABILITY.md): why a batch was not full
+        self._lane_steps: Dict[str, object] = by_label(
+            "fleetx_serving_lane_steps_total",
+            "Lanes at the dispatch of a decode tick, by where each stood: "
+            "the states of one tick add up to the engine's slots",
+            "state", LANE_STATES)
         # the unit of a prefill program's cache write, decided by its shape
         # (models/gpt/paged_write.py): a page at a time or a row at a time
         self._c_prefill_page_writes = counter(
@@ -636,6 +669,14 @@ class ServingMetrics:
         """A decode tick was read with no tick behind it on the device
         (``cause``: one of ``inflight.FLUSH_CAUSES``)."""
         self._flushed[cause].inc()
+
+    def record_lane_steps(self, decoding: int, fields: dict) -> dict:
+        """Count one dispatched tick's lanes by state
+        (:func:`lane_steps`); returns ``fields`` for the span."""
+        for state, lanes in lane_steps(decoding, fields).items():
+            if lanes:
+                self._lane_steps[state].inc(lanes)
+        return fields
 
     def record_first_token_overlapped(self) -> None:
         """A first token was read with a later program already dispatched
@@ -980,6 +1021,9 @@ class ServingMetrics:
             # admissions whose first token was read
             **_in_flight("first_tokens", self._c_firsts_overlapped,
                          self._firsts_flushed),
+            # lane-steps by state: lanes x the ticks dispatched in all
+            **{f"lane_steps_{state}": int(c.value)
+               for state, c in self._lane_steps.items()},
             # prefill programs by the unit of their cache write
             "prefill_page_writes": int(self._c_prefill_page_writes.value),
             "prefill_row_writes": int(self._c_prefill_row_writes.value),

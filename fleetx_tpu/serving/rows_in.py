@@ -390,6 +390,7 @@ class Tower:
                                     jnp.asarray(pixels), jnp.asarray(ints))
             req.staged.add(number)
             engine.metrics.record_tower(patches)
+            engine._carried["tower"] += 1
 
     def skipped(self, req, shared: int) -> int:
         """Images of ``req`` that lie wholly inside a match of ``shared``

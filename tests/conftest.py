@@ -236,40 +236,49 @@ _LING3 = ("ling3-l7-serve-reason-widebatch", "mla_qk_norm_busy_share",
 _EVABYTE = ("evabyte-l8-serve-bytedocs-longctx", "eva_decode_roofline",
             "eva_pool_busy_share", "eva_summary_rows_share",
             "eva_rows_read_share", "eva_summary_bytes_share")
+# (PR 68: the four entries of the step's own account, which list the
+# thirteen closed-loop cells: no cell's test knew them)
+_STEP_ACCOUNT = ("batch.lanes_prefilling_share", "batch.lanes_waiting_share",
+                 "batch.step_prefill_time_share", "batch.step_caller_ms_p50")
 _WRITTEN_BEFORE = {
     "tests/perfbench/test_perfbench_lfm2.py::"
     "test_every_width_is_the_published_one_and_only_the_depth_is_cut":
         ("jamba2-3b-serve-chat-peak", "axk1-l6-serve-docqa-latent",
          "dsv32-l5-serve-longqa-sparse", "trinity-l5-serve-mixed-longshort")
-        + _LONGCAT + _SOLAR2 + _KEYEVL2 + _LING3 + _EVABYTE,
+        + _LONGCAT + _SOLAR2 + _KEYEVL2 + _LING3 + _EVABYTE + _STEP_ACCOUNT,
     "tests/perfbench/test_perfbench_jamba2.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
         ("axk1-l6-serve-docqa-latent", "dsv32-l5-serve-longqa-sparse",
          "trinity-l5-serve-mixed-longshort") + _SETUP_ENTRIES + _LONGCAT
-        + _SOLAR2 + _KEYEVL2 + _LING3 + _EVABYTE,
+        + _SOLAR2 + _KEYEVL2 + _LING3 + _EVABYTE + _STEP_ACCOUNT,
     "tests/perfbench/test_perfbench_axk1.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
         ("dsv32-l5-serve-longqa-sparse", "trinity-l5-serve-mixed-longshort")
-        + _SETUP_ENTRIES + _LONGCAT + _SOLAR2 + _KEYEVL2 + _LING3 + _EVABYTE,
+        + _SETUP_ENTRIES + _LONGCAT + _SOLAR2 + _KEYEVL2 + _LING3 + _EVABYTE
+        + _STEP_ACCOUNT,
     "tests/perfbench/test_perfbench_dsv32.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
         ("trinity-l5-serve-mixed-longshort",) + _SETUP_ENTRIES + _LONGCAT
-        + _SOLAR2 + _KEYEVL2 + _LING3 + _EVABYTE,
+        + _SOLAR2 + _KEYEVL2 + _LING3 + _EVABYTE + _STEP_ACCOUNT,
     "tests/perfbench/test_perfbench_trinity.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
-        _SETUP_ENTRIES + _LONGCAT + _SOLAR2 + _KEYEVL2 + _LING3 + _EVABYTE,
+        _SETUP_ENTRIES + _LONGCAT + _SOLAR2 + _KEYEVL2 + _LING3 + _EVABYTE
+        + _STEP_ACCOUNT,
     "tests/perfbench/test_perfbench_longcat.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
-        _SOLAR2 + _KEYEVL2 + _LING3 + _EVABYTE,
+        _SOLAR2 + _KEYEVL2 + _LING3 + _EVABYTE + _STEP_ACCOUNT,
     "tests/perfbench/test_perfbench_solar2.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
-        _KEYEVL2 + _LING3 + _EVABYTE,
+        _KEYEVL2 + _LING3 + _EVABYTE + _STEP_ACCOUNT,
     "tests/perfbench/test_perfbench_keyevl2.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
-        _LING3 + _EVABYTE,
+        _LING3 + _EVABYTE + _STEP_ACCOUNT,
     "tests/perfbench/test_perfbench_ling3.py::"
     "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
-        _EVABYTE,
+        _EVABYTE + _STEP_ACCOUNT,
+    "tests/perfbench/test_perfbench_evabyte.py::"
+    "test_the_cell_is_appended_to_the_lists_it_joins_and_nothing_else_moved":
+        _STEP_ACCOUNT,
 }
 
 

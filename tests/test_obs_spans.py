@@ -191,13 +191,21 @@ def test_decode_only_tick_has_one_leaf_span_per_phase(ticked):
         assert all(s.parent == "serving.tick" for s in found), name
         assert all(tick.start_s <= s.start_s and s.end_s <= tick.end_s
                    for s in found), name
-    # the snapshot before the tick is the tick's sibling, the metrics
-    # block after it lies under no span; the re-commit after the delivered
-    # tokens is the tick's
+    # the snapshot before the tick is the tick's sibling, and so is the
+    # metrics block after it, a leaf of its own since PR 68 (no span
+    # encloses the step: nobody's parent changed); the re-commit after the
+    # delivered tokens is the tick's
     snapshot, commit = _named(spans, "serving.snapshot")
-    assert not _named(spans, "serving.observe")
+    (observe,) = _named(spans, "serving.observe")
     assert snapshot.parent is None and snapshot.end_s <= tick.start_s
-    assert spans[-1] is tick
+    assert observe.parent is None and observe.attrs == {}
+    assert spans[-2] is tick and spans[-1] is observe
+    assert tick.end_s <= observe.start_s
+    assert {s.parent for s in spans} == {None, "serving.tick"}
+    # what the step carried, said as the tick closes
+    assert tick.attrs == {"tick": tick.attrs["tick"], "admitted": 0,
+                          "chunked": 0, "tower": 0, "decoded": LANES,
+                          "prefill_rows": 0}
     # ONE emit span for sixteen lanes, never one per lane
     (emit,) = _named(spans, "serving.emit")
     assert commit.parent == "serving.tick" and commit.start_s >= emit.end_s
@@ -210,6 +218,22 @@ def test_decode_only_tick_has_one_leaf_span_per_phase(ticked):
                            "reads": decode.attrs["program"] - 1}
     assert len(spans) - names.count("serving.tick") \
         - names.count("serving.decode") <= 10
+
+
+def test_submit_is_a_leaf_span_that_carries_the_id_it_returns():
+    eng = _engine()
+    rec = get_recorder()
+    rec.clear()
+    rid = eng.submit(np.asarray([1, 2, 3], np.int32), max_length=2)
+    with pytest.raises(ValueError, match="empty prompt"):
+        eng.submit(np.zeros(0, np.int32))
+    taken, refused = rec.spans()
+    assert (taken.name, taken.parent, taken.attrs) == (
+        "serving.submit", None, {"prompt_len": 3, "request": rid})
+    # a refusal lies under the span too, and has no request to name
+    assert (refused.name, refused.attrs) == ("serving.submit",
+                                             {"prompt_len": 0})
+    eng.drain()
 
 
 def test_construction_is_one_span_that_holds_what_it_compiled():
