@@ -116,10 +116,15 @@ hostage to every long arriving prompt — prefill is MXU-bound, decode is
 HBM-bound, and one 4k-token prefill inside a tick stalls every active
 stream for its full duration. With a chunk size set, a prompt whose
 non-shared suffix exceeds it enters a ``prefilling`` lifecycle state:
-the engine runs AT MOST ONE chunk-sized prefill call per tick (a short
-prompt's whole-prompt call counts as that tick's chunk), interleaved
-with the batched decode, so no decode tick ever stalls more than ~one
-chunk of prefill compute. Chunks reuse the bucketed prefill jits at
+the engine runs AT MOST TWO chunk-sized prefill calls per tick (two
+chunks of the prompt mid-prefill; or its last chunk and the next
+request's admission; or two short prompts' whole-prompt calls:
+``_step_inner`` runs the pass that takes one call twice), interleaved
+with the batched decode, so no decode tick ever stalls more than ~two
+chunks of prefill compute, and a prompt holds the admission head for half
+the steps it did at one call a tick. The second call is a second CALL of
+the same program: nothing is traced for it. Chunks reuse the bucketed
+prefill jits at
 chunk granularity — long prompts stop minting per-length buckets up to
 ``cache_len`` — writing through the same per-row ``cache_positions`` /
 page-scatter seams decode uses: chunks write straight into the lane's
@@ -1219,31 +1224,43 @@ class ServingEngine:
         return summary
 
     def _step_inner(self, commit=lambda: None) -> Dict:
-        """The actual tick body: queued-expiry sweep, prefill work
-        (admissions — or, mid-chunked-prefill, exactly one chunk), one
+        """The actual tick body: queued-expiry sweep, prefill work, one
         batched decode step dispatched and the one before it read
         (module docstring "Tick order"), retirements, active-deadline
         sweep. ``commit`` re-bases the transactional snapshot after each
         completed phase (see :meth:`step`): an admission whose first token
-        was read, a chunk, a tick's tokens delivered. With chunking enabled the
-        tick's prefill budget is ONE chunk-sized device call — a chunk
-        of the in-flight prompt or one short admission — so decode never
-        stalls longer (the ``prefill_stall_ms`` histogram measures it)."""
+        was read, a chunk, a tick's tokens delivered. The prefill work is
+        a PASS that takes one chunk-shaped device call: a chunk of the
+        prompt mid-prefill, else admissions from the queue's head (with
+        chunking, ONE: a long prompt's first chunk or a short prompt's
+        one call). With chunking enabled the tick's prefill budget is TWO
+        such calls, so the pass runs twice: the prompt mid-prefill is read
+        at two chunks a step, the step its last chunk runs in admits the
+        next request, and decode never stalls longer than two chunks (the
+        ``prefill_stall_ms`` histogram measures it). FIFO holds as it
+        did: one prompt mid-prefill, and it is the admission head. A pass
+        that admits nobody (nobody queued, the head refused) ends the
+        work. Without chunking the one pass admits until a refusal."""
         timed_out = self._expire_queued(self._now())
         admitted = 0
         chunked = 0
         self._refused = None
         self._carried = {"tower": 0, "prefill_rows": 0}
         prefill_t0 = self._now()
-        if self._prefilling:
-            # FIFO holds: the mid-prefill request IS the admission head,
-            # so nothing else admits until its chunks finish (or expire)
-            self._refused = "slot"
-            n, expired = self._chunk_tick()
-            chunked += n
-            timed_out += expired
-            commit()  # chunk progress (prefill_pos) stays committed
-        else:
+        # the step's prefill work, ONE chunk-shaped call a pass: with a
+        # chunk size the step's budget is two such calls, so two passes
+        for _ in range(2 if self.prefill_chunk else 1):
+            if self._prefilling:
+                # FIFO holds: the mid-prefill request IS the admission
+                # head, so nothing else admits until its chunks finish
+                # (or expire)
+                self._refused = "slot"
+                n, expired = self._chunk_tick()
+                chunked += n
+                timed_out += expired
+                commit()  # chunk progress (prefill_pos) stays committed
+                continue
+            asked = admitted
             while (len(self.scheduler)
                    and self._can_admit(self.scheduler.peek())):
                 req = self.scheduler.pop_next()
@@ -1271,7 +1288,11 @@ class ServingEngine:
                     commit()
                 if self.prefill_chunk:
                     self._refused = "slot"
-                    break  # one prefill-shaped device call per tick
+                    break  # one prefill-shaped device call a pass
+            if admitted == asked:
+                break  # nobody queued, or the head refused: not asked twice
+        if self.prefill_chunk and chunked + admitted > 1:
+            self.metrics.record_second_chunk()
         if admitted or chunked:
             self.metrics.observe_prefill_stall(self._now() - prefill_t0)
         self._delivered = 0
@@ -1554,8 +1575,15 @@ class ServingEngine:
             # the device cache and ZERO tokens were emitted, so they go
             # back to the queue HEAD (they were the head when admitted)
             # and restart chunked prefill — byte-identity is structural,
-            # and the host tier below keeps their shared prefix cheap
-            for _, req in sorted(self._prefilling.items(), reverse=True):
+            # and the host tier below keeps their shared prefix cheap.
+            # Arrival order (the ids') holds behind an OLDER admission that
+            # a rollback has put back there (its last chunk ran in the same
+            # step, ahead of this one's admission, its token was unread)
+            back = list(self._prefilling.values())
+            newest = max((r.id for r in back), default=-1)
+            while len(self.scheduler) and self.scheduler.peek().id < newest:
+                back.append(self.scheduler.pop_next())
+            for req in sorted(back, key=lambda r: r.id, reverse=True):
                 req.slot = None
                 req.prefill_pos = 0
                 req.phase = "queued"

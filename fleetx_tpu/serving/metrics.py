@@ -164,7 +164,7 @@ class ServingMetrics:
         # what the two-level page cache moved between HBM and host DRAM
         self._c_prefill_chunks = counter(
             "fleetx_serving_prefill_chunks_total",
-            "Chunked-prefill device calls executed (one per tick max)")
+            "Chunked-prefill device calls executed (two per tick max)")
         self._c_host_spilled = counter(
             "fleetx_serving_host_spilled_pages_total",
             "Warm KV pages spilled to the host-DRAM tier on eviction")
@@ -369,6 +369,12 @@ class ServingMetrics:
             "Lanes at the dispatch of a decode tick, by where each stood: "
             "the states of one tick add up to the engine's slots",
             "state", LANE_STATES)
+        # the step's prefill budget (engine.py ``_step_inner``): the steps
+        # that took its second call, beside a chunk of an older prompt
+        self._c_second_chunks = counter(
+            "fleetx_serving_second_chunks_total",
+            "Steps whose second prefill-shaped call ran: a chunk of a "
+            "second prompt mid-prefill, or an admission behind a chunk")
         # the unit of a prefill program's cache write, decided by its shape
         # (models/gpt/paged_write.py): a page at a time or a row at a time
         self._c_prefill_page_writes = counter(
@@ -678,6 +684,10 @@ class ServingMetrics:
                 self._lane_steps[state].inc(lanes)
         return fields
 
+    def record_second_chunk(self) -> None:
+        """A step ran its second prefill-shaped call."""
+        self._c_second_chunks.inc()
+
     def record_first_token_overlapped(self) -> None:
         """A first token was read with a later program already dispatched
         behind its lane install."""
@@ -968,7 +978,7 @@ class ServingMetrics:
             "pages_in_use": self.pages_in_use,
             "pages_total": self.pages_total,
             # chunked-prefill + host-tier story (docs/SERVING.md): decode
-            # stall bounded by one chunk, prefix hits sustained past the
+            # stall bounded by two chunks, prefix hits sustained past the
             # device pool via the host-DRAM spill tier
             "prefill_chunks": self.prefill_chunks,
             "prefill_stall_ms_p50": stall_p50,
@@ -1024,6 +1034,7 @@ class ServingMetrics:
             # lane-steps by state: lanes x the ticks dispatched in all
             **{f"lane_steps_{state}": int(c.value)
                for state, c in self._lane_steps.items()},
+            "second_chunks": int(self._c_second_chunks.value),
             # prefill programs by the unit of their cache write
             "prefill_page_writes": int(self._c_prefill_page_writes.value),
             "prefill_row_writes": int(self._c_prefill_row_writes.value),

@@ -282,6 +282,18 @@ _WRITTEN_BEFORE = {
 }
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _no_other_files_events():
+    """A test file starts with none of another file's events: the event log
+    is the process's, a worker runs file after file, and a check that holds
+    a run to NO fault event (``perfbench/serving.py`` ``serving_checks``,
+    asked in-process by two files of ``tests/perfbench/``) would else read
+    the faults that a file before it in the worker injected on purpose."""
+    from fleetx_tpu.obs import get_event_log
+
+    get_event_log().clear()
+
+
 @pytest.fixture(autouse=True)
 def _benchmark_as_the_test_knew_it(request, monkeypatch):
     later = _WRITTEN_BEFORE.get(request.node.nodeid)

@@ -7,9 +7,9 @@ Three layers:
   KV, and the flash-interpret kernel path — and a request decoded from
   spill-REVIVED host pages must match its cold-prefilled run byte for
   byte (revived bytes are the spilled bytes).
-- **Scheduler semantics**: one chunk per tick interleaved with decode
-  (active requests keep streaming one token per tick while a long
-  prompt ingests), deadlines checked between chunks (an expired request
+- **Scheduler semantics**: chunks interleaved with decode, two a tick at
+  most (active requests keep streaming one token per tick while a long
+  prompt ingests; ``test_serving_second_chunk.py`` holds the budget), deadlines checked between chunks (an expired request
   stops burning prefill with nothing leaked), FIFO preserved.
 - **Crash safety**: a fault mid-chunk (prefill raise or decode raise
   while a prompt is mid-ingestion) rolls back, recovery requeues the
@@ -177,7 +177,7 @@ def test_free_and_mid_prefill_lanes_change_no_token(tiny_flash, monkeypatch):
         eng.step()
         if eng._prefilling:     # its pages were handed out at admission
             mid_prefill_ticks += int(eng.cache_manager.tables[:, -1].any())
-    assert mid_prefill_ticks >= 3
+    assert mid_prefill_ticks >= 2     # five chunks, two a step
     res = eng.drain()
     for rid, tokens in zip(short + [long_rid], want):
         assert_token_parity(np.asarray(res[rid].tokens), tokens,
@@ -297,7 +297,7 @@ def test_decode_streams_one_token_per_tick_during_chunked_prefill(tiny):
         summary = eng.step()
         assert len(req.tokens) == before + 1, (
             "active stream stalled during a prefill chunk")
-        assert summary["chunked"] <= 1
+        assert summary["chunked"] <= 2
     res = eng.drain()
     assert len(res[long_rid].tokens) == 3
     assert_token_parity(

@@ -185,15 +185,16 @@ def test_the_lane_step_counters_are_the_spans_sums(run):
 
 def _mid_prefill(model):
     """A short request decodes, a long prompt is mid-prefill in chunks of
-    four, a third is queued behind it: the head holds the slot."""
+    four, two a step, a third is queued behind it with a lane free: the
+    step's two prefill-shaped calls go to the head, which holds the slot."""
     engine = _engine(*model, prefill_chunk=4)
     rng = np.random.default_rng(1)
     engine.submit(rng.integers(1, 60, 3, dtype=np.int32), max_length=12)
     engine.step()
-    engine.submit(rng.integers(1, 60, 18, dtype=np.int32), max_length=2)
+    engine.submit(rng.integers(1, 60, 29, dtype=np.int32), max_length=2)
     engine.submit(rng.integers(1, 60, 3, dtype=np.int32), max_length=2)
-    engine.step()     # admits the long one: its first chunk
-    return engine, 3, lambda: None
+    engine.step()     # admits the long one: its first chunk, and its second
+    return engine, 2, lambda: None
 
 
 def _pool_too_small(model):
@@ -258,11 +259,18 @@ def test_waiting_on_names_what_refused_the_head(model, cause, scene):
         assert at["lanes_waiting"] == 1 and at["waiting_on"] == cause, at
         assert at["batch"] + sum(at[f] for f in FIELDS) == engine.slots
     checks = _named(spans, "serving.can_admit")
+    seconds = engine.metrics.snapshot()["second_chunks"]
     if cause == "slot":
         # a prompt mid-prefill holds the head: nobody is even asked
         assert not checks
         assert all(t.attrs["lanes_prefilling"] == 1 for t in ticks)
+        # and takes both of the step's calls, which the counter counts
+        # (as it did the step that admitted it and read on)
+        assert [t.attrs["chunked"] for t in _named(spans, "serving.tick")
+                ] == [2] * steps
+        assert seconds == before["second_chunks"] + steps == steps + 1
     else:
+        assert seconds == before["second_chunks"]
         # said where it is decided, and handed on: not derived again
         assert [c.attrs.get("refused") for c in checks] == [cause] * steps
     snap = engine.metrics.snapshot()

@@ -6,7 +6,8 @@ Runs ``perfbench/run.py`` unchanged (its result line is printed as ever)
 and then prints one more line, ``step_account {...}``, folded from the
 run's spans and counters (docs/OBSERVABILITY.md "What a step says of
 itself"): the window's lane-steps by state, with the waiting ones by
-cause, as shares of ``lanes x ticks``; whether the five lane fields added up
+cause, as shares of ``lanes x ticks``; the steps that took their second
+prefill-shaped call; whether the five lane fields added up
 to the lanes on EVERY ``serving.decode`` span and ``waiting_on`` stood
 exactly where a lane waited; the engine's ``lane_steps_*`` counters against
 the spans; what the window's steps carried (``serving.tick``); and the
@@ -74,6 +75,13 @@ def fold(run) -> dict:
         "steps_with_prefill": sum(
             1 for s in carried if s.attrs["admitted"] + s.attrs["chunked"]
             + s.attrs["tower"]),
+        # the steps of an engine with a chunk size that took their second
+        # prefill-shaped call, and the engine's own count of them (from its
+        # first tick on; None before PR 70)
+        "steps_with_two_calls": sum(
+            1 for s in carried if run.cell.deploy.get("prefill_chunk")
+            and s.attrs["chunked"] + s.attrs["admitted"] > 1),
+        "second_chunks": run.counters.get("second_chunks"),
         "carried": {key: sum(s.attrs[key] for s in carried)
                     for key in ("admitted", "chunked", "tower", "decoded",
                                 "prefill_rows")},
